@@ -101,7 +101,7 @@ class EngineConfig:
     salt: int = DEFAULT_SALT
     worker_id: int = 0
     # Fused decode steps per dispatch. >1 amortizes host<->device round trips
-    # (vital on remote/tunneled chips); trades up to decode_steps-1 wasted
+    # (one dispatch per burst); trades up to decode_steps-1 wasted
     # steps per finishing sequence and K-token stream granularity.
     decode_steps: int = 1
     # Per-step prefill token budget while decodable sequences are running:

@@ -52,6 +52,10 @@ class WorkerSpec:
     # GSPMD execution: a parallel.mesh.MeshPlan, or "auto" to derive one from
     # the device count and model shape (tp <= kv heads, ep for wide MoE).
     mesh_plan: Any = None
+    # The one device this worker's params, KV cache and step inputs live on
+    # (replicas in one process: worker i on device i). None = jax's default
+    # device; ignored under a mesh, which owns placement.
+    device: Any = None
     # Timing-model engine instead of JAX (planner/router fleets in CI and the
     # planner's local connector; parity: reference mocker, SURVEY.md row 35).
     mock: bool = False
@@ -234,13 +238,15 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
         return await JaxEngineService(build_mock_core(spec.engine_config, on_kv_event=on_kv_event)).start()
 
     def _build() -> ModelRunner:
-        # Device work (param init, cache allocation) can take seconds on a
-        # remote/real chip — keep it off the event loop so lease keep-alives
-        # and health endpoints stay live.
+        # Device work (param init, cache allocation) takes seconds — keep it
+        # off the event loop so lease keep-alives and health endpoints stay
+        # live.
+        import contextlib
+
+        import jax
+
         mesh = None
         if spec.mesh_plan is not None:
-            import jax
-
             from dynamo_tpu.parallel.mesh import MeshPlan, make_mesh
 
             plan = spec.mesh_plan
@@ -277,19 +283,29 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
             params = load_params(spec.model_dir, spec.model_config, mesh=mesh)
         else:
             params = None  # random-init below, possibly directly quantized
-        if spec.quantize and params is None:
-            # Random-init + quantize without ever materializing the
-            # full-precision tree: an 8B-class random model OOMs a 16 GB
-            # chip before quantize_params could shrink it.
-            from dynamo_tpu.models.quant import init_params_quantized
+        # Random init lands where it will be served from: on this worker's
+        # device, or (mesh) each device materializing only its own shard —
+        # an 8B bf16 model built whole on the default device OOMs it before
+        # shard_params could spread it.
+        device = spec.device
+        with jax.default_device(device) if device is not None else contextlib.nullcontext():
+            if spec.quantize and params is None:
+                # Random-init + quantize without ever materializing the
+                # full-precision tree: an 8B-class random model OOMs a 16 GB
+                # chip before quantize_params could shrink it.
+                from dynamo_tpu.models.quant import init_params_quantized
 
-            params = init_params_quantized(spec.model_config, 0, mode=spec.quantize)
-        elif spec.quantize:
-            from dynamo_tpu.models.quant import quantize_params
+                params = init_params_quantized(spec.model_config, 0, mode=spec.quantize)
+            elif spec.quantize:
+                from dynamo_tpu.models.quant import quantize_params
 
-            params = quantize_params(params, mode=spec.quantize)
-        elif params is None:
-            params = llama.init_params(spec.model_config, 0)
+                params = quantize_params(params, mode=spec.quantize)
+            elif params is None and mesh is not None:
+                from dynamo_tpu.parallel.sharding import init_sharded
+
+                params = init_sharded(lambda: llama.init_params(spec.model_config, 0), mesh)
+            elif params is None:
+                params = llama.init_params(spec.model_config, 0)
         return ModelRunner(
             spec.model_config,
             params,
@@ -298,10 +314,20 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
             max_batch_size=spec.engine_config.max_batch_size,
             attn_impl=spec.attn_impl,
             mesh=mesh,
+            device=device,
             cache_dtype=_kv_cache_dtype(),
         )
 
     runner = await asyncio.get_running_loop().run_in_executor(None, _build)
+    import jax
+
+    dev0 = jax.devices()[0]
+    logger.info(
+        "engine for %s: platform=%s device_kind=%s devices=%d placement=%s attention=%s",
+        spec.card.name, dev0.platform, dev0.device_kind, len(jax.devices()),
+        dict(runner.mesh.shape) if runner.mesh is not None else (runner.device or dev0),
+        runner.attn_impl,
+    )
     block_manager = None
     if spec.block_manager_config is not None:
         from dynamo_tpu.blocks import KvBlockManager
@@ -408,9 +434,7 @@ async def serve_worker(
         metadata={"model": spec.card.name},
     )
     card_lease = lease or await runtime.primary_lease()
-    await runtime.store.put(
-        spec.card.instance_key(instance.lease_id), spec.card.to_bytes(), lease_id=card_lease.id
-    )
+    await runtime.put_leased(spec.card.instance_key(instance.lease_id), spec.card.to_bytes(), card_lease)
     logger.info("worker serving %s as instance %x", spec.card.name, instance.lease_id)
     return service
 
@@ -578,7 +602,7 @@ async def drain_worker(
             instance, metadata={**instance.metadata, "draining": True}
         )
         try:
-            await runtime.store.put(instance.key, draining.to_bytes(), lease_id=lease.id)
+            await runtime.put_leased(instance.key, draining.to_bytes(), lease)
         except Exception:
             logger.exception("drain announcement failed; clients will retry against us")
     done = True
@@ -639,6 +663,13 @@ async def run_local(
     mock = engine_kw.pop("mock", False)
     quantize = engine_kw.pop("quantize", "")
     total_workers = num_workers + num_prefill_workers
+    # Replicas in one process each get a device of their own, round-robin
+    # (worker i on device i): left to jax's default they all sit on device 0.
+    devices = []
+    if total_workers > 1 and mesh_plan is None and not mock:
+        import jax
+
+        devices = jax.local_devices()
 
     def make_spec(i: int) -> WorkerSpec:
         spec = make_worker_spec(preset, **engine_kw)
@@ -647,6 +678,8 @@ async def run_local(
         spec.mesh_plan = mesh_plan
         spec.mock = mock
         spec.quantize = quantize
+        if devices:
+            spec.device = devices[i % len(devices)]
         if g2_blocks or g3_blocks or g4_blocks:
             from dynamo_tpu.blocks import BlockManagerConfig
 
@@ -849,20 +882,15 @@ async def run_role(args: argparse.Namespace) -> None:
     await stop.wait()
 
 
-async def _amain(args: argparse.Namespace) -> None:
-    if args.role != "local":
-        await run_role(args)
-        return
-    if args.input not in ("http", "text") and not args.input.startswith("batch:"):
-        raise SystemExit(
-            f"--input must be 'http', 'text', or 'batch:FILE.jsonl' (got {args.input!r})"
-        )
+async def start_local(args: argparse.Namespace) -> dict[str, Any]:
+    """``--role local``: bring the whole stack up in this process from parsed
+    CLI args; returns run_local's handles (pass them to :func:`stop_local`)."""
     disagg = None
     if args.disagg_threshold is not None:
         from dynamo_tpu.disagg.router import DisaggConfig
 
         disagg = DisaggConfig(max_local_prefill_length=args.disagg_threshold)
-    handles = await run_local(
+    return await run_local(
         args.model,
         host=args.host,
         port=args.http_port,
@@ -879,6 +907,44 @@ async def _amain(args: argparse.Namespace) -> None:
         mock=args.mock,
         quantize=args.quantize,
     )
+
+
+async def stop_local(handles: dict[str, Any]) -> None:
+    """Full teardown of a :func:`start_local` stack. Leaving engines/runtime
+    to loop-shutdown cancellation risks the shutdown-hang class the soak
+    tests guard against. One shielded task runs every step (each isolated),
+    so a Ctrl-C arriving during teardown can't skip the later closes."""
+
+    async def _teardown() -> None:
+        for closer in (
+            handles["http"].stop,
+            handles["watcher"].close,
+            *(svc.close for svc in handles["services"]),
+            handles["runtime"].close,
+        ):
+            try:
+                await closer()
+            except Exception:
+                logger.exception("teardown step %r failed", closer)
+
+    task = asyncio.ensure_future(_teardown())
+    try:
+        await asyncio.shield(task)
+    except asyncio.CancelledError:
+        if not task.done():
+            await asyncio.wait([task])
+        raise
+
+
+async def _amain(args: argparse.Namespace) -> None:
+    if args.role != "local":
+        await run_role(args)
+        return
+    if args.input not in ("http", "text") and not args.input.startswith("batch:"):
+        raise SystemExit(
+            f"--input must be 'http', 'text', or 'batch:FILE.jsonl' (got {args.input!r})"
+        )
+    handles = await start_local(args)
     logger.info("serving %s on port %d", args.model, handles["port"])
     try:
         if args.input == "text":
@@ -888,30 +954,7 @@ async def _amain(args: argparse.Namespace) -> None:
         else:
             await asyncio.Event().wait()
     finally:
-        # Full teardown: text/batch modes exit here normally, and leaving
-        # engines/runtime to loop-shutdown cancellation risks the
-        # shutdown-hang class the soak tests guard against. One shielded
-        # task runs every step (each isolated), so a Ctrl-C arriving during
-        # teardown can't skip the later closes.
-        async def _teardown() -> None:
-            for closer in (
-                handles["http"].stop,
-                handles["watcher"].close,
-                *(svc.close for svc in handles["services"]),
-                handles["runtime"].close,
-            ):
-                try:
-                    await closer()
-                except Exception:
-                    logger.exception("teardown step %r failed", closer)
-
-        task = asyncio.ensure_future(_teardown())
-        try:
-            await asyncio.shield(task)
-        except asyncio.CancelledError:
-            if not task.done():
-                await asyncio.wait([task])
-            raise
+        await stop_local(handles)
 
 
 async def run_text_input(port: int, model: str) -> None:
@@ -1037,10 +1080,13 @@ async def run_batch_input(port: int, model: str, input_path: str, *, concurrency
     )
 
 
-def main(argv: list[str] | None = None) -> None:
-    # Layered defaults (reference figment cascade, `config.rs:26-143`):
-    # dataclass defaults <- TOML (DYN_CONFIG) <- DYN_RUNTIME_*/DYN_WORKER_*
-    # env <- CLI flags (highest).
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """Parse the launcher CLI and re-export the engine flags the worker
+    settings cascade reads from the environment.
+
+    Layered defaults (reference figment cascade, `config.rs:26-143`):
+    dataclass defaults <- TOML (DYN_CONFIG) <- DYN_RUNTIME_*/DYN_WORKER_*
+    env <- CLI flags (highest)."""
     from dynamo_tpu.config import load_runtime_settings, load_store_settings, load_worker_settings
 
     rs = load_runtime_settings()
@@ -1134,11 +1180,6 @@ def main(argv: list[str] | None = None) -> None:
         help="host:port of the rank-0 jax coordinator (default: rendezvous via the store)",
     )
     parser.add_argument(
-        "--platform", default=None,
-        help="force a jax platform (e.g. 'cpu'); needed because hardware "
-             "plugins may override the JAX_PLATFORMS env var",
-    )
-    parser.add_argument(
         "--tune-profile", default=None,
         help="auto-tuner profile JSON (bench.py --tune output); applies its "
         "knob assignments as env defaults — explicit env/CLI still wins",
@@ -1170,18 +1211,6 @@ def main(argv: list[str] | None = None) -> None:
                 ),
                 flush=True,
             )
-    if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
-    from dynamo_tpu.runtime.logging import setup_logging
-
-    # Cascade-resolved logging settings; reference-named env toggles
-    # (DYN_LOGGING_JSONL etc.) still apply when the cascade left defaults.
-    setup_logging(
-        jsonl=rs.log_jsonl or None,
-        level=None if rs.log_level == "INFO" else rs.log_level,
-    )
     if args.decode_steps != 1:
         import os
 
@@ -1202,6 +1231,23 @@ def main(argv: list[str] | None = None) -> None:
         import os
 
         os.environ["DYN_WORKER_OVERLAP"] = "1"
+    args.runtime_settings = rs  # the cascade the flag defaults came from; main() logs by it
+    return args
+
+
+def main(argv: list[str] | None = None) -> None:
+    from dynamo_tpu.compile_cache import enable_compile_cache
+    from dynamo_tpu.runtime.logging import setup_logging
+
+    args = parse_args(argv)
+    # Cascade-resolved logging settings; reference-named env toggles
+    # (DYN_LOGGING_JSONL etc.) still apply when the cascade left defaults.
+    rs = args.runtime_settings
+    setup_logging(
+        jsonl=rs.log_jsonl or None,
+        level=None if rs.log_level == "INFO" else rs.log_level,
+    )
+    enable_compile_cache()
     asyncio.run(_amain(args))
 
 

@@ -27,10 +27,11 @@ closes the gap lazily:
 
 Achieved GB/s / FLOP/s divide by per-chip peaks: auto-detected from
 ``jax.devices()[0].device_kind`` (v4/v5e/v5p/v6e table below), overridable
-with ``DYN_PEAK_HBM_GBPS`` / ``DYN_PEAK_TFLOPS``. On CPU backends the
-fallback peaks are DDR-class proxies — roofline *fractions* there are test
-plumbing, not measurements (the bytes/flops themselves are still real XLA
-numbers; CPU populates cost_analysis).
+with ``DYN_PEAK_HBM_GBPS`` / ``DYN_PEAK_TFLOPS``; an accelerator missing from
+the table is an error. On the CPU platform the peaks are DDR-class proxies
+(source ``cpu-proxy:``) — roofline *fractions* there are test plumbing, not
+measurements (the bytes/flops themselves are still real XLA numbers; CPU
+populates cost_analysis).
 
 Wall-clock basis caveat: the ledger's wall is the ``timed_dispatch``
 measurement. On the synchronous paths that spans device execution; on the
@@ -81,10 +82,11 @@ CHIP_PEAKS: dict[str, tuple[float, float]] = {
     "v4": (1228.0, 275.0),
 }
 
-#: Documented CPU (and unknown-backend) fallback: one DDR channel-class
-#: 50 GB/s and 0.5 TFLOPS — deliberately round proxies so CPU rooflines
-#: read as plumbing, never as measurements.
-CPU_FALLBACK_PEAKS = (50.0, 0.5)
+#: CPU backends only: one DDR channel-class 50 GB/s and 0.5 TFLOPS —
+#: deliberately round proxies so CPU rooflines read as test plumbing, never
+#: as measurements (labelled ``cpu-proxy:`` in /debug/cost). An accelerator
+#: that is not in :data:`CHIP_PEAKS` is an error, not a default.
+CPU_PROXY_PEAKS = (50.0, 0.5)
 
 #: Module-wide count of cost-extraction lowerings (background compiles).
 #: The DYN_COST_PLANE=0 acceptance test spies on this staying flat.
@@ -124,24 +126,23 @@ def chip_peaks() -> tuple[float, float, str]:
     """(peak HBM GB/s, peak TFLOPS, source) for device 0.
 
     Env overrides win; else the :data:`CHIP_PEAKS` table keyed on
-    ``jax.devices()[0].device_kind``; else :data:`CPU_FALLBACK_PEAKS`.
+    ``jax.devices()[0].device_kind``; :data:`CPU_PROXY_PEAKS` on the CPU
+    platform only. Raises for an accelerator the table does not know.
     """
-    kind = ""
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = ""
+    dev = jax.devices()[0]
+    kind = dev.device_kind
     hbm = flops = None
-    source = f"fallback:{kind or 'unknown'}"
-    low = kind.lower()
-    for sub, (h, f) in CHIP_PEAKS.items():
-        if sub in low:
-            hbm, flops, source = h, f, f"table:{kind}"
-            break
-    if hbm is None:
-        hbm, flops = CPU_FALLBACK_PEAKS
+    source = ""
+    if dev.platform == "cpu":
+        (hbm, flops), source = CPU_PROXY_PEAKS, f"cpu-proxy:{kind}"
+    else:
+        low = kind.lower()
+        for sub, (h, f) in CHIP_PEAKS.items():
+            if sub in low:
+                hbm, flops, source = h, f, f"table:{kind}"
+                break
     env_h, env_f = os.environ.get(PEAK_HBM_ENV), os.environ.get(PEAK_FLOPS_ENV)
     try:
         if env_h:
@@ -151,6 +152,12 @@ def chip_peaks() -> tuple[float, float, str]:
             source = "env"
     except ValueError:
         logger.warning("ignoring malformed %s/%s", PEAK_HBM_ENV, PEAK_FLOPS_ENV)
+    if hbm is None or flops is None:
+        raise RuntimeError(
+            f"no peak HBM bandwidth / FLOPS known for {dev.platform} device_kind "
+            f"{kind!r}: add it to CHIP_PEAKS (observability/cost.py) or set both "
+            f"{PEAK_HBM_ENV} and {PEAK_FLOPS_ENV}"
+        )
     return float(hbm), float(flops), source
 
 
@@ -217,9 +224,12 @@ def _avatar(x):
     """ShapeDtypeStruct stand-in for an array; non-arrays pass through.
 
     Captured eagerly at the dispatch site — *before* the jitted call — so
-    donated cache buffers can't be invalidated under us. Sharding rides
-    along when the array has one, keeping the re-lowered program's cost
-    analysis faithful on meshes.
+    donated cache buffers can't be invalidated under us. The avatar must
+    lower to the very module the call lowered to, or the re-lowering compiles
+    a second copy instead of finding the call's executable: a sharding rides
+    along exactly when the array is committed to one (a mesh, a replica's
+    device) — on an uncommitted array it would add annotations the call's
+    module does not have.
     """
     import jax
 
@@ -227,12 +237,8 @@ def _avatar(x):
     dtype = getattr(x, "dtype", None)
     if shape is None or dtype is None:
         return x
-    sharding = getattr(x, "sharding", None)
-    try:
-        if sharding is not None:
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    except Exception:
-        pass
+    if getattr(x, "committed", False):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=x.sharding)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
@@ -546,5 +552,17 @@ class CostRegistry:
         }
 
     def close(self) -> None:
-        if self._thread is not None and self._thread.is_alive():
-            self._q.put(None)
+        """Stop the extraction thread: drop what is queued, let the compile
+        in flight finish, and join. A daemon thread still inside the compiler
+        when the interpreter exits takes the process down with it."""
+        thread = self._thread
+        if thread is None or not thread.is_alive():
+            return
+        try:
+            while True:
+                self._q.get_nowait()
+                self._q.task_done()
+        except queue.Empty:
+            pass
+        self._q.put(None)
+        thread.join()

@@ -40,16 +40,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace, check_vma spelled check_rep
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(*args, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _exp_shard_map(*args, **kw)
 import jax.numpy as jnp
 
 NEG_INF = -1e30  # large-but-finite: avoids NaN from (-inf) - (-inf) in masked softmax
@@ -247,7 +237,7 @@ def paged_attention_sharded(
         return paged_attention(q, kc, vc, bt, pos, scale=scale, impl=impl,
                                contiguous_positions=contiguous_positions)
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, row_spec, row_spec),
         out_specs=q_spec,

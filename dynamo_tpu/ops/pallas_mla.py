@@ -32,16 +32,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace, check_vma spelled check_rep
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(*args, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _exp_shard_map(*args, **kw)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -57,9 +47,6 @@ from dynamo_tpu.ops.pallas_paged import (  # shared kernel helpers
 
 NEG_INF = -1e30
 LANES = 128
-
-# jax >= 0.4.34 renamed TPUCompilerParams -> CompilerParams; support both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def mla_decode_supported(
@@ -312,7 +299,7 @@ def mla_paged_decode(
             jax.ShapeDtypeStruct((b, splits, r_rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, splits, r_rows, LANES), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
@@ -365,7 +352,7 @@ def mla_paged_decode_sharded(
             num_splits=num_splits,
         )
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, q_spec, P(), P(), row_spec, row_spec),
         out_specs=q_spec,

@@ -82,9 +82,6 @@ logger = logging.getLogger(__name__)
 NEG_INF = -1e30
 LANES = 128
 
-# jax >= 0.4.34 renamed TPUCompilerParams -> CompilerParams; support both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # Kernel-fallback observability: a config typo (odd GQA grouping, a page
 # slab width off the 128-lane grid) silently costs ~5x decode throughput if
 # the dispatch drops to the gather formulation. The dispatch runs at jit
@@ -527,7 +524,7 @@ def paged_decode_attention(
             jax.ShapeDtypeStruct((b, splits, r_rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, splits, r_rows, LANES), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

@@ -60,9 +60,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 
-# jax >= 0.4.34 renamed TPUCompilerParams -> CompilerParams; support both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _block_tokens(page_size: int, width: int) -> int:
     """KV tokens per compute block, budgeted against scoped VMEM (~16 MB):
@@ -311,7 +308,7 @@ def paged_prefill_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, t * group, head_dim), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

@@ -21,27 +21,8 @@ from __future__ import annotations
 import functools
 
 import jax
-
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace, check_vma spelled check_rep
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(*args, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _exp_shard_map(*args, **kw)
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def _pcast_varying(x, axes):
-    """Mark ``x`` as device-varying over ``axes`` where jax tracks vma
-    (>= 0.5); identity on 0.4.x, whose shard_map has no vma types and
-    accepts replicated/varying carries interchangeably."""
-    pcast = getattr(jax.lax, "pcast", None)
-    return x if pcast is None else pcast(x, axes, to="varying")
-
 
 NEG_INF = -1e30
 
@@ -99,9 +80,9 @@ def ring_attention_sharded(q, k, v, q_pos, kv_pos, *, axis_name: str, scale: flo
     # through the shard_map) so the fori_loop carry type matches the
     # (device-varying) merged partials.
     vary = tuple(vary_axes) if vary_axes else (axis_name,)
-    acc = _pcast_varying(jnp.zeros((b, tq, h, hd_v), jnp.float32), vary)
-    m = _pcast_varying(jnp.full((b, h, tq), NEG_INF, jnp.float32), vary)
-    l = _pcast_varying(jnp.zeros((b, h, tq), jnp.float32), vary)
+    acc = jax.lax.pcast(jnp.zeros((b, tq, h, hd_v), jnp.float32), vary, to="varying")
+    m = jax.lax.pcast(jnp.full((b, h, tq), NEG_INF, jnp.float32), vary, to="varying")
+    l = jax.lax.pcast(jnp.zeros((b, h, tq), jnp.float32), vary, to="varying")
 
     def ring_step(i, carry):
         acc, m, l, k_cur, v_cur, kv_pos_cur = carry
@@ -150,7 +131,7 @@ def ring_attention(
         ring_attention_sharded, axis_name=axis_name, scale=scale,
         vary_axes=(axis_name,) + ((batch_axis,) if batch_axis else ()),
     )
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, pos_spec, pos_spec),

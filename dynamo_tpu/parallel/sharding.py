@@ -134,3 +134,11 @@ def shard_params(params: dict[str, Any], mesh: Mesh) -> dict[str, Any]:
     """Place a params pytree onto the mesh with TP/EP shardings."""
     shardings = param_shardings(mesh, params)
     return jax.tree.map(jax.device_put, params, shardings)
+
+
+def init_sharded(init_fn, mesh: Mesh) -> dict[str, Any]:
+    """Run a no-argument params initializer with TP/EP-sharded outputs: each
+    device materializes only its own shard, so a model that needs the whole
+    mesh's memory is never built on one device first."""
+    shardings = param_shardings(mesh, jax.eval_shape(init_fn))
+    return jax.jit(init_fn, out_shardings=shardings)()

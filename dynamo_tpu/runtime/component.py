@@ -113,6 +113,7 @@ class DistributedRuntime:
         self._keepalive_task: asyncio.Task | None = None
         self._secondary_tasks: list[asyncio.Task] = []
         self._served: list[tuple[str, str]] = []  # (subject, key)
+        self._leased: dict[int, dict[str, bytes]] = {}  # lease id -> records to restore on lease loss
         self._closed = False
 
     @classmethod
@@ -134,15 +135,30 @@ class DistributedRuntime:
         self._secondary_tasks.append(asyncio.create_task(self._keepalive_loop(lease)))
         return lease
 
+    async def put_leased(self, key: str, value: bytes, lease: Lease) -> None:
+        """Put a record under a kept-alive lease and remember it, so that the
+        keep-alive loop can restore it if the lease is ever lost."""
+        await self.store.put(key, value, lease_id=lease.id)
+        self._leased.setdefault(lease.id, {})[key] = value
+
     async def _keepalive_loop(self, lease: Lease) -> None:
+        """Renew ``lease`` every TTL/3. A lease that expired anyway (this
+        process could not run for a TTL, or the store was out of reach) is a
+        false death: re-arm it under the same id — instance ids are lease ids
+        — and put its records back, so watchers see the worker return."""
         interval = max(lease.ttl / 3.0, 0.05)
         while True:
             await asyncio.sleep(interval)
             try:
-                await lease.keep_alive()
-            except KeyError:
-                logger.error("primary lease %d expired; runtime is no longer discoverable", lease.id)
-                return
+                try:
+                    await lease.keep_alive()
+                except KeyError:
+                    records = self._leased.get(lease.id, {})
+                    logger.error("lease %x expired while its holder is alive; re-registering %d record(s)",
+                                 lease.id, len(records))
+                    await self.store.adopt_lease(lease.id, lease.ttl)
+                    for key, value in records.items():
+                        await self.store.put(key, value, lease_id=lease.id)
             except Exception:
                 logger.exception("lease keep-alive failed; retrying")
 
@@ -231,7 +247,7 @@ class Endpoint:
             address=rt.transport.address_of(subject),
             metadata=metadata or {},
         )
-        await rt.store.put(instance.key, instance.to_bytes(), lease_id=lease.id)
+        await rt.put_leased(instance.key, instance.to_bytes(), lease)
         rt._served.append((subject, instance.key))
         logger.info("serving %s as instance %x at %s", self.path, lease.id, instance.address)
         return instance

@@ -79,6 +79,13 @@ class KeyValueStore(abc.ABC):
     async def create_lease(self, ttl: float = DEFAULT_LEASE_TTL) -> Lease: ...
 
     @abc.abstractmethod
+    async def adopt_lease(self, lease_id: int, ttl: float) -> None:
+        """Create — or re-arm — a lease under a *caller-chosen* id: a replica
+        mirroring its leader's ids, or a live holder taking back a lease that
+        expired under it (``DistributedRuntime._keepalive_loop``)."""
+        ...
+
+    @abc.abstractmethod
     async def keep_alive(self, lease_id: int) -> None: ...
 
     @abc.abstractmethod
@@ -221,9 +228,7 @@ class MemoryStore(KeyValueStore):
             return Lease(id=lid, ttl=ttl, store=self)
 
     async def adopt_lease(self, lease_id: int, ttl: float) -> None:
-        """Create — or re-arm — a lease under a *caller-chosen* id.
-
-        The replication apply path: a follower mirrors the leader's lease ids
+        """The replication apply path: a follower mirrors the leader's lease ids
         so that lease-bound keys land under the same identity, and re-arms the
         deadline against its own monotonic clock on every replicated
         keepalive (absolute deadlines cannot be shipped across processes).

@@ -45,13 +45,15 @@ logger = logging.getLogger(__name__)
 
 #: Ops that mutate store state — leader-only under replication.
 MUTATING_OPS = frozenset(
-    {"put", "put_if_absent", "delete", "create_lease", "keep_alive", "revoke_lease"}
+    {"put", "put_if_absent", "delete", "create_lease", "adopt_lease", "keep_alive", "revoke_lease"}
 )
 
 #: Ops the client may transparently retry once after a reconnect: replaying
 #: them cannot change the outcome (``put`` re-sends the same payload;
 #: ``create_lease``/``put_if_absent``/``revoke_lease`` could double-apply).
-IDEMPOTENT_OPS = frozenset({"get", "get_prefix", "keep_alive", "delete", "put", "who_leads"})
+IDEMPOTENT_OPS = frozenset(
+    {"get", "get_prefix", "keep_alive", "adopt_lease", "delete", "put", "who_leads"}
+)
 
 
 class NotLeaderError(RuntimeError):
@@ -240,6 +242,11 @@ class StoreServer:
             if repl is not None:
                 repl.record("lease", lease_id=lease.id, ttl=lease.ttl)
             return {"id": lease.id, "ttl": lease.ttl}
+        if op == "adopt_lease":
+            await s.adopt_lease(f["lease_id"], f["ttl"])
+            if repl is not None:
+                repl.record("lease", lease_id=f["lease_id"], ttl=f["ttl"])
+            return True
         if op == "keep_alive":
             await s.keep_alive(f["lease_id"])
             if repl is not None:
@@ -258,10 +265,16 @@ class StoreServer:
             await self.repl.close()
         if self._server is not None:
             self._server.close()
+        # Connections first: since Python 3.12.1 ``wait_closed`` waits for
+        # every open connection, and watch / replication streams never end
+        # on their own.
+        tasks = list(self._conn_tasks)
+        for t in tasks:
+            t.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        if self._server is not None:
             await self._server.wait_closed()
             self._server = None
-        for t in list(self._conn_tasks):
-            t.cancel()
         # The served store may hold resources (e.g. a persistence WAL).
         await self.store.close()
 
@@ -485,6 +498,9 @@ class StoreClient(KeyValueStore):
     async def create_lease(self, ttl: float = DEFAULT_LEASE_TTL) -> Lease:
         d = await self._call("create_lease", ttl=ttl)
         return Lease(id=d["id"], ttl=d["ttl"], store=self)
+
+    async def adopt_lease(self, lease_id: int, ttl: float) -> None:
+        await self._call("adopt_lease", lease_id=lease_id, ttl=ttl)
 
     async def keep_alive(self, lease_id: int) -> None:
         await self._call("keep_alive", lease_id=lease_id)

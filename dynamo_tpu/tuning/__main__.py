@@ -47,6 +47,10 @@ def main(argv: list[str] | None = None) -> int:
         plateau_rounds=ts.plateau_rounds, max_trials=args.max_trials,
         out_dir=args.out_dir, knobs=args.knobs,
     )
+    if args.mode == "jax":
+        from dynamo_tpu.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     tuner = Tuner(settings, metrics=TunerMetrics())
     report = tuner.run()
     print(json.dumps({

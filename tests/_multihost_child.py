@@ -18,8 +18,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import asyncio  # noqa: E402
 
 import numpy as np  # noqa: E402

@@ -3,6 +3,8 @@ plus the suite's byte-accounting model (bench.py)."""
 
 import asyncio
 
+import pytest
+
 from dynamo_tpu.bench import SyntheticConfig, synthesize, sweep_http
 from dynamo_tpu.bench.synthesizer import sharing_ratio
 
@@ -130,8 +132,10 @@ def test_decode_kernel_probe_structure(monkeypatch):
     monkeypatch.setenv("BENCH_DK_KV", "2")
     monkeypatch.setenv("BENCH_DK_HEAD_DIM", "16")
     monkeypatch.setenv("BENCH_DK_ITERS", "1")
-    out = bench.probe_decode_kernel()
-    assert out["interpret"] is True  # CPU-pinned suite
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench.probe_decode_kernel()  # a bandwidth probe refuses to run off-TPU
+    out = bench.probe_decode_kernel(interpret=True)
+    assert out["interpret"] is True
     assert "error" not in out
     assert len(out["grid"]) == 4  # 2 batches x 2 contexts
     for cell in out["grid"]:

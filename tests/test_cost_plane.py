@@ -9,6 +9,7 @@ join on the mock runner, and the DYN_COST_PLANE=0 acceptance: bit-identical
 tokens with zero extraction work (spied via the module global EXTRACTIONS).
 """
 
+import functools
 import os
 
 import aiohttp
@@ -58,13 +59,32 @@ def test_chip_peaks_env_override(monkeypatch):
     assert (hbm, tflops, source) == (819.0, 197.0, "env")
 
 
-def test_chip_peaks_cpu_fallback(monkeypatch):
+def test_chip_peaks_cpu_proxy(monkeypatch):
     monkeypatch.delenv("DYN_PEAK_HBM_GBPS", raising=False)
     monkeypatch.delenv("DYN_PEAK_TFLOPS", raising=False)
     hbm, tflops, source = chip_peaks()
-    # The test mesh is virtual CPU devices: documented DDR-class proxies.
-    assert (hbm, tflops) == cost_mod.CPU_FALLBACK_PEAKS
-    assert source.startswith("fallback:")
+    # The test mesh is virtual CPU devices: DDR-class proxies, labelled so.
+    assert (hbm, tflops) == cost_mod.CPU_PROXY_PEAKS
+    assert source.startswith("cpu-proxy:")
+
+
+@pytest.mark.parametrize("kind,expect", [("TPU v5 lite", (819.0, 197.0)), ("TPU v9x", None)])
+def test_chip_peaks_accelerator_table_or_error(monkeypatch, kind, expect):
+    """A known accelerator reads the table; an unknown one raises instead of
+    inheriting CPU-class peaks."""
+    import types
+
+    import jax
+
+    monkeypatch.delenv("DYN_PEAK_HBM_GBPS", raising=False)
+    monkeypatch.delenv("DYN_PEAK_TFLOPS", raising=False)
+    fake = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [fake])
+    if expect is None:
+        with pytest.raises(RuntimeError, match="v9x"):
+            chip_peaks()
+    else:
+        assert chip_peaks() == (*expect, f"table:{kind}")
 
 
 # -- extraction vs estimate ---------------------------------------------------
@@ -537,3 +557,108 @@ def test_cost_plane_off_bit_identical_zero_extractions(monkeypatch):
     assert runner_off.cost_registry is None
     assert cost_mod.EXTRACTIONS == before, "extraction ran with the plane off"
     assert tokens_on == tokens_off and len(tokens_on) == 6
+
+
+# -- the plane stays out of the serving path's way ----------------------------
+
+
+def test_lowering_is_submitted_after_the_call_returns():
+    """First sight of a bucket: the serving call compiles the program (and
+    writes the compile cache) BEFORE the registry's background thread is
+    handed the same lowering — two compiles of one program side by side were
+    what the one-chip host stalled under."""
+    runner, _ = _tiny_core_tokens()
+    order = []
+    real_submit = runner.cost_registry.submit
+
+    def fn(*args, **kwargs):
+        order.append("call")
+        return "out"
+
+    fn.lower = lambda *a, **k: None  # make_lower_thunk only closes over it
+
+    def submit(*args, **kwargs):
+        order.append("submit")
+        return real_submit(*args, **kwargs)
+
+    runner.cost_registry.submit = submit
+    padded = None  # _cost_estimate tolerates anything (best-effort)
+    assert runner._cost_call("probe", (1, 2, 3), "decode", padded, fn) == "out"
+    assert order == ["call", "submit"]
+    assert runner.cost_registry.seen("probe", (1, 2, 3))
+    assert runner._cost_call("probe", (1, 2, 3), "decode", padded, fn) == "out"
+    assert order == ["call", "submit", "call"]  # warm: one set lookup, no resubmit
+    runner.cost_registry.close()
+
+
+def test_close_drops_the_queue_and_joins_the_thread():
+    """A daemon thread left inside the compiler at interpreter exit crashed
+    the process on the chip: close() must leave no extraction thread alive,
+    and must not wait for work that has not started."""
+    import threading
+
+    reg = CostRegistry(peaks=(50.0, 0.5))
+    started, release = threading.Event(), threading.Event()
+    ran = []
+
+    def slow_lower():
+        started.set()
+        release.wait(30.0)
+        raise RuntimeError("stop here")
+
+    def never_lower():
+        ran.append(1)
+        raise RuntimeError("must have been dropped")
+
+    reg.submit("slow", (1,), "decode", lower=slow_lower)
+    assert started.wait(30.0)
+    reg.submit("queued", (2,), "decode", lower=never_lower)
+    threading.Timer(0.2, release.set).start()
+    reg.close()
+    assert reg._thread is not None and not reg._thread.is_alive()
+    assert not ran
+    reg.close()  # idempotent
+
+
+@pytest.mark.parametrize("committed", [False, True], ids=["uncommitted", "committed-to-device"])
+def test_extraction_finds_the_calls_executable(committed):
+    """The avatars must lower to the very module the serving call compiled:
+    the registry's ``lower().compile()`` then finds that executable (in
+    memory, or in the compile cache) and the backend compiles nothing twice.
+    On the chip every bucket used to be compiled a second time."""
+    import jax
+    import jax.numpy as jnp
+
+    misses, compiles = [], []
+
+    def on_event(event, **_kw):
+        if event == "/jax/compilation_cache/cache_misses":
+            misses.append(event)
+
+    def on_duration(event, seconds, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(seconds)
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+    @functools.partial(jax.jit, static_argnames=("bias",), donate_argnums=(1,))
+    def fn(w, cache, x, *, bias):
+        return (x @ w).sum() + bias, cache + 1.0
+
+    w = np.full((64, 64), 0.5, np.float32)
+    bias = 7 if committed else 9  # static: a program of its own per case
+    if committed:
+        dev = jax.devices()[-1]
+        args = (jax.device_put(w, dev), jax.device_put(np.zeros((8, 8), np.float32), dev),
+                jax.device_put(np.ones((4, 64), np.float32), dev))
+    else:
+        args = (jnp.asarray(w), jnp.zeros((8, 8)), jnp.ones((4, 64)))
+    thunk = make_lower_thunk(fn, args, {"bias": bias})
+    fn(*args, bias=bias)  # the serving call: compiles
+    seen_misses, seen_compiles = len(misses), len(compiles)
+    compiled = thunk().compile()  # the registry's extraction, after the call
+    assert len(misses) == seen_misses, "the re-lowering missed the compile cache: a second compile"
+    if not committed:
+        assert len(compiles) == seen_compiles  # found in memory, no backend work at all
+    assert compiled.cost_analysis() is not None

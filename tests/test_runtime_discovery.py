@@ -95,6 +95,48 @@ async def test_keep_alive_extends_lease():
     await store.close()
 
 
+@pytest.mark.parametrize("remote", [False, True], ids=["memory-store", "tcp-store"])
+async def test_runtime_reregisters_after_lease_loss(remote):
+    """A holder that could not renew for a whole TTL (seen on the chip's
+    shared host: the process did not run for 8-10 s) is not dead. Its
+    keep-alive loop takes the lease back under the same id and restores its
+    records, so watchers see the instance leave and return."""
+    import time
+
+    from dynamo_tpu.runtime.component import DistributedRuntime
+    from dynamo_tpu.runtime.store_server import StoreClient, StoreServer
+
+    server = None
+    if remote:
+        server = await StoreServer(MemoryStore(reap_interval=0.05), host="127.0.0.1", port=0).start()
+        store = StoreClient("127.0.0.1", server.port)
+    else:
+        store = MemoryStore(reap_interval=0.05)
+    rt = DistributedRuntime(store, lease_ttl=0.3)
+    lease = await rt.primary_lease()
+    await rt.put_leased("instances/ns/w/gen:1", b"record", lease)
+    events = []
+
+    async def watch():
+        async for ev in store.watch_prefix("instances/", initial=False):
+            events.append((ev.type, ev.key))
+
+    watcher = asyncio.create_task(watch())
+    await asyncio.sleep(0.05)
+    time.sleep(0.5)  # blocks the loop past the TTL: no keep-alive can run
+    await asyncio.sleep(0.4)  # the reaper expires the lease; the keep-alive loop repairs it
+    assert await store.get("instances/ns/w/gen:1") == b"record"
+    await lease.keep_alive()  # same id, alive again
+    assert events == [
+        (WatchEventType.DELETE, "instances/ns/w/gen:1"),
+        (WatchEventType.PUT, "instances/ns/w/gen:1"),
+    ]
+    watcher.cancel()
+    await rt.close()
+    if server is not None:
+        await server.close()
+
+
 async def test_put_with_unknown_lease_rejected():
     store = MemoryStore()
     with pytest.raises(KeyError):

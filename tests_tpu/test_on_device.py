@@ -64,8 +64,7 @@ def test_decode_kernel_on_device(head_dim):
 
 
 def test_prefill_faster_than_reference_long_context():
-    """The kernel must beat the gather formulation at ISL >= 1024 (the
-    VERDICT r2 'done' bar for the prefill path)."""
+    """The kernel must beat the gather formulation at ISL >= 1024."""
     import time
 
     rng = np.random.default_rng(2)
@@ -86,6 +85,8 @@ def test_prefill_faster_than_reference_long_context():
         return (time.perf_counter() - t0) / 5
 
     t_ref, t_ker = bench(ref), bench(ker)
+    print(f"prefill kernel {t_ker*1e3:.2f} ms, reference {t_ref*1e3:.2f} ms, "
+          f"reference/kernel = {t_ref / t_ker:.2f}x")
     assert t_ker < t_ref, f"kernel {t_ker*1e3:.1f} ms !< reference {t_ref*1e3:.1f} ms"
 
 
