@@ -131,6 +131,7 @@ class Backend(Operator):
                     cached_tokens=out.cached_tokens,
                     logprobs=lp,
                     admission_wait_ms=out.admission_wait_ms,
+                    first_token_ts=out.first_token_ts,
                 )
                 return  # Operator.generate closes the stream -> engine cancels
             final = out.finish_reason is not None
@@ -146,6 +147,7 @@ class Backend(Operator):
                     cached_tokens=out.cached_tokens,
                     logprobs=lp,
                     admission_wait_ms=out.admission_wait_ms,
+                    first_token_ts=out.first_token_ts,
                 )
             if final:
                 return

@@ -54,7 +54,7 @@ from dynamo_tpu.protocols.kv import ForwardPassMetrics, KvCacheEvent
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.runtime.faults import FAULTS, DropFault
 from dynamo_tpu.tokens import DEFAULT_SALT
-from dynamo_tpu.tracing import annotate
+from dynamo_tpu import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -396,7 +396,11 @@ class EngineCore:
         self._overlap_barrier_reason: str | None = None
         # Rows that were in flight when a dispatch crashed (CRASH record).
         self._aborted_inflight = 0
-        self._prev_step_end: float | None = None
+        # Where a step's and a gap's time goes, phase by phase: the runner
+        # marks dispatch -> wait, the service the five parts of the gap.
+        self.clock = tracing.StepClock()
+        if hasattr(runner, "clock"):
+            runner.clock = self.clock
         self.step_gap_ms_sum = 0.0
         self.step_gap_ms_count = 0
         self.step_gap_ms_last = 0.0
@@ -591,13 +595,14 @@ class EngineCore:
             prev_info = self.last_step_info
             tracker = getattr(self.runner, "compile_tracker", None)
             disp0 = tracker.dispatch_seconds_total if tracker is not None else 0.0
-            t0 = time.perf_counter()
             # Host gap since the previous step returned: the window where the
             # device has nothing newly dispatched (detok/stop/route/schedule
-            # time). The overlapped loop exists to hide exactly this.
-            gap_ms = (
-                (t0 - self._prev_step_end) * 1e3 if self._prev_step_end is not None else 0.0
-            )
+            # time). The overlapped loop exists to hide exactly this. The
+            # clock's five gap phases tile it (docs/OBSERVABILITY.md); 0 for
+            # the first step and for the one after a step that raised.
+            clock = self.clock
+            t0_ns, gap_ns = clock.begin()
+            gap_ms = gap_ns / 1e6
             self._overlap_mode = None
             self._overlap_barrier_reason = None
             self._aborted_inflight = 0
@@ -633,7 +638,9 @@ class EngineCore:
                     },
                 )
                 raise
-            wall_ms = (time.perf_counter() - t0) * 1e3
+            # ``record`` runs from here to the end of step(): it ends after
+            # this step's flight record is written, so the next record holds it.
+            wall_ms = (clock.mark(tracing.RECORD) - t0_ns) / 1e6
             info = self.last_step_info
             fresh = info is not prev_info  # _run_mixed built a new dict
             onboard_stalled = False
@@ -647,7 +654,7 @@ class EngineCore:
                     onboard_stalled = True
                 self._onboard_pending_step = False
             if not fresh and not out and not self.running:
-                self._prev_step_end = time.perf_counter()
+                clock.restart_gap()
                 return out  # idle drain: nothing dispatched, nothing to record
             overlap_mode = ""
             barrier_reason = ""
@@ -744,6 +751,10 @@ class EngineCore:
                 overlap_mode=overlap_mode,
                 barrier_reason=barrier_reason,
                 chained_rows=int(info.get("chained_rows", 0)) if fresh else 0,
+                t0_ns=t0_ns,
+                ann_ns=clock.ann_ns,
+                traced=clock.traced,
+                phases_us=clock.phases_us(),
                 **cost_fields,
             )
             # Time-loss accounting: every millisecond of this step's wall
@@ -777,7 +788,7 @@ class EngineCore:
                 recompiles=self.recompile_count,
                 shortfall_pages=self.onboard_shortfall_pages,
             )
-            self._prev_step_end = time.perf_counter()
+            clock.end()
             return out
 
     def _charge_loss(self, cause: str, ms: float) -> None:
@@ -852,7 +863,7 @@ class EngineCore:
         chunks = self._schedule_prefill()
         overlap_ok, reason = self._overlap_route(chunks)
         if overlap_ok:
-            with annotate("engine.overlap"):
+            with self.clock.annotate("engine.overlap"):
                 out = cancelled + self._run_mixed_overlapped(chunks)
             if not self.defer_offloads:
                 self.flush_offloads()
@@ -878,10 +889,10 @@ class EngineCore:
             # speculation on, pure-decode steps route here too: the verify
             # dispatch supersedes the burst/pipelined decode paths (drafts
             # already amortize the per-step host round trip).
-            with annotate("engine.mixed" if fused else "engine.prefill"):
+            with self.clock.annotate("engine.mixed" if fused else "engine.prefill"):
                 out = cancelled + self._run_mixed(chunks)
         elif self.running:
-            with annotate("engine.decode"):
+            with self.clock.annotate("engine.decode"):
                 out = cancelled + self._run_decode()
         else:
             out = cancelled + self._drain_inflight()
@@ -1251,6 +1262,7 @@ class EngineCore:
                     break
             self.waiting.popleft()
             seq.admitted_time = time.monotonic()
+            seq.admitted_step = self.step_gap_ms_count
             n_admitted += 1
             admit_cached += cached_len
             admit_total += total
@@ -1582,6 +1594,7 @@ class EngineCore:
         whole-prompt prefill would have used (golden parity, greedy and
         seeded). With chunking disabled this runs the scheduled whole
         prompts without decode rows — the legacy phase-exclusive step."""
+        self.clock.mark(tracing.BUILD)
         fused = self.config.chunk_prefill_tokens > 0
         spec = self._spec_active()
         out: list[tuple[Sequence, EngineOutput]] = []
@@ -1653,6 +1666,7 @@ class EngineCore:
         ) else 0
         sb.logit_mask = self._constraint_masks(batch)
         targets = None
+        self.clock.mark(tracing.DISPATCH)  # the runner marks dispatch -> wait
         try:
             if use_spec:
                 # Verify dispatch: decode rows score every candidate column,
@@ -1677,6 +1691,7 @@ class EngineCore:
             for s in batch:
                 self._finish(s, FinishReason.ERROR)
             raise
+        self.clock.mark(tracing.POST)
         rec = _InflightStep(
             batch, None, kind="spec" if use_spec else "step",
             ns=ns, n_dec=n_dec, samples=samples, drafts=drafts,
@@ -1953,6 +1968,7 @@ class EngineCore:
             # and the dispatch below chains out of them via _chain_map.
             self._note_barrier("spec")
             out += self._harvest_inflight()
+            self.clock.mark(tracing.SCHED)
             inf = None
         # Decode candidates at effective state: running rows still short of
         # their finish line, plus rows whose *final* prompt chunk is in
@@ -2000,6 +2016,7 @@ class EngineCore:
                 for s in decode_rows
             )
         )
+        self.clock.mark(tracing.BUILD)
         failed = self._ensure_lookahead_pages(
             decode_rows, k_cfg if want_burst else 1
         )
@@ -2113,6 +2130,7 @@ class EngineCore:
         lp_k = LOGPROBS_TOP_K if any(
             s.request.sampling.logprobs and smp for s, smp in zip(batch, samples)
         ) else 0
+        self.clock.mark(tracing.DISPATCH)
         try:
             if use_spec:
                 sb.spec_start = np.asarray(
@@ -2160,6 +2178,7 @@ class EngineCore:
         except Exception:
             self._abort_pipeline(batch)
             raise
+        self.clock.mark(tracing.POST)
         if inf is not None:
             self._overlap_mode = "overlapped"
         else:
@@ -2303,6 +2322,7 @@ class EngineCore:
         return outputs
 
     def _run_decode_sync(self, k: int) -> list[tuple[Sequence, EngineOutput]]:
+        self.clock.mark(tracing.BUILD)
         failed = self._ensure_burst_pages(k)
         if failed is not None:
             return [(failed, self._final_output(failed))]
@@ -2315,6 +2335,7 @@ class EngineCore:
         if k == 1:
             step_batch.logit_mask = self._constraint_masks(batch)
         lp_aux = None
+        self.clock.mark(tracing.DISPATCH)  # the runner marks dispatch -> wait
         try:
             if k == 1:
                 if lp_k:
@@ -2328,6 +2349,7 @@ class EngineCore:
             for s in batch:
                 self._finish(s, FinishReason.ERROR)
             raise
+        self.clock.mark(tracing.POST)
         return self._process_burst_tokens(batch, next_tokens, lp_aux)
 
     def _harvest_inflight(self) -> list[tuple[Sequence, EngineOutput]]:
@@ -2340,7 +2362,10 @@ class EngineCore:
             return []
         self._inflight = None
         self._inflight_adv = {}
+        clock = self.clock
+        clock.mark(tracing.WAIT)
         res, lp_aux = inf.handle.result()
+        clock.mark(tracing.POST)
         if inf.kind == "spec":
             return self._apply_mixed_results(inf, res[:, 0], res, lp_aux, chain_out=True)
         out = self._apply_mixed_results(inf, res[:, 0], None, lp_aux)
@@ -2352,7 +2377,9 @@ class EngineCore:
             # that are only reallocated to dispatches composed *after* these
             # sub-steps, so device program order makes the stale writes
             # harmless (same argument as preemption under overlap).
+            clock.mark(tracing.WAIT)
             res_j, lp_j = h.result()
+            clock.mark(tracing.POST)
             b = len(inf.batch)
             rec = _InflightStep(
                 inf.batch, h, kind="step", ns=[1] * b, n_dec=b,
@@ -2509,10 +2536,17 @@ class EngineCore:
         # First delta for this sequence: attach the admission wait (frontend
         # RequestTracker observes it once) and close the predictor's loop
         # with the actual TTFT.
-        wait_ms = None
+        wait_ms = prefill = None
         if seq.admitted_time is not None and not seq.admission_reported:
             seq.admission_reported = True
             wait_ms = max(0.0, (seq.admitted_time - seq.arrival_time) * 1e3)
+            prefill = {
+                "admitted_mono": time.perf_counter() - (time.monotonic() - seq.admitted_time),
+                "chunks": seq.prefill_chunks,
+                "steps": self.step_gap_ms_count + 1 - seq.admitted_step,  # this one is not counted yet
+                "prompt_tokens": seq.num_prompt,
+                "cached_tokens": seq.num_cached_at_start,
+            }
             # Pre-admission wait is lost time: a quota-gated deferral is the
             # admission plane's doing, anything else is plain resource wait.
             self._charge_loss("admission" if seq.quota_deferred else "queue", wait_ms)
@@ -2526,6 +2560,7 @@ class EngineCore:
             cached_tokens=seq.num_cached_at_start if seq.finish_reason else None,
             logprobs=logprobs[: len(tokens)] if logprobs else None,
             admission_wait_ms=round(wait_ms, 3) if wait_ms is not None else None,
+            prefill=prefill,
         )
         return seq, out
 

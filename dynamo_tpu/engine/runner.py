@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu import tracing
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.observability.compile import CompileTracker, timed_dispatch
@@ -247,6 +248,9 @@ class ModelRunner:
         # this is how a production recompile becomes visible (metrics plane
         # syncs counts(); the engine's flight recorder is its event sink).
         self.compile_tracker = CompileTracker()
+        #: The owning engine's phase clock (``EngineCore`` sets it): the
+        #: blocking programs mark dispatch -> wait where the enqueue returns.
+        self.clock: tracing.StepClock | None = None
         # Device-cost plane (DYN_COST_PLANE, default on): per-bucket
         # flops/bytes records joined with measured dispatch wall into the
         # live roofline ledger. None when the plane is off — the dispatch
@@ -320,10 +324,11 @@ class ModelRunner:
                 from dynamo_tpu.ops.attention import NEG_INF
 
                 sample_logits = jnp.where(logit_mask, logits, NEG_INF)
-            next_tokens = sample_tokens(
-                sample_logits, keys, temperature, top_k, top_p,
-                history=history, frequency_penalty=freq_pen, presence_penalty=pres_pen,
-            )
+            with jax.named_scope("sample"):
+                next_tokens = sample_tokens(
+                    sample_logits, keys, temperature, top_k, top_p,
+                    history=history, frequency_penalty=freq_pen, presence_penalty=pres_pen,
+                )
             if lp_k:
                 from dynamo_tpu.ops.sampling import token_logprobs
 
@@ -436,13 +441,14 @@ class ModelRunner:
                 from dynamo_tpu.ops.attention import NEG_INF
 
                 sample_logits = jnp.where(jnp.repeat(logit_mask, v, axis=0), flat, NEG_INF)
-            targets = sample_tokens(
-                sample_logits, keys,
-                jnp.repeat(temperature, v), jnp.repeat(top_k, v), jnp.repeat(top_p, v),
-                history=jnp.repeat(history, v, axis=0),
-                frequency_penalty=jnp.repeat(freq_pen, v),
-                presence_penalty=jnp.repeat(pres_pen, v),
-            )
+            with jax.named_scope("sample"):
+                targets = sample_tokens(
+                    sample_logits, keys,
+                    jnp.repeat(temperature, v), jnp.repeat(top_k, v), jnp.repeat(top_p, v),
+                    history=jnp.repeat(history, v, axis=0),
+                    frequency_penalty=jnp.repeat(freq_pen, v),
+                    presence_penalty=jnp.repeat(pres_pen, v),
+                )
             if lp_k:
                 from dynamo_tpu.ops.sampling import token_logprobs
 
@@ -511,10 +517,11 @@ class ModelRunner:
                     **mm_kw,
                 )
                 keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(seeds, cnt)
-                nxt = sample_tokens(
-                    logits, keys, temperature, top_k, top_p,
-                    history=hist, frequency_penalty=freq_pen, presence_penalty=pres_pen,
-                )
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(
+                        logits, keys, temperature, top_k, top_p,
+                        history=hist, frequency_penalty=freq_pen, presence_penalty=pres_pen,
+                    )
                 # The burst's own samples count toward later steps' penalties.
                 write = jnp.minimum(cnt, h_width - 1)
                 hist = jax.vmap(lambda hrow, w, t: hrow.at[w].set(t))(hist, write, nxt)
@@ -857,6 +864,12 @@ class ModelRunner:
             logger.debug("cost submit failed for %s", program, exc_info=True)
         return out
 
+    def _mark_wait(self) -> None:
+        """The jitted call has returned (enqueued); what follows blocks on the
+        result. Outside an engine step (warm-up, tests) there is no clock."""
+        if self.clock is not None:
+            self.clock.mark_in_step(tracing.WAIT)
+
     @_locked
     def step(self, batch: StepBatch, lp_k: int = 0):
         """Run one forward+sample step; returns sampled token ids i32[B_real].
@@ -944,6 +957,7 @@ class ModelRunner:
                     b=b, t=t, n=padded.block_tables.shape[1], h=padded.history.shape[1],
                     lp_k=lp_k,
                 )
+            self._mark_wait()
             if lp_k:
                 next_tokens, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
                 return np.asarray(next_tokens)[:b_real], {
@@ -1014,6 +1028,7 @@ class ModelRunner:
                 opt(padded.mrope_positions), opt(padded.logit_mask),
                 impl=impl, lp_k=lp_k,
             )
+        self._mark_wait()
         if lp_k:
             targets, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
             return np.asarray(targets)[:b_real], {
@@ -1070,6 +1085,7 @@ class ModelRunner:
                     b=b, t=t, n=padded.block_tables.shape[1], h=padded.history.shape[1],
                     num_steps=num_steps,
                 )
+            self._mark_wait()
             return np.asarray(toks).T[:b_real]  # [B, num_steps]
 
     def _chain_src_padded(self, chain_src, b_real: int, bp: int) -> np.ndarray:

@@ -64,6 +64,7 @@ class Sequence:
     # remaining TTFT the predictor estimated at the last EDF ordering (with
     # the timestamp of that estimate — the observation's time origin).
     admitted_time: float | None = None
+    admitted_step: int = 0  # the engine's recorded steps at that moment
     admission_reported: bool = False
     predicted_ttft_s: float | None = None
     predicted_at: float | None = None

@@ -24,6 +24,9 @@ from dynamo_tpu.protocols.common import EngineOutput, PreprocessedRequest
 from dynamo_tpu.protocols.kv import ForwardPassMetrics
 from dynamo_tpu.runtime.engine import AsyncEngine, Context
 from dynamo_tpu.runtime.faults import FAULTS
+from dynamo_tpu.tracing import (
+    INTAKE, NO_WORK, ROUTE, SUBMIT, Span, StepClock, record_span, trace_of,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -117,8 +120,13 @@ class JaxEngineService(AsyncEngine[Any, dict]):
 
     async def _engine_loop(self) -> None:
         loop = asyncio.get_running_loop()
+        # The gap between two steps, in five parts: handoff (the step returned
+        # in its thread -> this loop resumed; the core opens it), route,
+        # intake, no_work, submit (-> the next step begins; the core ends it).
+        clock = getattr(self.core, "clock", None) or StepClock()
         while not self._closed:
             # Drain intake without blocking.
+            clock.mark(INTAKE)
             admitted = False
             while True:
                 try:
@@ -127,8 +135,6 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                     break
                 # Intake-to-admission gap: how long the request sat waiting
                 # for the engine loop (scheduler queue wait on the timeline).
-                from dynamo_tpu.tracing import record_span, trace_of
-
                 record_span(
                     "engine_queue_wait",
                     (time.perf_counter() - t_enq) * 1e3,
@@ -156,6 +162,7 @@ class JaxEngineService(AsyncEngine[Any, dict]):
 
             if not self.core.has_work:
                 if not admitted:
+                    clock.mark(NO_WORK)
                     self._wake.clear()
                     await self._wake.wait()
                 continue
@@ -166,7 +173,9 @@ class JaxEngineService(AsyncEngine[Any, dict]):
             try:
                 if FAULTS.armed:
                     FAULTS.fire("engine.step")
+                clock.mark(SUBMIT)
                 outputs = await loop.run_in_executor(None, self.core.step)
+                clock.mark(ROUTE)
             except Exception as exc:
                 logger.exception("engine step failed; failing all in-flight streams")
                 flight = getattr(self.core, "flight", None)
@@ -260,6 +269,18 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                     embedding=[float(x) for x in vecs[i]],
                 ).to_dict()
             return
+        trace = trace_of(context)
+        if trace is not None and trace.root_ts:
+            # The request's time before the engine (parse, preprocess, route,
+            # the hop here), under the frontend's root span. Wall clock: the
+            # frontend may be another process.
+            record_span(
+                "frontend_pre_engine",
+                max(0.0, (time.time() - trace.root_ts) * 1e3),
+                trace=trace.under_root(),
+                start_ts=trace.root_ts,
+                request_id=request.request_id,
+            )
         await self.start()
         out_q: asyncio.Queue = asyncio.Queue()
         await self._intake.put((request, context, out_q, time.perf_counter()))
@@ -273,11 +294,9 @@ class JaxEngineService(AsyncEngine[Any, dict]):
             out_q.put_nowait(EngineOutput(token_ids=[], finish_reason=FinishReason.ERROR))
             out_q.put_nowait(_SENTINEL)
         finished = False
-        from dynamo_tpu.tracing import Span, record_span, trace_of
-
         span = Span(
             "engine_request",
-            trace=trace_of(context),
+            trace=trace,
             request_id=request.request_id,
             prompt_tokens=len(request.token_ids),
         )
@@ -293,13 +312,30 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                 if item.admission_wait_ms is not None:
                     # Arrival -> scheduler admission, measured by the core
                     # and attached to the first delta. As a span it joins
-                    # the /debug/explain budget's pre-decode segments.
+                    # the /debug/explain budget's pre-decode segments. The
+                    # same delta says when admission was: the span starts
+                    # there, and engine_prefill runs from there to now.
+                    pf = item.prefill or {}
+                    admitted = pf.get("admitted_mono")
                     record_span(
                         "engine_admission_wait",
                         item.admission_wait_ms,
                         trace=span.context,
+                        start_mono=None if admitted is None else admitted - item.admission_wait_ms / 1e3,
                         request_id=request.request_id,
                     )
+                    if admitted is not None and item.token_ids:
+                        record_span(
+                            "engine_prefill",
+                            (time.perf_counter() - admitted) * 1e3,
+                            trace=span.context,
+                            start_mono=admitted,
+                            request_id=request.request_id,
+                            prompt_tokens=pf["prompt_tokens"],
+                            cached_tokens=pf["cached_tokens"],
+                            chunks=pf["chunks"],
+                            steps=pf["steps"],
+                        )
                 if tokens_out == 0 and item.token_ids:
                     # TTFT as seen at the engine boundary: submit -> first
                     # token out of the step loop. Child of engine_request.
@@ -309,6 +345,7 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                         trace=span.context,
                         request_id=request.request_id,
                     )
+                    item.first_token_ts = time.time()
                 tokens_out += len(item.token_ids)
                 saw_finish = saw_finish or item.finish_reason is not None
                 yield item.to_dict()

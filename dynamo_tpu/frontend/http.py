@@ -28,6 +28,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import sys
+import time
 from typing import Any, AsyncIterator, Awaitable, Callable
 
 from aiohttp import web
@@ -44,6 +45,7 @@ from dynamo_tpu.frontend.openai_format import (
 )
 from dynamo_tpu.protocols.common import BackendOutput, FinishReason
 from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.tracing import Span, TraceContext, record_span
 
 logger = logging.getLogger(__name__)
 
@@ -102,7 +104,6 @@ class HttpService:
                 web.get("/debug/federation", self.debug_federation),
                 web.get("/debug/store", self.debug_store),
                 web.post("/clear_kv_blocks", self.clear_kv_blocks),
-                web.post("/engine/profile", self.engine_profile),
             ]
         )
 
@@ -234,12 +235,10 @@ class HttpService:
         # Trace ingress: continue the caller's W3C trace or mint a fresh one.
         # The root span's context rides ctx.trace through every pipeline
         # stage and process hop (GET /debug/traces/{ctx.id} reassembles it).
-        from dynamo_tpu.tracing import Span, TraceContext
-
         incoming = TraceContext.from_traceparent(request.headers.get("traceparent"))
         root = Span("http_request", trace=incoming, request_id=ctx.id, model=model, endpoint=kind)
+        root.__enter__()  # before its context is read: the root's start rides along
         ctx.trace = root.context.to_dict()
-        root.__enter__()
 
         try:
             with self.metrics.tracker(model, kind) as tracker:
@@ -330,6 +329,16 @@ class HttpService:
                     break
                 if jail is None:
                     await resp.write(sse_encode(fmt.delta(out)))
+                    if out.first_token_ts is not None:
+                        # Engine's first token -> its SSE chunk written: the
+                        # hop back, detokenizing, and this loop's own queue.
+                        record_span(
+                            "frontend_first_byte",
+                            max(0.0, (time.time() - out.first_token_ts) * 1e3),
+                            trace=TraceContext.from_dict(ctx.trace).under_root(),
+                            start_ts=out.first_token_ts,
+                            request_id=ctx.id,
+                        )
                     continue
                 # Tools declared: hold back potential tool-call markup; on
                 # the final delta decide between text and tool_calls finish.
@@ -676,31 +685,6 @@ class HttpService:
                 "router": router_resync_snapshot(),
             }
         )
-
-    async def engine_profile(self, request: web.Request) -> web.Response:
-        """On-demand device trace: POST {"seconds": 3, "dir": "/tmp/trace"}.
-
-        Captures an XPlane trace of this process's JAX work (meaningful when
-        the engine runs in-process, `launch.run_local`); view with
-        TensorBoard/xprof. Parity: A1 tracing hook (reference exposes engine
-        profilers through its debug surface)."""
-        from dynamo_tpu.tracing import profile_for, trace_running
-
-        try:
-            body = await request.json()
-        except Exception:
-            body = {}
-        if not isinstance(body, dict):
-            return web.json_response({"error": "body must be a JSON object"}, status=400)
-        try:
-            seconds = min(max(float(body.get("seconds", 3.0)), 0.1), 60.0)
-        except (TypeError, ValueError):
-            return web.json_response({"error": "seconds must be a number"}, status=400)
-        log_dir = str(body.get("dir", "/tmp/dynamo-trace"))
-        if trace_running():
-            return web.json_response({"error": "trace already running"}, status=409)
-        path = await profile_for(seconds, log_dir)
-        return web.json_response({"trace_dir": path, "seconds": seconds})
 
     async def clear_kv_blocks(self, request: web.Request) -> web.Response:
         if self.clear_kv_hook is None:

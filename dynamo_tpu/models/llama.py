@@ -375,73 +375,77 @@ def forward(
             if mla:
                 from dynamo_tpu.models.mla import mla_attention
 
-                attn_out, k_full, v_full = mla_attention(
-                    lp, cfg, h, positions, k_full, v_full,
-                    block_tables + li * npages,
-                    slot_mapping + li * (npages * ps),
-                    inv_freq_mla,
-                    attn_mscale=attn_mscale,
-                    ring=ring, mesh=mesh,
-                    ring_positions=ring_pos if ring else None,
-                    impl=attn_impl,
-                    contiguous_positions=contiguous_positions,
-                )
+                with jax.named_scope("attn"):
+                    attn_out, k_full, v_full = mla_attention(
+                        lp, cfg, h, positions, k_full, v_full,
+                        block_tables + li * npages,
+                        slot_mapping + li * (npages * ps),
+                        inv_freq_mla,
+                        attn_mscale=attn_mscale,
+                        ring=ring, mesh=mesh,
+                        ring_positions=ring_pos if ring else None,
+                        impl=attn_impl,
+                        contiguous_positions=contiguous_positions,
+                    )
                 x = x + attn_out
                 h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
-                mlp = _mlp_moe(lp, h2, cfg, mesh) if moe_layer else _mlp_dense(lp, h2, cfg.mlp_act)
+                with jax.named_scope("mlp"):
+                    mlp = _mlp_moe(lp, h2, cfg, mesh) if moe_layer else _mlp_dense(lp, h2, cfg.mlp_act)
                 return (x + mlp, k_full, v_full, li + 1), None
-            qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
-            if cfg.attention_bias:
-                qp, kp, vp = qp + lp["bq"], kp + lp["bk"], vp + lp["bv"]
-            if cfg.qk_norm == "flat":  # OLMoE: norm the flat projection
-                qp = rms_norm(qp, lp["q_norm"], eps=cfg.rms_eps)
-                kp = rms_norm(kp, lp["k_norm"], eps=cfg.rms_eps)
-            q = qp.reshape(b, t, cfg.num_heads, cfg.head_dim)
-            k = kp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-            v = vp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm == "head":  # Qwen3: per-head norm before rope
-                q = rms_norm(q, lp["q_norm"], eps=cfg.rms_eps)
-                k = rms_norm(k, lp["k_norm"], eps=cfg.rms_eps)
-            if mrope_positions is not None and cfg.mrope_section:
-                # Qwen2-VL 3D rope: ONLY the rotation angles change; cache
-                # slots, masking, and lengths keep the sequential positions.
-                q = apply_mrope(q, mrope_positions, inv_freq, cfg.mrope_section)
-                k = apply_mrope(k, mrope_positions, inv_freq, cfg.mrope_section)
-            else:
-                q = apply_rope(q, positions, inv_freq)
-                k = apply_rope(k, positions, inv_freq)
-            if attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
-                q = q * jnp.asarray(attn_mscale, q.dtype)
-            k_full, v_full = write_kv(k_full, v_full, k, v, slot_mapping + li * (npages * ps))
-            if ring:
-                from dynamo_tpu.parallel.ring import ring_attention
-
-                attn = ring_attention(q, k, v, ring_pos, mesh, scale=cfg.head_dim**-0.5)
-            else:
-                tables_l = block_tables + li * npages
-                if cfg.sliding_window > 0:
-                    attn = paged_attention(
-                        q, k_full, v_full, tables_l, positions,
-                        impl=attn_impl, sliding_window=cfg.sliding_window,
-                        contiguous_positions=contiguous_positions,
-                    )
-                elif attn_impl == "pallas" and mesh is not None:
-                    # Explicit tp/dp layout around the kernel: GSPMD would
-                    # otherwise all-gather the cache and replicate the
-                    # pallas_call on every device.
-                    from dynamo_tpu.ops.attention import paged_attention_sharded
-
-                    attn = paged_attention_sharded(
-                        q, k_full, v_full, tables_l, positions,
-                        mesh=mesh, impl=attn_impl,
-                        contiguous_positions=contiguous_positions,
-                    )
+            with jax.named_scope("attn"):  # projections, rope, cache write, attention, output
+                qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
+                if cfg.attention_bias:
+                    qp, kp, vp = qp + lp["bq"], kp + lp["bk"], vp + lp["bv"]
+                if cfg.qk_norm == "flat":  # OLMoE: norm the flat projection
+                    qp = rms_norm(qp, lp["q_norm"], eps=cfg.rms_eps)
+                    kp = rms_norm(kp, lp["k_norm"], eps=cfg.rms_eps)
+                q = qp.reshape(b, t, cfg.num_heads, cfg.head_dim)
+                k = kp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+                v = vp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+                if cfg.qk_norm == "head":  # Qwen3: per-head norm before rope
+                    q = rms_norm(q, lp["q_norm"], eps=cfg.rms_eps)
+                    k = rms_norm(k, lp["k_norm"], eps=cfg.rms_eps)
+                if mrope_positions is not None and cfg.mrope_section:
+                    # Qwen2-VL 3D rope: ONLY the rotation angles change; cache
+                    # slots, masking, and lengths keep the sequential positions.
+                    q = apply_mrope(q, mrope_positions, inv_freq, cfg.mrope_section)
+                    k = apply_mrope(k, mrope_positions, inv_freq, cfg.mrope_section)
                 else:
-                    attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
-                                           contiguous_positions=contiguous_positions)
-            x = x + _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
+                    q = apply_rope(q, positions, inv_freq)
+                    k = apply_rope(k, positions, inv_freq)
+                if attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
+                    q = q * jnp.asarray(attn_mscale, q.dtype)
+                k_full, v_full = write_kv(k_full, v_full, k, v, slot_mapping + li * (npages * ps))
+                if ring:
+                    from dynamo_tpu.parallel.ring import ring_attention
+
+                    attn = ring_attention(q, k, v, ring_pos, mesh, scale=cfg.head_dim**-0.5)
+                else:
+                    tables_l = block_tables + li * npages
+                    if cfg.sliding_window > 0:
+                        attn = paged_attention(
+                            q, k_full, v_full, tables_l, positions,
+                            impl=attn_impl, sliding_window=cfg.sliding_window,
+                            contiguous_positions=contiguous_positions,
+                        )
+                    elif attn_impl == "pallas" and mesh is not None:
+                        # Explicit tp/dp layout around the kernel: GSPMD would
+                        # otherwise all-gather the cache and replicate the
+                        # pallas_call on every device.
+                        from dynamo_tpu.ops.attention import paged_attention_sharded
+
+                        attn = paged_attention_sharded(
+                            q, k_full, v_full, tables_l, positions,
+                            mesh=mesh, impl=attn_impl,
+                            contiguous_positions=contiguous_positions,
+                        )
+                    else:
+                        attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
+                                               contiguous_positions=contiguous_positions)
+                x = x + _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
             h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
-            mlp = _mlp_moe(lp, h2, cfg, mesh) if moe_layer else _mlp_dense(lp, h2, cfg.mlp_act)
+            with jax.named_scope("mlp"):
+                mlp = _mlp_moe(lp, h2, cfg, mesh) if moe_layer else _mlp_dense(lp, h2, cfg.mlp_act)
             x = x + mlp
             return (x, k_full, v_full, li + 1), None
 

@@ -149,6 +149,13 @@ def route_tokens(
     return weights * scaling, topi
 
 
+def _widen(lp: dict) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The three expert matrices as the matmuls take them: quantized weights
+    are widened here, under a name of their own (a no-op on plain weights)."""
+    with jax.named_scope("moe.widen"):
+        return _dq(lp["w_gate"]), _dq(lp["w_up"]), _dq(lp["w_down"])
+
+
 def moe_mlp_dropless(
     lp: dict,
     x: jnp.ndarray,  # [N, D] flattened tokens
@@ -169,20 +176,27 @@ def moe_mlp_dropless(
     e = lp["router"].shape[-1]
     k = num_experts_per_token
 
-    weights, topi = route_tokens(lp, x, k=k, **(routing or {}))
+    # The scopes name the stages in the compiled ops' ``op_name`` metadata
+    # (what a profile shows instead of ``bitcast_multiply_fusion.N``).
+    with jax.named_scope("moe.router"):
+        weights, topi = route_tokens(lp, x, k=k, **(routing or {}))
 
-    flat_e = topi.reshape(-1)  # [N*k]
-    order = jnp.argsort(flat_e, stable=True)
-    xk = jnp.repeat(x, k, axis=0)[order]  # [N*k, D] grouped by expert
-    group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+        flat_e = topi.reshape(-1)  # [N*k]
+        order = jnp.argsort(flat_e, stable=True)
+        xk = jnp.repeat(x, k, axis=0)[order]  # [N*k, D] grouped by expert
+        group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
 
-    gate = jax.nn.silu(jax.lax.ragged_dot(xk, _dq(lp["w_gate"]), group_sizes))
-    up = jax.lax.ragged_dot(xk, _dq(lp["w_up"]), group_sizes)
-    down = jax.lax.ragged_dot(gate * up, _dq(lp["w_down"]), group_sizes)  # [N*k, D]
+    w_gate, w_up, w_down = _widen(lp)
+    with jax.named_scope("moe.experts_gate_up"):
+        gate = jax.nn.silu(jax.lax.ragged_dot(xk, w_gate, group_sizes))
+        up = jax.lax.ragged_dot(xk, w_up, group_sizes)
+    with jax.named_scope("moe.experts_down"):
+        down = jax.lax.ragged_dot(gate * up, w_down, group_sizes)  # [N*k, D]
 
-    rows = jnp.zeros_like(down).at[order].set(down)  # unsort
-    out = (rows.astype(jnp.float32) * weights.reshape(-1)[:, None]).reshape(n, k, d).sum(axis=1)
-    return out.astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        rows = jnp.zeros_like(down).at[order].set(down)  # unsort
+        out = (rows.astype(jnp.float32) * weights.reshape(-1)[:, None]).reshape(n, k, d).sum(axis=1)
+        return out.astype(x.dtype)
 
 
 def expert_capacity(num_tokens: int, num_experts: int, k: int, capacity_factor: float) -> int:
@@ -237,15 +251,16 @@ def moe_mlp(
     k = num_experts_per_token
     c = capacity if capacity is not None else expert_capacity(n, e, k, capacity_factor)
 
-    weights, topi = route_tokens(lp, x, k=k, **(routing or {}))
+    with jax.named_scope("moe.router"):
+        weights, topi = route_tokens(lp, x, k=k, **(routing or {}))
 
-    # Buffer position of each (token, choice) within its expert: rank among
-    # all earlier assignments to the same expert (token-major priority).
-    flat_e = topi.reshape(-1)  # [N*k]
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)  # [N*k, E]
-    pos = (jnp.cumsum(oh, axis=0) * oh).sum(-1) - 1  # [N*k]
-    keep = pos < c
-    slot = jnp.where(keep, pos, c)  # dropped choices land in a spill row
+        # Buffer position of each (token, choice) within its expert: rank among
+        # all earlier assignments to the same expert (token-major priority).
+        flat_e = topi.reshape(-1)  # [N*k]
+        oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)  # [N*k, E]
+        pos = (jnp.cumsum(oh, axis=0) * oh).sum(-1) - 1  # [N*k]
+        keep = pos < c
+        slot = jnp.where(keep, pos, c)  # dropped choices land in a spill row
 
     if _drop_stats_enabled():
         jax.debug.callback(
@@ -253,18 +268,23 @@ def moe_mlp(
         )
 
     # Scatter tokens into expert buffers (+1 spill row, sliced off).
-    xk = jnp.repeat(x, k, axis=0)  # [N*k, D] — choice j of token t at t*k+j
-    buf = jnp.zeros((e, c + 1, d), x.dtype).at[flat_e, slot].set(xk)
-    expert_in = buf[:, :c]  # [E, C, D]
+    with jax.named_scope("moe.router"):
+        xk = jnp.repeat(x, k, axis=0)  # [N*k, D] — choice j of token t at t*k+j
+        buf = jnp.zeros((e, c + 1, d), x.dtype).at[flat_e, slot].set(xk)
+        expert_in = buf[:, :c]  # [E, C, D]
 
     # Batched expert FFN: one contraction over all experts; GSPMD shards the
     # leading axis on ep from the weight shardings.
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, _dq(lp["w_gate"])))
-    up = jnp.einsum("ecd,edf->ecf", expert_in, _dq(lp["w_up"]))
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up, _dq(lp["w_down"]))  # [E, C, D]
+    w_gate, w_up, w_down = _widen(lp)
+    with jax.named_scope("moe.experts_gate_up"):
+        gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w_gate))
+        up = jnp.einsum("ecd,edf->ecf", expert_in, w_up)
+    with jax.named_scope("moe.experts_down"):
+        expert_out = jnp.einsum("ecf,efd->ecd", gate * up, w_down)  # [E, C, D]
 
     # Combine: gather each choice's row, weight, and sum over the k choices.
-    rows = expert_out[flat_e, jnp.minimum(slot, c - 1)]  # [N*k, D]
-    w = (weights.reshape(-1) * keep.astype(weights.dtype))[:, None]
-    out = (rows.astype(jnp.float32) * w).reshape(n, k, d).sum(axis=1)
-    return out.astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        rows = expert_out[flat_e, jnp.minimum(slot, c - 1)]  # [N*k, D]
+        w = (weights.reshape(-1) * keep.astype(weights.dtype))[:, None]
+        out = (rows.astype(jnp.float32) * w).reshape(n, k, d).sum(axis=1)
+        return out.astype(x.dtype)

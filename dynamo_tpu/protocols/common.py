@@ -129,6 +129,9 @@ class BackendOutput:
     # Engine admission wait (add_request -> first scheduling), reported once
     # on the request's first delta; None on later deltas.
     admission_wait_ms: float | None = None
+    # Wall clock at which the engine service handed out the request's first
+    # token (first delta only): the frontend times its first SSE byte from it.
+    first_token_ts: float | None = None
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -141,6 +144,7 @@ class BackendOutput:
             "embedding": self.embedding,
             "logprobs": self.logprobs,
             "admission_wait_ms": self.admission_wait_ms,
+            "first_token_ts": self.first_token_ts,
         }
 
     @classmethod
@@ -156,6 +160,7 @@ class BackendOutput:
             embedding=d.get("embedding"),
             logprobs=d.get("logprobs"),
             admission_wait_ms=d.get("admission_wait_ms"),
+            first_token_ts=d.get("first_token_ts"),
         )
 
 
@@ -176,6 +181,12 @@ class EngineOutput:
     # Engine admission wait (add_request -> first scheduling), attached to
     # the sequence's first delta only (frontend RequestTracker observes it).
     admission_wait_ms: float | None = None
+    # Same delta, stamped by the engine service as it hands the token out.
+    first_token_ts: float | None = None
+    # Same delta, in-process only (never on the wire): what the service's
+    # ``engine_prefill`` span says — ``admitted_mono`` (perf_counter at
+    # admission), ``chunks``, ``steps``, ``prompt_tokens``, ``cached_tokens``.
+    prefill: dict | None = None
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -187,6 +198,7 @@ class EngineOutput:
             "embedding": self.embedding,
             "logprobs": self.logprobs,
             "admission_wait_ms": self.admission_wait_ms,
+            "first_token_ts": self.first_token_ts,
         }
 
     @classmethod
@@ -201,4 +213,5 @@ class EngineOutput:
             embedding=d.get("embedding"),
             logprobs=d.get("logprobs"),
             admission_wait_ms=d.get("admission_wait_ms"),
+            first_token_ts=d.get("first_token_ts"),
         )

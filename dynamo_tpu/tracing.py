@@ -6,10 +6,14 @@ Two complementary planes, mirroring the reference's tracing stack
 - **Device**: :func:`device_trace` wraps `jax.profiler.start_trace` — dumps
   an XPlane/TensorBoard trace of everything the chip executed (XLA op
   timeline, HBM transfers, fusion view). ``annotate()`` adds named host-side
-  regions (engine phases) to the same timeline via TraceAnnotation.
+  regions (``engine.decode`` / ``engine.mixed`` per step) to the same
+  timeline via TraceAnnotation, and :class:`StepClock` times the phases
+  inside a step on ``perf_counter_ns`` whether or not a trace runs.
   Enable on any process with ``DYN_TRACE_DIR=/tmp/trace`` (traces the first
-  ``DYN_TRACE_SECONDS``, default 5), or on demand over HTTP:
-  ``POST /engine/profile {"seconds": 3}`` on the frontend.
+  ``DYN_TRACE_SECONDS``, default 5), or on demand on a worker:
+  ``POST /debug/profile/{worker}?duration_ms=3000`` on the frontend. Every
+  entry point goes through :func:`start_device_trace`, which alone sets the
+  profiler's options (:func:`profiler_options`: no Python function tracer).
 - **Request spans**: :class:`Span` measures one phase of one request and
   logs it as a structured JSONL record (``runtime/logging.py`` flattens the
   fields), giving grep-able per-request latency breakdowns without a
@@ -43,33 +47,55 @@ logger = logging.getLogger("dynamo.trace")
 
 _lock = threading.Lock()
 _active_dir: str | None = None
+#: True from the moment a session is up until its stop begins: the window in
+#: which a TraceAnnotation lands in the trace (none is built outside it).
+_annotating = False
 
 
 def trace_running() -> bool:
     return _active_dir is not None
 
 
+def profiler_options():
+    """The one place the profiler session's options are set.
+
+    The Python function tracer is off (``python_tracer_level=0``): with it on
+    every Python call of every thread is recorded and the host being measured
+    takes 3 to 5 times as long per step (1.8 -> 5.4 ms at 4 rows, 3.0 -> 15.4 ms
+    at 48; PERF.md, PR 24). ``host_tracer_level=1`` is the lowest
+    level at which ``TraceAnnotation`` (a level-1 TraceMe) still reaches the
+    host plane; the device planes do not depend on either."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
+
+
 def start_device_trace(log_dir: str) -> bool:
     """Begin an XPlane trace (idempotent; one at a time per process)."""
-    global _active_dir
+    global _active_dir, _annotating
     import jax
 
     with _lock:
         if _active_dir is not None:
             return False
-        jax.profiler.start_trace(log_dir)
+        jax.profiler.start_trace(log_dir, profiler_options=profiler_options())
         _active_dir = log_dir
+        _annotating = True
     logger.info("device trace started -> %s", log_dir)
     return True
 
 
 def stop_device_trace() -> str | None:
-    global _active_dir
+    global _active_dir, _annotating
     import jax
 
     with _lock:
         if _active_dir is None:
             return None
+        _annotating = False  # writing the trace takes seconds: annotate nothing meanwhile
         jax.profiler.stop_trace()
         path, _active_dir = _active_dir, None
     logger.info("device trace written -> %s", path)
@@ -86,16 +112,157 @@ def device_trace(log_dir: str) -> Iterator[None]:
             stop_device_trace()
 
 
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
 def annotate(name: str):
     """Named region on the profiler timeline.
 
     A no-op context when no trace is active — callers can sit on hot paths
     (the engine step loop) without paying TraceAnnotation construction."""
-    if _active_dir is None:
-        return contextlib.nullcontext()
+    if not _annotating:
+        return _NO_ANNOTATION
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+# -- phases of an engine step -------------------------------------------------
+
+#: Inside ``EngineCore.step()``: the first five tile the record's ``wall_ms``;
+#: ``record`` is the telemetry tail after it.
+STEP_PHASES = ("sched", "build", "dispatch", "wait", "post", "record")
+#: Between two steps (``engine/service.py``): they tile ``gap_ms``.
+GAP_PHASES = ("handoff", "route", "intake", "no_work", "submit")
+PHASES = STEP_PHASES + GAP_PHASES
+(SCHED, BUILD, DISPATCH, WAIT, POST, RECORD,
+ HANDOFF, ROUTE, INTAKE, NO_WORK, SUBMIT) = range(len(PHASES))
+_PHASE_EVENTS = tuple(f"phase.{p}" for p in STEP_PHASES)  # never "engine.*": the reducer keeps those
+_ZEROS = [0] * len(PHASES)
+
+
+class _StepAnnotation:
+    """The step's ``engine.*`` region; closes the phase region open inside it
+    first, so the trace nests."""
+
+    __slots__ = ("_clock", "_ann")
+
+    def __init__(self, clock: "StepClock", ann) -> None:
+        self._clock, self._ann = clock, ann
+
+    def __enter__(self) -> "_StepAnnotation":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._clock._close_region()
+        self._ann.__exit__(*exc)
+
+
+class StepClock:
+    """Phase marker of one engine: where a step's and a gap's time goes.
+
+    ``mark(phase)`` ends the running phase and starts ``phase``: one
+    ``perf_counter_ns`` and one add into a slot that lives as long as the
+    engine (a phase entered twice in a step accumulates). Only while a device
+    trace runs does a step phase also open a ``TraceAnnotation``
+    ``phase.<name>``; the gap phases cross threads and awaits and are never
+    annotated (their stamps map onto the trace through ``ann_ns``).
+
+    One writer at a time: the step thread between :meth:`begin` and
+    :meth:`end`, the service's event loop between ``end`` and ``begin`` — the
+    two alternate (``run_in_executor`` is awaited), so no lock.
+    """
+
+    __slots__ = ("ns", "carried", "t0_ns", "ann_ns", "traced", "_phase", "_t", "_region", "_gap_open")
+
+    def __init__(self) -> None:
+        self.ns = list(_ZEROS)
+        #: ``record`` of the step before and the gap since: they end when the
+        #: running step begins, so its record carries them (as ``gap_ms``).
+        self.carried = [0] * (len(PHASES) - RECORD)
+        self.t0_ns = 0
+        self.ann_ns = 0
+        self.traced = False
+        self._phase = HANDOFF
+        self._t = time.perf_counter_ns()
+        self._region = None
+        #: A step has ended and none has begun since: what the marks add up
+        #: is a gap. Not so before the first step or after one that raised.
+        self._gap_open = False
+
+    def begin(self) -> tuple[int, int]:
+        """Step start: closes the gap, keeps it as ``carried``, opens ``sched``.
+        Returns the start stamp and the gap's length, both in ns."""
+        now = time.perf_counter_ns()
+        ns = self.ns
+        if self._gap_open:
+            ns[self._phase] += now - self._t
+            self.carried = ns[RECORD:]
+        else:
+            self.carried = _ZEROS[RECORD:]
+        ns[:] = _ZEROS
+        self.t0_ns = now
+        self.ann_ns = 0
+        self.traced = False
+        self._gap_open = False
+        self._phase, self._t = SCHED, now
+        if self._region is not None:
+            self._close_region()
+        if _annotating:
+            self._open_region(SCHED)
+        return now, sum(self.carried[1:])
+
+    def mark(self, phase: int) -> int:
+        now = time.perf_counter_ns()
+        self.ns[self._phase] += now - self._t
+        self._phase, self._t = phase, now
+        if self._region is not None:
+            self._close_region()
+        if _annotating and phase <= RECORD:
+            self._open_region(phase)
+        return now
+
+    def mark_in_step(self, phase: int) -> None:
+        """``mark`` for a callee that also runs outside steps (the runner,
+        which a warm-up drives directly): only a step's phase gives way."""
+        if self._phase < RECORD:
+            self.mark(phase)
+
+    def end(self) -> None:
+        """Step end (after the flight record and the rest of the tail)."""
+        self.mark(HANDOFF)
+        self._gap_open = True
+
+    def restart_gap(self) -> None:
+        """A step that dispatched and recorded nothing: the gap starts anew."""
+        self.end()
+        self.ns[:] = _ZEROS
+
+    def annotate(self, name: str):
+        """The step's ``engine.*`` region, its entry stamped as ``ann_ns``:
+        the k-th traced STEP record is the k-th such event of the trace, which
+        puts ``perf_counter_ns`` and the trace's clock side by side."""
+        if not _annotating:
+            return _NO_ANNOTATION
+        self._close_region()
+        self.traced = True
+        self.ann_ns = time.perf_counter_ns()
+        return _StepAnnotation(self, annotate(name))
+
+    def phases_us(self) -> dict[str, float]:
+        """The running step's five phases so far and what it carries."""
+        ns = self.ns[:RECORD] + self.carried
+        return {p: round(v / 1e3, 1) for p, v in zip(PHASES, ns)}
+
+    def _open_region(self, phase: int) -> None:
+        import jax
+
+        self._region = jax.profiler.TraceAnnotation(_PHASE_EVENTS[phase])
+
+    def _close_region(self) -> None:
+        region, self._region = self._region, None
+        if region is not None:
+            region.__exit__(None, None, None)
 
 
 async def profile_for(seconds: float, log_dir: str) -> str | None:
@@ -161,6 +328,12 @@ class TraceContext:
 
     trace_id: str
     span_id: str
+    #: The trace's root span in this system (the frontend's ``http_request``)
+    #: and its wall-clock start: a hop that never saw the root can still name
+    #: it as a parent and time "since the request came in". Empty / 0.0 on a
+    #: context that came from a bare ``traceparent`` header.
+    root_id: str = ""
+    root_ts: float = 0.0
 
     @classmethod
     def new(cls) -> "TraceContext":
@@ -178,14 +351,22 @@ class TraceContext:
     def to_traceparent(self) -> str:
         return f"00-{self.trace_id}-{self.span_id}-01"
 
-    def to_dict(self) -> dict[str, str]:
-        return {"trace_id": self.trace_id, "span_id": self.span_id}
+    def to_dict(self) -> dict[str, Any]:
+        doc: dict[str, Any] = {"trace_id": self.trace_id, "span_id": self.span_id}
+        if self.root_id:
+            doc.update(root_id=self.root_id, root_ts=self.root_ts)
+        return doc
 
     @classmethod
     def from_dict(cls, obj: Any) -> "TraceContext | None":
         if not isinstance(obj, dict) or "trace_id" not in obj:
             return None
-        return cls(trace_id=str(obj["trace_id"]), span_id=str(obj.get("span_id", "")))
+        return cls(trace_id=str(obj["trace_id"]), span_id=str(obj.get("span_id", "")),
+                   root_id=str(obj.get("root_id", "")), root_ts=float(obj.get("root_ts") or 0.0))
+
+    def under_root(self) -> "TraceContext":
+        """This trace with its root span as the parent of what is recorded."""
+        return TraceContext(self.trace_id, self.root_id or self.span_id, self.root_id, self.root_ts)
 
 
 # -- span collection ----------------------------------------------------------
@@ -198,14 +379,20 @@ class SpanBuffer:
     ids, request_id, wall + monotonic start, duration, status ok|error and
     the exception type on failure. ``GET /debug/traces/{request_id}`` fans
     out to every worker's buffer and assembles one timeline from the union.
+    ``dropped`` counts the spans the ring has overwritten since the process
+    started (or the last :meth:`clear`): a reader of a window compares it
+    before and after to know whether the ring wrapped inside it.
     """
 
     def __init__(self, capacity: int = 4096) -> None:
         self._spans: deque[dict] = deque(maxlen=max(1, capacity))
         self._lock = threading.Lock()
+        self.dropped = 0
 
     def record(self, span: dict) -> None:
         with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
             self._spans.append(span)
 
     def query(self, *, request_id: str | None = None, trace_id: str | None = None) -> list[dict]:
@@ -220,6 +407,7 @@ class SpanBuffer:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self.dropped = 0
 
     def __len__(self) -> int:
         with self._lock:
@@ -273,7 +461,7 @@ class Span:
     __slots__ = (
         "name", "fields", "t0", "t_wall",
         "trace_id", "span_id", "parent_id", "status", "error_type",
-        "_cv_token",
+        "_root", "_cv_token",
     )
 
     def __init__(self, name: str, *, trace: TraceContext | None = None, **fields: Any) -> None:
@@ -282,9 +470,11 @@ class Span:
         if trace is not None:
             self.trace_id = trace.trace_id
             self.parent_id = trace.span_id or None
+            self._root = (trace.root_id, trace.root_ts)
         else:
             self.trace_id = _new_trace_id()  # root of a fresh trace
             self.parent_id = None
+            self._root = ("", 0.0)
         self.span_id = _new_span_id()
         self.status = "ok"
         self.error_type: str | None = None
@@ -294,8 +484,11 @@ class Span:
 
     @property
     def context(self) -> TraceContext:
-        """The context downstream hops should inherit (this span as parent)."""
-        return TraceContext(trace_id=self.trace_id, span_id=self.span_id)
+        """The context downstream hops should inherit (this span as parent).
+        A span that inherited no root is the root: read this after entering
+        it, so its start rides along."""
+        root_id, root_ts = self._root if self._root[0] else (self.span_id, self.t_wall)
+        return TraceContext(self.trace_id, self.span_id, root_id, root_ts)
 
     def __enter__(self) -> "Span":
         self.t0 = time.perf_counter()
@@ -353,6 +546,7 @@ def record_span(
     *,
     trace: TraceContext | None = None,
     start_ts: float | None = None,
+    start_mono: float | None = None,
     status: str = "ok",
     **fields: Any,
 ) -> dict:
@@ -361,11 +555,18 @@ def record_span(
     For durations captured by existing instrumentation (the KV-wire
     gather/pack/wire phase clocks, queue-wait gaps computed from enqueue
     stamps) where wrapping the work in a ``with Span(...)`` block is not
-    possible after the fact. Returns the recorded span dict.
+    possible after the fact. The phase ended now unless a start is given, on
+    the wall clock (``start_ts``) or on ``perf_counter`` (``start_mono``);
+    the other clock's start follows from it. Returns the recorded span dict.
     """
     span = Span(name, trace=trace, **fields)
-    span.t_wall = start_ts if start_ts is not None else time.time() - duration_ms / 1e3
-    span.t0 = time.perf_counter() - duration_ms / 1e3
+    now_wall, now_mono = time.time(), time.perf_counter()
+    if start_mono is not None and start_ts is None:
+        start_ts = now_wall - (now_mono - start_mono)
+    elif start_ts is not None and start_mono is None:
+        start_mono = now_mono - (now_wall - start_ts)
+    span.t_wall = start_ts if start_ts is not None else now_wall - duration_ms / 1e3
+    span.t0 = start_mono if start_mono is not None else now_mono - duration_ms / 1e3
     span.status = status
     logger.debug(
         "span %s %.1fms", name, duration_ms,

@@ -118,28 +118,149 @@ async def test_device_trace_writes_xplane(tmp_path):
     assert dumps, "no xplane dump written"
 
 
-async def test_profile_http_endpoint(tmp_path):
+@pytest.fixture
+def profiler_calls(monkeypatch):
+    """jax.profiler's session calls, recorded and not run."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda log_dir, **kw: calls.append(("start", str(log_dir), kw.get("profiler_options"))))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: calls.append(("stop",)))
+    return calls
+
+
+async def _capture_from_env(tmp_path, monkeypatch):
+    import asyncio
+
+    from dynamo_tpu import tracing
+
+    monkeypatch.setenv("DYN_TRACE_DIR", str(tmp_path / "env"))
+    monkeypatch.setenv("DYN_TRACE_SECONDS", "0.01")
+    tracing.maybe_trace_from_env()
+    for _ in range(200):
+        if not tracing.trace_running():
+            break
+        await asyncio.sleep(0.01)
+
+
+async def _capture_over_http(tmp_path, monkeypatch):
+    """``POST /debug/profile/{worker}``: the worker endpoint's own body."""
+    from dynamo_tpu.observability.service import ProfileCaptureService
+    from dynamo_tpu.runtime.engine import Context
+
+    monkeypatch.setenv("DYN_PROFILE_DIR", str(tmp_path / "http"))
+    svc = ProfileCaptureService(worker="w0")
+    docs = [d async for d in svc.generate({"action": "capture", "duration_ms": 5}, Context())]
+    assert docs[-1]["ok"] is True, docs
+
+
+async def _capture_as_the_benchmark_does(tmp_path, monkeypatch):
+    from dynamo_tpu import tracing
+
+    assert tracing.start_device_trace(str(tmp_path / "bench")) is True
+    assert tracing.stop_device_trace() == str(tmp_path / "bench")
+
+
+@pytest.mark.parametrize("capture", [_capture_from_env, _capture_over_http, _capture_as_the_benchmark_does],
+                         ids=["DYN_TRACE_DIR", "debug_profile", "benchmark"])
+async def test_every_capture_entry_point_leaves_the_python_tracer_off(capture, tmp_path, monkeypatch, profiler_calls):
+    """One place sets the profiler's options, and every way to start a capture
+    goes through it (``POST /engine/profile`` is gone: it traced the frontend's
+    process, which holds no chip in a split deployment)."""
+    from dynamo_tpu import tracing
+
+    await capture(tmp_path, monkeypatch)
+    assert [c[0] for c in profiler_calls] == ["start", "stop"] and not tracing.trace_running()
+    opts = profiler_calls[0][2]
+    assert opts is not None and opts.python_tracer_level == 0 and opts.host_tracer_level == 1
+
+
+async def test_engine_profile_route_is_gone():
     import aiohttp
 
-    from dynamo_tpu.launch import run_local
+    from dynamo_tpu.launch import run_local, stop_local
 
     handles = await run_local("test-tiny", port=0, mock=True, num_pages=64)
     try:
-        port = handles["port"]
+        async with aiohttp.ClientSession() as s:
+            r = await s.post(f"http://127.0.0.1:{handles['port']}/engine/profile", json={"seconds": 0.1})
+            assert r.status in (404, 405)
+    finally:
+        await stop_local(handles)
+
+
+async def test_one_request_yields_its_five_path_spans_under_one_trace():
+    """``frontend_pre_engine`` -> ``engine_queue_wait`` -> ``engine_admission_wait``
+    -> ``engine_prefill`` -> ``frontend_first_byte``: one trace id, in start
+    order, each inside ``http_request``, each naming a parent of that trace."""
+    import aiohttp
+
+    from dynamo_tpu.launch import run_local, stop_local
+    from dynamo_tpu.tracing import SPANS
+
+    handles = await run_local("test-tiny", port=0, num_pages=64, max_batch_size=4)
+    try:
         async with aiohttp.ClientSession() as s:
             r = await s.post(
-                f"http://127.0.0.1:{port}/engine/profile",
-                json={"seconds": 0.2, "dir": str(tmp_path / "t")},
-            )
-            body = await r.json()
+                f"http://127.0.0.1:{handles['port']}/v1/completions",
+                json={"model": "test-tiny", "prompt": [5, 6, 7, 8, 9, 10, 11], "max_tokens": 4, "stream": True})
             assert r.status == 200
-            assert body["trace_dir"]
+            trace_id = r.headers["x-dynamo-trace-id"]
+            body = await r.text()
+            assert "[DONE]" in body
     finally:
-        await handles["http"].stop()
-        await handles["watcher"].close()
-        for s in handles["services"]:
-            await s.close()
-        await handles["runtime"].close()
+        await stop_local(handles)
+    spans = SPANS.query(trace_id=trace_id)
+    by_name = {s["name"]: s for s in spans}
+    path = ["frontend_pre_engine", "engine_queue_wait", "engine_admission_wait", "engine_prefill",
+            "frontend_first_byte"]
+    assert set(path) <= set(by_name), sorted(by_name)
+    root = by_name["http_request"]
+    starts = [by_name[n]["start_mono"] for n in path]
+    assert starts == sorted(starts) and starts[0] == pytest.approx(root["start_mono"], abs=1e-3)
+    ids = {s["span_id"] for s in spans}
+    for n in path:
+        sp = by_name[n]
+        assert sp["parent_id"] in ids and sp["trace_id"] == trace_id
+        assert sp["start_mono"] >= root["start_mono"] - 1e-3
+        assert sp["start_mono"] + sp["duration_ms"] / 1e3 <= root["start_mono"] + root["duration_ms"] / 1e3 + 1e-3
+    assert by_name["frontend_pre_engine"]["parent_id"] == root["span_id"] == by_name["frontend_first_byte"]["parent_id"]
+    pf = by_name["engine_prefill"]
+    assert (pf["prompt_tokens"], pf["cached_tokens"], pf["chunks"]) == (7, 0, 1) and pf["steps"] >= 1
+    # The path's parts are contiguous: together they span the request's start to its first byte.
+    end = by_name["frontend_first_byte"]["start_mono"] + by_name["frontend_first_byte"]["duration_ms"] / 1e3
+    assert sum(by_name[n]["duration_ms"] for n in path) / 1e3 == pytest.approx(end - root["start_mono"], abs=5e-3)
+
+
+def test_span_buffer_counts_what_it_drops():
+    from dynamo_tpu.tracing import SpanBuffer
+
+    ring = SpanBuffer(capacity=3)
+    for i in range(3):
+        ring.record({"name": "s", "i": i})
+    assert ring.dropped == 0 and len(ring) == 3
+    ring.record({"name": "s", "i": 3})
+    ring.record({"name": "s", "i": 4})
+    assert ring.dropped == 2 and [s["i"] for s in ring.query()] == [2, 3, 4]
+    ring.clear()
+    assert ring.dropped == 0 and len(ring) == 0
+
+
+def test_trace_context_carries_its_root_across_hops():
+    from dynamo_tpu.tracing import Span, TraceContext
+
+    with Span("http_request") as root:
+        ctx = root.context
+    assert (ctx.root_id, ctx.root_ts) == (root.span_id, root.t_wall)
+    hop = TraceContext.from_dict(ctx.to_dict())
+    with Span("rpc_client", trace=hop) as child:
+        pass
+    far = TraceContext.from_dict(child.context.to_dict())
+    assert far.span_id == child.span_id and (far.root_id, far.root_ts) == (root.span_id, root.t_wall)
+    assert far.under_root().span_id == root.span_id
+    bare = TraceContext.from_traceparent(f"00-{'a' * 32}-{'b' * 16}-01")
+    assert bare.to_dict() == {"trace_id": "a" * 32, "span_id": "b" * 16}  # nothing added on the W3C path
 
 
 def test_span_logs_structured_fields(caplog):
