@@ -698,6 +698,8 @@ class EngineCore:
                 self.runner.last_attn_dispatch = None
                 self.attn_dispatch_counts[attn] = self.attn_dispatch_counts.get(attn, 0) + 1
             attn_phase, attn_path = attn if attn else ("", "")
+            # A step that dispatched ran the model, routed experts included.
+            moe_path = getattr(self.runner, "moe_path", "") if attn else ""
             # Feed the chunk-budget controller only steps that carried decode
             # rows: their wall time is the ITL a running request observed.
             if self.chunk_controller is not None and decode_rows:
@@ -743,6 +745,7 @@ class EngineCore:
                 dispatch_ms=round(dispatch_ms, 3),
                 attn_phase=attn_phase,
                 attn_path=attn_path,
+                moe_path=moe_path,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
                 deadline_slack_ms=self.last_admission.get("deadline_slack_ms", 0.0),

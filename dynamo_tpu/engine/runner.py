@@ -285,6 +285,12 @@ class ModelRunner:
                 if self.device is not None:
                     params = jax.device_put(params, self.device)
         self.params = params
+        # Which formulation the routed experts take ("fused" / "widened" / ""
+        # for a dense model): the predicate the forward itself dispatches on,
+        # so the engine's STEP records carry it beside the attention path.
+        from dynamo_tpu.parallel.moe import experts_path
+
+        self.moe_path = experts_path(params.get("layers", {}), mesh=mesh)
 
         @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
         def _step(params, k_cache, v_cache, tokens, positions, block_tables, slot_mapping,

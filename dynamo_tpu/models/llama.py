@@ -210,7 +210,7 @@ def _mlp_moe(lp: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None) -> jnp.nda
         out = _routed_dense(lp, xt, cfg)
     elif ep <= 1 and dispatch not in ("capacity", "dense"):
         out = moe_mlp_dropless(
-            lp, xt, num_experts_per_token=cfg.num_experts_per_token, routing=routing
+            lp, xt, num_experts_per_token=cfg.num_experts_per_token, routing=routing, mesh=mesh
         )
     else:
         cf = cfg.moe_capacity_factor
@@ -368,9 +368,20 @@ def forward(
             rope_frequencies(cfg.qk_rope_head_dim, theta=cfg.rope_theta, scaling=cfg.rope_scaling)
         )
 
+    # The fused expert kernel reads the stacked int8 experts in place, by
+    # layer index (parallel/moe.split_expert_stack says why).
+    moe_layers, expert_stack = params["layers"], None
+    if cfg.is_moe:
+        from dynamo_tpu.parallel.moe import join_expert_stack, split_expert_stack
+
+        moe_layers, expert_stack = split_expert_stack(params["layers"], mesh=mesh)
+    n_dense = jax.tree.leaves(params["dense_layers"])[0].shape[0] if "dense_layers" in params else 0
+
     def make_layer_step(moe_layer: bool):
         def layer_step(carry, lp):
             x, k_full, v_full, li = carry
+            if moe_layer:
+                lp = join_expert_stack(lp, expert_stack, li - n_dense)
             h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
             if mla:
                 from dynamo_tpu.models.mla import mla_attention
@@ -461,7 +472,7 @@ def forward(
     (x, k_out, v_out, _), _ = jax.lax.scan(
         make_layer_step(cfg.is_moe),
         carry,
-        params["layers"],
+        moe_layers,
     )
     k_out = k_out.reshape(k_cache.shape)
     v_out = v_out.reshape(v_cache.shape)

@@ -156,14 +156,81 @@ def _widen(lp: dict) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         return _dq(lp["w_gate"]), _dq(lp["w_up"]), _dq(lp["w_down"])
 
 
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _kernel_platform() -> bool:
+    """Where the grouped-matmul kernel can run: a TPU backend, or anywhere
+    under the Pallas interpreter (``DYNAMO_PALLAS_INTERPRET=1``, CPU tests)."""
+    from dynamo_tpu.ops.pallas_paged import interpret_mode
+
+    return jax.default_backend() == "tpu" or interpret_mode()
+
+
+def experts_path(lp: dict, *, mesh=None) -> str:
+    """Which formulation the routed experts of ``lp`` take (one layer's
+    params or the stacked layers; only trailing axes are read): ``"fused"``,
+    the int8 grouped-matmul kernel (``ops/pallas_moe.py``); ``"widened"``,
+    every other routed formulation (``_widen`` + ``ragged_dot`` / capacity
+    einsums / dense); ``""`` when ``lp`` has no routed experts.
+
+    The one predicate behind both the dispatch (:func:`moe_mlp_dropless`)
+    and the step's ``moe_path`` label (``ModelRunner``). The kernel takes
+    int8 leaves (``qw``: the per-output-channel scale commutes with the
+    contraction; packed int4's group scales do not) that no mesh axis shards
+    (``ep`` splits the expert axis, ``tp`` each expert's columns), under the
+    dropless dispatch, on a platform that runs it, at widths that tile.
+    Everything else keeps the XLA formulations."""
+    if "router" not in lp:
+        return ""
+    from dynamo_tpu.ops.pallas_moe import supported
+
+    leaves = [lp[name] for name in _EXPERT_LEAVES]
+    sharded = mesh is not None and any(int(mesh.shape.get(axis, 1)) > 1 for axis in ("ep", "tp"))
+    fused = (
+        not sharded
+        and os.environ.get("DYNAMO_MOE_DISPATCH", "") not in ("capacity", "dense")
+        and all(isinstance(leaf, dict) and "qw" in leaf for leaf in leaves)
+        and all(supported(*leaf["qw"].shape[-2:]) for leaf in leaves)
+        and _kernel_platform()
+    )
+    return "fused" if fused else "widened"
+
+
+def split_expert_stack(layers: dict, *, mesh=None) -> tuple[dict, dict | None]:
+    """``(xs, stack)`` for a ``lax.scan`` over stacked MoE layers. On the
+    fused path the int8 expert arrays leave the scanned tree: a custom call
+    on the scan's slice makes XLA copy the layer's experts first (three
+    dynamic-slice fusions of 134 MB each at OLMoE's widths, compiled for a
+    v5e), so the kernel takes the whole stack and the layer's index instead
+    (:func:`join_expert_stack`). Otherwise ``(layers, None)``."""
+    if experts_path(layers, mesh=mesh) != "fused":
+        return layers, None
+    stack = {name: layers[name]["qw"] for name in _EXPERT_LEAVES}
+    rest = {name: {k: v for k, v in layers[name].items() if k != "qw"} for name in _EXPERT_LEAVES}
+    return {**layers, **rest}, stack
+
+
+def join_expert_stack(lp: dict, stack: dict | None, layer: jnp.ndarray) -> dict:
+    """One scanned layer's params with the stacked int8 experts put back
+    beside their sliced scales, and the layer's index as ``expert_layer``."""
+    if stack is None:
+        return lp
+    leaves = {name: {**lp[name], "qw": stack[name]} for name in _EXPERT_LEAVES}
+    return {**lp, **leaves, "expert_layer": layer}
+
+
 def moe_mlp_dropless(
     lp: dict,
     x: jnp.ndarray,  # [N, D] flattened tokens
     *,
     num_experts_per_token: int,
     routing: dict | None = None,
+    mesh=None,
 ) -> jnp.ndarray:
-    """Dropless routed MoE via ``lax.ragged_dot`` (TPU grouped matmul).
+    """Dropless routed MoE via grouped matmuls: ``lax.ragged_dot``, or for
+    int8 experts where :func:`experts_path` says so the Pallas kernel that
+    reads them as stored (``ops/pallas_moe.py``).
 
     Token copies are stable-sorted by expert id (an O(N*k) argsort — token
     count, never vocabulary), expert FFNs run as ragged grouped matmuls with
@@ -186,12 +253,21 @@ def moe_mlp_dropless(
         xk = jnp.repeat(x, k, axis=0)[order]  # [N*k, D] grouped by expert
         group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
 
-    w_gate, w_up, w_down = _widen(lp)
-    with jax.named_scope("moe.experts_gate_up"):
-        gate = jax.nn.silu(jax.lax.ragged_dot(xk, w_gate, group_sizes))
-        up = jax.lax.ragged_dot(xk, w_up, group_sizes)
-    with jax.named_scope("moe.experts_down"):
-        down = jax.lax.ragged_dot(gate * up, w_down, group_sizes)  # [N*k, D]
+    if experts_path(lp, mesh=mesh) == "fused":
+        from dynamo_tpu.ops.pallas_moe import expert_ffn_int8
+        from dynamo_tpu.ops.pallas_paged import interpret_mode
+
+        down = expert_ffn_int8(
+            xk, lp["w_gate"], lp["w_up"], lp["w_down"], group_sizes, lp.get("expert_layer"),
+            interpret=interpret_mode(),
+        )
+    else:
+        w_gate, w_up, w_down = _widen(lp)
+        with jax.named_scope("moe.experts_gate_up"):
+            gate = jax.nn.silu(jax.lax.ragged_dot(xk, w_gate, group_sizes))
+            up = jax.lax.ragged_dot(xk, w_up, group_sizes)
+        with jax.named_scope("moe.experts_down"):
+            down = jax.lax.ragged_dot(gate * up, w_down, group_sizes)  # [N*k, D]
 
     with jax.named_scope("moe.combine"):
         rows = jnp.zeros_like(down).at[order].set(down)  # unsort

@@ -148,3 +148,73 @@ def test_quantized_matmul_compiles(sds, mode, d_in, d_out, fuses):
     temp = compiled.memory_analysis().temp_size_in_bytes
     if fuses:
         assert temp < d_in * d_out * 2 // 4
+
+
+# -- int8 expert grouped matmul (ops/pallas_moe.py) ---------------------------
+
+#: (hidden, expert width, experts, top-k): OLMoE-1B-7B and DeepSeek-V2-Lite.
+MOE_WIDTHS = {"olmoe-1b-7b": (2048, 1024, 64, 8), "deepseek-v2-lite": (2048, 1408, 64, 6)}
+
+
+def _expert_leaves(sds, e, d, f):
+    leaf = lambda d_in, d_out: {"qw": sds((e, d_in, d_out), jnp.int8), "scale": sds((e, d_out), jnp.bfloat16)}  # noqa: E731
+    return leaf(d, f), leaf(d, f), leaf(f, d)
+
+
+@pytest.mark.parametrize("preset, tokens", [
+    ("olmoe-1b-7b", 4), ("olmoe-1b-7b", 64), ("olmoe-1b-7b", 256), ("olmoe-1b-7b", 4096),
+    ("deepseek-v2-lite", 4), ("deepseek-v2-lite", 512),
+], ids=lambda v: str(v))
+def test_moe_grouped_matmul_kernel_compiles(sds, preset, tokens):
+    """Gate+up ([64, 2048, F] twice over one left operand) and down
+    ([64, F, 2048]) for 32 to 32,768 token copies: decode batches, a chunk
+    of prefill, the 64-row mixed step of the saturated cell."""
+    from dynamo_tpu.ops.pallas_moe import expert_ffn_int8, supported
+
+    d, f, e, k = MOE_WIDTHS[preset]
+    assert (PRESETS[preset].hidden_size, PRESETS[preset].moe_intermediate_size) == (d, f)
+    assert supported(d, f) and supported(f, d)
+    gate, up, down = _expert_leaves(sds, e, d, f)
+    text = _compiled_text(expert_ffn_int8, sds((tokens * k, d), jnp.bfloat16), gate, up, down, sds((e,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # The int8 arrays reach the kernel as stored: nothing widens them outside.
+    assert f"bf16[{e},{d},{f}]" not in text and f"bf16[{e},{f},{d}]" not in text
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "widened"])
+def test_olmoe_int8_step_moves_no_expert_array_outside_the_kernel(sds, kernel, monkeypatch):
+    """A whole OLMoE int8 decode step (the layer scan over stacked weights, 4
+    rows) lowered for the v5e. With the kernel, no operation of the program
+    but the two custom calls touches a layer's experts: no convert-multiply
+    to bf16[64, 2048, 1024], and no dynamic-slice copy of the int8 layer
+    either (the kernel takes the stack and the layer index). Without it
+    (what the CPU backend's predicate picks) the widened arrays are there:
+    the assertion has something to miss."""
+    import dataclasses
+    import functools
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+
+    cfg = dataclasses.replace(PRESETS["olmoe-1b-7b"], num_layers=2)
+    if kernel:
+        monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    assert moe.experts_path(params["layers"]) == ("fused" if kernel else "widened")
+    rows, page, pages_per_seq = 4, 128, 16
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, rows * pages_per_seq + 1, page)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    text = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas")).lower(
+        params=params, tokens=i32(rows, 1), positions=i32(rows, 1), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(rows, pages_per_seq), slot_mapping=i32(rows, 1), last_token_index=i32(rows),
+    ).compile().as_text()
+    widened = [line for line in text.splitlines() if "bf16[64,2048,1024]" in line or "bf16[64,1024,2048]" in line]
+    sliced = [line for line in text.splitlines() if "s8[1,64,2048,1024]" in line or "s8[1,64,1024,2048]" in line]
+    if kernel:
+        assert text.count("moe_grouped_matmul_int8") >= 2
+        assert not widened, widened[:2]
+        assert not sliced, sliced[:2]
+    else:
+        assert widened and "moe_grouped_matmul_int8" not in text

@@ -1116,3 +1116,50 @@ async def test_disagg_request_yields_single_trace_timeline(monkeypatch):
         for svc in handles["services"]:
             await svc.close()
         await handles["runtime"].close()
+
+
+# -- STEP records: which formulation the routed experts took -------------------
+
+
+@pytest.mark.parametrize("case, want", [("dense", ""), ("moe_bf16", "widened"), ("moe_int8_kernel", "fused")])
+def test_step_records_carry_moe_path_beside_attn_path(case, want, monkeypatch):
+    """Every STEP record that dispatched says which formulation its routed
+    experts took, from the predicate the forward dispatches on
+    (``parallel/moe.experts_path``): "" for a dense model, "widened" for
+    the XLA formulations, "fused" where the int8 kernel runs (interpret mode
+    stands in for the TPU here)."""
+    import dataclasses
+
+    from dynamo_tpu.engine.core import EngineConfig, EngineCore
+    from dynamo_tpu.engine.runner import ModelRunner
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import PRESETS
+    from dynamo_tpu.models.quant import quantize_params
+    from dynamo_tpu.observability.flight import STEP
+    from dynamo_tpu.protocols.common import PreprocessedRequest, SamplingOptions, StopConditions
+
+    if case == "dense":
+        cfg = PRESETS["test-tiny"]
+        params = llama.init_params(cfg, 0)
+    else:
+        cfg = dataclasses.replace(PRESETS["test-tiny-moe"], hidden_size=128, moe_intermediate_size=128)
+        params = llama.init_params(cfg, 0)
+    if case == "moe_int8_kernel":
+        params = quantize_params(params, mode="int8")
+        monkeypatch.setenv("DYNAMO_PALLAS_INTERPRET", "1")
+    runner = ModelRunner(cfg, params, num_pages=32, page_size=4, max_batch_size=4, prefill_bucket=16,
+                         attn_impl="reference")
+    assert runner.moe_path == want
+    core = EngineCore(runner, EngineConfig(num_pages=32, page_size=4, max_batch_size=4,
+                                           max_prefill_tokens=64, max_seq_len=64))
+    core.add_request(PreprocessedRequest(
+        token_ids=[1, 2, 3, 4, 5], sampling=SamplingOptions(temperature=0.0), stop=StopConditions(max_tokens=3)))
+    for _ in range(16):
+        if not core.has_work:
+            break
+        core.step()
+    records = core.flight.snapshot(kind=STEP)
+    dispatched = [r for r in records if r["attn_path"]]
+    assert dispatched and all("moe_path" in r for r in records)
+    assert {r["moe_path"] for r in dispatched} == {want}
+    assert all(r["moe_path"] == "" for r in records if not r["attn_path"])
