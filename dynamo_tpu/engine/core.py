@@ -700,6 +700,8 @@ class EngineCore:
             attn_phase, attn_path = attn if attn else ("", "")
             # A step that dispatched ran the model, routed experts included.
             moe_path = getattr(self.runner, "moe_path", "") if attn else ""
+            take_kv = getattr(self.runner, "take_kv_tokens", None)
+            kv_full, kv_window = take_kv() if attn and take_kv else (0, 0)
             # Feed the chunk-budget controller only steps that carried decode
             # rows: their wall time is the ITL a running request observed.
             if self.chunk_controller is not None and decode_rows:
@@ -746,6 +748,8 @@ class EngineCore:
                 attn_phase=attn_phase,
                 attn_path=attn_path,
                 moe_path=moe_path,
+                kv_tokens_full=kv_full,
+                kv_tokens_window=kv_window,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
                 deadline_slack_ms=self.last_admission.get("deadline_slack_ms", 0.0),
@@ -2454,8 +2458,12 @@ class EngineCore:
         from table INDEX, not page content, so reads of page 0 there are
         masked out regardless of what another sequence later writes in it.
         Release paths (finish/preempt) skip the zeros."""
-        win = getattr(self.runner.cfg, "sliding_window", 0) if hasattr(self.runner, "cfg") else 0
-        if not win or not self.config.swa_free_pages:
+        cfg = getattr(self.runner, "cfg", None)
+        win = getattr(cfg, "sliding_window", 0)
+        # One page-id space serves every layer: a page may go only when no
+        # layer reads it any more, so a model that mixes window and full
+        # layers releases nothing (its full layers read the whole context).
+        if not win or not self.config.swa_free_pages or getattr(cfg, "mixed_attention", False):
             return
         ps = self.config.page_size
         # Tokens at absolute positions < (next_pos - win) are out of every

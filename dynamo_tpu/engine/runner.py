@@ -18,6 +18,7 @@ import dataclasses
 import functools
 import logging
 import threading
+from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
@@ -265,6 +266,15 @@ class ModelRunner:
         # "prefill" x "pallas"/"fallback"/"ring". The engine copies this
         # into its STEP flight records and dispatch-path counters.
         self.last_attn_dispatch: tuple[str, str] | None = None
+        # The most recent dispatch's padded batch, until its key-token counts
+        # are taken (take_kv_tokens): counted after the enqueue, under the
+        # device's shadow, never between a result and the next enqueue.
+        self._kv_pending: StepBatch | None = None
+        self._kv_tokens_last: tuple[int, int] = (0, 0)
+        # Called (from the stepping thread) when a synchronous dispatch has
+        # enqueued its program and is about to block on the result: the
+        # service routes the previous step's outputs then (engine/service.py).
+        self.on_enqueued: Callable[[], None] | None = None
         self._dp = 1
         if mesh is not None:
             from dynamo_tpu.parallel.sharding import cache_shardings, shard_params
@@ -794,9 +804,11 @@ class ModelRunner:
         ("ring") without touching the jitted program."""
         t = int(padded.tokens.shape[1])
         phase = "verify" if (verify and t > 1) else ("decode" if t == 1 else "prefill")
+        if self.cfg.sliding_window:  # a model without a windowed layer counts nothing (0, 0)
+            self._kv_pending = padded
         if impl == "ring":
             return phase, "ring"
-        if impl != "pallas" or self.cfg.sliding_window > 0:
+        if impl != "pallas":  # windowed layers take the kernels under the same predicates
             return phase, "fallback"
         from dynamo_tpu.ops.pallas_paged import interpret_mode
 
@@ -818,6 +830,26 @@ class ModelRunner:
                 t_q, interpret=interp if phase != "prefill" else False,
             )
         return phase, "pallas" if ok else "fallback"
+
+    def take_kv_tokens(self) -> tuple[int, int]:
+        """(kv_tokens_full, kv_tokens_window) of the most recent dispatch."""
+        if self._kv_pending is not None:
+            self._kv_tokens_last, self._kv_pending = self._kv_tokens(self._kv_pending), None
+        return self._kv_tokens_last
+
+    def _kv_tokens(self, padded: StepBatch) -> tuple[int, int]:
+        """Key tokens one layer of each kind has to visit in this dispatch:
+        a full layer every row's context; a windowed layer at most the window
+        plus the row's new tokens less one. Padding rows (a null block table)
+        count nothing. Only a model with a windowed layer is counted."""
+        pos = np.asarray(padded.positions)[np.asarray(padded.block_tables).any(axis=1)]
+        if not len(pos):
+            return 0, 0
+        context = pos.max(axis=1).astype(np.int64) + 1
+        win = self.cfg.sliding_window
+        first = np.where(pos > 0, pos, np.iinfo(np.int32).max).min(axis=1)
+        new = context - np.minimum(pos[:, 0], first)
+        return int(context.sum()), int(np.minimum(context, win + new - 1).sum())
 
     # -- device-cost plane -------------------------------------------------
 
@@ -875,6 +907,9 @@ class ModelRunner:
         result. Outside an engine step (warm-up, tests) there is no clock."""
         if self.clock is not None:
             self.clock.mark_in_step(tracing.WAIT)
+        if self.on_enqueued is not None:
+            self.on_enqueued()
+        self.take_kv_tokens()  # the device is busy now: host work here costs no step time
 
     @_locked
     def step(self, batch: StepBatch, lp_k: int = 0):

@@ -13,6 +13,78 @@ import pathlib
 from typing import Any
 
 
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+def _split_rope(params: dict) -> tuple[float, dict | None]:
+    """One HF `rope_parameters` entry -> (theta, rope_scaling dict or None)."""
+    if "rope_theta" not in params:
+        raise ValueError(f"rope_parameters entry {params!r} carries no rope_theta")
+    rest = {k: v for k, v in params.items() if k != "rope_theta"}
+    plain = rest.get("rope_type", rest.get("type")) in (None, "default")
+    return float(params["rope_theta"]), (None if plain else rest)
+
+
+def _attention_layout(config: dict) -> dict:
+    """The window, the per-layer kinds and the RoPE(s) of an HF config, as
+    ModelConfig fields. Refuses by name what it cannot serve faithfully."""
+    n = config["num_hidden_layers"]
+    window = int(config.get("sliding_window") or 0)
+    kinds = config.get("layer_types")
+    mlp_kinds = config.get("mlp_layer_types")
+    if mlp_kinds is not None and (len(mlp_kinds) != n or set(mlp_kinds) - {"sparse"}):
+        raise ValueError(
+            f"mlp_layer_types {sorted(set(mlp_kinds))} over {len(mlp_kinds)} entries: only 'sparse' "
+            f"in each of the {n} layers is supported")
+    if kinds is not None:
+        kinds = tuple(kinds)
+        unknown = sorted(set(kinds) - {SLIDING, FULL})
+        if unknown or len(kinds) != n:
+            raise ValueError(
+                f"layer_types holds {unknown or len(kinds)}: expected {n} entries of "
+                f"{SLIDING!r} / {FULL!r}")
+        if SLIDING in kinds and not config.get("use_sliding_window", True):
+            raise ValueError("use_sliding_window is false but layer_types names sliding_attention layers")
+        if SLIDING in kinds and window <= 0:
+            raise ValueError("layer_types names sliding_attention layers but sliding_window is not set")
+        if SLIDING not in kinds:
+            window = 0
+        if len(set(kinds)) == 1:
+            kinds = ()  # all alike: the one global window says it all
+    else:
+        # HF gates the window: Qwen2-family configs carry sliding_window
+        # together with use_sliding_window=false (full causal). Without
+        # layer_types the key is adopted only when the gate is on (absent =
+        # on, Mistral-style) and max_window_layers, where given, covers every
+        # layer. A partial count names a mix this config does not spell out
+        # layer by layer, and 0 is no layer at all: full attention, never
+        # "every layer".
+        kinds = ()
+        mwl = config.get("max_window_layers")
+        if not config.get("use_sliding_window", True) or (mwl is not None and int(mwl) < n):
+            window = 0
+    out = dict(sliding_window=window, layer_types=kinds)
+    by_kind = config.get("rope_parameters")
+    if by_kind and set(by_kind) & {SLIDING, FULL}:
+        used = set(kinds) or {SLIDING if window else FULL}
+        missing = sorted(used - set(by_kind))
+        if missing:
+            raise ValueError(f"rope_parameters has no entry for {missing}")
+        ropes = {k: _split_rope(by_kind[k]) for k in sorted(used)}
+        if len(set(map(repr, ropes.values()))) == 1:
+            theta, scaling = next(iter(ropes.values()))
+        else:  # the top-level pair mirrors the full layers; forward reads by kind
+            theta, scaling = ropes[FULL]
+            out["rope_parameters"] = {k: dict(by_kind[k]) for k in sorted(used)}
+    elif by_kind:  # one flat set of parameters for every layer
+        theta, scaling = _split_rope(by_kind)
+    elif "rope_theta" in config:
+        theta, scaling = float(config["rope_theta"]), config.get("rope_scaling")
+    else:
+        theta, scaling = 10000.0, config.get("rope_scaling")
+    return dict(out, rope_theta=theta, rope_scaling=scaling)
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -67,6 +139,16 @@ class ModelConfig:
     # Sliding-window attention (Mistral): queries attend to the last
     # `sliding_window` positions only. 0 = full causal.
     sliding_window: int = 0
+    # Attention kind of each layer where kinds are mixed in one model:
+    # "sliding_attention" (the last `sliding_window` positions) or
+    # "full_attention", one entry per layer. () = every layer alike (windowed
+    # iff sliding_window > 0). One page-id space serves both kinds: every
+    # layer caches every token, sliding layers read only their window.
+    layer_types: tuple = ()
+    # RoPE per attention kind where the kinds differ (HF `rope_parameters`
+    # keyed by kind: rope_theta plus the rope_scaling keys). None = the one
+    # rope_theta / rope_scaling above for every layer.
+    rope_parameters: dict | None = None
     # Multimodal: the placeholder token id image embeddings substitute for
     # (None = text-only model); vision tower geometry lives in VisionConfig.
     image_token_id: int | None = None
@@ -100,6 +182,23 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def mixed_attention(self) -> bool:
+        """Window and full attention layers side by side in one model."""
+        return len(set(self.layer_types)) > 1
+
+    def layer_windows(self) -> tuple[int, ...]:
+        """Each layer's window in tokens, 0 for full attention."""
+        if not self.layer_types:
+            return (self.sliding_window,) * self.num_layers
+        return tuple(self.sliding_window if k == SLIDING else 0 for k in self.layer_types)
+
+    def rope_of(self, kind: str) -> tuple[float, dict | None]:
+        """(theta, scaling dict or None) of one attention kind."""
+        if not self.rope_parameters:
+            return self.rope_theta, self.rope_scaling
+        return _split_rope(self.rope_parameters[kind])
 
     def kv_bytes_per_token(self, itemsize: int | None = None) -> int:
         """Bytes of KV cache per token across all layers (2 = K and V; MLA
@@ -189,8 +288,7 @@ class ModelConfig:
             num_kv_heads=config.get("num_key_value_heads", heads),
             head_dim=config.get("head_dim") or hidden // heads,
             intermediate_size=config["intermediate_size"],
-            rope_theta=config.get("rope_theta", 10000.0),
-            rope_scaling=config.get("rope_scaling"),
+            **_attention_layout(config),
             rms_eps=config.get("rms_norm_eps", 1e-5),
             max_position=config.get("max_position_embeddings", 8192),
             tie_embeddings=config.get("tie_word_embeddings", False),
@@ -239,16 +337,6 @@ class ModelConfig:
             qk_norm={"qwen3": "head", "qwen3_moe": "head", "olmoe": "flat"}.get(
                 config.get("model_type", ""), ""
             ),
-            # HF gates the window: Qwen2-family configs carry sliding_window
-            # together with use_sliding_window=false (full causal). Adopt the
-            # key only when the gate is on (absent = on, Mistral-style) AND
-            # it applies to every layer (max_window_layers partial-SWA is
-            # unsupported — full attention is the conservative fallback).
-            sliding_window=int(config.get("sliding_window") or 0)
-            if config.get("use_sliding_window", True)
-            and int(config.get("max_window_layers") or config["num_hidden_layers"])
-            >= config["num_hidden_layers"]
-            else 0,
             # DeepSeek-V2/V3: MLA signalled by the latent-rank keys.
             attn_type="mla" if config.get("kv_lora_rank") else "gqa",
             q_lora_rank=config.get("q_lora_rank") or 0,

@@ -362,6 +362,29 @@ def forward(
         # A far-future sentinel position hides them from every real query.
         ring_pos = jnp.where(slot_mapping == 0, jnp.int32(2**30), positions)
 
+    # Window and full attention layers in one model: the one layer scan carries
+    # per-layer scalars beside the stacked weights (the window, with NO_WINDOW
+    # for a full layer; which RoPE table; the YaRN factor squared), so both
+    # kinds are one compiled layer body. A model whose layers are all alike
+    # scans its weights alone, as before.
+    layer_kinds = None
+    if cfg.mixed_attention:
+        if cfg.attn_type == "mla" or cfg.mrope_section:
+            raise NotImplementedError("mixed window/full layers are served for GQA text models only")
+        from dynamo_tpu.ops.pallas_paged import NO_WINDOW
+
+        kinds = sorted(set(cfg.layer_types))
+        ropes = [cfg.rope_of(kind) for kind in kinds]
+        rope_tables = jnp.asarray(np.stack(
+            [rope_frequencies(cfg.head_dim, theta=theta, scaling=scaling) for theta, scaling in ropes]))
+        factors = [rope_attention_factor(scaling) ** 2 for _, scaling in ropes]
+        which = [kinds.index(kind) for kind in cfg.layer_types]
+        layer_kinds = {
+            "window": jnp.asarray([w or NO_WINDOW for w in cfg.layer_windows()], jnp.int32),
+            "rope": jnp.asarray(which, jnp.int32),
+            "mscale": jnp.asarray([factors[i] for i in which], jnp.float32),
+        }
+
     mla = cfg.attn_type == "mla"
     if mla:
         inv_freq_mla = jnp.asarray(
@@ -380,6 +403,9 @@ def forward(
     def make_layer_step(moe_layer: bool):
         def layer_step(carry, lp):
             x, k_full, v_full, li = carry
+            kind = None
+            if layer_kinds is not None:
+                lp, kind = lp
             if moe_layer:
                 lp = join_expert_stack(lp, expert_stack, li - n_dense)
             h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
@@ -421,10 +447,14 @@ def forward(
                     # slots, masking, and lengths keep the sequential positions.
                     q = apply_mrope(q, mrope_positions, inv_freq, cfg.mrope_section)
                     k = apply_mrope(k, mrope_positions, inv_freq, cfg.mrope_section)
+                elif kind is not None:  # this layer's own RoPE, and its YaRN factor (1 if plain)
+                    q = apply_rope(q, positions, rope_tables[kind["rope"]])
+                    k = apply_rope(k, positions, rope_tables[kind["rope"]])
+                    q = q * kind["mscale"].astype(q.dtype)
                 else:
                     q = apply_rope(q, positions, inv_freq)
                     k = apply_rope(k, positions, inv_freq)
-                if attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
+                if kind is None and attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
                     q = q * jnp.asarray(attn_mscale, q.dtype)
                 k_full, v_full = write_kv(k_full, v_full, k, v, slot_mapping + li * (npages * ps))
                 if ring:
@@ -433,13 +463,10 @@ def forward(
                     attn = ring_attention(q, k, v, ring_pos, mesh, scale=cfg.head_dim**-0.5)
                 else:
                     tables_l = block_tables + li * npages
-                    if cfg.sliding_window > 0:
-                        attn = paged_attention(
-                            q, k_full, v_full, tables_l, positions,
-                            impl=attn_impl, sliding_window=cfg.sliding_window,
-                            contiguous_positions=contiguous_positions,
-                        )
-                    elif attn_impl == "pallas" and mesh is not None:
+                    # 0 = full causal; a mixed model hands each layer its own
+                    # (a runtime scalar: NO_WINDOW in its full layers).
+                    window = cfg.sliding_window if kind is None else kind["window"]
+                    if attn_impl == "pallas" and mesh is not None:
                         # Explicit tp/dp layout around the kernel: GSPMD would
                         # otherwise all-gather the cache and replicate the
                         # pallas_call on every device.
@@ -447,11 +474,12 @@ def forward(
 
                         attn = paged_attention_sharded(
                             q, k_full, v_full, tables_l, positions,
-                            mesh=mesh, impl=attn_impl,
+                            mesh=mesh, impl=attn_impl, sliding_window=window,
                             contiguous_positions=contiguous_positions,
                         )
                     else:
                         attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
+                                               sliding_window=window,
                                                contiguous_positions=contiguous_positions)
                 x = x + _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
             h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
@@ -467,12 +495,18 @@ def forward(
     # (first_k_dense_replace) run two scans — dense layers first — with the
     # layer counter (cache offsets) carried straight through.
     carry = (x, kf0, vf0, jnp.int32(0))
+
+    def scanned(layers, lo: int, hi: int):
+        if layer_kinds is None:
+            return layers
+        return layers, {name: v[lo:hi] for name, v in layer_kinds.items()}
+
     if "dense_layers" in params:
-        carry, _ = jax.lax.scan(make_layer_step(False), carry, params["dense_layers"])
+        carry, _ = jax.lax.scan(make_layer_step(False), carry, scanned(params["dense_layers"], 0, n_dense))
     (x, k_out, v_out, _), _ = jax.lax.scan(
         make_layer_step(cfg.is_moe),
         carry,
-        moe_layers,
+        scanned(moe_layers, n_dense, cfg.num_layers),
     )
     k_out = k_out.reshape(k_cache.shape)
     v_out = v_out.reshape(v_cache.shape)
@@ -518,6 +552,8 @@ def encode(
     (`lib/llm/src/http/service/openai.rs:580`, `engines.rs:321`).
     """
     b, t = tokens.shape
+    if cfg.mixed_attention:
+        raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE)")
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, theta=cfg.rope_theta, scaling=cfg.rope_scaling))
     attn_mscale = rope_attention_factor(cfg.rope_scaling) ** 2

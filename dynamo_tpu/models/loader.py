@@ -558,6 +558,11 @@ def load_params(
 # ---------------------------------------------------------------------------
 
 
+#: Architectures ``ModelConfig.from_hf`` reads whose checkpoint weight names
+#: have no map in ``_leaf_specs`` (no published list of them to work from).
+UNMAPPED_MODEL_TYPES = frozenset({"mellum"})
+
+
 def load_model(
     model_dir: str | pathlib.Path,
     *,
@@ -567,6 +572,12 @@ def load_model(
 ) -> tuple[ModelConfig, Params]:
     """Resolve an HF model directory: config.json -> ModelConfig, weights -> pytree."""
     p = pathlib.Path(model_dir)
+    model_type = json.loads((p / "config.json").read_text()).get("model_type")
+    if model_type in UNMAPPED_MODEL_TYPES:
+        raise ValueError(
+            f"model_type {model_type!r}: the architecture is served (ModelConfig.from_hf, random or "
+            f"benchmark-made weights) but its checkpoint's tensor names are not mapped here; "
+            f"refusing to guess them")
     cfg = ModelConfig.from_hf(p / "config.json", name=name or p.name)
     if dtype is not None:
         import dataclasses

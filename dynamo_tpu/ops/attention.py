@@ -45,6 +45,11 @@ import jax.numpy as jnp
 NEG_INF = -1e30  # large-but-finite: avoids NaN from (-inf) - (-inf) in masked softmax
 
 
+def is_windowed(sliding_window) -> bool:
+    """0 (or less) = full causal; a positive int or a traced i32 scalar bounds the keys."""
+    return not isinstance(sliding_window, int) or sliding_window > 0
+
+
 def gather_pages(cache: jnp.ndarray, block_tables: jnp.ndarray, n_kv: int) -> jnp.ndarray:
     """Gather per-sequence K or V: [pages, ps, W] x [B, N] -> [B, N*ps, kv, hd].
 
@@ -65,7 +70,7 @@ def paged_attention_reference(
     positions: jnp.ndarray,  # i32[B, T] absolute position of each query token
     *,
     scale: float | None = None,
-    sliding_window: int = 0,  # >0: keys older than q_pos - (w-1) are masked
+    sliding_window=0,  # >0: keys older than q_pos - (w-1) are masked (an int, or a traced i32 scalar)
 ) -> jnp.ndarray:
     """Causal paged attention; returns [B, T, n_heads, head_dim].
 
@@ -93,7 +98,7 @@ def paged_attention_reference(
     logits = jnp.einsum("btkgd,bskd->bkgts", qg, k, preferred_element_type=jnp.float32)
     key_pos = jnp.arange(s, dtype=jnp.int32)
     mask = key_pos[None, None, :] <= positions[:, :, None]  # [B, T, S]
-    if sliding_window > 0:
+    if is_windowed(sliding_window):
         # HF window semantics: a query at p attends to keys in
         # [p - (w - 1), p] — the page pool still HOLDS older pages (parity
         # with vLLM's non-rolled paged SWA); masking alone preserves exact
@@ -154,9 +159,16 @@ def paged_attention(
     scale: float | None = None,
     impl: str | None = None,
     contiguous_positions: bool = True,
-    sliding_window: int = 0,
+    sliding_window=0,
 ) -> jnp.ndarray:
     """Backend-dispatching paged attention (see module docstring).
+
+    ``sliding_window``: 0 = full causal; a positive int, or a traced i32
+    scalar (a layer scan that carries one window per layer, with
+    ``pallas_paged.NO_WINDOW`` for its full layers), bounds each query to its
+    last ``sliding_window`` positions. Windowed calls take the same kernels
+    under the same support predicates as full ones; the kernels skip the page
+    blocks wholly under the window.
 
     ``contiguous_positions`` declares that every real row of ``positions``
     steps by exactly 1 (engine prefill, chunked or not). Callers with gappy
@@ -171,18 +183,7 @@ def paged_attention(
         scale = q.shape[-1] ** -0.5
     if impl is None:
         impl = default_impl()
-    if impl == "reference" or sliding_window > 0:
-        if sliding_window > 0 and impl == "pallas":
-            # Make the downgrade VISIBLE: an operator asking for the kernel
-            # gets the reference formulation until a windowed kernel
-            # variant exists (counted + one-time warned like every other
-            # kernel fallback; exported at /metrics).
-            from dynamo_tpu.ops.pallas_paged import _record_fallback
-
-            _record_fallback("sliding_window", q, k_cache)
-        # SWA uses the reference formulation: the Pallas kernels derive
-        # causality from block walks that assume a full prefix (windowed
-        # block skipping is a future kernel variant).
+    if impl == "reference":
         return paged_attention_reference(
             q, k_cache, v_cache, block_tables, positions, scale=scale,
             sliding_window=sliding_window,
@@ -192,6 +193,7 @@ def paged_attention(
     return paged_attention_pallas(
         q, k_cache, v_cache, block_tables, positions, scale=scale,
         contiguous_positions=contiguous_positions,
+        window=sliding_window if is_windowed(sliding_window) else None,
     )
 
 
@@ -206,6 +208,7 @@ def paged_attention_sharded(
     scale: float | None = None,
     impl: str | None = None,
     contiguous_positions: bool = True,
+    sliding_window=0,
 ) -> jnp.ndarray:
     """Paged attention under a device mesh: tp shards heads, dp the batch.
 
@@ -233,16 +236,21 @@ def paged_attention_sharded(
     cache_spec = P(None, None, tp_axis)
     row_spec = P(batch_axis, None)
 
-    def body(q, kc, vc, bt, pos):
+    # A window rides in as a replicated operand: under a layer scan it is a
+    # traced per-layer scalar, which a closure over shard_map may not capture.
+    extra = (jnp.asarray(sliding_window, jnp.int32).reshape(1),) if is_windowed(sliding_window) else ()
+
+    def body(q, kc, vc, bt, pos, *w):
         return paged_attention(q, kc, vc, bt, pos, scale=scale, impl=impl,
-                               contiguous_positions=contiguous_positions)
+                               contiguous_positions=contiguous_positions,
+                               sliding_window=w[0][0] if w else 0)
 
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(q_spec, cache_spec, cache_spec, row_spec, row_spec),
+        in_specs=(q_spec, cache_spec, cache_spec, row_spec, row_spec) + (P(None),) * len(extra),
         out_specs=q_spec,
         # pallas_call's out_shape carries no vma metadata; the body has no
         # cross-device communication to check anyway (heads/batch are
         # embarrassingly parallel here).
         check_vma=False,
-    )(q, k_cache, v_cache, block_tables, positions)
+    )(q, k_cache, v_cache, block_tables, positions, *extra)

@@ -81,6 +81,10 @@ logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 LANES = 128
+#: A window value that bounds nothing (past every position; ``pos - NO_WINDOW``
+#: stays inside int32): a full-attention layer inside a scan that carries the
+#: window as a per-layer scalar.
+NO_WINDOW = 2**30
 
 # Kernel-fallback observability: a config typo (odd GQA grouping, a page
 # slab width off the 128-lane grid) silently costs ~5x decode throughput if
@@ -206,7 +210,7 @@ def _pages_per_block(
     return max(1, min(pages_per_seq, target))
 
 
-def _lse_combine(acc: jnp.ndarray, m: jnp.ndarray, l: jnp.ndarray) -> jnp.ndarray:
+def _lse_combine(acc: jnp.ndarray, m: jnp.ndarray, l: jnp.ndarray, *, guard_empty: bool = False) -> jnp.ndarray:
     """Merge per-split online-softmax partials along the split axis.
 
     ``acc`` f32[B, S, R, W] (unnormalized weighted values), ``m``/``l``
@@ -221,6 +225,12 @@ def _lse_combine(acc: jnp.ndarray, m: jnp.ndarray, l: jnp.ndarray) -> jnp.ndarra
     alpha = jnp.exp(m - m_max)  # [B, S, R]
     denom = jnp.sum(alpha * l, axis=1)  # [B, R]
     num = jnp.sum(acc * alpha[..., None], axis=1)  # [B, R, W]
+    if guard_empty:
+        # Windowed walks only: a padding query column (position 0) of a row
+        # whose walk starts past block 0 sees no key at all (l == 0 in every
+        # split). Its output is discarded, but 0/0 must not reach the cache
+        # through the null page.
+        denom = jnp.where(denom > 0.0, denom, 1.0)
     return num / denom[..., None]
 
 
@@ -229,19 +239,18 @@ def _decode_kernel(
     lengths_ref,  # i32[B] per-sequence walk length (max row position + 1)
     tables_ref,  # i32[B * pages_per_seq]
     qpos_ref,  # i32[B * t_q] absolute position of each query token
-    # blocked operands
-    q_ref,  # [t_q * n_heads, W] block-diagonal queries, W = n_kv * head_dim
-    k_hbm,  # [P, page_size, W] in HBM/ANY (page-major, heads flattened)
-    v_hbm,
-    acc_ref,  # f32[t_q * n_heads, W] — this (b, split)'s partial strip
-    m_ref,  # f32[t_q * n_heads, LANES] — running max (broadcast on lanes)
-    l_ref,  # f32[t_q * n_heads, LANES] — running normalizer
-    # scratch
-    k_buf,  # [dma_depth, block_tokens, W] VMEM ring
-    v_buf,
-    k_sem,  # DMA sems [dma_depth]
-    v_sem,
-    *,
+    *refs,
+    # windowed only, two more prefetched scalars first:
+    #   first_ref i32[B] smallest real query position of the row
+    #   window_ref i32[1] the window in tokens
+    # then, always, the blocked operands and the scratch:
+    #   q_ref [t_q * n_heads, W] block-diagonal queries, W = n_kv * head_dim
+    #   k_hbm, v_hbm [P, page_size, W] in HBM/ANY (page-major, heads flattened)
+    #   acc_ref f32[t_q * n_heads, W] — this (b, split)'s partial strip
+    #   m_ref, l_ref f32[t_q * n_heads, LANES] — running max / normalizer
+    #   k_buf, v_buf [dma_depth, block_tokens, W] VMEM ring
+    #   k_sem, v_sem DMA sems [dma_depth]
+    windowed: bool = False,
     batch: int,
     pages_per_seq: int,
     pages_per_block: int,
@@ -251,27 +260,51 @@ def _decode_kernel(
     n_heads: int,
     dma_depth: int,
 ):
+    if windowed:
+        first_ref, window_ref, *refs = refs
+        window = window_ref[0]
+    q_ref, k_hbm, v_hbm, acc_ref, m_ref, l_ref, k_buf, v_buf, k_sem, v_sem = refs
     b = pl.program_id(0)
     sp = pl.program_id(1)
     bk = pages_per_block * page_size  # tokens per compute block
 
-    def blocks_of(bb):
+    def end_of(bb):  # one past the row's last block
         return pl.cdiv(jnp.maximum(lengths_ref[bb], 1), bk)
 
-    nb_total = blocks_of(b)
+    def lo_of(bb):
+        # Windowed: the walk starts at the block that holds the oldest key
+        # any real query of the row may see (first - window + 1); blocks
+        # wholly under the window are never fetched. lo < end always (the
+        # row's first query position is below its length).
+        if not windowed:
+            return 0
+        return jnp.maximum(first_ref[bb] - window + 1, 0) // bk
+
+    def blocks_of(bb):  # blocks the row's walk visits
+        return end_of(bb) - lo_of(bb)
+
+    nb_total = end_of(b)
     # Split sp walks block-in-sequence indices [first, first + nb_here).
     # Boundaries derive from the STATIC blocks_per_split, so a row's
-    # accumulation order never depends on other rows' runtime lengths.
+    # accumulation order never depends on other rows' runtime lengths (a
+    # windowed row's blocks simply fall in its last splits).
     first = sp * blocks_per_split
-    nb_here = jnp.clip(nb_total - first, 0, blocks_per_split)
+    if windowed:
+        lo = lo_of(b)
+        first = jnp.clip(first, lo, nb_total)
+        nb_here = jnp.clip(sp * blocks_per_split + blocks_per_split, lo, nb_total) - first
+        visited_before = first - lo
+    else:
+        nb_here = jnp.clip(nb_total - first, 0, blocks_per_split)
+        visited_before = jnp.minimum(first, nb_total)
 
     # Ring slot is a pure function of the global block index (no mutable
-    # cross-step state): blocks of earlier sequences plus earlier splits
-    # of this one. Splits partition each sequence's walk, so the global
-    # order is plain (sequence, block-in-sequence) lexicographic.
+    # cross-step state): VISITED blocks of earlier sequences plus of earlier
+    # splits of this one. Splits partition each sequence's walk, so the
+    # global order is plain (sequence, block-in-sequence) lexicographic.
     g0 = (
         jax.lax.fori_loop(0, b, lambda bb, acc: acc + blocks_of(bb), jnp.int32(0))
-        + jnp.minimum(first, nb_total)
+        + visited_before
     )
 
     def page_index(bb, ii, j):
@@ -308,11 +341,11 @@ def _decode_kernel(
 
     def next_block(bb, ii):
         """Global-order successor of block (bb, ii): the sequence's next
-        block, else the next sequence's block 0. bb may walk past the last
+        block, else the next sequence's first visited block. bb may walk past the last
         sequence — start_ahead guards on bb < batch before dereferencing."""
-        advance = ii + 1 >= blocks_of(jnp.minimum(bb, batch - 1))
+        advance = ii + 1 >= end_of(jnp.minimum(bb, batch - 1))
         nb = jnp.where(advance, bb + 1, bb)
-        ni = jnp.where(advance, 0, ii + 1)
+        ni = jnp.where(advance, lo_of(jnp.minimum(bb + 1, batch - 1)), ii + 1)
         return nb, ni
 
     def start_ahead(slot, bb, ii):
@@ -326,7 +359,7 @@ def _decode_kernel(
     # indices, so the lookahead chain passes through them untouched).
     @pl.when(jnp.logical_and(b == 0, sp == 0))
     def _():
-        bb, ii = jnp.int32(0), jnp.int32(0)
+        bb, ii = jnp.int32(0), jnp.int32(0) + lo_of(0)
         for g in range(dma_depth - 1):
             start_ahead(g % dma_depth, bb, ii)
             bb, ii = next_block(bb, ii)
@@ -372,6 +405,8 @@ def _decode_kernel(
         )  # f32[R, bk]
         kpos = ii * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos <= qpos  # per-row causal horizon
+        if windowed:
+            mask = jnp.logical_and(mask, kpos > qpos - window)
         s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))  # [R, 1]
         # Mask p explicitly: in an all-masked block s == m_new == NEG_INF
@@ -445,8 +480,16 @@ def paged_decode_attention(
     scale: float,
     interpret: bool = False,
     num_splits: int = 0,  # 0 = auto (_auto_num_splits / DYN_DECODE_SPLITS)
+    window=None,  # i32 scalar (runtime value): keys older than pos - (window - 1) are not read
 ) -> jnp.ndarray:
     """Decode/verify paged attention; returns [B, T_q, n_heads, hd].
+
+    ``window`` (None = full causal, today's program unchanged) is a runtime
+    scalar, so one compiled program serves layers of different windows under
+    a layer scan; a value past every position (``NO_WINDOW``) is full
+    attention. A windowed row's block walk starts at the block that holds
+    ``first query position - window + 1`` and the in-block mask adds
+    ``kpos > position - window``: blocks under the window cost no DMA.
 
     Positions may be gappy per row (speculative verify batches, padding
     columns) — causality is per query token. Cache layout matches the
@@ -487,6 +530,16 @@ def paged_decode_attention(
         "btkgd,kK->btkgKd", q5.reshape(b, t_q, n_kv, group, head_dim), eye
     ).reshape(b, r_rows, width).astype(q_dtype)
 
+    windowed = window is not None
+    prefetch = [lengths, block_tables.reshape(-1), positions.reshape(-1)]
+    if windowed:
+        # The row's oldest real query: padding columns carry position 0 and
+        # trail the real ones, so column 0 and the non-zero entries are the
+        # candidates (a padding row is all zeros: it walks from block 0).
+        first_pos = jnp.minimum(
+            positions[:, 0], jnp.min(jnp.where(positions > 0, positions, jnp.iinfo(jnp.int32).max), axis=1))
+        prefetch += [first_pos, jnp.asarray(window, jnp.int32).reshape(1)]
+
     q_spec = pl.BlockSpec((None, r_rows, width), lambda bb, ss, *_: (bb, 0, 0))
     acc_spec = pl.BlockSpec((None, None, r_rows, width), lambda bb, ss, *_: (bb, ss, 0, 0))
     ml_spec = pl.BlockSpec((None, None, r_rows, LANES), lambda bb, ss, *_: (bb, ss, 0, 0))
@@ -500,11 +553,13 @@ def paged_decode_attention(
         t_q=t_q,
         n_heads=n_heads,
         dma_depth=depth,
+        windowed=windowed,
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # lengths, flat block table, query positions
+            # lengths, flat block table, query positions (+ first position, window)
+            num_scalar_prefetch=len(prefetch),
             grid=(b, splits),
             in_specs=[
                 q_spec,
@@ -528,15 +583,8 @@ def paged_decode_attention(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(
-        lengths,
-        block_tables.reshape(-1),
-        positions.reshape(-1),
-        q_bd,
-        kf,
-        vf,
-    )
-    out = _lse_combine(acc, m[..., 0], l[..., 0])  # [B, R, W]
+    )(*prefetch, q_bd, kf, vf)
+    out = _lse_combine(acc, m[..., 0], l[..., 0], guard_empty=windowed)  # [B, R, W]
     # Extract each row's diagonal strip: row (t, kv*G+g) reads lanes
     # [kv*hd, (kv+1)*hd). Fused einsum against the same eye.
     o6 = out.reshape(b, t_q, n_kv, group, n_kv, head_dim)
@@ -553,6 +601,7 @@ def paged_attention_pallas(
     *,
     scale: float,
     contiguous_positions: bool = True,
+    window=None,  # runtime i32 scalar, None = full causal (see paged_decode_attention)
 ) -> jnp.ndarray:
     """TPU dispatch: decode kernel for T == 1, prefill flash kernel for
     contiguous T > 1, the same decode kernel in multi-query form for gappy
@@ -599,7 +648,7 @@ def paged_attention_pallas(
         if decode_supported(q, k_cache, interpret=interpret):
             return paged_decode_attention(
                 q, k_cache, v_cache, block_tables, positions, scale=scale,
-                interpret=interpret,
+                interpret=interpret, window=window,
             )
         _record_fallback("decode", q, k_cache)
     elif not contiguous_positions:
@@ -610,7 +659,7 @@ def paged_attention_pallas(
         if decode_supported(q, k_cache, interpret=interpret):
             return paged_decode_attention(
                 q, k_cache, v_cache, block_tables, positions, scale=scale,
-                interpret=interpret,
+                interpret=interpret, window=window,
             )
         _record_fallback("verify", q, k_cache)
     else:
@@ -622,9 +671,14 @@ def paged_attention_pallas(
         if prefill_supported(q, k_cache):
             return paged_prefill_attention(
                 q, k_cache, v_cache, block_tables, positions, scale=scale,
-                interpret=interpret,
+                interpret=interpret, window=window,
             )
         _record_fallback("prefill", q, k_cache)
+    if window is not None:
+        # A windowed call the kernels refuse: counted under its own phase too,
+        # as before the kernels took windows.
+        _record_fallback("sliding_window", q, k_cache)
     return paged_attention_reference(
-        q, k_cache, v_cache, block_tables, positions, scale=scale
+        q, k_cache, v_cache, block_tables, positions, scale=scale,
+        sliding_window=0 if window is None else window,
     )

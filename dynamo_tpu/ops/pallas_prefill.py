@@ -92,23 +92,24 @@ def _prefill_kernel(
     kv_lens_ref,  # i32[B] attendable keys per row (chunk included; >= 1)
     starts_ref,  # i32[B] absolute position of the row's first query token
     tables_ref,  # i32[B * pages_per_seq]
-    # blocked operands
-    q_ref,  # [n_kv, tq * g, hd] pre-scaled, cache dtype
-    k_hbm,  # [P, page_size, W] in HBM/ANY
-    v_hbm,
-    o_ref,  # f32[n_kv, tq * g, hd]
-    # scratch
-    k_buf,  # [2, bk, W] VMEM
-    v_buf,
-    k_sem,
-    v_sem,
-    *,
+    *refs,
+    # windowed only, one more prefetched scalar first: window_ref i32[1];
+    # then, always, the blocked operands and the scratch:
+    #   q_ref [n_kv, tq * g, hd] pre-scaled, cache dtype
+    #   k_hbm, v_hbm [P, page_size, W] in HBM/ANY
+    #   o_ref f32[n_kv, tq * g, hd]
+    #   k_buf, v_buf [2, bk, W] VMEM; k_sem, v_sem
+    windowed: bool = False,
     tq: int,
     group: int,
     pages_per_seq: int,
     pages_per_block: int,
     page_size: int,
 ):
+    if windowed:
+        window_ref, *refs = refs
+        window = window_ref[0]
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem = refs
     b = pl.program_id(0)
     qi = pl.program_id(1)
     bk = pages_per_block * page_size
@@ -124,7 +125,12 @@ def _prefill_kernel(
     # to compute — and nothing to DMA (each skipped block saves the full
     # KV walk up to kv_len).
     has_work = start + qi * tq < kv_len
-    num_blocks = jnp.where(has_work, pl.cdiv(kend, bk), 0)
+    end_block = pl.cdiv(kend, bk)
+    # Windowed: this query block's first token sees no key older than
+    # start + qi*tq - window + 1, so the walk begins at that key's block and
+    # the blocks wholly under the window are never fetched.
+    first_block = jnp.maximum(start + qi * tq - window + 1, 0) // bk if windowed else 0
+    num_blocks = jnp.where(has_work, end_block - first_block, 0)
     # Clamp page lookups to the row's own used range (not just the table
     # width) so sentinel-filled table tails can never be dereferenced.
     last_page = jnp.maximum(kv_len - 1, 0) // page_size
@@ -159,7 +165,7 @@ def _prefill_kernel(
     # never be waited here and would alias the next grid step's wait.
     @pl.when(num_blocks > 0)
     def _():
-        start_block(0, 0)
+        start_block(0, first_block)
 
     n_kv, rows, hd = q_ref.shape
     q_all = q_ref[...]  # [n_kv, tq*g, hd] pre-scaled, cache dtype
@@ -171,12 +177,13 @@ def _prefill_kernel(
         + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
     )  # [rows, 1]
 
-    def body(i, carry):
+    def body(n, carry):
         # carry: per-KV-head (m [rows,1], l [rows,1], acc [rows,hd]) tuples —
         # a flat pytree, because Mosaic has no scatter for stacked updates.
-        cur = i % 2
+        cur = n % 2
+        i = first_block + n  # block-in-sequence index of the n-th visited block
 
-        @pl.when(i + 1 < num_blocks)
+        @pl.when(n + 1 < num_blocks)
         def _():
             start_block(1 - cur, i + 1)
 
@@ -188,6 +195,8 @@ def _prefill_kernel(
             v = v.astype(jnp.bfloat16)
         kpos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)  # [1, bk]
         mask = jnp.logical_and(kpos <= qpos, kpos < kv_len)  # [rows, bk]
+        if windowed:
+            mask = jnp.logical_and(mask, kpos > qpos - window)
 
         out = []
         for kv in range(n_kv):
@@ -201,6 +210,11 @@ def _prefill_kernel(
             s = jnp.where(mask, s, NEG_INF)
             mk = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - mk)
+            if windowed:
+                # A later row of the query block can have the whole first
+                # visited block under ITS window (s == mk == NEG_INF there,
+                # exp gives 1): select the masked entries out explicitly.
+                p = jnp.where(mask, p, 0.0)
             alpha = jnp.exp(m - mk)
             lk = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
             ak = alpha * acc + jax.lax.dot_general(
@@ -247,8 +261,14 @@ def paged_prefill_attention(
     *,
     scale: float,
     interpret: bool = False,
+    window=None,  # i32 scalar (runtime value); None = full causal, today's program
 ) -> jnp.ndarray:
     """Prefill-phase (T > 1) paged flash attention; returns [B, T, H, hd].
+
+    ``window``: a query at p attends keys in ``(p - window, p]``; each query
+    block's walk starts at the block holding its first token's oldest key.
+    A runtime scalar, so a layer scan can carry one per layer
+    (``pallas_paged.NO_WINDOW`` = full attention).
 
     ``positions`` rows must be contiguous (``positions[b, t] = start_b + t``
     for real tokens) — true for every engine prefill row, chunked or not,
@@ -281,8 +301,13 @@ def paged_prefill_attention(
     q_spec = pl.BlockSpec(
         (None, n_kv, rows, head_dim), lambda bb, qq, *_: (bb, 0, qq, 0)
     )
+    windowed = window is not None
+    prefetch = [kv_lens, starts, block_tables.reshape(-1)]
+    if windowed:
+        prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
     kernel = functools.partial(
         _prefill_kernel,
+        windowed=windowed,
         tq=tq,
         group=group,
         pages_per_seq=pages_per_seq,
@@ -292,7 +317,7 @@ def paged_prefill_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # kv_lens, starts, flat block table
+            num_scalar_prefetch=len(prefetch),  # kv_lens, starts, flat block table (+ window)
             grid=(b, qb),
             in_specs=[
                 q_spec,
@@ -312,13 +337,6 @@ def paged_prefill_attention(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(
-        kv_lens,
-        starts,
-        block_tables.reshape(-1),
-        qs,
-        k_cache,
-        v_cache,
-    )
+    )(*prefetch, qs, k_cache, v_cache)
     o = out.reshape(b, n_kv, t, group, head_dim).transpose(0, 2, 1, 3, 4)
     return o.reshape(b, t, n_heads, head_dim).astype(q.dtype)

@@ -100,6 +100,42 @@ def test_paged_prefill_kernel_compiles(sds, preset, page):
     assert "tpu_custom_call" in text
 
 
+#: Mellum2-12B-A2.5B's attention widths (benchmark/configs/mellum2-12b-a2.5b-int8.json):
+#: 32 query and 4 KV heads of 128 (a 512-lane page slab), page 128, window 1024.
+MELLUM2 = dict(heads=32, kv_heads=4, head_dim=128, page=128)
+
+
+def _mellum2_operands(sds, batch: int, t_q: int, context: int):
+    m = MELLUM2
+    pages_per_seq = context // m["page"]
+    cache = sds((batch * pages_per_seq + 1, m["page"], m["kv_heads"] * m["head_dim"]), jnp.bfloat16)
+    return (sds((batch, t_q, m["heads"], m["head_dim"]), jnp.bfloat16), cache, cache,
+            sds((batch, pages_per_seq), jnp.int32), sds((batch, t_q), jnp.int32))
+
+
+@pytest.mark.parametrize("t_q", [1, 5], ids=["decode", "verify5"])
+def test_windowed_decode_kernel_compiles(sds, t_q):
+    """The windowed block walk (window a runtime scalar: two more prefetched
+    operands) at 8 rows x 8,192 tokens of context."""
+    from dynamo_tpu.ops.pallas_paged import paged_decode_attention
+
+    text = _compiled_text(
+        lambda *a: paged_decode_attention(*a, scale=MELLUM2["head_dim"] ** -0.5, window=a[-1][0, 0] * 0 + 1024),
+        *_mellum2_operands(sds, batch=8, t_q=t_q, context=8192))
+    assert "tpu_custom_call" in text
+
+
+def test_windowed_prefill_kernel_compiles(sds):
+    """A 64-token chunk (the benchmark's pinned chunk) per row of a mixed
+    step, the window a runtime scalar."""
+    from dynamo_tpu.ops.pallas_prefill import paged_prefill_attention
+
+    text = _compiled_text(
+        lambda *a: paged_prefill_attention(*a, scale=MELLUM2["head_dim"] ** -0.5, window=a[-1][0, 0] * 0 + 1024),
+        *_mellum2_operands(sds, batch=8, t_q=64, context=8192))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("t_q", [1, 5], ids=["decode", "verify5"])
 def test_mla_decode_kernel_compiles(sds, t_q):
     from dynamo_tpu.models.mla import mla_cache_widths
@@ -218,3 +254,41 @@ def test_olmoe_int8_step_moves_no_expert_array_outside_the_kernel(sds, kernel, m
         assert not sliced, sliced[:2]
     else:
         assert widened and "moe_grouped_matmul_int8" not in text
+
+
+@pytest.mark.parametrize("t", [1, 64], ids=["decode", "mixed-chunk"])
+def test_mixed_layer_step_is_one_layer_body_on_the_kernels(sds, t, monkeypatch):
+    """Four layers (one period: sliding, sliding, sliding, full) of Mellum2 at
+    its published widths, int8, 8 rows over 64 pages of 128: the scan that
+    carries the per-layer window and RoPE compiles to ONE attention kernel and
+    the two expert kernels (one layer body for both kinds), and no operation
+    but those touches a layer's experts."""
+    import functools
+    import json
+    import pathlib
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+
+    doc = json.loads((pathlib.Path(__file__).parents[1] / "benchmark/configs/mellum2-12b-a2.5b-int8.json").read_text())
+    hf = {k: v for k, v in doc.items() if k not in ("serve", "rehearsal", "assumed", "reduced_why")}
+    hf.update(num_hidden_layers=4, layer_types=hf["layer_types"][:4], mlp_layer_types=hf["mlp_layer_types"][:4])
+    cfg = ModelConfig.from_hf(hf, name="mellum2-one-period")
+    assert cfg.mixed_attention
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    rows, page, pages_per_seq = 8, 128, 64
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 321, page)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    text = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas")).lower(
+        params=params, tokens=i32(rows, t), positions=i32(rows, t), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(rows, pages_per_seq), slot_mapping=i32(rows, t), last_token_index=i32(rows),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert ("paged_decode_attention" if t == 1 else "paged_prefill_attention") in text
+    assert text.count("moe_grouped_matmul_int8") >= 2
+    for shape in ("bf16[64,2304,896]", "bf16[64,896,2304]", "s8[1,64,2304,896]", "s8[1,64,896,2304]"):
+        assert shape not in text, shape

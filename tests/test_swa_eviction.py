@@ -112,3 +112,20 @@ def test_released_pages_evictable_while_stream_still_running():
     assert core.num_preemptions == 0, "demoted pages should satisfy admission"
     ctrl = drive(swa_free=False)
     assert ctrl.num_preemptions > 0, "control must actually be page-starved"
+
+
+def test_mixed_window_and_full_layers_release_nothing():
+    """One page-id space for all layers: while any layer reads the whole
+    context, no page is handed back, whatever the sliding layers' window (the
+    uniform-window model above still releases)."""
+    from dynamo_tpu.models.config import FULL, SLIDING
+
+    mixed = dataclasses.replace(CFG, layer_types=(SLIDING, FULL))
+    runner = ModelRunner(mixed, PARAMS, num_pages=64, page_size=PAGE, max_batch_size=2,
+                         prefill_bucket=16, attn_impl="reference")
+    core = EngineCore(runner, EngineConfig(num_pages=64, page_size=PAGE, max_batch_size=2, max_prefill_tokens=64,
+                                           max_seq_len=128, decode_steps=2, swa_free_pages=True))
+    toks, _seq, (live, zeros) = _generate(core)
+    assert len(toks) == 40 and max(zeros) == 0 and live[-1] >= (8 + 40) // PAGE - 1
+    _toks, _s, (_live, uniform_zeros) = _generate(_core(swa_free=True))
+    assert max(uniform_zeros) > 0

@@ -1,0 +1,153 @@
+#!/usr/bin/env python3
+"""The outputs check of a windowed configuration at lengths that cross its
+window, and the controls that show the check can see the window and the RoPEs.
+
+    python3 tools/mellum2_long_check.py [--workload <cell>] --seeds 3 --variants long,fp8kv,int4w
+
+``benchmark/correct.py`` checks prompts of 192 and 128 tokens, which never
+reach a window of 1024, and that file is the benchmark's. This script uses the
+benchmark's own ``run.bring_up`` and ``correct`` in its own process with
+``correct.CHECKED`` set here to prompts of 2304 and 1536 tokens (``long``):
+the live engine prefills them in 64-token chunks beside a decoding row and
+decodes 4 tokens through the cache; the float32 reference scores the same
+tokens. The same served sample is then scored against the reference made
+wrong in two ways, which must come out over the limit:
+
+- ``all_full``: the reference's sliding layers made full (window past every position);
+- ``one_rope``: the sliding layers' plain RoPE in the full layers too.
+
+``fp8kv`` and ``int4w`` are the two lower-precision controls of
+``benchmark/control.py`` at the benchmark's own lengths (its int4 re-coding
+keeps the int8 tree until the int4 one is whole, which a 12 GB model does not
+fit beside; here each leaf goes as soon as it is re-coded). The KV pool is cut
+to ``--pool-tokens`` in this process only, to leave room for the reference at
+2,308 tokens. Run by hand on the chip; ``JAX_PLATFORMS=cpu`` rehearses at the
+configuration's toy size with lengths cut by its check scale.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import gc
+import json
+import os
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(ROOT / "benchmark"))
+
+import run as bench_run  # noqa: E402
+
+LONG = [(2304, 4), (1536, 4)]
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+def _free(*trees) -> None:
+    import jax
+
+    for leaf in jax.tree.leaves(trees):
+        if not leaf.is_deleted():
+            leaf.delete()
+    gc.collect()
+
+
+def _to_int4_leaf_by_leaf(params):
+    """``weights.requantize_int4`` one matmul leaf at a time, the int8 codes
+    deleted as soon as their int4 form exists."""
+    from benchmark import weights
+
+    def walk(t):
+        if isinstance(t, dict) and "qw" in t:
+            low = weights.requantize_int4({"x": t})["x"]
+            _free(t)
+            return low
+        return {k: walk(v) for k, v in t.items()} if isinstance(t, dict) else t
+
+    return walk(params)
+
+
+def reference_variants(conf: dict) -> dict:
+    hf = conf["hf"]
+    one_rope = {**hf, "rope_parameters": {k: hf["rope_parameters"][SLIDING] for k in (SLIDING, FULL)}}
+    return {"sound": conf, "all_full": {**conf, "hf": {**hf, "sliding_window": 2**30}},
+            "one_rope": {**conf, "hf": one_rope}}
+
+
+async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> list[dict]:
+    from benchmark import correct, serving, weights
+
+    args.seed = seed
+    os.environ.pop("DYN_KV_CACHE_DTYPE", None)
+    if variant == "fp8kv":
+        os.environ["DYN_KV_CACHE_DTYPE"] = "fp8"
+    correct.CHECKED = LONG if variant == "long" else [(192, 4), (128, 4)]
+    state = await bench_run.bring_up(args, bench, cell, rehearsal, warm=False,
+                                     transform=_to_int4_leaf_by_leaf if variant == "int4w" else None)
+    conf, core = state["conf"], state["core"]
+    try:
+        sample = await correct.serve_sample(state["service"], conf, seed, scale=state["check_scale"])
+        steps = core.flight.snapshot(kind="step")
+    finally:
+        await serving.stop(state["handles"])
+        os.environ.pop("DYN_KV_CACHE_DTYPE", None)
+    runner, params = core.runner, state["params"]
+    _free(runner.k_cache, runner.v_cache)  # room for the reference's whole-sequence pass
+    if variant == "int4w":  # the reference reads the weights as configured: make them again
+        _free(runner.params, params)
+        params = weights.make_weights(serving.model_config(conf), seed, quant=conf["serve"]["quant"])
+    rows = []
+    refs = reference_variants(conf) if variant == "long" else {"sound": conf}
+    for ref_name, ref_conf in refs.items():
+        check = correct.score(ref_conf, params, sample)
+        rows.append({"seed": seed, "variant": variant, "reference": ref_name,
+                     "prompts": [len(s) - n + 1 for s, (_, n) in zip(sample["sequences"], sample["spans"])],
+                     "mixed_steps": sum(1 for s in steps if s["step_kind"] == "mixed"),
+                     "decode_steps": sum(1 for s in steps if s["step_kind"] == "decode"),
+                     "attn_paths": sorted({s["attn_path"] for s in steps if s["attn_path"]}),
+                     "moe_paths": sorted({s["moe_path"] for s in steps if s["moe_path"]}), **check})
+        bench_run.say(long_check=rows[-1])
+    _free(runner.params, params)
+    return rows
+
+
+async def amain(args) -> int:
+    from benchmark import serving
+
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = next(w for w in bench["workloads"] if w["name"] == args.workload)
+    rehearsal = os.environ.get("JAX_PLATFORMS", "") == "cpu"
+    load = serving.load_config
+
+    def load_with_small_pool(path, *, rehearsal=False):
+        conf = load(path, rehearsal=rehearsal)
+        if not rehearsal:
+            conf["serve"]["engine"]["pool_tokens"] = args.pool_tokens
+        return conf
+
+    serving.load_config = load_with_small_pool  # this process only: no file of the benchmark is edited
+    rows = []
+    for variant in args.variants.split(","):
+        for i in range(args.seeds):
+            rows += await one(args, bench, cell, rehearsal, args.first_seed + 7919 * i, variant)
+    summary = {}
+    for key in sorted({(r["variant"], r["reference"]) for r in rows}):
+        errs = [r["rel_err"] for r in rows if (r["variant"], r["reference"]) == key]
+        summary["/".join(key)] = {"n": len(errs), "rel_err_min": min(errs), "rel_err_max": max(errs),
+                                  "limit": rows[0]["limit"], "ok": [r["ok"] for r in rows
+                                                                     if (r["variant"], r["reference"]) == key]}
+    print(json.dumps({"long_check_summary": summary}))
+    return 0
+
+
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--workload", default="mellum2-12b-a2.5b-int8.longctx-decode")
+    ap.add_argument("--seeds", type=int, default=3)
+    ap.add_argument("--first-seed", type=int, default=2600000003)
+    ap.add_argument("--variants", default="long,fp8kv,int4w")
+    ap.add_argument("--pool-tokens", type=int, default=12288)
+    os.environ.setdefault("DYN_FLIGHT_BUFFER", "65536")
+    sys.exit(asyncio.run(amain(ap.parse_args())))
