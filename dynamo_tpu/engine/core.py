@@ -46,7 +46,7 @@ from typing import Callable
 import numpy as np
 
 from dynamo_tpu.engine.allocator import OutOfPagesError, PageAllocator
-from dynamo_tpu.engine.runner import ModelRunner, StepBatch
+from dynamo_tpu.engine.runner import SPLIT, ModelRunner, StepBatch
 from dynamo_tpu.engine.sequence import SeqStatus, Sequence
 from dynamo_tpu.observability.flight import CRASH, STEP, FlightRecorder
 from dynamo_tpu.protocols.common import EngineOutput, FinishReason, PreprocessedRequest
@@ -259,6 +259,11 @@ class EngineCore:
         # while decodable sequences exist must also carry their decode rows.
         self.last_step_info: dict = {}
         self.mixed_steps = 0
+        # Dispatches that carried a chunk row, by the layout of their program:
+        # tokens on one axis, a position per decode row ("split"), or the
+        # rows x t rectangle that pads every row to the chunk.
+        self.chunk_steps_split = 0
+        self.chunk_steps_rows_x_t = 0
         self.stall_violations = 0  # prefill-only dispatches that starved decodes
         self._next_seq_id = 0
         self._eos = set(config.eos_token_ids)
@@ -702,6 +707,16 @@ class EngineCore:
             moe_path = getattr(self.runner, "moe_path", "") if attn else ""
             take_kv = getattr(self.runner, "take_kv_tokens", None)
             kv_full, kv_window = take_kv() if attn and take_kv else (0, 0)
+            # The token positions the dispatched program computed, and how it
+            # laid them out (consumed like the attention label).
+            layout, step_tokens = "", 0
+            if attn and getattr(self.runner, "last_step_layout", None):
+                layout, step_tokens = self.runner.last_step_layout
+                self.runner.last_step_layout = None
+                if chunk_rows and layout == SPLIT:
+                    self.chunk_steps_split += 1
+                elif chunk_rows:
+                    self.chunk_steps_rows_x_t += 1
             # Feed the chunk-budget controller only steps that carried decode
             # rows: their wall time is the ITL a running request observed.
             if self.chunk_controller is not None and decode_rows:
@@ -750,6 +765,8 @@ class EngineCore:
                 moe_path=moe_path,
                 kv_tokens_full=kv_full,
                 kv_tokens_window=kv_window,
+                step_tokens=int(step_tokens),
+                layout=layout,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
                 deadline_slack_ms=self.last_admission.get("deadline_slack_ms", 0.0),

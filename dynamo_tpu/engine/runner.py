@@ -119,6 +119,83 @@ def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int):
     )
 
 
+#: Most chunk slots a split step seats. A step's chunk budget goes to the
+#: prompt in progress first and what its tail leaves to the next prompt's head,
+#: so a step carries one chunk row or, at a prompt boundary, two; each count
+#: (1, 2) is a program of its own per (rows, chunk, pages) corner. More chunk
+#: rows (prompts shorter than half the budget, a prefill pool's many-prompt
+#: steps) keep the rectangle.
+MAX_CHUNK_SLOTS = 2
+
+ROWS_X_T, SPLIT = "rows_x_t", "split"  # a dispatch's layout, as the STEP record names it
+
+
+def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int) -> np.ndarray:
+    """One i32 buffer for a chunk step laid out on one token axis: a position
+    per decode slot (row i of ``padded`` keeps slot i, column 0), then ``tp``
+    per chunk slot (the rows ``chunk`` of ``padded``, in order). A decode slot
+    whose row moved to a chunk slot, and a chunk slot beyond the rows there
+    are, is padding: it reads and writes the null page."""
+    bp, tp = padded.tokens.shape
+    c = len(chunk)
+    src = np.zeros(bp + nc, np.intp)  # the row of ``padded`` behind each slot
+    src[:bp] = np.arange(bp)
+    src[bp: bp + c] = chunk
+    pad = np.zeros(bp + nc, bool)
+    pad[chunk] = True
+    pad[bp + c:] = True
+
+    def tokenwise(a):
+        out = np.zeros(bp + nc * tp, np.int32)
+        out[:bp] = a[:, 0]
+        out[chunk] = 0
+        out[bp: bp + c * tp] = a[chunk].ravel()
+        return out
+
+    def rowwise(a, fill=None):
+        out = a[src]
+        if fill is not None:
+            out[pad] = fill
+        return out.view(np.int32)
+
+    last = np.arange(bp + nc, dtype=np.int32)  # a decode slot's one token is its last
+    last[bp:] = bp + np.arange(nc, dtype=np.int32) * tp
+    last[bp: bp + c] += padded.last_token_index[chunk]
+    return np.concatenate(
+        [
+            tokenwise(padded.tokens),
+            tokenwise(padded.positions),
+            rowwise(padded.block_tables, fill=0).ravel(),
+            tokenwise(padded.slot_mapping),
+            last,
+            rowwise(padded.temperature),
+            rowwise(padded.top_k),
+            rowwise(padded.top_p),
+            rowwise(padded.seeds),
+            rowwise(padded.sample_steps),
+            rowwise(padded.freq_pen),
+            rowwise(padded.pres_pen),
+            rowwise(padded.pos_limit, fill=0),
+            rowwise(padded.history).ravel(),
+        ]
+    )
+
+
+def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int):
+    """In-graph inverse of :func:`_pack_split`: the token axis flat, one row
+    of block table and sampling fields per slot."""
+    toks, r = nd + nc * tc, nd + nc
+    sizes = [toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h]
+    offs = np.concatenate([[0], np.cumsum(sizes)])
+    part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
+    f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)  # noqa: E731
+    return (
+        part[0], part[1], part[2].reshape(r, n), part[3], part[4],
+        f32(part[5]), part[6], f32(part[7]), jax.lax.bitcast_convert_type(part[8], jnp.uint32),
+        part[9], f32(part[10]), f32(part[11]), part[12], part[13].reshape(r, h),
+    )
+
+
 def _apply_chain(tokens, history, sample_steps, chain_buf, chain_src):
     """Per-row device-resident token sourcing for a chained dispatch.
 
@@ -266,6 +343,13 @@ class ModelRunner:
         # "prefill" x "pallas"/"fallback"/"ring". The engine copies this
         # into its STEP flight records and dispatch-path counters.
         self.last_attn_dispatch: tuple[str, str] | None = None
+        # (layout, token positions the program computes) of the most recent
+        # dispatch: "rows_x_t" (the padded rectangle) or "split" (_chunk_rows).
+        self.last_step_layout: tuple[str, int] | None = None
+        # A chunk step may lay its tokens out on one axis where the model step
+        # has the flat path: GQA / MHA text models on one device (llama.forward).
+        self._can_split = (forward_fn is None and mesh is None and cfg.attn_type != "mla"
+                           and not cfg.mrope_section)
         # The most recent dispatch's padded batch, until its key-token counts
         # are taken (take_kv_tokens): counted after the enqueue, under the
         # device's shadow, never between a result and the next enqueue.
@@ -302,6 +386,31 @@ class ModelRunner:
 
         self.moe_path = experts_path(params.get("layers", {}), mesh=mesh)
 
+        def _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
+                    freq_pen, pres_pen, history, logit_mask, lp_k):
+            """The tail of a step program: one sampled token a row, with its
+            logprobs where asked."""
+            keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(seeds, sample_steps)
+            sample_logits = logits
+            if logit_mask is not None:
+                # Constrained decoding: disallowed tokens can never sample.
+                # Logprobs (below) stay on the RAW logits — they report the
+                # model's distribution, not the constrained one.
+                from dynamo_tpu.ops.attention import NEG_INF
+
+                sample_logits = jnp.where(logit_mask, logits, NEG_INF)
+            with jax.named_scope("sample"):
+                next_tokens = sample_tokens(
+                    sample_logits, keys, temperature, top_k, top_p,
+                    history=history, frequency_penalty=freq_pen, presence_penalty=pres_pen,
+                )
+            if lp_k:
+                from dynamo_tpu.ops.sampling import token_logprobs
+
+                chosen, top_ids, top_lps = token_logprobs(logits, next_tokens, lp_k)
+                return next_tokens, k_cache, v_cache, chosen, top_ids, top_lps
+            return next_tokens, k_cache, v_cache
+
         @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
         def _step(params, k_cache, v_cache, tokens, positions, block_tables, slot_mapping,
                   last_idx, temperature, top_k, top_p, seeds, sample_steps,
@@ -331,28 +440,28 @@ class ModelRunner:
                 block_tables, slot_mapping, last_idx, attn_impl=impl, mesh=self.mesh,
                 **mm_kw,
             )
-            keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(seeds, sample_steps)
-            sample_logits = logits
-            if logit_mask is not None:
-                # Constrained decoding: disallowed tokens can never sample.
-                # Logprobs (below) stay on the RAW logits — they report the
-                # model's distribution, not the constrained one.
-                from dynamo_tpu.ops.attention import NEG_INF
-
-                sample_logits = jnp.where(logit_mask, logits, NEG_INF)
-            with jax.named_scope("sample"):
-                next_tokens = sample_tokens(
-                    sample_logits, keys, temperature, top_k, top_p,
-                    history=history, frequency_penalty=freq_pen, presence_penalty=pres_pen,
-                )
-            if lp_k:
-                from dynamo_tpu.ops.sampling import token_logprobs
-
-                chosen, top_ids, top_lps = token_logprobs(logits, next_tokens, lp_k)
-                return next_tokens, k_cache, v_cache, chosen, top_ids, top_lps
-            return next_tokens, k_cache, v_cache
+            return _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
+                           freq_pen, pres_pen, history, logit_mask, lp_k)
 
         self._step_fn = _step
+
+        @functools.partial(jax.jit, static_argnames=("nd", "nc", "tc", "n", "h", "lp_k"), donate_argnums=(1, 2))
+        def _step_split(params, k_cache, v_cache, packed, *, nd, nc, tc, n, h, lp_k=0):
+            """A chunk step on one token axis (``_pack_split``): ``nd`` decode
+            slots of one position, ``nc`` chunk slots of ``tc``. The same
+            forward and sampling fold as ``_step``, row for row."""
+            (tokens, positions, block_tables, slot_mapping, last_idx, temperature, top_k, top_p,
+             seeds, sample_steps, freq_pen, pres_pen, pos_limit, history) = _unpack_split(packed, nd, nc, tc, n, h)
+            limit = jnp.concatenate([pos_limit[:nd], jnp.repeat(pos_limit[nd:], tc)])
+            slot_mapping = jnp.where(positions < limit, slot_mapping, 0)  # _step's finish-line clamp
+            logits, k_cache, v_cache = llama.forward(
+                params, self.cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping,
+                last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc),
+            )
+            return _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
+                           freq_pen, pres_pen, history, None, lp_k)
+
+        self._step_split_fn = _step_split
 
         @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "lp_k"), donate_argnums=(1, 2))
         def _step_packed(params, k_cache, v_cache, packed, *, b, t, n, h, lp_k=0):
@@ -867,12 +976,11 @@ class ModelRunner:
         """Model-derived {bytes, flops} fallback for one dispatch of this
         padded bucket: weight stream + page-granular KV window."""
         try:
-            b, t = padded.tokens.shape
             window_tokens = padded.block_tables.shape[1] * self.page_size
             itemsize = int(np.dtype(self.k_cache.dtype).itemsize)
             return decode_step_estimate(
-                self.params, self.cfg, b, window_tokens,
-                cache_itemsize=itemsize, new_tokens=b * t,
+                self.params, self.cfg, padded.tokens.shape[0], window_tokens,
+                cache_itemsize=itemsize, new_tokens=self.last_step_layout[1],
             )
         except Exception:  # estimate is best-effort; pending beats wrong
             return None
@@ -911,6 +1019,25 @@ class ModelRunner:
             self.on_enqueued()
         self.take_kv_tokens()  # the device is busy now: host work here costs no step time
 
+    def _chunk_rows(self, padded: StepBatch) -> np.ndarray | None:
+        """The rows of a chunk step that take the chunk slots of a split token
+        axis: those with more than one real column (``last_token_index + 1``,
+        a row's ``num_new``). Every other row, padding included, rides as one
+        token. ``None`` keeps the rectangle: a decode step, a step outside what
+        the flat model step serves (``_can_split``; multimodal, constrained or
+        explicit M-RoPE rows), more chunk rows than ``MAX_CHUNK_SLOTS``, or a
+        step the split would not make smaller (a lone chunk row: its one decode
+        slot would be padding, and a padding token still routes through
+        experts of its own)."""
+        bp, tp = padded.tokens.shape
+        if (not self._can_split or tp == 1 or padded.mm_embeds is not None
+                or padded.logit_mask is not None or padded.mrope_positions is not None):
+            return None
+        chunk = np.flatnonzero(padded.last_token_index > 0)
+        if len(chunk) > MAX_CHUNK_SLOTS or bp + next_pow2(len(chunk)) * tp >= bp * tp:
+            return None
+        return chunk
+
     @_locked
     def step(self, batch: StepBatch, lp_k: int = 0):
         """Run one forward+sample step; returns sampled token ids i32[B_real].
@@ -923,6 +1050,13 @@ class ModelRunner:
         page, and only rows whose span completes their sequence have their
         sample accepted by the engine (the rest are discarded host-side).
 
+        That rectangle is what the caller hands in, not what is computed: a
+        ``T > 1`` step of several rows, of a GQA / MHA text model on one
+        device, runs a program whose token axis holds one position per row
+        plus ``T`` per chunk row (``_chunk_rows``, ``_pack_split``,
+        ``llama.forward``'s ``split``), and the sampled tokens come back in
+        the batch's row order all the same.
+
         ``lp_k > 0`` additionally returns a logprobs dict (chosen-token
         logprob + top-``lp_k`` alternatives, OpenAI semantics):
         ``(tokens, {"logprob": f32[B], "top_ids": i32[B, k], "top_lps":
@@ -932,18 +1066,38 @@ class ModelRunner:
         padded = self._pad(batch)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
         self.last_attn_dispatch = self._attn_dispatch(padded, impl)
+        bp, tp = padded.tokens.shape
+        chunk = self._chunk_rows(padded)
+        self.last_step_layout = (ROWS_X_T, bp * tp)
         # Everything the jitted programs specialize on, post-padding: this is
         # the compile cache key XLA sees (shapes + static args + arg presence).
         dispatch_key = (
-            padded.tokens.shape[0], padded.tokens.shape[1],
-            padded.block_tables.shape[1], padded.history.shape[1],
+            bp, tp, padded.block_tables.shape[1], padded.history.shape[1],
             lp_k, impl, self.mesh is not None,
             padded.mm_embeds is not None, padded.logit_mask is not None,
         )
+        rows = slice(b_real)  # where the batch's rows sit in the program's output
+        if chunk is not None:
+            # One position per row and tp per chunk slot instead of bp x tp. A
+            # step of several rows without a chunk row (a warm-up's null
+            # batch) is the one-chunk-slot program with that slot padding.
+            nc = next_pow2(len(chunk))
+            dispatch_key += (SPLIT, nc)
+            self.last_step_layout = (SPLIT, bp + nc * tp)
+            # Row i samples in decode slot i, a chunk row in its chunk slot.
+            rows = np.arange(b_real)
+            rows[chunk] = bp + np.arange(len(chunk))
         cost_kind = self._dispatch_kind(batch)
         with timed_dispatch(self.compile_tracker, "step", dispatch_key,
                             cost=self.cost_registry, kind=cost_kind):
-            if padded.mm_embeds is not None or padded.logit_mask is not None:
+            if chunk is not None:
+                out = self._cost_call(
+                    "step", dispatch_key, cost_kind, padded, self._step_split_fn,
+                    self.params, self.k_cache, self.v_cache, jnp.asarray(_pack_split(padded, chunk, nc)),
+                    nd=bp, nc=nc, tc=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1],
+                    lp_k=lp_k,
+                )
+            elif padded.mm_embeds is not None or padded.logit_mask is not None:
                 if self.mesh is not None:
                     from dynamo_tpu.parallel.sharding import batch_sharding
 
@@ -991,23 +1145,22 @@ class ModelRunner:
                     impl=impl, lp_k=lp_k,
                 )
             else:
-                b, t = padded.tokens.shape
                 out = self._cost_call(
                     "step", dispatch_key, cost_kind, padded, self._step_packed_fn,
                     self.params, self.k_cache, self.v_cache, jnp.asarray(_pack(padded)),
-                    b=b, t=t, n=padded.block_tables.shape[1], h=padded.history.shape[1],
+                    b=bp, t=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1],
                     lp_k=lp_k,
                 )
             self._mark_wait()
             if lp_k:
                 next_tokens, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
-                return np.asarray(next_tokens)[:b_real], {
-                    "logprob": np.asarray(chosen)[:b_real],
-                    "top_ids": np.asarray(top_ids)[:b_real],
-                    "top_lps": np.asarray(top_lps)[:b_real],
+                return np.asarray(next_tokens)[rows], {
+                    "logprob": np.asarray(chosen)[rows],
+                    "top_ids": np.asarray(top_ids)[rows],
+                    "top_lps": np.asarray(top_lps)[rows],
                 }
             next_tokens, self.k_cache, self.v_cache = out
-            return np.asarray(next_tokens)[:b_real]
+            return np.asarray(next_tokens)[rows]
 
     @_locked
     def spec_step(self, batch: StepBatch, verify_width: int, lp_k: int = 0):
@@ -1038,6 +1191,7 @@ class ModelRunner:
         ).astype(np.int32)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
         self.last_attn_dispatch = self._attn_dispatch(padded, impl, verify=True)
+        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         dispatch_key = (
             bp, padded.tokens.shape[1], padded.block_tables.shape[1],
             padded.history.shape[1], verify_width, lp_k, impl, self.mesh is not None,
@@ -1091,6 +1245,7 @@ class ModelRunner:
         b_real = batch.batch_size
         padded = self._pad(batch)
         self.last_attn_dispatch = self._attn_dispatch(padded, self.attn_impl)
+        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         dispatch_key = (
             padded.tokens.shape[0], padded.tokens.shape[1],
             padded.block_tables.shape[1], padded.history.shape[1],
@@ -1185,6 +1340,7 @@ class ModelRunner:
         padded = self._pad(batch)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
         self.last_attn_dispatch = self._attn_dispatch(padded, impl)
+        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         b, t = padded.tokens.shape
         n = padded.block_tables.shape[1]
         h = padded.history.shape[1]
@@ -1303,6 +1459,7 @@ class ModelRunner:
         ).astype(np.int32)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
         self.last_attn_dispatch = self._attn_dispatch(padded, impl, verify=True)
+        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         chain = chain_src is not None
         src = self._chain_src_padded(chain_src, b_real, bp) if chain else None
         dispatch_key = (

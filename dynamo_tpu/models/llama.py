@@ -276,6 +276,7 @@ def forward(
     mrope_positions: jnp.ndarray | None = None,  # i32[B, 3, T] Qwen2-VL 3D rope coords
     logit_indices: jnp.ndarray | None = None,  # i32[B, V] token columns to score (spec verify)
     contiguous_positions: bool = True,  # False: route attention via gappy-safe paths
+    split: tuple[int, int, int] | None = None,  # (decode slots, chunk slots, tokens per chunk slot)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One forward step. Returns (logits f32[B, vocab], k_cache, v_cache).
 
@@ -302,7 +303,23 @@ def forward(
     position runs — verify rows from the n-gram drafter *are* contiguous,
     but the proposer interface admits draft layouts that are not, and the
     prefill kernel would silently mis-attend on a gappy row.
+
+    ``split = (nd, nc, tc)`` lays a chunk step out on one token axis instead
+    of a ``[B, T]`` rectangle: ``tokens``, ``positions`` and ``slot_mapping``
+    are flat ``i32[nd + nc * tc]``, one position per decode slot then ``tc``
+    per chunk slot; ``block_tables`` has one row per slot (``nd + nc``) and
+    ``last_token_index`` names each slot's last token *on the flat axis*.
+    Everything but attention is per token already; attention alone sees rows
+    (the decode slots as ``[nd, 1]``, the chunk slots as ``[nc, tc]``, both
+    through the chunked kernel). GQA / MHA text models without a mesh only.
     """
+    if split is not None:
+        if (mesh is not None or attn_impl == "ring" or cfg.attn_type == "mla" or cfg.mrope_section
+                or mm_embeds is not None or logit_indices is not None or not contiguous_positions):
+            raise NotImplementedError("the split token axis serves unsharded GQA text steps only")
+        nd, nc, tc = split
+        assert tokens.shape == (nd + nc * tc,) and block_tables.shape[0] == nd + nc
+        tokens, positions, slot_mapping = tokens[None], positions[None], slot_mapping[None]
     b, t = tokens.shape
     nl, npages, ps = k_cache.shape[0], k_cache.shape[1], k_cache.shape[2]
     inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, theta=cfg.rope_theta, scaling=cfg.rope_scaling))
@@ -477,6 +494,18 @@ def forward(
                             mesh=mesh, impl=attn_impl, sliding_window=window,
                             contiguous_positions=contiguous_positions,
                         )
+                    elif split is not None:
+                        # One query per decode slot, tc per chunk slot; back onto the token axis.
+                        def rows(tok: slice, slot: slice, width: int):
+                            n = slot.stop - slot.start
+                            return paged_attention(
+                                q[0, tok].reshape(n, width, cfg.num_heads, cfg.head_dim), k_full, v_full,
+                                tables_l[slot], positions[0, tok].reshape(n, width),
+                                impl=attn_impl, sliding_window=window, chunked=True,
+                            ).reshape(1, n * width, cfg.num_heads, cfg.head_dim)
+
+                        attn = jnp.concatenate(
+                            [rows(slice(0, nd), slice(0, nd), 1), rows(slice(nd, t), slice(nd, nd + nc), tc)], axis=1)
                     else:
                         attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
                                                sliding_window=window,
@@ -523,7 +552,10 @@ def forward(
         sel = jnp.take_along_axis(x, logit_indices[:, :, None], axis=1)  # [B, V, D]
         logits = _qmm(sel, head, preferred_element_type=jnp.float32)  # [B, V, vocab]
         return logits, k_out, v_out
-    last = jnp.take_along_axis(x, last_token_index[:, None, None], axis=1)[:, 0]  # [B, D]
+    if split is not None:
+        last = x[0][last_token_index]  # [slots, D]
+    else:
+        last = jnp.take_along_axis(x, last_token_index[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _qmm(last, head, preferred_element_type=jnp.float32)  # [B, vocab]
     return logits, k_out, v_out
 

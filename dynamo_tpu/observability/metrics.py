@@ -64,6 +64,14 @@ class EngineMetrics:
         # Cumulative engine counters, synced from the core on scrape (the
         # core already counts; a prometheus Counter would double-book).
         self.mixed_steps = gauge(f"{ns}_mixed_steps_total", "Engine steps that fused prefill chunks with decodes")
+        self.chunk_steps_split = gauge(
+            f"{ns}_chunk_steps_split_total",
+            "Steps with a prefill chunk row whose program held one token position per decode row",
+        )
+        self.chunk_steps_rows_x_t = gauge(
+            f"{ns}_chunk_steps_rows_x_t_total",
+            "Steps with a prefill chunk row whose program padded every row to the chunk",
+        )
         self.stall_violations = gauge(
             f"{ns}_stall_violations_total", "Prefill-only dispatches that starved decodable sequences"
         )
@@ -402,6 +410,8 @@ class EngineMetrics:
         self.step_chunk_tokens.set(info.get("chunk_tokens", 0))
         self.step_decodable.set(info.get("decodable", 0))
         self.mixed_steps.set(getattr(core, "mixed_steps", 0))
+        self.chunk_steps_split.set(getattr(core, "chunk_steps_split", 0))
+        self.chunk_steps_rows_x_t.set(getattr(core, "chunk_steps_rows_x_t", 0))
         self.stall_violations.set(getattr(core, "stall_violations", 0))
         self.preemptions.set(getattr(core, "num_preemptions", 0))
         self.admission_rejections.set(getattr(core, "admission_rejections", 0))

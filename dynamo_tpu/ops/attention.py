@@ -160,8 +160,13 @@ def paged_attention(
     impl: str | None = None,
     contiguous_positions: bool = True,
     sliding_window=0,
+    chunked: bool = False,
 ) -> jnp.ndarray:
     """Backend-dispatching paged attention (see module docstring).
+
+    ``chunked`` says these rows belong to a chunk step whose token axis is
+    split (``models/llama.forward``'s ``split``): its one-query rows take the
+    chunked-prefill kernel too (``start = kv_len - 1``), not the decode kernel.
 
     ``sliding_window``: 0 = full causal; a positive int, or a traced i32
     scalar (a layer scan that carries one window per layer, with
@@ -194,6 +199,7 @@ def paged_attention(
         q, k_cache, v_cache, block_tables, positions, scale=scale,
         contiguous_positions=contiguous_positions,
         window=sliding_window if is_windowed(sliding_window) else None,
+        chunked=chunked,
     )
 
 

@@ -602,11 +602,13 @@ def paged_attention_pallas(
     scale: float,
     contiguous_positions: bool = True,
     window=None,  # runtime i32 scalar, None = full causal (see paged_decode_attention)
+    chunked: bool = False,  # T == 1 rows of a split chunk step: the prefill kernel, as T > 1
 ) -> jnp.ndarray:
     """TPU dispatch: decode kernel for T == 1, prefill flash kernel for
-    contiguous T > 1, the same decode kernel in multi-query form for gappy
-    T > 1 (speculative verify), XLA gather formulation as the (counted,
-    warned) fallback.
+    contiguous T > 1 (and for the one-query rows of a chunk step whose token
+    axis is split, ``chunked``), the same decode kernel in multi-query form
+    for gappy T > 1 (speculative verify), XLA gather formulation as the
+    (counted, warned) fallback.
 
     The prefill kernel requires per-row contiguous positions
     (``positions[b, t] = start_b + t``) — true for every engine prefill,
@@ -644,7 +646,7 @@ def paged_attention_pallas(
                 f"layouts (speculative verify, sliding window)"
             )
     interpret = interpret_mode()
-    if q.shape[1] == 1:
+    if q.shape[1] == 1 and not chunked:
         if decode_supported(q, k_cache, interpret=interpret):
             return paged_decode_attention(
                 q, k_cache, v_cache, block_tables, positions, scale=scale,

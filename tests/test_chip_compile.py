@@ -292,3 +292,96 @@ def test_mixed_layer_step_is_one_layer_body_on_the_kernels(sds, t, monkeypatch):
     assert text.count("moe_grouped_matmul_int8") >= 2
     for shape in ("bf16[64,2304,896]", "bf16[64,896,2304]", "s8[1,64,2304,896]", "s8[1,64,896,2304]"):
         assert shape not in text, shape
+
+
+def _olmoe_operands(sds, batch: int, t_q: int, context: int):
+    """OLMoE-1B-7B's attention widths: 16 query and 16 KV heads of 128 (a
+    2048-lane page slab, one query head a KV head), page 128."""
+    pages_per_seq = context // 128
+    cache = sds((batch * pages_per_seq + 1, 128, 16 * 128), jnp.bfloat16)
+    return (sds((batch, t_q, 16, 128), jnp.bfloat16), cache, cache,
+            sds((batch, pages_per_seq), jnp.int32), sds((batch, t_q), jnp.int32))
+
+
+@pytest.mark.parametrize("model", ["olmoe", "mellum2-windowed"])
+def test_chunked_kernel_takes_one_query_rows(sds, model):
+    """The decode slots of a split chunk step: ``paged_prefill_attention`` with
+    T = 1 (``start = kv_len - 1``; the query block is the whole one-token
+    array), 64 rows of one query head a KV head and 8 rows of a group of 8."""
+    from dynamo_tpu.ops.pallas_prefill import paged_prefill_attention
+
+    if model == "olmoe":
+        fn = lambda *a: paged_prefill_attention(*a, scale=128 ** -0.5)  # noqa: E731
+        operands = _olmoe_operands(sds, batch=64, t_q=1, context=1024)
+    else:
+        fn = lambda *a: paged_prefill_attention(*a, scale=128 ** -0.5, window=a[-1][0, 0] * 0 + 1024)  # noqa: E731
+        operands = _mellum2_operands(sds, batch=8, t_q=1, context=8192)
+    text = _compiled_text(fn, *operands)
+    assert "tpu_custom_call" in text and "paged_prefill_attention" in text
+
+
+def _split_step_text(sds, cfg, nd: int, tc: int, pages_per_seq: int, monkeypatch, nc: int = 1) -> str:
+    """``llama.forward`` on a split token axis (``nd`` decode slots, ``nc``
+    chunk slots of ``tc``), int8, lowered for the described chip."""
+    import functools
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 385, 128)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    toks = nd + nc * tc
+    return jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas", split=(nd, nc, tc))).lower(
+        params=params, tokens=i32(toks), positions=i32(toks), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(nd + nc, pages_per_seq), slot_mapping=i32(toks), last_token_index=i32(nd + nc),
+    ).compile().as_text()
+
+
+def _no_rectangle(text: str, nd: int, tc: int) -> None:
+    """No activation of the padded step is left: nothing shaped [rows, chunk, ...]."""
+    import re
+
+    left = sorted(set(re.findall(rf"\b(?:bf16|f32|s32)\[{nd},{tc},[0-9,]*\]", text)))
+    assert not left, left
+
+
+@pytest.mark.parametrize("nc", [1, 2], ids=["one-chunk-slot", "two-chunk-slots"])
+def test_split_mixed_step_olmoe_largest_corner(sds, monkeypatch, nc):
+    """The saturated cell's largest mixed step on a split token axis: 64
+    decode slots + one 64-token chunk slot over 8 pages, two layers of OLMoE
+    at its widths. Both attention calls are the chunked kernel (the
+    benchmark's prefill roofline sums that kernel's events inside a mixed
+    step's program), the decode kernel is not in the program, and no
+    [64, 64, ...] activation is left. With two chunk slots (a prompt's tail
+    and the next one's head in one step: no cell runs it) the same holds."""
+    import dataclasses
+
+    cfg = dataclasses.replace(PRESETS["olmoe-1b-7b"], num_layers=2)
+    text = _split_step_text(sds, cfg, nd=64, tc=64, pages_per_seq=8, monkeypatch=monkeypatch, nc=nc)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4  # one layer body: 2 attention + 2 expert calls
+    assert text.count("paged_prefill_attention") >= 2 and "paged_decode_attention" not in text
+    assert text.count("moe_grouped_matmul_int8") >= 2
+    _no_rectangle(text, 64, 64)
+
+
+def test_split_mixed_step_mellum2_largest_corner(sds, monkeypatch):
+    """longctx-decode's largest mixed step: 8 decode slots + one 64-token
+    chunk slot over 64 pages, one period (sliding x 3, full) of Mellum2 at its
+    widths, the window a per-layer runtime scalar in both calls."""
+    import json
+    import pathlib
+
+    from dynamo_tpu.models.config import ModelConfig
+
+    doc = json.loads((pathlib.Path(__file__).parents[1] / "benchmark/configs/mellum2-12b-a2.5b-int8.json").read_text())
+    hf = {k: v for k, v in doc.items() if k not in ("serve", "rehearsal", "assumed", "reduced_why")}
+    hf.update(num_hidden_layers=4, layer_types=hf["layer_types"][:4], mlp_layer_types=hf["mlp_layer_types"][:4])
+    cfg = ModelConfig.from_hf(hf, name="mellum2-one-period")
+    text = _split_step_text(sds, cfg, nd=8, tc=64, pages_per_seq=64, monkeypatch=monkeypatch)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert text.count("paged_prefill_attention") >= 2 and "paged_decode_attention" not in text
+    _no_rectangle(text, 8, 64)

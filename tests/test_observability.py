@@ -130,6 +130,8 @@ async def test_untraced_context_stays_untraced_over_tcp():
 class _FakeCore:
     last_step_info = {"decode_rows": 3, "chunk_rows": 2, "chunk_tokens": 128, "decodable": 3}
     mixed_steps = 7
+    chunk_steps_split = 6
+    chunk_steps_rows_x_t = 1
     stall_violations = 1
     num_preemptions = 2
     admission_rejections = 4
@@ -189,6 +191,8 @@ EXPECTED_ENGINE_FAMILIES = {
     "dynamo_engine_attn_dispatch_steps_total",
     "dynamo_engine_step_decodable_seqs",
     "dynamo_engine_mixed_steps_total",
+    "dynamo_engine_chunk_steps_split_total",
+    "dynamo_engine_chunk_steps_rows_x_t_total",
     "dynamo_engine_stall_violations_total",
     "dynamo_engine_preemptions_total",
     "dynamo_engine_admission_rejections_total",
@@ -289,6 +293,8 @@ async def test_engine_metrics_names_labels_and_values():
     assert 'dynamo_engine_step_decode_rows{worker="w1"} 3.0' in text
     assert 'dynamo_engine_step_chunk_tokens{worker="w1"} 128.0' in text
     assert 'dynamo_engine_mixed_steps_total{worker="w1"} 7.0' in text
+    assert 'dynamo_engine_chunk_steps_split_total{worker="w1"} 6.0' in text
+    assert 'dynamo_engine_chunk_steps_rows_x_t_total{worker="w1"} 1.0' in text
     assert 'dynamo_engine_admission_rejections_total{worker="w1"} 4.0' in text
     assert 'dynamo_engine_spec_tokens_proposed_total{worker="w1"} 20.0' in text
     assert 'dynamo_engine_spec_tokens_accepted_total{worker="w1"} 9.0' in text

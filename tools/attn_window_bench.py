@@ -3,13 +3,17 @@
 layer's call at several contexts, full against windowed.
 
     python3 tools/attn_window_bench.py [--rows 6] [--contexts 2048,4096,6144] [--window 1024]
+    python3 tools/attn_window_bench.py --heads 16 --kv-heads 16 --rows 48 --contexts 256,512,896 --variants none
 
 At Mellum2's head layout (32 query and 4 KV heads of 128, page 128, bf16
-cache) and ``--rows`` sequences all at one context, 28 calls chained in one
+cache; ``--heads`` / ``--kv-heads`` give another, OLMoE's is 16 / 16) and
+``--rows`` sequences all at one context, 28 calls chained in one
 program (a layer stack's worth; the time printed is one call's): ``paged_decode_attention``
-(T = 1) and ``paged_prefill_attention`` (one 64-token chunk a row) with
+(T = 1), ``paged_prefill_attention`` with T = 1 (what a decode slot of a split
+chunk step asks of the chunked kernel: the same bytes as the decode kernel's
+call) and with one 64-token chunk a row, each with
 ``window=None`` (the unwindowed program), ``NO_WINDOW`` (a full layer inside
-a mixed model's scan) and ``--window``. A windowed walk visits the blocks that
+a mixed model's scan) and ``--window`` (``--variants`` picks among the three). A windowed walk visits the blocks that
 hold the window, so its time must not grow with the context; the bytes each
 call needs and the share of the HBM peak they come to are printed beside.
 ``--rehearse`` (or no TPU) runs tiny shapes in interpret mode and prints no time.
@@ -26,7 +30,7 @@ import time
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
-HEADS, KV_HEADS, HEAD_DIM, PAGE = 32, 4, 128, 128
+HEAD_DIM, PAGE = 128, 128
 LAYERS = 28
 
 
@@ -35,6 +39,9 @@ def main() -> int:
     ap.add_argument("--rows", type=int, default=6)
     ap.add_argument("--contexts", default="2048,4096,6144")
     ap.add_argument("--window", type=int, default=1024)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=4)
+    ap.add_argument("--variants", default="none,no_window,window")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
@@ -51,16 +58,18 @@ def main() -> int:
     hbm = json.loads((ROOT / "benchmark" / "peaks.json").read_text())["TPU v5 lite"]["hbm_bytes_per_s"]
     pages_per_seq = -(-max(contexts) // PAGE)
     rng = np.random.default_rng(0)
-    width = KV_HEADS * HEAD_DIM
+    width = args.kv_heads * HEAD_DIM
+    variants = [(n, w) for n, w in (("none", None), ("no_window", NO_WINDOW), ("window", window))
+                if n in args.variants.split(",")]
     dtype = jnp.bfloat16 if on_chip else jnp.float32
     cache = jnp.asarray(rng.standard_normal((rows * pages_per_seq + 1, PAGE, width)), dtype)
     tables = jnp.asarray(1 + np.arange(rows * pages_per_seq, dtype=np.int32).reshape(rows, pages_per_seq))
     table = []
-    for kernel, t in ((paged_decode_attention, 1), (paged_prefill_attention, 64)):
-        q = jnp.asarray(rng.standard_normal((rows, t, HEADS, HEAD_DIM)), dtype)
+    for kernel, t in ((paged_decode_attention, 1), (paged_prefill_attention, 1), (paged_prefill_attention, 64)):
+        q = jnp.asarray(rng.standard_normal((rows, t, args.heads, HEAD_DIM)), dtype)
         for ctx in contexts:
             pos = jnp.asarray(np.broadcast_to(ctx - t + np.arange(t, dtype=np.int32), (rows, t)))
-            for name, w in (("none", None), ("no_window", NO_WINDOW), ("window", window)):
+            for name, w in variants:
                 def stack(q, k, v, bt, p, w=w):
                     # LAYERS calls in a row, each fed by the one before, in one
                     # program: the host's dispatch is paid once, not per call.
@@ -75,7 +84,7 @@ def main() -> int:
                 out = jax.block_until_ready(call(q, cache, cache, tables, pos))
                 assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
                 visited = ctx if w is None or w >= ctx else min(ctx, w + t - 1)
-                need = rows * visited * 2 * width * 2 + 2 * rows * t * HEADS * HEAD_DIM * 2
+                need = rows * visited * 2 * width * 2 + 2 * rows * t * args.heads * HEAD_DIM * 2
                 row = {"kernel": kernel.__name__, "t": t, "rows": rows, "context": ctx, "window": name,
                        "needed_bytes": need}
                 if on_chip:

@@ -2,7 +2,7 @@
 """The outputs check of a windowed configuration at lengths that cross its
 window, and the controls that show the check can see the window and the RoPEs.
 
-    python3 tools/mellum2_long_check.py [--workload <cell>] --seeds 3 --variants long,fp8kv,int4w
+    python3 tools/mellum2_long_check.py [--workload <cell>] --seeds 3 --variants long,ragged,fp8kv,int4w
 
 ``benchmark/correct.py`` checks prompts of 192 and 128 tokens, which never
 reach a window of 1024, and that file is the benchmark's. This script uses the
@@ -15,6 +15,12 @@ wrong in two ways, which must come out over the limit:
 
 - ``all_full``: the reference's sliding layers made full (window past every position);
 - ``one_rope``: the sliding layers' plain RoPE in the full layers too.
+
+``ragged`` serves three prompts whose lengths are no multiple of the chunk,
+all offered at once beside a decoding row: where one prompt's tail leaves
+budget, the next one's head shares the step, so the step has two chunk rows
+(the two-chunk-slot program of ``runner.MAX_CHUNK_SLOTS``, which no cell of the
+benchmark runs). Its rows name the chunk steps by layout and token positions.
 
 ``fp8kv`` and ``int4w`` are the two lower-precision controls of
 ``benchmark/control.py`` at the benchmark's own lengths (its int4 re-coding
@@ -29,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import collections
 import gc
 import json
 import os
@@ -42,6 +49,7 @@ sys.path.insert(0, str(ROOT / "benchmark"))
 import run as bench_run  # noqa: E402
 
 LONG = [(2304, 4), (1536, 4)]
+RAGGED = [(2290, 4), (1530, 4), (777, 4)]
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
@@ -76,6 +84,27 @@ def reference_variants(conf: dict) -> dict:
             "one_rope": {**conf, "hf": one_rope}}
 
 
+async def serve_together(service, conf: dict, seed: int, *, scale: float) -> dict:
+    """``correct.serve_sample`` with every checked prompt offered at once, and
+    a filler that decodes beside them to the end."""
+    import numpy as np
+
+    from benchmark import correct
+
+    rng = np.random.default_rng(np.random.SeedSequence([int(seed), 77]))
+    sized = [(max(8, int(p * scale)), n) for p, n in correct.CHECKED]
+    prompts = [rng.integers(1, conf["hf"]["vocab_size"], size=p).tolist() for p, _ in sized]
+    filler = rng.integers(1, conf["hf"]["vocab_size"], size=max(8, int(64 * scale))).tolist()
+    started = asyncio.Event()
+    beside = asyncio.ensure_future(correct._served(service, filler, 160, logprobs=False, started=started))
+    await started.wait()
+    served = await asyncio.gather(*(correct._served(service, p, n, logprobs=True) for p, (_, n) in zip(prompts, sized)))
+    await beside
+    return {"served": served,
+            "sequences": [p + [e["id"] for e in row][:-1] for p, row in zip(prompts, served)],
+            "spans": [(len(p) - 1, len(row)) for p, row in zip(prompts, served)]}
+
+
 async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> list[dict]:
     from benchmark import correct, serving, weights
 
@@ -83,12 +112,13 @@ async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> li
     os.environ.pop("DYN_KV_CACHE_DTYPE", None)
     if variant == "fp8kv":
         os.environ["DYN_KV_CACHE_DTYPE"] = "fp8"
-    correct.CHECKED = LONG if variant == "long" else [(192, 4), (128, 4)]
+    correct.CHECKED = {"long": LONG, "ragged": RAGGED}.get(variant, [(192, 4), (128, 4)])
     state = await bench_run.bring_up(args, bench, cell, rehearsal, warm=False,
                                      transform=_to_int4_leaf_by_leaf if variant == "int4w" else None)
     conf, core = state["conf"], state["core"]
     try:
-        sample = await correct.serve_sample(state["service"], conf, seed, scale=state["check_scale"])
+        serve = serve_together if variant == "ragged" else correct.serve_sample
+        sample = await serve(state["service"], conf, seed, scale=state["check_scale"])
         steps = core.flight.snapshot(kind="step")
     finally:
         await serving.stop(state["handles"])
@@ -105,6 +135,9 @@ async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> li
         rows.append({"seed": seed, "variant": variant, "reference": ref_name,
                      "prompts": [len(s) - n + 1 for s, (_, n) in zip(sample["sequences"], sample["spans"])],
                      "mixed_steps": sum(1 for s in steps if s["step_kind"] == "mixed"),
+                     "chunk_steps": dict(collections.Counter(
+                         f"{s['chunk_rows']}_chunk_rows.{s.get('layout', '')}.{s.get('step_tokens', 0)}"
+                         for s in steps if s["chunk_rows"])),
                      "decode_steps": sum(1 for s in steps if s["step_kind"] == "decode"),
                      "attn_paths": sorted({s["attn_path"] for s in steps if s["attn_path"]}),
                      "moe_paths": sorted({s["moe_path"] for s in steps if s["moe_path"]}), **check})
