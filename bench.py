@@ -106,12 +106,11 @@ def parse_suite() -> list[tuple[str, str, int, int, int, int]]:
 
 
 def tree_nbytes(tree) -> int:
-    # Single source of truth for byte accounting lives in the device-cost
-    # plane (observability/cost.py) — the serving-path ledger and this
-    # offline suite must agree by construction, not by parallel tree-walks.
-    from dynamo_tpu.observability.cost import tree_nbytes as _tree_nbytes
+    """Total bytes of every array leaf (packed quantized leaves count at
+    their true storage size: int8 ~1 B/elem, packed int4 ~0.5)."""
+    import jax
 
-    return _tree_nbytes(tree)
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
 
 
 def kv_bytes_per_token(cfg, cache_itemsize: int = 2) -> int:
@@ -155,9 +154,10 @@ def decode_weight_bytes(params, cfg) -> int:
     bytes (packed quantized leaves count at their true size, so int8 is
     ~1 byte/elem and int4 ~0.5) minus the embedding table when untied —
     decode gathers ``batch`` rows of it, never the full table."""
-    from dynamo_tpu.observability.cost import weight_stream_bytes
-
-    return weight_stream_bytes(params, cfg)
+    total = tree_nbytes(params)
+    if not getattr(cfg, "tie_embeddings", True) and "embed" in params:
+        total -= tree_nbytes(params["embed"])
+    return total
 
 
 def roofline_tok_per_sec(step_bytes: int, batch: int) -> float:
@@ -279,19 +279,6 @@ def run_config(preset: str, quant: str, batch: int, isl: int, osl: int,
     # utilization is a floor too).
     steps = generated / batch
     achieved_gbps = step_bytes * steps / elapsed / 1e9 if elapsed > 0 else 0.0
-    # Serving-path ledger (device-cost plane): the decode roofline fraction
-    # the production metrics export for this exact run — XLA-counted bytes
-    # over measured dispatch wall, vs the auto-detected chip peak. Differs
-    # from vs_roofline by construction (modeled bytes + spec bandwidth vs
-    # XLA bytes + detected peak); the two bracketing each other is the
-    # cross-check.
-    live_roofline_frac = 0.0
-    cost_reg = getattr(runner, "cost_registry", None)
-    if cost_reg is not None:
-        cost_reg.drain(timeout=30.0)
-        live_roofline_frac = float(
-            cost_reg.ledger().get("decode", {}).get("roofline_frac", 0.0)
-        )
     target = ANCHOR_TOK_PER_SEC.get(preset, 0.0)
     return {
         "preset": preset, "quant": quant or "bf16", "batch": batch,
@@ -304,7 +291,6 @@ def run_config(preset: str, quant: str, batch: int, isl: int, osl: int,
         "hbm_utilization": round(achieved_gbps / SPEC_HBM_GBPS, 4),
         "roofline_tok_per_sec": round(roofline, 1),
         "vs_roofline": round(tok_per_sec / roofline, 4) if roofline else 0.0,
-        "live_roofline_frac": round(live_roofline_frac, 4),
         "target": round(target, 1),
         "target_kind": ("north_star_proxy" if preset == "llama-3.2-1b"
                         else "fixed_r4_anchor" if target else "none"),
@@ -614,15 +600,6 @@ def probe_decode_kernel(*, interpret: bool = False) -> dict:
                    grid=[], decode_kernel_gbps=0.0, decode_roofline_frac=0.0)
         return out
 
-    # Device-cost-plane ledger over the same calls: the production roofline
-    # math (observability/cost.py — auto-detected chip peak, not the
-    # BENCH_SPEC constant) fed with the modeled KV bytes and measured wall.
-    # live_roofline_frac and decode_roofline_frac diverging flags a stale
-    # BENCH_SPEC_HBM_GBPS or a mis-detected chip.
-    from dynamo_tpu.observability.cost import CostRegistry, cost_plane_enabled
-
-    cost_reg = CostRegistry() if cost_plane_enabled() else None
-
     rng = np.random.default_rng(0)
     grid: list[dict] = []
     best = 0.0
@@ -655,14 +632,6 @@ def probe_decode_kernel(*, interpret: bool = False) -> dict:
             kv_bytes = 2 * batch * pages * page_size * width * itemsize
             gbps = kv_bytes * iters / dt / 1e9 if dt > 0 else 0.0
             best = max(best, gbps)
-            if cost_reg is not None:
-                key = (batch, ctx)
-                if not cost_reg.seen("decode_kernel", key):
-                    cost_reg.submit(
-                        "decode_kernel", key, "decode",
-                        estimate={"bytes": float(kv_bytes), "flops": 0.0},
-                    )
-                cost_reg.observe("decode_kernel", key, dt / iters, "decode")
             grid.append({
                 "batch": batch, "context": ctx,
                 "kv_bytes_per_call": kv_bytes,
@@ -671,16 +640,10 @@ def probe_decode_kernel(*, interpret: bool = False) -> dict:
                 "roofline_frac": round(gbps / SPEC_HBM_GBPS, 4),
             })
             gc.collect()
-    live_frac = 0.0
-    if cost_reg is not None:
-        ledger = cost_reg.ledger().get("decode", {})
-        live_frac = float(ledger.get("roofline_frac", 0.0))
-        cost_reg.close()
     out.update(
         grid=grid,
         decode_kernel_gbps=round(best, 6),
         decode_roofline_frac=round(best / SPEC_HBM_GBPS, 6),
-        live_roofline_frac=round(live_frac, 6),
     )
     return out
 
@@ -1543,16 +1506,6 @@ def build_doc(configs, pull, wire=None, stall=None, spec=None,
         # (see probe_decode_kernel; meaningless off-TPU but always present).
         "decode_kernel_gbps": (decode_kernel or {}).get("decode_kernel_gbps", 0.0),
         "decode_roofline_frac": (decode_kernel or {}).get("decode_roofline_frac", 0.0),
-        # Device-cost-plane headline key (ISSUE 19): the serving-path
-        # ledger's decode roofline fraction — XLA/estimate bytes over
-        # measured dispatch wall against the auto-detected chip peak, the
-        # same number dynamo_engine_roofline_frac exports in production.
-        # Taken from the engine suite's head config when it ran with the
-        # cost plane on, else from the kernel probe's ledger.
-        "live_roofline_frac": head.get(
-            "live_roofline_frac",
-            (decode_kernel or {}).get("live_roofline_frac", 0.0),
-        ) or (decode_kernel or {}).get("live_roofline_frac", 0.0),
         # KV-wire headline keys (ISSUE 8): best amortized cross-process wire
         # bandwidth from the stream-count x chunk-size sweep and its overlap
         # fraction (see probe_cross_process_wire / bench/kv_wire.py).

@@ -65,6 +65,40 @@ CONCURRENT_MIX = [(64, 128), (160, 96), (320, 64), (512, 48),
 EXIT_REHEARSAL = 3
 
 
+#: device_kind substring -> (peak HBM GB/s, peak bf16 dense TFLOPS) per chip, from
+#: the datasheets. Matched case-insensitively against jax's device_kind strings
+#: ("TPU v5 lite" is v5e, "TPU v6 lite" v6e, a bare "TPU v5" the p-class part).
+CHIP_PEAKS: dict[str, tuple[float, float]] = {
+    "v6e": (1640.0, 918.0),
+    "v6 lite": (1640.0, 918.0),
+    "v5e": (819.0, 197.0),
+    "v5 lite": (819.0, 197.0),
+    "v5p": (2765.0, 459.0),
+    "v5": (2765.0, 459.0),
+    "v4": (1228.0, 275.0),
+}
+
+#: The CPU rehearsal only: round numbers no one takes for a measurement.
+CPU_PROXY_PEAKS = (50.0, 0.5)
+
+
+def chip_peaks() -> tuple[float, float, str]:
+    """(peak HBM GB/s, peak TFLOPS, source) of device 0: from ``CHIP_PEAKS``,
+    ``CPU_PROXY_PEAKS`` on the CPU platform. An accelerator the table does not
+    know is an error, not a default."""
+    import jax
+
+    dev = jax.devices()[0]
+    kind = dev.device_kind
+    if dev.platform == "cpu":
+        return (*CPU_PROXY_PEAKS, f"cpu-proxy:{kind}")
+    for sub, (hbm, tflops) in CHIP_PEAKS.items():
+        if sub in kind.lower():
+            return hbm, tflops, f"table:{kind}"
+    raise RuntimeError(f"no peak HBM bandwidth / FLOPS known for {dev.platform} device_kind "
+                       f"{kind!r}: add it to CHIP_PEAKS (chip_smoke.py)")
+
+
 def emit(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
@@ -500,8 +534,7 @@ async def serve_one_chip(model: str, stats: CompileStats, *, enforce: bool) -> N
              pool_tokens=cfg.page_size * (cfg.num_pages - 1),
              kv_cache_bytes=runner.cache_memory_bytes(), context=cfg.max_seq_len,
              chunk_prefill_tokens=cfg.chunk_prefill_tokens, max_batch_size=cfg.max_batch_size,
-             decode_steps=cfg.decode_steps, overlap=cfg.overlap, spec_k=cfg.spec_k,
-             cost_plane=runner.cost_registry is not None)
+             decode_steps=cfg.decode_steps, overlap=cfg.overlap, spec_k=cfg.spec_k)
         base = f"http://127.0.0.1:{handles['port']}"
         tracker = runner.compile_tracker
         timeout = aiohttp.ClientTimeout(total=900)
@@ -555,7 +588,6 @@ async def serve_sharded(model: str, *, enforce: bool) -> None:
     import jax
 
     from dynamo_tpu import launch
-    from dynamo_tpu.observability.cost import tree_nbytes
 
     argv = _launch_argv(model, "--mesh", "tp=4")
     with Phase("sharded_start_server"):
@@ -564,7 +596,7 @@ async def serve_sharded(model: str, *, enforce: bool) -> None:
         core = handles["services"][0].core
         runner = core.runner
         base = f"http://127.0.0.1:{handles['port']}"
-        model_bytes = tree_nbytes(runner.params)
+        model_bytes = sum(x.nbytes for x in jax.tree.leaves(runner.params))
         emit(launch_argv=argv, mesh=dict(runner.mesh.shape), model_bytes=model_bytes,
              kv_cache_bytes=runner.cache_memory_bytes(), page_size=core.config.page_size,
              num_pages=core.config.num_pages)
@@ -686,7 +718,6 @@ def main() -> int:
 
     setup_logging()  # as launch.main does: the server's start-up facts go to stderr
     from dynamo_tpu.compile_cache import enable_compile_cache
-    from dynamo_tpu.observability.cost import chip_peaks
 
     cache_dir = enable_compile_cache()
     cache_entries_before = len(os.listdir(cache_dir))

@@ -46,7 +46,7 @@ from typing import Callable
 import numpy as np
 
 from dynamo_tpu.engine.allocator import OutOfPagesError, PageAllocator
-from dynamo_tpu.engine.runner import SPLIT, ModelRunner, StepBatch
+from dynamo_tpu.engine.runner import SPLIT, DispatchReport, ModelRunner, StepBatch
 from dynamo_tpu.engine.sequence import SeqStatus, Sequence
 from dynamo_tpu.observability.flight import CRASH, STEP, FlightRecorder
 from dynamo_tpu.protocols.common import EngineOutput, FinishReason, PreprocessedRequest
@@ -57,6 +57,8 @@ from dynamo_tpu.tokens import DEFAULT_SALT
 from dynamo_tpu import tracing
 
 logger = logging.getLogger(__name__)
+
+_NO_DISPATCH = DispatchReport()  # what the STEP record of a step that dispatched nothing holds
 
 # Logprobs requests always compute this many alternatives on-device (one
 # compiled program; per-request top_logprobs slices host-side — a static
@@ -325,9 +327,9 @@ class EngineCore:
         # first-execution events into the same ring, so a flight dump shows
         # recompiles interleaved with the steps that triggered them.
         self.flight = FlightRecorder()
-        _tracker = getattr(runner, "compile_tracker", None)
-        if _tracker is not None:
-            _tracker.bind_sink(self.flight.record)
+        self._compile_tracker = getattr(runner, "compile_tracker", None)
+        if self._compile_tracker is not None:
+            self._compile_tracker.bind_sink(self.flight.record)
         # Time-loss accounting (attribution plane): cumulative ms charged per
         # cause (the pinned attribution.LOSS_CAUSES vocabulary — barrier
         # reasons + queue/admission/onboard_stall/preempt/recompile/gap),
@@ -598,8 +600,7 @@ class EngineCore:
         """
         with self.step_lock:
             prev_info = self.last_step_info
-            tracker = getattr(self.runner, "compile_tracker", None)
-            disp0 = tracker.dispatch_seconds_total if tracker is not None else 0.0
+            tracker = self._compile_tracker
             # Host gap since the previous step returned: the window where the
             # device has nothing newly dispatched (detok/stop/route/schedule
             # time). The overlapped loop exists to hide exactly this. The
@@ -692,52 +693,21 @@ class EngineCore:
                 spec_drafted = spec_accepted = 0
                 kind = "decode" if self.running else "drain"
             self.step_kind_counts[kind] = self.step_kind_counts.get(kind, 0) + 1
-            dispatch_ms = (
-                (tracker.dispatch_seconds_total - disp0) * 1e3 if tracker is not None else 0.0
-            )
-            # Consume (don't just read) the runner's dispatch label: a step
-            # that only drains in-flight results must not re-count the
-            # previous dispatch.
-            attn = getattr(self.runner, "last_attn_dispatch", None)
-            if attn is not None:
-                self.runner.last_attn_dispatch = None
+            # What the runner dispatched in this step, taken once: a step that
+            # only drains in-flight results takes nothing.
+            report = self.runner.take_dispatch() or _NO_DISPATCH
+            dispatch_ms = report.seconds * 1e3
+            if report.attn_phase:
+                attn = (report.attn_phase, report.attn_path)
                 self.attn_dispatch_counts[attn] = self.attn_dispatch_counts.get(attn, 0) + 1
-            attn_phase, attn_path = attn if attn else ("", "")
-            # A step that dispatched ran the model, routed experts included.
-            moe_path = getattr(self.runner, "moe_path", "") if attn else ""
-            take_kv = getattr(self.runner, "take_kv_tokens", None)
-            kv_full, kv_window = take_kv() if attn and take_kv else (0, 0)
-            # The token positions the dispatched program computed, and how it
-            # laid them out (consumed like the attention label).
-            layout, step_tokens = "", 0
-            if attn and getattr(self.runner, "last_step_layout", None):
-                layout, step_tokens = self.runner.last_step_layout
-                self.runner.last_step_layout = None
-                if chunk_rows and layout == SPLIT:
-                    self.chunk_steps_split += 1
-                elif chunk_rows:
-                    self.chunk_steps_rows_x_t += 1
+            if chunk_rows and report.layout == SPLIT:
+                self.chunk_steps_split += 1
+            elif chunk_rows and report.layout:
+                self.chunk_steps_rows_x_t += 1
             # Feed the chunk-budget controller only steps that carried decode
             # rows: their wall time is the ITL a running request observed.
             if self.chunk_controller is not None and decode_rows:
                 self.chunk_controller.observe(wall_ms)
-            # Device-cost join: the registry accumulated bytes/flops for every
-            # dispatch this step made; against the dispatch wall that yields
-            # the step's roofline fraction. Without a tracker (mock runners)
-            # the step wall stands in for the dispatch wall.
-            cost_reg = getattr(self.runner, "cost_registry", None)
-            cost_fields: dict = {}
-            if cost_reg is not None:
-                step_hbm_bytes, step_flops = cost_reg.take_step()
-                disp_s = (dispatch_ms if tracker is not None else wall_ms) / 1e3
-                roofline_frac, _bound = cost_reg.roofline_of(
-                    step_hbm_bytes, step_flops, disp_s
-                )
-                cost_fields = {
-                    "hbm_bytes": int(step_hbm_bytes),
-                    "flops": int(step_flops),
-                    "roofline_frac": round(roofline_frac, 4),
-                }
             self.flight.record(
                 STEP,
                 step_kind=kind,
@@ -760,13 +730,13 @@ class EngineCore:
                 ),
                 wall_ms=round(wall_ms, 3),
                 dispatch_ms=round(dispatch_ms, 3),
-                attn_phase=attn_phase,
-                attn_path=attn_path,
-                moe_path=moe_path,
-                kv_tokens_full=kv_full,
-                kv_tokens_window=kv_window,
-                step_tokens=int(step_tokens),
-                layout=layout,
+                attn_phase=report.attn_phase,
+                attn_path=report.attn_path,
+                moe_path=report.moe_path,
+                kv_tokens_full=report.kv_tokens_full,
+                kv_tokens_window=report.kv_tokens_window,
+                step_tokens=report.step_tokens,
+                layout=report.layout,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
                 deadline_slack_ms=self.last_admission.get("deadline_slack_ms", 0.0),
@@ -779,7 +749,6 @@ class EngineCore:
                 ann_ns=clock.ann_ns,
                 traced=clock.traced,
                 phases_us=clock.phases_us(),
-                **cost_fields,
             )
             # Time-loss accounting: every millisecond of this step's wall
             # clock that was not runner dispatch, plus the host gap before

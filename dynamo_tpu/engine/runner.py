@@ -28,12 +28,6 @@ from dynamo_tpu import tracing
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.observability.compile import CompileTracker, timed_dispatch
-from dynamo_tpu.observability.cost import (
-    CostRegistry,
-    cost_plane_enabled,
-    decode_step_estimate,
-    make_lower_thunk,
-)
 from dynamo_tpu.ops.sampling import sample_tokens
 
 logger = logging.getLogger(__name__)
@@ -282,6 +276,23 @@ class StepBatch:
         return self.tokens.shape[0]
 
 
+@dataclasses.dataclass
+class DispatchReport:
+    """What a runner dispatched during one engine step. Every dispatch site
+    fills it (``ModelRunner._dispatch``) and the engine takes it once a step
+    (``take_dispatch``) into its STEP record: the seconds sum over the step's
+    dispatches, of everything else the last dispatch's value stands."""
+
+    seconds: float = 0.0  # inside the dispatch sites' timed blocks
+    attn_phase: str = ""  # "decode" / "verify" / "prefill"
+    attn_path: str = ""  # "pallas" / "fallback" / "ring"
+    moe_path: str = ""  # "fused" / "widened"; "" for a dense model
+    layout: str = ""  # ROWS_X_T / SPLIT
+    step_tokens: int = 0  # token positions the dispatched program computes
+    kv_tokens_full: int = 0  # key tokens one full / one windowed layer visits
+    kv_tokens_window: int = 0
+
+
 class ModelRunner:
     """Owns device state (params + paged KV cache) and runs engine steps."""
 
@@ -329,32 +340,20 @@ class ModelRunner:
         #: The owning engine's phase clock (``EngineCore`` sets it): the
         #: blocking programs mark dispatch -> wait where the enqueue returns.
         self.clock: tracing.StepClock | None = None
-        # Device-cost plane (DYN_COST_PLANE, default on): per-bucket
-        # flops/bytes records joined with measured dispatch wall into the
-        # live roofline ledger. None when the plane is off — the dispatch
-        # sites then skip every cost call (bit-identical serving, zero
-        # extraction).
-        self.cost_registry = CostRegistry() if cost_plane_enabled() else None
         # Padded page-counts whose gather/scatter kernels are compiled for
         # this runner (device-transfer warm-up bookkeeping — keyed on the
         # runner object itself, so id() reuse after GC can't skip a warm-up).
         self._devxfer_warm: set[int] = set()
-        # (phase, path) of the most recent dispatch — "decode"/"verify"/
-        # "prefill" x "pallas"/"fallback"/"ring". The engine copies this
-        # into its STEP flight records and dispatch-path counters.
-        self.last_attn_dispatch: tuple[str, str] | None = None
-        # (layout, token positions the program computes) of the most recent
-        # dispatch: "rows_x_t" (the padded rectangle) or "split" (_chunk_rows).
-        self.last_step_layout: tuple[str, int] | None = None
+        # What has been dispatched since the engine last took it (take_dispatch).
+        self._report: DispatchReport | None = None
         # A chunk step may lay its tokens out on one axis where the model step
         # has the flat path: GQA / MHA text models on one device (llama.forward).
         self._can_split = (forward_fn is None and mesh is None and cfg.attn_type != "mla"
                            and not cfg.mrope_section)
-        # The most recent dispatch's padded batch, until its key-token counts
-        # are taken (take_kv_tokens): counted after the enqueue, under the
+        # The most recent dispatch's padded batch, until its key tokens are
+        # counted into the report (_count_kv): after the enqueue, under the
         # device's shadow, never between a result and the next enqueue.
         self._kv_pending: StepBatch | None = None
-        self._kv_tokens_last: tuple[int, int] = (0, 0)
         # Called (from the stepping thread) when a synchronous dispatch has
         # enqueued its program and is about to block on the result: the
         # service routes the previous step's outputs then (engine/service.py).
@@ -940,11 +939,10 @@ class ModelRunner:
             )
         return phase, "pallas" if ok else "fallback"
 
-    def take_kv_tokens(self) -> tuple[int, int]:
-        """(kv_tokens_full, kv_tokens_window) of the most recent dispatch."""
+    def _count_kv(self, report: DispatchReport) -> None:
         if self._kv_pending is not None:
-            self._kv_tokens_last, self._kv_pending = self._kv_tokens(self._kv_pending), None
-        return self._kv_tokens_last
+            report.kv_tokens_full, report.kv_tokens_window = self._kv_tokens(self._kv_pending)
+            self._kv_pending = None
 
     def _kv_tokens(self, padded: StepBatch) -> tuple[int, int]:
         """Key tokens one layer of each kind has to visit in this dispatch:
@@ -960,55 +958,59 @@ class ModelRunner:
         new = context - np.minimum(pos[:, 0], first)
         return int(context.sum()), int(np.minimum(context, win + new - 1).sum())
 
-    # -- device-cost plane -------------------------------------------------
+    # -- the step's dispatch report -------------------------------------------
 
-    def _dispatch_kind(self, batch: StepBatch, *, spec: bool = False) -> str:
-        """Ledger step-kind of a dispatch (cost-plane vocabulary)."""
-        if spec:
-            return "spec_verify"
-        if batch.tokens.shape[1] == 1:
-            return "decode"
-        if batch.num_new is not None and bool((np.asarray(batch.num_new) == 1).any()):
-            return "mixed"  # decode rows fused into a multi-column step
-        return "prefill"
+    @contextlib.contextmanager
+    def _dispatch(self, program: str, key: tuple, padded: StepBatch, impl: str | None,
+                  layout: tuple[str, int], *, verify: bool = False):
+        """What every dispatch site runs its jitted call under: the block is
+        timed for the compile tracker, and the step's report takes the
+        dispatch's labels and its seconds. ``key`` is everything the program
+        specializes on after padding (the compile cache key XLA sees);
+        ``layout`` the layout and the token positions it computes."""
+        report = self._report
+        if report is None:
+            report = self._report = DispatchReport(moe_path=self.moe_path)
+        report.attn_phase, report.attn_path = self._attn_dispatch(padded, impl, verify=verify)
+        report.layout, report.step_tokens = layout
+        timed = timed_dispatch(self.compile_tracker, program, key)
+        with timed:
+            yield
+        # A dispatch outside an engine step (a warm-up drives the runner
+        # directly) is no part of the next step's dispatch time.
+        if self.clock is None or self.clock.in_step:
+            report.seconds += timed.seconds
 
-    def _cost_estimate(self, padded: StepBatch, kind: str) -> dict[str, float] | None:
-        """Model-derived {bytes, flops} fallback for one dispatch of this
-        padded bucket: weight stream + page-granular KV window."""
-        try:
-            window_tokens = padded.block_tables.shape[1] * self.page_size
-            itemsize = int(np.dtype(self.k_cache.dtype).itemsize)
-            return decode_step_estimate(
-                self.params, self.cfg, padded.tokens.shape[0], window_tokens,
-                cache_itemsize=itemsize, new_tokens=self.last_step_layout[1],
-            )
-        except Exception:  # estimate is best-effort; pending beats wrong
-            return None
+    @staticmethod
+    def _enqueue(fn, *args, **kwargs):
+        """Calls a jitted step program from a frame of its own. Called from
+        ``step``'s own frame, the first call of every chunk program took 0.45 to
+        0.55 s longer on the v5e's host (a tenth of a cell's warm set-up over
+        its 25 to 28 chunk programs), with the frame in between it does not:
+        measured both ways on four machines, why is not established (PERF.md,
+        PR 28)."""
+        return fn(*args, **kwargs)
 
-    def _cost_call(self, program: str, key: tuple, kind: str, padded: StepBatch,
-                   fn, *args, **kwargs):
-        """Run one jitted dispatch, registering its bucket with the cost
-        registry on first sight. The lowering thunk avatars the arguments
-        *before* the call (donation invalidates the cache buffers after) and
-        is submitted *after* it: the call has compiled the program and
-        written the compile cache by then, so the registry's background
-        ``lower().compile()`` reads that entry instead of compiling a second
-        copy side by side with the serving path's. Warm dispatches pay one
-        set lookup."""
-        reg = self.cost_registry
-        if reg is None or reg.seen(program, key):
-            return fn(*args, **kwargs)
-        lower = None
-        try:
-            lower = make_lower_thunk(fn, args, kwargs)
-        except Exception:
-            logger.debug("cost avatars failed for %s", program, exc_info=True)
-        out = fn(*args, **kwargs)
-        try:
-            reg.submit(program, key, kind, lower=lower, estimate=self._cost_estimate(padded, kind))
-        except Exception:
-            logger.debug("cost submit failed for %s", program, exc_info=True)
-        return out
+    def take_dispatch(self) -> DispatchReport | None:
+        """The report of what was dispatched since the last take, which this
+        take clears: a step that dispatched nothing (one that only harvests)
+        takes ``None`` and cannot count the step before it again."""
+        report, self._report = self._report, None
+        if report is not None:
+            self._count_kv(report)  # an async site has not counted yet
+        return report
+
+    @property
+    def last_attn_dispatch(self) -> tuple[str, str] | None:
+        """(phase, path) of the most recent dispatch not yet taken."""
+        r = self._report
+        return None if r is None else (r.attn_phase, r.attn_path)
+
+    @property
+    def last_step_layout(self) -> tuple[str, int] | None:
+        """(layout, token positions) of the most recent dispatch not yet taken."""
+        r = self._report
+        return None if r is None else (r.layout, r.step_tokens)
 
     def _mark_wait(self) -> None:
         """The jitted call has returned (enqueued); what follows blocks on the
@@ -1017,7 +1019,7 @@ class ModelRunner:
             self.clock.mark_in_step(tracing.WAIT)
         if self.on_enqueued is not None:
             self.on_enqueued()
-        self.take_kv_tokens()  # the device is busy now: host work here costs no step time
+        self._count_kv(self._report)  # the device is busy now: host work here costs no step time
 
     def _chunk_rows(self, padded: StepBatch) -> np.ndarray | None:
         """The rows of a chunk step that take the chunk slots of a split token
@@ -1065,12 +1067,9 @@ class ModelRunner:
         b_real = batch.batch_size
         padded = self._pad(batch)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
-        self.last_attn_dispatch = self._attn_dispatch(padded, impl)
         bp, tp = padded.tokens.shape
         chunk = self._chunk_rows(padded)
-        self.last_step_layout = (ROWS_X_T, bp * tp)
-        # Everything the jitted programs specialize on, post-padding: this is
-        # the compile cache key XLA sees (shapes + static args + arg presence).
+        layout = (ROWS_X_T, bp * tp)
         dispatch_key = (
             bp, tp, padded.block_tables.shape[1], padded.history.shape[1],
             lp_k, impl, self.mesh is not None,
@@ -1083,16 +1082,14 @@ class ModelRunner:
             # batch) is the one-chunk-slot program with that slot padding.
             nc = next_pow2(len(chunk))
             dispatch_key += (SPLIT, nc)
-            self.last_step_layout = (SPLIT, bp + nc * tp)
+            layout = (SPLIT, bp + nc * tp)
             # Row i samples in decode slot i, a chunk row in its chunk slot.
             rows = np.arange(b_real)
             rows[chunk] = bp + np.arange(len(chunk))
-        cost_kind = self._dispatch_kind(batch)
-        with timed_dispatch(self.compile_tracker, "step", dispatch_key,
-                            cost=self.cost_registry, kind=cost_kind):
+        with self._dispatch("step", dispatch_key, padded, impl, layout):
             if chunk is not None:
-                out = self._cost_call(
-                    "step", dispatch_key, cost_kind, padded, self._step_split_fn,
+                out = self._enqueue(
+                    self._step_split_fn,
                     self.params, self.k_cache, self.v_cache, jnp.asarray(_pack_split(padded, chunk, nc)),
                     nd=bp, nc=nc, tc=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1],
                     lp_k=lp_k,
@@ -1109,8 +1106,8 @@ class ModelRunner:
                 def opt(a):
                     return None if a is None else put(a)
 
-                out = self._cost_call(
-                    "step", dispatch_key, cost_kind, padded, self._step_fn,
+                out = self._enqueue(
+                    self._step_fn,
                     self.params, self.k_cache, self.v_cache,
                     put(padded.tokens), put(padded.positions),
                     put(padded.block_tables), put(padded.slot_mapping),
@@ -1131,8 +1128,8 @@ class ModelRunner:
                 def put(a):
                     return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
 
-                out = self._cost_call(
-                    "step", dispatch_key, cost_kind, padded, self._step_fn,
+                out = self._enqueue(
+                    self._step_fn,
                     self.params, self.k_cache, self.v_cache,
                     put(padded.tokens), put(padded.positions),
                     put(padded.block_tables), put(padded.slot_mapping),
@@ -1145,8 +1142,8 @@ class ModelRunner:
                     impl=impl, lp_k=lp_k,
                 )
             else:
-                out = self._cost_call(
-                    "step", dispatch_key, cost_kind, padded, self._step_packed_fn,
+                out = self._enqueue(
+                    self._step_packed_fn,
                     self.params, self.k_cache, self.v_cache, jnp.asarray(_pack(padded)),
                     b=bp, t=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1],
                     lp_k=lp_k,
@@ -1190,15 +1187,13 @@ class ModelRunner:
             padded.last_token_index[:, None],
         ).astype(np.int32)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
-        self.last_attn_dispatch = self._attn_dispatch(padded, impl, verify=True)
-        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         dispatch_key = (
             bp, padded.tokens.shape[1], padded.block_tables.shape[1],
             padded.history.shape[1], verify_width, lp_k, impl, self.mesh is not None,
             padded.mm_embeds is not None, padded.logit_mask is not None,
         )
-        with timed_dispatch(self.compile_tracker, "spec_step", dispatch_key,
-                            cost=self.cost_registry, kind="spec_verify"):
+        with self._dispatch("spec_step", dispatch_key, padded, impl,
+                            (ROWS_X_T, padded.tokens.size), verify=True):
             if self.mesh is not None:
                 from dynamo_tpu.parallel.sharding import batch_sharding
 
@@ -1210,8 +1205,8 @@ class ModelRunner:
             def opt(a):
                 return None if a is None else put(a)
 
-            out = self._cost_call(
-                "spec_step", dispatch_key, "spec_verify", padded, self._spec_step_fn,
+            out = self._enqueue(
+                self._spec_step_fn,
                 self.params, self.k_cache, self.v_cache,
                 put(padded.tokens), put(padded.positions),
                 put(padded.block_tables), put(padded.slot_mapping),
@@ -1244,25 +1239,21 @@ class ModelRunner:
         assert batch.tokens.shape[1] == 1, "multi_step is decode-only"
         b_real = batch.batch_size
         padded = self._pad(batch)
-        self.last_attn_dispatch = self._attn_dispatch(padded, self.attn_impl)
-        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         dispatch_key = (
             padded.tokens.shape[0], padded.tokens.shape[1],
             padded.block_tables.shape[1], padded.history.shape[1],
             num_steps, self.mesh is not None,
         )
-        # steps=num_steps: XLA cost analysis counts the fused loop body once,
-        # so the per-record bytes/flops cover ONE decode iteration.
-        with timed_dispatch(self.compile_tracker, "multi_step", dispatch_key,
-                            cost=self.cost_registry, kind="decode", steps=num_steps):
+        with self._dispatch("multi_step", dispatch_key, padded, self.attn_impl,
+                            (ROWS_X_T, padded.tokens.size)):
             if self.mesh is not None:
                 from dynamo_tpu.parallel.sharding import batch_sharding
 
                 def put(a):
                     return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
 
-                toks, self.k_cache, self.v_cache = self._cost_call(
-                    "multi_step", dispatch_key, "decode", padded, self._multi_step_fn,
+                toks, self.k_cache, self.v_cache = self._enqueue(
+                    self._multi_step_fn,
                     self.params, self.k_cache, self.v_cache,
                     put(padded.tokens[:, 0]), put(padded.positions[:, 0]),
                     put(padded.block_tables), put(padded.temperature),
@@ -1275,8 +1266,8 @@ class ModelRunner:
                 )
             else:
                 b, t = padded.tokens.shape
-                toks, self.k_cache, self.v_cache = self._cost_call(
-                    "multi_step", dispatch_key, "decode", padded, self._multi_step_packed_fn,
+                toks, self.k_cache, self.v_cache = self._enqueue(
+                    self._multi_step_packed_fn,
                     self.params, self.k_cache, self.v_cache, jnp.asarray(_pack(padded)),
                     b=b, t=t, n=padded.block_tables.shape[1], h=padded.history.shape[1],
                     num_steps=num_steps,
@@ -1339,8 +1330,6 @@ class ModelRunner:
         b_real = batch.batch_size
         padded = self._pad(batch)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
-        self.last_attn_dispatch = self._attn_dispatch(padded, impl)
-        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         b, t = padded.tokens.shape
         n = padded.block_tables.shape[1]
         h = padded.history.shape[1]
@@ -1354,9 +1343,7 @@ class ModelRunner:
             padded.mm_embeds is not None, padded.logit_mask is not None,
             padded.la_masks is not None,
         )
-        cost_kind = self._dispatch_kind(batch)
-        with timed_dispatch(self.compile_tracker, "step_async", dispatch_key,
-                            cost=self.cost_registry, kind=cost_kind):
+        with self._dispatch("step_async", dispatch_key, padded, impl, (ROWS_X_T, b * t)):
             if self.mesh is not None or extras:
                 if self.mesh is not None:
                     from dynamo_tpu.parallel.sharding import batch_sharding
@@ -1380,8 +1367,7 @@ class ModelRunner:
                     put(padded.mrope_delta),
                 )
                 if chain:
-                    out = self._cost_call(
-                        "step_async", dispatch_key, cost_kind, padded,
+                    out = self._enqueue(
                         self._step_chained_explicit_fn,
                         self.params, self.k_cache, self.v_cache,
                         self._chain_tokens, put(src), *explicit,
@@ -1391,8 +1377,7 @@ class ModelRunner:
                         impl=impl, lp_k=lp_k,
                     )
                 else:
-                    out = self._cost_call(
-                        "step_async", dispatch_key, cost_kind, padded,
+                    out = self._enqueue(
                         self._step_fn,
                         self.params, self.k_cache, self.v_cache, *explicit,
                         opt(padded.mm_embeds), opt(padded.mm_slot_offset),
@@ -1403,16 +1388,14 @@ class ModelRunner:
             else:
                 packed = jnp.asarray(_pack(padded))
                 if chain:
-                    out = self._cost_call(
-                        "step_async", dispatch_key, cost_kind, padded,
+                    out = self._enqueue(
                         self._step_chained_fn,
                         self.params, self.k_cache, self.v_cache, packed,
                         self._chain_tokens, jnp.asarray(src),
                         b=b, t=t, n=n, h=h, lp_k=lp_k,
                     )
                 else:
-                    out = self._cost_call(
-                        "step_async", dispatch_key, cost_kind, padded,
+                    out = self._enqueue(
                         self._step_packed_fn,
                         self.params, self.k_cache, self.v_cache, packed,
                         b=b, t=t, n=n, h=h, lp_k=lp_k,
@@ -1458,8 +1441,6 @@ class ModelRunner:
             padded.last_token_index[:, None],
         ).astype(np.int32)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
-        self.last_attn_dispatch = self._attn_dispatch(padded, impl, verify=True)
-        self.last_step_layout = (ROWS_X_T, padded.tokens.size)
         chain = chain_src is not None
         src = self._chain_src_padded(chain_src, b_real, bp) if chain else None
         dispatch_key = (
@@ -1467,8 +1448,8 @@ class ModelRunner:
             padded.history.shape[1], verify_width, lp_k, chain, impl,
             self.mesh is not None,
         )
-        with timed_dispatch(self.compile_tracker, "spec_step_async", dispatch_key,
-                            cost=self.cost_registry, kind="spec_verify"):
+        with self._dispatch("spec_step_async", dispatch_key, padded, impl,
+                            (ROWS_X_T, padded.tokens.size), verify=True):
             if self.mesh is not None:
                 from dynamo_tpu.parallel.sharding import batch_sharding
 
@@ -1485,16 +1466,14 @@ class ModelRunner:
                 put(padded.mrope_delta),
             )
             if chain:
-                out = self._cost_call(
-                    "spec_step_async", dispatch_key, "spec_verify", padded,
+                out = self._enqueue(
                     self._spec_step_chained_fn,
                     self.params, self.k_cache, self.v_cache,
                     self._chain_tokens, put(src), *explicit,
                     impl=impl, lp_k=lp_k,
                 )
             else:
-                out = self._cost_call(
-                    "spec_step_async", dispatch_key, "spec_verify", padded,
+                out = self._enqueue(
                     self._spec_step_fn,
                     self.params, self.k_cache, self.v_cache, *explicit,
                     impl=impl, lp_k=lp_k,

@@ -80,9 +80,6 @@ class JaxEngineService(AsyncEngine[Any, dict]):
         # step_lock, so running it in the executor waits that step out
         # before touching the engine state it is mutating.
         await asyncio.get_running_loop().run_in_executor(None, self.core.abort_all)
-        cost = getattr(self.core.runner, "cost_registry", None)
-        if cost is not None:  # its background compiles must not outlive us
-            await asyncio.get_running_loop().run_in_executor(None, cost.close)
         # In-flight streams would otherwise wait forever for a sentinel the
         # dead loop can never send (their consumers hang on shutdown/crash).
         self._drain_intake_failed()

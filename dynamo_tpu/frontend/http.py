@@ -96,7 +96,6 @@ class HttpService:
                 web.get("/debug/traces/{request_id}", self.debug_traces),
                 web.get("/debug/explain/{request_id}", self.debug_explain),
                 web.get("/debug/flight/{worker}", self.debug_flight),
-                web.get("/debug/cost", self.debug_cost),
                 web.get("/debug/profile/{worker}", self.debug_profile_status),
                 web.post("/debug/profile/{worker}", self.debug_profile_capture),
                 web.get("/debug/incidents", self.debug_incidents),
@@ -549,23 +548,6 @@ class HttpService:
                 },
             }
         )
-
-    async def debug_cost(self, request: web.Request) -> web.Response:
-        """Fleet-wide device-cost snapshot: per-worker chip peaks, the
-        per-compiled-program cost table (XLA flops / bytes-accessed / peak
-        memory joined with measured dispatch wall) and the per-step-kind
-        roofline ledger. A worker with ``DYN_COST_PLANE=0`` reports
-        ``enabled: false`` rather than vanishing from the listing."""
-        if self.telemetry is None:
-            return web.json_response(
-                {"error": "no worker telemetry wired on this frontend"}, status=404
-            )
-        try:
-            workers = await self.telemetry.collect_cost()
-        except Exception:
-            logger.exception("cost fan-out failed")
-            return web.json_response({"error": "cost fan-out failed"}, status=502)
-        return web.json_response({"count": len(workers), "workers": workers})
 
     async def debug_profile_status(self, request: web.Request) -> web.Response:
         """Profile-capture availability: is ``jax.profiler`` usable on the

@@ -470,10 +470,8 @@ async def _serve_worker_telemetry(
     )
     from dynamo_tpu.observability.metrics import install
     from dynamo_tpu.observability.service import (
-        COST_ENDPOINT,
         DEBUG_INCIDENTS_ENDPOINT,
         PROFILE_ENDPOINT,
-        CostQueryService,
         IncidentQueryService,
         ProfileCaptureService,
     )
@@ -512,16 +510,6 @@ async def _serve_worker_telemetry(
             IncidentQueryService(incidents.store, worker=worker_id),
             metadata=metadata, lease=lease,
         )
-    runner = getattr(service.core, "runner", None)
-    if runner is not None:
-        # Served even when DYN_COST_PLANE=0 — the service answers
-        # {"enabled": False}, so operators can tell "off" from "dead".
-        await component.endpoint(COST_ENDPOINT).serve(
-            CostQueryService(runner, worker=worker_id), metadata=metadata, lease=lease
-        )
-        cost_reg = getattr(runner, "cost_registry", None)
-        if cost_reg is not None:
-            cost_reg.worker = worker_id
     await component.endpoint(PROFILE_ENDPOINT).serve(
         ProfileCaptureService(worker=worker_id), metadata=metadata, lease=lease
     )
@@ -532,7 +520,6 @@ async def _serve_worker_telemetry(
         debug = WorkerDebugServer(
             metrics, flight=flight,
             incidents=incidents.store if incidents is not None else None,
-            cost=getattr(runner, "cost_registry", None),
         )
         await debug.start(port=int(port_spec))
         service.aux.append(debug)

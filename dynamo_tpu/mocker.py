@@ -32,7 +32,7 @@ from types import SimpleNamespace
 import numpy as np
 
 from dynamo_tpu.engine.core import EngineConfig, EngineCore
-from dynamo_tpu.engine.runner import StepBatch
+from dynamo_tpu.engine.runner import DispatchReport, StepBatch
 from dynamo_tpu.engine.service import JaxEngineService
 
 
@@ -83,13 +83,7 @@ class MockRunner:
         # loop (step_async) pays it only at harvest, where it hides under the
         # next step's compute. 0 keeps legacy timing for existing tests.
         self.d2h_us = d2h_us
-        # Device-cost plane: mock fleets light the same roofline surfaces
-        # (flight hbm_bytes, /debug/cost, metrics) the real runner does —
-        # there is no XLA program to extract from, so the synthetic
-        # estimate IS the cost record (source stays "estimate").
-        from dynamo_tpu.observability.cost import CostRegistry, cost_plane_enabled
-
-        self.cost_registry = CostRegistry() if cost_plane_enabled() else None
+        self._report: DispatchReport | None = None  # of what was dispatched since the last take
         self.simulated_us = 0.0
         # Device-busy accounting for the overlap bench probe: cumulative
         # compute time vs. wall elapsed gives device_idle_frac.
@@ -138,34 +132,11 @@ class MockRunner:
         top_ids[:, 0] = toks
         return {"logprob": lps, "top_ids": top_ids.astype(np.int32), "top_lps": top_lps}
 
-    #: synthetic weight-stream bytes each processed token "moves" — scales
-    #: the mock cost records without pretending to model a real chip.
-    _MOCK_BYTES_PER_TOKEN = 65536
-
-    def _observe_cost(self, batch: StepBatch, compute_us: float, *, spec: bool = False) -> None:
-        reg = self.cost_registry
-        if reg is None:
-            return
-        b, t = batch.tokens.shape
-        if spec:
-            kind = "spec_verify"
-        elif t == 1:
-            kind = "decode"
-        elif batch.num_new is not None and bool((np.asarray(batch.num_new) == 1).any()):
-            kind = "mixed"
-        else:
-            kind = "prefill"
-        key = (b, t)
-        if not reg.seen("mock_step", key):
-            tokens = b * t
-            reg.submit(
-                "mock_step", key, kind,
-                estimate={
-                    "bytes": self._MOCK_BYTES_PER_TOKEN * tokens,
-                    "flops": 2 * self._MOCK_BYTES_PER_TOKEN * tokens,
-                },
-            )
-        reg.observe("mock_step", key, compute_us / 1e6, kind)
+    def take_dispatch(self) -> DispatchReport | None:
+        """``ModelRunner.take_dispatch``. There is no program, so the report
+        holds no label and no dispatch time (the engine's step wall stands in)."""
+        report, self._report = self._report, None
+        return report
 
     def step(self, batch: StepBatch, lp_k: int = 0):
         b, t = batch.tokens.shape
@@ -179,7 +150,7 @@ class MockRunner:
             self.busy_us += compute
             # The synchronous loop blocks on compute AND the result copy.
             self._sleep_us(compute + self.d2h_us)
-        self._observe_cost(batch, compute)
+        self._report = DispatchReport()
         last_tok = batch.tokens[np.arange(b), batch.last_token_index]
         last_pos = batch.positions[np.arange(b), batch.last_token_index]
         toks = self._tokens_for(last_pos, last_tok)
@@ -231,7 +202,7 @@ class MockRunner:
         compute = self._mixed_compute_us(batch)
         self.busy_us += compute
         self.simulated_us += compute + self.d2h_us
-        self._observe_cost(batch, compute)
+        self._report = DispatchReport()
         now = time.monotonic()
         start = max(now, self._busy_until)
         self._busy_until = start + compute / 1e6
@@ -265,7 +236,7 @@ class MockRunner:
         compute = self._mixed_compute_us(batch)
         self.busy_us += compute
         self._sleep_us(compute + self.d2h_us)
-        self._observe_cost(batch, compute, spec=True)
+        self._report = DispatchReport()
         targets = self._spec_targets(batch, verify_width, batch.tokens)
         if lp_k:
             return targets, self._spec_lp_aux(targets, lp_k)
@@ -288,7 +259,7 @@ class MockRunner:
         compute = self._mixed_compute_us(batch)
         self.busy_us += compute
         self.simulated_us += compute + self.d2h_us
-        self._observe_cost(batch, compute, spec=True)
+        self._report = DispatchReport()
         start = max(time.monotonic(), self._busy_until)
         self._busy_until = start + compute / 1e6
         ready_at = self._busy_until + self.d2h_us / 1e6
@@ -309,6 +280,7 @@ class MockRunner:
         self._chain_host = None
 
     def multi_step(self, batch: StepBatch, num_steps: int) -> np.ndarray:
+        self._report = DispatchReport()
         b = batch.tokens.shape[0]
         out = np.zeros((b, num_steps), np.int32)
         tok = batch.tokens[:, 0]

@@ -16,14 +16,10 @@ Two surfaces over the same worker internals:
 - :mod:`incidents` — capture-on-anomaly black-box bundles: a size-capped
   on-disk store of flight/span/loss snapshots written at anomaly rising
   edges, engine-step crashes, and SLO burn-rate alerts.
-- :mod:`cost` — the device-cost plane: per-compiled-program XLA cost
-  analysis (flops / bytes-accessed / peak memory) joined with measured
-  dispatch wall into a live roofline ledger per step kind.
 """
 
 from dynamo_tpu.observability.anomaly import ANOMALY_KINDS, AnomalySentinel
 from dynamo_tpu.observability.compile import CompileTracker, timed_dispatch
-from dynamo_tpu.observability.cost import CostRegistry, chip_peaks, cost_plane_enabled
 from dynamo_tpu.observability.flight import FlightRecorder
 from dynamo_tpu.observability.incidents import (
     INCIDENT_KINDS,
@@ -32,14 +28,12 @@ from dynamo_tpu.observability.incidents import (
 )
 from dynamo_tpu.observability.metrics import EngineMetrics, federate_text, observe_kv_phase
 from dynamo_tpu.observability.service import (
-    COST_ENDPOINT,
     DEBUG_EXPLAIN_ENDPOINT,
     DEBUG_INCIDENTS_ENDPOINT,
     DEBUG_TRACES_ENDPOINT,
     FLIGHT_ENDPOINT,
     METRICS_SCRAPE_ENDPOINT,
     PROFILE_ENDPOINT,
-    CostQueryService,
     ExplainQueryService,
     FlightQueryService,
     IncidentQueryService,
@@ -64,12 +58,7 @@ __all__ = [
     "EngineMetrics",
     "federate_text",
     "observe_kv_phase",
-    "CostRegistry",
-    "chip_peaks",
-    "cost_plane_enabled",
-    "COST_ENDPOINT",
     "PROFILE_ENDPOINT",
-    "CostQueryService",
     "ProfileCaptureService",
     "DEBUG_EXPLAIN_ENDPOINT",
     "DEBUG_INCIDENTS_ENDPOINT",

@@ -179,8 +179,6 @@ def build_explain(
     barrier_ms: dict[str, float] = {}
     recompile_ms = 0.0
     steps_in_window = 0
-    roofline_weighted = 0.0
-    roofline_weight_ms = 0.0
     if step_docs:
         best: list[dict] = []
         for wid, steps in _steps_by_worker(step_docs).items():
@@ -198,13 +196,6 @@ def build_explain(
             compute = dispatch if dispatch > 0.0 else wall
             host = max(0.0, wall - compute)
             compute_ms += compute
-            # Device-cost plane: STEP records carry the step's roofline
-            # fraction; the dispatch-weighted mean annotates decode_compute
-            # so a postmortem can tell "compute was the bottleneck" from
-            # "we left bandwidth on the table".
-            if s.get("roofline_frac") is not None:
-                roofline_weight_ms += compute
-                roofline_weighted += float(s["roofline_frac"]) * compute
             gap_ms += float(s.get("gap_ms") or 0.0)
             reason = s.get("barrier_reason") or ""
             if s.get("overlap_mode") == "barrier" and reason:
@@ -267,13 +258,7 @@ def build_explain(
     for name in _KV_SPANS:
         seg(name, kv_ms[name])
     seg("transfer_wait", transfer_wait_ms)
-    if roofline_weight_ms > 0.0:
-        seg(
-            "decode_compute", compute_ms,
-            roofline_frac=round(roofline_weighted / roofline_weight_ms, 4),
-        )
-    else:
-        seg("decode_compute", compute_ms)
+    seg("decode_compute", compute_ms)
     seg("gap", gap_ms)
     for reason in sorted(barrier_ms, key=barrier_ms.get, reverse=True):
         seg(f"barrier:{reason}", barrier_ms[reason], reason=reason)

@@ -84,10 +84,8 @@ class CompileTracker:
         # Dispatch indices of slow (new_shape) compiles, for the storm window.
         self._slow_marks: deque[int] = deque(maxlen=max(1, storm_threshold))
         self.storm_warned = False
-        # Cumulative seconds spent inside runner dispatch calls — the engine
-        # core diffs this across a step to attribute in-step dispatch time.
+        # Cumulative seconds spent inside runner dispatch calls.
         self.dispatch_seconds_total = 0.0
-        self.last_dispatch_seconds = 0.0
 
     def bind_sink(self, sink: Callable[..., Any] | None) -> "CompileTracker":
         """``sink(kind, **fields)`` receives compile/storm events — wired to
@@ -106,7 +104,6 @@ class CompileTracker:
             self._dispatches += 1
             dispatch_idx = self._dispatches
             self.dispatch_seconds_total += max(0.0, seconds)
-            self.last_dispatch_seconds = max(0.0, seconds)
             full_key = (program, *key)
             if full_key in self._seen:
                 return None
@@ -190,23 +187,17 @@ class timed_dispatch:
     ...     out = self._step_fn(...)
 
     A ``None`` tracker makes it a no-op, so call sites need no branching.
-    ``cost``/``kind`` optionally forward the same (program, key, seconds)
-    observation to a :class:`~dynamo_tpu.observability.cost.CostRegistry`
-    on clean exit — the cost plane rides the exact bucket keys this
-    tracker already sees, without a second timing wrapper.
+    ``seconds`` holds the block's wall time after a clean exit (0.0 after a
+    raise, which the tracker does not see either).
     """
 
-    __slots__ = ("tracker", "program", "key", "cost", "kind", "steps", "_t0")
+    __slots__ = ("tracker", "program", "key", "seconds", "_t0")
 
-    def __init__(self, tracker: CompileTracker | None, program: str, key: tuple,
-                 *, cost: Any | None = None, kind: str | None = None,
-                 steps: int = 1) -> None:
+    def __init__(self, tracker: CompileTracker | None, program: str, key: tuple) -> None:
         self.tracker = tracker
         self.program = program
         self.key = key
-        self.cost = cost
-        self.kind = kind
-        self.steps = steps
+        self.seconds = 0.0
         self._t0 = 0.0
 
     def __enter__(self) -> "timed_dispatch":
@@ -216,11 +207,6 @@ class timed_dispatch:
     def __exit__(self, exc_type, exc, tb) -> None:
         if exc_type is not None:
             return
-        seconds = time.perf_counter() - self._t0
+        self.seconds = time.perf_counter() - self._t0
         if self.tracker is not None:
-            self.tracker.observe(self.program, self.key, seconds)
-        if self.cost is not None:
-            try:
-                self.cost.observe(self.program, self.key, seconds, self.kind, steps=self.steps)
-            except Exception:
-                logger.exception("cost observe failed")
+            self.tracker.observe(self.program, self.key, self.seconds)

@@ -46,6 +46,21 @@ COMPILE = "compile"
 CRASH = "crash"
 ANOMALY = "anomaly"
 
+#: Every key of a STEP record (``EngineCore.step`` writes them all, whatever
+#: the runner; docs/OBSERVABILITY.md has the table). ``dispatch_ms`` down to
+#: ``layout`` are the runner's ``DispatchReport`` of the step.
+STEP_KEYS = (
+    "seq", "ts", "kind", "step_kind", "decode_rows", "chunk_rows", "chunk_tokens",
+    "outputs", "waiting", "running", "prefilling", "free_pages", "preemptions",
+    "admission_rejections", "mixed_steps", "stall_violations",
+    "spec_drafted", "spec_accepted", "spec_accept_rate", "wall_ms",
+    "dispatch_ms", "attn_phase", "attn_path", "moe_path",
+    "kv_tokens_full", "kv_tokens_window", "step_tokens", "layout",
+    "admitted", "deferred", "deadline_slack_ms", "cached_frac", "gap_ms",
+    "overlap_mode", "barrier_reason", "chained_rows",
+    "t0_ns", "ann_ns", "traced", "phases_us",
+)
+
 _DEFAULT_CAPACITY = 2048
 _DUMP_DIR_ENV = "DYN_FLIGHT_DUMP_DIR"
 _CAPACITY_ENV = "DYN_FLIGHT_BUFFER"

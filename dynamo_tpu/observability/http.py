@@ -22,12 +22,11 @@ WORKER_HTTP_ENV = "DYN_WORKER_HTTP_PORT"
 
 class WorkerDebugServer:
     def __init__(
-        self, metrics: EngineMetrics, *, flight=None, incidents=None, cost=None
+        self, metrics: EngineMetrics, *, flight=None, incidents=None
     ) -> None:
         self.metrics = metrics
         self.flight = flight  # this worker's FlightRecorder, if it has one
         self.incidents = incidents  # this worker's IncidentStore, if it has one
-        self.cost = cost  # this worker's CostRegistry, if the cost plane is on
         self._runner: web.AppRunner | None = None
         self.port: int | None = None
         self.app = web.Application()
@@ -36,7 +35,6 @@ class WorkerDebugServer:
                 web.get("/metrics", self.prometheus),
                 web.get("/debug/traces/{request_id}", self.traces),
                 web.get("/debug/flight", self.flight_dump),
-                web.get("/debug/cost", self.cost_dump),
                 web.get("/debug/incidents", self.incidents_list),
                 web.get("/debug/incidents/{incident_id}", self.incident_get),
             ]
@@ -63,13 +61,6 @@ class WorkerDebugServer:
             last=int(last) if last else None, kind=request.query.get("kind")
         )
         return web.json_response({"records": records, "count": len(records)})
-
-    async def cost_dump(self, request: web.Request) -> web.Response:
-        if self.cost is None:
-            # Distinguish "cost plane off" from a wrong URL: 200 with
-            # enabled=False mirrors the telemetry-endpoint behavior.
-            return web.json_response({"enabled": False})
-        return web.json_response(self.cost.snapshot())
 
     async def incidents_list(self, request: web.Request) -> web.Response:
         if self.incidents is None:

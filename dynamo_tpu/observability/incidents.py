@@ -184,13 +184,12 @@ def _device_trace_state() -> dict:
     ``recompile_storm`` / ``step_gap_regression`` bundle), and where
     artifacts land."""
     from dynamo_tpu import tracing
-    from dynamo_tpu.observability.cost import profile_artifact_dir, profiler_available
 
     return {
         "armed": tracing.trace_running(),
         "dir": os.environ.get("DYN_TRACE_DIR"),
-        "capture_available": profiler_available(),
-        "artifact_dir": profile_artifact_dir(),
+        "capture_available": tracing.profiler_available(),
+        "artifact_dir": tracing.profile_artifact_dir(),
     }
 
 
@@ -264,13 +263,6 @@ class IncidentCapture:
         loss = None
         if self.core is not None and hasattr(self.core, "loss_snapshot"):
             loss = self.core.loss_snapshot()
-        cost = None
-        cost_reg = getattr(getattr(self.core, "runner", None), "cost_registry", None)
-        if cost_reg is not None:
-            try:
-                cost = cost_reg.snapshot()
-            except Exception:
-                logger.exception("cost snapshot for incident bundle failed (ignored)")
         return {
             "ts": now,
             "kind": kind,
@@ -280,7 +272,6 @@ class IncidentCapture:
             "flight": records,
             "spans": spans,
             "loss": loss,
-            "cost": cost,
             "config": _config_snapshot(self.settings),
             "device_trace": _device_trace_state(),
         }

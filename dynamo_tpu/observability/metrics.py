@@ -258,35 +258,6 @@ class EngineMetrics:
         self._lost_time_synced: dict[str, float] = {}
         self._step_time_synced: dict[str, float] = {}
         self._step_kinds_synced: dict[str, int] = {}
-        # Device-cost plane (observability/cost.py): roofline fraction per
-        # step kind from the XLA cost-analysis ledger joined with measured
-        # dispatch wall, plus true monotone byte/flop Counters delta-synced
-        # from the registry's cumulative totals (same watermark scheme as
-        # the lost-time Counter; a retroactive downward estimate correction
-        # never decrements — the watermark holds until totals regrow).
-        self._roofline = Gauge(
-            "dynamo_engine_roofline_frac",
-            "Achieved fraction of the chip's peak on the binding resource "
-            "per step kind (prefill / decode / mixed / spec_verify); the "
-            "bound label names the binding side (memory = HBM bandwidth, "
-            "compute = FLOP/s). Peaks come from DYN_PEAK_HBM_GBPS / "
-            "DYN_PEAK_TFLOPS or the built-in per-chip table",
-            ["worker", "step_kind", "bound"], registry=self.registry,
-        )
-        self._hbm_bytes = Counter(
-            "dynamo_engine_hbm_bytes",
-            "HBM bytes moved by engine dispatches per XLA cost analysis "
-            "(model-derived estimate until the background extraction "
-            "lands), by step kind",
-            ["worker", "step_kind"], registry=self.registry,
-        )
-        self._flops = Counter(
-            "dynamo_engine_flops",
-            "Floating-point operations executed by engine dispatches per "
-            "XLA cost analysis, by step kind",
-            ["worker", "step_kind"], registry=self.registry,
-        )
-        self._cost_synced: dict[tuple[str, str], float] = {}
         # Anomaly sentinel: 1 while a rolling-window detector is active on
         # this worker (hysteresis in the sentinel, not here), keyed by the
         # detector kind; fired totals count rising edges ever.
@@ -379,7 +350,6 @@ class EngineMetrics:
         self._lost_time_synced.clear()
         self._step_time_synced.clear()
         self._step_kinds_synced.clear()
-        self._cost_synced.clear()
         return self
 
     def bind_transfer(self, transfer: Any) -> "EngineMetrics":
@@ -450,22 +420,6 @@ class EngineMetrics:
             self._recompiles.clear()
             for (program, reason), n in tracker.counts().items():
                 self._recompiles.labels(self.worker, program, reason).set(n)
-        cost_reg = getattr(getattr(core, "runner", None), "cost_registry", None)
-        if cost_reg is not None:
-            self._roofline.clear()
-            for step_kind, row in cost_reg.ledger().items():
-                self._roofline.labels(
-                    self.worker, step_kind, row.get("bound") or "memory"
-                ).set(float(row.get("roofline_frac", 0.0)))
-            for step_kind, tot in cost_reg.totals().items():
-                for fam, counter in (
-                    ("bytes", self._hbm_bytes), ("flops", self._flops),
-                ):
-                    cur = float(tot.get(fam, 0.0))
-                    prev = self._cost_synced.get((step_kind, fam), 0.0)
-                    if cur > prev:
-                        counter.labels(self.worker, step_kind).inc(cur - prev)
-                        self._cost_synced[(step_kind, fam)] = cur
         dispatch = getattr(core, "attn_dispatch_counts", None)
         if dispatch is not None:
             self._attn_dispatch.clear()

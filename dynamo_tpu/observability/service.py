@@ -13,9 +13,6 @@ Every worker serves extra runtime endpoints next to ``generate``:
 - ``debug_incidents`` (:class:`IncidentQueryService`) — the worker's
   on-disk incident bundles (``observability/incidents.py``), the worker
   half of ``GET /debug/incidents[/{id}]``;
-- ``debug_cost`` (:class:`CostQueryService`) — the runner's device-cost
-  registry snapshot (``observability/cost.py``), the worker half of
-  ``GET /debug/cost``;
 - ``debug_profile`` (:class:`ProfileCaptureService`) — arms a bounded
   ``jax.profiler`` device trace on the worker, the worker half of
   ``POST /debug/profile/{worker}``.
@@ -47,7 +44,6 @@ METRICS_SCRAPE_ENDPOINT = "metrics_scrape"
 FLIGHT_ENDPOINT = "debug_flight"
 DEBUG_EXPLAIN_ENDPOINT = "debug_explain"
 DEBUG_INCIDENTS_ENDPOINT = "debug_incidents"
-COST_ENDPOINT = "debug_cost"
 PROFILE_ENDPOINT = "debug_profile"
 
 _FANOUT_TIMEOUT = 5.0
@@ -165,30 +161,6 @@ class IncidentQueryService(AsyncEngine[Any, dict]):
             yield {"worker": self.worker, "incidents": self.store.list()}
 
 
-class CostQueryService(AsyncEngine[Any, dict]):
-    """Answers any request with the runner's device-cost registry snapshot.
-
-    The snapshot is the ``GET /debug/cost`` body for one worker: chip peaks,
-    the per-compiled-program cost table and the per-step-kind roofline
-    ledger. A worker whose cost plane is disabled (``DYN_COST_PLANE=0``)
-    answers ``{"enabled": False}`` rather than dropping off the fan-out —
-    an operator must be able to tell "off" from "dead".
-    """
-
-    def __init__(self, runner, *, worker: str = "") -> None:
-        self.runner = runner
-        self.worker = worker or f"pid-{os.getpid()}"
-
-    async def generate(self, request: Any, context: Context) -> AsyncIterator[dict]:
-        registry = getattr(self.runner, "cost_registry", None)
-        if registry is None:
-            yield {"worker": self.worker, "enabled": False}
-            return
-        doc = registry.snapshot()
-        doc["worker"] = self.worker
-        yield doc
-
-
 class ProfileCaptureService(AsyncEngine[Any, dict]):
     """Arms a bounded ``jax.profiler`` device trace on this worker.
 
@@ -210,12 +182,12 @@ class ProfileCaptureService(AsyncEngine[Any, dict]):
         self.worker = worker or f"pid-{os.getpid()}"
 
     def _status(self) -> dict:
-        from dynamo_tpu.observability.cost import (
+        from dynamo_tpu.tracing import (
             profile_artifact_dir,
             profile_max_ms,
             profiler_available,
+            trace_running,
         )
-        from dynamo_tpu.tracing import trace_running
 
         return {
             "worker": self.worker,
@@ -226,12 +198,12 @@ class ProfileCaptureService(AsyncEngine[Any, dict]):
         }
 
     async def generate(self, request: Any, context: Context) -> AsyncIterator[dict]:
-        from dynamo_tpu.observability.cost import (
+        from dynamo_tpu.tracing import (
             profile_artifact_dir,
+            profile_for,
             profile_max_ms,
             profiler_available,
         )
-        from dynamo_tpu.tracing import profile_for
 
         request = request or {}
         if request.get("action", "status") != "capture":
@@ -408,18 +380,6 @@ class WorkerTelemetryClient:
                 continue
             wid = str(res.get("worker", f"{inst.instance_id:x}"))
             out[wid] = res.get("incidents", [])
-        return out
-
-    async def collect_cost(self) -> dict[str, dict]:
-        """Device-cost snapshots by worker id (the /debug/cost body)."""
-        targets = await self._targets(COST_ENDPOINT)
-        results = await asyncio.gather(*(self._ask(t, {}) for t in targets))
-        out: dict[str, dict] = {}
-        for inst, res in zip(targets, results):
-            if res is None:
-                continue
-            wid = str(res.pop("worker", f"{inst.instance_id:x}"))
-            out[wid] = res
         return out
 
     async def profile_status(self, worker: str | None = None) -> dict[str, dict]:

@@ -286,7 +286,10 @@ class Client:
         # the non-draining set, then to anything alive, rather than fail.
         return pool or active or alive
 
-    def _pick(self, instance_id: int | None) -> Instance:
+    def _pick(self, instance_id: int | None, tried: frozenset[int] | set[int] = frozenset()) -> Instance:
+        """The instance for one attempt. ``tried`` holds the instances this
+        request has already failed on: a retry goes to another replica while
+        there is one, whatever the failed one's breaker says so far."""
         if instance_id is not None:
             inst = self._instances.get(instance_id)
             if inst is None:
@@ -319,6 +322,7 @@ class Client:
                 endpoint_path=self.endpoint.path,
                 known_instances=len(self._instances),
             )
+        pool = [i for i in pool if i.instance_id not in tried] or pool
         if self.router_mode == "random":
             return random.choice(pool)
         self._rr_counter += 1
@@ -353,8 +357,10 @@ class Client:
         transport = self.endpoint.runtime.transport
         attempts = self._max_attempts if instance_id is None else 1
         last_error: Exception | None = None
+        tried: set[int] = set()
         for _ in range(attempts):
-            inst = self._pick(instance_id)
+            inst = self._pick(instance_id, tried)
+            tried.add(inst.instance_id)
             breaker = self._breaker_for(inst.instance_id)
             breaker.begin_attempt(time.monotonic())
             # Traced requests get a per-hop client span; its span_id becomes

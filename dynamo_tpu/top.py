@@ -216,25 +216,6 @@ def render(snap: FleetSnapshot, *, url: str, now: float | None = None) -> str:
     else:
         lines.append("  (no lost-time ledger samples)")
 
-    # --- roofline ---------------------------------------------------------
-    # Device-cost plane: achieved fraction of the chip's peak per worker
-    # and step kind, with which resource binds (memory vs compute).
-    roofline: list[tuple[str, str, str, float]] = []
-    for n, lab, v in snap.samples:
-        if n == "dynamo_engine_roofline_frac" and "step_kind" in lab:
-            roofline.append(
-                (lab.get("worker", "?"), lab["step_kind"], lab.get("bound", "?"), v)
-            )
-    lines.append("roofline (frac of chip peak, by step kind)")
-    if roofline:
-        for worker, step_kind, bound, frac in sorted(roofline)[:8]:
-            bar = "#" * int(min(1.0, max(0.0, frac)) * 20)
-            lines.append(
-                f"  {worker:<18} {step_kind:<12} {frac:>6.3f} [{bar:<20}] {bound}-bound"
-            )
-    else:
-        lines.append("  (no cost-plane samples; DYN_COST_PLANE=0 or no steps yet)")
-
     # --- federation health ------------------------------------------------
     failures = snap.by_label("dynamo_federation_scrape_failures_total", "worker")
     fed_failures = snap.federation.get("failures") or {}

@@ -56,6 +56,33 @@ def trace_running() -> bool:
     return _active_dir is not None
 
 
+def profiler_available() -> bool:
+    """Whether this process can arm a device trace (jax.profiler present)."""
+    try:
+        import jax.profiler  # noqa: F401
+
+        return hasattr(jax.profiler, "start_trace")
+    except Exception:
+        return False
+
+
+def profile_max_ms() -> float:
+    """Hard cap on one on-demand capture's duration (``DYN_PROFILE_MAX_MS``)."""
+    try:
+        return float(os.environ.get("DYN_PROFILE_MAX_MS", "10000"))
+    except ValueError:
+        return 10000.0
+
+
+def profile_artifact_dir() -> str:
+    """The root the on-demand captures' XPlane dumps land under (``DYN_PROFILE_DIR``)."""
+    import tempfile
+
+    return os.environ.get("DYN_PROFILE_DIR") or os.path.join(
+        tempfile.gettempdir(), "dynamo-profiles"
+    )
+
+
 def profiler_options():
     """The one place the profiler session's options are set.
 
@@ -222,10 +249,15 @@ class StepClock:
             self._open_region(phase)
         return now
 
+    @property
+    def in_step(self) -> bool:
+        """Between :meth:`begin` and the step's ``record`` phase."""
+        return self._phase < RECORD
+
     def mark_in_step(self, phase: int) -> None:
         """``mark`` for a callee that also runs outside steps (the runner,
         which a warm-up drives directly): only a step's phase gives way."""
-        if self._phase < RECORD:
+        if self.in_step:
             self.mark(phase)
 
     def end(self) -> None:

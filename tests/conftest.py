@@ -36,14 +36,6 @@ enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-# The device-cost plane (observability/cost.py) is default-ON in production,
-# but its background extraction thread re-lowers and re-compiles every
-# dispatched program — duplicate compile work that the full suite pays in
-# every runner/engine test and that pushes it past the tier-1 wall budget on
-# CPU. Default it off for tests; test_cost_plane.py (and the bench probe
-# structure test) opt back in explicitly.
-os.environ.setdefault("DYN_COST_PLANE", "0")
-
 import asyncio  # noqa: E402
 import inspect  # noqa: E402
 

@@ -124,9 +124,6 @@ def test_decode_kernel_probe_structure(monkeypatch):
 
     monkeypatch.setenv("BENCH_DK_BATCHES", "1,2")
     monkeypatch.setenv("BENCH_DK_CONTEXTS", "24,40")
-    # conftest defaults the device-cost plane off for the suite; this test
-    # asserts the probe's live_roofline_frac join, so opt back in.
-    monkeypatch.setenv("DYN_COST_PLANE", "1")
     monkeypatch.setenv("BENCH_DK_PAGE_SIZE", "8")
     monkeypatch.setenv("BENCH_DK_HEADS", "4")
     monkeypatch.setenv("BENCH_DK_KV", "2")
@@ -149,9 +146,6 @@ def test_decode_kernel_probe_structure(monkeypatch):
     assert out["decode_kernel_gbps"] == max(
         c["gbytes_per_sec"] for c in out["grid"])
     assert out["decode_roofline_frac"] > 0
-    # Device-cost plane cross-check (ISSUE 19): the probe feeds its measured
-    # cells through a CostRegistry, so the live-ledger fraction rides along.
-    assert out["live_roofline_frac"] > 0
 
 
 def test_slo_sched_probe_structure(monkeypatch):
@@ -284,14 +278,10 @@ def test_bench_doc_goodput_keys():
     assert doc2["spec_accept_rate"] == 0.6
     assert doc2["spec_decode_speedup"] == 1.8
     assert doc2["decode_kernel_gbps"] == 0.0  # probe absent: stable default
-    dk = {"decode_kernel_gbps": 700.5, "decode_roofline_frac": 0.8553,
-          "live_roofline_frac": 0.8101}
+    dk = {"decode_kernel_gbps": 700.5, "decode_roofline_frac": 0.8553}
     doc3 = bench.build_doc(configs, pull={}, decode_kernel=dk)
     assert doc3["decode_kernel_gbps"] == 700.5
     assert doc3["decode_roofline_frac"] == 0.8553
-    # Device-cost plane headline (ISSUE 19): headline-config value wins,
-    # kernel-probe value is the fallback.
-    assert doc3["live_roofline_frac"] == 0.8101
     assert doc3["detail"]["decode_kernel_probe"] == dk
     assert doc3["kv_wire_gbps"] == 0.0  # wire sweep absent: stable default
     # KV-wire headline keys (ISSUE 8) surface from the sweep dict.
@@ -335,7 +325,7 @@ def test_bench_doc_goodput_keys():
                 "decode_roofline_frac", "kv_wire_gbps",
                 "kv_wire_overlap_frac", "slo_sched_goodput_gain",
                 "slo_sched_ttft_p99_ms", "engine_overlap_itl_gain",
-                "device_idle_frac", "live_roofline_frac"):
+                "device_idle_frac"):
         assert key in empty
         assert empty[key] == 0.0
 

@@ -96,7 +96,7 @@ def test_chip_smoke_cpu_rehearsal_runs_to_the_end_and_is_not_ok():
     proc = subprocess.run(
         [sys.executable, str(ROOT / "chip_smoke.py")], capture_output=True, text=True,
         timeout=300, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "DYN_COST_PLANE": "0"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     lines = [line for line in proc.stdout.splitlines() if line.startswith("{")]
     assert proc.returncode == 3, proc.stderr[-2000:]

@@ -251,12 +251,6 @@ EXPECTED_ENGINE_FAMILIES = {
     "dynamo_engine_step_kind_steps_created",
     "dynamo_anomaly_active",
     "dynamo_anomaly_fired_total",
-    # Device-cost plane (ISSUE 19): roofline ledger joins. The counter
-    # `_created` families only appear once a cost-carrying core binds, which
-    # the fake core here does not.
-    "dynamo_engine_roofline_frac",
-    "dynamo_engine_hbm_bytes_total",
-    "dynamo_engine_flops_total",
     "dynamo_kv_transfer_phase_seconds",
     # prometheus_client emits the histogram's _created timestamps as their
     # own gauge family once a labelled child exists.

@@ -37,6 +37,9 @@ class StubRunner:
         self.cfg = StubCfg()
         self.dispatches: list[np.ndarray | None] = []  # num_new per dispatch
 
+    def take_dispatch(self):
+        return None
+
     def step(self, batch, lp_k=0):
         self.dispatches.append(None if batch.num_new is None
                                else np.asarray(batch.num_new))

@@ -232,3 +232,123 @@ def test_named_scopes_reach_the_lowered_step_programs_op_names():
              for part in name.split("/")}
     assert {"attn", "mlp", "moe.router", "moe.widen", "moe.experts_gate_up", "moe.experts_down",
             "moe.combine", "sample"} <= parts
+
+
+# -- the runner's dispatch report and the STEP record's keys (ISSUE 28) --------------
+
+
+def _null_batch(b, t, n=4):
+    from benchmark.serving import null_batch  # every row padding: shapes are all a dispatch site looks at
+
+    return null_batch(b, t, n)
+
+
+#: The runner's five dispatch sites: a call on a new bucket, the attention phase
+#: it reports and the token positions of its padded rectangle.
+SITES = {
+    "step": (lambda r: r.step(_null_batch(2, 1)), "decode", 2),
+    "spec_step": (lambda r: r.spec_step(_null_batch(2, 4), 3), "verify", 8),
+    "multi_step": (lambda r: r.multi_step(_null_batch(2, 1), 2), "decode", 2),
+    "step_async": (lambda r: r.step_async(_null_batch(2, 1)).result(), "decode", 2),
+    "spec_step_async": (lambda r: r.spec_step_async(_null_batch(2, 4), 3).result(), "verify", 8),
+}
+
+
+def _runner(**kw):
+    cfg = PRESETS["test-tiny"]
+    return ModelRunner(cfg, llama.init_params(cfg, 0), num_pages=16, page_size=PAGE, max_batch_size=4,
+                       prefill_bucket=4, attn_impl="reference", **kw)
+
+
+@pytest.mark.parametrize("site", SITES)
+def test_a_new_bucket_is_traced_once(site, monkeypatch):
+    """With no ``DYN_*`` set, the production dispatch path traces the model once
+    per new bucket and starts no thread that lowers it again."""
+    import os
+    import threading
+
+    for name in [n for n in os.environ if n.startswith("DYN_")]:
+        monkeypatch.delenv(name)
+    traces = []
+
+    def forward(*args, **kwargs):
+        traces.append(site)
+        return llama.forward(*args, **kwargs)
+
+    runner = _runner(forward_fn=forward)
+    dispatch = SITES[site][0]
+    dispatch(runner)
+    assert len(traces) == 1
+    dispatch(runner)  # the same bucket: the compiled program, no trace
+    assert len(traces) == 1
+    assert [t.name for t in threading.enumerate() if t.name == "dyn-cost-extract"] == []
+
+
+@pytest.mark.parametrize("site", SITES)
+def test_dispatch_report_is_taken_once(site):
+    from dynamo_tpu.engine.runner import ROWS_X_T, DispatchReport
+
+    dispatch, phase, tokens = SITES[site]
+    runner = _runner()
+    assert runner.take_dispatch() is None  # nothing dispatched yet
+    dispatch(runner)
+    report = runner.take_dispatch()
+    assert report == DispatchReport(seconds=report.seconds, attn_phase=phase, attn_path="fallback", moe_path="",
+                                    layout=ROWS_X_T, step_tokens=tokens, kv_tokens_full=0, kv_tokens_window=0)
+    assert report.seconds > 0
+    assert runner.take_dispatch() is None  # the take cleared it
+    dispatch(runner)
+    dispatch(runner)
+    twice = runner.take_dispatch()  # seconds sum over a step's dispatches, of the labels the last stands
+    assert twice.seconds > 0 and (twice.attn_phase, twice.step_tokens) == (phase, tokens)
+    # An engine step that only harvests what is in flight dispatched nothing: no label in its record.
+    _, steps = drive(make_core(overlap=True))
+    harvests = [r for r in steps if not r["phases_us"]["dispatch"]]
+    assert harvests and len(harvests) < len(steps)
+    for r in steps:
+        dispatched = r not in harvests
+        assert bool(r["attn_phase"]) == bool(r["attn_path"]) == bool(r["layout"]) == dispatched, r
+        assert (r["step_tokens"] > 0) == (r["dispatch_ms"] > 0) == dispatched and r["moe_path"] == ""
+
+
+def _mock_core():
+    from dynamo_tpu.mocker import build_mock_core
+
+    return build_mock_core(realtime=False)
+
+
+def _documented_step_keys():
+    import pathlib
+    import re
+
+    doc = (pathlib.Path(__file__).resolve().parent.parent / "docs" / "OBSERVABILITY.md").read_text()
+    table = doc.split("**Keys of a STEP record**")[1].split("- `compile`")[0]
+    return re.findall(r"^\s*\| `(\w+)` \|", table, flags=re.M)
+
+
+@pytest.mark.parametrize("make", [make_core, _mock_core], ids=["ModelRunner", "MockRunner"])
+def test_step_record_keys(make):
+    from dynamo_tpu.observability.flight import STEP_KEYS
+
+    _, steps = drive(make())
+    assert len(steps) >= 10 and len(set(STEP_KEYS)) == len(STEP_KEYS)
+    for r in steps:
+        assert tuple(r) == STEP_KEYS
+    assert not {"hbm_bytes", "flops", "roofline_frac"} & set(STEP_KEYS)
+    assert _documented_step_keys() == list(STEP_KEYS)
+
+
+def test_step_record_holds_what_the_benchmark_reads():
+    """The keys ``benchmark/program_spans.py`` and ``benchmark/layer_metrics/``
+    read, with the types they hold today."""
+    from dynamo_tpu.observability.flight import STEP_KEYS
+
+    reads = {"phases_us": dict, "t0_ns": int, "ann_ns": int, "traced": bool, "decode_rows": int,
+             "chunk_rows": int, "chunk_tokens": int, "step_tokens": int, "layout": str, "moe_path": str,
+             "kv_tokens_full": int, "kv_tokens_window": int, "attn_phase": str, "attn_path": str,
+             "wall_ms": float, "gap_ms": float}
+    assert set(reads) <= set(STEP_KEYS)
+    _, steps = drive(make_core())
+    for r in steps:
+        assert {k: type(r[k]) for k in reads} == reads
+    assert {r["layout"] for r in steps} <= {"rows_x_t", "split"} and all(r["step_tokens"] > 0 for r in steps)

@@ -90,11 +90,6 @@ REQUIRED_FAMILIES: dict[str, str] = {
     "dynamo_incidents_captured_total": "engine",
     "dynamo_anomaly_active": "engine",
     "dynamo_anomaly_fired_total": "engine",
-    # Device-cost plane (roofline ledger) — Counter families are exposed
-    # without the _total suffix in python-client exposition.
-    "dynamo_engine_roofline_frac": "engine",
-    "dynamo_engine_hbm_bytes": "engine",
-    "dynamo_engine_flops": "engine",
     # HA control plane (replicated store + frontend reconstruction) — the
     # store_failover / frontend_restart fleetsim gates key on these.
     "dynamo_store_role": "frontend",
