@@ -127,25 +127,27 @@ class EngineConfig:
     # quotas, and an ITL-driven chunk-budget controller. Off by default —
     # FIFO intake is then bit-identical to the pre-sched scheduler.
     slo_sched: bool = False
-    # Overlapped execution (DYN_OVERLAP): a depth-1 pipeline — step N+1 is
-    # dispatched with its decode rows' input tokens chained from N's
-    # device-resident samples before N's tokens reach the host, so the chip
-    # never idles on the per-step host round-trip. Mixed steps overlap too:
-    # prefill chunk rows feed from host (their tokens are known), decode
-    # rows chain; penalty history and the pos_limit write clamp are applied
-    # in-graph, so penalized rows and budget-final tokens are not barriers.
-    # Constrained (json_mode) rows chain via one-step-lookahead mask groups
+    # The pipelined step loop, which is the serving loop: a depth-1 pipeline
+    # — step N+1 is composed at the sequences' effective state and dispatched
+    # with its decode rows' input tokens chained from N's device-resident
+    # samples before N's tokens reach the host, so the host's work of a step
+    # hides under the device's program. Mixed steps ride it too: prefill
+    # chunk rows feed from host (their tokens are known), decode rows chain;
+    # penalty history and the pos_limit write clamp are applied in-graph, so
+    # penalized rows and budget-final tokens are not barriers. Constrained
+    # (json_mode) rows chain via one-step-lookahead mask groups
     # (constraint_lookahead_tokens); multimodal/mrope rows chain with their
     # extras threaded through the explicit-args chained program; and
-    # decode_steps>1 folds into the same pipeline as K chained sub-steps
-    # per dispatch. Stops are evaluated one step late; a late-detected stop
+    # decode_steps>1 folds into the same pipeline as K chained sub-steps per
+    # dispatch. Stops are evaluated one step late; a late-detected stop
     # cancels the in-flight row (its token is discarded, its pages released
-    # — output streams stay bit-identical to overlap=False). The residual
-    # barriers are cancellation, a lookahead-mask cache miss/cap overflow
-    # (constraint_miss), and spec without an async verify. Reasons are
-    # counted in overlap_barrier_counts and flight STEP records
-    # (BARRIER_REASONS is the full vocabulary). docs/SCHEDULER.md.
-    overlap: bool = False
+    # — output streams are bit-identical to the synchronous step's). What the
+    # graph cannot chain barriers to the synchronous step (_overlap_route),
+    # by a reason counted in overlap_barrier_counts and the STEP flight
+    # records (BARRIER_REASONS is the full vocabulary). docs/SCHEDULER.md.
+    # False steps synchronously throughout: the oracle the parity tests
+    # compare against, set by no entry point.
+    overlap: bool = True
     # Allow speculative verify dispatches to participate in the overlapped
     # pipeline (DYN_OVERLAP_SPEC): verify steps chain their base token from
     # the previous dispatch and their accepted tokens stay device-resident
@@ -856,7 +858,13 @@ class EngineCore:
         chunks = self._schedule_prefill()
         overlap_ok, reason = self._overlap_route(chunks)
         if overlap_ok:
-            with self.clock.annotate("engine.overlap"):
+            # The annotation says what the step dispatches, as the synchronous
+            # step's does (the trace's readers select programs by it): a chunk
+            # program where a chunk of several tokens rides or a verify may,
+            # else the decode program. A sequence whose last chunk is in
+            # flight still counts as prefilling and already decodes here.
+            chunky = any(n > 1 for _, n in chunks) or (self._spec_active() and bool(self.running))
+            with self.clock.annotate("engine.mixed" if chunky else "engine.decode"):
                 out = cancelled + self._run_mixed_overlapped(chunks)
             if not self.defer_offloads:
                 self.flush_offloads()
@@ -1917,7 +1925,7 @@ class EngineCore:
     def _run_mixed_overlapped(
         self, chunks: list[tuple[Sequence, int]]
     ) -> list[tuple[Sequence, EngineOutput]]:
-        """Depth-1 overlapped pipeline over *mixed* steps (DYN_OVERLAP).
+        """Depth-1 overlapped pipeline over *mixed* steps: the serving loop.
 
         Generalizes PR 10's pure-decode chaining: step N+1 is composed at
         the sequences' *effective* state (``_inflight_adv``) and dispatched
@@ -2220,7 +2228,7 @@ class EngineCore:
 
             rem = max(s.remaining_tokens(self.config.max_seq_len) for s in self.running)
             k = max(1, min(k, next_pow2(rem)))
-        # Overlapped execution (DYN_OVERLAP) never reaches this method:
+        # The pipelined loop never reaches this method:
         # _step_locked routes every composition — including decode_steps>1,
         # which is now served as chained sub-dispatches inside
         # _run_mixed_overlapped — through the pipeline, and drains it before

@@ -64,10 +64,13 @@ def _delta_mrope(positions: jnp.ndarray, delta: jnp.ndarray | None) -> jnp.ndarr
     return jnp.broadcast_to(p[:, None, :], (b, 3, t))
 
 
-def _pack(padded: "StepBatch") -> np.ndarray:
+def _pack(padded: "StepBatch", chain_src: np.ndarray | None = None) -> np.ndarray:
     """Flatten every step input into one i32 buffer (single host->device
     transfer — each separate transfer costs a fixed latency that dwarfs
-    these few KB)."""
+    these few KB). ``chain_src`` (``_apply_chain``) rides at its end: -1 in
+    every row where the host feeds the tokens."""
+    if chain_src is None:
+        chain_src = np.full(padded.tokens.shape[0], -1, np.int32)
     return np.concatenate(
         [
             padded.tokens.ravel(),
@@ -85,13 +88,14 @@ def _pack(padded: "StepBatch") -> np.ndarray:
             padded.pos_limit,
             padded.history.ravel(),
             padded.mrope_delta,
+            chain_src,
         ]
     )
 
 
 def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int):
     """In-graph inverse of :func:`_pack` (static offsets, free slices)."""
-    sizes = [b * t, b * t, b * n, b * t, b, b, b, b, b, b, b, b, b, b * h, b]
+    sizes = [b * t, b * t, b * n, b * t, b, b, b, b, b, b, b, b, b, b * h, b, b]
     offs = np.concatenate([[0], np.cumsum(sizes)])
     part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
     return (
@@ -110,6 +114,7 @@ def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int):
         part[12],
         part[13].reshape(b, h),
         part[14],
+        part[15],
     )
 
 
@@ -124,14 +129,21 @@ MAX_CHUNK_SLOTS = 2
 ROWS_X_T, SPLIT = "rows_x_t", "split"  # a dispatch's layout, as the STEP record names it
 
 
-def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int) -> np.ndarray:
+def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int,
+                chain_src: np.ndarray | None = None) -> np.ndarray:
     """One i32 buffer for a chunk step laid out on one token axis: a position
     per decode slot (row i of ``padded`` keeps slot i, column 0), then ``tp``
     per chunk slot (the rows ``chunk`` of ``padded``, in order). A decode slot
     whose row moved to a chunk slot, and a chunk slot beyond the rows there
-    are, is padding: it reads and writes the null page."""
+    are, is padding: it reads and writes the null page. At its end ride the
+    decode slots' ``chain_src`` (``_apply_chain``; a chunk row's tokens are the
+    host's) and the row each chunk slot samples for (-1: none)."""
     bp, tp = padded.tokens.shape
     c = len(chunk)
+    if chain_src is None:
+        chain_src = np.full(bp, -1, np.int32)
+    chunk_row = np.full(nc, -1, np.int32)
+    chunk_row[:c] = chunk
     src = np.zeros(bp + nc, np.intp)  # the row of ``padded`` behind each slot
     src[:bp] = np.arange(bp)
     src[bp: bp + c] = chunk
@@ -171,6 +183,8 @@ def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int) -> np.ndarray:
             rowwise(padded.pres_pen),
             rowwise(padded.pos_limit, fill=0),
             rowwise(padded.history).ravel(),
+            chain_src,
+            chunk_row,
         ]
     )
 
@@ -179,7 +193,7 @@ def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int
     """In-graph inverse of :func:`_pack_split`: the token axis flat, one row
     of block table and sampling fields per slot."""
     toks, r = nd + nc * tc, nd + nc
-    sizes = [toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h]
+    sizes = [toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h, nd, nc]
     offs = np.concatenate([[0], np.cumsum(sizes)])
     part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
     f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)  # noqa: E731
@@ -187,34 +201,47 @@ def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int
         part[0], part[1], part[2].reshape(r, n), part[3], part[4],
         f32(part[5]), part[6], f32(part[7]), jax.lax.bitcast_convert_type(part[8], jnp.uint32),
         part[9], f32(part[10]), f32(part[11]), part[12], part[13].reshape(r, h),
+        part[14], part[15],
     )
 
 
-def _apply_chain(tokens, history, sample_steps, chain_buf, chain_src):
+def _apply_chain(first, history, sample_steps, chain_buf, chain_src):
     """Per-row device-resident token sourcing for a chained dispatch.
 
+    ``first`` i32[B] is each row's column-0 input token as the host packed it.
     ``chain_src`` i32[B] holds, per row, a flat index into ``chain_buf`` (the
-    previous dispatch's device-resident samples — [Bp] for plain steps,
-    [Bp*V] row-major for spec verifies) or -1 for host-fed rows. Chained
-    rows' column-0 input token is gathered in-graph; host-fed rows (prefill
-    chunks, fresh admissions) keep their host token untouched.
+    previous dispatch's device-resident samples: one fixed width in row order
+    for plain steps, [Bp*V] row-major for spec verifies) or -1 for host-fed
+    rows. Chained rows' token is gathered in-graph; host-fed rows (prefill
+    chunks, fresh admissions, every row of a synchronous step) keep their
+    host token bit for bit.
 
     The gathered token is also appended to the penalty ``history`` at index
     ``sample_steps - 1``: a chained row's host history is stale by exactly
     the one in-flight token it is chaining, and that token IS the gathered
-    value, so the write restores bit-identical penalty state. For host-fed
-    rows the write re-stores the value already there (a no-op), which keeps
-    the program branch-free.
+    value, so the write restores bit-identical penalty state. Host-fed rows
+    keep their history as it is; selects only, no scatter, so the program
+    stays branch-free and a step's chain costs its trace next to nothing.
     """
-    src = jnp.clip(chain_src, 0, chain_buf.shape[0] - 1)
-    gathered = chain_buf[src]
     chained = chain_src >= 0
-    tokens = tokens.at[:, 0].set(jnp.where(chained, gathered, tokens[:, 0]))
+    gathered = chain_buf[jnp.clip(chain_src, 0, chain_buf.shape[0] - 1)]
+    first = jnp.where(chained, gathered, first)
     idx = jnp.clip(sample_steps - 1, 0, history.shape[1] - 1)
-    cur = jnp.take_along_axis(history, idx[:, None], axis=1)[:, 0]
-    upd = jnp.where(chained, gathered, cur)
-    history = jax.vmap(lambda hrow, w, t_: hrow.at[w].set(t_))(history, idx, upd)
-    return tokens, history
+    here = chained[:, None] & (jnp.arange(history.shape[1])[None, :] == idx[:, None])
+    return first, jnp.where(here, gathered[:, None], history)
+
+
+def _chain_rows(tokens, history, sample_steps, chain_buf, chain_src):
+    """``_apply_chain`` on column 0 of a rows x T token rectangle."""
+    first, history = _apply_chain(tokens[:, 0], history, sample_steps, chain_buf, chain_src)
+    return tokens.at[:, 0].set(first), history
+
+
+def _chain_out(row_tokens, width: int):
+    """A dispatch's samples in the batch's row order, at the chain buffer's one
+    width (rows past it, which no engine composes, are not chainable)."""
+    n = min(row_tokens.shape[0], width)
+    return jnp.pad(row_tokens[:n].astype(jnp.int32), (0, width - n))
 
 
 @dataclasses.dataclass
@@ -444,45 +471,55 @@ class ModelRunner:
 
         self._step_fn = _step
 
+        # The two programs a text step of one device runs, from ``step`` and from
+        # ``step_async`` alike: besides the packed inputs each takes the chain
+        # buffer (the previous dispatch's samples, ``_chain_width`` wide whatever
+        # the rows bucket, so its shape is no part of the jit key) and returns
+        # its own samples in that form beside ``_step``'s outputs. A
+        # synchronous step packs -1 into every ``chain_src``: the ``where`` in
+        # ``_apply_chain`` hands the host's tokens through.
         @functools.partial(jax.jit, static_argnames=("nd", "nc", "tc", "n", "h", "lp_k"), donate_argnums=(1, 2))
-        def _step_split(params, k_cache, v_cache, packed, *, nd, nc, tc, n, h, lp_k=0):
+        def _step_split(params, k_cache, v_cache, packed, chain_buf, *, nd, nc, tc, n, h, lp_k=0):
             """A chunk step on one token axis (``_pack_split``): ``nd`` decode
             slots of one position, ``nc`` chunk slots of ``tc``. The same
-            forward and sampling fold as ``_step``, row for row."""
+            forward and sampling fold as ``_step``, row for row. A decode
+            slot's chained token is gathered into its one position; the chain
+            buffer it returns holds a chunk row's sample (emitted at ``nd +
+            slot``) at its row index like any other."""
             (tokens, positions, block_tables, slot_mapping, last_idx, temperature, top_k, top_p,
-             seeds, sample_steps, freq_pen, pres_pen, pos_limit, history) = _unpack_split(packed, nd, nc, tc, n, h)
+             seeds, sample_steps, freq_pen, pres_pen, pos_limit, history,
+             chain_src, chunk_row) = _unpack_split(packed, nd, nc, tc, n, h)
+            first, hist = _apply_chain(tokens[:nd], history[:nd], sample_steps[:nd], chain_buf, chain_src)
+            tokens = tokens.at[:nd].set(first)
+            history = jnp.concatenate([hist, history[nd:]])
             limit = jnp.concatenate([pos_limit[:nd], jnp.repeat(pos_limit[nd:], tc)])
             slot_mapping = jnp.where(positions < limit, slot_mapping, 0)  # _step's finish-line clamp
             logits, k_cache, v_cache = llama.forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping,
                 last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc),
             )
-            return _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
-                           freq_pen, pres_pen, history, None, lp_k)
+            out = _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
+                          freq_pen, pres_pen, history, None, lp_k)
+            chain = _chain_out(out[0][:nd], chain_buf.shape[0])
+            for k in range(nc):  # a slot without a row holds -1: no index matches
+                chain = jnp.where(jnp.arange(chain.shape[0]) == chunk_row[k], out[0][nd + k], chain)
+            return out, chain
 
         self._step_split_fn = _step_split
 
         @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "lp_k"), donate_argnums=(1, 2))
-        def _step_packed(params, k_cache, v_cache, packed, *, b, t, n, h, lp_k=0):
-            args = _unpack(packed, b, t, n, h)
-            return _step(params, k_cache, v_cache, *args, impl=self.attn_impl, lp_k=lp_k)
+        def _step_packed(params, k_cache, v_cache, packed, chain_buf, *, b, t, n, h, lp_k=0):
+            """The rows x T rectangle from one packed buffer. Each row's
+            column-0 token is sourced per ``chain_src`` (the buffer's last
+            part) from ``chain_buf`` where the overlapped loop dispatches a
+            step before the one before it has reached the host."""
+            *args, chain_src = _unpack(packed, b, t, n, h)
+            # args: 0=tokens, 9=sample_steps, 13=history (see _pack order).
+            args[0], args[13] = _chain_rows(args[0], args[13], args[9], chain_buf, chain_src)
+            out = _step(params, k_cache, v_cache, *args, impl=self.attn_impl, lp_k=lp_k)
+            return out, _chain_out(out[0], chain_buf.shape[0])
 
         self._step_packed_fn = _step_packed
-
-        @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "lp_k"), donate_argnums=(1, 2))
-        def _step_chained(params, k_cache, v_cache, packed, chain_buf, chain_src, *, b, t, n, h, lp_k=0):
-            """Chained (possibly mixed) step: each row's column-0 input token
-            is sourced per ``chain_src`` from the previous dispatch's
-            device-resident samples instead of the host (the overlapped
-            engine loop dispatches step N+1 before fetching step N's tokens —
-            see step_async). Rows with ``chain_src < 0`` (prefill chunks,
-            fresh admissions) feed from host as usual."""
-            args = list(_unpack(packed, b, t, n, h))
-            # args: 0=tokens, 9=sample_steps, 13=history (see _pack order).
-            args[0], args[13] = _apply_chain(args[0], args[13], args[9], chain_buf, chain_src)
-            return _step(params, k_cache, v_cache, *args, impl=self.attn_impl, lp_k=lp_k)
-
-        self._step_chained_fn = _step_chained
 
         @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
         def _step_chained_explicit(params, k_cache, v_cache, chain_buf, chain_src,
@@ -502,7 +539,7 @@ class ModelRunner:
             gather: each row's group id is looked up at its (possibly
             device-sourced) column-0 token, which is exactly the token the
             host could not know at compose time."""
-            tokens, history = _apply_chain(tokens, history, sample_steps, chain_buf, chain_src)
+            tokens, history = _chain_rows(tokens, history, sample_steps, chain_buf, chain_src)
             logit_mask = None
             if la_masks is not None:
                 rows = jnp.arange(tokens.shape[0])
@@ -595,7 +632,7 @@ class ModelRunner:
             (drafts are host-proposed, chunk tokens are prompt text). The
             same losslessness argument as _spec_step applies unchanged — the
             gathered token equals the token the host would have shipped."""
-            tokens, history = _apply_chain(tokens, history, sample_steps, chain_buf, chain_src)
+            tokens, history = _chain_rows(tokens, history, sample_steps, chain_buf, chain_src)
             return _spec_step(
                 params, k_cache, v_cache, tokens, positions, block_tables,
                 slot_mapping, verify_indices, temperature, top_k, top_p, seeds,
@@ -662,7 +699,7 @@ class ModelRunner:
         def _multi_step_packed(params, k_cache, v_cache, packed, *, b, t, n, h, num_steps):
             (tokens, positions, block_tables, _slot, _last,
              temperature, top_k, top_p, seeds, sample_steps,
-             freq_pen, pres_pen, pos_limit, history, mrope_delta) = _unpack(packed, b, t, n, h)
+             freq_pen, pres_pen, pos_limit, history, mrope_delta, _src) = _unpack(packed, b, t, n, h)
             return _multi_step(
                 params, k_cache, v_cache, tokens[:, 0], positions[:, 0], block_tables,
                 temperature, top_k, top_p, seeds, sample_steps,
@@ -671,7 +708,22 @@ class ModelRunner:
 
         self._multi_step_packed_fn = _multi_step_packed
 
-        self._chain_tokens = None  # device i32[Bp] (or [Bp*V]): latest dispatch's samples
+        # The latest async dispatch's samples, device-resident: i32[_chain_width]
+        # in the batch's row order after a text step of one device, whatever its
+        # rows bucket; a mesh's or an extras step's [Bp] and a verify's [Bp*V]
+        # keep their own shape (``_chain_for_text`` brings those to the width
+        # when a text step chains out of them).
+        self._chain_tokens = None
+        self._chain_width = self._bucket_batch(max_batch_size)
+        # What a step that chains nothing passes for the buffer.
+        self._chain_idle = jax.device_put(np.zeros(self._chain_width, np.int32), self.device)
+
+        @jax.jit
+        def _rebase_chain(buf, src):
+            picked = jnp.where(src >= 0, buf[jnp.clip(src, 0, buf.shape[0] - 1)], 0)
+            return _chain_out(picked, self._chain_width)
+
+        self._rebase_chain_fn = _rebase_chain
 
         @functools.partial(jax.jit, donate_argnums=(0, 1))
         def _write_page(k_cache, v_cache, k, v, pid):
@@ -1021,16 +1073,17 @@ class ModelRunner:
             self.on_enqueued()
         self._count_kv(self._report)  # the device is busy now: host work here costs no step time
 
-    def _chunk_rows(self, padded: StepBatch) -> np.ndarray | None:
+    def _chunk_rows(self, padded: StepBatch, chain_src: np.ndarray | None = None) -> np.ndarray | None:
         """The rows of a chunk step that take the chunk slots of a split token
         axis: those with more than one real column (``last_token_index + 1``,
         a row's ``num_new``). Every other row, padding included, rides as one
         token. ``None`` keeps the rectangle: a decode step, a step outside what
         the flat model step serves (``_can_split``; multimodal, constrained or
-        explicit M-RoPE rows), more chunk rows than ``MAX_CHUNK_SLOTS``, or a
-        step the split would not make smaller (a lone chunk row: its one decode
-        slot would be padding, and a padding token still routes through
-        experts of its own)."""
+        explicit M-RoPE rows), more chunk rows than ``MAX_CHUNK_SLOTS``, a
+        chunk row whose first token is chained (no engine composes one: a
+        chunk's tokens are the host's), or a step the split would not make
+        smaller (a lone chunk row: its one decode slot would be padding, and a
+        padding token still routes through experts of its own)."""
         bp, tp = padded.tokens.shape
         if (not self._can_split or tp == 1 or padded.mm_embeds is not None
                 or padded.logit_mask is not None or padded.mrope_positions is not None):
@@ -1038,7 +1091,61 @@ class ModelRunner:
         chunk = np.flatnonzero(padded.last_token_index > 0)
         if len(chunk) > MAX_CHUNK_SLOTS or bp + next_pow2(len(chunk)) * tp >= bp * tp:
             return None
+        if chain_src is not None and (chain_src[chunk] >= 0).any():
+            return None
         return chunk
+
+    def _text_step(self, padded: StepBatch, b_real: int, lp_k: int,
+                   chain_src: np.ndarray | None = None):
+        """The step of text rows on one device, as ``step`` and ``step_async``
+        both dispatch it: ``(key, layout, rows, fn, pack, statics)``. ``key`` is
+        what the layout adds to the dispatch key, ``rows`` where the batch's
+        rows sit in the program's output, ``fn`` the layout's one jitted
+        program, which takes ``(params, k_cache, v_cache, pack(), chain buffer,
+        **statics)`` and returns ``(_step's outputs, the new chain buffer)``.
+        The caller makes the call itself, through ``_enqueue``: the frames
+        round a jitted call are part of what its first call costs."""
+        bp, tp = padded.tokens.shape
+        statics = dict(n=padded.block_tables.shape[1], h=padded.history.shape[1], lp_k=lp_k)
+        chunk = self._chunk_rows(padded, chain_src)
+        if chunk is None:
+            return ((), (ROWS_X_T, bp * tp), slice(b_real), self._step_packed_fn,
+                    lambda: _pack(padded, chain_src), dict(statics, b=bp, t=tp))
+        # One position per row and tp per chunk slot instead of bp x tp. A
+        # step of several rows without a chunk row (a warm-up's null
+        # batch) is the one-chunk-slot program with that slot padding.
+        nc = next_pow2(len(chunk))
+        # Row i samples in decode slot i, a chunk row in its chunk slot.
+        rows = np.arange(b_real)
+        rows[chunk] = bp + np.arange(len(chunk))
+        return ((SPLIT, nc), (SPLIT, bp + nc * tp), rows, self._step_split_fn,
+                lambda: _pack_split(padded, chunk, nc, chain_src), dict(statics, nd=bp, nc=nc, tc=tp))
+
+    def _explicit_inputs(self, padded: StepBatch):
+        """``(opt, inputs)`` for the explicit-argument programs (``_step``'s
+        positional order): each array placed where the program wants it, a
+        mesh's rows sharded; ``opt`` places an optional extra, ``None`` kept."""
+        if self.mesh is not None:
+            from dynamo_tpu.parallel.sharding import batch_sharding
+
+            def put(a):
+                return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
+        else:
+            put = jnp.asarray
+
+        def opt(a):
+            return None if a is None else put(a)
+
+        return opt, (
+            put(padded.tokens), put(padded.positions),
+            put(padded.block_tables), put(padded.slot_mapping),
+            put(padded.last_token_index), put(padded.temperature),
+            put(padded.top_k), put(padded.top_p),
+            put(padded.seeds), put(padded.sample_steps),
+            put(padded.freq_pen), put(padded.pres_pen),
+            put(padded.pos_limit), put(padded.history),
+            put(padded.mrope_delta),
+        )
 
     @_locked
     def step(self, batch: StepBatch, lp_k: int = 0):
@@ -1068,7 +1175,6 @@ class ModelRunner:
         padded = self._pad(batch)
         impl = self._select_impl(padded) if self.mesh is not None else self.attn_impl
         bp, tp = padded.tokens.shape
-        chunk = self._chunk_rows(padded)
         layout = (ROWS_X_T, bp * tp)
         dispatch_key = (
             bp, tp, padded.block_tables.shape[1], padded.history.shape[1],
@@ -1076,77 +1182,22 @@ class ModelRunner:
             padded.mm_embeds is not None, padded.logit_mask is not None,
         )
         rows = slice(b_real)  # where the batch's rows sit in the program's output
-        if chunk is not None:
-            # One position per row and tp per chunk slot instead of bp x tp. A
-            # step of several rows without a chunk row (a warm-up's null
-            # batch) is the one-chunk-slot program with that slot padding.
-            nc = next_pow2(len(chunk))
-            dispatch_key += (SPLIT, nc)
-            layout = (SPLIT, bp + nc * tp)
-            # Row i samples in decode slot i, a chunk row in its chunk slot.
-            rows = np.arange(b_real)
-            rows[chunk] = bp + np.arange(len(chunk))
+        explicit = self.mesh is not None or padded.mm_embeds is not None or padded.logit_mask is not None
+        if not explicit:
+            key, layout, rows, fn, pack, statics = self._text_step(padded, b_real, lp_k)
+            dispatch_key += key
         with self._dispatch("step", dispatch_key, padded, impl, layout):
-            if chunk is not None:
-                out = self._enqueue(
-                    self._step_split_fn,
-                    self.params, self.k_cache, self.v_cache, jnp.asarray(_pack_split(padded, chunk, nc)),
-                    nd=bp, nc=nc, tc=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1],
-                    lp_k=lp_k,
-                )
-            elif padded.mm_embeds is not None or padded.logit_mask is not None:
-                if self.mesh is not None:
-                    from dynamo_tpu.parallel.sharding import batch_sharding
-
-                    def put(a):
-                        return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
-                else:
-                    put = jnp.asarray
-
-                def opt(a):
-                    return None if a is None else put(a)
-
+            if not explicit:
+                out, _ = self._enqueue(fn, self.params, self.k_cache, self.v_cache,
+                                       jnp.asarray(pack()), self._chain_idle, **statics)
+            else:
+                opt, inputs = self._explicit_inputs(padded)
                 out = self._enqueue(
                     self._step_fn,
-                    self.params, self.k_cache, self.v_cache,
-                    put(padded.tokens), put(padded.positions),
-                    put(padded.block_tables), put(padded.slot_mapping),
-                    put(padded.last_token_index), put(padded.temperature),
-                    put(padded.top_k), put(padded.top_p),
-                    put(padded.seeds), put(padded.sample_steps),
-                    put(padded.freq_pen), put(padded.pres_pen),
-                    put(padded.pos_limit), put(padded.history),
-                    put(padded.mrope_delta),
+                    self.params, self.k_cache, self.v_cache, *inputs,
                     opt(padded.mm_embeds), opt(padded.mm_slot_offset), opt(padded.mm_counts),
                     opt(padded.mrope_positions), opt(padded.logit_mask),
-                    impl=impl,
-                    lp_k=lp_k,
-                )
-            elif self.mesh is not None:
-                from dynamo_tpu.parallel.sharding import batch_sharding
-
-                def put(a):
-                    return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
-
-                out = self._enqueue(
-                    self._step_fn,
-                    self.params, self.k_cache, self.v_cache,
-                    put(padded.tokens), put(padded.positions),
-                    put(padded.block_tables), put(padded.slot_mapping),
-                    put(padded.last_token_index), put(padded.temperature),
-                    put(padded.top_k), put(padded.top_p),
-                    put(padded.seeds), put(padded.sample_steps),
-                    put(padded.freq_pen), put(padded.pres_pen),
-                    put(padded.pos_limit), put(padded.history),
-                    put(padded.mrope_delta),
                     impl=impl, lp_k=lp_k,
-                )
-            else:
-                out = self._enqueue(
-                    self._step_packed_fn,
-                    self.params, self.k_cache, self.v_cache, jnp.asarray(_pack(padded)),
-                    b=bp, t=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1],
-                    lp_k=lp_k,
                 )
             self._mark_wait()
             if lp_k:
@@ -1292,15 +1343,27 @@ class ModelRunner:
         ), "chain_src points past the device-resident sample buffer"
         return src
 
+    def _chain_for_text(self, src: np.ndarray) -> tuple[jax.Array, np.ndarray]:
+        """The chain buffer and sources a text step's program takes: the buffer
+        at its one width. The samples of a verify, or of a step that went by
+        the explicit arguments, keep a shape of their own; a text step that
+        chains out of one has them gathered to the width first (one small
+        program more, on the step after such a dispatch only)."""
+        buf = self._chain_tokens
+        if buf.shape[0] == self._chain_width:
+            return buf, src
+        chained = src >= 0
+        return (self._rebase_chain_fn(buf, jnp.asarray(src)),
+                np.where(chained, np.arange(len(src), dtype=np.int32), -1).astype(np.int32))
+
     @_locked
     def step_async(self, batch: StepBatch, lp_k: int = 0, *, chain: bool = False,
                    chain_src: np.ndarray | None = None) -> "DeviceStepTokens":
         """Dispatch ONE (possibly mixed prefill+decode) step without blocking
         on its result.
 
-        The overlapped engine loop (``DYN_OVERLAP=1``) uses this to run a
-        depth-1 pipeline at decode_steps == 1: the sampled tokens stay
-        device-resident (``self._chain_tokens``, kept flat i32[Bp]), so the
+        The engine's pipelined loop uses this to keep one step in flight: the
+        sampled tokens stay device-resident (``self._chain_tokens``), so the
         next step can be dispatched with ``chain=True`` — each row's input
         token gathered in-graph per ``chain_src`` — before this step's
         tokens ever reach the host. ``chain_src`` i32[B_real] names, per
@@ -1312,13 +1375,16 @@ class ModelRunner:
         their single real token. Returns a :class:`DeviceStepTokens` handle
         whose ``result()`` blocks on the already-started device->host copy.
 
-        Extras the packed i32 buffer has no slots for — multimodal embeds,
-        explicit 3-axis mrope coords, a host-known constraint mask
-        (``logit_mask``, unchained rows only) or the lookahead mask groups
-        (``la_masks``/``la_groups``, chained dispatches) — route through the
-        explicit-args programs; plain text steps keep the single packed
-        transfer. ``lp_k`` rides along — the aux logprob arrays are fetched
-        with the tokens.
+        Text rows on one device run the very program :meth:`step` runs for the
+        shape, in the layout :meth:`step` would take (``_text_step``), under
+        the dispatch key :meth:`step` records: what a warm-up through ``step``
+        compiled is what this dispatches, chained or not. Extras the packed
+        i32 buffer has no slots for — multimodal embeds, explicit 3-axis mrope
+        coords, a host-known constraint mask (``logit_mask``, unchained rows
+        only) or the lookahead mask groups (``la_masks``/``la_groups``,
+        chained dispatches) — and a mesh's row-sharded inputs route through
+        the explicit-args programs. ``lp_k`` rides along — the aux logprob
+        arrays are fetched with the tokens.
         """
         assert batch.la_masks is None or chain, (
             "lookahead mask groups resolve against the chain gather; "
@@ -1334,43 +1400,32 @@ class ModelRunner:
         n = padded.block_tables.shape[1]
         h = padded.history.shape[1]
         src = self._chain_src_padded(chain_src, b_real, b) if chain else None
-        extras = (
+        rows = slice(b_real)
+        if self.mesh is None and not (
             padded.mm_embeds is not None or padded.mrope_positions is not None
             or padded.logit_mask is not None or padded.la_masks is not None
-        )
-        dispatch_key = (
-            b, t, n, h, lp_k, chain, impl, self.mesh is not None,
-            padded.mm_embeds is not None, padded.logit_mask is not None,
-            padded.la_masks is not None,
-        )
-        with self._dispatch("step_async", dispatch_key, padded, impl, (ROWS_X_T, b * t)):
-            if self.mesh is not None or extras:
-                if self.mesh is not None:
-                    from dynamo_tpu.parallel.sharding import batch_sharding
-
-                    def put(a):
-                        return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
-                else:
-                    put = jnp.asarray
-
-                def opt(a):
-                    return None if a is None else put(a)
-
-                explicit = (
-                    put(padded.tokens), put(padded.positions),
-                    put(padded.block_tables), put(padded.slot_mapping),
-                    put(padded.last_token_index), put(padded.temperature),
-                    put(padded.top_k), put(padded.top_p),
-                    put(padded.seeds), put(padded.sample_steps),
-                    put(padded.freq_pen), put(padded.pres_pen),
-                    put(padded.pos_limit), put(padded.history),
-                    put(padded.mrope_delta),
-                )
+        ):
+            chain_buf = self._chain_idle
+            if chain:
+                chain_buf, src = self._chain_for_text(src)
+            key, layout, rows, fn, pack, statics = self._text_step(padded, b_real, lp_k, src)
+            with self._dispatch("step", (b, t, n, h, lp_k, impl, False, False, False) + key,
+                                padded, impl, layout):
+                out, chain_buf = self._enqueue(fn, self.params, self.k_cache, self.v_cache,
+                                               jnp.asarray(pack()), chain_buf, **statics)
+        else:
+            dispatch_key = (
+                b, t, n, h, lp_k, chain, impl, self.mesh is not None,
+                padded.mm_embeds is not None, padded.logit_mask is not None,
+                padded.la_masks is not None,
+            )
+            with self._dispatch("step_async", dispatch_key, padded, impl, (ROWS_X_T, b * t)):
+                opt, explicit = self._explicit_inputs(padded)
                 if chain:
                     out = self._enqueue(
                         self._step_chained_explicit_fn,
                         self.params, self.k_cache, self.v_cache,
-                        self._chain_tokens, put(src), *explicit,
+                        self._chain_tokens, opt(src), *explicit,
                         opt(padded.mm_embeds), opt(padded.mm_slot_offset),
                         opt(padded.mm_counts), opt(padded.mrope_positions),
                         opt(padded.la_masks), opt(padded.la_groups),
@@ -1385,34 +1440,20 @@ class ModelRunner:
                         opt(padded.logit_mask),
                         impl=impl, lp_k=lp_k,
                     )
-            else:
-                packed = jnp.asarray(_pack(padded))
-                if chain:
-                    out = self._enqueue(
-                        self._step_chained_fn,
-                        self.params, self.k_cache, self.v_cache, packed,
-                        self._chain_tokens, jnp.asarray(src),
-                        b=b, t=t, n=n, h=h, lp_k=lp_k,
-                    )
-                else:
-                    out = self._enqueue(
-                        self._step_packed_fn,
-                        self.params, self.k_cache, self.v_cache, packed,
-                        b=b, t=t, n=n, h=h, lp_k=lp_k,
-                    )
+            chain_buf = out[0]  # [Bp], the rows in order: a shape of its own
         if lp_k:
             toks, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
             aux = (chosen, top_ids, top_lps)
         else:
             toks, self.k_cache, self.v_cache = out
             aux = None
-        self._chain_tokens = toks
+        self._chain_tokens = chain_buf
         for buf in (toks, *(aux or ())):
             try:  # start the device->host DMA early; overlaps the next step
                 buf.copy_to_host_async()
             except Exception:
                 pass
-        return DeviceStepTokens(toks, aux, b_real)
+        return DeviceStepTokens(toks, aux, rows)
 
     @_locked
     def spec_step_async(self, batch: StepBatch, verify_width: int, lp_k: int = 0, *,
@@ -1513,21 +1554,6 @@ class ModelRunner:
             out = self._embed_fn(self.params, jnp.asarray(tokens), jnp.asarray(mask))
         return np.asarray(out)[:n]
 
-    def can_chain(self, batch_size: int) -> bool:
-        """True if a chained burst for this real batch size would line up with
-        the previous burst's padded output."""
-        return (
-            self._chain_tokens is not None
-            and self._chain_tokens.shape[0] == self._bucket_batch(batch_size)
-        )
-
-    def chain_len(self) -> int:
-        """Flat length of the device-resident sample buffer (0 = no buffer).
-
-        The engine validates its per-row ``chain_src`` indices against this
-        before dispatching a chained step."""
-        return 0 if self._chain_tokens is None else int(self._chain_tokens.shape[0])
-
     def reset_chain(self) -> None:
         self._chain_tokens = None
 
@@ -1561,21 +1587,21 @@ class DeviceStepTokens:
     """Handle to a single dispatched decode step's sampled tokens (and
     optional logprob aux arrays), device-resident (``ModelRunner.step_async``)."""
 
-    def __init__(self, toks: jax.Array, aux, b_real: int) -> None:
+    def __init__(self, toks: jax.Array, aux, rows) -> None:
         self._toks = toks
         self._aux = aux  # (chosen, top_ids, top_lps) or None
-        self._b_real = b_real
+        self._rows = rows  # where the batch's rows sit in the program's output
 
     def result(self) -> tuple[np.ndarray, dict | None]:
         """Block until on host; returns (tokens i32[B_real, 1], lp_aux|None)."""
-        toks = np.asarray(self._toks)[: self._b_real, None]
+        toks = np.asarray(self._toks)[self._rows, None]
         if self._aux is None:
             return toks, None
         chosen, top_ids, top_lps = self._aux
         return toks, {
-            "logprob": np.asarray(chosen)[: self._b_real],
-            "top_ids": np.asarray(top_ids)[: self._b_real],
-            "top_lps": np.asarray(top_lps)[: self._b_real],
+            "logprob": np.asarray(chosen)[self._rows],
+            "top_ids": np.asarray(top_ids)[self._rows],
+            "top_lps": np.asarray(top_lps)[self._rows],
         }
 
 

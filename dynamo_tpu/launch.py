@@ -162,10 +162,6 @@ class WorkerSpec:
                 env_flag(os.environ, "DYN_ASYNC_ONBOARD")
                 or env_flag(os.environ, "DYN_CACHE_AWARE")
             ),
-            overlap=(
-                env_flag(os.environ, "DYN_OVERLAP")
-                or env_flag(os.environ, "DYN_WORKER_OVERLAP")
-            ),
             overlap_spec=(
                 env_flag(os.environ, "DYN_OVERLAP_SPEC", default=True)
                 and env_flag(os.environ, "DYN_WORKER_OVERLAP_SPEC", default=True)
@@ -1154,12 +1150,6 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
         help="KV-cache storage dtype; fp8 halves KV HBM (attention upcasts "
         "at the matmul)",
     )
-    parser.add_argument(
-        "--overlap", action="store_true", default=ws.overlap,
-        help="overlapped execution: depth-1 decode pipeline with device-"
-        "resident token feedback (DYN_OVERLAP); output streams stay "
-        "bit-identical to off",
-    )
     parser.add_argument("--num-nodes", type=int, default=1, help="hosts forming one worker's mesh")
     parser.add_argument("--node-rank", type=int, default=0)
     parser.add_argument(
@@ -1214,10 +1204,6 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
         import os
 
         os.environ["DYN_WORKER_KV_CACHE_DTYPE"] = args.kv_cache_dtype
-    if args.overlap:
-        import os
-
-        os.environ["DYN_WORKER_OVERLAP"] = "1"
     args.runtime_settings = rs  # the cascade the flag defaults came from; main() logs by it
     return args
 

@@ -270,12 +270,6 @@ class MockRunner:
         aux = self._spec_lp_aux(targets, lp_k) if lp_k else None
         return MockSpecTokens(self, targets, aux, ready_at)
 
-    def can_chain(self, batch_size: int) -> bool:
-        return self._chain_host is not None and self._chain_host.shape[0] == batch_size
-
-    def chain_len(self) -> int:
-        return 0 if self._chain_host is None else int(self._chain_host.shape[0])
-
     def reset_chain(self) -> None:
         self._chain_host = None
 

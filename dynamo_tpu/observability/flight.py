@@ -10,8 +10,8 @@ recorder keeps the last N steps verbatim, the way an aircraft FDR does:
   cumulative preemptions/rejections, step wall time and in-step runner
   dispatch time, plus the overlapped-execution fields ``gap_ms`` (host gap
   since the previous step completed — the window the device idles unless
-  the DYN_OVERLAP pipeline hides it) and ``overlap_mode`` ("overlapped" /
-  "barrier" while the pipeline is armed, "" otherwise).
+  the pipelined loop hides it) and ``overlap_mode`` ("overlapped" /
+  "barrier"; "" only from an engine stepped synchronously throughout).
 - ``compile`` records — emitted by the :class:`~dynamo_tpu.observability.
   compile.CompileTracker` when a runner dispatch hits a never-seen shape
   bucket (the XLA recompile a generic tool cannot see).

@@ -145,9 +145,9 @@ class EngineMetrics:
             "dispatch path (pallas kernel, reference fallback, ring)",
             ["worker", "phase", "path"], registry=self.registry,
         )
-        # Overlapped execution (DYN_OVERLAP): device-idle observability.
+        # The pipelined step loop: device-idle observability.
         # gap_ms is the host window between a step returning and the next
-        # dispatch — the time the overlapped loop exists to hide.
+        # dispatch — the time the pipelined loop exists to hide.
         self.step_gap_ms_last = gauge(
             f"{ns}_step_gap_ms",
             "Host gap (ms) between the previous engine step completing and "
@@ -160,11 +160,10 @@ class EngineMetrics:
         )
         self._overlap_steps = Gauge(
             "dynamo_engine_overlap_steps_total",
-            "Engine steps by overlapped-execution mode while DYN_OVERLAP is "
-            "armed: 'overlapped' = a chained lookahead step was dispatched "
-            "before harvesting the previous one, 'barrier' = the step fell "
-            "back to the synchronous path (composition change, fill, spec, "
-            "constraints, penalties)",
+            "Engine steps by pipeline mode: 'overlapped' = the step was "
+            "dispatched before harvesting the previous one, 'barrier' = it "
+            "dispatched with nothing in flight or fell back to the "
+            "synchronous step (fill, cancel, spec, constraint miss, drain)",
             ["worker", "mode"], registry=self.registry,
         )
         self._overlap_barriers = Gauge(

@@ -92,8 +92,12 @@ def test_batched_staggered_arrivals():
     core = make_core()
     p1, p2 = [1, 2, 3, 4, 5], [9, 8, 7]
     core.add_request(greedy_request(p1, max_tokens=5))
-    first = {s.seq_id: out.token_ids for s, out in core.step()}  # prefill 1
+    # The pipelined loop reads a step's tokens while the next one runs: the
+    # step that dispatches the prefill hands back nothing yet.
+    assert core.step() == []  # prefill 1 dispatched
     core.add_request(greedy_request(p2, max_tokens=5))  # arrives mid-flight
+    first = {s.seq_id: out.token_ids for s, out in core.step()}  # prefill 1 read, prefill 2 beside decode 1 dispatched
+    assert list(first) == [0] and len(first[0]) == 1
     outputs = run_to_completion(core)
     assert first[0] + outputs[0] == greedy_reference(p1, 5)
     assert outputs[1] == greedy_reference(p2, 5)

@@ -2,6 +2,8 @@
 
 import asyncio
 
+import pytest
+
 from dynamo_tpu.engine.core import EngineConfig, EngineCore
 from dynamo_tpu.engine.runner import ModelRunner
 from dynamo_tpu.engine.service import JaxEngineService
@@ -65,7 +67,15 @@ async def test_cancellation_ends_stream():
             if len(got) == 2:
                 ctx.stop_generating()
         assert got[-1]["finish_reason"] in ("cancelled", "stop", "length")
+        # The pipelined loop may have dispatched one more step with the row
+        # before it saw the stop: it reads that step next (its token is
+        # discarded) and has nothing left.
+        for _ in range(200):
+            if not svc.core.has_work:
+                break
+            await asyncio.sleep(0.01)
         assert not svc.core.has_work
+        assert svc.core.allocator.stats().active_pages == 0
     finally:
         await svc.close()
 
@@ -92,7 +102,7 @@ def _spy(svc):
 
 
 async def test_a_steps_outputs_are_routed_after_the_next_enqueue():
-    svc = make_service()
+    svc = make_service(overlap=False)  # the synchronous step: what the pipelined loop barriers to
     log = _spy(svc)
     try:
         outs = [o async for o in svc.generate(req([1, 2, 3], 6), Context())]
@@ -111,8 +121,9 @@ async def test_a_steps_outputs_are_routed_after_the_next_enqueue():
         await svc.close()
 
 
-async def test_streams_see_every_token_in_order_under_deferred_routing():
-    svc = make_service()
+@pytest.mark.parametrize("overlap", [True, False], ids=["pipelined", "synchronous"])
+async def test_streams_see_every_token_in_order_under_deferred_routing(overlap):
+    svc = make_service(overlap=overlap)
     try:
         async def run(prompt, n):
             return [t async for o in svc.generate(req(prompt, n), Context()) for t in o["token_ids"]]
@@ -133,7 +144,7 @@ async def test_streams_see_every_token_in_order_under_deferred_routing():
 
 
 async def test_a_runner_without_the_callback_routes_at_once():
-    svc = make_service()
+    svc = make_service(overlap=False)
     log = _spy(svc)
     try:
         await svc.start()
@@ -148,7 +159,7 @@ async def test_a_runner_without_the_callback_routes_at_once():
 
 
 async def test_the_overlapped_loop_holds_nothing_back():
-    svc = make_service(overlap=True)  # step_async never blocks on its own result: no callback
+    svc = make_service()  # the serving loop; step_async never blocks on its own result: no callback
     log = _spy(svc)
     try:
         outs = [o async for o in svc.generate(req([1, 2, 3], 5), Context())]

@@ -210,21 +210,30 @@ def test_acceptance_positive_on_repetitive_stream():
     assert core.spec_steps > 0
 
 
-def test_rng_fold_advances_once_per_emitted_token():
+@pytest.mark.parametrize("overlap", [True, False], ids=["pipelined", "synchronous"])
+def test_rng_fold_advances_once_per_emitted_token(overlap):
     """sample_steps handed to the verify dispatch must equal the number of
     tokens emitted so far — fold advances exactly once per emitted token,
-    never per dispatch and never for rejected drafts."""
-    core = make_core(spec_k=4, params=_flat_params())
+    never per dispatch and never for rejected drafts. The pipelined loop reads
+    a verify in flight before it composes on top of it (its acceptance decides
+    every position after), and chains a verify out of a plain step in flight:
+    at its dispatch the fold is the tokens the sequence holds plus the one the
+    step in flight owes it."""
+    core = make_core(spec_k=4, params=_flat_params(), overlap=overlap)
     calls = []
-    orig = core.runner.spec_step
+    site = "spec_step_async" if overlap else "spec_step"
+    orig = getattr(core.runner, site)
 
-    def spy(batch, verify_width, lp_k=0):
-        calls.append(int(np.asarray(batch.sample_steps)[0]))
-        return orig(batch, verify_width, lp_k=lp_k)
+    def spy(batch, verify_width, lp_k=0, **kw):
+        calls.append((int(np.asarray(batch.sample_steps)[0]), seq.num_generated + core._adv(seq)[1]))
+        return orig(batch, verify_width, lp_k=lp_k, **kw)
 
-    core.runner.spec_step = spy
+    setattr(core.runner, site, spy)
     seq = core.add_request(PreprocessedRequest(
-        token_ids=[1, 2, 3, 4],
+        # A periodic prompt: the proposer drafts from it, and the seeded stream
+        # rejects most of what it drafts (a step without a draft is no verify
+        # in the pipelined loop, which then chains a plain step).
+        token_ids=[1, 2, 3, 4] if not overlap else [1, 2, 1, 2, 1, 2, 1],
         sampling=SamplingOptions(temperature=0.9, seed=11),
         stop=StopConditions(max_tokens=16, ignore_eos=True),
     ))
@@ -233,9 +242,12 @@ def test_rng_fold_advances_once_per_emitted_token():
     while core.has_work and steps < 100:
         before = len(calls)
         outs = core.step()
-        if len(calls) > before:
-            assert calls[-1] == emitted
         emitted += sum(len(o.token_ids) for _, o in outs)
+        if len(calls) > before:
+            fold, generated = calls[-1]
+            assert fold == generated
+            if not overlap:  # nothing in flight, nothing handed back yet: the tokens emitted before this step
+                assert fold == emitted - sum(len(o.token_ids) for _, o in outs)
         steps += 1
     assert emitted == 16
     assert len(calls) > 0

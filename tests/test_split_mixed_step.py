@@ -1,9 +1,11 @@
 """A chunk step on one token axis (ISSUE 27): a decode row rides a chunk step
 as one token position, not padded to the chunk. The split layout against the
 rows x t rectangle for the same batch (dense GQA, an OLMoE-like MoE, a window +
-full model), the warm-up property (a null batch and a served step of the same
-buckets are one program), who keeps the rectangle, the row order of what comes
-back, and the STEP record's ``step_tokens`` / ``layout``."""
+full model), from both of the runner's entry points (ISSUE 29: the synchronous
+``step`` and the pipelined loop's ``step_async`` call one program per layout),
+the warm-up property (a null batch and a served step of the same buckets are
+one program, chained or not), who keeps the rectangle, the row order of what
+comes back, and the STEP record's ``step_tokens`` / ``layout``."""
 
 import dataclasses
 
@@ -71,17 +73,32 @@ def step_batch(rows, *, pages_per_row: int = 10, seed: int = 0, temperature: flo
         num_new=np.asarray([n for _, n in rows], np.int32))
 
 
-# -- the same step, both layouts --------------------------------------------------
+# -- the same step, both layouts, both entry points --------------------------------
 
 
+def _sync(runner, batch, **kw):
+    return runner.step(batch, **kw)
+
+
+def _async(runner, batch, lp_k=0):
+    """``step_async`` as the pipelined loop calls it, handed back in ``step``'s form."""
+    toks, lp = runner.step_async(batch, lp_k=lp_k).result()
+    return (toks[:, 0], lp) if lp_k else toks[:, 0]
+
+
+LOOPS = {"step": _sync, "step_async": _async}
+
+
+@pytest.mark.parametrize("loop", LOOPS.keys())
 @pytest.mark.parametrize("rows", BATCHES.values(), ids=BATCHES.keys())
 @pytest.mark.parametrize("model", MODELS.keys())
-def test_split_layout_computes_what_the_rectangle_computes(model, rows):
+def test_split_layout_computes_what_the_rectangle_computes(model, rows, loop):
     """Sampled tokens, logprobs (the tolerance of tests/test_chunked_prefill.py)
-    and every live page of the cache, split against rectangle."""
+    and every live page of the cache, split against rectangle: a decode row
+    beside a chunk, two chunk slots, a lone chunk that keeps the rectangle."""
     cfg = MODELS[model]
     a, b = runner_for(cfg), runner_for(cfg, split=False)
-    toks_a, lp_a = a.step(step_batch(rows), lp_k=3)
+    toks_a, lp_a = LOOPS[loop](a, step_batch(rows), lp_k=3)
     toks_b, lp_b = b.step(step_batch(rows), lp_k=3)
     assert a.last_step_layout[0] == (SPLIT if len(rows) > 1 else ROWS_X_T) and b.last_step_layout[0] == ROWS_X_T
     assert toks_a.shape == (len(rows),) and toks_a.tolist() == toks_b.tolist()
@@ -92,11 +109,42 @@ def test_split_layout_computes_what_the_rectangle_computes(model, rows):
         np.testing.assert_allclose(np.asarray(ca)[:, 1:], np.asarray(cb)[:, 1:], rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("loop", LOOPS.keys())
 @pytest.mark.parametrize("model", MODELS.keys())
-def test_seeded_sampling_folds_row_by_row_as_in_the_rectangle(model):
+def test_seeded_sampling_folds_row_by_row_as_in_the_rectangle(model, loop):
     rows = BATCHES["chunk-between-decodes"]
     a, b = runner_for(MODELS[model]), runner_for(MODELS[model], split=False)
-    assert a.step(step_batch(rows, temperature=0.9)).tolist() == b.step(step_batch(rows, temperature=0.9)).tolist()
+    assert (LOOPS[loop](a, step_batch(rows, temperature=0.9)).tolist()
+            == b.step(step_batch(rows, temperature=0.9)).tolist())
+
+
+@pytest.mark.parametrize("rows", [BATCHES["two-decodes-one-chunk"], BATCHES["chunk-between-decodes"]],
+                         ids=["one-chunk-slot", "two-chunk-slots"])
+def test_a_split_step_chains_its_decode_rows_and_hands_on_a_chunk_rows_sample(rows):
+    """Two dispatches of the pipelined loop on the split layout against the
+    same two stepped synchronously with the host's tokens: the first leaves
+    every row's sample in the chain buffer at its row index (a chunk row's
+    too, which the program emits past the decode slots), the second gathers
+    each decode row's input token from there, its host token a placeholder."""
+    cfg = MODELS["dense-gqa"]
+    a, b = runner_for(cfg), runner_for(cfg)
+    first = step_batch(rows, temperature=0.9)
+    toks = b.step(first)
+    assert a.step_async(step_batch(rows, temperature=0.9)).result()[0][:, 0].tolist() == toks.tolist()
+    assert np.asarray(a._chain_tokens).shape == (a._chain_width,)
+    assert np.asarray(a._chain_tokens)[: len(rows)].tolist() == toks.tolist()
+    # The step after: every row decodes on (a chunk row's prompt ended), beside one new chunk row.
+    nxt = [(start + n, 1) for start, n in rows] + [(0, 6)]
+    host = step_batch(nxt, seed=1, temperature=0.9)
+    host.tokens[: len(rows), 0] = toks
+    chained = step_batch(nxt, seed=1, temperature=0.9)
+    chained.tokens[: len(rows), 0] = 0
+    src = np.asarray(list(range(len(rows))) + [-1], np.int32)
+    got = a.step_async(chained, chain=True, chain_src=src).result()[0][:, 0]
+    assert a.last_step_layout[0] == SPLIT
+    assert got.tolist() == b.step(host).tolist()
+    for ca, cb in ((a.k_cache, b.k_cache), (a.v_cache, b.v_cache)):
+        np.testing.assert_array_equal(np.asarray(ca)[:, 1:], np.asarray(cb)[:, 1:])
 
 
 def test_tokens_come_back_in_the_batchs_row_order():
@@ -163,23 +211,73 @@ def test_split_step_on_the_kernels_in_interpret_mode(monkeypatch):
 # -- the warm-up property ---------------------------------------------------------
 
 
+@pytest.mark.parametrize("loop", LOOPS.keys())
 @pytest.mark.parametrize("rows, null", [
     (BATCHES["two-decodes-one-chunk"], (4, 8, 16)),  # 3 rows -> 4, 7 tokens -> 8, 10 pages -> 16
     (BATCHES["one-token-last-chunk"], (4, 8, 16)),
     # A lone chunk row: the split would add a padding decode slot to its 8 tokens, so null batch and step keep [1, 8].
     (BATCHES["one-chunk-alone"], (1, 8, 16)),
 ], ids=["mixed", "mixed-with-a-1-token-tail", "pure-prefill"])
-def test_a_null_batch_warms_the_program_a_served_step_runs(rows, null):
+def test_a_null_batch_warms_the_program_a_served_step_runs(rows, null, loop):
     runner = runner_for(MODELS["dense-gqa"])
     runner.step(serving.null_batch(*null))  # what benchmark/serving.warm_up steps a program with: every row padding
     (warm,) = runner.compile_tracker.events()
     layout = (SPLIT, null[0] + null[1]) if null[0] > 1 else (ROWS_X_T, null[1])
     assert warm["bucket"][:3] == list(null) and (warm["bucket"][-2:] == [SPLIT, 1]) == (null[0] > 1)
     assert runner.last_step_layout == layout
-    runner.step(step_batch(rows))
+    LOOPS[loop](runner, step_batch(rows))
     assert runner.compile_tracker.events() == [warm]  # no new key: the served step ran the warmed program
     assert runner._step_split_fn._cache_size() + runner._step_packed_fn._cache_size() == 1
     assert runner.last_step_layout == layout
+
+
+def _jit_programs(runner) -> int:
+    return runner._step_split_fn._cache_size() + runner._step_packed_fn._cache_size()
+
+
+def test_an_overlapped_run_compiles_nothing_a_null_batch_warm_up_has_not():
+    """After ``runner.step(null_batch(b, t, n))`` over a small lattice, as the
+    benchmark warms a cell, the pipelined loop serves requests that cross a
+    rows bucket (3 -> 5 rows) and mix chunk and decode steps, chained wherever
+    it can, and neither traces a program nor records a dispatch key the
+    warm-up has not: the chain buffer has one width whatever the bucket of the
+    dispatch before, so it is no part of a program's key."""
+    chunk = 16
+    core = _core(MODELS["dense-gqa"], chunk=chunk)
+    runner = core.runner
+    lattice = [(b, t, n) for t in (1, chunk) for b in (1, 2, 4, 8) for n in (1, 2, 4, 8, 16)]
+    for shape in lattice:
+        runner.step(serving.null_batch(*shape))
+    warm, programs = list(runner.compile_tracker.events()), _jit_programs(runner)
+    assert len(warm) == len(lattice) == programs
+    assert {e["program"] for e in warm} == {"step"}
+    outputs: dict = {}
+    for i in range(3):  # prompts in whole chunks, as the cells' are: the time axis is 1 or the chunk
+        core.add_request(greedy_request(list(range(1 + i, 1 + i + chunk)), max_tokens=24))
+    for _ in range(6):
+        for seq, out in core.step():
+            outputs.setdefault(seq.seq_id, []).extend(out.token_ids)
+    for i in range(2):  # two more beside the three decoding: rows 3 -> 5, and their prompts in two chunks
+        core.add_request(greedy_request(list(range(7 + i, 7 + i + 2 * chunk)), max_tokens=8))
+    outputs = run_to_completion(core, outputs=outputs)
+    assert [len(outputs[i]) for i in range(5)] == [24, 24, 24, 8, 8]
+    steps = [s for s in core.flight.snapshot(kind="step") if s["attn_phase"]]
+    assert max(s["decode_rows"] for s in steps) == 5
+    assert any(s["step_kind"] == "mixed" and s["layout"] == SPLIT and s["chained_rows"] for s in steps)
+    assert sum(s["overlap_mode"] == "overlapped" for s in steps) > len(steps) // 2
+    assert runner.compile_tracker.events() == warm and _jit_programs(runner) == programs
+
+
+@pytest.mark.parametrize("rows", [[(5, 1), (9, 1), (4, 1)], BATCHES["two-decodes-one-chunk"],
+                                  BATCHES["chunk-between-decodes"], BATCHES["one-chunk-alone"]],
+                         ids=["decode", "one-chunk-slot", "two-chunk-slots", "lone-chunk"])
+def test_step_and_step_async_record_one_dispatch_key_for_a_shape(rows):
+    a, b = runner_for(MODELS["dense-gqa"]), runner_for(MODELS["dense-gqa"])
+    a.step(step_batch(rows))
+    b.step_async(step_batch(rows)).result()
+    (ea,), (eb,) = a.compile_tracker.events(), b.compile_tracker.events()
+    assert (ea["program"], ea["bucket"]) == (eb["program"], eb["bucket"]) and ea["program"] == "step"
+    assert a.last_step_layout == b.last_step_layout
 
 
 def test_decode_steps_keep_their_program_and_key():
@@ -216,8 +314,10 @@ OUTSIDE = {
                    lambda r: r.step(_masked(step_batch(BATCHES["two-decodes-one-chunk"]))), 4 * 8),
     "spec-verify": (lambda: runner_for(MODELS["dense-gqa"]),
                     lambda r: r.spec_step(step_batch(BATCHES["two-decodes-one-chunk"]), 2), 4 * 8),
-    "async": (lambda: runner_for(MODELS["dense-gqa"]),
-              lambda r: r.step_async(step_batch(BATCHES["two-decodes-one-chunk"])).result(), 4 * 8),
+    # A chunk row whose first token is chained (no engine composes one): only the rectangle gathers into it.
+    "async-chained-chunk-row": (lambda: runner_for(MODELS["dense-gqa"]), lambda r: (
+        r.step_async(step_batch([(5, 1), (21, 1), (12, 1)])).result(),
+        r.step_async(step_batch(BATCHES["two-decodes-one-chunk"]), chain=True).result()), 4 * 8),
     "mesh": (_mesh_runner, lambda r: r.step(step_batch(BATCHES["two-decodes-one-chunk"])), 4 * 8),
     "mla": (lambda: runner_for(PRESETS["test-tiny-mla"]),
             lambda r: r.step(step_batch(BATCHES["two-decodes-one-chunk"])), 4 * 8),
@@ -242,9 +342,9 @@ def test_steps_outside_the_class_keep_the_rectangle(case):
 # -- through the engine: the STEP record and the counters ----------------------------
 
 
-def _core(cfg, chunk: int = 4) -> EngineCore:
+def _core(cfg, chunk: int = 4, **engine) -> EngineCore:
     config = EngineConfig(num_pages=64, page_size=PAGE, max_batch_size=8, max_prefill_tokens=chunk,
-                          max_seq_len=128, chunk_prefill_tokens=chunk)
+                          max_seq_len=128, chunk_prefill_tokens=chunk, **engine)
     runner = ModelRunner(cfg, llama.init_params(cfg, 0), num_pages=64, page_size=PAGE, max_batch_size=8,
                          prefill_bucket=16, attn_impl="reference")
     return EngineCore(runner, config)
@@ -261,8 +361,9 @@ def _serve_two(core: EngineCore) -> dict:
     return run_to_completion(core, outputs=outputs)
 
 
-def test_step_records_say_layout_and_tokens_and_the_engine_counts_them():
-    core = _core(MODELS["dense-gqa"])
+@pytest.mark.parametrize("overlap", [True, False], ids=["pipelined", "synchronous"])
+def test_step_records_say_layout_and_tokens_and_the_engine_counts_them(overlap):
+    core = _core(MODELS["dense-gqa"], overlap=overlap)
     outputs = _serve_two(core)
     assert outputs[0] == greedy_reference([1, 2, 3, 4, 5], 12)
     assert outputs[1] == greedy_reference(list(range(7, 24)), 5)
@@ -270,16 +371,24 @@ def test_step_records_say_layout_and_tokens_and_the_engine_counts_them():
     chunky = [s for s in steps if s["chunk_rows"]]
     mixed = [s for s in chunky if s["decode_rows"]]
     assert mixed and all(s["step_kind"] == "mixed" for s in mixed)
-    assert all(s["layout"] == SPLIT for s in mixed)
+    # A prompt's 1-token tail: the synchronous step seats it as a decode row; the
+    # pipelined loop, which schedules it while the chunk before it is in flight,
+    # as a chunk row of one token. A decode program (T = 1) either way.
+    tails = [s for s in chunky if s["chunk_tokens"] == 1]
+    assert len(tails) == (2 if overlap else 0)
+    assert all((s["layout"], s["step_tokens"]) == (ROWS_X_T, s["decode_rows"] + 1) for s in tails)
+    mixed = [s for s in mixed if s not in tails]
+    assert mixed and all(s["layout"] == SPLIT for s in mixed)
     # a decode row and a chunk row: 2 slots + the 4-token chunk, not 2 x 4; a lone chunk row keeps its [1, 4]
     assert all(s["step_tokens"] == 2 + 4 for s in mixed)
-    lone = [s for s in chunky if not s["decode_rows"]]
+    lone = [s for s in chunky if not s["decode_rows"] and s not in tails]
     assert lone and all((s["layout"], s["step_tokens"]) == (ROWS_X_T, 4) for s in lone)
     assert all(s["decode_rows"] + s["chunk_tokens"] <= s["step_tokens"] for s in chunky)
     decodes = [s for s in steps if not s["chunk_rows"]]
-    assert decodes and all(s["layout"] == ROWS_X_T and s["step_tokens"] == 1 for s in decodes[:3])
-    assert core.chunk_steps_split == len(mixed) and core.chunk_steps_rows_x_t == len(lone)
+    assert decodes and all(s["layout"] == ROWS_X_T and s["step_tokens"] == 1 for s in decodes[:2])
+    assert core.chunk_steps_split == len(mixed) and core.chunk_steps_rows_x_t == len(lone) + len(tails)
     assert core.mixed_steps >= len(mixed)
+    assert {s["overlap_mode"] for s in steps} == ({"overlapped", "barrier"} if overlap else {""})
 
 
 def test_an_mla_engine_counts_its_chunk_steps_as_padded():

@@ -122,7 +122,11 @@ def test_traced_steps_open_phase_regions_inside_the_engine_region(overlap, fake_
     log = fake_annotation.log
     opened = [n for ev, n in log if ev == "open"]
     engine = [n for n in opened if n.startswith("engine.")]
-    assert set(engine) <= {"engine.decode", "engine.mixed", "engine.prefill", "engine.overlap"}
+    # Named by what the step dispatches, in the pipelined loop as on the synchronous step:
+    # the benchmark's trace readers select the step programs by these names.
+    assert {"engine.decode"} <= set(engine) <= {"engine.decode", "engine.mixed", "engine.prefill"}
+    kinds = {r["step_kind"]: n for r, n in zip([r for r in steps if r["traced"]], engine)}
+    assert kinds["decode"] == "engine.decode"
     assert set(opened) - set(engine) == {f"phase.{p}" for p in tracing.STEP_PHASES}
     # One engine.* region per traced record, its entry stamped inside the step.
     traced = [r for r in steps if r["traced"]]
@@ -224,7 +228,7 @@ def test_named_scopes_reach_the_lowered_step_programs_op_names():
     padded = runner._pad(batch)
     bp, tp = padded.tokens.shape
     lowered = runner._step_packed_fn.lower(
-        runner.params, runner.k_cache, runner.v_cache, _pack(padded),
+        runner.params, runner.k_cache, runner.v_cache, _pack(padded), runner._chain_idle,
         b=bp, t=tp, n=padded.block_tables.shape[1], h=padded.history.shape[1], lp_k=0)
     import re
 
