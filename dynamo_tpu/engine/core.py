@@ -738,6 +738,10 @@ class EngineCore:
                 kv_tokens_full=report.kv_tokens_full,
                 kv_tokens_window=report.kv_tokens_window,
                 step_tokens=report.step_tokens,
+                moe_choices=report.moe_counts[0],  # parallel/moe.HELD_COUNTS, in its order: plain keywords,
+                moe_choices_zero=report.moe_counts[1],  # because a ** in the middle takes the whole call
+                moe_choices_held=report.moe_counts[2],  # off the interpreter's fast path (0.015 ms a step
+                moe_experts_touched=report.moe_counts[3],  # on a v5e's host: PERF.md, PR 34)
                 layout=report.layout,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
@@ -2108,9 +2112,13 @@ class EngineCore:
             else:
                 tokens[i, :n] = s.tokens[ec : ec + n]
                 samples[i] = ec + n == len(s.tokens)
+            block_tables[i, : len(s.pages)] = s.pages
+            if n == 1:  # a decode row: one position, in plain integers
+                positions[i, 0] = ec
+                slots[i, 0] = s.pages[ec // ps] * ps + ec % ps
+                continue  # last[i] stays 0
             pos = np.arange(ec, ec + n, dtype=np.int32)
             positions[i, :n] = pos
-            block_tables[i, : len(s.pages)] = s.pages
             page_arr = np.asarray(s.pages, dtype=np.int32)
             slots[i, :n] = page_arr[pos // ps] * ps + pos % ps
             last[i] = n - 1

@@ -316,8 +316,12 @@ class DispatchReport:
     moe_path: str = ""  # "fused" / "widened"; "" for a dense model
     layout: str = ""  # ROWS_X_T / SPLIT
     step_tokens: int = 0  # token positions the dispatched program computes
-    kv_tokens_full: int = 0  # key tokens one full / one windowed layer visits
+    kv_tokens_full: int = 0  # key tokens one full / one windowed attention (sub)layer visits
     kv_tokens_window: int = 0
+    # parallel/moe.HELD_COUNTS, counted on the device by the programs of a model
+    # whose expert layer holds a share or has identity experts, and summed over
+    # the programs whose outputs had reached the host when the report was taken.
+    moe_counts: tuple[int, int, int, int] = (0, 0, 0, 0)
 
 
 class ModelRunner:
@@ -374,9 +378,15 @@ class ModelRunner:
         # What has been dispatched since the engine last took it (take_dispatch).
         self._report: DispatchReport | None = None
         # A chunk step may lay its tokens out on one axis where the model step
-        # has the flat path: GQA / MHA text models on one device (llama.forward).
-        self._can_split = (forward_fn is None and mesh is None and cfg.attn_type != "mla"
-                           and not cfg.mrope_section)
+        # has the flat path: text models on one device (llama.forward), GQA,
+        # MHA and MLA attention alike.
+        self._can_split = forward_fn is None and mesh is None and not cfg.mrope_section
+        # The step programs of such a model return the expert layers' counters
+        # beside their outputs (llama.forward's moe_counts); they wait here, on
+        # the device, until a report takes those that are ready.
+        self._moe_counted = forward_fn is None and cfg.moe_held_share
+        counted = {"moe_counts": True} if self._moe_counted else {}  # llama.forward's keyword, where it applies
+        self._moe_counts_pending: list[jax.Array] = []
         # The most recent dispatch's padded batch, until its key tokens are
         # counted into the report (_count_kv): after the enqueue, under the
         # device's shadow, never between a result and the next enqueue.
@@ -461,13 +471,13 @@ class ModelRunner:
                     mrope_positions if mrope_positions is not None
                     else _delta_mrope(positions, mrope_delta)
                 )
-            logits, k_cache, v_cache = self._forward(
+            logits, k_cache, v_cache, *counts = self._forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache,
                 block_tables, slot_mapping, last_idx, attn_impl=impl, mesh=self.mesh,
-                **mm_kw,
+                **mm_kw, **counted,
             )
-            return _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
-                           freq_pen, pres_pen, history, logit_mask, lp_k)
+            return (*_sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
+                             freq_pen, pres_pen, history, logit_mask, lp_k), *counts)
 
         self._step_fn = _step
 
@@ -494,12 +504,12 @@ class ModelRunner:
             history = jnp.concatenate([hist, history[nd:]])
             limit = jnp.concatenate([pos_limit[:nd], jnp.repeat(pos_limit[nd:], tc)])
             slot_mapping = jnp.where(positions < limit, slot_mapping, 0)  # _step's finish-line clamp
-            logits, k_cache, v_cache = llama.forward(
+            logits, k_cache, v_cache, *counts = llama.forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping,
-                last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc),
+                last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc), **counted,
             )
-            out = _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
-                          freq_pen, pres_pen, history, None, lp_k)
+            out = (*_sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
+                            freq_pen, pres_pen, history, None, lp_k), *counts)
             chain = _chain_out(out[0][:nd], chain_buf.shape[0])
             for k in range(nc):  # a slot without a row holds -1: no index matches
                 chain = jnp.where(jnp.arange(chain.shape[0]) == chunk_row[k], out[0][nd + k], chain)
@@ -964,7 +974,7 @@ class ModelRunner:
         ("ring") without touching the jitted program."""
         t = int(padded.tokens.shape[1])
         phase = "verify" if (verify and t > 1) else ("decode" if t == 1 else "prefill")
-        if self.cfg.sliding_window:  # a model without a windowed layer counts nothing (0, 0)
+        if self.cfg.sliding_window or self.cfg.attn_type == "mla":  # other models count nothing (0, 0)
             self._kv_pending = padded
         if impl == "ring":
             return phase, "ring"
@@ -977,10 +987,11 @@ class ModelRunner:
         if self.cfg.attn_type == "mla":
             from dynamo_tpu.ops.pallas_mla import mla_decode_supported
 
-            # MLA prefill DOES ride the multi-query kernel (T <= row cap).
+            # Every attention sublayer alike, and whatever T: a row's queries
+            # ride the multi-query kernel in tiles within its row cap
+            # (models/mla._attend_paged), on the split token axis too.
             ok = mla_decode_supported(
-                self.k_cache.shape[-1], self.v_cache.shape[-1],
-                t if t > 1 else 1, self.cfg.num_heads, interpret=interp,
+                self.k_cache.shape[-1], self.v_cache.shape[-1], 1, self.cfg.num_heads, interpret=interp,
             )
         else:
             from dynamo_tpu.ops.pallas_paged import decode_kernel_supported
@@ -998,14 +1009,17 @@ class ModelRunner:
 
     def _kv_tokens(self, padded: StepBatch) -> tuple[int, int]:
         """Key tokens one layer of each kind has to visit in this dispatch:
-        a full layer every row's context; a windowed layer at most the window
-        plus the row's new tokens less one. Padding rows (a null block table)
-        count nothing. Only a model with a windowed layer is counted."""
+        a full layer (an MLA model's attention sublayer) every row's context;
+        a windowed layer at most the window plus the row's new tokens less
+        one. Padding rows (a null block table) count nothing. Only a model
+        with a windowed layer, or with latent attention, is counted."""
         pos = np.asarray(padded.positions)[np.asarray(padded.block_tables).any(axis=1)]
         if not len(pos):
             return 0, 0
         context = pos.max(axis=1).astype(np.int64) + 1
         win = self.cfg.sliding_window
+        if not win:
+            return int(context.sum()), 0
         first = np.where(pos > 0, pos, np.iinfo(np.int32).max).min(axis=1)
         new = context - np.minimum(pos[:, 0], first)
         return int(context.sum()), int(np.minimum(context, win + new - 1).sum())
@@ -1050,7 +1064,29 @@ class ModelRunner:
         report, self._report = self._report, None
         if report is not None:
             self._count_kv(report)  # an async site has not counted yet
+            if self._moe_counts_pending:
+                report.moe_counts = self._ready_moe_counts()
         return report
+
+    def _keep_moe_counts(self, out: tuple) -> tuple:
+        """A step program's outputs without the expert layers' counters, which
+        stay on the device (their copy to the host started) for a later report."""
+        if not self._moe_counted:
+            return out
+        *out, counts = out
+        counts.copy_to_host_async()
+        self._moe_counts_pending.append(counts)
+        return tuple(out)
+
+    def _ready_moe_counts(self) -> tuple[int, int, int, int]:
+        """The pending counters of the programs that have ended, summed and
+        dropped: a synchronous step's own, a pipelined step's predecessor's."""
+        pending, ready = self._moe_counts_pending, 0
+        while ready < len(pending) and pending[ready].is_ready():
+            ready += 1
+        self._moe_counts_pending = pending[ready:]
+        total = np.sum([np.asarray(c) for c in pending[:ready]], axis=0, dtype=np.int64) if ready else np.zeros(4)
+        return tuple(int(v) for v in total)
 
     @property
     def last_attn_dispatch(self) -> tuple[str, str] | None:
@@ -1160,8 +1196,8 @@ class ModelRunner:
         sample accepted by the engine (the rest are discarded host-side).
 
         That rectangle is what the caller hands in, not what is computed: a
-        ``T > 1`` step of several rows, of a GQA / MHA text model on one
-        device, runs a program whose token axis holds one position per row
+        ``T > 1`` step of several rows, of a text model on one device (GQA,
+        MHA or MLA attention), runs a program whose token axis holds one position per row
         plus ``T`` per chunk row (``_chunk_rows``, ``_pack_split``,
         ``llama.forward``'s ``split``), and the sampled tokens come back in
         the batch's row order all the same.
@@ -1199,6 +1235,7 @@ class ModelRunner:
                     opt(padded.mrope_positions), opt(padded.logit_mask),
                     impl=impl, lp_k=lp_k,
                 )
+            out = self._keep_moe_counts(out)
             self._mark_wait()
             if lp_k:
                 next_tokens, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
@@ -1441,6 +1478,7 @@ class ModelRunner:
                         impl=impl, lp_k=lp_k,
                     )
             chain_buf = out[0]  # [Bp], the rows in order: a shape of its own
+        out = self._keep_moe_counts(out)
         if lp_k:
             toks, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
             aux = (chosen, top_ids, top_lps)

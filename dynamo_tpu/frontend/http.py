@@ -327,7 +327,7 @@ class HttpService:
                     await resp.write(sse_encode(_ENGINE_ERROR_EVENT))
                     break
                 if jail is None:
-                    await resp.write(sse_encode(fmt.delta(out)))
+                    await resp.write(fmt.sse_delta(out))
                     if out.first_token_ts is not None:
                         # Engine's first token -> its SSE chunk written: the
                         # hop back, detokenizing, and this loop's own queue.

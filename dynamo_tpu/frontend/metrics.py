@@ -279,6 +279,12 @@ class FrontendMetrics:
         return RequestTracker(self, model, endpoint)
 
 
+#: A stream's inter-token gaps are handed to the deployment-wide ITL
+#: histogram and quantiles this many at a time, or after this many seconds.
+_ITL_BATCH = 32
+_ITL_BATCH_S = 1.0
+
+
 class RequestTracker:
     """Per-request context manager: times the request + token stream gaps."""
 
@@ -295,6 +301,10 @@ class RequestTracker:
         # deployment aggregates.
         self._ttft: float | None = None
         self._gaps: list[float] = []
+        # Gaps reach the deployment-wide histogram and quantiles in batches
+        # (_feed_gaps): how many of _gaps they have seen, and when.
+        self._fed = 0
+        self._fed_at = 0.0
         self._tokens = 0
         self._admission_reported = False
 
@@ -309,6 +319,7 @@ class RequestTracker:
         self.m.inflight.labels(self.model).dec()
         self.m.requests.labels(self.model, self.endpoint, self.status).inc()
         self.m.duration.labels(self.model).observe(time.monotonic() - self._start)
+        self._feed_gaps(self._last_token or 0.0)
         if self._ttft is not None:  # token-producing request: classify vs SLO
             verdict = self.m.slo.account(
                 ttft_s=self._ttft,
@@ -333,16 +344,33 @@ class RequestTracker:
 
     def on_token(self) -> None:
         now = time.monotonic()
-        if self._last_token is None:
+        last = self._last_token
+        self._last_token = now
+        if last is None:
             self._ttft = now - self._start
             self.m.ttft.labels(self.model).observe(self._ttft)
             self.m.slo.observe_ttft(self._ttft)
-        else:
-            gap = now - self._last_token
-            self.m.itl.labels(self.model).observe(gap)
-            self.m.slo.observe_itl(gap)
-            self._gaps.append(gap)
-        self._last_token = now
+            self._fed_at = now
+            return
+        gaps = self._gaps
+        gaps.append(now - last)
+        if len(gaps) - self._fed >= _ITL_BATCH or now - self._fed_at >= _ITL_BATCH_S:
+            self._feed_gaps(now)
+
+    def _feed_gaps(self, now: float) -> None:
+        """Hand the gaps not yet seen to the ITL histogram and the SLO
+        quantiles, in one tight loop. Per token this costs the event loop a
+        list append; a scrape in mid-stream lags a stream by at most
+        ``_ITL_BATCH`` gaps or ``_ITL_BATCH_S`` seconds, and a finished
+        request has handed in every gap (``__exit__``)."""
+        fed, self._fed, self._fed_at = self._fed, len(self._gaps), now
+        if fed == self._fed:
+            return
+        observe = self.m.itl.labels(self.model).observe
+        observe_slo = self.m.slo.observe_itl
+        for gap in self._gaps[fed:]:
+            observe(gap)
+            observe_slo(gap)
 
     def on_usage(self, prompt_tokens: int | None, output_tokens: int, cached_tokens: int | None) -> None:
         if prompt_tokens:

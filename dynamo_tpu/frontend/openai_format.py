@@ -79,7 +79,35 @@ def _chat_lp_content(entries: list[dict]) -> list[dict[str, Any]]:
     return out
 
 
-class ChatStream:
+#: Stands for a delta's text in a stream's pre-rendered plain chunk.
+_TEXT_MARK = "\x00dyn-text\x00"
+
+
+class _PlainDelta:
+    """A stream's plain delta (text only: no finish, no logprobs) as bytes
+    around the text: every field but the text is the same from chunk to
+    chunk, so the chunk is rendered once and split at the text."""
+
+    _plain: tuple[bytes, bytes] | None = None
+    _empty: bytes | None = None
+
+    def sse_delta(self, out: BackendOutput) -> bytes:
+        """``sse_encode(self.delta(out))``, byte for byte."""
+        if out.finish_reason is not None or out.logprobs:
+            return sse_encode(self.delta(out))
+        if not out.text:  # a token that released no text yet: the same chunk every time
+            if self._empty is None:
+                self._empty = sse_encode(self.delta(out))
+            return self._empty
+        plain = self._plain
+        if plain is None:
+            head, tail = sse_encode(self.delta(BackendOutput(text=_TEXT_MARK))).split(
+                json.dumps(_TEXT_MARK).encode(), 1)
+            plain = self._plain = (head, tail)
+        return plain[0] + json.dumps(out.text).encode() + plain[1]
+
+
+class ChatStream(_PlainDelta):
     """Builds chat.completion.chunk objects from BackendOutput deltas."""
 
     def __init__(self, model: str, *, request_id: str | None = None, send_usage: bool = False) -> None:
@@ -132,7 +160,7 @@ class ChatStream:
         return self._chunk({"tool_calls": deltas}, finish="tool_calls", usage=usage)
 
 
-class CompletionStream:
+class CompletionStream(_PlainDelta):
     """Builds text_completion chunks from BackendOutput deltas."""
 
     def __init__(self, model: str, *, request_id: str | None = None, send_usage: bool = False) -> None:

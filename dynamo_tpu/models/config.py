@@ -170,6 +170,25 @@ class ModelConfig:
     # convention models/ops use (models/loader.py). False for every
     # non-MLA family: their HF checkpoints are already half-split.
     rope_interleave: bool = False
+    # LongCat-Flash: ``mla_scale_q_lora`` / ``mla_scale_kv_lora``. The query
+    # (both parts) and the KV latent are multiplied by these after their
+    # latent norms: sqrt(hidden / rank) where the config switches them on.
+    mla_scale_q: float = 1.0
+    mla_scale_kv: float = 1.0
+    # Shortcut-connected MoE layer (LongCat-Flash): one layer holds two
+    # attention sublayers and two dense FFNs, and a MoE that reads the first
+    # sublayer's post-attention norm and is added at the end of the layer
+    # (models/llama.py). The cache holds ``cache_layers`` slabs.
+    shortcut_moe: bool = False
+    # Identity "zero-compute" experts: router outputs past the routed experts
+    # whose term is the token itself times its routing weight.
+    moe_zero_experts: int = 0
+    # A share of the routed experts held here (expert parallelism, one chip's
+    # part): the router scores ``moe_experts_total`` experts (0 = all
+    # ``num_experts`` are held), this model holds ``num_experts`` of them
+    # from id ``moe_expert_first`` on and computes their part of the result.
+    moe_experts_total: int = 0
+    moe_expert_first: int = 0
 
     @property
     def q_dim(self) -> int:
@@ -182,6 +201,26 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def cache_layers(self) -> int:
+        """Slabs of the paged cache: one per attention (sub)layer."""
+        return self.num_layers * (2 if self.shortcut_moe else 1)
+
+    @property
+    def routed_experts(self) -> int:
+        """Routed experts the router scores, held here or not."""
+        return self.moe_experts_total or self.num_experts
+
+    @property
+    def router_outputs(self) -> int:
+        return self.routed_experts + self.moe_zero_experts
+
+    @property
+    def moe_held_share(self) -> bool:
+        """Some router outputs are not experts held here (a share, or zero
+        experts): the expert layer of ``parallel/moe.moe_mlp_held``."""
+        return self.is_moe and (self.router_outputs != self.num_experts)
 
     @property
     def mixed_attention(self) -> bool:
@@ -211,18 +250,75 @@ class ModelConfig:
             # Physical bytes: the rope stream is padded to one 128-lane tile
             # (models/mla.py:mla_cache_widths — Mosaic DMA alignment).
             rope_width = max(self.qk_rope_head_dim, 128)
-            return self.num_layers * (self.kv_lora_rank + rope_width) * itemsize
-        return 2 * self.num_layers * self.kv_dim * itemsize
+            return self.cache_layers * (self.kv_lora_rank + rope_width) * itemsize
+        return 2 * self.cache_layers * self.kv_dim * itemsize
 
     def param_count(self) -> int:
-        embed = self.vocab_size * self.hidden_size
-        attn = self.hidden_size * (self.q_dim + 2 * self.kv_dim) + self.q_dim * self.hidden_size
-        mlp = 3 * self.hidden_size * self.intermediate_size
-        if self.is_moe:
-            mlp = self.num_experts * 3 * self.hidden_size * self.moe_intermediate_size + self.hidden_size * self.num_experts
-        norms = 2 * self.hidden_size
+        """Parameters held here: of a share of the experts, the held ones."""
+        d = self.hidden_size
+        embed = self.vocab_size * d
+        if self.attn_type == "mla":
+            dq = self.num_heads * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+            q = d * self.q_lora_rank + self.q_lora_rank * (dq + 1) if self.q_lora_rank else d * dq
+            attn = (q + d * (self.kv_lora_rank + self.qk_rope_head_dim) + self.kv_lora_rank
+                    + self.kv_lora_rank * self.num_heads * (self.qk_nope_head_dim + self.v_head_dim)
+                    + self.num_heads * self.v_head_dim * d)
+        else:
+            attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+        dense = 3 * d * self.intermediate_size
+        moe = self.num_experts * 3 * d * self.moe_intermediate_size + d * self.router_outputs
+        norms = 2 * d
         head = 0 if self.tie_embeddings else embed
-        return embed + head + self.hidden_size + self.num_layers * (attn + mlp + norms)
+        if self.shortcut_moe:  # two attention blocks, two dense FFNs and the experts in one layer
+            layer = 2 * (attn + dense + norms) + moe
+        else:
+            layer = attn + (moe if self.is_moe else dense) + norms
+        return embed + head + d + self.num_layers * layer
+
+    @classmethod
+    def _from_longcat(cls, config: dict, name: str | None) -> "ModelConfig":
+        """LongCat-Flash's config.json (``num_layers`` double layers, ``ffn_hidden_size``,
+        ``expert_ffn_hidden_size``, ``moe_topk``, ``zero_expert_num``, ``mla_scale_*``).
+        A file that states a share (``n_routed_experts_published`` beside
+        ``n_routed_experts`` held here, of rank ``expert_share_rank``) gives a model
+        that holds that share. Refuses by name what the layer does not compute."""
+        if config.get("attention_method", "MLA") != "MLA":
+            raise ValueError(f"attention_method {config['attention_method']!r} is not served: only 'MLA'")
+        zero = int(config.get("zero_expert_num", 0) or 0)
+        if zero and config.get("zero_expert_type") != "identity":
+            raise ValueError(f"zero_expert_type {config.get('zero_expert_type')!r} is not served: only 'identity'")
+        if config.get("rope_scaling"):
+            raise ValueError("rope_scaling on a shortcut-MoE MLA model is not served")
+        hidden, heads = config["hidden_size"], config["num_attention_heads"]
+        held = int(config["n_routed_experts"])
+        total = int(config.get("n_routed_experts_published", held))
+        first = int(config.get("expert_share_rank", 0)) * held
+        if first + held > total:
+            raise ValueError(f"experts [{first}, {first + held}) lie outside the {total} published")
+        return cls(
+            name=name or config.get("_name_or_path", "longcat-flash"),
+            vocab_size=config["vocab_size"], hidden_size=hidden, num_layers=config["num_layers"],
+            num_heads=heads, num_kv_heads=heads, head_dim=config["v_head_dim"],
+            intermediate_size=config["ffn_hidden_size"],
+            rope_theta=float(config.get("rope_theta", 10000.0)), rope_scaling=None,
+            rms_eps=config.get("rms_norm_eps", 1e-5),
+            max_position=config.get("max_position_embeddings", 8192),
+            tie_embeddings=bool(config.get("tie_word_embeddings", False)),
+            num_experts=held, num_experts_per_token=int(config["moe_topk"]),
+            moe_intermediate_size=config["expert_ffn_hidden_size"],
+            moe_experts_total=total if total != held else 0, moe_expert_first=first,
+            moe_zero_experts=zero,
+            # Softmax over every router output, the top-k by score plus the
+            # balancing bias, the scores themselves (not renormalised) times the factor.
+            moe_scoring="softmax", moe_norm_topk=False, moe_router_bias=True,
+            moe_routed_scaling=float(config.get("routed_scaling_factor", 1.0) or 1.0),
+            attn_type="mla", q_lora_rank=config["q_lora_rank"], kv_lora_rank=config["kv_lora_rank"],
+            qk_nope_head_dim=config["qk_nope_head_dim"], qk_rope_head_dim=config["qk_rope_head_dim"],
+            v_head_dim=config["v_head_dim"],
+            mla_scale_q=(hidden / config["q_lora_rank"]) ** 0.5 if config.get("mla_scale_q_lora") else 1.0,
+            mla_scale_kv=(hidden / config["kv_lora_rank"]) ** 0.5 if config.get("mla_scale_kv_lora") else 1.0,
+            attention_bias=False, shortcut_moe=True,
+        )
 
     @classmethod
     def from_hf(cls, config: dict[str, Any] | str | pathlib.Path, *, name: str | None = None) -> "ModelConfig":
@@ -270,6 +366,8 @@ class ModelConfig:
                 "(Gemma-2/3 softcapping + alternating-window attention); "
                 "supported Gemma family: model_type 'gemma'"
             )
+        if "num_hidden_layers" not in config and "ffn_hidden_size" in config:
+            return cls._from_longcat(config, name)
         hidden = config["hidden_size"]
         heads = config["num_attention_heads"]
         # DeepSeek replaces the first k MoE layers with dense MLPs
@@ -530,6 +628,20 @@ PRESETS: dict[str, ModelConfig] = {
         rope_interleave=True, moe_scoring="sigmoid", moe_router_bias=True,
         moe_norm_topk=True, moe_routed_scaling=2.5, moe_n_group=2,
         moe_topk_group=1, first_k_dense=1,
+    ),
+    # Shortcut-MoE test model (LongCat-Flash's layer, tiny): two MLA sublayers
+    # and two dense FFNs a layer, 4 of 16 routed experts held here (ids 4-7),
+    # 8 identity experts in the 24-way router, scaled latents.
+    "test-tiny-scmoe": ModelConfig(
+        name="test-tiny-scmoe", vocab_size=256, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=4, head_dim=16, intermediate_size=128,
+        rope_theta=10000.0, max_position=512, dtype="float32",
+        num_experts=4, num_experts_per_token=4, moe_intermediate_size=32,
+        moe_experts_total=16, moe_expert_first=4, moe_zero_experts=8,
+        moe_scoring="softmax", moe_norm_topk=False, moe_router_bias=True, moe_routed_scaling=6.0,
+        attn_type="mla", q_lora_rank=32, kv_lora_rank=24,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        mla_scale_q=2.0 ** 0.5, mla_scale_kv=(64 / 24) ** 0.5, shortcut_moe=True,
     ),
     # MLA test model (tiny): latent cache + absorbed projections.
     "test-tiny-mla": ModelConfig(

@@ -119,11 +119,36 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
             )
         return layers
 
+    def shortcut_stack(l: int) -> dict:
+        """A shortcut-MoE layer's leaves: two sublayers (attention with its two
+        norms and a dense FFN, each under a key of its own so that every
+        matrix keeps the leaf name its kind has everywhere) beside one router
+        and the held experts."""
+        from dynamo_tpu.models.mla import init_mla_params
+
+        def sub(i: int) -> dict:
+            ks = [jax.random.fold_in(k, 2 + i) for k in keys]
+            return {
+                "attn_norm": jnp.ones((l, d), dt), "mlp_norm": jnp.ones((l, d), dt),
+                **init_mla_params(cfg, ks[0], dt, l),
+                "w_gate": w(ks[5], (l, d, f), d), "w_up": w(ks[6], (l, d, f), d), "w_down": w(ks[7], (l, f, d), f),
+            }
+
+        e, mf = cfg.num_experts, cfg.moe_intermediate_size
+        return {
+            "sub0": sub(0), "sub1": sub(1),
+            "router": w(keys[4], (l, d, cfg.router_outputs), d),
+            "router_bias": jnp.zeros((l, cfg.router_outputs), jnp.float32),
+            "w_gate": w(keys[5], (l, e, d, mf), d), "w_up": w(keys[6], (l, e, d, mf), d),
+            "w_down": w(keys[7], (l, e, mf, d), mf),
+        }
+
     k_dense = cfg.first_k_dense if cfg.is_moe else 0
     params: Params = {
         "embed": w(keys[8], (cfg.vocab_size, d), d),
         "norm_f": jnp.ones((d,), dt),
-        "layers": layer_stack(cfg.num_layers - k_dense, cfg.is_moe, 0),
+        "layers": (shortcut_stack(cfg.num_layers) if cfg.shortcut_moe
+                   else layer_stack(cfg.num_layers - k_dense, cfg.is_moe, 0)),
     }
     if k_dense:
         params["dense_layers"] = layer_stack(k_dense, False, 1)
@@ -133,7 +158,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
 
 
 def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype: jnp.dtype | None = None):
-    """Allocate the paged KV cache: two [L, num_pages, page_size, n_kv * hd] arrays.
+    """Allocate the paged KV cache: two [L, num_pages, page_size, n_kv * hd] arrays,
+    L one slab per attention (sub)layer (``cfg.cache_layers``).
 
     Page-major per layer with KV heads flattened into the trailing (lane)
     dimension — one page is a single contiguous ``ps x W`` slab covering all
@@ -152,10 +178,10 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype: jnp.d
 
         wk, wv = mla_cache_widths(cfg)
         return (
-            jnp.zeros((cfg.num_layers, num_pages, page_size, wk), dt),
-            jnp.zeros((cfg.num_layers, num_pages, page_size, wv), dt),
+            jnp.zeros((cfg.cache_layers, num_pages, page_size, wk), dt),
+            jnp.zeros((cfg.cache_layers, num_pages, page_size, wv), dt),
         )
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
+    shape = (cfg.cache_layers, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
@@ -277,8 +303,13 @@ def forward(
     logit_indices: jnp.ndarray | None = None,  # i32[B, V] token columns to score (spec verify)
     contiguous_positions: bool = True,  # False: route attention via gappy-safe paths
     split: tuple[int, int, int] | None = None,  # (decode slots, chunk slots, tokens per chunk slot)
+    moe_counts: bool = False,  # also return the held-share expert layers' counters
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One forward step. Returns (logits f32[B, vocab], k_cache, v_cache).
+
+    ``moe_counts`` (a model whose expert layer holds a share or has identity
+    experts, ``cfg.moe_held_share``): a fourth output, i32[4], the layers'
+    ``parallel/moe.HELD_COUNTS`` summed over the real tokens of the step.
 
     Works for prefill (T = padded prompt chunk) and decode (T=1) alike; the
     engine runner donates the cache buffers so updates happen in place.
@@ -310,13 +341,15 @@ def forward(
     per chunk slot; ``block_tables`` has one row per slot (``nd + nc``) and
     ``last_token_index`` names each slot's last token *on the flat axis*.
     Everything but attention is per token already; attention alone sees rows
-    (the decode slots as ``[nd, 1]``, the chunk slots as ``[nc, tc]``, both
-    through the chunked kernel). GQA / MHA text models without a mesh only.
+    (the decode slots as ``[nd, 1]``, the chunk slots as ``[nc, tc]``: a GQA /
+    MHA model's both through the chunked kernel, an MLA model's through the
+    MLA kernel, a chunk's queries in tiles, ``models/mla.py``). Text models
+    without a mesh only.
     """
     if split is not None:
-        if (mesh is not None or attn_impl == "ring" or cfg.attn_type == "mla" or cfg.mrope_section
+        if (mesh is not None or attn_impl == "ring" or cfg.mrope_section
                 or mm_embeds is not None or logit_indices is not None or not contiguous_positions):
-            raise NotImplementedError("the split token axis serves unsharded GQA text steps only")
+            raise NotImplementedError("the split token axis serves unsharded text steps only")
         nd, nc, tc = split
         assert tokens.shape == (nd + nc * tc,) and block_tables.shape[0] == nd + nc
         tokens, positions, slot_mapping = tokens[None], positions[None], slot_mapping[None]
@@ -417,6 +450,42 @@ def forward(
         moe_layers, expert_stack = split_expert_stack(params["layers"], mesh=mesh)
     n_dense = jax.tree.leaves(params["dense_layers"])[0].shape[0] if "dense_layers" in params else 0
 
+    def shortcut_layer_step(carry, lp):
+        """A shortcut-MoE layer (LongCat-Flash): attention, dense FFN, attention,
+        dense FFN on the residual stream, and a MoE that reads the first
+        sublayer's post-attention norm and joins the stream at the layer's
+        end. The sublayers' cache slabs are 2 li and 2 li + 1."""
+        from dynamo_tpu.models.mla import mla_attention
+        from dynamo_tpu.parallel.moe import moe_mlp_held
+
+        x, k_full, v_full, li, counts = carry
+        lp = join_expert_stack(lp, expert_stack, li)
+
+        def attention(i: int, x, k_full, v_full):
+            sp, slab = lp[f"sub{i}"], 2 * li + i
+            h = rms_norm(x, sp["attn_norm"], eps=cfg.rms_eps)
+            with jax.named_scope(f"attn.{i}"):
+                out, k_full, v_full = mla_attention(
+                    sp, cfg, h, positions, k_full, v_full,
+                    block_tables + slab * npages, slot_mapping + slab * (npages * ps), inv_freq_mla,
+                    attn_mscale=attn_mscale, ring=ring, mesh=mesh, ring_positions=ring_pos if ring else None,
+                    impl=attn_impl, contiguous_positions=contiguous_positions, split=split)
+            x = x + out
+            return x, rms_norm(x, sp["mlp_norm"], eps=cfg.rms_eps), k_full, v_full
+
+        a0, h0, k_full, v_full = attention(0, x, k_full, v_full)
+        with jax.named_scope("mlp.moe"):
+            m, counted = moe_mlp_held(
+                lp, h0.reshape(b * t, -1), num_experts_per_token=cfg.num_experts_per_token,
+                first=cfg.moe_expert_first, routed=cfg.routed_experts, routing=_routing_kwargs(cfg),
+                valid=(slot_mapping != 0).reshape(-1), mesh=mesh)
+        with jax.named_scope("mlp.dense0"):
+            b0 = a0 + _mlp_dense(lp["sub0"], h0, cfg.mlp_act)
+        a1, h1, k_full, v_full = attention(1, b0, k_full, v_full)
+        with jax.named_scope("mlp.dense1"):
+            y = a1 + _mlp_dense(lp["sub1"], h1, cfg.mlp_act) + m.reshape(a1.shape)
+        return (y, k_full, v_full, li + 1, counts + counted), None
+
     def make_layer_step(moe_layer: bool):
         def layer_step(carry, lp):
             x, k_full, v_full, li = carry
@@ -440,6 +509,7 @@ def forward(
                         ring_positions=ring_pos if ring else None,
                         impl=attn_impl,
                         contiguous_positions=contiguous_positions,
+                        split=split,
                     )
                 x = x + attn_out
                 h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
@@ -530,15 +600,25 @@ def forward(
             return layers
         return layers, {name: v[lo:hi] for name, v in layer_kinds.items()}
 
-    if "dense_layers" in params:
-        carry, _ = jax.lax.scan(make_layer_step(False), carry, scanned(params["dense_layers"], 0, n_dense))
-    (x, k_out, v_out, _), _ = jax.lax.scan(
-        make_layer_step(cfg.is_moe),
-        carry,
-        scanned(moe_layers, n_dense, cfg.num_layers),
-    )
+    counts = None
+    if cfg.moe_held_share and not cfg.shortcut_moe:
+        raise NotImplementedError("a held share of the experts, or identity experts, is served in shortcut-MoE layers only")
+    if cfg.shortcut_moe:
+        if layer_kinds is not None or not mla or n_dense:
+            raise NotImplementedError("a shortcut-MoE layer is served with MLA sublayers, all alike")
+        (x, k_out, v_out, _, counts), _ = jax.lax.scan(
+            shortcut_layer_step, carry + (jnp.zeros((4,), jnp.int32),), moe_layers)
+    else:
+        if "dense_layers" in params:
+            carry, _ = jax.lax.scan(make_layer_step(False), carry, scanned(params["dense_layers"], 0, n_dense))
+        (x, k_out, v_out, _), _ = jax.lax.scan(
+            make_layer_step(cfg.is_moe),
+            carry,
+            scanned(moe_layers, n_dense, cfg.num_layers),
+        )
     k_out = k_out.reshape(k_cache.shape)
     v_out = v_out.reshape(v_cache.shape)
+    extra = (counts,) if moe_counts else ()
 
     x = rms_norm(x, params["norm_f"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
     # bf16 operands, f32 accumulate: no f32 materialization of the (huge)
@@ -551,13 +631,13 @@ def forward(
         # the layer stack it amortizes.
         sel = jnp.take_along_axis(x, logit_indices[:, :, None], axis=1)  # [B, V, D]
         logits = _qmm(sel, head, preferred_element_type=jnp.float32)  # [B, V, vocab]
-        return logits, k_out, v_out
+        return (logits, k_out, v_out, *extra)
     if split is not None:
         last = x[0][last_token_index]  # [slots, D]
     else:
         last = jnp.take_along_axis(x, last_token_index[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _qmm(last, head, preferred_element_type=jnp.float32)  # [B, vocab]
-    return logits, k_out, v_out
+    return (logits, k_out, v_out, *extra)
 
 
 def encode(
@@ -584,8 +664,9 @@ def encode(
     (`lib/llm/src/http/service/openai.rs:580`, `engines.rs:321`).
     """
     b, t = tokens.shape
-    if cfg.mixed_attention:
-        raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE)")
+    if cfg.mixed_attention or cfg.shortcut_moe:
+        raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE) "
+                                  "and hold one attention block each")
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, theta=cfg.rope_theta, scaling=cfg.rope_scaling))
     attn_mscale = rope_attention_factor(cfg.rope_scaling) ** 2

@@ -579,6 +579,10 @@ def load_model(
             f"benchmark-made weights) but its checkpoint's tensor names are not mapped here; "
             f"refusing to guess them")
     cfg = ModelConfig.from_hf(p / "config.json", name=name or p.name)
+    if cfg.shortcut_moe:  # its config names no model_type to refuse by; its layer's leaves have no map either
+        raise ValueError(
+            "a shortcut-MoE model (two attention sublayers and two dense FFNs a layer) is served from "
+            "random or benchmark-made weights; its checkpoint's tensor names are not mapped here")
     if dtype is not None:
         import dataclasses
 

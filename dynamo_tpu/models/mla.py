@@ -20,17 +20,24 @@ Paged-cache mapping — no engine changes needed:
 
 Both are ordinary ``[L, pages, page_size, W]`` arrays, so the allocator,
 prefix cache, tier offload, and disagg transfer treat MLA pages exactly
-like GQA pages. Decode attention streams pages through the Pallas MLA
-kernel (``ops/pallas_mla.py`` — 6.2x the gather formulation on v5e);
-prefill and non-kernel geometries use the gather formulation. The 2D
-projections (w_kv_a, w_q*, wo_mla) are int8-quantizable like every other
-matmul weight.
+like GQA pages. Attention streams pages through the Pallas MLA kernel
+(``ops/pallas_mla.py``): a decode row as one query, a chunk's queries in
+tiles within the kernel's row cap (``_query_tile``), each page read once per
+tile. Geometries the kernel does not take use the gather
+formulation. The 2D projections (w_kv_a, w_q*, wo_mla) are int8-quantizable
+like every other matmul weight.
+
+A family may scale the query and the KV latent after their latent norms
+(``cfg.mla_scale_q`` / ``cfg.mla_scale_kv``, LongCat-Flash): the scaled
+latent is what the cache holds.
 
 Parity: the MLA serving capability the reference gets from SGLang/vLLM's
 DeepSeek support (`examples/sglang`, BASELINE config #4).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -102,8 +109,14 @@ def mla_attention(
     ring_positions: jnp.ndarray | None = None,  # [B, T] padding-hidden positions
     impl: str | None = None,  # "pallas" enables the MLA decode/verify kernel
     contiguous_positions: bool = True,  # False: gappy rows (speculative verify)
+    split: tuple[int, int, int] | None = None,  # (decode slots, chunk slots, tokens per chunk slot)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One MLA layer: returns (attn_out [B,T,D], c_cache, r_cache).
+
+    ``split = (nd, nc, tc)``: ``h`` is one token axis ``[1, nd + nc * tc, D]``
+    (``llama.forward``'s ``split``) and ``block_tables`` has a row per slot.
+    Projections and the cache write are per token; attention alone sees rows,
+    the decode slots as ``[nd, 1]`` and the chunk slots as ``[nc, tc]``.
 
     ``ring=True`` runs the sp-sharded ring path for whole-prompt prefills:
     in the absorbed formulation MLA *is* MQA with key ``[c; k_rope]``
@@ -118,6 +131,8 @@ def mla_attention(
     # -- latent + rope key, written through to the paged cache -------------
     kv_a = _qmm(h, lp["w_kv_a"])  # [B, T, r_kv + dr]
     c = rms_norm(kv_a[..., :r_kv], lp["kv_norm"], eps=cfg.rms_eps)
+    if cfg.mla_scale_kv != 1.0:
+        c = c * jnp.asarray(cfg.mla_scale_kv, c.dtype)
     k_rope = apply_rope(kv_a[..., None, r_kv:], positions, inv_freq)[:, :, 0]  # [B,T,dr]
 
     num_pages, ps, r_width = r_cache.shape[0], r_cache.shape[1], r_cache.shape[2]
@@ -141,15 +156,17 @@ def mla_attention(
         q = _qmm(q_a, lp["w_q_b"]).reshape(b, t, n_heads, dn + dr)
     else:
         q = _qmm(h, lp["w_q"]).reshape(b, t, n_heads, dn + dr)
+    if cfg.mla_scale_q != 1.0:
+        q = q * jnp.asarray(cfg.mla_scale_q, q.dtype)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, inv_freq)
     # absorb W_uk: scores live in latent space
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, lp["w_uk"])  # [B,T,H,r_kv]
 
+    scale = (dn + dr) ** -0.5 * attn_mscale
     if ring:
         from dynamo_tpu.parallel.ring import ring_attention
 
-        scale = (dn + dr) ** -0.5 * attn_mscale
         q_full = jnp.concatenate([q_lat.astype(h.dtype), q_rope], axis=-1)
         k_full = jnp.concatenate([c, k_rope], axis=-1)[:, :, None, :]  # MQA
         v_lat = c[:, :, None, :]
@@ -161,17 +178,54 @@ def mla_attention(
         out = jnp.einsum("bthr,rhv->bthv", out_lat.astype(h.dtype), lp["w_uv"])
         return _qmm(out.reshape(b, t, n_heads * dv), lp["wo_mla"]), c_cache, r_cache
 
-    # -- decode: stream pages through the Pallas MLA kernel ----------------
-    # The gather formulation below reads the latent cache ~4x per step
-    # (gather write + score read + output read): measured 0.21x roofline at
-    # V3 MLA geometry. The kernel reads each page once (6.2x measured,
-    # BENCH r04). Under a mesh it runs per-device on the query-head shard
-    # against the replicated latent cache (shard_map — no collectives
-    # inside attention; see parallel/sharding.cache_shardings).
     if impl is None:
         from dynamo_tpu.ops.attention import default_impl
 
         impl = default_impl()
+    attend = functools.partial(_attend_paged, c_cache=c_cache, r_cache=r_cache, scale=scale, impl=impl, mesh=mesh,
+                               contiguous_positions=contiguous_positions)
+    if split is None:
+        out_lat = attend(q_lat, q_rope, block_tables=block_tables, positions=positions)
+    else:
+        nd, nc, tc = split
+
+        def rows(tok: slice, slot: slice, width: int):  # slots as rows, and back onto the token axis
+            n = slot.stop - slot.start
+            got = attend(q_lat[0, tok].reshape(n, width, n_heads, r_kv), q_rope[0, tok].reshape(n, width, n_heads, dr),
+                         block_tables=block_tables[slot], positions=positions[0, tok].reshape(n, width))
+            return got.reshape(1, n * width, n_heads, r_kv)
+
+        out_lat = jnp.concatenate(
+            [rows(slice(0, nd), slice(0, nd), 1), rows(slice(nd, t), slice(nd, nd + nc), tc)], axis=1)
+    out = jnp.einsum("bthr,rhv->bthv", out_lat.astype(h.dtype), lp["w_uv"])  # [B,T,H,dv]
+    return _qmm(out.reshape(b, t, n_heads * dv), lp["wo_mla"]), c_cache, r_cache
+
+
+def _query_tile(t: int, cap: int) -> int:
+    """Queries a kernel row takes of a ``t``-token row: one where the kernel's
+    row cap holds it, else the largest divisor of ``t`` within *half* the cap
+    (64 tokens under a cap of 17: 8). The cap counts the staged queries and the
+    accumulator; the score and probability blocks grow with the rows too, and
+    16 queries of 64 heads overran a v5e's scoped VMEM by 5% where 8 compile
+    (tests/test_chip_compile.py)."""
+    if t <= cap:
+        return t
+    return max(q for q in range(1, max(1, cap // 2) + 1) if t % q == 0)
+
+
+def _attend_paged(q_lat, q_rope, *, c_cache, r_cache, block_tables, positions, scale: float,
+                  impl: str, mesh, contiguous_positions: bool) -> jnp.ndarray:
+    """Absorbed attention of rows ``[B, T]`` against the paged latent cache:
+    latent-space output ``[B, T, H, r_kv]`` (float32 from the kernel).
+
+    The Pallas kernel reads each page once per kernel row, where the gather
+    formulation below materializes the gathered latents and reads them for
+    the scores and again for the output. Under a mesh it runs per device on
+    the query-head shard against the replicated latent cache (shard_map, no
+    collectives inside attention; parallel/sharding.cache_shardings)."""
+    b, t, n_heads, r_kv = q_lat.shape
+    dr = q_rope.shape[-1]
+    ps, r_width = r_cache.shape[1], r_cache.shape[2]
     if impl == "pallas":
         from dynamo_tpu.ops.pallas_mla import (
             interpret_mode,
@@ -179,51 +233,37 @@ def mla_attention(
             mla_paged_decode,
             mla_paged_decode_sharded,
         )
+        from dynamo_tpu.ops.pallas_paged import _max_verify_t
 
         # The multi-query kernel's per-row causal mask is exact for ANY
         # position layout (T = 1 decode, gappy speculative-verify rows,
-        # contiguous prefill windows) — the only gates are geometry and the
-        # VMEM row cap on T.
-        if mla_decode_supported(
-            r_kv, r_width, t, n_heads, interpret=interpret_mode()
-        ):
-            scale = (dn + dr) ** -0.5 * attn_mscale
-            q_rope_k = q_rope  # [B, T, H, dr]
+        # contiguous prefill windows): the only gates are geometry and the
+        # VMEM row cap on the queries of one kernel row. A longer row goes in
+        # tiles, each a kernel row of its own over the same block table.
+        if mla_decode_supported(r_kv, r_width, 1, n_heads, interpret=interpret_mode()):
+            tq = _query_tile(t, _max_verify_t(n_heads, r_kv + r_width))
+            tiles = t // tq
+            q_rope_k = q_rope
             if r_width != dr:  # match the lane-padded rope stream
-                q_rope_k = jnp.pad(
-                    q_rope_k, ((0, 0), (0, 0), (0, 0), (0, r_width - dr))
-                )
-            if mesh is None:
-                out_lat = mla_paged_decode(
-                    q_lat, q_rope_k, c_cache, r_cache,
-                    block_tables, positions,
-                    scale=scale, interpret=interpret_mode(),
-                )  # [B, T, H, r_kv]
-            else:
-                out_lat = mla_paged_decode_sharded(
-                    q_lat, q_rope_k, c_cache, r_cache,
-                    block_tables, positions,
-                    mesh=mesh, scale=scale, interpret=interpret_mode(),
-                )
-            out = jnp.einsum("bthr,rhv->bthv", out_lat.astype(h.dtype), lp["w_uv"])
-            return _qmm(out.reshape(b, t, n_heads * dv), lp["wo_mla"]), c_cache, r_cache
+                q_rope_k = jnp.pad(q_rope_k, ((0, 0), (0, 0), (0, 0), (0, r_width - dr)))
+            kernel = mla_paged_decode if mesh is None else functools.partial(mla_paged_decode_sharded, mesh=mesh)
+            out_lat = kernel(
+                q_lat.reshape(b * tiles, tq, n_heads, r_kv), q_rope_k.reshape(b * tiles, tq, n_heads, r_width),
+                c_cache, r_cache, jnp.repeat(block_tables, tiles, axis=0) if tiles > 1 else block_tables,
+                positions.reshape(b * tiles, tq), scale=scale, interpret=interpret_mode(),
+            )
+            return out_lat.reshape(b, t, n_heads, r_kv)
         if t == 1 or not contiguous_positions:
-            # Decode/verify falling off the kernel is the ~5x downgrade
-            # worth alerting on; a T-over-cap contiguous prefill is not
-            # (no MLA prefill kernel exists to fall back FROM).
+            # Decode/verify falling off the kernel is the downgrade worth
+            # alerting on; a prefill off it is the same geometry's.
             from dynamo_tpu.ops.pallas_paged import _record_fallback
 
-            _record_fallback(
-                "mla_decode" if t == 1 else "mla_verify", q, c_cache
-            )
+            _record_fallback("mla_decode" if t == 1 else "mla_verify", q_lat, c_cache)
 
     # -- gather this batch's pages and attend ------------------------------
-    pages_per_seq = block_tables.shape[1]
-    s = pages_per_seq * ps
+    s = block_tables.shape[1] * ps
     c_pages = c_cache[block_tables.reshape(-1)].reshape(b, s, r_kv)
     r_pages = r_cache[block_tables.reshape(-1)].reshape(b, s, r_width)[..., :dr]
-
-    scale = (dn + dr) ** -0.5 * attn_mscale
     logits = (
         jnp.einsum("bthr,bsr->bhts", q_lat, c_pages, preferred_element_type=jnp.float32)
         + jnp.einsum("bthr,bsr->bhts", q_rope, r_pages, preferred_element_type=jnp.float32)
@@ -232,12 +272,9 @@ def mla_attention(
     mask = key_pos[None, None, :] <= positions[:, :, None]  # [B, T, S]
     logits = jnp.where(mask[:, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-
-    out_lat = jnp.einsum(
+    return jnp.einsum(
         "bhts,bsr->bthr", probs.astype(c_pages.dtype), c_pages, preferred_element_type=jnp.float32
     )  # [B, T, H, r_kv]
-    out = jnp.einsum("bthr,rhv->bthv", out_lat.astype(h.dtype), lp["w_uv"])  # [B,T,H,dv]
-    return _qmm(out.reshape(b, t, n_heads * dv), lp["wo_mla"]), c_cache, r_cache
 
 
 def mla_attention_naive(
@@ -256,7 +293,7 @@ def mla_attention_naive(
     r_kv, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
 
     kv_a = _qmm(h, lp["w_kv_a"])
-    c = rms_norm(kv_a[..., :r_kv], lp["kv_norm"], eps=cfg.rms_eps)
+    c = rms_norm(kv_a[..., :r_kv], lp["kv_norm"], eps=cfg.rms_eps) * jnp.asarray(cfg.mla_scale_kv, h.dtype)
     k_rope = apply_rope(kv_a[..., None, r_kv:], positions, inv_freq)  # [B,T,1,dr]
     k_nope = jnp.einsum("btr,rhn->bthn", c, lp["w_uk"])  # [B,T,H,dn]
     v = jnp.einsum("btr,rhv->bthv", c, lp["w_uv"])  # [B,T,H,dv]
@@ -266,6 +303,7 @@ def mla_attention_naive(
         q = _qmm(q_a, lp["w_q_b"]).reshape(b, t, n_heads, dn + dr)
     else:
         q = _qmm(h, lp["w_q"]).reshape(b, t, n_heads, dn + dr)
+    q = q * jnp.asarray(cfg.mla_scale_q, q.dtype)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, inv_freq)
 

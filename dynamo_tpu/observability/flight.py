@@ -55,7 +55,8 @@ STEP_KEYS = (
     "admission_rejections", "mixed_steps", "stall_violations",
     "spec_drafted", "spec_accepted", "spec_accept_rate", "wall_ms",
     "dispatch_ms", "attn_phase", "attn_path", "moe_path",
-    "kv_tokens_full", "kv_tokens_window", "step_tokens", "layout",
+    "kv_tokens_full", "kv_tokens_window", "step_tokens",
+    "moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched", "layout",
     "admitted", "deferred", "deadline_slack_ms", "cached_frac", "gap_ms",
     "overlap_mode", "barrier_reason", "chained_rows",
     "t0_ns", "ann_ns", "traced", "phases_us",

@@ -5,8 +5,7 @@ query head attends to ONE shared K/V stream — key ``[c ; k_rope]``
 (latent width r_kv + rope width dr) and value ``c`` — so the paged cache
 holds just ``r_kv + dr`` lanes per token (`models/mla.py`). The XLA gather
 formulation materializes the gathered latents and reads them three times
-per step (gather write, score einsum, output einsum): measured 0.21x of
-the HBM roofline on v5e at DeepSeek-V3 MLA geometry (BENCH r04). This
+per step (gather write, score einsum, output einsum). This
 kernel streams each page from HBM exactly once — an N-deep DMA ring,
 online softmax, accumulation in latent space — the same split-K,
 multi-query structure as the GQA decode kernel (`pallas_paged.py`, whose
@@ -303,6 +302,7 @@ def mla_paged_decode(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="mla_paged_decode_attention",
     )(
         lengths,
         block_tables.reshape(-1),

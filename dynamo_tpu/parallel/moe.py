@@ -102,9 +102,14 @@ def route_tokens(
     n_group: int = 0,
     topk_group: int = 0,
     group_score: str = "max",
+    f32_logits: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Router semantics shared by every MoE family; returns (weights f32[N,k],
     expert ids i32[N,k]).
+
+    ``f32_logits``: the router matmul accumulates and hands over float32 (a
+    family whose published router is float32 end to end); otherwise the
+    product is rounded to the activations' dtype before the softmax.
 
     - ``softmax`` scoring + ``norm_topk``: Mixtral (softmax over all logits,
       gather top-k, renormalize — algebraically softmax(top-k logits)).
@@ -120,7 +125,10 @@ def route_tokens(
       (`modeling_deepseek_v2.py:76`); V3's noaux_tc uses the ``"top2sum"``
       of biased scores (`modeling_deepseek_v3.py:127`).
     """
-    logits = (x @ lp["router"]).astype(jnp.float32)  # [N, E]
+    if f32_logits:
+        logits = jnp.dot(x, lp["router"], preferred_element_type=jnp.float32)
+    else:
+        logits = (x @ lp["router"]).astype(jnp.float32)  # [N, E]
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     elif scoring == "softmax":
@@ -273,6 +281,99 @@ def moe_mlp_dropless(
         rows = jnp.zeros_like(down).at[order].set(down)  # unsort
         out = (rows.astype(jnp.float32) * weights.reshape(-1)[:, None]).reshape(n, k, d).sum(axis=1)
         return out.astype(x.dtype)
+
+
+#: Counters a held-share expert layer returns beside its output, in this order.
+HELD_COUNTS = ("moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched")
+
+
+def held_rows_cap(copies: int, held: int, outputs: int) -> int:
+    """Rows one pass of the held experts takes: twice the copies even routing
+    sends here, in whole 128-row tiles, and never more than there are."""
+    even = -(-2 * copies * held // max(1, outputs))
+    return min(-(-copies // 16) * 16, -(-max(even, 1) // 128) * 128)
+
+
+def moe_mlp_held(
+    lp: dict,
+    x: jnp.ndarray,  # [N, D] flattened tokens
+    *,
+    num_experts_per_token: int,
+    first: int,  # id of the first expert held here
+    routed: int,  # routed experts the router scores; outputs past them are identities
+    routing: dict | None = None,
+    valid: jnp.ndarray | None = None,  # bool[N]: padding tokens route nowhere
+    mesh=None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of a routed MoE layer that one holder of experts computes:
+    ``lp``'s expert arrays hold experts ``[first, first + held)`` of the
+    ``routed`` the router scores; its outputs ``>= routed`` are identity
+    ("zero-compute") experts. Every token is routed over all outputs; the
+    result is the held experts' terms plus the identity term (the token times
+    the summed weights of its identity choices), which is computed where the
+    token lives. A choice that lands on an expert held elsewhere adds nothing
+    here, costs no work and reads no weight: the holders' parts, with the
+    identity term counted once, sum to the whole layer.
+
+    The copies that landed here are sorted to the front by expert and taken
+    ``held_rows_cap`` rows at a pass (one pass unless routing is far from
+    even; none where nothing landed), through the int8 kernel where
+    :func:`experts_path` says so. Returns ``(out [N, D], counts i32[4])``,
+    the counts as :data:`HELD_COUNTS` names them, over ``valid`` tokens.
+    """
+    n, d = x.shape
+    k = num_experts_per_token
+    held = jax.tree.leaves(lp["w_gate"])[0].shape[-3]
+    fused = experts_path(lp, mesh=mesh) == "fused"
+    if valid is None:
+        valid = jnp.ones((n,), bool)
+
+    with jax.named_scope("moe.router"):
+        weights, topi = route_tokens(lp, x, k=k, f32_logits=True, **(routing or {}))
+        local = topi - first
+        here = (local >= 0) & (local < held) & valid[:, None]  # [N, k]
+        key = jnp.where(here, local, held).reshape(-1)  # copies held elsewhere sort last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        n_here = ends[-1]
+        is_zero = topi >= routed
+        counts = jnp.stack([valid.sum() * k, (is_zero & valid[:, None]).sum(), n_here, (sizes > 0).sum()]).astype(jnp.int32)
+
+    with jax.named_scope("moe.zero"):
+        zero_w = jnp.where(is_zero, weights, 0.0).sum(axis=-1)  # f32[N]
+        out = x.astype(jnp.float32) * zero_w[:, None]
+
+    cap = held_rows_cap(n * k, held, lp["router"].shape[-1])
+    order = jnp.pad(order, (0, -(n * k) % cap))
+    flat_w = weights.reshape(-1)
+    if not fused:
+        w_gate, w_up, w_down = _widen(lp)
+
+    def one_pass(i, acc):
+        lo = i * cap
+        idx = jax.lax.dynamic_slice(order, (lo,), (cap,))
+        tok = idx // k
+        rows = x[tok]  # [cap, D] grouped by expert
+        in_pass = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+        if fused:
+            from dynamo_tpu.ops.pallas_moe import expert_ffn_int8
+            from dynamo_tpu.ops.pallas_paged import interpret_mode
+
+            down = expert_ffn_int8(rows, lp["w_gate"], lp["w_up"], lp["w_down"], in_pass,
+                                   lp.get("expert_layer"), interpret=interpret_mode())
+        else:
+            with jax.named_scope("moe.experts_gate_up"):
+                hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, in_pass)) * jax.lax.ragged_dot(rows, w_up, in_pass)
+            with jax.named_scope("moe.experts_down"):
+                down = jax.lax.ragged_dot(hidden, w_down, in_pass)
+        with jax.named_scope("moe.combine"):
+            live = (lo + jnp.arange(cap)) < n_here  # rows past the held copies were never computed
+            term = jnp.where(live[:, None], down.astype(jnp.float32) * flat_w[idx][:, None], 0.0)
+            return acc.at[tok].add(term)
+
+    out = jax.lax.fori_loop(0, -(-n_here // cap), one_pass, out)
+    return out.astype(x.dtype), counts
 
 
 def expert_capacity(num_tokens: int, num_experts: int, k: int, capacity_factor: float) -> int:

@@ -130,13 +130,16 @@ class IncrementalDetokenizer:
         self._ids: list[int] = []
         self._prefix_offset = 0
         self._read_offset = 0
+        self._prefix_text: str | None = None  # decode of [prefix_offset, read_offset), kept until they move
         self._skip_special = skip_special_tokens
 
     def push(self, token_ids: list[int]) -> str:
         """Add tokens; return newly-stable text (possibly empty)."""
         self._ids.extend(token_ids)
-        prefix = self._tok.decode(self._ids[self._prefix_offset : self._read_offset],
-                                  skip_special_tokens=self._skip_special)
+        prefix = self._prefix_text
+        if prefix is None:  # the window moved since it was last decoded
+            prefix = self._prefix_text = self._tok.decode(
+                self._ids[self._prefix_offset : self._read_offset], skip_special_tokens=self._skip_special)
         full = self._tok.decode(self._ids[self._prefix_offset :],
                                 skip_special_tokens=self._skip_special)
         if len(full) <= len(prefix) or full.endswith("�"):
@@ -144,6 +147,7 @@ class IncrementalDetokenizer:
         delta = full[len(prefix) :]
         self._prefix_offset = self._read_offset
         self._read_offset = len(self._ids)
+        self._prefix_text = None
         return delta
 
     @property

@@ -385,3 +385,47 @@ def test_split_mixed_step_mellum2_largest_corner(sds, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert text.count("paged_prefill_attention") >= 2 and "paged_decode_attention" not in text
     _no_rectangle(text, 8, 64)
+
+
+@pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
+def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
+    """reason-saturated's largest steps at LongCat-Flash's widths (two of its
+    double layers, this chip's 16 of 512 experts): 64 decode rows, and 64
+    decode slots + one 64-token chunk slot, over 16 pages. One layer body: the
+    two MLA sublayers' attention through the MLA kernel (a chunk's 64 queries
+    in tiles of 8: 16 overran the scoped VMEM), the held experts through the
+    grouped int8 kernel at its 6144 x 2048 tiles inside the pass loop, and no
+    expert array copied outside it."""
+    import functools
+    import json
+    import pathlib
+    import re
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    doc = json.loads((pathlib.Path(__file__).parents[1] / "benchmark/configs/longcat-flash-chat-ep32-int8.json").read_text())
+    hf = {k: v for k, v in doc.items() if k not in ("serve", "rehearsal", "assumed", "reduced_why", "deployment")}
+    cfg = ModelConfig.from_hf({**hf, "num_layers": 2}, name="longcat-two-layers")
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
+    assert k_cache.shape == (4, 1025, 128, 512) and v_cache.shape == (4, 1025, 128, 128)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if split is None:
+        toks, slots = (64, 1), 64
+    else:
+        toks, slots = (split[0] + split[1] * split[2],), split[0] + split[1]
+    text = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas", split=split, moe_counts=True)).lower(
+        params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(slots, 16), slot_mapping=i32(*toks), last_token_index=i32(slots),
+    ).compile().as_text()
+    # One layer body: an MLA call per sublayer (two per sublayer on the split axis) and the expert FFN's two.
+    assert text.count('custom_call_target="tpu_custom_call"') == (4 if split is None else 6)
+    assert "mla_paged_decode_attention" in text and text.count("moe_grouped_matmul_int8") >= 2
+    # The held experts stay where they are: no [16, 6144, 2048] array is produced outside the kernel.
+    made = re.findall(r"= s8\[(?:1,)?16,(?:6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element|bitcast)(\w[\w-]*)\(", text)
+    assert not made, made

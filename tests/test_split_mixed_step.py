@@ -30,6 +30,10 @@ MODELS = {
     "olmoe-like": dataclasses.replace(PRESETS["test-tiny-moe"], name="olmoe-like", qk_norm="flat",
                                       moe_norm_topk=False, tie_embeddings=False),
     "window-and-full": _toy(),  # sliding x 3 + full, window 8, a RoPE each (tests/test_mixed_attention.py)
+    # Latent attention (ISSUE 34): the decode slots and the chunk slots through models/mla._attend_paged.
+    "mla": PRESETS["test-tiny-mla"],
+    # Two MLA sublayers and two dense FFNs a layer, a share of the experts and identity experts (LongCat-Flash's layer).
+    "shortcut-moe-mla": PRESETS["test-tiny-scmoe"],
 }
 #: Rows as (first position, new tokens): contexts on both sides of the toy's window of 8.
 BATCHES = {
@@ -319,8 +323,9 @@ OUTSIDE = {
         r.step_async(step_batch([(5, 1), (21, 1), (12, 1)])).result(),
         r.step_async(step_batch(BATCHES["two-decodes-one-chunk"]), chain=True).result()), 4 * 8),
     "mesh": (_mesh_runner, lambda r: r.step(step_batch(BATCHES["two-decodes-one-chunk"])), 4 * 8),
-    "mla": (lambda: runner_for(PRESETS["test-tiny-mla"]),
-            lambda r: r.step(step_batch(BATCHES["two-decodes-one-chunk"])), 4 * 8),
+    # A runner handed a forward of its own: only llama.forward is known to take the split token axis.
+    "own-forward": (lambda: runner_for(MODELS["dense-gqa"], forward_fn=llama.forward),
+                    lambda r: r.step(step_batch(BATCHES["two-decodes-one-chunk"])), 4 * 8),
     "more-chunk-rows-than-slots": (lambda: runner_for(MODELS["dense-gqa"]),
                                    lambda r: r.step(step_batch([(3, 1)] + [(2, 4)] * (MAX_CHUNK_SLOTS + 1))),
                                    next_pow2(MAX_CHUNK_SLOTS + 2) * 4),
@@ -391,10 +396,11 @@ def test_step_records_say_layout_and_tokens_and_the_engine_counts_them(overlap):
     assert {s["overlap_mode"] for s in steps} == ({"overlapped", "barrier"} if overlap else {""})
 
 
-def test_an_mla_engine_counts_its_chunk_steps_as_padded():
+def test_an_mla_engine_splits_its_chunk_steps_too():
+    """Until ISSUE 34 an MLA model's chunk step was the padded rectangle; now a
+    decode row beside a chunk is one position there as well."""
     core = _core(PRESETS["test-tiny-mla"])
     _serve_two(core)
-    chunky = [s for s in core.flight.snapshot(kind="step") if s["chunk_rows"]]
-    assert chunky and all(s["layout"] == ROWS_X_T for s in chunky)
-    assert any(s["step_tokens"] == 2 * 4 for s in chunky)  # two rows padded to the chunk
-    assert core.chunk_steps_rows_x_t == len(chunky) and core.chunk_steps_split == 0
+    mixed = [s for s in core.flight.snapshot(kind="step") if s["chunk_tokens"] > 1 and s["decode_rows"]]
+    assert mixed and all((s["layout"], s["step_tokens"]) == (SPLIT, 2 + 4) for s in mixed)
+    assert core.chunk_steps_split == len(mixed)
