@@ -29,7 +29,7 @@ import numpy as np
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import paged_attention, write_kv
 from dynamo_tpu.ops.norm import rms_norm
-from dynamo_tpu.models.quant import maybe_dequant as _dq, quant_matmul as _qmm
+from dynamo_tpu.models.quant import held_flat, maybe_dequant as _dq, quant_matmul as _qmm
 from dynamo_tpu.ops.rope import apply_mrope, apply_rope, rope_attention_factor, rope_frequencies
 
 Params = dict
@@ -523,6 +523,12 @@ def forward(
                 if cfg.qk_norm == "flat":  # OLMoE: norm the flat projection
                     qp = rms_norm(qp, lp["q_norm"], eps=cfg.rms_eps)
                     kp = rms_norm(kp, lp["k_norm"], eps=cfg.rms_eps)
+                else:
+                    # Nothing stands between the projection and its heads, so
+                    # the heads' layout would reach the dot and re-lay wq and
+                    # wk. (A flat norm reads whole rows and is laid out flat
+                    # itself; v's heads are flattened again by the cache write.)
+                    qp, kp = held_flat(qp), held_flat(kp)
                 q = qp.reshape(b, t, cfg.num_heads, cfg.head_dim)
                 k = kp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
                 v = vp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)

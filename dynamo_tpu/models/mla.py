@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.models.quant import quant_matmul as _qmm
+from dynamo_tpu.models.quant import held_flat, quant_matmul as _qmm
 from dynamo_tpu.ops.norm import rms_norm
 from dynamo_tpu.ops.rope import apply_rope
 
@@ -153,9 +153,9 @@ def mla_attention(
     # -- queries, absorbed into latent space -------------------------------
     if "w_q_a" in lp:
         q_a = rms_norm(_qmm(h, lp["w_q_a"]), lp["q_norm"], eps=cfg.rms_eps)
-        q = _qmm(q_a, lp["w_q_b"]).reshape(b, t, n_heads, dn + dr)
+        q = held_flat(_qmm(q_a, lp["w_q_b"])).reshape(b, t, n_heads, dn + dr)
     else:
-        q = _qmm(h, lp["w_q"]).reshape(b, t, n_heads, dn + dr)
+        q = held_flat(_qmm(h, lp["w_q"])).reshape(b, t, n_heads, dn + dr)
     if cfg.mla_scale_q != 1.0:
         q = q * jnp.asarray(cfg.mla_scale_q, q.dtype)
     q_nope, q_rope = q[..., :dn], q[..., dn:]

@@ -204,6 +204,29 @@ def quant_matmul(x: jnp.ndarray, leaf: Any, *, preferred_element_type: Any | Non
     return jnp.matmul(x, leaf, preferred_element_type=preferred_element_type)
 
 
+def held_flat(y: jnp.ndarray) -> jnp.ndarray:
+    """A projection's flat output ``[..., heads * dim]`` on its way to being
+    split into heads: the same values, as an array the compiler has to lay
+    out as it stands, so that the projection reads its weight where it lies.
+
+    Without it the layout that RoPE and the attention kernel want for the
+    small heads-major activation is pushed back through the reshape and the
+    scale into the dot, and the compiler pays by re-laying the *weight*: in
+    every layer of every step a slice of the layer's int8 matrix out of the
+    stack and a transposed copy of it (Mellum2's ``wq``, 9.4 MB: 16 + 22 us a
+    layer, 28 layers; LongCat-Flash's ``w_q_b``, 18.9 MB, twice a layer; the
+    chip's times in PERF.md, PR 35). Held flat, the dot reads the stack by the
+    layer's index, as ``wo`` and the FFN matrices always did, and what is
+    transposed is the activation (64 KB at 8 rows). The values are untouched:
+    the same int8 codes, convert, scale and accumulation.
+
+    ``tools/step_relayouts.py <config>`` lists what a configuration's step
+    programs still re-lay; ``tests/test_chip_compile.py`` holds the benchmark's
+    three configurations to no copy of an int8 weight.
+    """
+    return jax.lax.optimization_barrier(y)
+
+
 def maybe_dequant(leaf: Any, dtype: Any = jnp.bfloat16) -> jnp.ndarray:
     """The read-side accessor every matmul site goes through.
 

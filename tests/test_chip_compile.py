@@ -429,3 +429,72 @@ def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
     # The held experts stay where they are: no [16, 6144, 2048] array is produced outside the kernel.
     made = re.findall(r"= s8\[(?:1,)?16,(?:6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element|bitcast)(\w[\w-]*)\(", text)
     assert not made, made
+
+
+# -- weights read where they lie (ISSUE 35, models/quant.held_flat) -----------
+
+def _benchmark_config(name: str, layers: int, vocab: int = 8192):
+    """A benchmark configuration at its published widths, cut to ``layers``
+    and a small vocabulary (the layer scan's body depends on neither)."""
+    import pathlib
+
+    from benchmark import serving
+
+    conf = serving.load_config(pathlib.Path(__file__).parents[1] / f"benchmark/configs/{name}.json")
+    hf = conf["hf"]
+    hf.update({"num_layers" if "num_layers" in hf else "num_hidden_layers": layers, "vocab_size": vocab})
+    for per_layer in ("layer_types", "mlp_layer_types"):
+        if per_layer in hf:
+            hf[per_layer] = hf[per_layer][:layers]
+    return serving.model_config(conf)
+
+
+@pytest.mark.parametrize("config, rows, mixed, held", [
+    ("mellum2-12b-a2.5b-int8", 8, False, True),
+    ("mellum2-12b-a2.5b-int8", 8, True, True),
+    ("mellum2-12b-a2.5b-int8", 8, False, False),  # the barrier taken out: the assertion has something to miss
+    ("longcat-flash-chat-ep32-int8", 64, False, True),
+    ("olmoe-1b-7b-int8", 64, False, True),  # the control: a flat q/k norm stands between, no barrier, no copy
+    ("olmoe-1b-7b-int8", 64, True, True),
+], ids=lambda v: str(v))
+def test_step_programs_relay_no_int8_weight(sds, monkeypatch, config, rows, mixed, held):
+    """Two layers of each benchmark configuration at its published widths, the
+    decode step and the chunk step as served (``rows`` decode slots + one
+    64-token chunk slot), lowered for the described chip: the layer scan's body
+    holds no copy, transposition or stand-alone slice of an ``s8`` array of
+    1 MiB or more. The q (and k) projection reads its int8 weight from the
+    stack by the layer's index, in the layout it is stored in. Without
+    ``held_flat`` Mellum2's body slices ``wq`` and ``wk`` out of the stack and
+    copies each transposed: 21 MB a layer."""
+    import functools
+
+    from dynamo_tpu.models import llama, mla
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+    from tests.test_step_relayouts import load_tool
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    if not held:
+        monkeypatch.setattr(llama, "held_flat", lambda y: y)
+        monkeypatch.setattr(mla, "held_flat", lambda y: y)
+    cfg = _benchmark_config(config, layers=2)
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    pages_per_seq = 16
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, (rows + 1) * pages_per_seq + 1, 128)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    split = (rows, 1, 64) if mixed else None
+    toks, slots = ((rows + 64,), rows + 1) if mixed else ((rows, 1), rows)
+    counted = {"moe_counts": True} if cfg.moe_held_share else {}
+    text = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas", split=split, **counted)).lower(
+        params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(slots, pages_per_seq), slot_mapping=i32(*toks), last_token_index=i32(slots),
+    ).compile().as_text()
+    assert text.count("moe_grouped_matmul_int8") >= 2  # the chip's program, not the CPU's widened one
+    relaid = [(op["name"], op["shape"], op["reads"], op["writes"]) for op in load_tool().relayouts(text) if op["dtype"] == "s8"]
+    if held:
+        assert not relaid, relaid
+    else:
+        shapes = {shape for _, shape, _, _ in relaid}
+        assert {"s8[1,2304,4096]", "s8[1,2304,512]"} <= shapes, relaid
+        assert any(reads and "{2,1,0}" in reads[0] and writes == "{1,2,0}" for _, _, reads, writes in relaid), relaid
