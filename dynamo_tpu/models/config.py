@@ -85,6 +85,18 @@ def _attention_layout(config: dict) -> dict:
     return dict(out, rope_theta=theta, rope_scaling=scaling)
 
 
+def _expert_share(config: dict) -> tuple[int, int, int]:
+    """(held, total, first id) of the routed experts a config states: all of
+    them, or the share a file gives as ``n_routed_experts`` held here of
+    ``n_routed_experts_published``, the ``expert_share_rank``-th such share."""
+    held = int(config["n_routed_experts"])
+    total = int(config.get("n_routed_experts_published", held))
+    first = int(config.get("expert_share_rank", 0)) * held
+    if held <= 0 or first + held > total:
+        raise ValueError(f"experts [{first}, {first + held}) lie outside the {total} published")
+    return held, total, first
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -266,14 +278,15 @@ class ModelConfig:
         else:
             attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
         dense = 3 * d * self.intermediate_size
-        moe = self.num_experts * 3 * d * self.moe_intermediate_size + d * self.router_outputs
+        moe = (self.num_experts * 3 * d * self.moe_intermediate_size + d * self.router_outputs
+               + 3 * d * self.shared_expert_size + (d if self.shared_expert_gated else 0))
         norms = 2 * d
         head = 0 if self.tie_embeddings else embed
         if self.shortcut_moe:  # two attention blocks, two dense FFNs and the experts in one layer
-            layer = 2 * (attn + dense + norms) + moe
-        else:
-            layer = attn + (moe if self.is_moe else dense) + norms
-        return embed + head + d + self.num_layers * layer
+            return embed + head + d + self.num_layers * (2 * (attn + dense + norms) + moe)
+        k_dense = self.first_k_dense if self.is_moe else self.num_layers
+        return (embed + head + d + self.num_layers * (attn + norms)
+                + k_dense * dense + (self.num_layers - k_dense) * moe)
 
     @classmethod
     def _from_longcat(cls, config: dict, name: str | None) -> "ModelConfig":
@@ -290,11 +303,7 @@ class ModelConfig:
         if config.get("rope_scaling"):
             raise ValueError("rope_scaling on a shortcut-MoE MLA model is not served")
         hidden, heads = config["hidden_size"], config["num_attention_heads"]
-        held = int(config["n_routed_experts"])
-        total = int(config.get("n_routed_experts_published", held))
-        first = int(config.get("expert_share_rank", 0)) * held
-        if first + held > total:
-            raise ValueError(f"experts [{first}, {first + held}) lie outside the {total} published")
+        held, total, first = _expert_share(config)
         return cls(
             name=name or config.get("_name_or_path", "longcat-flash"),
             vocab_size=config["vocab_size"], hidden_size=hidden, num_layers=config["num_layers"],
@@ -377,6 +386,27 @@ class ModelConfig:
         # (models/llama.py dual scan, models/loader._leaf_specs).
         first_dense = int(config.get("first_k_dense_replace", 0) or 0)
         all_dense = first_dense >= config["num_hidden_layers"]
+        n_experts = 0 if all_dense else (
+            config.get("num_experts", config.get("num_local_experts", config.get("n_routed_experts", 0))) or 0)
+        if n_experts and config.get("moe_layer_freq", 1) != 1:
+            raise ValueError(f"moe_layer_freq {config['moe_layer_freq']!r} is not served: only 1 "
+                             "(every layer after the leading dense ones is routed)")
+        # A file may state a share of the routed experts (one chip's part of an
+        # expert-parallel deployment; ``ep_size``, where a config carries it, is
+        # the publisher's own setting and decides nothing here).
+        experts_total = expert_first = 0
+        if not all_dense and "n_routed_experts_published" in config:
+            n_experts, total, expert_first = _expert_share(config)
+            experts_total = total if total != n_experts else 0
+        # One group is no group limit: no group top-k in the program.
+        n_group = int(config.get("n_group", 0) or 0) if n_experts else 0
+        topk_group = int(config.get("topk_group", 0) or 0) if n_group > 1 else 0
+        if n_group <= 1:
+            n_group = 0
+        elif experts_total and n_experts % (experts_total // n_group):
+            raise ValueError(f"a held share of {n_experts} experts splits a routing group of "
+                             f"{experts_total // n_group} (n_group {n_group} over {experts_total}): "
+                             "a share holds whole groups")
         return cls(
             name=name or config.get("_name_or_path", config.get("model_type", "model")),
             vocab_size=config["vocab_size"],
@@ -384,15 +414,17 @@ class ModelConfig:
             num_layers=config["num_hidden_layers"],
             num_heads=heads,
             num_kv_heads=config.get("num_key_value_heads", heads),
+            # (A latent-attention config's ``head_dim`` is its rope width, HF's
+            # convention; that family's layer reads the four MLA sizes below.)
             head_dim=config.get("head_dim") or hidden // heads,
             intermediate_size=config["intermediate_size"],
             **_attention_layout(config),
             rms_eps=config.get("rms_norm_eps", 1e-5),
             max_position=config.get("max_position_embeddings", 8192),
             tie_embeddings=config.get("tie_word_embeddings", False),
-            num_experts=(n_experts := 0 if all_dense else (
-                config.get("num_experts", config.get("num_local_experts", config.get("n_routed_experts", 0))) or 0
-            )),
+            num_experts=n_experts,
+            moe_experts_total=experts_total,
+            moe_expert_first=expert_first,
             num_experts_per_token=(config.get("num_experts_per_tok", 0) or 0) if n_experts else 0,
             # Mixtral stores the expert width in intermediate_size itself.
             moe_intermediate_size=((config.get("moe_intermediate_size", 0) or 0) or config["intermediate_size"]) if n_experts else 0,
@@ -415,8 +447,8 @@ class ModelConfig:
                 "norm_topk_prob", config.get("model_type") in ("mixtral", "deepseek_v3")
             )),
             moe_routed_scaling=float(config.get("routed_scaling_factor", 1.0) or 1.0),
-            moe_n_group=(config.get("n_group", 0) or 0) if n_experts else 0,
-            moe_topk_group=(config.get("topk_group", 0) or 0) if n_experts else 0,
+            moe_n_group=n_group,
+            moe_topk_group=topk_group,
             # noaux_tc correction bias: native transformers' DeepseekV3Config
             # doesn't serialize topk_method, but its modeling always creates
             # e_score_correction_bias — key off model_type too.
@@ -652,3 +684,10 @@ PRESETS: dict[str, ModelConfig] = {
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
     ),
 }
+# ``test-tiny-v3`` as one holder of an expert-parallel deployment sees it: 4 of
+# 16 routed experts held (ids 4-7), the router over all 16 with no group limit,
+# the shared expert and the leading dense layer whole.
+PRESETS["test-tiny-v3-held"] = dataclasses.replace(
+    PRESETS["test-tiny-v3"], name="test-tiny-v3-held", tie_embeddings=False,
+    moe_experts_total=16, moe_expert_first=4, moe_n_group=0, moe_topk_group=0,
+)

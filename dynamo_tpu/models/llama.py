@@ -87,17 +87,18 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
                 }
             )
         if moe:
+            # The experts held here; the router scores every output there is.
             e, mf = cfg.num_experts, cfg.moe_intermediate_size
             layers.update(
                 {
-                    "router": w(ks[4], (l, d, e), d),
+                    "router": w(ks[4], (l, d, cfg.router_outputs), d),
                     "w_gate": w(ks[5], (l, e, d, mf), d),
                     "w_up": w(ks[6], (l, e, d, mf), d),
                     "w_down": w(ks[7], (l, e, mf, d), mf),
                 }
             )
             if cfg.moe_router_bias:
-                layers["router_bias"] = jnp.zeros((l, e), jnp.float32)
+                layers["router_bias"] = jnp.zeros((l, cfg.router_outputs), jnp.float32)
             if cfg.shared_expert_size:
                 fs = cfg.shared_expert_size
                 layers.update(
@@ -248,11 +249,35 @@ def _mlp_moe(lp: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None) -> jnp.nda
             routing=routing,
         )
     if cfg.shared_expert_size:
+        out = out + _shared_expert(lp, xt, cfg)
+    return out.reshape(b, t, d)
+
+
+def _shared_expert(lp: Params, xt: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The always-on expert beside the routed ones, on flattened tokens [N, D]."""
+    with jax.named_scope("moe.shared"):
         shared = _qmm(jax.nn.silu(_qmm(xt, lp["w_shared_gate"])) * _qmm(xt, lp["w_shared_up"]), lp["w_shared_down"])
         if cfg.shared_expert_gated:
             shared = shared * jax.nn.sigmoid((xt @ lp["shared_gate"]).astype(jnp.float32)).astype(shared.dtype)
-        out = out + shared
-    return out.reshape(b, t, d)
+        return shared
+
+
+def _mlp_moe_held(lp: Params, x: jnp.ndarray, cfg: ModelConfig, valid: jnp.ndarray, mesh=None):
+    """The MoE of a model that holds a share of its routed experts (or has
+    identity experts), ``cfg.moe_held_share``: this holder's part of the routed
+    sum (``parallel/moe.moe_mlp_held``) and the shared expert, which every
+    holder computes whole. Returns the output and the layer's ``HELD_COUNTS``
+    over the ``valid`` tokens."""
+    from dynamo_tpu.parallel.moe import moe_mlp_held
+
+    b, t, d = x.shape
+    xt = x.reshape(b * t, d)
+    out, counted = moe_mlp_held(
+        lp, xt, num_experts_per_token=cfg.num_experts_per_token, first=cfg.moe_expert_first,
+        routed=cfg.routed_experts, routing=_routing_kwargs(cfg), valid=valid.reshape(-1), mesh=mesh)
+    if cfg.shared_expert_size:
+        out = out + _shared_expert(lp, xt, cfg)
+    return out.reshape(b, t, d), counted
 
 
 def _routed_dense(lp: Params, xt: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -456,7 +481,6 @@ def forward(
         sublayer's post-attention norm and joins the stream at the layer's
         end. The sublayers' cache slabs are 2 li and 2 li + 1."""
         from dynamo_tpu.models.mla import mla_attention
-        from dynamo_tpu.parallel.moe import moe_mlp_held
 
         x, k_full, v_full, li, counts = carry
         lp = join_expert_stack(lp, expert_stack, li)
@@ -475,24 +499,32 @@ def forward(
 
         a0, h0, k_full, v_full = attention(0, x, k_full, v_full)
         with jax.named_scope("mlp.moe"):
-            m, counted = moe_mlp_held(
-                lp, h0.reshape(b * t, -1), num_experts_per_token=cfg.num_experts_per_token,
-                first=cfg.moe_expert_first, routed=cfg.routed_experts, routing=_routing_kwargs(cfg),
-                valid=(slot_mapping != 0).reshape(-1), mesh=mesh)
+            m, counted = _mlp_moe_held(lp, h0, cfg, slot_mapping != 0, mesh)
         with jax.named_scope("mlp.dense0"):
             b0 = a0 + _mlp_dense(lp["sub0"], h0, cfg.mlp_act)
         a1, h1, k_full, v_full = attention(1, b0, k_full, v_full)
         with jax.named_scope("mlp.dense1"):
-            y = a1 + _mlp_dense(lp["sub1"], h1, cfg.mlp_act) + m.reshape(a1.shape)
+            y = a1 + _mlp_dense(lp["sub1"], h1, cfg.mlp_act) + m
         return (y, k_full, v_full, li + 1, counts + counted), None
 
     def make_layer_step(moe_layer: bool):
+        def ffn(lp, h2, counts: list):
+            """The layer's FFN on the normed stream; a model that holds a share
+            of its experts carries their counters beside the stream."""
+            with jax.named_scope("mlp"):
+                if not moe_layer:
+                    return _mlp_dense(lp, h2, cfg.mlp_act), counts
+                if cfg.moe_held_share:
+                    mlp, counted = _mlp_moe_held(lp, h2, cfg, slot_mapping != 0, mesh)
+                    return mlp, [counts[0] + counted]
+                return _mlp_moe(lp, h2, cfg, mesh), counts
+
         def layer_step(carry, lp):
-            x, k_full, v_full, li = carry
+            x, k_full, v_full, li, *counts = carry
             kind = None
             if layer_kinds is not None:
                 lp, kind = lp
-            if moe_layer:
+            if moe_layer:  # the expert stack's layers are counted from the first MoE layer
                 lp = join_expert_stack(lp, expert_stack, li - n_dense)
             h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
             if mla:
@@ -513,9 +545,8 @@ def forward(
                     )
                 x = x + attn_out
                 h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
-                with jax.named_scope("mlp"):
-                    mlp = _mlp_moe(lp, h2, cfg, mesh) if moe_layer else _mlp_dense(lp, h2, cfg.mlp_act)
-                return (x + mlp, k_full, v_full, li + 1), None
+                mlp, counts = ffn(lp, h2, counts)
+                return (x + mlp, k_full, v_full, li + 1, *counts), None
             with jax.named_scope("attn"):  # projections, rope, cache write, attention, output
                 qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
                 if cfg.attention_bias:
@@ -588,10 +619,9 @@ def forward(
                                                contiguous_positions=contiguous_positions)
                 x = x + _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
             h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
-            with jax.named_scope("mlp"):
-                mlp = _mlp_moe(lp, h2, cfg, mesh) if moe_layer else _mlp_dense(lp, h2, cfg.mlp_act)
+            mlp, counts = ffn(lp, h2, counts)
             x = x + mlp
-            return (x, k_full, v_full, li + 1), None
+            return (x, k_full, v_full, li + 1, *counts), None
 
         return layer_step
 
@@ -599,32 +629,30 @@ def forward(
     # O(1) in depth (matters at 70B/80-layer scale). Mixed DeepSeek stacks
     # (first_k_dense_replace) run two scans — dense layers first — with the
     # layer counter (cache offsets) carried straight through.
-    carry = (x, kf0, vf0, jnp.int32(0))
+    # A model that holds a share of its experts (or has identity experts)
+    # carries its expert layers' HELD_COUNTS beside the stream, summed.
+    carry = (x, kf0, vf0, jnp.int32(0)) + ((jnp.zeros((4,), jnp.int32),) if cfg.moe_held_share else ())
 
     def scanned(layers, lo: int, hi: int):
         if layer_kinds is None:
             return layers
         return layers, {name: v[lo:hi] for name, v in layer_kinds.items()}
 
-    counts = None
-    if cfg.moe_held_share and not cfg.shortcut_moe:
-        raise NotImplementedError("a held share of the experts, or identity experts, is served in shortcut-MoE layers only")
     if cfg.shortcut_moe:
         if layer_kinds is not None or not mla or n_dense:
             raise NotImplementedError("a shortcut-MoE layer is served with MLA sublayers, all alike")
-        (x, k_out, v_out, _, counts), _ = jax.lax.scan(
-            shortcut_layer_step, carry + (jnp.zeros((4,), jnp.int32),), moe_layers)
+        (x, k_out, v_out, _, *counts), _ = jax.lax.scan(shortcut_layer_step, carry, moe_layers)
     else:
         if "dense_layers" in params:
             carry, _ = jax.lax.scan(make_layer_step(False), carry, scanned(params["dense_layers"], 0, n_dense))
-        (x, k_out, v_out, _), _ = jax.lax.scan(
+        (x, k_out, v_out, _, *counts), _ = jax.lax.scan(
             make_layer_step(cfg.is_moe),
             carry,
             scanned(moe_layers, n_dense, cfg.num_layers),
         )
     k_out = k_out.reshape(k_cache.shape)
     v_out = v_out.reshape(v_cache.shape)
-    extra = (counts,) if moe_counts else ()
+    extra = (counts[0] if counts else None,) if moe_counts else ()
 
     x = rms_norm(x, params["norm_f"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
     # bf16 operands, f32 accumulate: no f32 materialization of the (huge)
@@ -670,9 +698,9 @@ def encode(
     (`lib/llm/src/http/service/openai.rs:580`, `engines.rs:321`).
     """
     b, t = tokens.shape
-    if cfg.mixed_attention or cfg.shortcut_moe:
-        raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE) "
-                                  "and hold one attention block each")
+    if cfg.mixed_attention or cfg.shortcut_moe or cfg.moe_held_share:
+        raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE), "
+                                  "hold one attention block each and all their experts")
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, theta=cfg.rope_theta, scaling=cfg.rope_scaling))
     attn_mscale = rope_attention_factor(cfg.rope_scaling) ** 2

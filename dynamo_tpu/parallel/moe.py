@@ -157,11 +157,12 @@ def route_tokens(
     return weights * scaling, topi
 
 
-def _widen(lp: dict) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def _widen(lp: dict, dtype) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The three expert matrices as the matmuls take them: quantized weights
-    are widened here, under a name of their own (a no-op on plain weights)."""
+    are widened here to the activations' ``dtype``, under a name of their own
+    (a no-op on plain weights)."""
     with jax.named_scope("moe.widen"):
-        return _dq(lp["w_gate"]), _dq(lp["w_up"]), _dq(lp["w_down"])
+        return _dq(lp["w_gate"], dtype), _dq(lp["w_up"], dtype), _dq(lp["w_down"], dtype)
 
 
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
@@ -270,7 +271,7 @@ def moe_mlp_dropless(
             interpret=interpret_mode(),
         )
     else:
-        w_gate, w_up, w_down = _widen(lp)
+        w_gate, w_up, w_down = _widen(lp, x.dtype)
         with jax.named_scope("moe.experts_gate_up"):
             gate = jax.nn.silu(jax.lax.ragged_dot(xk, w_gate, group_sizes))
             up = jax.lax.ragged_dot(xk, w_up, group_sizes)
@@ -340,15 +341,18 @@ def moe_mlp_held(
         is_zero = topi >= routed
         counts = jnp.stack([valid.sum() * k, (is_zero & valid[:, None]).sum(), n_here, (sizes > 0).sum()]).astype(jnp.int32)
 
-    with jax.named_scope("moe.zero"):
-        zero_w = jnp.where(is_zero, weights, 0.0).sum(axis=-1)  # f32[N]
-        out = x.astype(jnp.float32) * zero_w[:, None]
+    if lp["router"].shape[-1] > routed:
+        with jax.named_scope("moe.zero"):
+            zero_w = jnp.where(is_zero, weights, 0.0).sum(axis=-1)  # f32[N]
+            out = x.astype(jnp.float32) * zero_w[:, None]
+    else:  # a router without identity outputs: the held experts' terms are all there is
+        out = jnp.zeros((n, d), jnp.float32)
 
     cap = held_rows_cap(n * k, held, lp["router"].shape[-1])
     order = jnp.pad(order, (0, -(n * k) % cap))
     flat_w = weights.reshape(-1)
     if not fused:
-        w_gate, w_up, w_down = _widen(lp)
+        w_gate, w_up, w_down = _widen(lp, x.dtype)
 
     def one_pass(i, acc):
         lo = i * cap
@@ -452,7 +456,7 @@ def moe_mlp(
 
     # Batched expert FFN: one contraction over all experts; GSPMD shards the
     # leading axis on ep from the weight shardings.
-    w_gate, w_up, w_down = _widen(lp)
+    w_gate, w_up, w_down = _widen(lp, x.dtype)
     with jax.named_scope("moe.experts_gate_up"):
         gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w_gate))
         up = jnp.einsum("ecd,edf->ecf", expert_in, w_up)

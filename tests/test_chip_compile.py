@@ -431,17 +431,61 @@ def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
     assert not made, made
 
 
+@pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
+def test_plain_held_share_step_joyai_largest_corners(sds, monkeypatch, split):
+    """reason-saturated's largest steps at JoyAI-LLM-Flash's widths (its dense
+    layer and two of its MoE layers, this chip's 32 of 256 experts, the whole
+    vocabulary left out): 64 decode rows, and 64 decode slots + one 64-token
+    chunk slot, over 16 pages. The dual scan: every layer's attention through
+    the MLA kernel at 32 heads, the held experts through the grouped int8
+    kernel at its 2048 x 768 tiles inside the pass loop by a layer index
+    counted from the first MoE layer, no expert array copied outside it, and
+    the four counters beside the outputs."""
+    import functools
+    import re
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    cfg = _benchmark_config("joyai-llm-flash-ep8-int8", layers=2)
+    assert (cfg.num_layers, cfg.first_k_dense, cfg.num_experts, cfg.routed_experts) == (3, 1, 32, 256)
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
+    assert k_cache.shape == (3, 1025, 128, 512) and v_cache.shape == (3, 1025, 128, 128)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if split is None:
+        toks, slots = (64, 1), 64
+    else:
+        toks, slots = (split[0] + split[1] * split[2],), split[0] + split[1]
+    compiled = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas", split=split, moe_counts=True)).lower(
+        params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(slots, 16), slot_mapping=i32(*toks), last_token_index=i32(slots),
+    ).compile()
+    text = compiled.as_text()
+    assert "mla_paged_decode_attention" in text and text.count("moe_grouped_matmul_int8") >= 2
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # The held experts stay where they are: no [32, 2048, 768] array is produced outside the kernel.
+    made = re.findall(r"= s8\[(?:1,)?32,(?:2048,768|768,2048)\]\S* (?!parameter|get-tuple-element|bitcast)(\w[\w-]*)\(", text)
+    assert not made, made
+    assert [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)][-1] == (4,)  # HELD_COUNTS beside the outputs
+
+
 # -- weights read where they lie (ISSUE 35, models/quant.held_flat) -----------
 
 def _benchmark_config(name: str, layers: int, vocab: int = 8192):
     """A benchmark configuration at its published widths, cut to ``layers``
-    and a small vocabulary (the layer scan's body depends on neither)."""
+    (after its leading dense ones, where it has them) and a small vocabulary
+    (the layer scan's body depends on neither)."""
     import pathlib
 
     from benchmark import serving
 
     conf = serving.load_config(pathlib.Path(__file__).parents[1] / f"benchmark/configs/{name}.json")
     hf = conf["hf"]
+    layers += hf.get("first_k_dense_replace", 0)  # leading dense layers are a scan of their own
     hf.update({"num_layers" if "num_layers" in hf else "num_hidden_layers": layers, "vocab_size": vocab})
     for per_layer in ("layer_types", "mlp_layer_types"):
         if per_layer in hf:
@@ -456,6 +500,8 @@ def _benchmark_config(name: str, layers: int, vocab: int = 8192):
     ("longcat-flash-chat-ep32-int8", 64, False, True),
     ("olmoe-1b-7b-int8", 64, False, True),  # the control: a flat q/k norm stands between, no barrier, no copy
     ("olmoe-1b-7b-int8", 64, True, True),
+    ("joyai-llm-flash-ep8-int8", 64, False, True),  # a dense scan, then the plain body with a held share
+    ("joyai-llm-flash-ep8-int8", 64, True, True),
 ], ids=lambda v: str(v))
 def test_step_programs_relay_no_int8_weight(sds, monkeypatch, config, rows, mixed, held):
     """Two layers of each benchmark configuration at its published widths, the

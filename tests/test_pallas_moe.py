@@ -49,7 +49,7 @@ def _ffn_f32(x, lp, sizes):
 
 def _ffn_widened(x, lp, sizes):
     """Today's formulation, as ``moe_mlp_dropless`` spells it."""
-    w_gate, w_up, w_down = moe._widen(lp)
+    w_gate, w_up, w_down = moe._widen(lp, x.dtype)
     gate = jax.nn.silu(jax.lax.ragged_dot(x, w_gate, sizes))
     return jax.lax.ragged_dot(gate * jax.lax.ragged_dot(x, w_up, sizes), w_down, sizes)
 

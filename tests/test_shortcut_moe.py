@@ -133,13 +133,15 @@ def test_cache_has_a_slab_per_attention_sublayer_and_the_second_is_written():
 # -- the engine against the plain reference -------------------------------------
 
 
-def _served_logprobs(cfg, params, prompt, n_out, *, chunk, overlap=True):
+def _served_logprobs(cfg, params, prompt, n_out, *, chunk, overlap=True, split=True):
     """Through EngineCore: the prompt prefilled in ``chunk``-token chunks beside
-    a decoding row (the split token axis), then decoded through the paged
-    latent cache; every generated token's logprob and its top 20."""
+    a decoding row (the split token axis, or with ``split=False`` the rows x
+    tokens rectangle), then decoded through the paged latent cache; every
+    generated token's logprob and its top 20."""
     page = 4
     runner = ModelRunner(cfg, params, num_pages=64, page_size=page, max_batch_size=2,
                          prefill_bucket=4, attn_impl="reference")
+    runner._can_split = runner._can_split and split
     core = EngineCore(runner, EngineConfig(
         num_pages=64, page_size=page, max_batch_size=2, max_prefill_tokens=chunk, chunk_prefill_tokens=chunk,
         max_seq_len=128, enable_prefix_caching=False, overlap=overlap))
@@ -369,8 +371,10 @@ def test_a_model_without_a_share_or_identities_returns_no_counts():
         core.step()
     steps = core.flight.snapshot(kind="step")
     assert steps and all(s["moe_choices"] == 0 and s["moe_experts_touched"] == 0 for s in steps)
-    with pytest.raises(NotImplementedError, match="shortcut-MoE layers only"):
-        bad = dataclasses.replace(cfg, moe_zero_experts=2)
-        k, v = llama.init_kv_cache(bad, 4, 4)
-        llama.forward(llama.init_params(bad, 0), bad, jnp.ones((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.int32), k, v,
-                      jnp.ones((1, 1), jnp.int32), jnp.full((1, 1), 4, jnp.int32), jnp.zeros((1,), jnp.int32))
+    # The plain layer body serves identity experts too (tests/test_held_share_plain.py has a share in it).
+    zeros = dataclasses.replace(cfg, moe_zero_experts=2)
+    k, v = llama.init_kv_cache(zeros, 4, 4)
+    out = llama.forward(llama.init_params(zeros, 0), zeros, jnp.ones((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.int32), k, v,
+                        jnp.ones((1, 1), jnp.int32), jnp.full((1, 1), 4, jnp.int32), jnp.zeros((1,), jnp.int32),
+                        moe_counts=True)
+    assert out[3][0] == 1 * 2 * 2 and out[3][1] + out[3][2] == out[3][0]  # a token, two choices, two layers: held or identity

@@ -92,7 +92,7 @@ def main() -> int:
 
     def widened(stack, xk, sizes):
         def layer(acc, lp):
-            wg, wu, wd = _widen(lp)
+            wg, wu, wd = _widen(lp, xk.dtype)
             gate = jax.nn.silu(jax.lax.ragged_dot(xk, wg, sizes))
             up = jax.lax.ragged_dot(xk, wu, sizes)
             return acc + jax.lax.ragged_dot(gate * up, wd, sizes).astype(jnp.float32), None
