@@ -16,6 +16,11 @@ helpers it shares; see ``docs/KERNELS.md``), with two differences:
 - The value IS the latent: ``acc += p @ c`` — no separate V stream at all,
   so HBM traffic per token is r_kv + dr bytes where GQA pays 2 * H_kv * hd.
 
+A row's walk copies only the pages the row holds: its last block, where the
+row holds fewer pages than a block has, starts and waits on those pages alone
+and contracts over them in a branch compiled for that count
+(``docs/KERNELS.md``, "The walk's tail").
+
 Because MLA is already MQA, multi-query verify rows need no block-diagonal
 staging: T_q query tokens per sequence are a plain ``[T_q * n_heads, r_kv]``
 row stack, each row masked to its own token's causal horizon — speculative
@@ -68,7 +73,9 @@ def mla_decode_supported(
 
 def _mla_decode_kernel(
     # scalar prefetch (SMEM)
-    lengths_ref,  # i32[B] per-sequence walk length (max row position + 1)
+    pages_ref,  # i32[B] pages the row's walk holds (its farthest token's, >= 1)
+    blocks_ref,  # i32[B] blocks the row's walk visits
+    first_ref,  # i32[B] blocks of the rows before: the row's first global block
     tables_ref,  # i32[B * pages_per_seq]
     qpos_ref,  # i32[B * t_q] absolute position of each query token
     # blocked operands
@@ -98,49 +105,38 @@ def _mla_decode_kernel(
     sp = pl.program_id(1)
     bk = pages_per_block * page_size
 
-    def blocks_of(bb):
-        return pl.cdiv(jnp.maximum(lengths_ref[bb], 1), bk)
-
-    nb_total = blocks_of(b)
+    nb_total = blocks_ref[b]
     # Static split boundaries (see pallas_paged._decode_kernel): a row's
     # accumulation order never depends on other rows' runtime lengths.
     first = sp * blocks_per_split
     nb_here = jnp.clip(nb_total - first, 0, blocks_per_split)
+    g0 = first_ref[b] + jnp.minimum(first, nb_total)
 
-    g0 = (
-        jax.lax.fori_loop(0, b, lambda bb, acc: acc + blocks_of(bb), jnp.int32(0))
-        + jnp.minimum(first, nb_total)
-    )
+    def held_pages(bb, ii):
+        # Pages of row bb's block ii that the row holds: the whole block but
+        # in the row's tail. Starts and waits both count by it, so a copy is
+        # waited on exactly where it was started.
+        return jnp.clip(pages_ref[bb] - ii * pages_per_block, 1, pages_per_block)
 
-    def page_index(bb, ii, j):
-        last = jnp.maximum(lengths_ref[bb] - 1, 0) // page_size
-        idx = jnp.minimum(ii * pages_per_block + j, last)
-        return tables_ref[bb * pages_per_seq + idx]
+    def page_copies(slot, bb, ii, j):
+        page = tables_ref[bb * pages_per_seq + ii * pages_per_block + j]
+        rows = pl.ds(j * page_size, page_size)
+        return (
+            pltpu.make_async_copy(c_hbm.at[page], c_buf.at[slot, rows, :], c_sem.at[slot]),
+            pltpu.make_async_copy(r_hbm.at[page], r_buf.at[slot, rows, :], r_sem.at[slot]),
+        )
 
     def start_block(slot, bb, ii):
+        held = held_pages(bb, ii)
         for j in range(pages_per_block):
-            page = page_index(bb, ii, j)
-            rows = pl.ds(j * page_size, page_size)
-            pltpu.make_async_copy(
-                c_hbm.at[page], c_buf.at[slot, rows, :], c_sem.at[slot]
-            ).start()
-            pltpu.make_async_copy(
-                r_hbm.at[page], r_buf.at[slot, rows, :], r_sem.at[slot]
-            ).start()
 
-    def wait_block(slot, bb, ii):
-        for j in range(pages_per_block):
-            page = page_index(bb, ii, j)
-            rows = pl.ds(j * page_size, page_size)
-            pltpu.make_async_copy(
-                c_hbm.at[page], c_buf.at[slot, rows, :], c_sem.at[slot]
-            ).wait()
-            pltpu.make_async_copy(
-                r_hbm.at[page], r_buf.at[slot, rows, :], r_sem.at[slot]
-            ).wait()
+            @pl.when(j < held)
+            def _():
+                for copy in page_copies(slot, bb, ii, j):
+                    copy.start()
 
     def next_block(bb, ii):
-        advance = ii + 1 >= blocks_of(jnp.minimum(bb, batch - 1))
+        advance = ii + 1 >= blocks_ref[jnp.minimum(bb, batch - 1)]
         nb = jnp.where(advance, bb + 1, bb)
         ni = jnp.where(advance, 0, ii + 1)
         return nb, ni
@@ -169,20 +165,28 @@ def _mla_decode_kernel(
     for tt in range(t_q):
         qpos = jnp.where(row_t == tt, qpos_ref[b * t_q + tt], qpos)
 
-    def body(i, carry):
-        m, l, acc = carry
+    def visit(i):
+        """Start the copies of the block ``dma_depth - 1`` ahead of this
+        split's block ``i``; return that block's ring slot and index."""
         ii = first + i
         g = g0 + i
-        slot = g % dma_depth
         bb, nxt = b, ii
         for _ in range(dma_depth - 1):
             bb, nxt = next_block(bb, nxt)
         start_ahead((g + dma_depth - 1) % dma_depth, bb, nxt)
+        return g % dma_depth, ii
 
-        wait_block(slot, b, ii)
-
-        c = c_buf[slot]  # [bk, r_kv] cache dtype
-        r = r_buf[slot]  # [bk, r_width]
+    def attend(held, slot, ii, m, l, acc):
+        """One online-softmax step over the first ``held`` (static) pages of
+        the block in ``slot``: waits on those pages' copies and contracts over
+        them alone. Ring rows no copy of this block wrote hold an earlier
+        block's latents or nothing yet, and 0 * NaN is NaN."""
+        for j in range(held):
+            for copy in page_copies(slot, b, ii, j):
+                copy.wait()
+        tokens = pl.ds(0, held * page_size)
+        c = c_buf[slot, tokens, :]  # [held * page_size, r_kv] cache dtype
+        r = r_buf[slot, tokens, :]  # [held * page_size, r_width]
         if c.dtype.itemsize < 2:  # fp8 cache: DMA at 1 B/elem, matmul in bf16
             c = c.astype(jnp.bfloat16)
             r = r.astype(jnp.bfloat16)
@@ -192,7 +196,7 @@ def _mla_decode_kernel(
             q_lat, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) + jax.lax.dot_general(
             q_rope, r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # f32[R, bk]
+        )  # f32[R, held * page_size]
         kpos = ii * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos <= qpos
         s = jnp.where(mask, s, NEG_INF)
@@ -209,13 +213,34 @@ def _mla_decode_kernel(
         )  # f32[R, r_kv]
         return m_new, l_new, acc_new
 
+    def full_block(i, carry):
+        slot, ii = visit(i)
+        return attend(pages_per_block, slot, ii, *carry)
+
+    def put(m, l, acc):
+        acc_ref[...] = acc
+        m_ref[...] = jnp.broadcast_to(m, (r_rows, LANES))
+        l_ref[...] = jnp.broadcast_to(l, (r_rows, LANES))
+
+    # The blocks the row fills go through the loop; its last block, where the
+    # row holds fewer pages than a block has, comes after it, in the one
+    # branch that is compiled for that count of pages.
+    tail_pages = pages_ref[b] - (nb_total - 1) * pages_per_block
+    has_tail = (nb_here > 0) & (first + nb_here == nb_total) & (tail_pages < pages_per_block)
+    n_full = nb_here - has_tail.astype(jnp.int32)
     m0 = jnp.full((r_rows, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((r_rows, 1), jnp.float32)
     acc0 = jnp.zeros((r_rows, r_kv), jnp.float32)
-    m_fin, l_fin, acc_fin = jax.lax.fori_loop(0, nb_here, body, (m0, l0, acc0))
-    acc_ref[...] = acc_fin
-    m_ref[...] = jnp.broadcast_to(m_fin, (r_rows, LANES))
-    l_ref[...] = jnp.broadcast_to(l_fin, (r_rows, LANES))
+    put(*jax.lax.fori_loop(0, n_full, full_block, (m0, l0, acc0)))
+
+    @pl.when(has_tail)
+    def _():
+        slot, ii = visit(n_full)
+        for held in range(1, pages_per_block):
+
+            @pl.when(tail_pages == held)
+            def _():
+                put(*attend(held, slot, ii, m_ref[:, :1], l_ref[:, :1], acc_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "num_splits"))
@@ -253,8 +278,12 @@ def mla_paged_decode(
     splits = max(1, min(splits, max_blocks))
     bps = -(-max_blocks // splits)
 
-    # Walk covers the row's farthest token; rows mask their own horizon.
-    lengths = jnp.max(positions, axis=1) + 1
+    # Walk covers the row's farthest token; rows mask their own horizon. What
+    # the walk counts by is worked out here, once: the kernel's scalar core
+    # then does no division and no loop over earlier rows between the copies.
+    pages = jnp.maximum(jnp.max(positions, axis=1), 0) // page_size + 1
+    blocks = -(-pages // ppb)
+    first_block = jnp.cumsum(blocks) - blocks
 
     q_dtype = c_cache.dtype if c_cache.dtype.itemsize >= 2 else jnp.bfloat16
     r_rows = t_q * n_heads
@@ -277,7 +306,7 @@ def mla_paged_decode(
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=5,
             grid=(b, splits),
             in_specs=[
                 pl.BlockSpec((None, r_rows, r_kv), lambda bb, ss, *_: (bb, 0, 0)),
@@ -299,12 +328,17 @@ def mla_paged_decode(
             jax.ShapeDtypeStruct((b, splits, r_rows, LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # Each tail branch keeps score temporaries of its own: a chunk's
+            # 512 query rows come to 18.3 MiB where the default scope is 16.
+            vmem_limit_bytes=32 * 2**20,
         ),
         interpret=interpret,
         name="mla_paged_decode_attention",
     )(
-        lengths,
+        pages,
+        blocks,
+        first_block,
         block_tables.reshape(-1),
         positions.reshape(-1),
         q_lat_s,

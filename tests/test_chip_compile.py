@@ -136,8 +136,22 @@ def test_windowed_prefill_kernel_compiles(sds):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("t_q", [1, 5], ids=["decode", "verify5"])
-def test_mla_decode_kernel_compiles(sds, t_q):
+#: ``mla-8b-proxy`` at 32 rows, then the two reason-saturated cells' calls
+#: (benchmark/configs/joyai-llm-flash-ep8-int8.json at 32 heads,
+#: longcat-flash-chat-ep32-int8.json at 64): 64 rows of 16 pages; a decode row
+#: is one query, a chunk's queries go in tiles of 16 at 32 heads and of 8 at 64
+#: (models/mla._query_tile), 512 kernel rows either way. The walk's tail copies
+#: and waits under conditions and compiles a branch per count of held pages,
+#: whose temporaries at 512 rows pass the default scoped VMEM.
+MLA_CALLS = {
+    "decode": (32, 32, 1), "verify5": (32, 32, 5),
+    "joyai_decode": (64, 32, 1), "joyai_verify8": (64, 32, 8), "joyai_chunk_tile16": (64, 32, 16),
+    "longcat_decode": (64, 64, 1), "longcat_chunk_tile8": (64, 64, 8),
+}
+
+
+@pytest.mark.parametrize("batch,heads,t_q", MLA_CALLS.values(), ids=MLA_CALLS.keys())
+def test_mla_decode_kernel_compiles(sds, batch, heads, t_q):
     from dynamo_tpu.models.mla import mla_cache_widths
     from dynamo_tpu.ops.pallas_mla import mla_decode_supported, mla_paged_decode
 
@@ -145,12 +159,12 @@ def test_mla_decode_kernel_compiles(sds, t_q):
     # kv_lora 512 latents + rope 64, the rope stream padded to one lane tile.
     r_kv, r_rope = mla_cache_widths(cfg)
     assert (r_kv, r_rope) == (512, 128)
-    batch, page, pages_per_seq = 32, 128, 16
-    assert mla_decode_supported(r_kv, r_rope, t_q, cfg.num_heads)
+    page, pages_per_seq = 128, 16
+    assert mla_decode_supported(r_kv, r_rope, t_q, heads)
     text = _compiled_text(
         mla_paged_decode,
-        sds((batch, t_q, cfg.num_heads, r_kv), jnp.bfloat16),
-        sds((batch, t_q, cfg.num_heads, r_rope), jnp.bfloat16),
+        sds((batch, t_q, heads, r_kv), jnp.bfloat16),
+        sds((batch, t_q, heads, r_rope), jnp.bfloat16),
         sds((batch * pages_per_seq + 1, page, r_kv), jnp.bfloat16),
         sds((batch * pages_per_seq + 1, page, r_rope), jnp.bfloat16),
         sds((batch, pages_per_seq), jnp.int32),
