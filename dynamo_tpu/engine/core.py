@@ -356,6 +356,13 @@ class EngineCore:
         # label is refined to the lease id at telemetry bring-up (launch.py).
         self.incidents = IncidentCapture(worker=f"pid-{os.getpid()}", core=self)
         self.sentinel.on_fire = lambda kind, info: self.incidents.capture("anomaly", info)
+        # Long steps (one whose period is five times its kind's; the
+        # sentinel tells which) by cause: "gc" / "profiler" where a host
+        # pause of tracing.HOST_PAUSES covers half of what was lost,
+        # "compile", or "" for one that nothing names.
+        self._host_pauses = tracing.install_host_pauses()
+        self.long_steps: dict[str, int] = {}
+        self.long_step_lost_ms: dict[str, float] = {}
         # Cumulative counters for the metrics plane.
         self._prompt_tokens_total = 0
         self._generated_tokens_total = 0
@@ -710,7 +717,7 @@ class EngineCore:
             # rows: their wall time is the ITL a running request observed.
             if self.chunk_controller is not None and decode_rows:
                 self.chunk_controller.observe(wall_ms)
-            self.flight.record(
+            record = self.flight.record(
                 STEP,
                 step_kind=kind,
                 decode_rows=decode_rows,
@@ -773,6 +780,7 @@ class EngineCore:
                 self._charge_loss(barrier_reason, host_ms)
             else:
                 self._charge_loss("gap", host_ms)
+            recompiles0 = self.recompile_count
             if tracker is not None:
                 events = tracker.events()
                 for ev in events[self._recompile_events_seen:]:
@@ -787,8 +795,51 @@ class EngineCore:
                 recompiles=self.recompile_count,
                 shortfall_pages=self.onboard_shortfall_pages,
             )
+            # The step's period: the eleven phases its record holds (the tail
+            # of the step before, the gap, this step up to here) less the wait
+            # for a request, which is no pause.
+            no_work_ns = clock.carried[tracing.NO_WORK - tracing.RECORD]
+            period_ms = wall_ms + gap_ms + (clock.carried[0] - no_work_ns) / 1e6
+            expected_ms = self.sentinel.observe_period(kind, decode_rows, period_ms)
+            if expected_ms:
+                self._record_long_step(
+                    record, period_ms, expected_ms, no_work_ns=no_work_ns,
+                    compiled=self.recompile_count > recompiles0,
+                )
+            if self._host_pauses.pending:
+                self._host_pauses.flush()
             clock.end()
             return out
+
+    def _record_long_step(
+        self, record: dict, period_ms: float, expected_ms: float, *, no_work_ns: int, compiled: bool
+    ) -> None:
+        """One ``engine_long_step`` span and the counters by cause, for a step
+        the sentinel found long: what was lost, the phase that holds most of
+        it, and the host pauses (garbage collection, the profiler's start and
+        stop) that lie inside the period, on ``perf_counter_ns``."""
+        lost_ms = period_ms - expected_ms
+        phases = record["phases_us"]
+        phase = max((p for p in tracing.PHASES if p != "no_work"), key=phases.__getitem__)
+        hi_ns = record["t0_ns"] + int(record["wall_ms"] * 1e6)
+        lo_ns = hi_ns - int(period_ms * 1e6) - no_work_ns
+        gc_ms, gc_generation, profiler_ms = self._host_pauses.overlap_ms(lo_ns, hi_ns)
+        if profiler_ms >= lost_ms / 2:
+            cause = "profiler"
+        elif gc_ms >= lost_ms / 2:
+            cause = "gc"
+        else:
+            cause = "compile" if compiled else ""
+        self.long_steps[cause] = self.long_steps.get(cause, 0) + 1
+        self.long_step_lost_ms[cause] = self.long_step_lost_ms.get(cause, 0.0) + lost_ms
+        tracing.record_span(
+            "engine_long_step", round(period_ms, 3), start_mono=lo_ns / 1e9, request_id="engine_long_step",
+            step_kind=record["step_kind"], decode_rows=record["decode_rows"],
+            expected_ms=round(expected_ms, 3), lost_ms=round(lost_ms, 3),
+            phase=phase, phase_ms=round(phases[phase] / 1e3, 3),
+            gc_ms=round(gc_ms, 3), gc_generation=gc_generation, profiler_ms=round(profiler_ms, 3),
+            cause=cause, traced=record["traced"], t0_ns=record["t0_ns"], seq=record["seq"],
+        )
 
     def _charge_loss(self, cause: str, ms: float) -> None:
         """Accumulate lost wall time under one attribution cause (ms)."""

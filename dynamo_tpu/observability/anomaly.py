@@ -21,6 +21,12 @@ floor, so a quiet fleet (or a cold start legitimately filling the bucket
 lattice) never false-positives. An active anomaly clears after
 ``clear_after`` consecutive quiet steps (hysteresis — no flapping gauge).
 All knobs ride :class:`~dynamo_tpu.config.AnomalySettings` (``DYN_ANOMALY_*``).
+
+Beside the windows the sentinel names single **long steps**
+(:meth:`AnomalySentinel.observe_period`): a step whose period is over
+:data:`LONG_STEP_RATIO` times what its kind has been taking and at least
+:data:`LONG_STEP_FLOOR_MS` over it. That one is always on and has no knob; the
+engine writes the ``engine_long_step`` span for it.
 """
 
 from __future__ import annotations
@@ -41,6 +47,15 @@ ANOMALY_KINDS = (
     "recompile_storm",
     "onboard_shortfall_burst",
 )
+
+#: A step is long when its period is over this many times its kind's expected
+#: period and at least the floor over it.
+LONG_STEP_RATIO = 5.0
+LONG_STEP_FLOOR_MS = 10.0
+#: The expected period is the mean of a kind's first steps, then an
+#: exponential mean at 1 / LONG_STEP_ARM; no step is long before it is armed.
+#: As many long steps in a row are a new regime: the kind arms anew.
+LONG_STEP_ARM = 32
 
 
 class AnomalySentinel:
@@ -73,6 +88,34 @@ class AnomalySentinel:
         self.active: dict[str, dict[str, Any]] = {}
         #: kind -> rising edges ever fired (scoreboards / tests).
         self.fired: dict[str, int] = {}
+        #: (step kind, power-of-two bucket of decode rows) -> [steps folded,
+        #: expected period in ms, long steps in a row].
+        self._periods: dict[tuple[str, int], list] = {}
+
+    # -- long steps --------------------------------------------------------
+
+    def observe_period(self, step_kind: str, decode_rows: int, period_ms: float) -> float:
+        """Fold one step's period; returns what was expected if the step is
+        long, else 0.0. A long step does not move the expected period. Steps
+        are told apart by kind and by the bucket of their decode rows, so a
+        batch that fills up is another kind of step and not a long one."""
+        key = (step_kind, decode_rows.bit_length())
+        state = self._periods.get(key)
+        if state is None:
+            state = self._periods[key] = [0, 0.0, 0]
+        n, expected = state[0], state[1]
+        if n < LONG_STEP_ARM:
+            state[0] = n + 1
+            state[1] = expected + (period_ms - expected) / (n + 1)
+            return 0.0
+        if period_ms > LONG_STEP_RATIO * expected and period_ms - expected >= LONG_STEP_FLOOR_MS:
+            state[2] += 1
+            if state[2] >= LONG_STEP_ARM:
+                state[:] = [0, 0.0, 0]
+            return expected
+        state[1] = expected + (period_ms - expected) / LONG_STEP_ARM
+        state[2] = 0
+        return 0.0
 
     # -- observation -------------------------------------------------------
 

@@ -31,6 +31,8 @@ from typing import Any, Awaitable, Callable
 
 from prometheus_client import Counter, CollectorRegistry, Gauge, Histogram, generate_latest
 
+from dynamo_tpu import tracing
+
 logger = logging.getLogger(__name__)
 
 _PHASE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
@@ -254,9 +256,40 @@ class EngineMetrics:
             "drain) — the step-kind histogram behind EngineCore.loss_snapshot",
             ["worker", "kind"], registry=self.registry,
         )
+        # Long steps (one whose period is five times its kind's) by cause, and
+        # the process's garbage collections by generation: what the host was
+        # doing when a step took a tenth of a second (docs/OBSERVABILITY.md,
+        # "Long steps and host pauses"). Delta-synced like the ledgers above.
+        self._long_steps = Counter(
+            "dynamo_engine_long_steps",
+            "Engine steps whose period was over five times their kind's "
+            "expected period and at least 10 ms over it, by cause: gc, "
+            "profiler, compile, or unnamed",
+            ["worker", "cause"], registry=self.registry,
+        )
+        self._long_step_lost = Counter(
+            "dynamo_engine_long_step_lost_seconds",
+            "Seconds the long steps took beyond their kind's expected period, "
+            "by cause: gc, profiler, compile, or unnamed",
+            ["worker", "cause"], registry=self.registry,
+        )
+        self._gc_pauses = Counter(
+            "dynamo_host_gc_pauses",
+            "Garbage collections this process ran since the first engine was "
+            "built, by generation (every collection, however short)",
+            ["worker", "generation"], registry=self.registry,
+        )
+        self._gc_pause_seconds = Counter(
+            "dynamo_host_gc_pause_seconds",
+            "Seconds this process spent in garbage collections, by generation: "
+            "the interpreter is held throughout, on whatever thread collects",
+            ["worker", "generation"], registry=self.registry,
+        )
         self._lost_time_synced: dict[str, float] = {}
         self._step_time_synced: dict[str, float] = {}
         self._step_kinds_synced: dict[str, int] = {}
+        self._long_steps_synced: dict[str, tuple[int, float]] = {}
+        self._gc_synced = [(0, 0)] * tracing.GC_GENERATIONS
         # Anomaly sentinel: 1 while a rolling-window detector is active on
         # this worker (hysteresis in the sentinel, not here), keyed by the
         # detector kind; fired totals count rising edges ever.
@@ -349,6 +382,7 @@ class EngineMetrics:
         self._lost_time_synced.clear()
         self._step_time_synced.clear()
         self._step_kinds_synced.clear()
+        self._long_steps_synced.clear()
         return self
 
     def bind_transfer(self, transfer: Any) -> "EngineMetrics":
@@ -479,6 +513,20 @@ class EngineMetrics:
                 if n > prev:
                     self._step_kinds.labels(self.worker, kind).inc(n - prev)
                     self._step_kinds_synced[kind] = n
+        long_steps = getattr(core, "long_steps", None)
+        if long_steps is not None:
+            for cause, n in long_steps.items():
+                ms = core.long_step_lost_ms[cause]
+                prev_n, prev_ms = self._long_steps_synced.get(cause, (0, 0.0))
+                self._long_steps.labels(self.worker, cause or "unnamed").inc(n - prev_n)
+                self._long_step_lost.labels(self.worker, cause or "unnamed").inc((ms - prev_ms) / 1e3)
+                self._long_steps_synced[cause] = (n, ms)
+        pauses = tracing.HOST_PAUSES
+        for gen, now in enumerate(zip(pauses.gc_count, pauses.gc_ns)):
+            prev = self._gc_synced[gen]
+            self._gc_pauses.labels(self.worker, str(gen)).inc(now[0] - prev[0])
+            self._gc_pause_seconds.labels(self.worker, str(gen)).inc((now[1] - prev[1]) / 1e9)
+            self._gc_synced[gen] = now
         sentinel = getattr(core, "sentinel", None)
         if sentinel is not None:
             self._anomaly_active.clear()

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import logging
 import os
 import re
@@ -108,7 +109,8 @@ def start_device_trace(log_dir: str) -> bool:
     with _lock:
         if _active_dir is not None:
             return False
-        jax.profiler.start_trace(log_dir, profiler_options=profiler_options())
+        with HOST_PAUSES.profiler("start"):
+            jax.profiler.start_trace(log_dir, profiler_options=profiler_options())
         _active_dir = log_dir
         _annotating = True
     logger.info("device trace started -> %s", log_dir)
@@ -123,7 +125,8 @@ def stop_device_trace() -> str | None:
         if _active_dir is None:
             return None
         _annotating = False  # writing the trace takes seconds: annotate nothing meanwhile
-        jax.profiler.stop_trace()
+        with HOST_PAUSES.profiler("stop"):
+            jax.profiler.stop_trace()
         path, _active_dir = _active_dir, None
     logger.info("device trace written -> %s", path)
     return path
@@ -614,6 +617,137 @@ def record_span(
         "parent_id": span.parent_id, "duration_ms": round(duration_ms, 3),
         "status": status, **fields,
     }
+
+
+# -- host pauses --------------------------------------------------------------
+
+#: A collection shorter than this is counted and leaves no span.
+GC_SPAN_FLOOR_NS = 1_000_000
+GC_GENERATIONS = 3
+
+
+class HostPauseTracker:
+    """What stopped the host, process-wide and on ``perf_counter_ns`` (the
+    clock of :class:`StepClock` and of a STEP record's ``t0_ns``): the cyclic
+    garbage collector, which holds the interpreter on whatever thread it runs,
+    and the profiler's own start and stop.
+
+    Every collection adds to the plain-integer counters by generation; one of
+    :data:`GC_SPAN_FLOOR_NS` or more is also a pause. A pause is kept in
+    :attr:`recent` as ``(t0_ns, dur_ns, cause, generation)`` for the engine's
+    long-step lookup and becomes a ``host_pause`` span in :data:`SPANS`. The
+    span is written by :meth:`flush`, never by the collector's callback: a
+    collection can begin under any allocation, one made while holding the span
+    ring's lock too.
+    """
+
+    def __init__(self) -> None:
+        self.gc_count = [0] * GC_GENERATIONS
+        self.gc_ns = [0] * GC_GENERATIONS
+        self.recent: deque[tuple[int, int, str, int]] = deque(maxlen=64)
+        #: ``perf_counter_ns`` at the start of the profiler call that is running (0: none).
+        self.profiler_since_ns = 0
+        self.installed = False
+        #: Pauses whose span is not written yet (bounded: nobody may come to flush).
+        self.pending: deque[tuple[int, int, dict]] = deque(maxlen=256)
+        self._gc_t0 = 0
+        self._gc_region = None
+
+    def install(self) -> None:
+        """Hook the collector (idempotent)."""
+        if not self.installed:
+            gc.callbacks.append(self._on_gc)
+            self.installed = True
+
+    def uninstall(self) -> None:
+        """Leave ``gc.callbacks`` as :meth:`install` found it (tests)."""
+        if self.installed:
+            gc.callbacks.remove(self._on_gc)
+            self.installed = False
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # One collection runs at a time, so a start and its stop pair up.
+        if phase == "start":
+            if _annotating:
+                import jax
+
+                self._gc_region = jax.profiler.TraceAnnotation("host.gc")  # never "engine.*"
+                self._gc_region.__enter__()
+            self._gc_t0 = time.perf_counter_ns()
+            return
+        dur = time.perf_counter_ns() - self._gc_t0
+        gen = info["generation"]
+        self.gc_count[gen] += 1
+        self.gc_ns[gen] += dur
+        if self._gc_region is not None:
+            region, self._gc_region = self._gc_region, None
+            region.__exit__(None, None, None)
+        if dur >= GC_SPAN_FLOOR_NS:
+            self.note("gc", self._gc_t0, dur, generation=gen, collected=info["collected"],
+                      uncollectable=info["uncollectable"])
+
+    def note(self, cause: str, t0_ns: int, dur_ns: int, *, generation: int = -1, **fields: Any) -> None:
+        """Keep one pause; its span waits for :meth:`flush`."""
+        self.recent.append((t0_ns, dur_ns, cause, generation))
+        if generation >= 0:
+            fields["generation"] = generation
+        fields.update(cause=cause, t0_ns=t0_ns, thread=threading.current_thread().name)
+        self.pending.append((t0_ns, dur_ns, fields))
+
+    @contextlib.contextmanager
+    def profiler(self, what: str) -> Iterator[None]:
+        """Round ``jax.profiler.start_trace`` / ``stop_trace``."""
+        self.profiler_since_ns = t0 = time.perf_counter_ns()
+        try:
+            yield
+        finally:
+            self.profiler_since_ns = 0
+            self.note("profiler", t0, time.perf_counter_ns() - t0, what=what)
+            self.flush()
+
+    def flush(self) -> None:
+        """Write the kept pauses' spans (``request_id`` ``host_pause``, so that
+        ``/debug/traces/host_pause`` lists them)."""
+        while self.pending:
+            try:
+                t0_ns, dur_ns, fields = self.pending.popleft()
+            except IndexError:  # another thread flushed it
+                return
+            record_span("host_pause", dur_ns / 1e6, start_mono=t0_ns / 1e9, request_id="host_pause", **fields)
+
+    def overlap_ms(self, lo_ns: int, hi_ns: int) -> tuple[float, int, float]:
+        """``(gc_ms, oldest generation among them or -1, profiler_ms)`` of the
+        kept pauses, and of a profiler call still running, inside ``[lo_ns, hi_ns]``."""
+        gc_ns = prof_ns = 0
+        oldest = -1
+        for t0, dur, cause, gen in tuple(self.recent):
+            cover = min(hi_ns, t0 + dur) - max(lo_ns, t0)
+            if cover <= 0:
+                continue
+            if cause == "gc":
+                gc_ns += cover
+                oldest = max(oldest, gen)
+            else:
+                prof_ns += cover
+        since = self.profiler_since_ns
+        if since:
+            prof_ns += max(0, hi_ns - max(lo_ns, since))
+        return gc_ns / 1e6, oldest, prof_ns / 1e6
+
+
+#: The process's tracker. The profiler's pauses are always noted; the
+#: collector's once :func:`install_host_pauses` has run.
+HOST_PAUSES = HostPauseTracker()
+
+
+def install_host_pauses() -> HostPauseTracker:
+    """Hook the process's tracker to the collector (the first ``EngineCore`` calls it)."""
+    HOST_PAUSES.install()
+    return HOST_PAUSES
+
+
+def uninstall_host_pauses() -> None:
+    HOST_PAUSES.uninstall()
 
 
 def trace_of(context: Any) -> TraceContext | None:

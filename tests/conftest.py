@@ -123,6 +123,25 @@ def pytest_pyfunc_call(pyfuncitem):
     return None
 
 
+@pytest.fixture
+def host_pauses(monkeypatch):
+    """A host-pause tracker and a span ring of the test's own: the process's
+    tracker is unhooked from the collector meanwhile and hooked back after."""
+    from dynamo_tpu import tracing
+
+    real = tracing.HOST_PAUSES
+    was_installed = real.installed
+    real.uninstall()
+    monkeypatch.setattr(tracing, "HOST_PAUSES", tracing.HostPauseTracker())
+    monkeypatch.setattr(tracing, "SPANS", tracing.SpanBuffer(1024))
+    try:
+        yield tracing.HOST_PAUSES
+    finally:
+        tracing.HOST_PAUSES.uninstall()
+        if was_installed:
+            real.install()
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     import jax

@@ -375,8 +375,7 @@ def test_bench_doc_fleet_sim_keys():
 
 def test_bench_doc_quant_and_mask_keys():
     """Roofline burn-down keys (ISSUE 16): the quant-mode sweep and the
-    vectorized-mask probe surface stable `_gain`/`_ms` suffixed keys (so
-    tools/bench_regress.py derives direction without a schema change) and
+    vectorized-mask probe surface stable `_gain`/`_ms` suffixed keys and
     detail records; absent probes keep 0.0 defaults."""
     import bench
 

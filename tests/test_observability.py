@@ -153,6 +153,8 @@ class _FakeCore:
     step_wall_ms_total = 4000.0
     step_dispatch_ms_total = 3000.0
     step_kind_counts = {"mixed": 5, "decode": 30}
+    long_steps = {"gc": 2, "": 1}
+    long_step_lost_ms = {"gc": 210.0, "": 95.0}
     sentinel = SimpleNamespace(
         active={"recompile_storm": {"value": 9.0, "threshold": 8.0, "since_step": 300}},
         fired={"recompile_storm": 2},
@@ -251,6 +253,16 @@ EXPECTED_ENGINE_FAMILIES = {
     "dynamo_engine_step_kind_steps_created",
     "dynamo_anomaly_active",
     "dynamo_anomaly_fired_total",
+    # Long steps by cause and the process's collections by generation
+    # (ISSUE 38): Counters, delta-synced like the ledgers above.
+    "dynamo_engine_long_steps_total",
+    "dynamo_engine_long_steps_created",
+    "dynamo_engine_long_step_lost_seconds_total",
+    "dynamo_engine_long_step_lost_seconds_created",
+    "dynamo_host_gc_pauses_total",
+    "dynamo_host_gc_pauses_created",
+    "dynamo_host_gc_pause_seconds_total",
+    "dynamo_host_gc_pause_seconds_created",
     "dynamo_kv_transfer_phase_seconds",
     # prometheus_client emits the histogram's _created timestamps as their
     # own gauge family once a labelled child exists.
@@ -293,6 +305,10 @@ async def test_engine_metrics_names_labels_and_values():
     assert 'dynamo_engine_spec_tokens_proposed_total{worker="w1"} 20.0' in text
     assert 'dynamo_engine_spec_tokens_accepted_total{worker="w1"} 9.0' in text
     assert 'dynamo_engine_step_gap_ms{worker="w1"} 0.75' in text
+    assert 'dynamo_engine_long_steps_total{cause="gc",worker="w1"} 2.0' in text
+    assert 'dynamo_engine_long_steps_total{cause="unnamed",worker="w1"} 1.0' in text
+    assert 'dynamo_engine_long_step_lost_seconds_total{cause="gc",worker="w1"} 0.21' in text
+    assert 'dynamo_host_gc_pauses_total{generation="2",worker="w1"}' in text
     assert 'dynamo_engine_step_gap_ms_mean{worker="w1"} 1.25' in text
     assert 'dynamo_engine_overlap_steps_total{mode="overlapped",worker="w1"} 6.0' in text
     assert 'dynamo_engine_overlap_steps_total{mode="barrier",worker="w1"} 2.0' in text
@@ -454,48 +470,6 @@ def test_barrier_reasons_synced():
     assert extras == ("queue", "admission", "onboard_stall", "preempt", "recompile", "gap")
     assert loss == tuple(declared) + extras
     assert check_barrier_reasons.check_loss_causes(declared, loss, extras, doc_loss) == []
-
-
-def test_bench_regress_gate(tmp_path, monkeypatch):
-    """Invokes the tools/ bench-trajectory gate (ISSUE 15 satellite): the
-    newest committed BENCH_r*.json round must hold the trajectory, with
-    direction-aware tolerances and the documented waiver knob."""
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent / "tools"))
-    try:
-        import bench_regress
-    finally:
-        sys.path.pop(0)
-    # Direction table: throughput-like keys gate downward movement,
-    # latency-like keys gate upward movement, unknown keys never gate.
-    assert bench_regress.direction("decode_tokens_per_sec_per_chip") == 1
-    assert bench_regress.direction("loss_coverage_frac") == 1
-    assert bench_regress.direction("ttft_ms") == -1
-    assert bench_regress.direction("decode_idle_frac") == -1
-    assert bench_regress.direction("mystery_key") == 0
-    # Tail recovery: a parsed=null wrapper falls back to the last JSON line
-    # of the tail; an unusable tail yields no document (round skipped).
-    doc = bench_regress._recover_doc(
-        {"parsed": None, "tail": 'noise\n{"value": 2.0}\ntrailing'}
-    )
-    assert doc == {"value": 2.0}
-    assert bench_regress._recover_doc({"parsed": None, "tail": "junk"}) is None
-
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"n": 1, "parsed": {"value": 100.0, "ttft_ms": 10.0, "odd": 1.0}}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(
-        {"n": 2, "parsed": {"value": 60.0, "ttft_ms": 14.0, "odd": 9.0}}))
-    regressions, notes = bench_regress.compare(
-        bench_regress.load_rounds(tmp_path), tolerance=0.25)
-    assert any(r.startswith("value:") for r in regressions)  # 60 < 100 * 0.75
-    assert any(r.startswith("ttft_ms:") for r in regressions)  # 14 > 10 * 1.25
-    assert any("odd" in n for n in notes)  # unknown direction stays advisory
-    monkeypatch.setenv("DYN_BENCH_REGRESS_WAIVE", "value")
-    assert [r.split(":")[0] for r in bench_regress.check(tmp_path)] == ["ttft_ms"]
-    monkeypatch.setenv("DYN_BENCH_REGRESS_WAIVE", "all")
-    assert bench_regress.check(tmp_path) == []
-    # The committed history itself must hold (this is the CI wiring).
-    monkeypatch.delenv("DYN_BENCH_REGRESS_WAIVE", raising=False)
-    assert bench_regress.check() == []
 
 
 # -- latency attribution (ISSUE 15 tentpole) ----------------------------------

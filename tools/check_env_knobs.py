@@ -28,7 +28,7 @@ _QUOTED_KNOB = re.compile(r"[\"'](DYN_[A-Z0-9_]*)[\"']")
 
 #: Source files scanned for knob literals: the package, the top-level bench
 #: harness (its BENCH_* knobs are out of scope; its DYN_* reads are not),
-#: and the operator tools (bench_regress.py reads DYN_BENCH_REGRESS_*).
+#: and the operator tools.
 _SOURCE_GLOBS = [("dynamo_tpu", "**/*.py"), (".", "bench.py"), ("tools", "*.py")]
 #: Docs scanned for the documented set — every env table the project keeps.
 _DOC_GLOBS = [("docs", "*.md"), (".", "README.md")]
