@@ -749,6 +749,7 @@ class EngineCore:
                 moe_choices_zero=report.moe_counts[1],  # because a ** in the middle takes the whole call
                 moe_choices_held=report.moe_counts[2],  # off the interpreter's fast path (0.015 ms a step
                 moe_experts_touched=report.moe_counts[3],  # on a v5e's host: PERF.md, PR 34)
+                moe_extra_passes=report.moe_counts[4],
                 layout=report.layout,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),

@@ -29,6 +29,7 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.observability.compile import CompileTracker, timed_dispatch
 from dynamo_tpu.ops.sampling import sample_tokens
+from dynamo_tpu.parallel.moe import HELD_COUNTS
 
 logger = logging.getLogger(__name__)
 
@@ -321,7 +322,7 @@ class DispatchReport:
     # parallel/moe.HELD_COUNTS, counted on the device by the programs of a model
     # whose expert layer holds a share or has identity experts, and summed over
     # the programs whose outputs had reached the host when the report was taken.
-    moe_counts: tuple[int, int, int, int] = (0, 0, 0, 0)
+    moe_counts: tuple[int, ...] = (0,) * len(HELD_COUNTS)
 
 
 class ModelRunner:
@@ -1078,14 +1079,14 @@ class ModelRunner:
         self._moe_counts_pending.append(counts)
         return tuple(out)
 
-    def _ready_moe_counts(self) -> tuple[int, int, int, int]:
+    def _ready_moe_counts(self) -> tuple[int, ...]:
         """The pending counters of the programs that have ended, summed and
         dropped: a synchronous step's own, a pipelined step's predecessor's."""
         pending, ready = self._moe_counts_pending, 0
         while ready < len(pending) and pending[ready].is_ready():
             ready += 1
         self._moe_counts_pending = pending[ready:]
-        total = np.sum([np.asarray(c) for c in pending[:ready]], axis=0, dtype=np.int64) if ready else np.zeros(4)
+        total = np.sum([np.asarray(c) for c in pending[:ready]], axis=0, dtype=np.int64) if ready else np.zeros(len(HELD_COUNTS))
         return tuple(int(v) for v in total)
 
     @property

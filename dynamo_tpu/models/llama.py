@@ -631,7 +631,11 @@ def forward(
     # layer counter (cache offsets) carried straight through.
     # A model that holds a share of its experts (or has identity experts)
     # carries its expert layers' HELD_COUNTS beside the stream, summed.
-    carry = (x, kf0, vf0, jnp.int32(0)) + ((jnp.zeros((4,), jnp.int32),) if cfg.moe_held_share else ())
+    carry = (x, kf0, vf0, jnp.int32(0))
+    if cfg.moe_held_share:
+        from dynamo_tpu.parallel.moe import HELD_COUNTS
+
+        carry += (jnp.zeros((len(HELD_COUNTS),), jnp.int32),)
 
     def scanned(layers, lo: int, hi: int):
         if layer_kinds is None:

@@ -56,7 +56,8 @@ STEP_KEYS = (
     "spec_drafted", "spec_accepted", "spec_accept_rate", "wall_ms",
     "dispatch_ms", "attn_phase", "attn_path", "moe_path",
     "kv_tokens_full", "kv_tokens_window", "step_tokens",
-    "moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched", "layout",
+    "moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched",
+    "moe_extra_passes", "layout",
     "admitted", "deferred", "deadline_slack_ms", "cached_frac", "gap_ms",
     "overlap_mode", "barrier_reason", "chained_rows",
     "t0_ns", "ann_ns", "traced", "phases_us",

@@ -285,12 +285,19 @@ def moe_mlp_dropless(
 
 
 #: Counters a held-share expert layer returns beside its output, in this order.
-HELD_COUNTS = ("moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched")
+HELD_COUNTS = ("moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched", "moe_extra_passes")
+
+#: Token copies (tokens x choices) up to which a held-share layer lays its
+#: copies out by dense arithmetic on one-hots (a decode or a mixed step: a few
+#: fused operations whose work grows with the square of the copies); beyond
+#: (a prefill-sized call) by a sort, a gather and a scatter-add. The largest
+#: size timed on a v5e: 128 tokens x 12 choices (PERF.md, PR 39).
+DENSE_COPIES = 1536
 
 
 def held_rows_cap(copies: int, held: int, outputs: int) -> int:
-    """Rows one pass of the held experts takes: twice the copies even routing
-    sends here, in whole 128-row tiles, and never more than there are."""
+    """Rows the usual pass of the held experts takes: twice the copies even
+    routing sends here, in whole 128-row tiles, and never more than there are."""
     even = -(-2 * copies * held // max(1, outputs))
     return min(-(-copies // 16) * 16, -(-max(even, 1) // 128) * 128)
 
@@ -316,16 +323,26 @@ def moe_mlp_held(
     here, costs no work and reads no weight: the holders' parts, with the
     identity term counted once, sum to the whole layer.
 
-    The copies that landed here are sorted to the front by expert and taken
-    ``held_rows_cap`` rows at a pass (one pass unless routing is far from
-    even; none where nothing landed), through the int8 kernel where
-    :func:`experts_path` says so. Returns ``(out [N, D], counts i32[4])``,
-    the counts as :data:`HELD_COUNTS` names them, over ``valid`` tokens.
+    A copy's row is its place in the stable order by held expert (copies held
+    elsewhere last), so the experts see their copies grouped, through the int8
+    kernel where :func:`experts_path` says so. One pass takes the first
+    ``held_rows_cap`` rows; where more copies landed here than that (routing
+    far from even) the other arm of one ``cond`` takes them all: no loop, and
+    exact either way. Up to :data:`DENSE_COPIES` copies the rows are found
+    without a sort (a copy's row is the number of copies before it in that
+    order: one comparison of every pair), filled by a 0/1 product and added
+    back by a product with the weights in float32 (``HIGHEST``: the terms and
+    their sums as a float32 scatter-add has them); beyond, by ``argsort``,
+    gather and scatter-add. Returns ``(out [N, D], counts i32[5])``, the
+    counts as :data:`HELD_COUNTS` names them, over ``valid`` tokens; the last
+    is 1 where the pass over every copy's rows ran.
     """
     n, d = x.shape
     k = num_experts_per_token
+    copies = n * k
     held = jax.tree.leaves(lp["w_gate"])[0].shape[-3]
     fused = experts_path(lp, mesh=mesh) == "fused"
+    dense = copies <= DENSE_COPIES
     if valid is None:
         valid = jnp.ones((n,), bool)
 
@@ -334,50 +351,70 @@ def moe_mlp_held(
         local = topi - first
         here = (local >= 0) & (local < held) & valid[:, None]  # [N, k]
         key = jnp.where(here, local, held).reshape(-1)  # copies held elsewhere sort last
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        ends = jnp.cumsum(sizes)
-        n_here = ends[-1]
         is_zero = topi >= routed
-        counts = jnp.stack([valid.sum() * k, (is_zero & valid[:, None]).sum(), n_here, (sizes > 0).sum()]).astype(jnp.int32)
+        if dense:
+            # Keys made distinct by the copy's index: a copy's row is how many keys are smaller.
+            distinct = key * copies + jnp.arange(copies, dtype=jnp.int32)
+            row = (distinct[None, :] < distinct[:, None]).sum(axis=1, dtype=jnp.int32).reshape(n, k)
+            sizes = (key[:, None] == jnp.arange(held, dtype=jnp.int32)).sum(axis=0, dtype=jnp.int32)
+        else:
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        # Choices, identity choices and held choices of the valid tokens: per token, then one sum
+        # (a sum over both axes at once compiles to three more operations a layer).
+        tallies = jnp.stack([jnp.full((n,), k, jnp.int32), is_zero.sum(axis=1, dtype=jnp.int32),
+                             here.sum(axis=1, dtype=jnp.int32)], axis=1)
+        tallies = jnp.where(valid[:, None], tallies, 0).sum(axis=0)
+        n_here = tallies[2]
+        cap = held_rows_cap(copies, held, lp["router"].shape[-1])
+        usual = n_here <= cap  # else the pass over every copy's rows: counted, a routing far from even
+        counts = jnp.concatenate([tallies, (sizes > 0).sum(dtype=jnp.int32)[None], (~usual).astype(jnp.int32)[None]])
 
     if lp["router"].shape[-1] > routed:
         with jax.named_scope("moe.zero"):
             zero_w = jnp.where(is_zero, weights, 0.0).sum(axis=-1)  # f32[N]
-            out = x.astype(jnp.float32) * zero_w[:, None]
+            zero = x.astype(jnp.float32) * zero_w[:, None]
     else:  # a router without identity outputs: the held experts' terms are all there is
-        out = jnp.zeros((n, d), jnp.float32)
+        zero = None
 
-    cap = held_rows_cap(n * k, held, lp["router"].shape[-1])
-    order = jnp.pad(order, (0, -(n * k) % cap))
-    flat_w = weights.reshape(-1)
     if not fused:
         w_gate, w_up, w_down = _widen(lp, x.dtype)
 
-    def one_pass(i, acc):
-        lo = i * cap
-        idx = jax.lax.dynamic_slice(order, (lo,), (cap,))
-        tok = idx // k
-        rows = x[tok]  # [cap, D] grouped by expert
-        in_pass = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+    def one_pass(m: int) -> jnp.ndarray:
+        """The layer's output from the first ``m`` rows: the held experts'
+        terms summed in float32, the identity term added, in ``x``'s dtype (a
+        ``cond``'s result crosses HBM: half the bytes)."""
+        with jax.named_scope("moe.dispatch"):
+            if dense:
+                at = row[None, :, :] == jnp.arange(m, dtype=jnp.int32)[:, None, None]  # [m, N, k]: one 1 a row
+                rows = jnp.dot(at.any(axis=2).astype(x.dtype), x, preferred_element_type=x.dtype)
+            else:
+                idx = jnp.pad(order, (0, max(0, m - copies)))[:m]
+                rows = x[idx // k]
         if fused:
             from dynamo_tpu.ops.pallas_moe import expert_ffn_int8
             from dynamo_tpu.ops.pallas_paged import interpret_mode
 
-            down = expert_ffn_int8(rows, lp["w_gate"], lp["w_up"], lp["w_down"], in_pass,
+            down = expert_ffn_int8(rows, lp["w_gate"], lp["w_up"], lp["w_down"], sizes,
                                    lp.get("expert_layer"), interpret=interpret_mode())
         else:
             with jax.named_scope("moe.experts_gate_up"):
-                hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, in_pass)) * jax.lax.ragged_dot(rows, w_up, in_pass)
+                hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) * jax.lax.ragged_dot(rows, w_up, sizes)
             with jax.named_scope("moe.experts_down"):
-                down = jax.lax.ragged_dot(hidden, w_down, in_pass)
+                down = jax.lax.ragged_dot(hidden, w_down, sizes)
         with jax.named_scope("moe.combine"):
-            live = (lo + jnp.arange(cap)) < n_here  # rows past the held copies were never computed
-            term = jnp.where(live[:, None], down.astype(jnp.float32) * flat_w[idx][:, None], 0.0)
-            return acc.at[tok].add(term)
+            live = jnp.arange(m) < n_here  # rows past the held copies were never computed
+            down = jnp.where(live[:, None], down.astype(jnp.float32), 0.0)
+            if dense:
+                mix = jnp.where(at, weights[None, :, :], 0.0).sum(axis=2)  # f32[m, N]: a row's weight at its token
+                terms = jnp.einsum("mn,md->nd", mix, down, precision=jax.lax.Precision.HIGHEST)
+            else:
+                terms = jnp.zeros((n, d), jnp.float32).at[idx // k].add(down * weights.reshape(-1)[idx][:, None])
+            return (terms if zero is None else zero + terms).astype(x.dtype)
 
-    out = jax.lax.fori_loop(0, -(-n_here // cap), one_pass, out)
-    return out.astype(x.dtype), counts
+    if cap >= copies:
+        return one_pass(cap), counts
+    return jax.lax.cond(usual, lambda: one_pass(cap), lambda: one_pass(-(-copies // 16) * 16)), counts
 
 
 def expert_capacity(num_tokens: int, num_experts: int, k: int, capacity_factor: float) -> int:

@@ -408,8 +408,9 @@ def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
     decode slots + one 64-token chunk slot, over 16 pages. One layer body: the
     two MLA sublayers' attention through the MLA kernel (a chunk's 64 queries
     in tiles of 8: 16 overran the scoped VMEM), the held experts through the
-    grouped int8 kernel at its 6144 x 2048 tiles inside the pass loop, and no
-    expert array copied outside it."""
+    grouped int8 kernel at its 6144 x 2048 tiles in each arm of the layer's
+    ``cond`` (the usual pass; every copy's rows), and no expert array copied
+    outside it."""
     import functools
     import json
     import pathlib
@@ -437,8 +438,8 @@ def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
         params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=k_cache, v_cache=v_cache,
         block_tables=i32(slots, 16), slot_mapping=i32(*toks), last_token_index=i32(slots),
     ).compile().as_text()
-    # One layer body: an MLA call per sublayer (two per sublayer on the split axis) and the expert FFN's two.
-    assert text.count('custom_call_target="tpu_custom_call"') == (4 if split is None else 6)
+    # One layer body: an MLA call per sublayer (two per sublayer on the split axis) and the expert FFN's two an arm.
+    assert text.count('custom_call_target="tpu_custom_call"') == (6 if split is None else 8)
     assert "mla_paged_decode_attention" in text and text.count("moe_grouped_matmul_int8") >= 2
     # The held experts stay where they are: no [16, 6144, 2048] array is produced outside the kernel.
     made = re.findall(r"= s8\[(?:1,)?16,(?:6144,2048|2048,6144)\]\S* (?!parameter|get-tuple-element|bitcast)(\w[\w-]*)\(", text)
@@ -452,9 +453,9 @@ def test_plain_held_share_step_joyai_largest_corners(sds, monkeypatch, split):
     vocabulary left out): 64 decode rows, and 64 decode slots + one 64-token
     chunk slot, over 16 pages. The dual scan: every layer's attention through
     the MLA kernel at 32 heads, the held experts through the grouped int8
-    kernel at its 2048 x 768 tiles inside the pass loop by a layer index
-    counted from the first MoE layer, no expert array copied outside it, and
-    the four counters beside the outputs."""
+    kernel at its 2048 x 768 tiles in each arm of the layer's ``cond`` by a
+    layer index counted from the first MoE layer, no expert array copied
+    outside it, and the five counters beside the outputs."""
     import functools
     import re
 
@@ -484,7 +485,7 @@ def test_plain_held_share_step_joyai_largest_corners(sds, monkeypatch, split):
     # The held experts stay where they are: no [32, 2048, 768] array is produced outside the kernel.
     made = re.findall(r"= s8\[(?:1,)?32,(?:2048,768|768,2048)\]\S* (?!parameter|get-tuple-element|bitcast)(\w[\w-]*)\(", text)
     assert not made, made
-    assert [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)][-1] == (4,)  # HELD_COUNTS beside the outputs
+    assert [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)][-1] == (5,)  # HELD_COUNTS beside the outputs
 
 
 # -- weights read where they lie (ISSUE 35, models/quant.held_flat) -----------
@@ -507,6 +508,44 @@ def _benchmark_config(name: str, layers: int, vocab: int = 8192):
     return serving.model_config(conf)
 
 
+_STEP_TEXTS: dict = {}
+
+
+def _two_layer_step_text(sds, monkeypatch, config: str, rows: int, mixed: bool, held: bool = True) -> str:
+    """Two layers of a benchmark configuration at its published widths, the
+    decode step or the chunk step as served (``rows`` decode slots + one
+    64-token chunk slot), compiled for the described chip; a text is compiled
+    once for the tests of this file."""
+    key = (config, rows, mixed, held)
+    if key in _STEP_TEXTS:
+        return _STEP_TEXTS[key]
+
+    import functools
+
+    from dynamo_tpu.models import llama, mla
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    if not held:
+        monkeypatch.setattr(llama, "held_flat", lambda y: y)
+        monkeypatch.setattr(mla, "held_flat", lambda y: y)
+    cfg = _benchmark_config(config, layers=2)
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    pages_per_seq = 16
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, (rows + 1) * pages_per_seq + 1, 128)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    split = (rows, 1, 64) if mixed else None
+    toks, slots = ((rows + 64,), rows + 1) if mixed else ((rows, 1), rows)
+    counted = {"moe_counts": True} if cfg.moe_held_share else {}
+    _STEP_TEXTS[key] = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas", split=split, **counted)).lower(
+        params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=k_cache, v_cache=v_cache,
+        block_tables=i32(slots, pages_per_seq), slot_mapping=i32(*toks), last_token_index=i32(slots),
+    ).compile().as_text()
+    return _STEP_TEXTS[key]
+
+
 @pytest.mark.parametrize("config, rows, mixed, held", [
     ("mellum2-12b-a2.5b-int8", 8, False, True),
     ("mellum2-12b-a2.5b-int8", 8, True, True),
@@ -526,30 +565,9 @@ def test_step_programs_relay_no_int8_weight(sds, monkeypatch, config, rows, mixe
     stack by the layer's index, in the layout it is stored in. Without
     ``held_flat`` Mellum2's body slices ``wq`` and ``wk`` out of the stack and
     copies each transposed: 21 MB a layer."""
-    import functools
-
-    from dynamo_tpu.models import llama, mla
-    from dynamo_tpu.models.quant import init_params_quantized
-    from dynamo_tpu.parallel import moe
     from tests.test_step_relayouts import load_tool
 
-    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
-    if not held:
-        monkeypatch.setattr(llama, "held_flat", lambda y: y)
-        monkeypatch.setattr(mla, "held_flat", lambda y: y)
-    cfg = _benchmark_config(config, layers=2)
-    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
-    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
-    pages_per_seq = 16
-    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, (rows + 1) * pages_per_seq + 1, 128)))
-    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
-    split = (rows, 1, 64) if mixed else None
-    toks, slots = ((rows + 64,), rows + 1) if mixed else ((rows, 1), rows)
-    counted = {"moe_counts": True} if cfg.moe_held_share else {}
-    text = jax.jit(functools.partial(llama.forward, cfg=cfg, attn_impl="pallas", split=split, **counted)).lower(
-        params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=k_cache, v_cache=v_cache,
-        block_tables=i32(slots, pages_per_seq), slot_mapping=i32(*toks), last_token_index=i32(slots),
-    ).compile().as_text()
+    text = _two_layer_step_text(sds, monkeypatch, config, rows, mixed, held)
     assert text.count("moe_grouped_matmul_int8") >= 2  # the chip's program, not the CPU's widened one
     relaid = [(op["name"], op["shape"], op["reads"], op["writes"]) for op in load_tool().relayouts(text) if op["dtype"] == "s8"]
     if held:
@@ -558,3 +576,33 @@ def test_step_programs_relay_no_int8_weight(sds, monkeypatch, config, rows, mixe
         shapes = {shape for _, shape, _, _ in relaid}
         assert {"s8[1,2304,4096]", "s8[1,2304,512]"} <= shapes, relaid
         assert any(reads and "{2,1,0}" in reads[0] and writes == "{1,2,0}" for _, _, reads, writes in relaid), relaid
+
+
+
+@pytest.mark.parametrize("config, held_share, at_most", [
+    ("joyai-llm-flash-ep8-int8", True, 155),  # 151 here; 124 in the body + 41 in its loop before ISSUE 39
+    ("longcat-flash-chat-ep32-int8", True, 242),  # 237 here; 215 + 42 before
+    ("olmoe-1b-7b-int8", False, 0),  # the control: the dropless layer sorts its copies and scatters them back
+], ids=lambda v: str(v))
+def test_held_layer_routes_a_decode_step_without_sort_scatter_or_loop(sds, monkeypatch, config, held_share, at_most):
+    """The 64-row decode step's layer body, two layers, compiled for the
+    described chip (``tools/step_relayouts.body_counts``): a layer that holds a
+    share of its experts sorts once (the router's ``top_k``), scatters nothing
+    under a ``moe.`` scope, nests no loop in the layer scan, takes the usual
+    pass and the all-rows pass as the two arms of one conditional, and
+    executes at most ``at_most`` instructions a layer (the body and one arm;
+    two layers compile to a longer body than the benchmark's 40 or 7:
+    ``tools/step_relayouts.py --count`` reads 124 and 204 there, 153 and 223
+    before).
+    OLMoE's dropless layer is what the assertions would miss: two sorts and two
+    scatters."""
+    from tests.test_step_relayouts import load_tool
+
+    counts = load_tool().body_counts(_two_layer_step_text(sds, monkeypatch, config, 64, False))
+    if not held_share:
+        assert len(counts["sorts"]) == 2 and len(counts["moe_scatters"]) == 2, counts
+        return
+    assert len(counts["sorts"]) == 1 and not counts["moe_scatters"], counts
+    assert counts["nested"]["while"] == 0 and len(counts["arms"]) == 1 and len(counts["arms"][0]) == 2, counts
+    assert counts["by_scope"].get("moe.experts/cond") and "moe.experts" not in counts["by_scope"], counts
+    assert 0 < counts["executed"] <= at_most, counts

@@ -286,7 +286,7 @@ def test_counters_match_a_count_by_hand_and_leave_padding_out():
     kw = dict(num_experts_per_token=4, first=4, routed=16, routing=llama._routing_kwargs(cfg))
     out, counts = moe.moe_mlp_held(lp, h, valid=valid, **kw)
     mine = np.asarray(ref.route(h, lp, ref.shape_of(TOY_HF)))[:20, 4:8] > 0
-    assert counts.tolist() == [20 * 4, 0, int(mine.sum()), int(mine.any(axis=0).sum())]
+    assert counts.tolist() == [20 * 4, 0, int(mine.sum()), int(mine.any(axis=0).sum()), 0]
     assert 0 < int(counts[2]) < 20 * 4
     out_all, _ = moe.moe_mlp_held(lp, h, **kw)
     np.testing.assert_allclose(out[:20], out_all[:20], atol=1e-6)  # a token's result does not turn on its neighbours
@@ -310,11 +310,159 @@ def test_step_records_carry_the_plain_bodys_counts(overlap):
     assert sum(s["moe_choices_zero"] for s in steps) == 0
     assert 0.12 < sum(s["moe_choices_held"] for s in steps) / choices < 0.4
     assert all(s["moe_experts_touched"] <= 4 * 2 and s["moe_choices_held"] <= s["moe_choices"] for s in steps)
+    assert all(s["moe_extra_passes"] == 0 for s in steps)  # near-even routing: the usual pass held every copy that landed here
     assert any(s["kv_tokens_full"] > 0 for s in steps if s["step_kind"] == "decode")
     assert not core.runner._moe_counts_pending or overlap
 
 
 # -- the dual scan and the int8 stack ----------------------------------------------
+
+
+# -- the routing bookkeeping against the sort, the gather and the scatter-add it replaced --------
+
+
+def _held_by_sort(lp, x, *, num_experts_per_token, first, routed, routing, valid, passes):
+    """``moe_mlp_held`` as it stood before ISSUE 39, kept as the plain
+    reference: the copies that landed here sorted to the front by expert
+    (``argsort``, ``bincount``), taken ``held_rows_cap`` rows at a pass in a
+    loop whose trip count the routing decides, gathered, and added back by a
+    scatter-add. ``passes`` gets every pass's ``(first row, rows, group sizes)``."""
+    n, d = x.shape
+    k = num_experts_per_token
+    held = jax.tree.leaves(lp["w_gate"])[0].shape[-3]
+    fused = moe.experts_path(lp) == "fused"
+    weights, topi = moe.route_tokens(lp, x, k=k, f32_logits=True, **routing)
+    local = topi - first
+    here = (local >= 0) & (local < held) & valid[:, None]
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    n_here = ends[-1]
+    is_zero = topi >= routed
+    counts = jnp.stack([valid.sum() * k, (is_zero & valid[:, None]).sum(), n_here, (sizes > 0).sum()]).astype(jnp.int32)
+    if lp["router"].shape[-1] > routed:
+        out = x.astype(jnp.float32) * jnp.where(is_zero, weights, 0.0).sum(axis=-1)[:, None]
+    else:
+        out = jnp.zeros((n, d), jnp.float32)
+    cap = moe.held_rows_cap(n * k, held, lp["router"].shape[-1])
+    order = jnp.pad(order, (0, -(n * k) % cap))
+    flat_w = weights.reshape(-1)
+    if not fused:
+        w_gate, w_up, w_down = moe._widen(lp, x.dtype)
+
+    def one_pass(i, acc):
+        lo = i * cap
+        idx = jax.lax.dynamic_slice(order, (lo,), (cap,))
+        tok = idx // k
+        rows = x[tok]
+        in_pass = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+        jax.debug.callback(lambda lo, rows, sizes: passes.append((int(lo), np.asarray(rows), np.asarray(sizes))),
+                           lo, rows, in_pass)
+        if fused:
+            from dynamo_tpu.ops.pallas_moe import expert_ffn_int8
+
+            down = expert_ffn_int8(rows, lp["w_gate"], lp["w_up"], lp["w_down"], in_pass, interpret=True)
+        else:
+            hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, in_pass)) * jax.lax.ragged_dot(rows, w_up, in_pass)
+            down = jax.lax.ragged_dot(hidden, w_down, in_pass)
+        live = (lo + jnp.arange(cap)) < n_here
+        term = jnp.where(live[:, None], down.astype(jnp.float32) * flat_w[idx][:, None], 0.0)
+        return acc.at[tok].add(term)
+
+    out = jax.lax.fori_loop(0, -(-n_here // cap), one_pass, out)
+    return out.astype(x.dtype), counts
+
+
+#: (tokens, routing, valid tokens or None for all, routed outputs, arm). Routing: ``even`` as the seed has it;
+#: ``all_here`` every choice of every token on the four held experts (more copies than the usual pass takes:
+#: the other arm of the ``cond``); ``none_here`` no choice on them. Routed outputs 12 of 16: four identity outputs.
+#: Arms: ``ragged`` float32 ``ragged_dot``; ``fused`` the int8 kernel in interpret mode on bf16 tokens; ``sorted``
+#: the form prefill-sized calls keep (``DENSE_COPIES`` 0), ``ragged_dot``.
+PARITY_CASES = (
+    [(tokens, how, None, 16, "ragged") for tokens in (1, 8, 64, 128) for how in ("even", "all_here", "none_here")]
+    + [(8, "even", 5, 16, "ragged"), (8, "all_here", 5, 16, "ragged"), (64, "even", 40, 16, "ragged"),
+       (64, "all_here", 40, 16, "ragged"), (64, "even", None, 12, "ragged"), (64, "all_here", 40, 12, "ragged"),
+       (64, "even", None, 16, "fused"), (64, "all_here", None, 16, "fused"), (128, "even", None, 16, "fused"),
+       (8, "even", 5, 16, "fused"), (64, "even", 40, 12, "fused"),
+       (64, "even", 40, 12, "sorted"), (64, "all_here", None, 16, "sorted"), (8, "none_here", None, 16, "sorted")])
+
+
+@pytest.mark.parametrize("tokens, how, n_valid, routed, arm", PARITY_CASES,
+                         ids=["-".join(str(v) for v in case) for case in PARITY_CASES])
+def test_the_held_layer_lays_its_copies_where_the_sort_did(tokens, how, n_valid, routed, arm, monkeypatch):
+    """``moe_mlp_held`` against the algorithm it had (above): the rows handed
+    to the experts (as far as a copy landed here) and the group sizes equal
+    the reference's exactly, over all its passes; ``HELD_COUNTS`` exactly; the
+    output to float32 rounding of a sum taken in another order: 2e-6 of the
+    largest output in float32, and in bf16 (the fused arm rounds the float32
+    sum once more) one unit in the last place, 2**-7 of it. Rank 1 of 4:
+    ``first`` = 4."""
+    from dynamo_tpu.models.quant import quantize_params
+    from dynamo_tpu.ops import pallas_moe
+
+    if arm == "fused":
+        monkeypatch.setenv("DYNAMO_PALLAS_INTERPRET", "1")
+        cfg = dataclasses.replace(_toy(hidden_size=128, moe_intermediate_size=128), dtype="bfloat16")
+        lp = _moe_layer(quantize_params(llama.init_params(cfg, 1), mode="int8"))
+        lp = {**lp, "router_bias": jnp.zeros_like(lp["router_bias"])}
+    else:
+        cfg = _toy()
+        lp = _moe_layer(_weights(cfg))
+    if arm == "sorted":
+        monkeypatch.setattr(moe, "DENSE_COPIES", 0)
+    assert moe.experts_path(lp) == ("fused" if arm == "fused" else "widened")
+    mine = (jnp.arange(16) >= 4) & (jnp.arange(16) < 8)
+    steer = {"even": 0.0, "all_here": 10.0, "none_here": -10.0}[how]
+    lp = {**lp, "router_bias": lp["router_bias"] + steer * mine}
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, cfg.hidden_size), jnp.float32).astype(cfg.dtype)
+    valid = jnp.arange(tokens) < (tokens if n_valid is None else n_valid)
+    kw = dict(num_experts_per_token=4, first=4, routed=routed, routing=llama._routing_kwargs(cfg), valid=valid)
+
+    ref_passes, seen = [], []
+    want, want_counts = _held_by_sort(lp, x, passes=ref_passes, **kw)
+    jax.effects_barrier()
+
+    def keep(rows, sizes):
+        jax.debug.callback(lambda rows, sizes: seen.append((np.asarray(rows), np.asarray(sizes))), rows, sizes)
+
+    real_ffn, real_ragged = pallas_moe.expert_ffn_int8, jax.lax.ragged_dot
+
+    def ffn(rows, gate, up, down, sizes, layer=None, **kwargs):
+        keep(rows, sizes)
+        return real_ffn(rows, gate, up, down, sizes, layer, **kwargs)
+
+    def ragged(lhs, rhs, sizes, **kwargs):
+        if lhs.shape[-1] == cfg.hidden_size:  # the gate's and the up's product: the rows as dispatched
+            keep(lhs, sizes)
+        return real_ragged(lhs, rhs, sizes, **kwargs)
+
+    monkeypatch.setattr(pallas_moe, "expert_ffn_int8", ffn)
+    monkeypatch.setattr(jax.lax, "ragged_dot", ragged)
+    got, counts = moe.moe_mlp_held(lp, x, **kw)
+    jax.effects_barrier()
+
+    n_here = int(counts[2])
+    # HELD_COUNTS: the reference's four, and whether the pass over every copy's rows ran
+    assert counts.tolist() == want_counts.tolist() + [int(n_here > moe.held_rows_cap(tokens * 4, 4, 16))]
+    assert {"even": 0 < n_here < tokens * 4 or tokens == 1, "all_here": n_here == int(valid.sum()) * 4,
+            "none_here": n_here == 0}[how]
+    if how == "all_here" and tokens * 4 > moe.held_rows_cap(tokens * 4, 4, 16):
+        assert len(ref_passes) > 1 and int(counts[4]) == 1  # the reference needed its loop: more copies than a pass's rows
+    assert len({s.tobytes() for _, s in seen}) == 1 and len({r.shape for r, _ in seen}) == 1  # one pass, whichever arm
+    rows, sizes = seen[0]
+    ref_passes.sort(key=lambda p: p[0])
+    ref_rows = np.concatenate([r for _, r, _ in ref_passes]) if ref_passes else np.zeros((0, cfg.hidden_size))
+    assert sizes.tolist() == (sum(s for _, _, s in ref_passes) if ref_passes else np.zeros(4, int)).tolist()
+    assert int(sizes.sum()) == n_here and rows.shape[0] >= n_here
+    assert (rows[:n_here] == ref_rows[:n_here]).all()
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if arm == "fused":
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -7, atol=2.0 ** -7 * np.abs(want).max())
+    else:
+        np.testing.assert_allclose(got, want, atol=2e-6 * max(np.abs(want).max(), 1e-30))
+    if routed == 16:
+        assert float(np.abs(got[int(valid.sum()):]).max(initial=0.0)) == 0.0  # a padding token lands nowhere
 
 
 def test_the_dual_scan_finds_its_experts_in_the_int8_stack(monkeypatch):

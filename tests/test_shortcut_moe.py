@@ -285,22 +285,23 @@ def test_counts_leave_padding_tokens_out_and_no_held_choice_means_no_pass():
     z = ref.shape_of(TOY_HF)
     np.testing.assert_allclose(out[20:], ref.zero_experts_term(h, ref.route(h, lp, z), z)[20:], atol=1e-6)
     none, counts_none = moe.moe_mlp_held(lp, h, valid=jnp.zeros((32,), bool), **kw)
-    assert counts_none.tolist() == [0, 0, 0, 0]
+    assert counts_none.tolist() == [0, 0, 0, 0, 0]
     assert moe.held_rows_cap(64 * 12, 16, 768) == 128 and moe.held_rows_cap(128 * 12, 16, 768) == 128
     assert moe.held_rows_cap(4096 * 12, 16, 768) == 2048 and moe.held_rows_cap(8, 4, 24) == 16
 
 
-def test_routing_far_from_even_takes_several_passes_and_stays_exact(monkeypatch):
+def test_routing_far_from_even_takes_every_copys_rows_and_stays_exact(monkeypatch):
     """Every choice of every token on the held experts (a bias that pulls them
-    there): four times the rows one pass takes, so the pass loop runs four
-    times, and the result is still the reference's."""
+    there): four times the rows the usual pass takes, so the layer's ``cond``
+    takes its other arm, the pass over every copy's rows (counted in
+    ``moe_extra_passes``), and the result is still the reference's."""
     cfg = _toy()
     lp = jax.tree.map(lambda x: x[0], {k: v for k, v in _weights(cfg)["layers"].items() if not k.startswith("sub")})
     lp["router_bias"] = jnp.where((jnp.arange(24) >= 4) & (jnp.arange(24) < 8), 10.0, 0.0)
     h = jax.random.normal(jax.random.PRNGKey(5), (64, 64), jnp.float32)
     monkeypatch.setattr(moe, "held_rows_cap", lambda copies, held, outputs: 64)
     out, counts = moe.moe_mlp_held(lp, h, num_experts_per_token=4, first=4, routed=16, routing=llama._routing_kwargs(cfg))
-    assert counts.tolist() == [256, 0, 256, 4]
+    assert counts.tolist() == [256, 0, 256, 4, 1]
     np.testing.assert_allclose(out, ref.moe(h, lp, ref.shape_of(TOY_HF)), atol=1e-5 * float(jnp.abs(out).max()))
 
 

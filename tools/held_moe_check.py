@@ -5,6 +5,7 @@ experts, the identity ("zero-compute") experts where the router has them, the
 shared expert where the model has one.
 
     python3 tools/held_moe_check.py [--workload <cell>] --seeds 3 --tokens 384
+    python3 tools/held_moe_check.py [--workload <cell>] --time 64,128 [--time-layers 12]
 
 ``benchmark/correct.py`` is weak on the held experts by construction: 2% of a
 token's choices reach LongCat-Flash's 16 of 512 experts here and an eighth
@@ -38,6 +39,16 @@ printed beside their even-routing expectations and the reference's own.
 control's (PERF.md section 6, PR 34 and PR 36); the exit code is 1 where a
 sound reading is over it or a control under it. Run by hand on the chip;
 ``JAX_PLATFORMS=cpu`` rehearses at the configuration's toy size.
+
+``--time`` times the routed part alone instead (``moe_mlp_held``: router,
+bookkeeping, the held experts, the combine; no shared expert): a scan over
+``--time-layers`` MoE layers of the int8 stack as the layer scan hands them
+over, at each of the given token counts, microseconds a layer (the median of
+20 runs over the layers; ``--time-ops FILE`` also traces five runs and keeps the
+device's time operation by operation). ``--time-caps a,b`` times it once more
+for each number with the rows of the usual pass (``held_rows_cap``) set to it:
+the expert kernel's row tile follows the pass's rows. To compare two trees, unpack the other under ``_scratch/``,
+copy this file into its ``tools/`` and run both in one call.
 """
 
 from __future__ import annotations
@@ -149,12 +160,84 @@ def check(conf: dict, seed: int, tokens: int) -> dict:
     return row
 
 
+def time_routed(conf: dict, seed: int, tokens: int, n_layers: int, caps: list[int], runs: int = 20, ops_to: str = "") -> dict:
+    """Microseconds a layer of ``moe_mlp_held`` scanned over ``n_layers`` MoE
+    layers, once for each of ``caps``: the rows of the usual pass
+    (``held_rows_cap``) set to that number, 0 for the function's own.
+    ``ops_to``: a file stem for the device's time operation by operation
+    (``tools/step_ops_table.ops_table`` of five traced runs), one a cap."""
+    import shutil
+    import statistics
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import serving, weights
+    from dynamo_tpu.models.llama import _routing_kwargs
+    from dynamo_tpu.parallel import moe
+
+    cfg = serving.model_config(conf)
+    cfg = dataclasses.replace(cfg, num_layers=n_layers + cfg.first_k_dense)
+    params = weights.make_weights(cfg, seed, quant=conf["serve"]["quant"] if jax.default_backend() == "tpu" else "")
+    path = moe.experts_path(params["layers"])
+    xs, stack = moe.split_expert_stack(params["layers"])
+    xs = {k: v for k, v in xs.items() if k in ("router", "router_bias", "w_gate", "w_up", "w_down")}
+    del params
+    h = jax.random.normal(jax.random.PRNGKey(seed % 2**31), (tokens, cfg.hidden_size), jnp.float32).astype(cfg.dtype)
+
+    def routed_step(xs, stack, h):
+        def layer(carry, lp):
+            h, li = carry
+            out, counts = moe.moe_mlp_held(
+                moe.join_expert_stack(lp, stack, li), h, num_experts_per_token=cfg.num_experts_per_token,
+                first=cfg.moe_expert_first, routed=cfg.routed_experts, routing=_routing_kwargs(cfg))
+            return (h + out * 1e-3, li + 1), counts
+        (h, _), counts = jax.lax.scan(layer, (h, jnp.int32(0)), xs)
+        return h, counts.sum(axis=0)
+
+    row = {"tokens": tokens, "layers": n_layers, "path": path, "caps": {}}
+    own_cap = moe.held_rows_cap
+    for cap in caps:
+        moe.held_rows_cap = (lambda *_, cap=cap: cap) if cap else own_cap
+        try:
+            run = jax.jit(routed_step)
+            counts = jax.block_until_ready(run(xs, stack, h))[1]
+            took = []
+            for _ in range(runs):
+                t0 = time.perf_counter()
+                jax.block_until_ready(run(xs, stack, h))
+                took.append(time.perf_counter() - t0)
+            if ops_to:
+                sys.path.insert(0, str(ROOT / "tools"))
+                from step_ops_table import ops_table
+
+                trace_dir = ROOT / ".bench_work" / "moe_time_trace"
+                shutil.rmtree(trace_dir, ignore_errors=True)
+                jax.profiler.start_trace(str(trace_dir))
+                for _ in range(5):
+                    jax.block_until_ready(run(xs, stack, h))
+                jax.profiler.stop_trace()
+                pathlib.Path(ops_to).parent.mkdir(parents=True, exist_ok=True)
+                pathlib.Path(f"{ops_to}-{tokens}-cap{cap}.json").write_text(json.dumps(ops_table(str(trace_dir))))
+                shutil.rmtree(trace_dir, ignore_errors=True)
+        finally:
+            moe.held_rows_cap = own_cap
+        row["caps"][str(cap)] = {"us_per_layer": statistics.median(took) / n_layers * 1e6, "min_us": min(took) / n_layers * 1e6,
+                                 "counts": [int(v) for v in counts]}
+    return row
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workload", default="longcat-flash-chat-ep32-int8.reason-saturated")
     ap.add_argument("--seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=3400000007)
     ap.add_argument("--tokens", type=int, default=384)
+    ap.add_argument("--time", default="", help="time the routed part alone at these token counts (a,b,...) and stop")
+    ap.add_argument("--time-layers", type=int, default=12)
+    ap.add_argument("--time-caps", default="0", help="with --time: rows of the usual pass to force (a,b,...; 0: the function's own)")
+    ap.add_argument("--time-ops", default="", help="with --time: write the traced per-operation tables to this stem")
     args = ap.parse_args()
     from benchmark import serving
 
@@ -166,6 +249,12 @@ def main() -> int:
     import jax
 
     print(json.dumps({"platform": jax.default_backend(), "kind": jax.devices()[0].device_kind, "rehearsal": rehearsal}))
+    if args.time:
+        for tokens in (int(t) for t in args.time.split(",")):
+            row = time_routed(conf, args.first_seed, tokens, args.time_layers, [int(c) for c in args.time_caps.split(",")],
+                              ops_to="" if rehearsal else args.time_ops)
+            print(json.dumps({"moe_time": {"workload": args.workload, **row}}), flush=True)
+        return 0
     rows = []
     for i in range(args.seeds):
         rows.append(check(conf, args.first_seed + 7919 * i, args.tokens))

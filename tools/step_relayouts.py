@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """What a configuration's step programs re-lay per step: no chip needed.
 
-    JAX_PLATFORMS=cpu python3 tools/step_relayouts.py <config name> [rows] [--dump DIR]
+    JAX_PLATFORMS=cpu python3 tools/step_relayouts.py <config name> [rows] [--dump DIR] [--count]
 
 Compiles the decode step (``rows`` x 1) and the chunk step as it is served
 (``rows`` decode slots + one chunk slot on the split token axis) of
@@ -22,6 +22,14 @@ with a transposed copy of the layer's weight in every layer of every step
 (Mellum2: 0.6 GB a step; LongCat-Flash: 0.5 GB; PERF.md, PR 35). Run this
 before a chip does, on every new configuration; ``--dump`` keeps the compiled
 text, in which an entry's name finds what feeds it and what it feeds.
+
+``--count`` lists instead what the layer scan's body executes: its instructions
+(a fusion is one; parameters, constants, tuples and bitcasts run nothing) by the
+scope their ``op_name`` names (``attn``, ``moe.router``, ``moe.combine``,
+``moe.shared``, ...; ``/while`` and ``/cond`` behind a scope mark what a loop or
+a conditional nested in the body holds), the sorts, and the scatters of a
+``moe.`` scope. A small operation costs a microsecond or more of a step however
+little it computes, 39 times a step in JoyAI-LLM-Flash (PERF.md, PR 39).
 """
 
 from __future__ import annotations
@@ -46,6 +54,11 @@ MOVES = frozenset({"parameter", "constant", "dynamic-slice", "slice", "bitcast",
 MOVERS = frozenset({"dynamic-slice", "slice", "copy", "transpose"})
 ITEMSIZE = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2, "f32": 4, "s32": 4, "u32": 4,
             "f64": 8, "s64": 8, "u64": 8}
+
+#: Opcodes that run nothing on the device.
+FREE = frozenset({"parameter", "constant", "get-tuple-element", "tuple", "bitcast"})
+#: A layer body's scopes: an instruction is counted under the first of these that its ``op_name`` holds.
+SCOPES = ("moe.combine", "moe.dispatch", "moe.shared", "moe.zero", "moe.experts", "moe.router", "attn", "mlp")
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$")
@@ -121,15 +134,102 @@ def relayouts(text: str, *, min_bytes: int = MIN_BYTES) -> list[dict]:
                 continue
             operands = re.findall(r"%([\w.\-]+)", ins["rest"].split("), ")[0])
             big = [shapes[o] for o in operands if o in shapes and _bytes(shapes[o])[1] >= min_bytes]
-            op_name = re.search(r'op_name="([^"]*)"', ins["rest"])
             found.append({
                 "name": ins["name"], "opcode": ins["opcode"], "dtype": dtype, "shape": ins["shape"].split("{")[0],
                 "reads": [f"{s.split('{')[0]}{_layout(s)}" for s in big], "writes": _layout(ins["shape"]),
-                "bytes": nbytes, "times": times, "op_name": op_name.group(1) if op_name else "",
+                "bytes": nbytes, "times": times, "op_name": op_name_of(ins),
             })
 
     walk(entry, 1, False)
     return found
+
+
+def op_name_of(ins: dict) -> str:
+    """An instruction's ``op_name`` metadata; "" where the compiler kept none."""
+    found = re.search(r'op_name="([^"]*)"', ins["rest"])
+    return found.group(1) if found else ""
+
+
+def op_names(text: str) -> dict[str, str]:
+    """``{instruction: op_name}`` over every computation of a compiled text."""
+    return {ins["name"]: op_name_of(ins) for instructions in _computations(text).values() for ins in instructions}
+
+
+def scope_of(op_name: str) -> str:
+    return next((scope for scope in SCOPES if scope in op_name), "other")
+
+
+def _called(ins: dict) -> list[tuple[str, str]]:
+    """``(kind, computation)`` of what a ``while`` or a ``conditional`` runs."""
+    if ins["opcode"] == "while":
+        return [("while", re.search(r"body=%?([\w.\-]+)", ins["rest"]).group(1))]
+    if ins["opcode"] == "conditional":
+        names = re.search(r"branch_computations=\{([^}]*)\}", ins["rest"])
+        names = names.group(1).split(",") if names else re.findall(r"(?:true|false)_computation=%?([\w.\-]+)", ins["rest"])
+        return [("cond", name.strip().lstrip("%")) for name in names]
+    return []
+
+
+def layer_body(comps: dict[str, list[dict]], text: str) -> str | None:
+    """The layer scan's body: of the loops the entry computation runs, the
+    one whose body holds the most instructions."""
+    entry = next((name for name in comps if re.search(rf"^ENTRY %?{re.escape(name)} ", text, re.M)), None)
+    bodies = [body for ins in comps.get(entry, []) for kind, body in _called(ins) if kind == "while"]
+    return max(bodies, key=lambda body: len(comps.get(body, [])), default=None)
+
+
+def body_counts(text: str) -> dict:
+    """What the layer scan's body of a compiled program executes: ``body``,
+    the instructions of the body itself; ``nested``, those of the loops and
+    conditionals inside it, by kind (a loop inside a conditional's arm counts
+    as the conditional's); ``arms``, the instructions of each arm of the
+    body's conditionals, of which a run of the body takes one (``executed``:
+    ``body``, one trip of each nested loop and the longest arm of each
+    conditional); ``by_scope``, all of
+    them by :func:`scope_of` (``<scope>/while``, ``<scope>/cond`` where
+    nested); ``sorts`` and ``moe_scatters``, the names of every ``sort`` and
+    of every ``scatter`` under a ``moe.`` scope, fused or not."""
+    comps = _computations(text)
+    out = {"body": 0, "executed": 0, "nested": {"while": 0, "cond": 0}, "arms": [], "by_scope": {}, "sorts": [],
+           "moe_scatters": []}
+
+    def inside(comp: str, op_name: str) -> None:
+        """Sorts and scatters of a computation, those of its fusions too."""
+        for ins in comps.get(comp, []):
+            name = op_name_of(ins) or op_name
+            if ins["opcode"] == "sort":
+                out["sorts"].append(ins["name"])
+            elif ins["opcode"] == "scatter" and "moe." in name:
+                out["moe_scatters"].append(ins["name"])
+            elif ins["opcode"] == "fusion":
+                inside(re.search(r"calls=%?([\w.\-]+)", ins["rest"]).group(1), name)
+
+    def walk(comp: str, nest: str) -> int:
+        """Counts ``comp``'s instructions and what they nest; returns how many."""
+        n = 0
+        for ins in comps.get(comp, []):
+            if ins["opcode"] in FREE:
+                continue
+            n += 1
+            if nest:
+                out["nested"][nest] += 1
+            else:
+                out["body"] += 1
+            scope = scope_of(op_name_of(ins)) + (f"/{nest}" if nest else "")
+            out["by_scope"][scope] = out["by_scope"].get(scope, 0) + 1
+            inner = [walk(called, nest or kind) for kind, called in _called(ins)]
+            n += sum(inner) if ins["opcode"] == "while" else 0
+            if ins["opcode"] == "conditional" and not nest:
+                out["arms"].append(inner)
+        inside(comp, "")
+        return n
+
+    body = layer_body(comps, text)
+    if body is not None:
+        walk(body, "")
+    out["executed"] = out["body"] + out["nested"]["while"] + sum(max(arms) for arms in out["arms"])
+    out["by_scope"] = dict(sorted(out["by_scope"].items()))
+    return out
 
 
 def summary(found: list[dict]) -> dict:
@@ -188,6 +288,7 @@ def main() -> int:
     ap.add_argument("config")
     ap.add_argument("rows", nargs="?", type=int, default=64)
     ap.add_argument("--dump", default="", help="write each program's compiled text into this directory")
+    ap.add_argument("--count", action="store_true", help="list what the layer scan's body executes, by scope")
     args = ap.parse_args()
 
     from benchmark import serving
@@ -204,6 +305,9 @@ def main() -> int:
         if args.dump:
             pathlib.Path(args.dump).mkdir(parents=True, exist_ok=True)
             (pathlib.Path(args.dump) / f"{args.config}.{label}.hlo.txt").write_text(text)
+        if args.count:
+            out["steps"][label] = body_counts(text)
+            continue
         out["steps"][label] = {"fusions": len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = .* fusion\(", text, re.M)),
                                **summary(relayouts(text))}
     print(json.dumps(out, indent=1))
