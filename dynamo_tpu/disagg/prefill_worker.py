@@ -53,6 +53,9 @@ class PrefillWorker:
         max_concurrency: int = 2,
         ship_concurrency: int | None = None,
     ) -> None:
+        from dynamo_tpu.disagg.transfer import refuse_recurrent
+
+        refuse_recurrent(service.core, "a prefill worker (its pages are shipped to a decode worker)")
         self.runtime = runtime
         self.service = service
         self.queue = DistributedQueue(runtime, queue_name)

@@ -275,6 +275,16 @@ def blob_to_blocks(meta: list[dict], blob) -> list[dict]:
     return out
 
 
+def refuse_recurrent(core, what: str) -> None:
+    """Page transfer moves pages, and a model with recurrent layers keeps the
+    rest of a sequence in a state slot that no page holds: refused by name
+    (snapshots of the state at page boundaries are not built)."""
+    if getattr(core, "state_slots", None) is not None:
+        raise NotImplementedError(
+            f"{core.runner.cfg.name}: {what} is not served for a model with recurrent layers: a sequence's state "
+            "lives in a slot beside its pages, and page transfer would move the pages alone")
+
+
 class KvTransferService(AsyncEngine[Any, dict]):
     """Served by decode workers: ingests KV blocks into the local cache.
 
@@ -290,6 +300,7 @@ class KvTransferService(AsyncEngine[Any, dict]):
     PENDING_PULL_MAX_AGE = 120.0
 
     def __init__(self, core: EngineCore) -> None:
+        refuse_recurrent(core, "the KV transfer service")
         self.core = core
         self._completions: dict[str, asyncio.Event] = {}
         # request_id -> (pinned, staged, parents, t_monotonic): pages staged

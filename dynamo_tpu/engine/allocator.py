@@ -28,6 +28,38 @@ class OutOfPagesError(RuntimeError):
     pass
 
 
+class SlotAllocator:
+    """Slots ``1..num_slots-1`` of a model's recurrent state (slot 0 is the
+    reserved null slot, as page 0 is the null page). A slot is fixed in size,
+    belongs to one running sequence and is overwritten every step: it is never
+    shared, cached or committed, so there is a free list and nothing else. The
+    sequence's own first chunk zeroes the slot it was given (models/kda.py)."""
+
+    def __init__(self, num_slots: int) -> None:
+        if num_slots < 2:
+            raise ValueError("need at least 2 slots (slot 0 is reserved)")
+        self.num_slots = num_slots
+        self._free: list[int] = list(range(num_slots - 1, 0, -1))  # pop() yields low ids first
+
+    @property
+    def total(self) -> int:
+        return self.num_slots - 1
+
+    @property
+    def live(self) -> int:
+        return self.total - len(self._free)
+
+    def allocate(self) -> int:
+        if not self._free:
+            raise OutOfPagesError("no free state slot")
+        return self._free.pop()
+
+    def release(self, slot: int) -> None:
+        if slot <= 0 or slot >= self.num_slots or slot in self._free:
+            raise ValueError(f"release of state slot {slot}: not a live slot")
+        self._free.append(slot)
+
+
 @dataclass
 class _PageInfo:
     refcount: int = 0

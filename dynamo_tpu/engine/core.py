@@ -45,7 +45,7 @@ from typing import Callable
 
 import numpy as np
 
-from dynamo_tpu.engine.allocator import OutOfPagesError, PageAllocator
+from dynamo_tpu.engine.allocator import OutOfPagesError, PageAllocator, SlotAllocator
 from dynamo_tpu.engine.runner import SPLIT, DispatchReport, ModelRunner, StepBatch
 from dynamo_tpu.engine.sequence import SeqStatus, Sequence
 from dynamo_tpu.observability.flight import CRASH, STEP, FlightRecorder
@@ -250,6 +250,24 @@ class EngineCore:
         self.config = config
         self.block_manager = block_manager
         self.allocator = PageAllocator(config.num_pages, config.page_size, on_event=on_kv_event)
+        # A model with recurrent layers keeps a second kind of per-sequence
+        # state: a slot, taken at admission and given back at finish or
+        # preemption (which is by recompute: the sequence starts again from its
+        # tokens, in whatever slot it is given then). Pages alone do not bring
+        # a state back, so such a model never matches a prefix, whatever
+        # ``enable_prefix_caching`` says; its KV events still go out.
+        self.state_slots: SlotAllocator | None = None
+        if getattr(runner, "recurrent", False):
+            self.state_slots = SlotAllocator(runner.state_slots)
+            if config.spec_k > 0 or config.decode_steps > 1:
+                raise ValueError(
+                    f"{runner.cfg.name}: spec_k {config.spec_k} / decode_steps {config.decode_steps} are not served "
+                    "for a model with recurrent layers: a rejected draft or a burst's overshoot cannot be taken "
+                    "out of a state again")
+            if config.enable_prefix_caching:
+                logger.info("%s has recurrent layers: prefix matching is off (a state has no pages to match; "
+                            "state snapshots are not built), KV events still go out", runner.cfg.name)
+        self.prefix_matching = config.enable_prefix_caching and self.state_slots is None
         self.waiting: deque[Sequence] = deque()
         self.running: list[Sequence] = []
         # Admitted but mid-prompt: their next chunk is scheduled each step
@@ -750,6 +768,8 @@ class EngineCore:
                 moe_choices_held=report.moe_counts[2],  # off the interpreter's fast path (0.015 ms a step
                 moe_experts_touched=report.moe_counts[3],  # on a v5e's host: PERF.md, PR 34)
                 moe_extra_passes=report.moe_counts[4],
+                state_rows=report.state_rows,
+                state_slots_live=self.state_slots.live if self.state_slots is not None else 0,
                 layout=report.layout,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
@@ -1260,7 +1280,7 @@ class EngineCore:
             matched: list[int] = []
             onboard_n = 0  # tier blocks to onboard (payloads fetched post-alloc)
             hashes: list[int] = []
-            if self.config.enable_prefix_caching:
+            if self.prefix_matching:
                 hashes = seq.block_seq.block_hashes
                 matched = self.allocator.match_prefix(hashes)
                 if self.block_manager is not None:
@@ -1350,6 +1370,8 @@ class EngineCore:
                     )
             seq.pages = matched + new_pages
             seq.prefill_chunks = 0
+            if self.state_slots is not None:  # live sequences never outnumber max_batch_size, nor the slots
+                seq.state_slot = self.state_slots.allocate()
             if async_ob:
                 # The onboard region is pending: cached state reflects only
                 # the resident match until the session lands (shortfall
@@ -2500,9 +2522,12 @@ class EngineCore:
                 history[i, : len(gen)] = gen
         else:
             history = np.full((b, 1), -1, np.int32)
+        state_slots = None
+        if self.state_slots is not None:
+            state_slots = np.fromiter((s.state_slot for s in batch), np.int32, b)
         return StepBatch(tokens, positions, block_tables, slots, last, temp, top_k, top_p,
                          seeds, steps, freq, pres, limits, history,
-                         mrope_delta=mrope_delta)
+                         mrope_delta=mrope_delta, state_slots=state_slots)
 
     def _release_out_of_window(self, seq: Sequence) -> None:
         """Free pages fully below the sliding-attention window.
@@ -2684,6 +2709,7 @@ class EngineCore:
         self.num_preemptions += 1
         self._cancel_onboards(seq)
         self.allocator.release([p for p in seq.pages if p != 0])
+        self._release_state_slot(seq)  # recompute: the next run starts from zeros in the slot it is given
         seq.pages = []
         seq.committed_pages = 0
         seq.num_cached = 0
@@ -2700,6 +2726,11 @@ class EngineCore:
             self.prefilling.remove(seq)
         self.waiting.appendleft(seq)
 
+    def _release_state_slot(self, seq: Sequence) -> None:
+        if seq.state_slot:
+            self.state_slots.release(seq.state_slot)
+            seq.state_slot = 0
+
     def _finish(self, seq: Sequence, reason: FinishReason) -> None:
         seq.status = SeqStatus.FINISHED
         seq.finish_reason = reason
@@ -2709,6 +2740,7 @@ class EngineCore:
         if seq.pages:
             self.allocator.release([p for p in seq.pages if p != 0])
             seq.pages = []
+        self._release_state_slot(seq)
         if seq in self.running:
             self.running.remove(seq)
         if seq in self.prefilling:

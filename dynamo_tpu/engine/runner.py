@@ -90,13 +90,16 @@ def _pack(padded: "StepBatch", chain_src: np.ndarray | None = None) -> np.ndarra
             padded.history.ravel(),
             padded.mrope_delta,
             chain_src,
+            *(() if padded.state_slots is None else (padded.state_slots,)),
         ]
     )
 
 
-def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int):
-    """In-graph inverse of :func:`_pack` (static offsets, free slices)."""
-    sizes = [b * t, b * t, b * n, b * t, b, b, b, b, b, b, b, b, b, b * h, b, b]
+def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int, slots: bool = False):
+    """In-graph inverse of :func:`_pack` (static offsets, free slices);
+    ``slots``: the rows' state slots ride at the end (a model with recurrent
+    layers) and come back as one more part."""
+    sizes = [b * t, b * t, b * n, b * t, b, b, b, b, b, b, b, b, b, b * h, b, b] + [b] * slots
     offs = np.concatenate([[0], np.cumsum(sizes)])
     part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
     return (
@@ -115,7 +118,7 @@ def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int):
         part[12],
         part[13].reshape(b, h),
         part[14],
-        part[15],
+        *part[15:],
     )
 
 
@@ -186,15 +189,17 @@ def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int,
             rowwise(padded.history).ravel(),
             chain_src,
             chunk_row,
+            *(() if padded.state_slots is None else (rowwise(padded.state_slots, fill=0),)),
         ]
     )
 
 
-def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int):
+def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int, slots: bool = False):
     """In-graph inverse of :func:`_pack_split`: the token axis flat, one row
-    of block table and sampling fields per slot."""
+    of block table and sampling fields per slot (and, with ``slots``, one
+    state slot: a padding slot's is the null slot)."""
     toks, r = nd + nc * tc, nd + nc
-    sizes = [toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h, nd, nc]
+    sizes = [toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h, nd, nc] + [r] * slots
     offs = np.concatenate([[0], np.cumsum(sizes)])
     part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
     f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)  # noqa: E731
@@ -202,7 +207,7 @@ def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int
         part[0], part[1], part[2].reshape(r, n), part[3], part[4],
         f32(part[5]), part[6], f32(part[7]), jax.lax.bitcast_convert_type(part[8], jnp.uint32),
         part[9], f32(part[10]), f32(part[11]), part[12], part[13].reshape(r, h),
-        part[14], part[15],
+        part[14], *part[15:],
     )
 
 
@@ -298,6 +303,10 @@ class StepBatch:
     # chunk rows score only their last column (start n-1), which keeps the
     # chunk rows' sampling bit-identical to the non-speculative step.
     spec_start: np.ndarray | None = None  # i32[B]
+    # A model with recurrent layers: each row's state slot (the engine gives a
+    # sequence one at admission). None = every row the null slot 0, which is
+    # what a padding row has: a warm-up's null batch needs no more.
+    state_slots: np.ndarray | None = None  # i32[B]
 
     @property
     def batch_size(self) -> int:
@@ -323,6 +332,7 @@ class DispatchReport:
     # whose expert layer holds a share or has identity experts, and summed over
     # the programs whose outputs had reached the host when the report was taken.
     moe_counts: tuple[int, ...] = (0,) * len(HELD_COUNTS)
+    state_rows: int = 0  # rows whose state slot the dispatch touched (a model with recurrent layers)
 
 
 class ModelRunner:
@@ -382,6 +392,13 @@ class ModelRunner:
         # has the flat path: text models on one device (llama.forward), GQA,
         # MHA and MLA attention alike.
         self._can_split = forward_fn is None and mesh is None and not cfg.mrope_section
+        # A model with recurrent layers (KDA): a second kind of per-sequence
+        # state beside the pages, a fixed-size slot a running sequence
+        # (models/kda.py); slot 0 is the null slot, as page 0 is the null page.
+        self.recurrent = forward_fn is None and bool(cfg.layer_group_size)
+        if self.recurrent and mesh is not None:
+            raise NotImplementedError(f"{cfg.name}: a model with recurrent layers is served on one device, not a mesh")
+        self.state_slots = max_batch_size + 1 if self.recurrent else 0
         # The step programs of such a model return the expert layers' counters
         # beside their outputs (llama.forward's moe_counts); they wait here, on
         # the device, until a report takes those that are ready.
@@ -415,6 +432,15 @@ class ModelRunner:
                     cfg, num_pages, page_size, dtype=cache_dtype)
                 if self.device is not None:
                     params = jax.device_put(params, self.device)
+        # The recurrent state buffers (state, conv), donated through a step
+        # and handed back like the caches; () for every other model, whose
+        # step programs take and return nothing for it.
+        self.state: tuple = ()
+        if self.recurrent:
+            from dynamo_tpu.models.kda import init_state
+
+            with self._on_device():
+                self.state = init_state(cfg, self.state_slots)
         self.params = params
         # Which formulation the routed experts take ("fused" / "widened" / ""
         # for a dense model): the predicate the forward itself dispatches on,
@@ -453,7 +479,7 @@ class ModelRunner:
                   last_idx, temperature, top_k, top_p, seeds, sample_steps,
                   freq_pen, pres_pen, pos_limit, history, mrope_delta=None,
                   mm_embeds=None, mm_slot_offset=None, mm_counts=None,
-                  mrope_positions=None, logit_mask=None, *, impl, lp_k=0):
+                  mrope_positions=None, logit_mask=None, *, impl, lp_k=0, recurrent=None):
             # In-graph finish-line clamp: any column at/past a row's absolute
             # position limit writes KV to the reserved null page 0 instead of
             # a live slot. Host scheduling never dispatches such a column for
@@ -472,6 +498,8 @@ class ModelRunner:
                     mrope_positions if mrope_positions is not None
                     else _delta_mrope(positions, mrope_delta)
                 )
+            if recurrent is not None:  # (state, conv, slot ids): back as the last two outputs
+                mm_kw["recurrent"] = recurrent
             logits, k_cache, v_cache, *counts = self._forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache,
                 block_tables, slot_mapping, last_idx, attn_impl=impl, mesh=self.mesh,
@@ -489,8 +517,11 @@ class ModelRunner:
         # its own samples in that form beside ``_step``'s outputs. A
         # synchronous step packs -1 into every ``chain_src``: the ``where`` in
         # ``_apply_chain`` hands the host's tokens through.
-        @functools.partial(jax.jit, static_argnames=("nd", "nc", "tc", "n", "h", "lp_k"), donate_argnums=(1, 2))
-        def _step_split(params, k_cache, v_cache, packed, chain_buf, *, nd, nc, tc, n, h, lp_k=0):
+        recurrent = self.recurrent
+
+        @functools.partial(jax.jit, static_argnames=("nd", "nc", "tc", "n", "h", "lp_k"), donate_argnums=(1, 2),
+                           donate_argnames=("state",))
+        def _step_split(params, k_cache, v_cache, packed, chain_buf, *, nd, nc, tc, n, h, lp_k=0, state=()):
             """A chunk step on one token axis (``_pack_split``): ``nd`` decode
             slots of one position, ``nc`` chunk slots of ``tc``. The same
             forward and sampling fold as ``_step``, row for row. A decode
@@ -499,7 +530,8 @@ class ModelRunner:
             slot``) at its row index like any other."""
             (tokens, positions, block_tables, slot_mapping, last_idx, temperature, top_k, top_p,
              seeds, sample_steps, freq_pen, pres_pen, pos_limit, history,
-             chain_src, chunk_row) = _unpack_split(packed, nd, nc, tc, n, h)
+             chain_src, chunk_row, *slot_ids) = _unpack_split(packed, nd, nc, tc, n, h, slots=recurrent)
+            kept = {"recurrent": (*state, *slot_ids)} if recurrent else {}
             first, hist = _apply_chain(tokens[:nd], history[:nd], sample_steps[:nd], chain_buf, chain_src)
             tokens = tokens.at[:nd].set(first)
             history = jnp.concatenate([hist, history[nd:]])
@@ -507,7 +539,7 @@ class ModelRunner:
             slot_mapping = jnp.where(positions < limit, slot_mapping, 0)  # _step's finish-line clamp
             logits, k_cache, v_cache, *counts = llama.forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping,
-                last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc), **counted,
+                last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc), **counted, **kept,
             )
             out = (*_sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
                             freq_pen, pres_pen, history, None, lp_k), *counts)
@@ -518,16 +550,22 @@ class ModelRunner:
 
         self._step_split_fn = _step_split
 
-        @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "lp_k"), donate_argnums=(1, 2))
-        def _step_packed(params, k_cache, v_cache, packed, chain_buf, *, b, t, n, h, lp_k=0):
+        @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "lp_k"), donate_argnums=(1, 2),
+                           donate_argnames=("state",))
+        def _step_packed(params, k_cache, v_cache, packed, chain_buf, *, b, t, n, h, lp_k=0, state=()):
             """The rows x T rectangle from one packed buffer. Each row's
             column-0 token is sourced per ``chain_src`` (the buffer's last
             part) from ``chain_buf`` where the overlapped loop dispatches a
             step before the one before it has reached the host."""
-            *args, chain_src = _unpack(packed, b, t, n, h)
+            *args, chain_src = _unpack(packed, b, t, n, h, slots=recurrent)
+            kept = {}
+            if recurrent:  # the rows' state slots ride last, behind the chain sources
+                slot_ids = chain_src
+                *args, chain_src = args
+                kept = {"recurrent": (*state, slot_ids)}
             # args: 0=tokens, 9=sample_steps, 13=history (see _pack order).
             args[0], args[13] = _chain_rows(args[0], args[13], args[9], chain_buf, chain_src)
-            out = _step(params, k_cache, v_cache, *args, impl=self.attn_impl, lp_k=lp_k)
+            out = _step(params, k_cache, v_cache, *args, impl=self.attn_impl, lp_k=lp_k, **kept)
             return out, _chain_out(out[0], chain_buf.shape[0])
 
         self._step_packed_fn = _step_packed
@@ -941,6 +979,8 @@ class ModelRunner:
             la_groups=la_g,
             num_new=None if batch.num_new is None else pad1(batch.num_new, bp),
             spec_start=None if batch.spec_start is None else pad1(batch.spec_start, bp),
+            state_slots=(None if not self.recurrent else np.zeros(bp, np.int32) if batch.state_slots is None
+                         else pad1(batch.state_slots.astype(np.int32), bp)),
         )
 
     # -- execution ---------------------------------------------------------
@@ -1040,6 +1080,8 @@ class ModelRunner:
             report = self._report = DispatchReport(moe_path=self.moe_path)
         report.attn_phase, report.attn_path = self._attn_dispatch(padded, impl, verify=verify)
         report.layout, report.step_tokens = layout
+        if padded.state_slots is not None:
+            report.state_rows = int(np.count_nonzero(padded.state_slots))
         timed = timed_dispatch(self.compile_tracker, program, key)
         with timed:
             yield
@@ -1068,6 +1110,22 @@ class ModelRunner:
             if self._moe_counts_pending:
                 report.moe_counts = self._ready_moe_counts()
         return report
+
+    def _refuse_recurrent(self, what: str) -> None:
+        if self.recurrent:
+            raise NotImplementedError(
+                f"{self.cfg.name}: {what} is not served for a model with recurrent layers (a rejected or replayed "
+                "token cannot be taken out of a state again; rows with extras ride programs that carry no state)")
+
+    def _keep_state(self, out: tuple) -> tuple:
+        """A step program's outputs without the recurrent state buffers, its
+        last two outputs, which become the runner's own again (as the caches
+        do); every other model's programs return none."""
+        if not self.recurrent:
+            return out
+        *out, state, conv = out
+        self.state = (state, conv)
+        return tuple(out)
 
     def _keep_moe_counts(self, out: tuple) -> tuple:
         """A step program's outputs without the expert layers' counters, which
@@ -1223,10 +1281,12 @@ class ModelRunner:
         if not explicit:
             key, layout, rows, fn, pack, statics = self._text_step(padded, b_real, lp_k)
             dispatch_key += key
+        else:
+            self._refuse_recurrent("a step with image rows or a host-built constraint mask")
         with self._dispatch("step", dispatch_key, padded, impl, layout):
             if not explicit:
                 out, _ = self._enqueue(fn, self.params, self.k_cache, self.v_cache,
-                                       jnp.asarray(pack()), self._chain_idle, **statics)
+                                       jnp.asarray(pack()), self._chain_idle, **statics, state=self.state)
             else:
                 opt, inputs = self._explicit_inputs(padded)
                 out = self._enqueue(
@@ -1236,7 +1296,7 @@ class ModelRunner:
                     opt(padded.mrope_positions), opt(padded.logit_mask),
                     impl=impl, lp_k=lp_k,
                 )
-            out = self._keep_moe_counts(out)
+            out = self._keep_moe_counts(self._keep_state(out))
             self._mark_wait()
             if lp_k:
                 next_tokens, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
@@ -1267,6 +1327,7 @@ class ModelRunner:
         j draft tokens (rng fold ``sample_steps + j``); with ``lp_k`` the
         logprobs dict carries per-column arrays [B, V] / [B, V, k].
         """
+        self._refuse_recurrent("speculative verify")
         b_real = batch.batch_size
         padded = self._pad(batch)
         bp = padded.tokens.shape[0]
@@ -1326,6 +1387,7 @@ class ModelRunner:
         positions + num_steps.
         """
         assert batch.tokens.shape[1] == 1, "multi_step is decode-only"
+        self._refuse_recurrent("a fused decode burst")
         b_real = batch.batch_size
         padded = self._pad(batch)
         dispatch_key = (
@@ -1450,8 +1512,9 @@ class ModelRunner:
             with self._dispatch("step", (b, t, n, h, lp_k, impl, False, False, False) + key,
                                 padded, impl, layout):
                 out, chain_buf = self._enqueue(fn, self.params, self.k_cache, self.v_cache,
-                                               jnp.asarray(pack()), chain_buf, **statics)
+                                               jnp.asarray(pack()), chain_buf, **statics, state=self.state)
         else:
+            self._refuse_recurrent("a step with image rows or constraint masks")
             dispatch_key = (
                 b, t, n, h, lp_k, chain, impl, self.mesh is not None,
                 padded.mm_embeds is not None, padded.logit_mask is not None,
@@ -1479,7 +1542,7 @@ class ModelRunner:
                         impl=impl, lp_k=lp_k,
                     )
             chain_buf = out[0]  # [Bp], the rows in order: a shape of its own
-        out = self._keep_moe_counts(out)
+        out = self._keep_moe_counts(self._keep_state(out))
         if lp_k:
             toks, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
             aux = (chosen, top_ids, top_lps)
@@ -1512,6 +1575,7 @@ class ModelRunner:
         assert batch.mm_embeds is None and batch.logit_mask is None, (
             "spec_step_async does not take multimodal/constrained batches"
         )
+        self._refuse_recurrent("speculative verify")
         b_real = batch.batch_size
         padded = self._pad(batch)
         bp = padded.tokens.shape[0]
@@ -1597,7 +1661,7 @@ class ModelRunner:
         self._chain_tokens = None
 
     def cache_memory_bytes(self) -> int:
-        return int(self.k_cache.nbytes + self.v_cache.nbytes)
+        return int(self.k_cache.nbytes + self.v_cache.nbytes + sum(buf.nbytes for buf in self.state))
 
 
 class InFlightPages:

@@ -37,6 +37,9 @@ class Sequence:
     num_cached_at_start: int = 0  # prefix-cache hits at admission (for usage stats)
     pages: list[int] = field(default_factory=list)
     committed_pages: int = 0  # pages already committed to the prefix cache
+    # A model with recurrent layers: the slot that holds this sequence's state
+    # while it runs (0 = none: waiting, preempted or finished).
+    state_slot: int = 0
     # Forward chunks this (re)prefill has executed (chunked prefill
     # progress; reset on preemption along with num_cached).
     prefill_chunks: int = 0

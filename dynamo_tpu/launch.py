@@ -376,6 +376,12 @@ async def serve_worker(
         spec, on_kv_event=broadcaster.publish, g4_storage=_g4_storage_for(spec, runtime)
     )
     service.spec = spec  # run_local reads vision_config/params off it (VLM)
+    if getattr(service.core, "state_slots", None) is not None and spec.card.router_mode == "kv":
+        await service.close()
+        raise ValueError(
+            f"{spec.card.name}: router_mode 'kv' is not served for a model with recurrent layers: the KV router "
+            "sends a request where its prefix's pages lie, and such a model reuses no prefix (pages alone do not "
+            "bring back a state); use round_robin or random")
     broadcaster.bind_snapshot(service.core.allocator.cache_snapshot)
     ns, comp, ep = spec.card.endpoint
     component = runtime.namespace(ns).component(comp)

@@ -85,16 +85,29 @@ def _attention_layout(config: dict) -> dict:
     return dict(out, rope_theta=theta, rope_scaling=scaling)
 
 
-def _expert_share(config: dict) -> tuple[int, int, int]:
+def _expert_share(config: dict, held_key: str = "n_routed_experts") -> tuple[int, int, int]:
     """(held, total, first id) of the routed experts a config states: all of
-    them, or the share a file gives as ``n_routed_experts`` held here of
+    them, or the share a file gives as ``held_key`` held here of
     ``n_routed_experts_published``, the ``expert_share_rank``-th such share."""
-    held = int(config["n_routed_experts"])
+    held = int(config[held_key])
     total = int(config.get("n_routed_experts_published", held))
     first = int(config.get("expert_share_rank", 0)) * held
     if held <= 0 or first + held > total:
         raise ValueError(f"experts [{first}, {first + held}) lie outside the {total} published")
     return held, total, first
+
+
+def _group_limit(config: dict, held: int, total: int) -> tuple[int, int]:
+    """(n_group, topk_group) of a group-limited router over ``total`` experts
+    of which ``held`` are held here; one group is no group limit: (0, 0), no
+    group top-k in the program. A share holds whole groups."""
+    n_group = int(config.get("n_group", 0) or 0)
+    if n_group <= 1:
+        return 0, 0
+    if held != total and (total % n_group or held % (total // n_group)):
+        raise ValueError(f"a held share of {held} experts splits a routing group of "
+                         f"{total // n_group} (n_group {n_group} over {total}): a share holds whole groups")
+    return n_group, int(config.get("topk_group", 0) or 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,6 +214,14 @@ class ModelConfig:
     # from id ``moe_expert_first`` on and computes their part of the result.
     moe_experts_total: int = 0
     moe_expert_first: int = 0
+    # Hybrid stack (Ling-3.0's ``bailing_hybrid``): layers come in periods of
+    # ``layer_group_size``; the last layer of a period is latent attention
+    # (``attn_type`` "mla"), every other one a delta-rule linear-attention layer
+    # (KDA, models/kda.py) whose per-sequence state is a fixed-size slot
+    # beside the paged latent cache. 0 = every layer alike.
+    layer_group_size: int = 0
+    kda_conv_size: int = 4  # taps of the causal depthwise convolution on q, k and v
+    kda_lower_bound: float = -5.0  # the log-decay lies in (kda_lower_bound, 0)
 
     @property
     def q_dim(self) -> int:
@@ -215,9 +236,24 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def recurrent_layers(self) -> int:
+        """Layers whose state is a slot, not pages (KDA): all but one a period."""
+        g = self.layer_group_size
+        return self.num_layers - self.num_layers // g if g else 0
+
+    @property
     def cache_layers(self) -> int:
-        """Slabs of the paged cache: one per attention (sub)layer."""
-        return self.num_layers * (2 if self.shortcut_moe else 1)
+        """Slabs of the paged cache: one per attention (sub)layer that attends
+        over the context (a recurrent layer holds none)."""
+        return (self.num_layers - self.recurrent_layers) * (2 if self.shortcut_moe else 1)
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one sequence holds over all KDA layers: a
+        float32 ``head_dim x head_dim`` matrix a head, and the last
+        ``kda_conv_size - 1`` inputs of the three convolved streams."""
+        itemsize = 2 if self.dtype == "bfloat16" else 4
+        per_layer = self.num_heads * self.head_dim * self.head_dim * 4 + (self.kda_conv_size - 1) * 3 * self.q_dim * itemsize
+        return self.recurrent_layers * per_layer
 
     @property
     def routed_experts(self) -> int:
@@ -285,6 +321,13 @@ class ModelConfig:
         if self.shortcut_moe:  # two attention blocks, two dense FFNs and the experts in one layer
             return embed + head + d + self.num_layers * (2 * (attn + dense + norms) + moe)
         k_dense = self.first_k_dense if self.is_moe else self.num_layers
+        if self.layer_group_size:  # KDA: five full projections, two head-wise ones, filters, decay constants, head norm
+            q = self.q_dim
+            kda = (5 * d * q + 2 * d * self.num_heads + 3 * self.kda_conv_size * q + self.num_heads + q + self.head_dim)
+            n_kda = self.recurrent_layers
+            gate = d * self.num_heads  # the latent-attention layers' head-wise output gate (``w_out_gate``)
+            return (embed + head + d + n_kda * kda + (self.num_layers - n_kda) * (attn + gate)
+                    + self.num_layers * norms + k_dense * dense + (self.num_layers - k_dense) * moe)
         return (embed + head + d + self.num_layers * (attn + norms)
                 + k_dense * dense + (self.num_layers - k_dense) * moe)
 
@@ -327,6 +370,74 @@ class ModelConfig:
             mla_scale_q=(hidden / config["q_lora_rank"]) ** 0.5 if config.get("mla_scale_q_lora") else 1.0,
             mla_scale_kv=(hidden / config["kv_lora_rank"]) ** 0.5 if config.get("mla_scale_kv_lora") else 1.0,
             attention_bias=False, shortcut_moe=True,
+        )
+
+    @classmethod
+    def _from_bailing_hybrid(cls, config: dict, name: str | None) -> "ModelConfig":
+        """Ling-3.0's config.json (``model_type`` ``bailing_hybrid``): KDA
+        layers with one latent-attention layer every ``layer_group_size``,
+        ``first_k_dense_replace`` leading dense FFNs, then a sigmoid router
+        over ``num_experts`` limited to ``topk_group`` of ``n_group`` groups,
+        and ``num_shared_experts`` shared experts. A file that states a share
+        (``n_routed_experts_published`` beside ``num_experts`` held here, of
+        rank ``expert_share_rank``) gives a model that holds that share.
+        Refuses by name what the layers do not compute; keys that decide
+        nothing at inference as set (``max_window_layers``, ``use_nGPT``,
+        ``up_proj_norm``, ``use_mla_nope``, ``seq_aux``, ``mtp_*``,
+        ``num_nextn_predict_layers``, ``partial_rotary_factor``, ``rotary_dim``)
+        are taken without complaint."""
+        layers, group = int(config["num_hidden_layers"]), int(config["layer_group_size"])
+        if group < 2 or layers % group:
+            raise ValueError(f"layer_group_size {group} over {layers} layers is not served: whole periods of "
+                             "linear-attention layers closed by one latent-attention layer")
+        first_dense = int(config.get("first_k_dense_replace", 0) or 0)
+        if first_dense >= group:
+            raise ValueError(f"first_k_dense_replace {first_dense} reaches the first latent-attention layer "
+                             f"(layer_group_size {group}) is not served: the dense FFNs lie under linear-attention layers")
+        unserved = {
+            "use_kda_lora": False, "value_norm": False, "use_qkv_bias": False, "use_bias": False, "use_nGPT": False,
+            "up_proj_norm": False, "scale_router_input": False, "num_kv_heads_for_linear_attn": 0,
+            "linear_silu": True, "use_qk_norm": True, "kda_safe_gate": True, "group_norm_size": 1,
+            "gated_attention_proj_granularity_type": "head_wise", "score_function": "sigmoid",
+            "topk_method": "noaux_tc", "moe_router_enable_expert_bias": True, "hidden_act": "silu",
+            "q_lora_rank": None, "rope_scaling": None,
+        }
+        for key, served in unserved.items():
+            got = config.get(key, served)
+            if got != served and (got or served):  # a falsy key is a falsy key, however it is spelt
+                raise ValueError(f"{key} {got!r} is not served for model_type 'bailing_hybrid': only {served!r}")
+        for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+            limits = list(config.get(key) or [])
+            held = [(i, v) for i, v in enumerate(limits[:layers]) if v]
+            if held:
+                raise ValueError(f"{key} is {held[0][1]!r} at layer {held[0][0]}, a layer held here: a clamped SwiGLU "
+                                 "is not served (entries of layers past num_hidden_layers are not read)")
+        hidden, heads = config["hidden_size"], config["num_attention_heads"]
+        held, total, first = _expert_share(config, "num_experts")
+        n_group, topk_group = _group_limit(config, held, total)
+        return cls(
+            name=name or config.get("_name_or_path", "bailing_hybrid"),
+            vocab_size=config["vocab_size"], hidden_size=hidden, num_layers=layers,
+            num_heads=heads, num_kv_heads=heads, head_dim=config.get("head_dim") or hidden // heads,
+            intermediate_size=config["intermediate_size"],
+            rope_theta=float(config.get("rope_theta", 10000.0)), rope_scaling=None,
+            rms_eps=config.get("rms_norm_eps", 1e-6),
+            max_position=config.get("max_position_embeddings", 8192),
+            tie_embeddings=bool(config.get("tie_word_embeddings", False)),
+            num_experts=held, num_experts_per_token=int(config["num_experts_per_tok"]),
+            moe_intermediate_size=config["moe_intermediate_size"],
+            moe_experts_total=total if total != held else 0, moe_expert_first=first,
+            shared_expert_size=int(config.get("num_shared_experts", 0) or 0) * int(
+                config.get("moe_shared_expert_intermediate_size") or config["moe_intermediate_size"]),
+            moe_scoring="sigmoid", moe_norm_topk=bool(config.get("norm_topk_prob", True)), moe_router_bias=True,
+            moe_routed_scaling=float(config.get("routed_scaling_factor", 1.0) or 1.0),
+            moe_n_group=n_group, moe_topk_group=topk_group, first_k_dense=first_dense,
+            attn_type="mla", q_lora_rank=0, kv_lora_rank=config["kv_lora_rank"],
+            qk_nope_head_dim=config["qk_nope_head_dim"], qk_rope_head_dim=config["qk_rope_head_dim"],
+            v_head_dim=config["v_head_dim"], rope_interleave=bool(config.get("rope_interleave", True)),
+            attention_bias=False, layer_group_size=group,
+            kda_conv_size=int(config.get("short_conv_kernel_size", 4)),
+            kda_lower_bound=float(config.get("kda_lower_bound", -5.0)),
         )
 
     @classmethod
@@ -377,6 +488,8 @@ class ModelConfig:
             )
         if "num_hidden_layers" not in config and "ffn_hidden_size" in config:
             return cls._from_longcat(config, name)
+        if config.get("model_type") == "bailing_hybrid":
+            return cls._from_bailing_hybrid(config, name)
         hidden = config["hidden_size"]
         heads = config["num_attention_heads"]
         # DeepSeek replaces the first k MoE layers with dense MLPs
@@ -398,15 +511,7 @@ class ModelConfig:
         if not all_dense and "n_routed_experts_published" in config:
             n_experts, total, expert_first = _expert_share(config)
             experts_total = total if total != n_experts else 0
-        # One group is no group limit: no group top-k in the program.
-        n_group = int(config.get("n_group", 0) or 0) if n_experts else 0
-        topk_group = int(config.get("topk_group", 0) or 0) if n_group > 1 else 0
-        if n_group <= 1:
-            n_group = 0
-        elif experts_total and n_experts % (experts_total // n_group):
-            raise ValueError(f"a held share of {n_experts} experts splits a routing group of "
-                             f"{experts_total // n_group} (n_group {n_group} over {experts_total}): "
-                             "a share holds whole groups")
+        n_group, topk_group = _group_limit(config, n_experts, experts_total or n_experts) if n_experts else (0, 0)
         return cls(
             name=name or config.get("_name_or_path", config.get("model_type", "model")),
             vocab_size=config["vocab_size"],
@@ -691,3 +796,50 @@ PRESETS["test-tiny-v3-held"] = dataclasses.replace(
     PRESETS["test-tiny-v3"], name="test-tiny-v3-held", tie_embeddings=False,
     moe_experts_total=16, moe_expert_first=4, moe_n_group=0, moe_topk_group=0,
 )
+
+
+#: Ling-3.0-flash's published ``config.json`` (inclusionAI; ``model_type``
+#: ``bailing_hybrid``), key for key: the presets below are what ``from_hf``
+#: makes of it, and ``tests/benchmark/test_benchmark_ling.py`` holds it to the
+#: catalog row where the catalog is on the machine.
+LING_3_FLASH_HF: dict[str, Any] = {
+    "model_type": "bailing_hybrid",
+    "expert_swiglu_limit_list": [0] * 35 + [4] * 7,
+    "share_expert_swiglu_limit_list": [0] * 34 + [5] * 6 + [7] * 2,
+    "first_k_dense_replace": 2, "gated_attention_proj_granularity_type": "head_wise", "group_norm_size": 1,
+    "head_dim": 128, "hidden_act": "silu", "hidden_size": 2560, "intermediate_size": 6144,
+    "kda_lower_bound": -5, "kda_safe_gate": True, "kv_lora_rank": 512, "layer_group_size": 6,
+    "linear_silu": True, "max_position_embeddings": 262144, "max_window_layers": 20,
+    "moe_intermediate_size": 768, "moe_router_enable_expert_bias": True,
+    "moe_shared_expert_intermediate_size": 768, "mtp_loss_scaling_factor": 0, "mtp_use_kda": False,
+    "n_group": 8, "no_kda_lora": True, "norm_topk_prob": True, "num_attention_heads": 32, "num_experts": 512,
+    "num_experts_per_tok": 8, "num_hidden_layers": 42, "num_key_value_heads": 32,
+    "num_kv_heads_for_linear_attn": 0, "num_nextn_predict_layers": 1, "num_shared_experts": 1,
+    "partial_rotary_factor": 0.5, "q_lora_rank": None, "qk_head_dim": 192, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "rms_norm_eps": 1e-06, "rope_interleave": True, "rope_scaling": None,
+    "rope_theta": 6000000, "rotary_dim": 64, "routed_scaling_factor": 2.5, "scale_router_input": False,
+    "score_function": "sigmoid", "scoring_func": "sigmoid", "seq_aux": True, "short_conv_kernel_size": 4,
+    "tie_word_embeddings": False, "topk_group": 4, "topk_method": "noaux_tc", "up_proj_norm": False,
+    "use_bias": False, "use_kda_lora": False, "use_mla_nope": False, "use_nGPT": False, "use_qk_norm": True,
+    "use_qkv_bias": False, "v_head_dim": 128, "value_norm": False, "vocab_size": 157184,
+}
+#: The published model cut to the layers without a clamped SwiGLU (the limit
+#: lists are non-zero from layer 34 on, ``from_hf`` refuses them by name):
+#: layers 0-29, five whole periods, every expert held. Named for the cut: the
+#: whole model is not served until the clamp is.
+PRESETS["ling-3.0-flash-30l"] = ModelConfig.from_hf(
+    {**LING_3_FLASH_HF, "num_hidden_layers": 30}, name="ling-3.0-flash-30l")
+#: The same keys at toy widths: two periods of three layers (KDA, KDA, MLA),
+#: one leading dense FFN, 8 of 16 experts held (the second of 2 routing groups,
+#: one of which a token may choose from), float32.
+TINY_HYBRID_HF: dict[str, Any] = {
+    **LING_3_FLASH_HF, "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 32, "num_hidden_layers": 6, "layer_group_size": 3,
+    "first_k_dense_replace": 1, "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+    "kv_lora_rank": 24, "qk_rope_head_dim": 8, "rotary_dim": 8, "qk_nope_head_dim": 16, "qk_head_dim": 24,
+    "v_head_dim": 16, "num_experts": 8, "n_routed_experts_published": 16, "expert_share_rank": 1,
+    "expert_share_chips": 2, "n_group": 2, "topk_group": 1, "num_experts_per_tok": 2, "vocab_size": 256,
+    "max_position_embeddings": 512,
+}
+PRESETS["test-tiny-hybrid"] = dataclasses.replace(
+    ModelConfig.from_hf(TINY_HYBRID_HF, name="test-tiny-hybrid"), dtype="float32")

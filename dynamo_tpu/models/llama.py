@@ -54,22 +54,23 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
     def w(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
 
-    def layer_stack(l: int, moe: bool, key_salt: int) -> dict:
+    def layer_stack(l: int, moe: bool, key_salt: int, attn: bool = True) -> dict:
         ks = [jax.random.fold_in(k, key_salt) for k in keys]
         layers = {
             "attn_norm": jnp.ones((l, d), dt),
             "mlp_norm": jnp.ones((l, d), dt),
         }
-        if cfg.qk_norm:
+        # (attn=False: a hybrid stack keeps its attention blocks by kind, beside the FFNs)
+        if attn and cfg.qk_norm:
             qn = cfg.head_dim if cfg.qk_norm == "head" else cfg.q_dim
             kn = cfg.head_dim if cfg.qk_norm == "head" else cfg.kv_dim
             layers["q_norm"] = jnp.ones((l, qn), dt)
             layers["k_norm"] = jnp.ones((l, kn), dt)
-        if cfg.attn_type == "mla":
+        if attn and cfg.attn_type == "mla":
             from dynamo_tpu.models.mla import init_mla_params
 
             layers.update(init_mla_params(cfg, ks[0], dt, l))
-        else:
+        elif attn:
             layers.update(
                 {
                     "wq": w(ks[0], (l, d, q), d),
@@ -78,7 +79,7 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
                     "wo": w(ks[3], (l, q, d), q),
                 }
             )
-        if cfg.attention_bias:
+        if attn and cfg.attention_bias:
             layers.update(
                 {
                     "bq": jnp.zeros((l, q), dt),
@@ -145,14 +146,25 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
         }
 
     k_dense = cfg.first_k_dense if cfg.is_moe else 0
+    hybrid = bool(cfg.layer_group_size)
     params: Params = {
         "embed": w(keys[8], (cfg.vocab_size, d), d),
         "norm_f": jnp.ones((d,), dt),
         "layers": (shortcut_stack(cfg.num_layers) if cfg.shortcut_moe
-                   else layer_stack(cfg.num_layers - k_dense, cfg.is_moe, 0)),
+                   else layer_stack(cfg.num_layers - k_dense, cfg.is_moe, 0, attn=not hybrid)),
     }
     if k_dense:
-        params["dense_layers"] = layer_stack(k_dense, False, 1)
+        params["dense_layers"] = layer_stack(k_dense, False, 1, attn=not hybrid)
+    if hybrid:
+        # The attention blocks by kind, each stack in layer order: ``layers`` and
+        # ``dense_layers`` hold the norms and the FFNs of every layer.
+        from dynamo_tpu.models.kda import init_kda_params
+        from dynamo_tpu.models.mla import init_mla_params
+
+        n_mla = cfg.num_layers - cfg.recurrent_layers
+        params["kda_layers"] = init_kda_params(cfg, jax.random.fold_in(keys[0], 2), dt, cfg.recurrent_layers)
+        params["mla_layers"] = {**init_mla_params(cfg, jax.random.fold_in(keys[0], 3), dt, n_mla),
+                                "w_out_gate": w(jax.random.fold_in(keys[1], 3), (n_mla, d, cfg.num_heads), d)}
     if not cfg.tie_embeddings:
         params["lm_head"] = w(keys[9], (d, cfg.vocab_size), d)
     return params
@@ -329,8 +341,19 @@ def forward(
     contiguous_positions: bool = True,  # False: route attention via gappy-safe paths
     split: tuple[int, int, int] | None = None,  # (decode slots, chunk slots, tokens per chunk slot)
     moe_counts: bool = False,  # also return the held-share expert layers' counters
+    recurrent: tuple | None = None,  # (state, conv, slot ids i32[rows]) of a model with KDA layers
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One forward step. Returns (logits f32[B, vocab], k_cache, v_cache).
+
+    ``recurrent`` (a model with recurrent layers, ``cfg.layer_group_size``):
+    the two state buffers of ``models/kda.init_state`` and each row's slot
+    (each slot's, on the split token axis; 0 is the null slot). The buffers
+    come back as the last two outputs, updated: the caller donates them as it
+    does the cache. Without it such a model's rows each start from a zero
+    state that is dropped with the call, and the outputs are the usual three:
+    a whole-sequence call from position 0, which is how the benchmark's
+    ``tests/benchmark/test_benchmark_reference.py`` calls the program for
+    every configuration it has (a file only a ``benchmark`` PR edits).
 
     ``moe_counts`` (a model whose expert layer holds a share or has identity
     experts, ``cfg.moe_held_share``): a fourth output, i32[4], the layers'
@@ -371,6 +394,17 @@ def forward(
     MLA kernel, a chunk's queries in tiles, ``models/mla.py``). Text models
     without a mesh only.
     """
+    if cfg.layer_group_size and (mesh is not None or logit_indices is not None
+                                 or not contiguous_positions or mm_embeds is not None):
+        raise NotImplementedError(
+            "a model with recurrent (KDA) layers is served on one device, one token after another: "
+            "no mesh, no speculative verify, no image rows")
+    keep_state = recurrent is not None
+    if cfg.layer_group_size and not keep_state:  # a state of the call's own: a slot a row, all zeros
+        from dynamo_tpu.models.kda import init_state
+
+        rows = block_tables.shape[0]
+        recurrent = (*init_state(cfg, rows + 1), jnp.arange(1, rows + 1, dtype=jnp.int32))
     if split is not None:
         if (mesh is not None or attn_impl == "ring" or cfg.mrope_section
                 or mm_embeds is not None or logit_indices is not None or not contiguous_positions):
@@ -625,6 +659,75 @@ def forward(
 
         return layer_step
 
+    def hybrid_stack(x, kf, vf, *counts):
+        """Periods of ``cfg.layer_group_size`` layers: KDA layers closed by
+        one latent-attention layer, the first ``n_dense`` layers' FFN dense
+        and every later one routed. Two attention kinds have two parameter
+        trees and two kinds of state, so the stack is a scan over periods
+        around a loop over the period's KDA layers; a layer reads its leaves
+        from the stacks by index (what a scan's own slicing does), so that
+        the three layer bodies (KDA + dense, KDA + routed, MLA + routed) are
+        each compiled once whatever the depth."""
+        from dynamo_tpu.models.kda import kda_attention
+        from dynamo_tpu.models.mla import mla_attention
+
+        if ring or layer_kinds is not None or cfg.first_k_dense != n_dense:
+            raise NotImplementedError("a hybrid stack is served by the paged path, its leading dense FFNs as stated")
+        state, conv, slot_ids = recurrent
+        group, n_kda = cfg.layer_group_size, cfg.recurrent_layers
+        slots = state.shape[0] // n_kda
+        valid = slot_mapping != 0
+        at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)  # noqa: E731
+
+        def ffn(carry, lp, i_moe):
+            x, kf, vf, state, conv, *counts = carry
+            h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps)
+            with jax.named_scope("mlp"):
+                if i_moe is None:
+                    mlp = _mlp_dense(lp, h2, cfg.mlp_act)
+                elif cfg.moe_held_share:
+                    mlp, counted = _mlp_moe_held(join_expert_stack(lp, expert_stack, i_moe), h2, cfg, valid, mesh)
+                    counts = [counts[0] + counted]
+                else:
+                    mlp = _mlp_moe(join_expert_stack(lp, expert_stack, i_moe), h2, cfg, mesh)
+            return (x + mlp, kf, vf, state, conv, *counts)
+
+        def kda_layer(carry, i_kda, ffn_layers, i_ffn, routed: bool):
+            x, kf, vf, state, conv, *counts = carry
+            lp = at(ffn_layers, i_ffn)
+            h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps)
+            with jax.named_scope("attn.kda"):
+                out, state, conv = kda_attention(
+                    at(params["kda_layers"], i_kda), cfg, h, positions, valid, state, conv,
+                    slot_ids + i_kda * slots, impl=attn_impl, split=split)
+            return ffn((x + out, kf, vf, state, conv, *counts), lp, i_ffn if routed else None)
+
+        def mla_layer(carry, i_mla, i_ffn):
+            x, kf, vf, state, conv, *counts = carry
+            lp = at(moe_layers, i_ffn)
+            h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps)
+            with jax.named_scope("attn"):
+                out, kf, vf = mla_attention(
+                    at(params["mla_layers"], i_mla), cfg, h, positions, kf, vf,
+                    block_tables + i_mla * npages, slot_mapping + i_mla * (npages * ps), inv_freq_mla,
+                    attn_mscale=attn_mscale, impl=attn_impl, split=split)
+            return ffn((x + out, kf, vf, state, conv, *counts), lp, i_ffn)
+
+        carry = (x, kf, vf, state, conv, *counts)
+        if n_dense:
+            carry, _ = jax.lax.scan(
+                lambda c, i: (kda_layer(c, i, params["dense_layers"], i, False), None), carry, jnp.arange(n_dense))
+
+        def period(carry, p):
+            first = jnp.where(p == 0, n_dense, 0) if n_dense else 0  # the first period's dense layers are done
+            carry = jax.lax.fori_loop(
+                first, group - 1,
+                lambda j, c: kda_layer(c, p * (group - 1) + j, moe_layers, p * group + j - n_dense, True), carry)
+            return mla_layer(carry, p, p * group + group - 1 - n_dense), None
+
+        carry, _ = jax.lax.scan(period, carry, jnp.arange(cfg.num_layers // group))
+        return carry
+
     # Scan over layers: one layer's program is traced once — compile time is
     # O(1) in depth (matters at 70B/80-layer scale). Mixed DeepSeek stacks
     # (first_k_dense_replace) run two scans — dense layers first — with the
@@ -642,7 +745,11 @@ def forward(
             return layers
         return layers, {name: v[lo:hi] for name, v in layer_kinds.items()}
 
-    if cfg.shortcut_moe:
+    state_out = ()
+    if cfg.layer_group_size:
+        x, k_out, v_out, *state_out = hybrid_stack(x, kf0, vf0, *carry[4:])
+        counts, state_out = state_out[2:], state_out[:2] if keep_state else ()
+    elif cfg.shortcut_moe:
         if layer_kinds is not None or not mla or n_dense:
             raise NotImplementedError("a shortcut-MoE layer is served with MLA sublayers, all alike")
         (x, k_out, v_out, _, *counts), _ = jax.lax.scan(shortcut_layer_step, carry, moe_layers)
@@ -656,7 +763,7 @@ def forward(
         )
     k_out = k_out.reshape(k_cache.shape)
     v_out = v_out.reshape(v_cache.shape)
-    extra = (counts[0] if counts else None,) if moe_counts else ()
+    extra = ((counts[0] if counts else None,) if moe_counts else ()) + tuple(state_out)
 
     x = rms_norm(x, params["norm_f"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
     # bf16 operands, f32 accumulate: no f32 materialization of the (huge)
@@ -702,7 +809,7 @@ def encode(
     (`lib/llm/src/http/service/openai.rs:580`, `engines.rs:321`).
     """
     b, t = tokens.shape
-    if cfg.mixed_attention or cfg.shortcut_moe or cfg.moe_held_share:
+    if cfg.mixed_attention or cfg.shortcut_moe or cfg.moe_held_share or cfg.layer_group_size:
         raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE), "
                                   "hold one attention block each and all their experts")
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))

@@ -164,6 +164,8 @@ def mla_attention(
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, lp["w_uk"])  # [B,T,H,r_kv]
 
     scale = (dn + dr) ** -0.5 * attn_mscale
+    if ring and "w_out_gate" in lp:
+        raise NotImplementedError("a gated attention output is served by the paged path, not the ring")
     if ring:
         from dynamo_tpu.parallel.ring import ring_attention
 
@@ -198,6 +200,9 @@ def mla_attention(
         out_lat = jnp.concatenate(
             [rows(slice(0, nd), slice(0, nd), 1), rows(slice(nd, t), slice(nd, nd + nc), tc)], axis=1)
     out = jnp.einsum("bthr,rhv->bthv", out_lat.astype(h.dtype), lp["w_uv"])  # [B,T,H,dv]
+    if "w_out_gate" in lp:  # a sigmoid gate a head on the output, before its projection
+        gate = jax.nn.sigmoid(jnp.dot(h, lp["w_out_gate"], preferred_element_type=jnp.float32))
+        out = (out * gate[..., None]).astype(h.dtype)
     return _qmm(out.reshape(b, t, n_heads * dv), lp["wo_mla"]), c_cache, r_cache
 
 

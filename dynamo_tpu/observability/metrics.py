@@ -91,6 +91,13 @@ class EngineMetrics:
         self.pages_cached = gauge(f"{ns}_pages_cached", "Evictable prefix-cache pages (refcount 0)")
         self.pages_active = gauge(f"{ns}_pages_active", "Pages referenced by live sequences")
         self.page_utilization = gauge(f"{ns}_page_utilization_ratio", "active_pages / total_pages")
+        # A model with recurrent layers: its second kind of per-sequence state (all 0 for every other model).
+        self.state_slots_total = gauge(f"{ns}_state_slots", "Recurrent-state slots a running sequence can take (the null slot apart)")
+        self.state_slots_live = gauge(f"{ns}_state_slots_live", "Recurrent-state slots held by live sequences")
+        self.prefix_matching_off = gauge(
+            f"{ns}_prefix_matching_off_by_model",
+            "1 where prefix caching is configured on and the model switches matching off (recurrent layers: pages alone bring back no state)",
+        )
         self.page_fragmentation = gauge(
             f"{ns}_page_fragmentation_ratio",
             "cached / (free + cached): share of idle pages reclaimable only by eviction",
@@ -426,6 +433,10 @@ class EngineMetrics:
         self.pages_cached.set(stats.cached_pages)
         self.pages_active.set(stats.active_pages)
         self.page_utilization.set(stats.active_pages / stats.total_pages if stats.total_pages else 0.0)
+        slots = getattr(core, "state_slots", None)
+        self.state_slots_total.set(slots.total if slots is not None else 0)
+        self.state_slots_live.set(slots.live if slots is not None else 0)
+        self.prefix_matching_off.set(int(slots is not None and core.config.enable_prefix_caching))
         idle = stats.free_pages + stats.cached_pages
         self.page_fragmentation.set(stats.cached_pages / idle if idle else 0.0)
         self.cache_hit_ratio.set(stats.hit_rate)

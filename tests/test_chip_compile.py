@@ -606,3 +606,71 @@ def test_held_layer_routes_a_decode_step_without_sort_scatter_or_loop(sds, monke
     assert counts["nested"]["while"] == 0 and len(counts["arms"]) == 1 and len(counts["arms"][0]) == 2, counts
     assert counts["by_scope"].get("moe.experts/cond") and "moe.experts" not in counts["by_scope"], counts
     assert 0 < counts["executed"] <= at_most, counts
+
+
+# -- a hybrid stack: KDA layers in slots beside the paged latent cache (ISSUE 40) ----------------
+
+def test_kda_decode_kernel_compiles(sds):
+    """The decode step of the KDA recurrence at Ling-3.0-flash's widths: 64
+    rows x 32 heads of 128 x 128 over 15 layers x 65 slots, the state aliased
+    to the kernel's output (updated where it lies: no second 2 GB buffer)."""
+    from dynamo_tpu.ops.pallas_kda import kda_decode_step
+
+    f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
+    compiled = jax.jit(kda_decode_step.__wrapped__, donate_argnums=(0,)).lower(
+        f32(15 * 65, 32, 128, 128), sds((64,), jnp.int32), sds((64,), jnp.bool_),
+        f32(64, 32, 128), f32(64, 32, 128), f32(64, 32, 128), f32(64, 32, 128), f32(64, 32)).compile()
+    assert "kda_decode_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 15 * 65 * 32 * 128 * 128 * 4 and mem.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
+def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
+    """reason-saturated's largest steps at Ling-3.0-flash's widths, one period
+    (its two dense FFNs, three routed KDA layers and the MLA layer, this
+    chip's 64 of 512 experts in 4 of 8 groups, a small vocabulary): 64 decode
+    rows, and 64 decode slots + one 64-token chunk slot, over 16 pages. The KDA
+    layers' decode rows through ``kda_decode_step``, the MLA layer through the
+    MLA kernel, the held experts through the grouped int8 kernel by a layer
+    index counted from the first routed layer; the state buffers come back as
+    the last two outputs and are updated where they lie; no int8 weight is
+    re-laid inside the loops."""
+    import functools
+
+    from dynamo_tpu.models import kda, llama
+    from dynamo_tpu.models.quant import init_params_quantized
+    from dynamo_tpu.parallel import moe
+    from tests.test_step_relayouts import load_tool
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    cfg = _benchmark_config("ling-3.0-flash-ep8-int8", layers=4)  # + 2 dense = one period of 6
+    assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, cfg.first_k_dense) == (6, 5, 1, 2)
+    assert (cfg.num_experts, cfg.routed_experts, cfg.moe_n_group, cfg.moe_topk_group) == (64, 512, 8, 4)
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
+    state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
+    assert state.shape == (5 * 65, 32, 128, 128) and conv.shape == (5 * 65, 3, 3 * 4096)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if split is None:
+        toks, slots = (64, 1), 64
+    else:
+        toks, slots = (split[0] + split[1] * split[2],), split[0] + split[1]
+
+    def step(params, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index, state, conv, slot_ids):
+        return llama.forward(params, cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index,
+                             attn_impl="pallas", split=split, moe_counts=True, recurrent=(state, conv, slot_ids))
+
+    compiled = jax.jit(step, donate_argnums=(3, 4, 8, 9)).lower(
+        params, i32(*toks), i32(*toks), k_cache, v_cache, i32(slots, 16), i32(*toks), i32(slots), state, conv, i32(slots),
+    ).compile()
+    text = compiled.as_text()
+    assert "kda_decode_step" in text and "mla_paged_decode_attention" in text and text.count("moe_grouped_matmul_int8") >= 2
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
+    assert shapes[-3:] == [(5,), state.shape, conv.shape]  # HELD_COUNTS, then the state buffers
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4 and mem.temp_size_in_bytes < 1 << 30  # no second copy of the state
+    relaid = [(op["name"], op["shape"]) for op in load_tool().relayouts(text) if op["dtype"] == "s8"]
+    assert not relaid, relaid

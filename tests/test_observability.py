@@ -267,6 +267,11 @@ EXPECTED_ENGINE_FAMILIES = {
     # prometheus_client emits the histogram's _created timestamps as their
     # own gauge family once a labelled child exists.
     "dynamo_kv_transfer_phase_seconds_created",
+    # Recurrent-state slots of a model with KDA layers, and whether the model
+    # switched prefix matching off (ISSUE 40); 0 for every other model.
+    "dynamo_engine_state_slots",
+    "dynamo_engine_state_slots_live",
+    "dynamo_engine_prefix_matching_off_by_model",
 }
 
 

@@ -124,7 +124,7 @@ async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> li
         await serving.stop(state["handles"])
         os.environ.pop("DYN_KV_CACHE_DTYPE", None)
     runner, params = core.runner, state["params"]
-    _free(runner.k_cache, runner.v_cache)  # room for the reference's whole-sequence pass
+    _free(runner.k_cache, runner.v_cache, getattr(runner, "state", ()))  # room for the reference's whole-sequence pass
     if variant == "int4w":  # the reference reads the weights as configured: make them again
         _free(runner.params, params)
         params = weights.make_weights(serving.model_config(conf), seed, quant=conf["serve"]["quant"])

@@ -185,9 +185,11 @@ def main() -> int:
         return load(trace_dir)
 
     def enqueue_and_remember(fn, *a, **kw):
-        key = (getattr(fn, "__name__", ""), tuple(sorted(kw.items())))
+        # (``state``: a recurrent model's buffers ride as a keyword, arrays like the positional ones)
+        key = (getattr(fn, "__name__", ""), tuple(sorted((k, v) for k, v in kw.items() if k != "state")))
         if key not in seen:  # the shapes of a program's first call: what lowers it again
-            seen[key] = [fn, jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), a), kw, 0]
+            shapes = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), tree)  # noqa: E731
+            seen[key] = [fn, shapes(a), {k: shapes(v) if k == "state" else v for k, v in kw.items()}, 0]
         seen[key][3] += 1
         return enqueue(fn, *a, **kw)
 

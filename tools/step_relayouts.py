@@ -270,9 +270,14 @@ def step_texts(mc, *, rows: int, chunk: int, pages_per_row: int, pool_pages: int
 
     def compiled(split, toks, slots) -> str:
         fn = functools.partial(llama.forward, cfg=mc, attn_impl="pallas", split=split, **counted)
-        return jax.jit(fn, donate_argnames=("k_cache", "v_cache")).lower(
+        kept = {}
+        if mc.layer_group_size:  # a model with recurrent layers: its state buffers (rows + the null slot) and slot ids
+            from dynamo_tpu.models import kda
+
+            kept["recurrent"] = (*like(jax.eval_shape(lambda: kda.init_state(mc, rows + 1))), i32(slots))
+        return jax.jit(fn, donate_argnames=("k_cache", "v_cache", "recurrent")).lower(
             params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=kc, v_cache=vc,
-            block_tables=i32(slots, pages_per_row), slot_mapping=i32(*toks), last_token_index=i32(slots),
+            block_tables=i32(slots, pages_per_row), slot_mapping=i32(*toks), last_token_index=i32(slots), **kept,
         ).compile().as_text()
 
     texts = {"decode": compiled(None, (rows, 1), rows)}
