@@ -432,6 +432,12 @@ class ModelRunner:
                     cfg, num_pages, page_size, dtype=cache_dtype)
                 if self.device is not None:
                     params = jax.device_put(params, self.device)
+                # A latent model's per-head up-projections heads-major, so that a
+                # step reads each from the stack once (models/mla.lay_heads_major).
+                # Under a mesh the leaves shard by head as published.
+                from dynamo_tpu.models.mla import lay_heads_major
+
+                params = lay_heads_major(params)
         # The recurrent state buffers (state, conv), donated through a step
         # and handed back like the caches; () for every other model, whose
         # step programs take and return nothing for it.

@@ -82,6 +82,40 @@ def init_mla_params(cfg: ModelConfig, key: jax.Array, dt, num_layers: int) -> di
     return leaves
 
 
+#: The per-head up-projections as published (``[L, r_kv, H, d]``) and the einsum
+#: each takes there; heads-major (``lay_heads_major``) a leaf is ``<name>_h``.
+_UP = {"w_uk": "bthn,rhn->bthr", "w_uv": "bthr,rhv->bthv"}
+
+
+def lay_heads_major(params: dict) -> dict:
+    """``params`` with every latent layer stack's ``w_uk`` / ``w_uv`` (``[L, r_kv,
+    H, d]``, layers leading) replaced by ``w_uk_h`` ``[L, H, dn, r_kv]`` / ``w_uv_h`` ``[L, H, r_kv,
+    dv]``: a head's slab contiguous, the contracted dimension second to last.
+
+    Heads are the batch dimension of both absorbed contractions. Published, the
+    chip tiles ``(H, d)``, a head's slab is one sublane of every tile, and the
+    compiler re-lays the layer's whole slice of the stack into VMEM before the
+    dot: two serial operations a weight, 36 us a layer for two reads of 5
+    (PERF.md, PR 41). Heads-major the slice fuses into the dot and the weight is
+    read once, where it lies. Called once by an unsharded runner on the tree it
+    takes; loaders, ``init_mla_params`` and ``parallel/sharding.py`` (the leaves
+    shard by head under a mesh) keep the published lay-out."""
+    if not isinstance(params, dict):
+        return params
+    out = {k: lay_heads_major(v) for k, v in params.items() if k not in _UP}
+    if "w_uk" in params:
+        out["w_uk_h"] = jnp.transpose(params["w_uk"], (0, 2, 3, 1))
+        out["w_uv_h"] = jnp.transpose(params["w_uv"], (0, 2, 1, 3))
+    return out
+
+
+def up_project(lp: Params, name: str, x: jnp.ndarray) -> jnp.ndarray:
+    """``x [B, T, H, d_in]`` through the per-head up-projection ``name``."""
+    if name + "_h" in lp:
+        return jnp.einsum("bthi,hio->btho", x, lp[name + "_h"])
+    return jnp.einsum(_UP[name], x, lp[name])
+
+
 def mla_cache_widths(cfg: ModelConfig) -> tuple[int, int]:
     """(k_cache width, v_cache width): latents and rope keys.
 
@@ -161,7 +195,7 @@ def mla_attention(
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, inv_freq)
     # absorb W_uk: scores live in latent space
-    q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, lp["w_uk"])  # [B,T,H,r_kv]
+    q_lat = up_project(lp, "w_uk", q_nope)  # [B,T,H,r_kv]
 
     scale = (dn + dr) ** -0.5 * attn_mscale
     if ring and "w_out_gate" in lp:
@@ -199,7 +233,7 @@ def mla_attention(
 
         out_lat = jnp.concatenate(
             [rows(slice(0, nd), slice(0, nd), 1), rows(slice(nd, t), slice(nd, nd + nc), tc)], axis=1)
-    out = jnp.einsum("bthr,rhv->bthv", out_lat.astype(h.dtype), lp["w_uv"])  # [B,T,H,dv]
+    out = up_project(lp, "w_uv", out_lat.astype(h.dtype))  # [B,T,H,dv]
     if "w_out_gate" in lp:  # a sigmoid gate a head on the output, before its projection
         gate = jax.nn.sigmoid(jnp.dot(h, lp["w_out_gate"], preferred_element_type=jnp.float32))
         out = (out * gate[..., None]).astype(h.dtype)

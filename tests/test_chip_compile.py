@@ -63,6 +63,17 @@ def _compiled_text(fn, *args, **static) -> str:
     return jax.jit(fn, static_argnames=tuple(static)).lower(*args, **static).compile().as_text()
 
 
+def _served_params(sds, cfg, laid: bool = True):
+    """The int8 tree's shapes as an unsharded runner serves them: a latent
+    model's per-head up-projections heads-major (``engine/runner.py``;
+    ``laid=False``: as published, the control)."""
+    from dynamo_tpu.models.mla import lay_heads_major
+    from dynamo_tpu.models.quant import init_params_quantized
+
+    lay = lay_heads_major if laid else (lambda tree: tree)
+    return jax.tree.map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(lambda: lay(init_params_quantized(cfg, 0, mode="int8"))))
+
+
 def _gqa_operands(sds, preset: str, batch: int, t_q: int, page: int, context: int = 2048):
     cfg = PRESETS[preset]
     width = cfg.num_kv_heads * cfg.head_dim
@@ -418,7 +429,6 @@ def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
 
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.models.quant import init_params_quantized
     from dynamo_tpu.parallel import moe
 
     monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
@@ -426,7 +436,7 @@ def test_shortcut_moe_step_longcat_largest_corners(sds, monkeypatch, split):
     hf = {k: v for k, v in doc.items() if k not in ("serve", "rehearsal", "assumed", "reduced_why", "deployment")}
     cfg = ModelConfig.from_hf({**hf, "num_layers": 2}, name="longcat-two-layers")
     like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
-    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    params = _served_params(sds, cfg)
     k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
     assert k_cache.shape == (4, 1025, 128, 512) and v_cache.shape == (4, 1025, 128, 128)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
@@ -460,14 +470,13 @@ def test_plain_held_share_step_joyai_largest_corners(sds, monkeypatch, split):
     import re
 
     from dynamo_tpu.models import llama
-    from dynamo_tpu.models.quant import init_params_quantized
     from dynamo_tpu.parallel import moe
 
     monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
     cfg = _benchmark_config("joyai-llm-flash-ep8-int8", layers=2)
     assert (cfg.num_layers, cfg.first_k_dense, cfg.num_experts, cfg.routed_experts) == (3, 1, 32, 256)
     like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
-    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    params = _served_params(sds, cfg)
     k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
     assert k_cache.shape == (3, 1025, 128, 512) and v_cache.shape == (3, 1025, 128, 128)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
@@ -511,19 +520,18 @@ def _benchmark_config(name: str, layers: int, vocab: int = 8192):
 _STEP_TEXTS: dict = {}
 
 
-def _two_layer_step_text(sds, monkeypatch, config: str, rows: int, mixed: bool, held: bool = True) -> str:
+def _two_layer_step_text(sds, monkeypatch, config: str, rows: int, mixed: bool, held: bool = True, laid: bool = True) -> str:
     """Two layers of a benchmark configuration at its published widths, the
     decode step or the chunk step as served (``rows`` decode slots + one
     64-token chunk slot), compiled for the described chip; a text is compiled
     once for the tests of this file."""
-    key = (config, rows, mixed, held)
+    key = (config, rows, mixed, held, laid)
     if key in _STEP_TEXTS:
         return _STEP_TEXTS[key]
 
     import functools
 
     from dynamo_tpu.models import llama, mla
-    from dynamo_tpu.models.quant import init_params_quantized
     from dynamo_tpu.parallel import moe
 
     monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
@@ -532,7 +540,7 @@ def _two_layer_step_text(sds, monkeypatch, config: str, rows: int, mixed: bool, 
         monkeypatch.setattr(mla, "held_flat", lambda y: y)
     cfg = _benchmark_config(config, layers=2)
     like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
-    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    params = _served_params(sds, cfg, laid=laid)
     pages_per_seq = 16
     k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, (rows + 1) * pages_per_seq + 1, 128)))
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
@@ -579,8 +587,53 @@ def test_step_programs_relay_no_int8_weight(sds, monkeypatch, config, rows, mixe
 
 
 
+def _up_projection_moves(text: str, heads: int, r_kv: int = 512, d: int = 128) -> list:
+    """What the layer scan's body slices out of a stack or copies that is shaped
+    like a layer's ``w_uk`` / ``w_uv`` (bf16, ``r_kv x heads x d`` in any order):
+    ``tools/step_relayouts.relayouts`` lists every copy, transposition or
+    stand-alone slice of 1 MiB or more; a weight's carry the scan's own
+    ``dynamic_slice`` as their ``op_name`` (an activation of as many values, a
+    chunk step's ``[128, heads, 512]`` latent queries, carries its einsum's)."""
+    import re
+
+    from tests.test_step_relayouts import load_tool
+
+    def dims(shape: str) -> list:
+        return sorted(n for n in map(int, re.findall(r"\d+", shape.split("[", 1)[1])) if n != 1)
+
+    return [(op["name"], op["shape"], op["op_name"]) for op in load_tool().relayouts(text)
+            if op["dtype"] == "bf16" and dims(op["shape"]) == sorted((r_kv, heads, d)) and op["op_name"].endswith("dynamic_slice")]
+
+
+@pytest.mark.parametrize("config, heads, mixed, laid", [
+    ("joyai-llm-flash-ep8-int8", 32, False, True),
+    ("joyai-llm-flash-ep8-int8", 32, True, True),
+    ("joyai-llm-flash-ep8-int8", 32, False, False),  # as published: the assertion has something to miss
+    ("longcat-flash-chat-ep32-int8", 64, False, True),
+    ("longcat-flash-chat-ep32-int8", 64, True, True),
+], ids=lambda v: str(v))
+def test_step_programs_slice_no_up_projection_out_of_its_stack(sds, monkeypatch, config, heads, mixed, laid):
+    """The two reason-saturated cells' largest decode and chunk steps (64 decode
+    slots; + one 64-token chunk slot), two layers at the published widths,
+    compiled for the described chip on the tree an unsharded runner serves: no
+    stand-alone ``dynamic-slice`` of ``w_uk`` / ``w_uv`` into VMEM and no copy
+    of either (heads-major, the stack's slice fuses into the contraction that
+    uses it: ``models/mla.lay_heads_major``). On the published tree the decode
+    step makes two such slices a layer of 4.19 MB (ISSUE 41: 18.5 us a layer,
+    and the contractions that then read them 17.4). Ling's corners are held to
+    the same in ``test_hybrid_step_ling_largest_corners``."""
+    moves = _up_projection_moves(_two_layer_step_text(sds, monkeypatch, config, 64, mixed, laid=laid), heads)
+    if laid:
+        assert not moves, moves
+    else:
+        assert len(moves) == 2 and all(name.startswith("constant_dynamic-slice_fusion") for name, _, _ in moves), moves
+
+
 @pytest.mark.parametrize("config, held_share, at_most", [
-    ("joyai-llm-flash-ep8-int8", True, 155),  # 151 here; 124 in the body + 41 in its loop before ISSUE 39
+    # 176 here: 151 until ISSUE 41, whose laid tree frees the 8.4 MB of VMEM that w_uk / w_uv took, and a
+    # two-layer stack's small leaves then ride 25 more async copies into it (40 layers: 126 -> 122 by
+    # ``--count``); 124 in the body + 41 in its loop before ISSUE 39
+    ("joyai-llm-flash-ep8-int8", True, 180),
     ("longcat-flash-chat-ep32-int8", True, 242),  # 237 here; 215 + 42 before
     ("olmoe-1b-7b-int8", False, 0),  # the control: the dropless layer sorts its copies and scatters them back
 ], ids=lambda v: str(v))
@@ -639,7 +692,6 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     import functools
 
     from dynamo_tpu.models import kda, llama
-    from dynamo_tpu.models.quant import init_params_quantized
     from dynamo_tpu.parallel import moe
     from tests.test_step_relayouts import load_tool
 
@@ -648,7 +700,7 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, cfg.first_k_dense) == (6, 5, 1, 2)
     assert (cfg.num_experts, cfg.routed_experts, cfg.moe_n_group, cfg.moe_topk_group) == (64, 512, 8, 4)
     like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
-    params = like(jax.eval_shape(lambda: init_params_quantized(cfg, 0, mode="int8")))
+    params = _served_params(sds, cfg)
     k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
     state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
     assert state.shape == (5 * 65, 32, 128, 128) and conv.shape == (5 * 65, 3, 3 * 4096)
@@ -674,3 +726,4 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     assert mem.alias_size_in_bytes >= state.size * 4 and mem.temp_size_in_bytes < 1 << 30  # no second copy of the state
     relaid = [(op["name"], op["shape"]) for op in load_tool().relayouts(text) if op["dtype"] == "s8"]
     assert not relaid, relaid
+    assert not _up_projection_moves(text, heads=32)  # the MLA layer's w_uk / w_uv are read where they lie

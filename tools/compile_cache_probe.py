@@ -50,6 +50,7 @@ def child(config: str, rows: list[int]) -> int:
     from benchmark import serving, weights
     from dynamo_tpu.compile_cache import enable_compile_cache
     from dynamo_tpu.models import kda, llama
+    from dynamo_tpu.models.mla import lay_heads_major
 
     path = enable_compile_cache()
     jax.config.update("jax_explain_cache_misses", True)
@@ -69,7 +70,7 @@ def child(config: str, rows: list[int]) -> int:
     sds = jax.ShapeDtypeStruct
     like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
     i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
-    params = like(jax.eval_shape(lambda: weights.make_weights(mc, 0, quant=conf["serve"]["quant"])))
+    params = like(jax.eval_shape(lambda: lay_heads_major(weights.make_weights(mc, 0, quant=conf["serve"]["quant"]))))  # as the runner lays it
     kc, vc = like(jax.eval_shape(lambda: llama.init_kv_cache(mc, eng["pool_tokens"] // page_size + 1, page_size)))
     counted = {"moe_counts": True} if mc.moe_held_share else {}
     programs = []

@@ -251,6 +251,7 @@ def step_texts(mc, *, rows: int, chunk: int, pages_per_row: int, pool_pages: int
 
     from benchmark import weights
     from dynamo_tpu.models import llama
+    from dynamo_tpu.models.mla import lay_heads_major
     from dynamo_tpu.parallel import moe
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -263,7 +264,8 @@ def step_texts(mc, *, rows: int, chunk: int, pages_per_row: int, pool_pages: int
     def like(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
-    params = like(jax.eval_shape(lambda: weights.make_weights(mc, 0, quant=quant)))
+    # (the tree as an unsharded runner lays it: engine/runner.py)
+    params = like(jax.eval_shape(lambda: lay_heads_major(weights.make_weights(mc, 0, quant=quant))))
     kc, vc = like(jax.eval_shape(lambda: llama.init_kv_cache(mc, pool_pages, page_size)))
     i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
     counted = {"moe_counts": True} if mc.moe_held_share else {}  # as engine/runner.py asks
