@@ -114,7 +114,9 @@ def tree_nbytes(tree) -> int:
 
 
 def kv_bytes_per_token(cfg, cache_itemsize: int = 2) -> int:
-    """HBM bytes read per cached token per decode step, across all layers.
+    """HBM bytes read per cached token per decode step, across all layers
+    (of a model that mixes window and full layers: across the full layers,
+    see :func:`decode_step_bytes` for the sliding ones).
 
     Delegates to ModelConfig.kv_bytes_per_token so the MLA accounting uses
     the *physical* cache layout (rope stream lane-padded to 128 — a local
@@ -139,14 +141,24 @@ def decode_step_bytes(params, cfg, batch: int, isl: int, osl: int,
       vs_roofline back over 1 — don't use this model there;
     - KV: page-granular — the paged kernels DMA whole pages, so each
       sequence's window is its context rounded up to the page size,
-      averaged over the osl decode steps.
+      averaged over the osl decode steps. A sliding layer of a model that
+      mixes window and full layers reads the pages its window reaches
+      into and no more.
     """
     weight_read = decode_weight_bytes(params, cfg)
     per_tok = kv_bytes_per_token(cfg, cache_itemsize)
-    page_tokens = sum(
-        -(-(isl + s + 1) // page_size) * page_size for s in range(osl)
-    ) / max(osl, 1)
-    return int(weight_read + batch * page_tokens * per_tok)
+    contexts = [isl + s + 1 for s in range(osl)]
+    page_tokens = sum(-(-c // page_size) * page_size for c in contexts) / max(osl, 1)
+    kv = page_tokens * per_tok
+    if getattr(cfg, "mixed_attention", False):
+        from dynamo_tpu.models.config import SLIDING
+
+        # blocks from the window's first position to the context's last
+        window_tokens = sum(
+            (-(-c // page_size) - max(0, c - cfg.sliding_window) // page_size) * page_size for c in contexts
+        ) / max(osl, 1)
+        kv += window_tokens * cfg.kv_bytes_per_token(itemsize=cache_itemsize, kind=SLIDING)
+    return int(weight_read + batch * kv)
 
 
 def decode_weight_bytes(params, cfg) -> int:

@@ -278,11 +278,17 @@ def blob_to_blocks(meta: list[dict], blob) -> list[dict]:
 def refuse_recurrent(core, what: str) -> None:
     """Page transfer moves pages, and a model with recurrent layers keeps the
     rest of a sequence in a state slot that no page holds: refused by name
-    (snapshots of the state at page boundaries are not built)."""
+    (snapshots of the state at page boundaries are not built). So is a model
+    with a page pool per layer kind, whose sequence holds pages of two id spaces."""
     if getattr(core, "state_slots", None) is not None:
         raise NotImplementedError(
             f"{core.runner.cfg.name}: {what} is not served for a model with recurrent layers: a sequence's state "
             "lives in a slot beside its pages, and page transfer would move the pages alone")
+    if getattr(core, "window_allocator", None) is not None:
+        raise NotImplementedError(
+            f"{core.runner.cfg.name}: {what} is not served for a model with a page pool per layer kind (window and "
+            "full layers mixed): a transfer moves a page across every layer by one id, and such a model's ids name "
+            "pages of one kind's pool")
 
 
 class KvTransferService(AsyncEngine[Any, dict]):

@@ -25,7 +25,7 @@ EventCallback = Callable[[KvCacheEvent], None]
 
 
 class OutOfPagesError(RuntimeError):
-    pass
+    """A pool cannot give what was asked of it; the message names the pool."""
 
 
 class SlotAllocator:
@@ -84,13 +84,18 @@ class AllocatorStats:
 
 
 class PageAllocator:
-    """Allocator over pages ``1..num_pages-1`` (page 0 is the reserved null page)."""
+    """Allocator over pages ``1..num_pages-1`` (page 0 is the reserved null page)
+    of one pool, ``pool`` by name: a model whose layers are all alike has one
+    ("kv"); a model that mixes window and full layers has a second over the
+    sliding layers' page ids ("window"), which publishes no KV event."""
 
-    def __init__(self, num_pages: int, page_size: int, *, on_event: EventCallback | None = None) -> None:
+    def __init__(self, num_pages: int, page_size: int, *, on_event: EventCallback | None = None,
+                 pool: str = "kv") -> None:
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is reserved)")
         self.num_pages = num_pages
         self.page_size = page_size
+        self.pool = pool
         self._on_event = on_event
         self._free: list[int] = list(range(num_pages - 1, 0, -1))  # pop() yields low ids first
         self._pages: dict[int, _PageInfo] = {}
@@ -111,6 +116,11 @@ class PageAllocator:
         """Pages allocatable right now (free list + evictable cache)."""
         return len(self._free) + len(self._lru)
 
+    @property
+    def live(self) -> int:
+        """Pages some live sequence holds (neither free nor evictable)."""
+        return self.num_pages - 1 - self.num_free()
+
     def stats(self) -> AllocatorStats:
         active = sum(1 for p in self._pages.values() if p.refcount > 0)
         return AllocatorStats(
@@ -127,7 +137,7 @@ class PageAllocator:
     def allocate(self, n: int = 1) -> list[int]:
         """Take ``n`` fresh pages (evicting prefix cache LRU-first if needed)."""
         if self.num_free() < n:
-            raise OutOfPagesError(f"need {n} pages, have {self.num_free()}")
+            raise OutOfPagesError(f"need {n} pages of the {self.pool} pool, have {self.num_free()} of {self.num_pages - 1}")
         out: list[int] = []
         removed: list[BlockRemoved] = []
         for _ in range(n):

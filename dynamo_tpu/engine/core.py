@@ -268,6 +268,26 @@ class EngineCore:
                 logger.info("%s has recurrent layers: prefix matching is off (a state has no pages to match; "
                             "state snapshots are not built), KV events still go out", runner.cfg.name)
         self.prefix_matching = config.enable_prefix_caching and self.state_slots is None
+        # A model that mixes window and full layers keeps a page pool a layer
+        # kind: ``allocator`` is the full layers', which seats the context and
+        # speaks to the KV event plane; ``window_allocator`` the sliding
+        # layers', whose pages a sequence gives back as they fall wholly
+        # behind the window (``_release_out_of_window``). A sequence holds a
+        # page of each for every block it grows by (``_grow``).
+        self.window_allocator: PageAllocator | None = None
+        self.window_pages_released = 0
+        self._window_released_step = 0
+        if getattr(runner, "two_pool", False):
+            name = runner.cfg.name
+            if block_manager is not None:
+                raise ValueError(
+                    f"{name}: offload tiers are not served for a model with a page pool per layer kind: a page "
+                    "id names a page of one kind's pool (engine/runner.py refuses the page movers by name)")
+            if not config.swa_free_pages and runner.window_pages < config.num_pages:
+                raise ValueError(
+                    f"{name}: swa_free_pages is off and the window pool holds {runner.window_pages} pages of the "
+                    f"full pool's {config.num_pages}: pages that are never given back need a pool that seats the context")
+            self.window_allocator = PageAllocator(runner.window_pages, config.page_size, pool="window")
         self.waiting: deque[Sequence] = deque()
         self.running: list[Sequence] = []
         # Admitted but mid-prompt: their next chunk is scheduled each step
@@ -770,6 +790,9 @@ class EngineCore:
                 moe_extra_passes=report.moe_counts[4],
                 state_rows=report.state_rows,
                 state_slots_live=self.state_slots.live if self.state_slots is not None else 0,
+                full_pages_live=self.allocator.live,
+                window_pages_live=self.window_allocator.live if self.window_allocator is not None else 0,
+                window_pages_released=self._take_window_released(),
                 layout=report.layout,
                 admitted=int(self.last_admission.get("admitted", 0)),
                 deferred=int(self.last_admission.get("deferred", 0)),
@@ -1208,7 +1231,10 @@ class EngineCore:
         ) if chunked else 0
 
         def free_pages() -> int:
-            return max(0, self.allocator.num_free() - reserve)
+            free = self.allocator.num_free()
+            if self.window_allocator is not None:  # a block takes a page of each pool
+                free = min(free, self.window_allocator.num_free())
+            return max(0, free - reserve)
 
         # 1) Continue sequences already mid-prompt (arrival order).
         for seq in self.prefilling:
@@ -1231,7 +1257,7 @@ class EngineCore:
             need = seq.pages_needed(ps, dc + n)
             if need:
                 try:
-                    seq.pages.extend(self.allocator.allocate(need))
+                    self._grow(seq, need)
                 except OutOfPagesError:
                     continue
             budget -= n
@@ -1293,6 +1319,8 @@ class EngineCore:
                         onboard_n -= 1
                     else:
                         self.allocator.release([matched.pop()])
+            window_matched = self._match_window(hashes, matched)  # may shorten ``matched``
+            new_window: list[int] = []
             cached_len = (len(matched) + onboard_n) * ps
             num_new = total - cached_len
             # Pipelined onboarding (config.async_onboard): admit the row
@@ -1319,21 +1347,21 @@ class EngineCore:
                 n = min(num_new, budget)
                 n = min(n, (len(matched) + free_pages()) * ps - cached_len)
                 if n <= 0:
-                    self.allocator.release(matched)
+                    self._release_pages(matched, window_matched)
                     if not chunks and not self.running:
                         self._note_head_stall(seq, num_new)
                     break
             else:
                 n = num_new
                 if chunks and n > budget:
-                    self.allocator.release(matched)
+                    self._release_pages(matched, window_matched)
                     break
             if not async_ob:
                 pages_goal = -(-(cached_len + n) // ps)
                 try:
-                    new_pages = self.allocator.allocate(pages_goal - len(matched))
+                    new_pages, new_window = self._allocate(pages_goal - len(matched))
                 except OutOfPagesError:
-                    self.allocator.release(matched)
+                    self._release_pages(matched, window_matched)
                     if not chunks and not self.running:
                         self._note_head_stall(seq, num_new)
                     break
@@ -1369,6 +1397,7 @@ class EngineCore:
                         self.onboard_page_counts.get(tier, 0) + 1
                     )
             seq.pages = matched + new_pages
+            seq.window_pages = window_matched + new_window
             seq.prefill_chunks = 0
             if self.state_slots is not None:  # live sequences never outnumber max_batch_size, nor the slots
                 seq.state_slot = self.state_slots.allocate()
@@ -1635,7 +1664,7 @@ class EngineCore:
                 need = s.pages_needed(self.config.page_size, dc + 1 + len(d))
                 if need:
                     try:
-                        s.pages.extend(self.allocator.allocate(need))
+                        self._grow(s, need)
                     except OutOfPagesError:
                         d = []
             if budget is not None:
@@ -1894,10 +1923,8 @@ class EngineCore:
                 if not s.is_finished:
                     keep = s.num_cached // ps + 1
                     if len(s.pages) > keep:
-                        extra = [p for p in s.pages[keep:] if p != 0]
-                        del s.pages[keep:]
-                        if extra:
-                            self.allocator.release(extra)
+                        self._release_pages(s.pages[keep:], s.window_pages[keep:])
+                        del s.pages[keep:], s.window_pages[keep:]
                 self._commit_filled_pages(s)
                 self._release_out_of_window(s)
                 # May finish the sequence (page release) — must follow commit.
@@ -1970,7 +1997,7 @@ class EngineCore:
             )
             if need:
                 try:
-                    s.pages.extend(self.allocator.allocate(need))
+                    self._grow(s, need)
                 except OutOfPagesError:
                     victim = self.running[-1] if self.running else s
                     if victim is s and len(self.running) <= 1:
@@ -2347,7 +2374,7 @@ class EngineCore:
             need = seq.pages_needed(self.config.page_size, min(horizon, remaining))
             if need:
                 try:
-                    seq.pages.extend(self.allocator.allocate(need))
+                    self._grow(seq, need)
                 except OutOfPagesError:
                     victim = self.running[-1]
                     if victim is seq and len(self.running) == 1:
@@ -2525,12 +2552,32 @@ class EngineCore:
         state_slots = None
         if self.state_slots is not None:
             state_slots = np.fromiter((s.state_slot for s in batch), np.int32, b)
+        window_tables = window_slots = None
+        if self.window_allocator is not None:
+            # The sliding layers' tables, shaped as the full layers': a real
+            # column (its full slot is not the null page's) lies inside the
+            # window, so its block's window page is held.
+            ps = self.config.page_size
+            window_tables = np.zeros_like(block_tables)
+            window_slots = np.zeros_like(slots)
+            for i, s in enumerate(batch):
+                window_tables[i, : len(s.window_pages)] = s.window_pages
+                if slots.shape[1] == 1 and slots[i, 0]:  # a decode step: one position a row, in plain integers
+                    pos = int(positions[i, 0])
+                    window_slots[i, 0] = s.window_pages[pos // ps] * ps + pos % ps
+            if slots.shape[1] > 1:
+                pages = np.take_along_axis(window_tables, np.minimum(positions // ps, block_tables.shape[1] - 1), axis=1)
+                window_slots = np.where(slots != 0, pages * ps + positions % ps, 0).astype(np.int32)
         return StepBatch(tokens, positions, block_tables, slots, last, temp, top_k, top_p,
                          seeds, steps, freq, pres, limits, history,
-                         mrope_delta=mrope_delta, state_slots=state_slots)
+                         mrope_delta=mrope_delta, state_slots=state_slots,
+                         window_block_tables=window_tables, window_slot_mapping=window_slots)
 
     def _release_out_of_window(self, seq: Sequence) -> None:
-        """Free pages fully below the sliding-attention window.
+        """Free pages fully below the sliding-attention window: of a model
+        whose layers are all windowed the sequence's pages, of a model that
+        mixes window and full layers its *window* pool's pages (the full
+        layers read the whole context: their pool's pages stay).
 
         The block table keeps its positional shape: released entries point
         at the reserved null page 0 — the SWA mask derives key positions
@@ -2539,28 +2586,33 @@ class EngineCore:
         Release paths (finish/preempt) skip the zeros."""
         cfg = getattr(self.runner, "cfg", None)
         win = getattr(cfg, "sliding_window", 0)
-        # One page-id space serves every layer: a page may go only when no
-        # layer reads it any more, so a model that mixes window and full
-        # layers releases nothing (its full layers read the whole context).
-        if not win or not self.config.swa_free_pages or getattr(cfg, "mixed_attention", False):
+        if not win or not self.config.swa_free_pages:
             return
+        pages, allocator = seq.pages, self.allocator
+        if getattr(cfg, "mixed_attention", False):
+            if self.window_allocator is None:  # a runner that keeps one page-id space for every layer
+                return
+            pages, allocator = seq.window_pages, self.window_allocator
         ps = self.config.page_size
         # Tokens at absolute positions < (next_pos - win) are out of every
         # future query's window; a page is releasable once its LAST slot is.
-        keep_from = max(0, len(seq.tokens) - win) // ps
-        if keep_from <= 0:
-            return
-        drop = [pid for pid in seq.pages[:keep_from] if pid != 0]
-        if not drop:
-            return
+        # The next query is the first token not yet cached: mid-prompt that
+        # is the next chunk's first, not the prompt's last.
+        keep_from = max(0, min(len(seq.tokens), seq.num_cached + 1) - win) // ps
         # Never release pages the commit walk hasn't published yet (caching
         # on: commit runs first each step, so this only guards odd orderings).
-        if self.config.enable_prefix_caching and seq.committed_pages < keep_from:
-            drop = [pid for pid in seq.pages[: seq.committed_pages] if pid != 0]
-            keep_from = seq.committed_pages
-        self.allocator.release(drop)
-        for i in range(keep_from):
-            seq.pages[i] = 0
+        if self.config.enable_prefix_caching:
+            keep_from = min(keep_from, seq.committed_pages)
+        if keep_from <= 0:
+            return
+        drop = [pid for pid in pages[:keep_from] if pid != 0]
+        if not drop:
+            return
+        allocator.release(drop)
+        pages[:keep_from] = [0] * keep_from
+        if allocator is self.window_allocator:
+            self.window_pages_released += len(drop)
+            self._window_released_step += len(drop)
 
     def _commit_filled_pages(self, seq: Sequence) -> None:
         """Publish newly-filled pages to the prefix cache (emits stored events)
@@ -2573,6 +2625,8 @@ class EngineCore:
             idx = seq.committed_pages
             blk = blocks[idx]
             newly_cached = self.allocator.commit(seq.pages[idx], blk.block_hash, blk.parent_hash, blk.tokens)
+            if self.window_allocator is not None and seq.window_pages[idx]:  # under the same hash, in its own pool
+                self.window_allocator.commit(seq.window_pages[idx], blk.block_hash, blk.parent_hash)
             if newly_cached and self.block_manager is not None:
                 # Deferred: the device->host read happens in flush_offloads(),
                 # batched, after the step's outputs have been routed.
@@ -2708,9 +2762,9 @@ class EngineCore:
         logger.info("preempting seq %d (%d pages)", seq.seq_id, len(seq.pages))
         self.num_preemptions += 1
         self._cancel_onboards(seq)
-        self.allocator.release([p for p in seq.pages if p != 0])
+        self._release_pages(seq.pages, seq.window_pages)
         self._release_state_slot(seq)  # recompute: the next run starts from zeros in the slot it is given
-        seq.pages = []
+        seq.pages, seq.window_pages = [], []
         seq.committed_pages = 0
         seq.num_cached = 0
         seq.prefill_chunks = 0
@@ -2726,6 +2780,59 @@ class EngineCore:
             self.prefilling.remove(seq)
         self.waiting.appendleft(seq)
 
+    def _allocate(self, n: int) -> tuple[list[int], list[int]]:
+        """Pages for ``n`` more blocks: of the pool, and of a model with a pool
+        per layer kind of each pool (else no window pages), or
+        ``OutOfPagesError`` and nothing taken."""
+        new = self.allocator.allocate(n)
+        if self.window_allocator is None:
+            return new, []
+        try:
+            return new, self.window_allocator.allocate(n)
+        except OutOfPagesError:
+            self.allocator.release(new)
+            raise
+
+    def _grow(self, seq: Sequence, need: int) -> None:
+        """``need`` more blocks for ``seq`` (``_allocate``)."""
+        new, window = self._allocate(need)
+        seq.pages.extend(new)
+        seq.window_pages.extend(window)
+
+    def _release_pages(self, pages, window_pages=()) -> None:
+        """Hands a sequence's pages back, each pool its own (the null page,
+        which stands for a page already given back, is nobody's)."""
+        if held := [p for p in pages if p != 0]:
+            self.allocator.release(held)
+        if held := [p for p in window_pages if p != 0]:
+            self.window_allocator.release(held)
+
+    def _match_window(self, hashes, matched: list[int]) -> list[int]:
+        """The prefix rule of a model with a pool per layer kind. A sliding
+        layer's keys cannot be recomputed from the full layers' pages, so a hit
+        is the longest prefix of whole blocks whose full pages are cached
+        (``matched``, acquired) *and* whose last ``ceil(window / page_size)``
+        window pages are: ``matched`` is cut to it in place (what is cut is
+        released), and the window pool's table of the hit comes back, acquired:
+        the null page for every block wholly under the window. [] for every
+        other model."""
+        if self.window_allocator is None:
+            return []
+        reach = -(-self.runner.cfg.sliding_window // self.config.page_size)
+        while matched:
+            first = max(0, len(matched) - reach)
+            got = [self.window_allocator.acquire_cached(h) for h in hashes[first: len(matched)]]
+            if None not in got:
+                return [0] * first + got
+            self.window_allocator.release([p for p in got if p is not None])
+            self.allocator.release([matched.pop()])
+        return []
+
+    def _take_window_released(self) -> int:
+        """Window-pool pages given back since the last STEP record."""
+        n, self._window_released_step = self._window_released_step, 0
+        return n
+
     def _release_state_slot(self, seq: Sequence) -> None:
         if seq.state_slot:
             self.state_slots.release(seq.state_slot)
@@ -2737,9 +2844,8 @@ class EngineCore:
         self._cancel_onboards(seq)
         if self.admission is not None:
             self.admission.on_finish(seq)
-        if seq.pages:
-            self.allocator.release([p for p in seq.pages if p != 0])
-            seq.pages = []
+        self._release_pages(seq.pages, seq.window_pages)
+        seq.pages, seq.window_pages = [], []
         self._release_state_slot(seq)
         if seq in self.running:
             self.running.remove(seq)

@@ -91,15 +91,20 @@ def _pack(padded: "StepBatch", chain_src: np.ndarray | None = None) -> np.ndarra
             padded.mrope_delta,
             chain_src,
             *(() if padded.state_slots is None else (padded.state_slots,)),
+            *(() if padded.window_block_tables is None
+              else (padded.window_block_tables.ravel(), padded.window_slot_mapping.ravel())),
         ]
     )
 
 
-def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int, slots: bool = False):
+def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int, slots: bool = False, pools: bool = False):
     """In-graph inverse of :func:`_pack` (static offsets, free slices);
     ``slots``: the rows' state slots ride at the end (a model with recurrent
-    layers) and come back as one more part."""
-    sizes = [b * t, b * t, b * n, b * t, b, b, b, b, b, b, b, b, b, b * h, b, b] + [b] * slots
+    layers) and come back as one more part; ``pools``: behind them the window
+    pool's block tables and slot mapping (a model with a pool per layer kind),
+    two more parts, flat."""
+    sizes = ([b * t, b * t, b * n, b * t, b, b, b, b, b, b, b, b, b, b * h, b, b] + [b] * slots
+             + [b * n, b * t] * pools)
     offs = np.concatenate([[0], np.cumsum(sizes)])
     part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
     return (
@@ -190,16 +195,21 @@ def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int,
             chain_src,
             chunk_row,
             *(() if padded.state_slots is None else (rowwise(padded.state_slots, fill=0),)),
+            *(() if padded.window_block_tables is None
+              else (rowwise(padded.window_block_tables, fill=0).ravel(), tokenwise(padded.window_slot_mapping))),
         ]
     )
 
 
-def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int, slots: bool = False):
+def _unpack_split(packed: jnp.ndarray, nd: int, nc: int, tc: int, n: int, h: int, slots: bool = False,
+                  pools: bool = False):
     """In-graph inverse of :func:`_pack_split`: the token axis flat, one row
     of block table and sampling fields per slot (and, with ``slots``, one
-    state slot: a padding slot's is the null slot)."""
+    state slot: a padding slot's is the null slot; with ``pools``, the window
+    pool's block tables and slot mapping, flat, as the last two parts)."""
     toks, r = nd + nc * tc, nd + nc
-    sizes = [toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h, nd, nc] + [r] * slots
+    sizes = ([toks, toks, r * n, toks, r, r, r, r, r, r, r, r, r, r * h, nd, nc] + [r] * slots
+             + [r * n, toks] * pools)
     offs = np.concatenate([[0], np.cumsum(sizes)])
     part = [packed[offs[i] : offs[i + 1]] for i in range(len(sizes))]
     f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)  # noqa: E731
@@ -307,6 +317,13 @@ class StepBatch:
     # sequence one at admission). None = every row the null slot 0, which is
     # what a padding row has: a warm-up's null batch needs no more.
     state_slots: np.ndarray | None = None  # i32[B]
+    # A model with a page pool per layer kind (window and full layers mixed):
+    # the sliding layers' block tables and slot mapping, page ids of the window
+    # pool, shaped as ``block_tables`` / ``slot_mapping``; a block wholly under
+    # the window is the null page 0. None = every row the null page, which is
+    # what a padding row has.
+    window_block_tables: np.ndarray | None = None  # i32[B, N]
+    window_slot_mapping: np.ndarray | None = None  # i32[B, T]
 
     @property
     def batch_size(self) -> int:
@@ -353,6 +370,7 @@ class ModelRunner:
         mesh=None,  # jax.sharding.Mesh for TP/DP execution (see dynamo_tpu.parallel)
         device=None,  # single-device runners: the jax.Device everything lives on
         embed_pooling: str = "mean",  # /v1/embeddings pooling ("mean" | "last")
+        window_chunk: int | None = None,  # most tokens a row brings to one step, where the caller bounds it
     ) -> None:
         from dynamo_tpu.ops.attention import default_impl
 
@@ -399,6 +417,14 @@ class ModelRunner:
         if self.recurrent and mesh is not None:
             raise NotImplementedError(f"{cfg.name}: a model with recurrent layers is served on one device, not a mesh")
         self.state_slots = max_batch_size + 1 if self.recurrent else 0
+        # A model that mixes window and full layers: a page pool and a block
+        # table per layer kind (llama.init_kv_cache). ``num_pages`` is the full
+        # layers' pool, which seats the context; the sliding layers' holds a
+        # window of pages a row (llama.window_pool_pages: derived from the
+        # caller's chunk bound, as many as ``num_pages`` without one).
+        self.two_pool = forward_fn is None and cfg.mixed_attention
+        self.window_pages = (llama.window_pool_pages(cfg, num_pages, page_size, max_batch_size, window_chunk)
+                             if self.two_pool else 0)
         # The step programs of such a model return the expert layers' counters
         # beside their outputs (llama.forward's moe_counts); they wait here, on
         # the device, until a report takes those that are ready.
@@ -422,14 +448,15 @@ class ModelRunner:
             # device (it may not fit there).
             cs = cache_shardings(mesh, cfg.attn_type)
             self.k_cache, self.v_cache = jax.jit(
-                lambda: llama.init_kv_cache(cfg, num_pages, page_size, dtype=cache_dtype),
+                lambda: llama.init_kv_cache(cfg, num_pages, page_size, dtype=cache_dtype,
+                                            window_pages=self.window_pages or None),
                 out_shardings=(cs, cs),
             )()
             self._dp = int(mesh.shape["dp"])
         else:
             with self._on_device():
                 self.k_cache, self.v_cache = llama.init_kv_cache(
-                    cfg, num_pages, page_size, dtype=cache_dtype)
+                    cfg, num_pages, page_size, dtype=cache_dtype, window_pages=self.window_pages or None)
                 if self.device is not None:
                     params = jax.device_put(params, self.device)
                 # A latent model's per-head up-projections heads-major, so that a
@@ -485,7 +512,8 @@ class ModelRunner:
                   last_idx, temperature, top_k, top_p, seeds, sample_steps,
                   freq_pen, pres_pen, pos_limit, history, mrope_delta=None,
                   mm_embeds=None, mm_slot_offset=None, mm_counts=None,
-                  mrope_positions=None, logit_mask=None, *, impl, lp_k=0, recurrent=None):
+                  mrope_positions=None, logit_mask=None, window_tables=None, window_slots=None,
+                  *, impl, lp_k=0, recurrent=None):
             # In-graph finish-line clamp: any column at/past a row's absolute
             # position limit writes KV to the reserved null page 0 instead of
             # a live slot. Host scheduling never dispatches such a column for
@@ -506,6 +534,9 @@ class ModelRunner:
                 )
             if recurrent is not None:  # (state, conv, slot ids): back as the last two outputs
                 mm_kw["recurrent"] = recurrent
+            if window_tables is not None:  # the sliding layers' pool, under the same clamp
+                mm_kw.update(window_tables=window_tables, window_pages=self.window_pages,
+                             window_slots=jnp.where(positions < pos_limit[:, None], window_slots, 0))
             logits, k_cache, v_cache, *counts = self._forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache,
                 block_tables, slot_mapping, last_idx, attn_impl=impl, mesh=self.mesh,
@@ -523,7 +554,7 @@ class ModelRunner:
         # its own samples in that form beside ``_step``'s outputs. A
         # synchronous step packs -1 into every ``chain_src``: the ``where`` in
         # ``_apply_chain`` hands the host's tokens through.
-        recurrent = self.recurrent
+        recurrent, two_pool = self.recurrent, self.two_pool
 
         @functools.partial(jax.jit, static_argnames=("nd", "nc", "tc", "n", "h", "lp_k"), donate_argnums=(1, 2),
                            donate_argnames=("state",))
@@ -536,13 +567,16 @@ class ModelRunner:
             slot``) at its row index like any other."""
             (tokens, positions, block_tables, slot_mapping, last_idx, temperature, top_k, top_p,
              seeds, sample_steps, freq_pen, pres_pen, pos_limit, history,
-             chain_src, chunk_row, *slot_ids) = _unpack_split(packed, nd, nc, tc, n, h, slots=recurrent)
-            kept = {"recurrent": (*state, *slot_ids)} if recurrent else {}
+             chain_src, chunk_row, *rest) = _unpack_split(packed, nd, nc, tc, n, h, slots=recurrent, pools=two_pool)
+            kept = {"recurrent": (*state, rest[0])} if recurrent else {}
             first, hist = _apply_chain(tokens[:nd], history[:nd], sample_steps[:nd], chain_buf, chain_src)
             tokens = tokens.at[:nd].set(first)
             history = jnp.concatenate([hist, history[nd:]])
             limit = jnp.concatenate([pos_limit[:nd], jnp.repeat(pos_limit[nd:], tc)])
             slot_mapping = jnp.where(positions < limit, slot_mapping, 0)  # _step's finish-line clamp
+            if two_pool:
+                kept.update(window_tables=rest[-2].reshape(nd + nc, n), window_pages=self.window_pages,
+                            window_slots=jnp.where(positions < limit, rest[-1], 0))
             logits, k_cache, v_cache, *counts = llama.forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping,
                 last_idx, attn_impl=self.attn_impl, split=(nd, nc, tc), **counted, **kept,
@@ -563,12 +597,13 @@ class ModelRunner:
             column-0 token is sourced per ``chain_src`` (the buffer's last
             part) from ``chain_buf`` where the overlapped loop dispatches a
             step before the one before it has reached the host."""
-            *args, chain_src = _unpack(packed, b, t, n, h, slots=recurrent)
+            args = list(_unpack(packed, b, t, n, h, slots=recurrent, pools=two_pool))
             kept = {}
-            if recurrent:  # the rows' state slots ride last, behind the chain sources
-                slot_ids = chain_src
-                *args, chain_src = args
-                kept = {"recurrent": (*state, slot_ids)}
+            if two_pool:  # the window pool's tables and slots ride last of all
+                kept.update(window_slots=args.pop().reshape(b, t), window_tables=args.pop().reshape(b, n))
+            if recurrent:  # the rows' state slots ride behind the chain sources
+                kept["recurrent"] = (*state, args.pop())
+            chain_src = args.pop()
             # args: 0=tokens, 9=sample_steps, 13=history (see _pack order).
             args[0], args[13] = _chain_rows(args[0], args[13], args[9], chain_buf, chain_src)
             out = _step(params, k_cache, v_cache, *args, impl=self.attn_impl, lp_k=lp_k, **kept)
@@ -584,7 +619,7 @@ class ModelRunner:
                                    history, mrope_delta=None,
                                    mm_embeds=None, mm_slot_offset=None, mm_counts=None,
                                    mrope_positions=None, la_masks=None, la_groups=None,
-                                   *, impl, lp_k=0):
+                                   window_tables=None, window_slots=None, *, impl, lp_k=0):
             """Explicit-args chained step: mesh runners (the packed buffer
             cannot be row-sharded) and any chained dispatch carrying extras
             the packed buffer has no slots for — multimodal embeds, explicit
@@ -605,7 +640,7 @@ class ModelRunner:
                 slot_mapping, last_idx, temperature, top_k, top_p, seeds,
                 sample_steps, freq_pen, pres_pen, pos_limit, history, mrope_delta,
                 mm_embeds, mm_slot_offset, mm_counts, mrope_positions, logit_mask,
-                impl=impl, lp_k=lp_k,
+                window_tables, window_slots, impl=impl, lp_k=lp_k,
             )
 
         self._step_chained_explicit_fn = _step_chained_explicit
@@ -615,7 +650,8 @@ class ModelRunner:
                        verify_indices, temperature, top_k, top_p, seeds, sample_steps,
                        freq_pen, pres_pen, history, mrope_delta=None,
                        mm_embeds=None, mm_slot_offset=None, mm_counts=None,
-                       mrope_positions=None, logit_mask=None, *, impl, lp_k=0):
+                       mrope_positions=None, logit_mask=None, window_tables=None, window_slots=None,
+                       *, impl, lp_k=0):
             """Speculative verify: one forward scoring V candidate positions
             per row, then a target sample at every one of them.
 
@@ -640,6 +676,8 @@ class ModelRunner:
                     mrope_positions if mrope_positions is not None
                     else _delta_mrope(positions, mrope_delta)
                 )
+            if window_tables is not None:
+                mm_kw.update(window_tables=window_tables, window_slots=window_slots, window_pages=self.window_pages)
             logits, k_cache, v_cache = self._forward(
                 params, self.cfg, tokens, positions, k_cache, v_cache,
                 block_tables, slot_mapping, verify_indices[:, 0],
@@ -680,7 +718,7 @@ class ModelRunner:
                                tokens, positions, block_tables, slot_mapping,
                                verify_indices, temperature, top_k, top_p, seeds,
                                sample_steps, freq_pen, pres_pen, history,
-                               mrope_delta=None, *, impl, lp_k=0):
+                               mrope_delta=None, window_tables=None, window_slots=None, *, impl, lp_k=0):
             """Chained speculative verify: decode rows' column-0 (bonus/base)
             token gathers from the previous dispatch's device-resident
             samples; draft columns 1..K and prefill-chunk rows feed from host
@@ -692,7 +730,7 @@ class ModelRunner:
                 params, k_cache, v_cache, tokens, positions, block_tables,
                 slot_mapping, verify_indices, temperature, top_k, top_p, seeds,
                 sample_steps, freq_pen, pres_pen, history, mrope_delta,
-                impl=impl, lp_k=lp_k,
+                window_tables=window_tables, window_slots=window_slots, impl=impl, lp_k=lp_k,
             )
 
         self._spec_step_chained_fn = _spec_step_chained
@@ -701,7 +739,7 @@ class ModelRunner:
         def _multi_step(params, k_cache, v_cache, tokens, positions, block_tables,
                         temperature, top_k, top_p, seeds, sample_steps,
                         freq_pen, pres_pen, pos_limit, history, mrope_delta=None,
-                        *, num_steps):
+                        window_tables=None, *, num_steps):
             """``num_steps`` fused decode iterations in one dispatch.
 
             The sampled token of step i is step i+1's input; slot mapping is
@@ -726,6 +764,10 @@ class ModelRunner:
                 mm_kw = {}
                 if self.cfg.mrope_section:
                     mm_kw["mrope_positions"] = _delta_mrope(pos[:, None], mrope_delta)
+                if window_tables is not None:  # the sliding layers' pool: the same derivation from its own table
+                    wpage = jnp.take_along_axis(window_tables, (pos // ps)[:, None], axis=1)[:, 0]
+                    wslot = jnp.where(pos < pos_limit, wpage * ps + pos % ps, 0)
+                    mm_kw.update(window_tables=window_tables, window_slots=wslot[:, None], window_pages=self.window_pages)
                 logits, kc, vc = self._forward(
                     params, self.cfg, tok[:, None], pos[:, None], kc, vc,
                     block_tables, slot[:, None], zeros, attn_impl=self.attn_impl,
@@ -754,11 +796,13 @@ class ModelRunner:
         def _multi_step_packed(params, k_cache, v_cache, packed, *, b, t, n, h, num_steps):
             (tokens, positions, block_tables, _slot, _last,
              temperature, top_k, top_p, seeds, sample_steps,
-             freq_pen, pres_pen, pos_limit, history, mrope_delta, _src) = _unpack(packed, b, t, n, h)
+             freq_pen, pres_pen, pos_limit, history, mrope_delta, _src, *pools) = _unpack(
+                 packed, b, t, n, h, pools=two_pool)
             return _multi_step(
                 params, k_cache, v_cache, tokens[:, 0], positions[:, 0], block_tables,
                 temperature, top_k, top_p, seeds, sample_steps,
-                freq_pen, pres_pen, pos_limit, history, mrope_delta, num_steps=num_steps,
+                freq_pen, pres_pen, pos_limit, history, mrope_delta,
+                pools[0].reshape(b, n) if two_pool else None, num_steps=num_steps,
             )
 
         self._multi_step_packed_fn = _multi_step_packed
@@ -819,9 +863,16 @@ class ModelRunner:
 
     # -- tier access (block manager offload/onboard) -----------------------
 
+    def _refuse_two_pool(self, what: str) -> None:
+        if self.two_pool:
+            raise NotImplementedError(
+                f"{self.cfg.name}: {what} is not served for a model with a page pool per layer kind (window and "
+                "full layers mixed): a page id names a page of one kind's pool, and a block's window pages may be gone")
+
     @_locked
     def read_page(self, page_id: int) -> tuple[np.ndarray, np.ndarray]:
         """Device->host copy of one page: ([L, ps, kv, hd], [L, ps, kv, hd])."""
+        self._refuse_two_pool("reading a page across layers (offload, transfer)")
         return (
             np.asarray(self.k_cache[:, page_id]),
             np.asarray(self.v_cache[:, page_id]),
@@ -850,6 +901,7 @@ class ModelRunner:
         """
         if not page_ids:
             return InFlightPages(None, None, 0)
+        self._refuse_two_pool("reading pages across layers (offload, transfer)")
         n = len(page_ids)
         padded = np.zeros(next_pow2(n), np.int32)
         padded[:n] = page_ids
@@ -864,6 +916,7 @@ class ModelRunner:
     @_locked
     def write_page(self, page_id: int, k: np.ndarray, v: np.ndarray) -> None:
         """Host->device copy into one page (in place via buffer donation)."""
+        self._refuse_two_pool("writing a page across layers (onboard, transfer)")
         self.k_cache, self.v_cache = self._write_page_fn(
             self.k_cache, self.v_cache, jnp.asarray(k), jnp.asarray(v), page_id
         )
@@ -878,6 +931,7 @@ class ModelRunner:
         """
         if not page_ids:
             return
+        self._refuse_two_pool("writing pages across layers (onboard, transfer)")
         n = len(page_ids)
         k_stack = np.stack(ks, axis=1) if isinstance(ks, (list, tuple)) else ks
         v_stack = np.stack(vs, axis=1) if isinstance(vs, (list, tuple)) else vs
@@ -924,6 +978,11 @@ class ModelRunner:
         bp = self._bucket_batch(b)
         tp = self._bucket_time(t)
         np_ = self._bucket_pages(batch.block_tables.shape[1])
+        if self.two_pool and batch.window_block_tables is None and batch.block_tables.any():
+            # Only a batch of null rows (a warm-up) may leave the window pool's
+            # tables out: real rows would read and write its null page.
+            raise ValueError(f"{self.cfg.name}: a batch for a model with a page pool per layer kind names pages of the "
+                             "full pool and carries no window_block_tables / window_slot_mapping")
         hp = next_pow2(batch.history.shape[1])  # 1 when no penalties in batch
         mm = None
         if batch.mm_embeds is not None:
@@ -987,6 +1046,10 @@ class ModelRunner:
             spec_start=None if batch.spec_start is None else pad1(batch.spec_start, bp),
             state_slots=(None if not self.recurrent else np.zeros(bp, np.int32) if batch.state_slots is None
                          else pad1(batch.state_slots.astype(np.int32), bp)),
+            window_block_tables=(None if not self.two_pool else np.zeros((bp, np_), np.int32)
+                                 if batch.window_block_tables is None else pad2(batch.window_block_tables, bp, np_)),
+            window_slot_mapping=(None if not self.two_pool else np.zeros((bp, tp), np.int32)
+                                 if batch.window_slot_mapping is None else pad2(batch.window_slot_mapping, bp, tp)),
         )
 
     # -- execution ---------------------------------------------------------
@@ -1300,6 +1363,7 @@ class ModelRunner:
                     self.params, self.k_cache, self.v_cache, *inputs,
                     opt(padded.mm_embeds), opt(padded.mm_slot_offset), opt(padded.mm_counts),
                     opt(padded.mrope_positions), opt(padded.logit_mask),
+                    opt(padded.window_block_tables), opt(padded.window_slot_mapping),
                     impl=impl, lp_k=lp_k,
                 )
             out = self._keep_moe_counts(self._keep_state(out))
@@ -1372,6 +1436,7 @@ class ModelRunner:
                 put(padded.mrope_delta),
                 opt(padded.mm_embeds), opt(padded.mm_slot_offset), opt(padded.mm_counts),
                 opt(padded.mrope_positions), opt(padded.logit_mask),
+                opt(padded.window_block_tables), opt(padded.window_slot_mapping),
                 impl=impl, lp_k=lp_k,
             )
         self._mark_wait()
@@ -1419,6 +1484,7 @@ class ModelRunner:
                     put(padded.freq_pen), put(padded.pres_pen),
                     put(padded.pos_limit), put(padded.history),
                     put(padded.mrope_delta),
+                    None if padded.window_block_tables is None else put(padded.window_block_tables),
                     num_steps=num_steps,
                 )
             else:
@@ -1536,6 +1602,7 @@ class ModelRunner:
                         opt(padded.mm_embeds), opt(padded.mm_slot_offset),
                         opt(padded.mm_counts), opt(padded.mrope_positions),
                         opt(padded.la_masks), opt(padded.la_groups),
+                        opt(padded.window_block_tables), opt(padded.window_slot_mapping),
                         impl=impl, lp_k=lp_k,
                     )
                 else:
@@ -1545,6 +1612,7 @@ class ModelRunner:
                         opt(padded.mm_embeds), opt(padded.mm_slot_offset),
                         opt(padded.mm_counts), opt(padded.mrope_positions),
                         opt(padded.logit_mask),
+                        opt(padded.window_block_tables), opt(padded.window_slot_mapping),
                         impl=impl, lp_k=lp_k,
                     )
             chain_buf = out[0]  # [Bp], the rows in order: a shape of its own
@@ -1615,18 +1683,20 @@ class ModelRunner:
                 put(padded.freq_pen), put(padded.pres_pen), put(padded.history),
                 put(padded.mrope_delta),
             )
+            pools = {} if padded.window_block_tables is None else dict(
+                window_tables=put(padded.window_block_tables), window_slots=put(padded.window_slot_mapping))
             if chain:
                 out = self._enqueue(
                     self._spec_step_chained_fn,
                     self.params, self.k_cache, self.v_cache,
                     self._chain_tokens, put(src), *explicit,
-                    impl=impl, lp_k=lp_k,
+                    impl=impl, lp_k=lp_k, **pools,
                 )
             else:
                 out = self._enqueue(
                     self._spec_step_fn,
                     self.params, self.k_cache, self.v_cache, *explicit,
-                    impl=impl, lp_k=lp_k,
+                    impl=impl, lp_k=lp_k, **pools,
                 )
         if lp_k:
             targets, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out

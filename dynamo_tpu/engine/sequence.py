@@ -36,6 +36,10 @@ class Sequence:
     num_cached: int = 0  # tokens whose KV is in the paged cache
     num_cached_at_start: int = 0  # prefix-cache hits at admission (for usage stats)
     pages: list[int] = field(default_factory=list)
+    # A model with a page pool per layer kind: the sliding layers' pages, one
+    # entry a block as ``pages`` has (so the two block tables keep one shape);
+    # a block wholly under the window is the null page 0, its page given back.
+    window_pages: list[int] = field(default_factory=list)
     committed_pages: int = 0  # pages already committed to the prefix cache
     # A model with recurrent layers: the slot that holds this sequence's state
     # while it runs (0 = none: waiting, preempted or finished).

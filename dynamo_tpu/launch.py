@@ -312,6 +312,13 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
             mesh=mesh,
             device=device,
             cache_dtype=_kv_cache_dtype(),
+            # What bounds a row's step, for a mixed model's window pool
+            # (llama.window_pool_pages), where pages behind the window are
+            # given back and prompts are chunked at all: the chunk beside
+            # decoding rows, ``max_prefill_tokens`` in a step without any
+            # (the chunk controller never goes above its base).
+            window_chunk=(max(spec.engine_config.chunk_prefill_tokens, spec.engine_config.max_prefill_tokens)
+                          if spec.engine_config.swa_free_pages and spec.engine_config.chunk_prefill_tokens > 0 else None),
         )
 
     runner = await asyncio.get_running_loop().run_in_executor(None, _build)

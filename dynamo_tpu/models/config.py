@@ -14,6 +14,9 @@ from typing import Any
 
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+#: Families whose full-attention layers take no rotary embedding where window
+#: and full layers are mixed (EXAONE 4.0's hybrid attention and its MoE successor).
+NOPE_IN_FULL_LAYERS = ("exaone4", "exaone_moe")
 
 
 def _split_rope(params: dict) -> tuple[float, dict | None]:
@@ -32,16 +35,22 @@ def _attention_layout(config: dict) -> dict:
     window = int(config.get("sliding_window") or 0)
     kinds = config.get("layer_types")
     mlp_kinds = config.get("mlp_layer_types")
-    if mlp_kinds is not None and (len(mlp_kinds) != n or set(mlp_kinds) - {"sparse"}):
-        raise ValueError(
-            f"mlp_layer_types {sorted(set(mlp_kinds))} over {len(mlp_kinds)} entries: only 'sparse' "
-            f"in each of the {n} layers is supported")
-    if kinds is not None:
-        kinds = tuple(kinds)
-        unknown = sorted(set(kinds) - {SLIDING, FULL})
-        if unknown or len(kinds) != n:
+    # A per-layer list may run past ``num_hidden_layers`` (a file that serves the
+    # model's first layers keeps the published lists whole): the entries of
+    # the layers held are read, a list shorter than them is refused.
+    if mlp_kinds is not None:
+        # The leading ``first_k_dense_replace`` layers are dense, every later one routed.
+        k_dense = int(config.get("first_k_dense_replace", 0) or 0)
+        if len(mlp_kinds) < n or list(mlp_kinds[:n]) != ["dense"] * min(k_dense, n) + ["sparse"] * max(n - k_dense, 0):
             raise ValueError(
-                f"layer_types holds {unknown or len(kinds)}: expected {n} entries of "
+                f"mlp_layer_types {sorted(set(mlp_kinds))} over {len(mlp_kinds)} entries: only 'dense' in the first "
+                f"{k_dense} layers (first_k_dense_replace) and 'sparse' in each later one of the {n} is supported")
+    if kinds is not None:
+        held, kinds = len(kinds), tuple(kinds[:n])
+        unknown = sorted(set(kinds) - {SLIDING, FULL})
+        if unknown or held < n:
+            raise ValueError(
+                f"layer_types holds {unknown or held}: expected {n} entries of "
                 f"{SLIDING!r} / {FULL!r}")
         if SLIDING in kinds and not config.get("use_sliding_window", True):
             raise ValueError("use_sliding_window is false but layer_types names sliding_attention layers")
@@ -49,6 +58,14 @@ def _attention_layout(config: dict) -> dict:
             raise ValueError("layer_types names sliding_attention layers but sliding_window is not set")
         if SLIDING not in kinds:
             window = 0
+        # Where a config states the windows or the pattern a second time, they agree with layer_types.
+        per_layer = config.get("sliding_windows")
+        if per_layer is not None and list(per_layer[:n]) != [window if k == SLIDING else 0 for k in kinds]:
+            raise ValueError(f"sliding_windows {list(per_layer)[:8]}... disagrees with layer_types and sliding_window {window}")
+        pattern = config.get("sliding_window_pattern")
+        if isinstance(pattern, str) and pattern and tuple(
+                {"L": SLIDING, "G": FULL}.get(pattern[i % len(pattern)]) for i in range(n)) != kinds:
+            raise ValueError(f"sliding_window_pattern {pattern!r} disagrees with layer_types")
         if len(set(kinds)) == 1:
             kinds = ()  # all alike: the one global window says it all
     else:
@@ -76,6 +93,14 @@ def _attention_layout(config: dict) -> dict:
         else:  # the top-level pair mirrors the full layers; forward reads by kind
             theta, scaling = ropes[FULL]
             out["rope_parameters"] = {k: dict(by_kind[k]) for k in sorted(used)}
+    elif by_kind and config.get("model_type") in NOPE_IN_FULL_LAYERS and len(set(kinds)) > 1:
+        # One flat set, which is the sliding layers': the full layers do not
+        # rotate (an identity table is a kind like any other, models/llama.py).
+        theta, scaling = _split_rope(by_kind)
+        if scaling is not None:
+            raise ValueError(f"rope_type {scaling.get('rope_type', scaling.get('type'))!r} is not served for "
+                             f"model_type {config['model_type']!r}: only 'default' in its sliding layers")
+        out["rope_parameters"] = {FULL: {"rope_type": "nope", "rope_theta": theta}, SLIDING: dict(by_kind)}
     elif by_kind:  # one flat set of parameters for every layer
         theta, scaling = _split_rope(by_kind)
     elif "rope_theta" in config:
@@ -167,8 +192,9 @@ class ModelConfig:
     # Attention kind of each layer where kinds are mixed in one model:
     # "sliding_attention" (the last `sliding_window` positions) or
     # "full_attention", one entry per layer. () = every layer alike (windowed
-    # iff sliding_window > 0). One page-id space serves both kinds: every
-    # layer caches every token, sliding layers read only their window.
+    # iff sliding_window > 0). A mixed model's cache is a page pool and a block
+    # table per kind (models/llama.init_kv_cache): the full layers' pool seats
+    # the context, the sliding layers' a window of pages a row.
     layer_types: tuple = ()
     # RoPE per attention kind where the kinds differ (HF `rope_parameters`
     # keyed by kind: rope_theta plus the rope_scaling keys). None = the one
@@ -287,13 +313,22 @@ class ModelConfig:
             return self.rope_theta, self.rope_scaling
         return _split_rope(self.rope_parameters[kind])
 
-    def kv_bytes_per_token(self, itemsize: int | None = None) -> int:
-        """Bytes of KV cache per token across all layers (2 = K and V; MLA
-        caches one latent + rope key instead). ``itemsize`` overrides the
-        dtype-derived cache element size (e.g. a bf16 cache for an f32
-        model)."""
+    def cache_layers_of(self, kind: str) -> int:
+        """Cache slabs of one attention kind (``SLIDING`` / ``FULL``) in a mixed model."""
+        return sum(k == kind for k in self.layer_types)
+
+    def kv_bytes_per_token(self, itemsize: int | None = None, kind: str | None = None) -> int:
+        """Bytes of KV cache a token of context adds (2 = K and V; MLA caches
+        one latent + rope key instead): over all layers, and in a model that
+        mixes window and full layers over the full layers, whose pool seats
+        the context (a sliding layer holds a window of pages a row however
+        long the context: ``kind=SLIDING`` gives a token's bytes in those).
+        ``itemsize`` overrides the dtype-derived cache element size (e.g. a
+        bf16 cache for an f32 model)."""
         if itemsize is None:
             itemsize = 2 if self.dtype == "bfloat16" else 4
+        if self.mixed_attention:
+            return 2 * self.cache_layers_of(kind or FULL) * self.kv_dim * itemsize
         if self.attn_type == "mla":
             # Physical bytes: the rope stream is padded to one 128-lane tile
             # (models/mla.py:mla_cache_widths — Mosaic DMA alignment).
@@ -509,9 +544,13 @@ class ModelConfig:
         # the publisher's own setting and decides nothing here).
         experts_total = expert_first = 0
         if not all_dense and "n_routed_experts_published" in config:
-            n_experts, total, expert_first = _expert_share(config)
+            n_experts, total, expert_first = _expert_share(
+                config, "n_routed_experts" if "n_routed_experts" in config else "num_experts")
             experts_total = total if total != n_experts else 0
         n_group, topk_group = _group_limit(config, n_experts, experts_total or n_experts) if n_experts else (0, 0)
+        if n_experts and not n_group and int(config.get("topk_group", 0) or 0) > 1:
+            raise ValueError(f"topk_group {config['topk_group']!r} over n_group {config.get('n_group')!r} is not served: "
+                             "one group is no group limit, and takes topk_group 1")
         return cls(
             name=name or config.get("_name_or_path", config.get("model_type", "model")),
             vocab_size=config["vocab_size"],
@@ -535,7 +574,8 @@ class ModelConfig:
             moe_intermediate_size=((config.get("moe_intermediate_size", 0) or 0) or config["intermediate_size"]) if n_experts else 0,
             # Qwen2-MoE names the width directly; DeepSeek counts experts.
             shared_expert_size=((config.get("shared_expert_intermediate_size", 0) or 0)
-            or (config.get("n_shared_experts", 0) or 0) * (config.get("moe_intermediate_size", 0) or 0)) if n_experts else 0,
+            or (config.get("n_shared_experts", config.get("num_shared_experts", 0)) or 0)
+            * (config.get("moe_intermediate_size", 0) or 0)) if n_experts else 0,
             shared_expert_gated=config.get("model_type") == "qwen2_moe",
             # Native transformers' DeepseekV3Config does not serialize
             # scoring_func (its modeling hardcodes sigmoid routing), so a
@@ -559,7 +599,7 @@ class ModelConfig:
             # e_score_correction_bias — key off model_type too.
             moe_router_bias=bool(n_experts) and (
                 config.get("topk_method", "") == "noaux_tc"
-                or config.get("model_type") == "deepseek_v3"
+                or config.get("model_type") in ("deepseek_v3", "exaone_moe")
             ),
             first_k_dense=0 if all_dense else first_dense,
             attention_bias=bool(config.get("attention_bias", config.get("model_type") in (
@@ -569,7 +609,7 @@ class ModelConfig:
             mlp_act="gelu_tanh" if config.get("model_type") == "gemma" else "silu",
             norm_plus_one=config.get("model_type") == "gemma",
             embed_scale=config.get("model_type") == "gemma",
-            qk_norm={"qwen3": "head", "qwen3_moe": "head", "olmoe": "flat"}.get(
+            qk_norm={"qwen3": "head", "qwen3_moe": "head", "exaone4": "head", "exaone_moe": "head", "olmoe": "flat"}.get(
                 config.get("model_type", ""), ""
             ),
             # DeepSeek-V2/V3: MLA signalled by the latent-rank keys.

@@ -6,10 +6,11 @@ Design (TPU-first, not a torch translation):
   forward pass is a ``lax.scan`` over layers. One layer gets traced/compiled
   regardless of depth — compile time is O(1) in ``num_layers`` (matters at
   70B/80-layer scale) and XLA schedules identical per-layer programs.
-- The KV cache is **paged** ([L, num_pages, page_size, n_kv, head_dim],
-  page-major — see ``ops/attention.py``) and flows through the scan carry;
-  each layer reads its slice and writes back via dynamic index updates,
-  which XLA aliases in place under buffer donation.
+- The KV cache is **paged** ([L, num_pages, page_size, n_kv * head_dim],
+  page-major — see ``ops/attention.py``; a model that mixes window and full
+  layers lays a pool per kind end to end, ``init_kv_cache``) and flows through
+  the scan carry flat; each layer addresses its own pages by offset and writes
+  back via scatter, which XLA aliases in place under buffer donation.
 - One forward function serves prefill (T>1) and decode (T=1); queries attend
   to the paged cache, so chunked prefill and prefix reuse need no extra code
   path (see ``dynamo_tpu/ops/attention.py``).
@@ -170,9 +171,49 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
     return params
 
 
-def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype: jnp.dtype | None = None):
+def window_pool_pages(cfg: ModelConfig, num_pages: int, page_size: int, rows: int, chunk_tokens: int | None) -> int:
+    """Pages of a mixed model's window pool, the null page included: a row
+    holds the pages its window and the chunk it is computing reach into and
+    the page being written, ``ceil((window + chunk) / page) + 1``, whatever its
+    context. Never more than the full pool's ``num_pages``, which is also what
+    an unknown chunk (``None``: nothing bounds a row's step) takes."""
+    if chunk_tokens is None:
+        return num_pages
+    return min(num_pages, rows * (-(-(cfg.sliding_window + chunk_tokens) // page_size) + 1) + 1)
+
+
+def pool_layout(cfg: ModelConfig, flat_pages: int, window_pages: int | None) -> tuple[list[int], int, int]:
+    """Where each layer of a mixed model starts in the flat cache: (each
+    layer's first page, pages a full layer holds, pages a sliding layer
+    holds). The full layers' pools lie first, then the sliding layers'.
+    ``window_pages`` None: both kinds hold the same number of pages."""
+    from dynamo_tpu.models.config import SLIDING
+
+    n_win = cfg.cache_layers_of(SLIDING)
+    n_full = cfg.num_layers - n_win
+    p_win = flat_pages // cfg.num_layers if window_pages is None else window_pages
+    p_full, rest = divmod(flat_pages - n_win * p_win, n_full)
+    if rest or p_full <= 0:
+        raise ValueError(f"a cache of {flat_pages} pages is not {n_full} full pools and {n_win} window pools of {p_win}")
+    bases, seen = [], {True: 0, False: 0}
+    for kind in cfg.layer_types:
+        win = kind == SLIDING
+        bases.append(n_full * p_full + seen[win] * p_win if win else seen[win] * p_full)
+        seen[win] += 1
+    return bases, p_full, p_win
+
+
+def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype: jnp.dtype | None = None,
+                  window_pages: int | None = None):
     """Allocate the paged KV cache: two [L, num_pages, page_size, n_kv * hd] arrays,
     L one slab per attention (sub)layer (``cfg.cache_layers``).
+
+    A model that mixes window and full layers (``cfg.mixed_attention``) has a
+    page pool per kind: two [1, n_full * num_pages + n_window * window_pages,
+    page_size, W] arrays, each full layer's ``num_pages`` end to end and then
+    each sliding layer's ``window_pages`` (``pool_layout``; None = as many as
+    ``num_pages``, so that one block table can serve both kinds). Page 0 of
+    every layer's pool is its null page.
 
     Page-major per layer with KV heads flattened into the trailing (lane)
     dimension — one page is a single contiguous ``ps x W`` slab covering all
@@ -195,6 +236,12 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype: jnp.d
             jnp.zeros((cfg.cache_layers, num_pages, page_size, wv), dt),
         )
     shape = (cfg.cache_layers, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
+    if cfg.mixed_attention:
+        from dynamo_tpu.models.config import SLIDING
+
+        n_win = cfg.cache_layers_of(SLIDING)
+        pages = (cfg.num_layers - n_win) * num_pages + n_win * (num_pages if window_pages is None else window_pages)
+        shape = (1, pages, *shape[2:])
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
@@ -342,8 +389,18 @@ def forward(
     split: tuple[int, int, int] | None = None,  # (decode slots, chunk slots, tokens per chunk slot)
     moe_counts: bool = False,  # also return the held-share expert layers' counters
     recurrent: tuple | None = None,  # (state, conv, slot ids i32[rows]) of a model with KDA layers
+    window_tables: jnp.ndarray | None = None,  # a mixed model's sliding layers: their block tables,
+    window_slots: jnp.ndarray | None = None,  # their slot mapping (shaped as the full layers')
+    window_pages: int | None = None,  # and the pages each of them holds (``init_kv_cache``)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One forward step. Returns (logits f32[B, vocab], k_cache, v_cache).
+
+    ``window_tables`` / ``window_slots`` (a model that mixes window and full
+    layers): the page ids of the sliding layers' pool, which holds a row's
+    window and not its context; a block wholly under the window is the null
+    page 0 (the kernels walk from the window's first block and the mask is by
+    position). Without them the sliding layers take the full layers' tables,
+    which a cache of equal pools (``window_pages`` None) seats.
 
     ``recurrent`` (a model with recurrent layers, ``cfg.layer_group_size``):
     the two state buffers of ``models/kda.init_state`` and each row's slot
@@ -488,10 +545,21 @@ def forward(
             [rope_frequencies(cfg.head_dim, theta=theta, scaling=scaling) for theta, scaling in ropes]))
         factors = [rope_attention_factor(scaling) ** 2 for _, scaling in ropes]
         which = [kinds.index(kind) for kind in cfg.layer_types]
+        # A pool per kind in the one flat cache: each layer's first page and
+        # which of the two block tables (and slot mappings) names its pages.
+        from dynamo_tpu.models.config import SLIDING
+
+        bases, _, _ = pool_layout(cfg, nl * npages, window_pages)
+        if window_tables is None:
+            window_tables, window_slots = block_tables, slot_mapping
+        elif split is not None:
+            window_slots = window_slots[None]
         layer_kinds = {
             "window": jnp.asarray([w or NO_WINDOW for w in cfg.layer_windows()], jnp.int32),
             "rope": jnp.asarray(which, jnp.int32),
             "mscale": jnp.asarray([factors[i] for i in which], jnp.float32),
+            "base": jnp.asarray(bases, jnp.int32),
+            "windowed": jnp.asarray([k == SLIDING for k in cfg.layer_types], jnp.bool_),
         }
 
     mla = cfg.attn_type == "mla"
@@ -614,13 +682,20 @@ def forward(
                     k = apply_rope(k, positions, inv_freq)
                 if kind is None and attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
                     q = q * jnp.asarray(attn_mscale, q.dtype)
-                k_full, v_full = write_kv(k_full, v_full, k, v, slot_mapping + li * (npages * ps))
+                if kind is None:
+                    slots_l = slot_mapping + li * (npages * ps)
+                else:  # the layer's own pool: its kind's slots, from its first page
+                    slots_l = jnp.where(kind["windowed"], window_slots, slot_mapping) + kind["base"] * ps
+                k_full, v_full = write_kv(k_full, v_full, k, v, slots_l)
                 if ring:
                     from dynamo_tpu.parallel.ring import ring_attention
 
                     attn = ring_attention(q, k, v, ring_pos, mesh, scale=cfg.head_dim**-0.5)
                 else:
-                    tables_l = block_tables + li * npages
+                    if kind is None:
+                        tables_l = block_tables + li * npages
+                    else:  # and its kind's block table
+                        tables_l = jnp.where(kind["windowed"], window_tables, block_tables) + kind["base"]
                     # 0 = full causal; a mixed model hands each layer its own
                     # (a runtime scalar: NO_WINDOW in its full layers).
                     window = cfg.sliding_window if kind is None else kind["window"]

@@ -560,7 +560,7 @@ def load_params(
 
 #: Architectures ``ModelConfig.from_hf`` reads whose checkpoint weight names
 #: have no map in ``_leaf_specs`` (no published list of them to work from).
-UNMAPPED_MODEL_TYPES = frozenset({"mellum"})
+UNMAPPED_MODEL_TYPES = frozenset({"mellum", "exaone_moe"})
 
 
 def load_model(

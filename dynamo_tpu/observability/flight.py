@@ -57,7 +57,8 @@ STEP_KEYS = (
     "dispatch_ms", "attn_phase", "attn_path", "moe_path",
     "kv_tokens_full", "kv_tokens_window", "step_tokens",
     "moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched",
-    "moe_extra_passes", "state_rows", "state_slots_live", "layout",
+    "moe_extra_passes", "state_rows", "state_slots_live",
+    "full_pages_live", "window_pages_live", "window_pages_released", "layout",
     "admitted", "deferred", "deadline_slack_ms", "cached_frac", "gap_ms",
     "overlap_mode", "barrier_reason", "chained_rows",
     "t0_ns", "ann_ns", "traced", "phases_us",

@@ -94,6 +94,13 @@ class EngineMetrics:
         # A model with recurrent layers: its second kind of per-sequence state (all 0 for every other model).
         self.state_slots_total = gauge(f"{ns}_state_slots", "Recurrent-state slots a running sequence can take (the null slot apart)")
         self.state_slots_live = gauge(f"{ns}_state_slots_live", "Recurrent-state slots held by live sequences")
+        # A model with a page pool per layer kind: the sliding layers' pool beside the one above (all 0 for every other model).
+        self.window_pages_total = gauge(f"{ns}_window_pages_total", "Allocatable pages of the window pool (sliding layers of a mixed model)")
+        self.window_pages_free = gauge(f"{ns}_window_pages_free", "Window-pool pages on the free list")
+        self.window_pages_cached = gauge(f"{ns}_window_pages_cached", "Evictable prefix-cache pages of the window pool")
+        self.window_pages_active = gauge(f"{ns}_window_pages_active", "Window-pool pages referenced by live sequences")
+        self.window_pages_released = gauge(
+            f"{ns}_window_pages_released_total", "Window-pool pages given back as they fell wholly behind the window")
         self.prefix_matching_off = gauge(
             f"{ns}_prefix_matching_off_by_model",
             "1 where prefix caching is configured on and the model switches matching off (recurrent layers: pages alone bring back no state)",
@@ -433,6 +440,13 @@ class EngineMetrics:
         self.pages_cached.set(stats.cached_pages)
         self.pages_active.set(stats.active_pages)
         self.page_utilization.set(stats.active_pages / stats.total_pages if stats.total_pages else 0.0)
+        window = getattr(core, "window_allocator", None)
+        wstats = window.stats() if window is not None else None
+        self.window_pages_total.set(wstats.total_pages if wstats else 0)
+        self.window_pages_free.set(wstats.free_pages if wstats else 0)
+        self.window_pages_cached.set(wstats.cached_pages if wstats else 0)
+        self.window_pages_active.set(wstats.active_pages if wstats else 0)
+        self.window_pages_released.set(getattr(core, "window_pages_released", 0))
         slots = getattr(core, "state_slots", None)
         self.state_slots_total.set(slots.total if slots is not None else 0)
         self.state_slots_live.set(slots.live if slots is not None else 0)

@@ -27,6 +27,8 @@ def rope_frequencies(
     rope_type = (scaling or {}).get("rope_type", (scaling or {}).get("type"))
     if rope_type in (None, "none", "default"):
         pass
+    elif rope_type == "nope":  # no rotation: every angle 0, the table of the identity
+        inv_freq = np.zeros_like(inv_freq)
     elif rope_type == "llama3":
         factor = float(scaling["factor"])
         lo = float(scaling["low_freq_factor"])

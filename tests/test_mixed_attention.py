@@ -224,14 +224,20 @@ def test_a_model_without_a_windowed_layer_counts_no_key_tokens():
     assert core.runner._kv_pending is None
 
 
-def test_mixed_model_releases_no_page_and_a_uniform_window_still_does():
+def test_mixed_model_keeps_its_full_pages_and_releases_its_window_pages():
+    """A pool per layer kind (ISSUE 42): the full layer reads the whole
+    context, so no page of its pool goes; the sliding layers' pages go back to
+    their own pool once they lie wholly behind the window of 8."""
     cfg = _toy()
     entries, core = _served_logprobs(cfg, _weights(cfg), list(range(1, 41)), 8, chunk=12)
-    assert len(entries) == 8 and core.config.swa_free_pages
-    seq = type("S", (), {"tokens": list(range(48)), "pages": list(range(1, 13)), "committed_pages": 12})()
-    before = core.allocator.num_free()
+    assert len(entries) == 8 and core.config.swa_free_pages and core.window_allocator is not None
+    seq = type("S", (), {"tokens": list(range(48)), "num_cached": 47, "pages": list(range(1, 13)),
+                         "window_pages": core.window_allocator.allocate(12), "committed_pages": 12})()
+    before, window_before = core.allocator.num_free(), core.window_allocator.num_free()
     core._release_out_of_window(seq)
     assert seq.pages == list(range(1, 13)) and core.allocator.num_free() == before
+    assert seq.window_pages[:10] == [0] * 10 and 0 not in seq.window_pages[10:]  # (48 - 8) // 4 pages behind the window
+    assert core.window_allocator.num_free() == window_before + 10 and core.window_pages_released >= 10
 
 
 # -- a model whose layers are all alike -----------------------------------------

@@ -272,6 +272,14 @@ EXPECTED_ENGINE_FAMILIES = {
     "dynamo_engine_state_slots",
     "dynamo_engine_state_slots_live",
     "dynamo_engine_prefix_matching_off_by_model",
+    # The second page pool of a model that mixes window and full layers
+    # (ISSUE 42): its AllocatorStats and the pages given back behind the
+    # window; 0 for every other model.
+    "dynamo_engine_window_pages_total",
+    "dynamo_engine_window_pages_free",
+    "dynamo_engine_window_pages_cached",
+    "dynamo_engine_window_pages_active",
+    "dynamo_engine_window_pages_released_total",
 }
 
 

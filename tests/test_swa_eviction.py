@@ -114,18 +114,39 @@ def test_released_pages_evictable_while_stream_still_running():
     assert ctrl.num_preemptions > 0, "control must actually be page-starved"
 
 
-def test_mixed_window_and_full_layers_release_nothing():
-    """One page-id space for all layers: while any layer reads the whole
-    context, no page is handed back, whatever the sliding layers' window (the
-    uniform-window model above still releases)."""
+def test_mixed_window_and_full_layers_release_the_window_pool_alone():
+    """A page pool per layer kind (ISSUE 42): the full layer reads the whole
+    context, so its pool's pages stay while the sequence lives; the sliding
+    layer's pages go back to their own pool behind the window, as the
+    uniform-window model's one pool's do, and the tokens are what they are
+    with nothing released."""
     from dynamo_tpu.models.config import FULL, SLIDING
 
     mixed = dataclasses.replace(CFG, layer_types=(SLIDING, FULL))
-    runner = ModelRunner(mixed, PARAMS, num_pages=64, page_size=PAGE, max_batch_size=2,
-                         prefill_bucket=16, attn_impl="reference")
-    core = EngineCore(runner, EngineConfig(num_pages=64, page_size=PAGE, max_batch_size=2, max_prefill_tokens=64,
-                                           max_seq_len=128, decode_steps=2, swa_free_pages=True))
-    toks, _seq, (live, zeros) = _generate(core)
+
+    def core_of(swa_free: bool):
+        runner = ModelRunner(mixed, PARAMS, num_pages=64, page_size=PAGE, max_batch_size=2,
+                             prefill_bucket=16, attn_impl="reference")
+        return EngineCore(runner, EngineConfig(num_pages=64, page_size=PAGE, max_batch_size=2, max_prefill_tokens=64,
+                                               max_seq_len=128, decode_steps=2, swa_free_pages=swa_free))
+
+    core = core_of(True)
+    window_zeros = []
+    seq = core.add_request(PreprocessedRequest(
+        token_ids=[3, 5, 7, 11, 13, 2, 4, 6], sampling=SamplingOptions(temperature=0.0),
+        stop=StopConditions(max_tokens=40, ignore_eos=True)), Context())
+    toks, zeros, live = [], [], []
+    while core.has_work:
+        for _s, out in core.step():
+            toks.extend(out.token_ids)
+        if seq.pages:
+            zeros.append(seq.pages.count(0))
+            window_zeros.append(seq.window_pages.count(0))
+            live.append(len(seq.pages))
     assert len(toks) == 40 and max(zeros) == 0 and live[-1] >= (8 + 40) // PAGE - 1
+    assert 0 < max(window_zeros) <= core.window_pages_released
+    assert core.allocator.live == 0 and core.window_allocator.live == 0
+    base, _seq, (_live, base_zeros) = _generate(core_of(False))
+    assert base == toks and max(base_zeros) == 0
     _toks, _s, (_live, uniform_zeros) = _generate(_core(swa_free=True))
     assert max(uniform_zeros) > 0

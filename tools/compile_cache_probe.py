@@ -71,7 +71,11 @@ def child(config: str, rows: list[int]) -> int:
     like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
     i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
     params = like(jax.eval_shape(lambda: lay_heads_major(weights.make_weights(mc, 0, quant=conf["serve"]["quant"]))))  # as the runner lays it
-    kc, vc = like(jax.eval_shape(lambda: llama.init_kv_cache(mc, eng["pool_tokens"] // page_size + 1, page_size)))
+    pool_pages = eng["pool_tokens"] // page_size + 1
+    # A model with a page pool per layer kind: the window pool the serving path derives (launch.py).
+    window_pages = (llama.window_pool_pages(mc, pool_pages, page_size, eng["max_batch_size"], chunk)
+                    if mc.mixed_attention else None)
+    kc, vc = like(jax.eval_shape(lambda: llama.init_kv_cache(mc, pool_pages, page_size, window_pages=window_pages)))
     counted = {"moe_counts": True} if mc.moe_held_share else {}
     programs = []
     for r in rows:
@@ -79,9 +83,12 @@ def child(config: str, rows: list[int]) -> int:
             kept = {}
             if mc.layer_group_size:
                 kept["recurrent"] = (*like(jax.eval_shape(lambda: kda.init_state(mc, eng["max_batch_size"] + 1))), i32(slots))
+            if window_pages is not None:
+                kept.update(window_tables=i32(slots, pages_per_row), window_slots=i32(*toks))
+                counted = {**counted, "window_pages": window_pages}
             fn = functools.partial(llama.forward, cfg=mc, attn_impl="pallas", split=split, **counted)
             before, t0 = listing(path), time.time()
-            jax.jit(fn, donate_argnames=("k_cache", "v_cache", *kept)).lower(
+            jax.jit(fn, donate_argnames=("k_cache", "v_cache", *(k for k in kept if k == "recurrent"))).lower(
                 params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=kc, v_cache=vc,
                 block_tables=i32(slots, pages_per_row), slot_mapping=i32(*toks), last_token_index=i32(slots), **kept,
             ).compile()

@@ -134,6 +134,12 @@ async def amain(args) -> int:
                    # every collection from the lead-in's start to the window's end, however short
                    "collections_by_generation": [b - a for a, b in zip(gc0[0], gc1[0])],
                    "collection_ms_by_generation": [round((b - a) / 1e6, 3) for a, b in zip(gc0[1], gc1[1])]}
+            decode = [s for s in steps if s["step_kind"] == "decode"]
+            if decode:  # what the median gap turns on, beside it: the decode steps' own period and what they read
+                row["decode"] = {"steps": len(decode),
+                                 "period_p50_ms": stats.percentile([s["wall_ms"] + s["gap_ms"] for s in decode], 50),
+                                 **{k: sum(s.get(k, 0) for s in decode) / len(decode)
+                                    for k in ("decode_rows", "kv_tokens_full", "kv_tokens_window", "moe_experts_touched")}}
             names = [m["name"] for m in bench_run.cell_metrics(bench, "per_layer", cell)
                      if traced and not rehearsal or m["source"] == "program_counter"]
             row["metrics"] = {n: plugins.load("layer_metrics", n).read(ctx) for n in names}
@@ -162,6 +168,7 @@ async def amain(args) -> int:
     print(json.dumps({"long_steps_run": [{k: r[k] for k in ("seed", "traced", "out_tok_s", "itl_p50_ms", "engine_steps",
                                                              "collections_by_generation", "collection_ms_by_generation")}
                                          | {"long_steps": len(r["long_steps"]), "pauses": len(r["pauses"]),
+                                            "decode": r.get("decode"),
                                             **{k: v for k, v in r["metrics"].items() if "long_step" in k or "gc_pause" in k}}
                                          for r in table]}))
     return 0

@@ -29,6 +29,14 @@ fit beside; here each leaf goes as soon as it is re-coded). The KV pool is cut
 to ``--pool-tokens`` in this process only, to leave room for the reference at
 2,308 tokens. Run by hand on the chip; ``JAX_PLATFORMS=cpu`` rehearses at the
 configuration's toy size with lengths cut by its check scale.
+
+``--where`` (PR 42) says where a reading comes from: for every compared token
+the served logprobs against the float32 reference, and beside it the reference
+against *itself* with its matmuls at bfloat16 precision on the same weights
+and ids. A token at which the reference moves as far under rounding alone as
+the served path lies from it is a token the sequence makes sensitive (a
+near-tie in a router or under a sharp softmax upstream), not a fault of the
+served path.
 """
 
 from __future__ import annotations
@@ -105,6 +113,46 @@ async def serve_together(service, conf: dict, seed: int, *, scale: float) -> dic
             "spans": [(len(p) - 1, len(row)) for p, row in zip(prompts, served)]}
 
 
+def where(conf: dict, params, sample: dict) -> dict:
+    """Per compared token, as shares of the largest reference logit: the served
+    logprobs' distance from the float32 reference (largest, mean and mean
+    signed over the ids the engine named) and the distance of the reference
+    computed with bfloat16 matmuls from the float32 one over the same ids."""
+    import functools
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import correct
+
+    exact, absmax = correct.reference_logprobs(conf, params, sample["sequences"], sample["spans"])
+    ref = importlib.import_module(f"benchmark.reference.{conf['reference']}")
+    rounded = jax.jit(functools.partial(ref.forward, hf=conf["hf"]))
+    tokens = []
+    with jax.default_matmul_precision("bfloat16"):
+        for i, (seq, (first, steps)) in enumerate(zip(sample["sequences"], sample["spans"])):
+            toks = np.zeros(max(correct.PAD_TO, len(seq)), np.int32)
+            toks[: len(seq)] = seq
+            logits = np.asarray(rounded(params, tokens=jnp.asarray(toks))[first: first + steps], np.float32)
+            z = logits - logits.max(axis=-1, keepdims=True)
+            low = z - np.log(np.exp(z).sum(axis=-1, keepdims=True))
+            for j, e in enumerate(sample["served"][i]):
+                ids = [e["id"]] + [t for t, _ in e["top"]]
+                got = np.asarray([e["logprob"]] + [lp for _, lp in e["top"]], np.float64)
+                served, own = got - exact[i][j, ids], low[j, ids] - exact[i][j, ids]
+                tokens.append({"sequence": i, "position": first + j,
+                               "served_max": float(np.abs(served).max() / absmax),
+                               "served_mean": float(np.abs(served).mean() / absmax),
+                               "served_signed_mean": float(served.mean() / absmax),
+                               "reference_bf16_max": float(np.abs(own).max() / absmax),
+                               "reference_bf16_mean": float(np.abs(own).mean() / absmax)})
+    return {"ref_logit_absmax": absmax, "tokens": tokens,
+            "served_max": max(t["served_max"] for t in tokens),
+            "reference_bf16_max": max(t["reference_bf16_max"] for t in tokens)}
+
+
 async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> list[dict]:
     from benchmark import correct, serving, weights
 
@@ -142,6 +190,8 @@ async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> li
                      "attn_paths": sorted({s["attn_path"] for s in steps if s["attn_path"]}),
                      "moe_paths": sorted({s["moe_path"] for s in steps if s["moe_path"]}), **check})
         bench_run.say(long_check=rows[-1])
+    if args.where:
+        bench_run.say(where={"seed": seed, "variant": variant, **where(conf, params, sample)})
     _free(runner.params, params)
     return rows
 
@@ -182,5 +232,6 @@ if __name__ == "__main__":
     ap.add_argument("--first-seed", type=int, default=2600000003)
     ap.add_argument("--variants", default="long,fp8kv,int4w")
     ap.add_argument("--pool-tokens", type=int, default=12288)
+    ap.add_argument("--where", action="store_true", help="per compared token: served and bfloat16 reference against float32")
     os.environ.setdefault("DYN_FLIGHT_BUFFER", "65536")
     sys.exit(asyncio.run(amain(ap.parse_args())))

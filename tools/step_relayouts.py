@@ -266,7 +266,9 @@ def step_texts(mc, *, rows: int, chunk: int, pages_per_row: int, pool_pages: int
 
     # (the tree as an unsharded runner lays it: engine/runner.py)
     params = like(jax.eval_shape(lambda: lay_heads_major(weights.make_weights(mc, 0, quant=quant))))
-    kc, vc = like(jax.eval_shape(lambda: llama.init_kv_cache(mc, pool_pages, page_size)))
+    # A model with a page pool per layer kind: the window pool the serving path derives (launch.py).
+    window_pages = llama.window_pool_pages(mc, pool_pages, page_size, rows, chunk) if mc.mixed_attention else None
+    kc, vc = like(jax.eval_shape(lambda: llama.init_kv_cache(mc, pool_pages, page_size, window_pages=window_pages)))
     i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
     counted = {"moe_counts": True} if mc.moe_held_share else {}  # as engine/runner.py asks
 
@@ -277,7 +279,9 @@ def step_texts(mc, *, rows: int, chunk: int, pages_per_row: int, pool_pages: int
             from dynamo_tpu.models import kda
 
             kept["recurrent"] = (*like(jax.eval_shape(lambda: kda.init_state(mc, rows + 1))), i32(slots))
-        return jax.jit(fn, donate_argnames=("k_cache", "v_cache", "recurrent")).lower(
+        if window_pages is not None:
+            kept.update(window_tables=i32(slots, pages_per_row), window_slots=i32(*toks), window_pages=window_pages)
+        return jax.jit(fn, donate_argnames=("k_cache", "v_cache", "recurrent"), static_argnames=("window_pages",)).lower(
             params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=kc, v_cache=vc,
             block_tables=i32(slots, pages_per_row), slot_mapping=i32(*toks), last_token_index=i32(slots), **kept,
         ).compile().as_text()
