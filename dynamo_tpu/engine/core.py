@@ -780,6 +780,7 @@ class EngineCore:
                 attn_phase=report.attn_phase,
                 attn_path=report.attn_path,
                 moe_path=report.moe_path,
+                router_select=report.router_select,
                 kv_tokens_full=report.kv_tokens_full,
                 kv_tokens_window=report.kv_tokens_window,
                 step_tokens=report.step_tokens,

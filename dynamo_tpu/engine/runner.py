@@ -29,7 +29,7 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.observability.compile import CompileTracker, timed_dispatch
 from dynamo_tpu.ops.sampling import sample_tokens
-from dynamo_tpu.parallel.moe import HELD_COUNTS
+from dynamo_tpu.parallel.moe import HELD_COUNTS, router_select
 
 logger = logging.getLogger(__name__)
 
@@ -341,6 +341,7 @@ class DispatchReport:
     attn_phase: str = ""  # "decode" / "verify" / "prefill"
     attn_path: str = ""  # "pallas" / "fallback" / "ring"
     moe_path: str = ""  # "fused" / "widened"; "" for a dense model
+    router_select: str = ""  # "passes" / "sort": how the router takes the step's tokens' top k; "" for a dense model
     layout: str = ""  # ROWS_X_T / SPLIT
     step_tokens: int = 0  # token positions the dispatched program computes
     kv_tokens_full: int = 0  # key tokens one full / one windowed attention (sub)layer visits
@@ -481,6 +482,9 @@ class ModelRunner:
         from dynamo_tpu.parallel.moe import experts_path
 
         self.moe_path = experts_path(params.get("layers", {}), mesh=mesh)
+        # The router's outputs (0 for a dense model): what a step's ``router_select`` label turns on.
+        router = params.get("layers", {}).get("router")
+        self._router_outputs = 0 if router is None else int(router.shape[-1])
 
         def _sample(logits, k_cache, v_cache, temperature, top_k, top_p, seeds, sample_steps,
                     freq_pen, pres_pen, history, logit_mask, lp_k):
@@ -1149,6 +1153,7 @@ class ModelRunner:
             report = self._report = DispatchReport(moe_path=self.moe_path)
         report.attn_phase, report.attn_path = self._attn_dispatch(padded, impl, verify=verify)
         report.layout, report.step_tokens = layout
+        report.router_select = router_select(report.step_tokens, self._router_outputs, self.cfg.num_experts_per_token)
         if padded.state_slots is not None:
             report.state_rows = int(np.count_nonzero(padded.state_slots))
         timed = timed_dispatch(self.compile_tracker, program, key)

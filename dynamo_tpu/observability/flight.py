@@ -54,7 +54,7 @@ STEP_KEYS = (
     "outputs", "waiting", "running", "prefilling", "free_pages", "preemptions",
     "admission_rejections", "mixed_steps", "stall_violations",
     "spec_drafted", "spec_accepted", "spec_accept_rate", "wall_ms",
-    "dispatch_ms", "attn_phase", "attn_path", "moe_path",
+    "dispatch_ms", "attn_phase", "attn_path", "moe_path", "router_select",
     "kv_tokens_full", "kv_tokens_window", "step_tokens",
     "moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched",
     "moe_extra_passes", "state_rows", "state_slots_live",

@@ -135,26 +135,101 @@ def route_tokens(
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"unknown moe scoring {scoring!r}")
-    choice = scores + lp["router_bias"] if "router_bias" in lp else scores
-    if n_group > 1 and 0 < topk_group < n_group:
-        n, e = choice.shape
-        grouped = choice.reshape(n, n_group, e // n_group)
-        if group_score == "top2sum":
-            gscore = jax.lax.top_k(grouped, min(2, e // n_group))[0].sum(-1)  # [N, G]
-        else:
-            gscore = grouped.max(-1)
-        _, gidx = jax.lax.top_k(gscore, topk_group)
-        gmask = jnp.zeros_like(gscore, dtype=bool).at[
-            jnp.arange(n)[:, None], gidx
-        ].set(True)
-        choice = jnp.where(
-            jnp.repeat(gmask, e // n_group, axis=1), choice, -jnp.inf
-        )
-    _, topi = jax.lax.top_k(choice, k)
-    weights = jnp.take_along_axis(scores, topi, axis=1)  # [N, k] unbiased
+    weights, topi = select_experts(
+        scores, lp.get("router_bias"), form=router_select(scores.shape[0], scores.shape[1], k),
+        k=k, n_group=n_group, topk_group=topk_group, group_score=group_score)
     if norm_topk:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     return weights * scaling, topi
+
+
+#: Where the router takes its experts by passes of ``max``: from this many
+#: outputs, and from this many entries of the step's scores (tokens x outputs)
+#: a pass. ``lax.top_k``'s sort grows with the tokens and the passes hardly
+#: do; under both lines the sort is as cheap or cheaper. Timed on a v5e at
+#: 1-2,048 tokens x 64-768 outputs (``tools/router_select_bench.py``; PERF.md, PR 43).
+PASSES_FROM_OUTPUTS = 256
+PASSES_FROM_ENTRIES_A_PASS = 2048
+
+
+def router_select(tokens: int, outputs: int, k: int) -> str:
+    """How :func:`route_tokens` takes ``k`` experts for each of ``tokens``
+    tokens from a router of ``outputs`` outputs: ``"passes"``, ``k`` passes of
+    ``max`` over the row; ``"sort"``, ``lax.top_k``, which this chip lowers to
+    a sort of every row, and a gather for the weights; ``""`` without a
+    router. Both give the same ids, order and weights bit for bit; only the
+    cost differs, by the shape. The one predicate behind both the selection
+    and a step's ``router_select`` label (``ModelRunner._dispatch``)."""
+    if not outputs:
+        return ""
+    wide = outputs >= PASSES_FROM_OUTPUTS and tokens * outputs >= PASSES_FROM_ENTRIES_A_PASS * k
+    return "passes" if wide else "sort"
+
+
+def _best_groups_only(choice: jnp.ndarray, n_group: int, topk_group: int, group_score: str) -> jnp.ndarray:
+    """``choice`` [N, E] with every expert outside the row's best
+    ``topk_group`` of ``n_group`` groups at ``-inf``, without a sort or a
+    scatter: what ``top_k`` of the groups' scores keeps, ties to the lower
+    group as ``top_k`` breaks them."""
+    n, e = choice.shape
+    grouped = choice.reshape(n, n_group, e // n_group)
+    gscore = grouped.max(-1)  # [N, G]
+    if group_score == "top2sum" and e // n_group > 1:
+        # The second of a group's top two: the maximum again where two entries hold it, else the
+        # largest below it (``top_k(grouped, 2)[0].sum(-1)`` value for value).
+        at_max = grouped == gscore[..., None]
+        below = jnp.where(at_max, -jnp.inf, grouped).max(-1)
+        gscore = gscore + jnp.where(at_max.sum(-1, dtype=jnp.int32) > 1, gscore, below)
+    # A group's rank is the number of groups that beat it: a higher score, or the same and a lower index.
+    g = jnp.arange(n_group, dtype=jnp.int32)
+    mine, other = gscore[:, :, None], gscore[:, None, :]
+    beaten_by = (other > mine) | ((other == mine) & (g[None, :] < g[:, None]))  # [N, G, G]
+    kept = beaten_by.sum(-1, dtype=jnp.int32) < topk_group
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+
+
+def _take_by_passes(choice: jnp.ndarray, scores: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``lax.top_k(choice, k)``'s ids (descending, the lower index first among
+    equals) and ``scores`` at them, by ``k`` passes over the row: each takes
+    the first index of the maximum among the entries not yet taken, and the
+    score there as a sum with one non-zero term. The passes run on the floats'
+    bits as integers in the floats' order, so that a taken entry can be marked
+    by a value no entry has (the least integer): ``-inf`` would not do, the
+    group limit writes it, and a row whose finite entries run out still returns
+    what ``top_k`` returns. Each pass compiles to one fusion."""
+    lane = jnp.arange(choice.shape[-1], dtype=jnp.int32)
+    bits = jax.lax.bitcast_convert_type(choice, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)  # ordered as the floats are
+    ids, weights = [], []
+    for _ in range(k):
+        first = jnp.argmax(key, axis=-1, keepdims=True).astype(jnp.int32)  # [N, 1]
+        hit = lane == first
+        key = jnp.where(hit, jnp.iinfo(jnp.int32).min, key)
+        ids.append(first)
+        weights.append(jnp.where(hit, scores, 0.0).sum(-1, keepdims=True))
+    return jnp.concatenate(weights, axis=1), jnp.concatenate(ids, axis=1)
+
+
+def select_experts(
+    scores: jnp.ndarray,  # f32[N, E]
+    bias: jnp.ndarray | None,  # f32[E]: added for the choice, never for the weight
+    *,
+    form: str,  # :func:`router_select`'s word
+    k: int,
+    n_group: int = 0,
+    topk_group: int = 0,
+    group_score: str = "max",
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The selection of :func:`route_tokens` alone: ``(scores at the chosen
+    experts f32[N, k], expert ids i32[N, k])``, the ids as ``lax.top_k`` of
+    the biased, group-limited scores orders them."""
+    choice = scores if bias is None else scores + bias
+    if n_group > 1 and 0 < topk_group < n_group:
+        choice = _best_groups_only(choice, n_group, topk_group, group_score)
+    if form == "passes":
+        return _take_by_passes(choice, scores, k)
+    _, topi = jax.lax.top_k(choice, k)
+    return jnp.take_along_axis(scores, topi, axis=1), topi  # [N, k] unbiased
 
 
 def _widen(lp: dict, dtype) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:

@@ -254,3 +254,106 @@ def test_drop_fraction_estimator():
         capacity=32 * CFG.num_experts_per_token,
     )
     assert dropped2 == 0
+
+
+def _route_tokens_by_sorts(lp, x, *, k, scoring="softmax", norm_topk=True, scaling=1.0, n_group=0, topk_group=0,
+                           group_score="max", f32_logits=False):
+    """``route_tokens`` as it stood before its selection went to passes of
+    ``max`` (PR 43), kept line for line: three ``top_k``, a scatter for the
+    groups' mask, a gather for the weights. The oracle of the parity cases."""
+    if f32_logits:
+        logits = jnp.dot(x, lp["router"], preferred_element_type=jnp.float32)
+    else:
+        logits = (x @ lp["router"]).astype(jnp.float32)  # [N, E]
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    choice = scores + lp["router_bias"] if "router_bias" in lp else scores
+    if n_group > 1 and 0 < topk_group < n_group:
+        n, e = choice.shape
+        grouped = choice.reshape(n, n_group, e // n_group)
+        if group_score == "top2sum":
+            gscore = jax.lax.top_k(grouped, min(2, e // n_group))[0].sum(-1)  # [N, G]
+        else:
+            gscore = grouped.max(-1)
+        _, gidx = jax.lax.top_k(gscore, topk_group)
+        gmask = jnp.zeros_like(gscore, dtype=bool).at[
+            jnp.arange(n)[:, None], gidx
+        ].set(True)
+        choice = jnp.where(
+            jnp.repeat(gmask, e // n_group, axis=1), choice, -jnp.inf
+        )
+    _, topi = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, topi, axis=1)  # [N, k] unbiased
+    if norm_topk:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return weights * scaling, topi
+
+
+# (tokens, router outputs, has a bias, route_tokens' keywords): the benchmark's cells' routers
+# (Ling, Ling's groups ranked as DeepSeek-V2 ranks them, JoyAI, LongCat, K-EXAONE, OLMoE, Mellum2),
+# one row, and a group limit that leaves a row fewer finite entries than it takes.
+ROUTERS = {
+    "ling-64x512-top2sum": (64, 512, True, dict(k=8, scoring="sigmoid", scaling=2.5, n_group=8, topk_group=4,
+                                                group_score="top2sum", f32_logits=True)),
+    "groups-by-max-64x512": (64, 512, False, dict(k=8, scoring="sigmoid", n_group=8, topk_group=4)),
+    "joyai-64x256": (64, 256, True, dict(k=8, scoring="sigmoid", scaling=2.5, f32_logits=True)),
+    "longcat-64x768": (64, 768, True, dict(k=12, norm_topk=False, scaling=6.0, f32_logits=True)),
+    "exaone-8x128": (8, 128, True, dict(k=8, scoring="sigmoid", scaling=2.5, f32_logits=True)),
+    "mellum2-48x64": (48, 64, False, dict(k=8)),
+    "olmoe-48x64": (48, 64, False, dict(k=8, norm_topk=False)),
+    "one-row-1x512": (1, 512, True, dict(k=8, scoring="sigmoid", n_group=8, topk_group=4, group_score="top2sum")),
+    "finite-run-out-16x32": (16, 32, True, dict(k=8, scoring="sigmoid", n_group=8, topk_group=1,
+                                                group_score="top2sum")),
+    "groups-of-one-16x8": (16, 8, False, dict(k=2, scoring="sigmoid", n_group=8, topk_group=3,
+                                              group_score="top2sum")),
+}
+
+
+@pytest.mark.parametrize("form", ["served", "passes", "sort"])
+@pytest.mark.parametrize("ties", [False, True], ids=["continuous", "ties"])
+@pytest.mark.parametrize("router", ROUTERS)
+def test_route_tokens_selects_what_the_sorts_selected(router, ties, form, monkeypatch):
+    """The selection by passes of ``max`` (and the groups' by rank) gives the
+    three sorts' ids, in their order, and their weights, bit for bit: as the
+    predicate dispatches it (``served``) and with either form forced. With
+    ``ties`` the logits take five values and the bias three, so experts,
+    groups and a group's top two tie everywhere."""
+    from dynamo_tpu.parallel import moe
+
+    n, e, has_bias, kw = ROUTERS[router]
+    rng = np.random.default_rng(sorted(ROUTERS).index(router) * 2 + ties)
+    logits = rng.standard_normal((n, e)) * 2.0
+    bias = rng.standard_normal(e) * 0.05
+    if ties:
+        logits, bias = np.round(logits / 2.0) * 2.0, np.round(bias * 10.0) / 10.0
+    # An identity router: the logits are what the test drew, in either precision of the product.
+    lp = {"router": jnp.eye(e, dtype=jnp.float32)}
+    if has_bias:
+        lp["router_bias"] = jnp.asarray(bias, jnp.float32)
+    x = jnp.asarray(logits, jnp.float32)
+    if form != "served":
+        monkeypatch.setattr(moe, "router_select", lambda *a, **k: form)
+    want_w, want_i = jax.jit(lambda lp, x: _route_tokens_by_sorts(lp, x, **kw))(lp, x)
+    got_w, got_i = jax.jit(lambda lp, x: moe.route_tokens(lp, x, **kw))(lp, x)
+    if ties:  # the case is what it says: some row's k-th and (k+1)-th choices tie
+        ranked = np.sort(np.asarray(jax.nn.sigmoid(x) if kw.get("scoring") == "sigmoid" else x), axis=1)[:, ::-1]
+        assert (ranked[:, kw["k"] - 1] == ranked[:, kw["k"]]).any()
+    assert got_i.dtype == want_i.dtype and got_w.dtype == want_w.dtype
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert np.array_equal(np.asarray(got_w), np.asarray(want_w))
+
+
+def test_a_row_whose_finite_entries_run_out_returns_what_top_k_returns():
+    """A group limit that keeps 4 finite entries of a row that takes 8: the
+    passes go on into the ``-inf`` entries in index order, as ``top_k`` does,
+    and never take an entry twice."""
+    from dynamo_tpu.parallel import moe
+
+    n, e, _, kw = ROUTERS["finite-run-out-16x32"]
+    scores = jnp.asarray(np.random.default_rng(5).random((n, e)), jnp.float32)
+    got_w, got_i = moe.select_experts(scores, None, form="passes", k=kw["k"], n_group=8, topk_group=1)
+    want_w, want_i = moe.select_experts(scores, None, form="sort", k=kw["k"], n_group=8, topk_group=1)
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i)) and np.array_equal(np.asarray(got_w), np.asarray(want_w))
+    assert all(len(set(row)) == kw["k"] for row in np.asarray(got_i).tolist())
+    best = np.asarray(scores).reshape(n, 8, 4).max(-1).argmax(-1)  # the kept group, then the row's first entries
+    assert all(sorted(row[:4]) == list(range(4 * g, 4 * g + 4)) for row, g in zip(np.asarray(got_i).tolist(), best))
+    assert all(row[4:] == [i for i in range(e) if i // 4 != g][:4] for row, g in zip(np.asarray(got_i).tolist(), best))

@@ -1152,3 +1152,58 @@ def test_step_records_carry_moe_path_beside_attn_path(case, want, monkeypatch):
     assert dispatched and all("moe_path" in r for r in records)
     assert {r["moe_path"] for r in dispatched} == {want}
     assert all(r["moe_path"] == "" for r in records if not r["attn_path"])
+
+
+@pytest.mark.parametrize("case", ["dense", "narrow", "grouped_wide"])
+def test_step_records_carry_router_select_beside_moe_path(case, monkeypatch):
+    """Every STEP record that dispatched says how the router took the step's
+    experts: "" for a dense model, "sort" (``lax.top_k``) for a narrow router
+    at any size, and for a wide, group-limited one "passes" on the step that
+    holds the prompt's 16 tokens and "sort" on the one-row decode steps. The
+    label and ``route_tokens`` read one predicate on the same numbers: every
+    program traced asked ``parallel/moe.router_select`` with its record's
+    ``step_tokens`` and got the record's word."""
+    import dataclasses
+
+    from dynamo_tpu.engine.core import EngineConfig, EngineCore
+    from dynamo_tpu.engine.runner import ModelRunner
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import PRESETS
+    from dynamo_tpu.observability.flight import STEP
+    from dynamo_tpu.parallel import moe
+    from dynamo_tpu.protocols.common import PreprocessedRequest, SamplingOptions, StopConditions
+
+    cfg = {"dense": PRESETS["test-tiny"], "narrow": PRESETS["test-tiny-moe"],
+           "grouped_wide": dataclasses.replace(PRESETS["test-tiny-v3"], num_experts=256, moe_n_group=8,
+                                               moe_topk_group=4, moe_intermediate_size=8)}[case]
+    traced = {}
+    predicate = moe.router_select
+
+    def recording(tokens, outputs, k):
+        traced[tokens, outputs, k] = predicate(tokens, outputs, k)
+        return traced[tokens, outputs, k]
+
+    monkeypatch.setattr(moe, "router_select", recording)  # what route_tokens asks while a program is traced
+    runner = ModelRunner(cfg, llama.init_params(cfg, 0), num_pages=32, page_size=4, max_batch_size=4,
+                         prefill_bucket=16, attn_impl="reference")
+    core = EngineCore(runner, EngineConfig(num_pages=32, page_size=4, max_batch_size=4,
+                                           max_prefill_tokens=64, max_seq_len=64))
+    core.add_request(PreprocessedRequest(
+        token_ids=list(range(1, 14)), sampling=SamplingOptions(temperature=0.0), stop=StopConditions(max_tokens=3)))
+    for _ in range(16):
+        if not core.has_work:
+            break
+        core.step()
+    records = core.flight.snapshot(kind=STEP)
+    dispatched = [r for r in records if r["attn_path"]]
+    assert dispatched and all(r["router_select"] == "" for r in records if not r["attn_path"])
+    said = {(r["step_tokens"], r["router_select"]) for r in dispatched}
+    want = {"dense": {""}, "narrow": {"sort"}, "grouped_wide": {"passes", "sort"}}[case]
+    assert {word for _, word in said} == want, said
+    if case == "dense":
+        assert not traced
+        return
+    outputs, k = cfg.num_experts, cfg.num_experts_per_token
+    assert {(tokens, outputs, k): word for tokens, word in said} == traced
+    if case == "grouped_wide":
+        assert (16, "passes") in said and min(said)[1] == "sort"

@@ -142,7 +142,10 @@ def check(conf: dict, seed: int, tokens: int) -> dict:
         shared = np.asarray(jax.jit(lambda layers, h: llama._shared_expert(program_layer(cfg, layers), h, cfg))(layers, h),
                             np.float32)
         with jax.default_matmul_precision("highest"):
-            want_shared = np.asarray(jax.jit(ref.shared_expert_term)(hf32, lp0))
+            # (Ling-3.0-flash's reference has the same term inline in its ``routed_ffn``.)
+            shared_term = getattr(ref, "shared_expert_term", lambda h, lp: ref.swiglu_of(
+                h, lp, "w_shared_gate", "w_shared_up", "w_shared_down"))
+            want_shared = np.asarray(jax.jit(shared_term)(hf32, lp0))
         row.update(shared=far(shared, want_shared), shared_absmax=float(np.abs(want_shared).max()))
         want = want + want_shared
     row["whole"] = far(whole, want)
