@@ -52,6 +52,13 @@ Design (fresh, built around the engine's page-major cache layout):
   Ring slot is a pure function of the global block index (a prefix count
   over earlier sequences and splits), so there is no mutable cross-step
   state and the kernel is interpret-mode exact.
+- A row's walk copies only the pages some query of the row may see
+  (:func:`decode_walk`): in its first block not the page slots under the
+  window, in its last not the slots past its farthest token; the V rows of
+  those slots are zeroed in VMEM and the block is contracted whole. What
+  the walk counts by (a row's first and last page, its blocks, the blocks
+  of the rows before) is worked out by the wrapper and prefetched
+  (``docs/KERNELS.md``, "The walk's tail and the window's head").
 
 Replaces the role of vLLM's paged-attention CUDA kernel in the reference
 stack (SURVEY.md §2 row 30, §7 hard part (a); `lib/llm/src/kernels/` is the
@@ -69,6 +76,7 @@ import functools
 import logging
 import os
 import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -200,8 +208,8 @@ def _pages_per_block(
     compile with "scoped vmem ... exceeded"), so when ``width`` is given
     the block shrinks to keep the tiles within an 8 MiB budget (deeper
     rings trade block depth for pipeline depth at constant VMEM). No
-    divisibility requirement — the tail block clamps its page indices and
-    masks by length."""
+    divisibility requirement — a row's last block copies only the page slots
+    the row holds (:func:`decode_walk`) and masks by position."""
     target = max(1, 1024 // page_size)
     if width is not None:
         budget = 8 * 2**20
@@ -234,14 +242,58 @@ def _lse_combine(acc: jnp.ndarray, m: jnp.ndarray, l: jnp.ndarray, *, guard_empt
     return num / denom[..., None]
 
 
+class DecodeWalk(NamedTuple):
+    """What a call's block walk counts by, per row (each i32[B]): worked out
+    once by the wrapper from the query positions and the window, prefetched by
+    the kernel, and read by the tools and tests that count the pages a call
+    moves."""
+
+    first_page: jnp.ndarray  # first page some real query of the row may see
+    last_page: jnp.ndarray  # page of the row's farthest query token
+    first_block: jnp.ndarray  # first block the walk visits (blocks are absolute: page // pages_per_block)
+    blocks: jnp.ndarray  # blocks the walk visits
+    blocks_before: jnp.ndarray  # blocks of the rows before: the row's first global block
+
+    @property
+    def pages_started(self):
+        """Pages the call copies: the held ones, each once."""
+        return jnp.sum(self.last_page - self.first_page + 1)
+
+
+def decode_walk(positions: jnp.ndarray, page_size: int, pages_per_block: int, window=None) -> DecodeWalk:
+    """The walk of ``positions`` i32[B, T_q] (padding columns at position 0).
+
+    A row holds pages ``first_page .. last_page``: from the oldest key its
+    oldest real query may see (``first - window + 1``; page 0 without a window,
+    and for ``NO_WINDOW``) to its farthest query token. Its walk visits the
+    blocks that hold them, and in a visited block only held page slots are
+    copied: ``pages_started`` of ``sum(blocks) * pages_per_block`` slots."""
+    last_page = jnp.maximum(jnp.max(positions, axis=1), 0) // page_size
+    if window is None:
+        first_page = jnp.zeros_like(last_page)
+    else:
+        # The row's oldest real query: padding columns carry position 0 and
+        # trail the real ones, so column 0 and the non-zero entries are the
+        # candidates (a padding row is all zeros: it holds page 0).
+        first_pos = jnp.minimum(
+            positions[:, 0], jnp.min(jnp.where(positions > 0, positions, jnp.iinfo(jnp.int32).max), axis=1))
+        first_page = jnp.maximum(first_pos - jnp.asarray(window, jnp.int32) + 1, 0) // page_size
+    first_block = first_page // pages_per_block
+    blocks = last_page // pages_per_block + 1 - first_block
+    return DecodeWalk(first_page, last_page, first_block, blocks, jnp.cumsum(blocks) - blocks)
+
+
 def _decode_kernel(
-    # scalar prefetch (SMEM, shared by all grid steps)
-    lengths_ref,  # i32[B] per-sequence walk length (max row position + 1)
+    # scalar prefetch (SMEM, shared by all grid steps): the walk (DecodeWalk)
+    last_ref,  # i32[B] the row's last held page
+    blocks_ref,  # i32[B] blocks the row's walk visits
+    before_ref,  # i32[B] blocks of the rows before
     tables_ref,  # i32[B * pages_per_seq]
     qpos_ref,  # i32[B * t_q] absolute position of each query token
     *refs,
-    # windowed only, two more prefetched scalars first:
-    #   first_ref i32[B] smallest real query position of the row
+    # windowed only, three more prefetched scalars first:
+    #   head_ref i32[B] the row's first held page
+    #   lo_ref i32[B] the first block the row's walk visits
     #   window_ref i32[1] the window in tokens
     # then, always, the blocked operands and the scratch:
     #   q_ref [t_q * n_heads, W] block-diagonal queries, W = n_kv * head_dim
@@ -261,83 +313,81 @@ def _decode_kernel(
     dma_depth: int,
 ):
     if windowed:
-        first_ref, window_ref, *refs = refs
+        head_ref, lo_ref, window_ref, *refs = refs
         window = window_ref[0]
     q_ref, k_hbm, v_hbm, acc_ref, m_ref, l_ref, k_buf, v_buf, k_sem, v_sem = refs
     b = pl.program_id(0)
     sp = pl.program_id(1)
     bk = pages_per_block * page_size  # tokens per compute block
 
-    def end_of(bb):  # one past the row's last block
-        return pl.cdiv(jnp.maximum(lengths_ref[bb], 1), bk)
-
     def lo_of(bb):
-        # Windowed: the walk starts at the block that holds the oldest key
-        # any real query of the row may see (first - window + 1); blocks
-        # wholly under the window are never fetched. lo < end always (the
-        # row's first query position is below its length).
-        if not windowed:
-            return 0
-        return jnp.maximum(first_ref[bb] - window + 1, 0) // bk
+        # Windowed: the walk starts at the block that holds the row's first
+        # held page; blocks wholly under the window are never visited.
+        return lo_ref[bb] if windowed else 0
 
-    def blocks_of(bb):  # blocks the row's walk visits
-        return end_of(bb) - lo_of(bb)
+    def end_of(bb):  # one past the row's last block
+        return lo_of(bb) + blocks_ref[bb]
 
-    nb_total = end_of(b)
     # Split sp walks block-in-sequence indices [first, first + nb_here).
     # Boundaries derive from the STATIC blocks_per_split, so a row's
     # accumulation order never depends on other rows' runtime lengths (a
     # windowed row's blocks simply fall in its last splits).
-    first = sp * blocks_per_split
-    if windowed:
-        lo = lo_of(b)
-        first = jnp.clip(first, lo, nb_total)
-        nb_here = jnp.clip(sp * blocks_per_split + blocks_per_split, lo, nb_total) - first
-        visited_before = first - lo
-    else:
-        nb_here = jnp.clip(nb_total - first, 0, blocks_per_split)
-        visited_before = jnp.minimum(first, nb_total)
+    lo, nb_total = lo_of(b), end_of(b)
+    first = jnp.clip(sp * blocks_per_split, lo, nb_total)
+    nb_here = jnp.clip(sp * blocks_per_split + blocks_per_split, lo, nb_total) - first
 
     # Ring slot is a pure function of the global block index (no mutable
     # cross-step state): VISITED blocks of earlier sequences plus of earlier
     # splits of this one. Splits partition each sequence's walk, so the
     # global order is plain (sequence, block-in-sequence) lexicographic.
-    g0 = (
-        jax.lax.fori_loop(0, b, lambda bb, acc: acc + blocks_of(bb), jnp.int32(0))
-        + visited_before
-    )
+    g0 = before_ref[b] + first - lo
 
-    def page_index(bb, ii, j):
-        # The tail block may reach past the sequence's allocated pages:
-        # clamp to the row's own used range (not just the table width) so
-        # the DMA never dereferences entries the engine didn't fill —
-        # sentinel-filled tables (-1 tails) are safe, not just zero-filled
-        # ones. Clamped tokens are masked out by the position check.
-        last = jnp.maximum(lengths_ref[bb] - 1, 0) // page_size
-        idx = jnp.minimum(ii * pages_per_block + j, last)
-        return tables_ref[bb * pages_per_seq + idx]
+    def held_slots(bb, ii):
+        """Per page slot of row bb's block ii, whether its page is one of the
+        row's: from the window's head to the row's last page. Starts and
+        waits both go by it, for the row whose block it is, so a copy is
+        waited on exactly where it was started; no table entry outside the
+        held range is read."""
+        base = ii * pages_per_block
+        hi = last_ref[bb] - base
+        held = [j <= hi for j in range(pages_per_block)]
+        if windowed:
+            lo_j = head_ref[bb] - base
+            held = [jnp.logical_and(h, j >= lo_j) for j, h in enumerate(held)]
+        return held
+
+    def page_rows(j):
+        return pl.ds(j * page_size, page_size)
+
+    def page_copies(slot, bb, ii, j):
+        page = tables_ref[bb * pages_per_seq + ii * pages_per_block + j]
+        return (
+            pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, page_rows(j), :], k_sem.at[slot]),
+            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, page_rows(j), :], v_sem.at[slot]),
+        )
 
     def start_block(slot, bb, ii):
-        for j in range(pages_per_block):
-            page = page_index(bb, ii, j)
-            rows = pl.ds(j * page_size, page_size)
-            pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, rows, :], k_sem.at[slot]
-            ).start()
-            pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, rows, :], v_sem.at[slot]
-            ).start()
+        for j, held in enumerate(held_slots(bb, ii)):
 
-    def wait_block(slot, bb, ii):
-        for j in range(pages_per_block):
-            page = page_index(bb, ii, j)
-            rows = pl.ds(j * page_size, page_size)
-            pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, rows, :], k_sem.at[slot]
-            ).wait()
-            pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, rows, :], v_sem.at[slot]
-            ).wait()
+            @pl.when(held)
+            def _():
+                for copy in page_copies(slot, bb, ii, j):
+                    copy.start()
+
+    def land_block(slot, bb, ii):
+        """Wait on the block's held pages. The ring rows of the others hold an
+        earlier block's values or nothing yet, and 0 * NaN is NaN: their V
+        rows are zeroed (their K rows only meet the mask's select)."""
+        for j, held in enumerate(held_slots(bb, ii)):
+
+            @pl.when(held)
+            def _():
+                for copy in page_copies(slot, bb, ii, j):
+                    copy.wait()
+
+            @pl.when(jnp.logical_not(held))
+            def _():
+                v_buf[slot, page_rows(j), :] = jnp.zeros((page_size, v_buf.shape[-1]), v_buf.dtype)
 
     def next_block(bb, ii):
         """Global-order successor of block (bb, ii): the sequence's next
@@ -378,6 +428,11 @@ def _decode_kernel(
     qpos = jnp.zeros((r_rows, 1), jnp.int32)
     for tt in range(t_q):
         qpos = jnp.where(row_t == tt, qpos_ref[b * t_q + tt], qpos)
+    if windowed:
+        # The newest key a query does NOT see. A real query's window starts
+        # at or past the row's first held page; a padding column (position 0)
+        # would reach under it, to pages this walk never copied.
+        under = jnp.maximum(qpos - window, head_ref[b] * page_size - 1)
 
     def body(i, carry):
         m, l, acc = carry
@@ -391,7 +446,7 @@ def _decode_kernel(
             bb, nxt = next_block(bb, nxt)
         start_ahead((g + dma_depth - 1) % dma_depth, bb, nxt)
 
-        wait_block(slot, b, ii)
+        land_block(slot, b, ii)
 
         k = k_buf[slot]  # [bk, W] cache dtype
         v = v_buf[slot]
@@ -406,7 +461,7 @@ def _decode_kernel(
         kpos = ii * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos <= qpos  # per-row causal horizon
         if windowed:
-            mask = jnp.logical_and(mask, kpos > qpos - window)
+            mask = jnp.logical_and(mask, kpos > under)
         s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))  # [R, 1]
         # Mask p explicitly: in an all-masked block s == m_new == NEG_INF
@@ -487,9 +542,10 @@ def paged_decode_attention(
     ``window`` (None = full causal, today's program unchanged) is a runtime
     scalar, so one compiled program serves layers of different windows under
     a layer scan; a value past every position (``NO_WINDOW``) is full
-    attention. A windowed row's block walk starts at the block that holds
+    attention. A windowed row's block walk starts at the page that holds
     ``first query position - window + 1`` and the in-block mask adds
-    ``kpos > position - window``: blocks under the window cost no DMA.
+    ``kpos > position - window``: pages under the window cost no DMA, and
+    their table entries are never read.
 
     Positions may be gappy per row (speculative verify batches, padding
     columns) — causality is per query token. Cache layout matches the
@@ -512,10 +568,6 @@ def paged_decode_attention(
 
     kf, vf = k_cache, v_cache
 
-    # Walk length covers the row's farthest query token (max, not last:
-    # padding columns carry position 0); rows mask their own horizon.
-    lengths = jnp.max(positions, axis=1) + 1
-
     # Block-diagonal query staging: row t * n_heads + (kv * G + g) occupies
     # lane strip [kv*hd, (kv+1)*hd). One einsum against eye(n_kv); XLA
     # fuses it. Scale in f32, then store in the cache dtype so the kernel's
@@ -530,15 +582,15 @@ def paged_decode_attention(
         "btkgd,kK->btkgKd", q5.reshape(b, t_q, n_kv, group, head_dim), eye
     ).reshape(b, r_rows, width).astype(q_dtype)
 
+    # What the walk counts by is worked out here, once: the kernel's scalar
+    # core then does no division and no loop over earlier rows between the
+    # copies. The walk covers the row's farthest query token (max, not last:
+    # padding columns carry position 0); rows mask their own horizon.
     windowed = window is not None
-    prefetch = [lengths, block_tables.reshape(-1), positions.reshape(-1)]
+    walk = decode_walk(positions, page_size, ppb, window)
+    prefetch = [walk.last_page, walk.blocks, walk.blocks_before, block_tables.reshape(-1), positions.reshape(-1)]
     if windowed:
-        # The row's oldest real query: padding columns carry position 0 and
-        # trail the real ones, so column 0 and the non-zero entries are the
-        # candidates (a padding row is all zeros: it walks from block 0).
-        first_pos = jnp.minimum(
-            positions[:, 0], jnp.min(jnp.where(positions > 0, positions, jnp.iinfo(jnp.int32).max), axis=1))
-        prefetch += [first_pos, jnp.asarray(window, jnp.int32).reshape(1)]
+        prefetch += [walk.first_page, walk.first_block, jnp.asarray(window, jnp.int32).reshape(1)]
 
     q_spec = pl.BlockSpec((None, r_rows, width), lambda bb, ss, *_: (bb, 0, 0))
     acc_spec = pl.BlockSpec((None, None, r_rows, width), lambda bb, ss, *_: (bb, ss, 0, 0))
@@ -558,7 +610,7 @@ def paged_decode_attention(
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # lengths, flat block table, query positions (+ first position, window)
+            # the walk's counts, flat block table, query positions (+ the window's head, window)
             num_scalar_prefetch=len(prefetch),
             grid=(b, splits),
             in_specs=[

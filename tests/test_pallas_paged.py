@@ -50,10 +50,107 @@ def test_decode_kernel_matches_reference(b, n_heads, n_kv, head_dim, pages_per_s
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-def test_decode_kernel_tail_block_clamps():
-    """pages_per_seq > pages_per_block: the tail compute block reaches past
-    the table and must clamp page indices (masked by length) — the deep-block
-    path every page-16 serving config hits at long context."""
+#: Page 8, rows of up to 20 pages (160 tokens), 4 query heads over 2 KV heads of 32.
+HELD_PAGE, HELD_PAGES_PER_SEQ = 8, 20
+
+
+def check_only_held_pages_move(monkeypatch, *, pages_per_block, positions, window=None, num_splits=1):
+    """Drive the decode kernel over ``positions`` [B, T_q] with everything no
+    query of a row can see poisoned: every pool page outside the rows' held
+    ranges is NaN, every table entry outside them is out of range, and the
+    interpreter hands out NaN for VMEM no copy wrote. The kernel contracts
+    over whole blocks, so one copy of a page that is not held, or one ring row
+    left as it was, turns the output NaN. The held range is worked out here by
+    hand (from the window's first key to the farthest query, in pages) and the
+    wrapper's helper must name the same pages; the output must be the
+    reference's on every real query (a padding column under a window sees no
+    key: finite, and discarded)."""
+    import dynamo_tpu.ops.pallas_paged as pp
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(pp, "_pages_per_block", lambda pps, *a: min(pps, pages_per_block))
+    positions = np.asarray(positions, np.int32)
+    b, t_q = positions.shape
+    page, pages_per_seq, n_heads, n_kv, hd = HELD_PAGE, HELD_PAGES_PER_SEQ, 4, 2, 32
+    real = (positions > 0) | (np.arange(t_q)[None] == 0)
+    last = positions.max(axis=1) // page
+    oldest = np.where(real, positions, np.iinfo(np.int32).max).min(axis=1)
+    first = np.zeros(b, np.int64) if window is None else np.maximum(oldest - int(window) + 1, 0) // page
+
+    num_pages = 2 + b * pages_per_seq
+    tables = np.full((b, pages_per_seq), 2**30, np.int32)
+    held = np.zeros(num_pages, bool)
+    for i in range(b):
+        ids = 1 + i * pages_per_seq + np.arange(first[i], last[i] + 1)
+        tables[i, first[i]: last[i] + 1] = ids
+        held[ids] = True
+    walk = pp.decode_walk(jnp.asarray(positions), page, pages_per_block, window)
+    np.testing.assert_array_equal(np.asarray(walk.first_page), first)
+    np.testing.assert_array_equal(np.asarray(walk.last_page), last)
+    assert int(walk.pages_started) == held.sum()
+    visited = int(jnp.sum(walk.blocks)) * pages_per_block
+    assert held.sum() <= visited < held.sum() + 2 * b * pages_per_block  # at most a block's slots at either end
+
+    rng = np.random.default_rng(int(positions.sum()) + pages_per_block)
+    shape = (num_pages, page, n_kv * hd)
+    k = np.where(held[:, None, None], rng.standard_normal(shape), np.nan).astype(np.float32)
+    v = np.where(held[:, None, None], rng.standard_normal(shape), np.nan).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((b, t_q, n_heads, hd)), jnp.float32)
+    # The reference first and to its end: the TPU interpreter's callbacks run
+    # JAX operations of their own, and deadlock against a dispatch from here.
+    want = np.asarray(paged_attention_reference(
+        q, jnp.nan_to_num(k), jnp.nan_to_num(v), jnp.asarray(np.where(tables == 2**30, 0, tables)),
+        jnp.asarray(positions), scale=hd**-0.5, sliding_window=0 if window is None else window))
+    paged_decode_attention.clear_cache()  # the pinned block size is read at trace time
+    got = np.asarray(paged_decode_attention(
+        q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables), jnp.asarray(positions), scale=hd**-0.5,
+        window=None if window is None else jnp.int32(window), num_splits=num_splits,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan")))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[real], want[real], rtol=2e-5, atol=2e-5)
+
+
+def _tail_contexts(pages_per_block):
+    """One block and a tail of 1 .. pages_per_block pages (the last is a second
+    full block), the last page 5 of 8 tokens full."""
+    block = pages_per_block * HELD_PAGE
+    return [block + HELD_PAGE * k - 3 for k in range(1, pages_per_block + 1)]
+
+
+#: (pages a block, context, splits): tails of every count at 4 and 8 pages a
+#: block, alternately in one split and in three; a row inside its first page,
+#: one that ends on a block's edge, one a token past it.
+TAILS = [
+    (ppb, ctx, 3 if n % 2 else 1) for ppb in (4, 8) for n, ctx in enumerate(_tail_contexts(ppb))
+] + [(4, 1, 1), (4, 7, 3), (4, 32, 1), (4, 33, 3), (8, 64, 3), (8, 65, 1)]
+
+
+@pytest.mark.parametrize("pages_per_block, context, num_splits", TAILS,
+                         ids=[f"block{p}-ctx{c}-splits{s}" for p, c, s in TAILS])
+def test_decode_kernel_tail_block_moves_only_held_pages(monkeypatch, pages_per_block, context, num_splits):
+    """A row's last block copies the pages the row holds and nothing else: no
+    slot past the row's last page is clamped to that page and fetched again.
+    Row 0 is short and row 2 long, so the first ring slots hold mostly
+    unwritten rows when the later rows' tails land in them."""
+    check_only_held_pages_move(
+        monkeypatch, pages_per_block=pages_per_block, num_splits=num_splits,
+        positions=[[2], [context - 1], [149], [context - 1]])
+
+
+@pytest.mark.parametrize("pages_per_block", [4, 8])
+def test_decode_kernel_padding_and_verify_rows_move_only_held_pages(monkeypatch, pages_per_block):
+    """A padding row (every position 0) holds page 0 alone; a gappy verify row
+    holds up to its farthest token's page, whichever column that is."""
+    check_only_held_pages_move(
+        monkeypatch, pages_per_block=pages_per_block, num_splits=2,
+        positions=[[0, 0, 0], [70, 73, 71], [0, 0, 0], [95, 0, 0], [38, 39, 40]])
+
+
+def test_decode_kernel_tail_block_at_serving_widths():
+    """pages_per_seq > pages_per_block at 8 query over 2 KV heads of 64 and
+    page 16: rows that fill their blocks, end on a block's edge and end
+    mid-block — the deep-block path every page-16 serving config hits at long
+    context."""
     import dynamo_tpu.ops.pallas_paged as pp
 
     rng = np.random.default_rng(3)

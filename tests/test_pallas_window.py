@@ -103,3 +103,39 @@ def test_no_window_value_is_full_attention_and_the_dispatch_takes_the_kernels(mo
     _check(paged_attention(qc, k, v, tables, chunk_pos, impl="pallas", sliding_window=jnp.int32(200)),
            paged_attention_reference(qc, k, v, tables, chunk_pos, sliding_window=200), chunk_real)
     assert pallas_paged.fallback_snapshot() == before
+
+
+#: (name, pages a block, rows' positions [B, T_q], window, splits), at
+#: ``tests/test_pallas_paged.py``'s page of 8 tokens: a block is 32 or 64 tokens.
+HEADS_OF_WINDOWS = [
+    # keys 46..69 of row 1: pages 5..8, blocks 1 and 2 of 4 pages; row 3's window ends a block
+    ("straddles-a-block-edge", 4, [[2], [69], [149], [63]], 24, 1),
+    ("straddles-a-block-edge-3-splits", 4, [[2], [69], [149], [63]], 24, 3),
+    # keys 34..36: one page, the fifth, in block 1's first slot; row 3: the last slot of block 0
+    ("inside-a-page", 4, [[2], [36], [149], [30]], 3, 1),
+    ("inside-a-page-8", 8, [[2], [36], [149], [62]], 3, 3),
+    # the head falls on every slot of an 8-page block in turn: first keys 71, 81, 95, 120
+    ("heads-across-a-block", 8, [[110], [120], [134], [159]], 40, 1),
+    ("one-page-windows", 8, [[7], [8], [71], [72]], 8, 3),
+    ("no-window", 4, [[2], [69], [149], [100]], NO_WINDOW, 3),
+    ("no-window-8", 8, [[2], [69], [149], [100]], NO_WINDOW, 1),
+    ("window-past-the-context", 8, [[2], [69], [149], [100]], 1000, 1),
+    ("padding-row", 4, [[0], [69], [0], [100]], 24, 1),
+    # oldest real query 77 -> first key 54. A padding column (position 0) sees no key at all, not
+    # even in the last row, whose walk visits block 0 and holds pages 2.. (4..) of it, not page 0
+    ("verify-row", 4, [[77, 78, 80], [101, 0, 0], [0, 0, 0], [30, 31, 33], [45, 0, 0]], 24, 1),
+    ("verify-row-8-3-splits", 8, [[77, 78, 80], [101, 0, 0], [0, 0, 0], [130, 131, 133], [60, 0, 62]], 24, 3),
+]
+
+
+@pytest.mark.parametrize("name, pages_per_block, positions, window, num_splits", HEADS_OF_WINDOWS,
+                         ids=[c[0] for c in HEADS_OF_WINDOWS])
+def test_windowed_walk_moves_only_held_pages(monkeypatch, name, pages_per_block, positions, window, num_splits):
+    """A windowed row copies the pages from its window's first key to its
+    farthest query and no other: not the slots of its first block under the
+    window (the engine has released those pages of a window pool), not the
+    slots of its last block past the row."""
+    from tests.test_pallas_paged import check_only_held_pages_move
+
+    check_only_held_pages_move(monkeypatch, pages_per_block=pages_per_block, positions=positions,
+                               window=window, num_splits=num_splits)
