@@ -161,7 +161,7 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument("--page-size", type=int, default=0, help="0 = engine default (serving on TPU: use 128)")
     p.add_argument("--max-seq-len", type=int, default=0, help="0 = engine default")
     p.add_argument("--max-prefill-tokens", type=int, default=0, help="chunked-prefill budget per step; 0 = engine default")
-    p.add_argument("--decode-steps", type=int, default=0, help="fused decode burst length; 0 = engine default")
+    p.add_argument("--decode-steps", type=int, default=0, help="chained decode sub-dispatches a step; 0 = engine default")
     p.add_argument("--quantize", default="", help="weight-only quantization (int8)")
     p.add_argument("--mock", action="store_true", help="timing-model engine (CI)")
     p.add_argument("--seed", type=int, default=0)

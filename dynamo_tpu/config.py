@@ -125,10 +125,6 @@ class WorkerSettings:
     # Speculative decoding draft length (n-gram self-drafting, lossless);
     # 0 disables. See docs/SCHEDULER.md "Speculative steps".
     spec_k: int = 0
-    # Chain speculative verify steps through the pipelined step loop
-    # (accepted tokens stay device-resident). Off barriers every spec step
-    # to the sync verify path. Bare DYN_OVERLAP_SPEC=0 also clears it.
-    overlap_spec: bool = True
     # KV-cache storage dtype: 'bf16' (default) or 'fp8' (float8_e4m3fn,
     # halves KV HBM; attention upcasts to the query dtype at the matmul).
     kv_cache_dtype: str = "bf16"

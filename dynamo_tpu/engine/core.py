@@ -77,7 +77,7 @@ BARRIER_REASONS = (
     "prefill",  # legacy XOR mode: whole-prompt prefill steps carry no decodes
     "constraint",  # constrained rows with lookahead disabled (knob = 0)
     "constraint_miss",  # lookahead mask-cache miss or candidate-cap overflow
-    "spec",  # verify in flight (harvest-first) or spec cannot chain
+    "spec",  # verify in flight (harvest-first) or the runner has no async verify
     "drain",  # every live row finishes inside the in-flight step
     "pages",  # sole candidate cannot extend: commit in-flight, then re-check
     "fill",  # pipeline refill: dispatched with nothing in flight
@@ -102,9 +102,10 @@ class EngineConfig:
     swa_free_pages: bool = True
     salt: int = DEFAULT_SALT
     worker_id: int = 0
-    # Fused decode steps per dispatch. >1 amortizes host<->device round trips
-    # (one dispatch per burst); trades up to decode_steps-1 wasted
-    # steps per finishing sequence and K-token stream granularity.
+    # The pipelined loop's count of chained pure-decode sub-dispatches a step
+    # (_run_mixed_overlapped), and nothing else: >1 harvests that many tokens
+    # a row per step() call, at K-token stream granularity. The synchronous
+    # loop (overlap=False, the oracle) emits one token a step whatever it says.
     decode_steps: int = 1
     # Per-step prefill token budget while decodable sequences are running:
     # prompts are admitted/advanced in chunks of at most this many tokens,
@@ -148,12 +149,6 @@ class EngineConfig:
     # False steps synchronously throughout: the oracle the parity tests
     # compare against, set by no entry point.
     overlap: bool = True
-    # Allow speculative verify dispatches to participate in the overlapped
-    # pipeline (DYN_OVERLAP_SPEC): verify steps chain their base token from
-    # the previous dispatch and their accepted tokens stay device-resident
-    # to feed the next one. Off forces a barrier on every spec step (the
-    # pre-PR-11 behavior); output streams are identical either way.
-    overlap_spec: bool = True
     # Pipelined tier onboarding (DYN_ASYNC_ONBOARD; DYN_CACHE_AWARE also
     # arms it): admission no longer blocks on G2/G3/G4 payload reads — a
     # background session fetches them and they land through the batched
@@ -205,7 +200,6 @@ class _InflightStep:
     n_dec: int = 0  # leading decode rows (the rest are prefill chunks)
     samples: list | None = None  # per-row: does the engine accept a sample?
     drafts: list | None = None  # per-decode-row draft tokens (spec)
-    v: int = 1  # verify width (spec)
     extra: list = dataclasses.field(default_factory=list)  # burst sub-step handles
 
 
@@ -988,8 +982,7 @@ class EngineCore:
             # decode must not wait on them. Legacy mode (fused=False) runs
             # the scheduled whole prompts without decode rows (XOR). With
             # speculation on, pure-decode steps route here too: the verify
-            # dispatch supersedes the burst/pipelined decode paths (drafts
-            # already amortize the per-step host round trip).
+            # dispatch supersedes the decode step.
             with self.clock.annotate("engine.mixed" if fused else "engine.prefill"):
                 out = cancelled + self._run_mixed(chunks)
         elif self.running:
@@ -1083,14 +1076,10 @@ class EngineCore:
                 # barrier to the sync mask path (which warms exactly the
                 # states that missed) and retry the pipeline next step.
                 return False, "constraint_miss"
-        if self._spec_active() and not (
-            self.config.overlap_spec
-            and hasattr(self.runner, "spec_step_async")
-        ):
-            # Speculation is on but cannot chain (knob off or runner has no
-            # async verify): stand down entirely — barrier to the sync
-            # verify path rather than silently dropping drafts (the
-            # pre-ISSUE-11 behavior).
+        if self._spec_active() and not hasattr(self.runner, "spec_step_async"):
+            # Speculation is on but the runner has no async verify: stand
+            # down entirely — barrier to the sync verify path rather than
+            # silently dropping drafts.
             return False, "spec"
         return True, None
 
@@ -1709,12 +1698,12 @@ class EngineCore:
         out: list[tuple[Sequence, EngineOutput]] = []
         decode_rows: list[Sequence] = []
         if (fused or (spec and not chunks)) and self.running:
-            failed = self._ensure_burst_pages(1)
+            failed = self._ensure_next_page()
             if failed is not None:
                 out.append((failed, self._final_output(failed)))
             decode_rows = list(self.running)
         # Speculative drafts per decode row (empty lists when spec is off).
-        # Must run after _ensure_burst_pages: preemption there invalidates
+        # Must run after _ensure_next_page: preemption there invalidates
         # the row list. A decode row with drafts becomes a verify row — its
         # span is [input token, draft_1..draft_k] at consecutive positions.
         drafts: list[list[int]] = (
@@ -1804,7 +1793,6 @@ class EngineCore:
         rec = _InflightStep(
             batch, None, kind="spec" if use_spec else "step",
             ns=ns, n_dec=n_dec, samples=samples, drafts=drafts,
-            v=(self.config.spec_k + 1 if use_spec else 1),
         )
         return out + self._apply_mixed_results(rec, next_tokens, targets, lp_aux)
 
@@ -1865,20 +1853,15 @@ class EngineCore:
         next_tokens,
         targets,
         lp_aux,
-        *,
-        chain_out: bool = False,
     ) -> list[tuple[Sequence, EngineOutput]]:
         """Apply a (possibly mixed / speculative) step's sampled tokens.
 
         Shared by the synchronous path and the overlapped harvest. Rows
         whose sequence left RUNNING while the step was in flight
-        (cancelled, preempted) are skipped — their samples are discarded,
-        exactly like burst overshoot. With ``chain_out`` (spec harvest in
-        the overlapped pipeline) each surviving row's last accepted token
-        is recorded in ``_chain_map`` as a flat index into the runner's
-        device-resident ``[B*V]`` targets buffer, so the next dispatch can
-        chain from it without the token ever leaving the device; plain
-        dispatches record their map at dispatch time instead. When called
+        (cancelled, preempted) are skipped — their samples are discarded.
+        A plain dispatch records its ``_chain_map`` at dispatch time; a
+        verify records none, so the dispatch after its harvest takes every
+        row's token from the host. When called
         from the overlapped harvest, ``last_step_info`` is the *current*
         step's dict — a harvest step's spec fields therefore describe the
         previous dispatch's acceptance, which is when it becomes known."""
@@ -1913,10 +1896,6 @@ class EngineCore:
                     if s.check_stop(self._eos, self.config.max_seq_len) is not None:
                         break  # overshoot past EOS/length is discarded
                 spec_accepted += max(0, len(accepted) - 1)
-                if chain_out and not s.is_finished:
-                    # accepted[-1] == targets[i, len(accepted) - 1]: its flat
-                    # index feeds the next dispatch's chained column 0.
-                    self._chain_map[s.seq_id] = i * rec.v + len(accepted) - 1
                 # Roll back speculative pages the accepted span didn't
                 # reach: they were freshly allocated this step (commit
                 # never passes num_cached), so release returns them to the
@@ -1946,8 +1925,6 @@ class EngineCore:
                 self._release_out_of_window(s)
                 # May finish the sequence (page release) — must follow commit.
                 self._accept_constrained(s, [tok])
-                if chain_out and not s.is_finished:
-                    self._chain_map[s.seq_id] = i * rec.v  # its column 0
                 lp = (self._lp_cols(s, lp_aux, i, [tok]) if use_spec
                       else self._lp_entries(s, lp_aux, i))
                 out.append(self._emit(s, tok, lp))
@@ -2045,9 +2022,8 @@ class EngineCore:
         step are excluded from the lookahead (their finish is detected at
         harvest, one step late — streams stay bit-identical to
         overlap=False). A speculative verify in flight is harvested first —
-        its acceptance decides every position after it — and the next
-        dispatch chains out of its device-resident targets buffer, so even
-        then tokens never round-trip through the host.
+        its acceptance decides every position after it — and the dispatch
+        composed on it takes its rows' tokens from the host.
 
         Compositions the pre-lookahead pipeline barriered on now ride it
         too: constrained rows select their mask in-graph from the
@@ -2071,8 +2047,8 @@ class EngineCore:
         if inf is not None and inf.kind == "spec":
             # A verify's acceptance decides every position that follows —
             # nothing can be composed until it lands. Harvest first; the
-            # accepted tokens stay device-resident (flat targets buffer)
-            # and the dispatch below chains out of them via _chain_map.
+            # accepted tokens are in s.tokens, where the dispatch below
+            # finds them (a verify leaves no entry in _chain_map).
             self._note_barrier("spec")
             out += self._harvest_inflight()
             self.clock.mark(tracing.SCHED)
@@ -2098,11 +2074,7 @@ class EngineCore:
                 self._note_barrier("drain")
                 out += self._drain_inflight()
             return out
-        spec = (
-            self._spec_active()
-            and self.config.overlap_spec
-            and hasattr(self.runner, "spec_step_async")
-        )
+        spec = self._spec_active()  # the runner verifies asynchronously, or _overlap_route barriered
         # decode_steps>1 folds into the pipeline as K chained pure-decode
         # sub-steps behind the primary dispatch. Only clean decode batches
         # burst: chunks change composition mid-burst; speculation already
@@ -2138,7 +2110,7 @@ class EngineCore:
             self._note_barrier("pages")
             out += self._drain_inflight()
             if failed.status is SeqStatus.RUNNING:
-                f2 = self._ensure_burst_pages(1)
+                f2 = self._ensure_next_page()
                 if f2 is not None:
                     out.append((f2, self._final_output(f2)))
             return out
@@ -2147,9 +2119,8 @@ class EngineCore:
         decode_rows = [s for s in decode_rows if s.status is SeqStatus.RUNNING]
         k_burst = 1
         if want_burst and decode_rows:
-            # Never burst a row past its finish line: unlike the sync fused
-            # burst there is no cheap overshoot to discard — every sub-step
-            # is a real dispatch — so the shortest row clamps the depth.
+            # Never burst a row past its finish line: every sub-step is a
+            # real dispatch, so the shortest row clamps the depth.
             k_burst = max(
                 1, min(k_cfg, min(self._eff_remaining(s) for s in decode_rows))
             )
@@ -2253,7 +2224,7 @@ class EngineCore:
                 )
                 new_inf = _InflightStep(
                     batch, dev, kind="spec", ns=ns, n_dec=n_dec,
-                    samples=samples, drafts=drafts, v=v,
+                    samples=samples, drafts=drafts,
                 )
             else:
                 dev = self.runner.step_async(
@@ -2294,10 +2265,10 @@ class EngineCore:
             self._overlap_mode = "overlapped"
         else:
             self._note_barrier("fill")
-        # The new dispatch's chain map: a verify's is only known at its
-        # harvest (acceptance decides the column); a plain step's is its
-        # emitting rows. Installed *before* the harvest below so late
-        # finishes prune their (now meaningless) entries.
+        # The new dispatch's chain map: a plain step's emitting rows; none
+        # for a verify, which is harvested before anything is composed on it.
+        # Installed *before* the harvest below so late finishes prune their
+        # (now meaningless) entries.
         if use_spec:
             self._chain_map = {}
         else:
@@ -2319,7 +2290,7 @@ class EngineCore:
         else:
             # A burst's sub-steps advance every row one more cached slot and
             # one more emitted token each (rows that finish mid-burst discard
-            # the overshoot at harvest, same as the sync fused burst).
+            # the overshoot at harvest).
             self._inflight_adv = {
                 s.seq_id: (n + k_burst - 1, (1 if smp else 0) + k_burst - 1)
                 for s, n, smp in zip(batch, ns, samples)
@@ -2329,59 +2300,50 @@ class EngineCore:
     # -- decode phase ------------------------------------------------------
 
     def _run_decode(self) -> list[tuple[Sequence, EngineOutput]]:
-        k = max(1, self.config.decode_steps)
-        if self.running:
-            # Don't burst past the farthest finish line: the overshoot is
-            # discarded compute (at decode_steps=64 and 10 tokens remaining,
-            # 84% of the burst). Pow2 keeps k on the compiled bucket lattice.
-            from dynamo_tpu.engine.runner import next_pow2
-
-            rem = max(s.remaining_tokens(self.config.max_seq_len) for s in self.running)
-            k = max(1, min(k, next_pow2(rem)))
-        # The pipelined loop never reaches this method:
-        # _step_locked routes every composition — including decode_steps>1,
-        # which is now served as chained sub-dispatches inside
-        # _run_mixed_overlapped — through the pipeline, and drains it before
-        # any barrier falls through to the synchronous paths below.
-        if self._inflight is not None:
+        """The synchronous decode step: one token a running row. The
+        pipelined loop never reaches it (_step_locked drains the pipeline
+        before a barrier falls through to the synchronous paths)."""
+        if self._inflight is not None:  # config.overlap went off mid-run
             return self._drain_inflight()
-        # Constraints need a fresh host-built mask per token, and logprobs
-        # ride the single-step path because the fused burst's scan doesn't
-        # surface per-step logits. (Penalized rows burst fine: the in-graph
-        # scan self-counts repetitions within the burst.)
-        if any(
-            s.constraint is not None or s.request.sampling.logprobs
-            for s in self.running
-        ):
-            return self._run_decode_sync(1)
-        return self._run_decode_sync(k)
+        self.clock.mark(tracing.BUILD)
+        failed = self._ensure_next_page()
+        if failed is not None:
+            return [(failed, self._final_output(failed))]
+        # Snapshot: _finish() inside _emit() mutates self.running mid-loop.
+        batch = list(self.running)
+        if not batch:
+            return []
+        step_batch = self._decode_step_batch(batch)
+        step_batch.logit_mask = self._constraint_masks(batch)
+        lp_k = LOGPROBS_TOP_K if any(s.request.sampling.logprobs for s in batch) else 0
+        self.clock.mark(tracing.DISPATCH)  # the runner marks dispatch -> wait
+        try:
+            stepped = self.runner.step(step_batch, lp_k=lp_k) if lp_k else self.runner.step(step_batch)
+        except Exception:
+            for s in batch:
+                self._finish(s, FinishReason.ERROR)
+            raise
+        next_tokens, lp_aux = stepped if lp_k else (stepped, None)
+        self.clock.mark(tracing.POST)
+        b = len(batch)
+        rec = _InflightStep(batch, None, ns=[1] * b, n_dec=b, samples=[True] * b)
+        return self._apply_mixed_results(rec, next_tokens, None, lp_aux)
 
-    def _ensure_burst_pages(self, horizon: int, *, fail_sole: bool = True) -> Sequence | None:
-        """Give every running sequence pages covering the next ``horizon``
-        tokens; preempt on exhaustion. If the sole remaining sequence cannot
-        fit it is returned — finished with ERROR when ``fail_sole``, left
-        untouched otherwise (the pipelined path must first commit the burst
-        already in flight, which may contain the sequence's legitimate
-        finish)."""
+    def _ensure_next_page(self) -> Sequence | None:
+        """Give every running sequence the page its next token lands in;
+        preempt on exhaustion. If the sole remaining sequence cannot fit (its
+        context outgrew the cache) it is finished with ERROR and returned."""
         i = 0
         while i < len(self.running):
             seq = self.running[i]
-            # A sequence never decodes past max_tokens (or the context
-            # window): demanding pages beyond that caused end-of-run
-            # preemption storms when the burst horizon overshot the finish.
-            # (Safe because overshoot KV writes land in the null page — see
-            # the pos_limit mask in the runner's fused burst.)
-            remaining = seq.remaining_tokens(self.config.max_seq_len)
-            need = seq.pages_needed(self.config.page_size, min(horizon, remaining))
+            need = seq.pages_needed(self.config.page_size, 1)
             if need:
                 try:
                     self._grow(seq, need)
                 except OutOfPagesError:
                     victim = self.running[-1]
                     if victim is seq and len(self.running) == 1:
-                        # Sole sequence can't fit: context outgrew the cache.
-                        if fail_sole:
-                            self._finish(seq, FinishReason.ERROR)
+                        self._finish(seq, FinishReason.ERROR)
                         return seq
                     self._preempt(victim)
                     continue  # retry same index (list shrank behind us)
@@ -2389,8 +2351,8 @@ class EngineCore:
         return None
 
     def _decode_step_batch(self, batch: list[Sequence]) -> StepBatch:
-        """Host arrays for a synchronous decode step/burst, each row starting
-        at its committed state."""
+        """Host arrays for a synchronous decode step, each row at its
+        committed state."""
         ps = self.config.page_size
         b = len(batch)
         n = max(len(s.pages) for s in batch)
@@ -2407,67 +2369,11 @@ class EngineCore:
             slots[i, 0] = s.pages[pos // ps] * ps + pos % ps
         return self._sampling_batch(batch, tokens, positions, block_tables, slots, last)
 
-    def _process_burst_tokens(self, batch: list[Sequence], next_tokens, lp_aux=None) -> list[tuple[Sequence, EngineOutput]]:
-        """Apply a burst's sampled tokens to the batch's sequences.
-
-        Sequences that left RUNNING while the burst was in flight (cancelled,
-        preempted) are skipped — their sampled tokens are discarded, exactly
-        like post-stop overshoot within a burst."""
-        outputs = []
-        for i, s in enumerate(batch):
-            if s.status is not SeqStatus.RUNNING:
-                continue
-            accepted: list[int] = []
-            for tok in next_tokens[i]:
-                s.num_cached += 1
-                s.append_token(int(tok))
-                self._generated_tokens_total += 1
-                accepted.append(int(tok))
-                if s.check_stop(self._eos, self.config.max_seq_len) is not None:
-                    break  # overshoot from the burst is discarded
-            self._commit_filled_pages(s)
-            self._release_out_of_window(s)
-            # May finish the sequence (page release) — must follow commit.
-            self._accept_constrained(s, accepted)
-            outputs.append(self._emit_many(s, accepted, self._lp_entries(s, lp_aux, i)))
-        return outputs
-
-    def _run_decode_sync(self, k: int) -> list[tuple[Sequence, EngineOutput]]:
-        self.clock.mark(tracing.BUILD)
-        failed = self._ensure_burst_pages(k)
-        if failed is not None:
-            return [(failed, self._final_output(failed))]
-        # Snapshot: _finish() inside _emit() mutates self.running mid-loop.
-        batch = list(self.running)
-        if not batch:
-            return []
-        step_batch = self._decode_step_batch(batch)
-        lp_k = LOGPROBS_TOP_K if any(s.request.sampling.logprobs for s in batch) else 0
-        if k == 1:
-            step_batch.logit_mask = self._constraint_masks(batch)
-        lp_aux = None
-        self.clock.mark(tracing.DISPATCH)  # the runner marks dispatch -> wait
-        try:
-            if k == 1:
-                if lp_k:
-                    stepped, lp_aux = self.runner.step(step_batch, lp_k=lp_k)
-                else:
-                    stepped = self.runner.step(step_batch)
-                next_tokens = stepped[:, None]
-            else:
-                next_tokens = self.runner.multi_step(step_batch, k)  # [B, k]
-        except Exception:
-            for s in batch:
-                self._finish(s, FinishReason.ERROR)
-            raise
-        self.clock.mark(tracing.POST)
-        return self._process_burst_tokens(batch, next_tokens, lp_aux)
-
     def _harvest_inflight(self) -> list[tuple[Sequence, EngineOutput]]:
         """Consume the in-flight step, keeping the runner's device-resident
-        sample buffer alive — a dispatch composed on top of this harvest may
-        chain out of it (spec chain-out). Clears the effective-state advance:
-        the host has caught up."""
+        sample buffer alive — the dispatch already composed on top of it
+        chains out of that buffer. Clears the effective-state advance: the
+        host has caught up."""
         inf = self._inflight
         if inf is None:
             return []
@@ -2477,9 +2383,7 @@ class EngineCore:
         clock.mark(tracing.WAIT)
         res, lp_aux = inf.handle.result()
         clock.mark(tracing.POST)
-        if inf.kind == "spec":
-            return self._apply_mixed_results(inf, res[:, 0], res, lp_aux, chain_out=True)
-        out = self._apply_mixed_results(inf, res[:, 0], None, lp_aux)
+        out = self._apply_mixed_results(inf, res[:, 0], res if inf.kind == "spec" else None, lp_aux)
         for h in inf.extra:
             # decode_steps burst sub-steps: one more pure-decode token per
             # row each, applied in dispatch order. Rows that finished in an
@@ -2541,7 +2445,7 @@ class EngineCore:
         # Generated-token history feeds the sampler's repetition penalties.
         # Only shipped when some request actually set a penalty: H collapses
         # to 1 otherwise, keeping the packed step input small. Width covers
-        # this dispatch's own fused decode burst (the scan appends in-graph).
+        # a chained row's in-flight token (written in-graph at steps - 1).
         if freq.any() or pres.any():
             h = max(int(steps.max()) + self.config.decode_steps, 1)
             history = np.full((b, h), -1, np.int32)

@@ -18,7 +18,6 @@ import dataclasses
 import functools
 import logging
 import threading
-from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
@@ -225,10 +224,9 @@ def _apply_chain(first, history, sample_steps, chain_buf, chain_src):
     """Per-row device-resident token sourcing for a chained dispatch.
 
     ``first`` i32[B] is each row's column-0 input token as the host packed it.
-    ``chain_src`` i32[B] holds, per row, a flat index into ``chain_buf`` (the
-    previous dispatch's device-resident samples: one fixed width in row order
-    for plain steps, [Bp*V] row-major for spec verifies) or -1 for host-fed
-    rows. Chained rows' token is gathered in-graph; host-fed rows (prefill
+    ``chain_src`` i32[B] holds, per row, an index into ``chain_buf`` (the
+    previous step's device-resident samples in its batch's row order) or -1
+    for host-fed rows. Chained rows' token is gathered in-graph; host-fed rows (prefill
     chunks, fresh admissions, every row of a synchronous step) keep their
     host token bit for bit.
 
@@ -436,10 +434,6 @@ class ModelRunner:
         # counted into the report (_count_kv): after the enqueue, under the
         # device's shadow, never between a result and the next enqueue.
         self._kv_pending: StepBatch | None = None
-        # Called (from the stepping thread) when a synchronous dispatch has
-        # enqueued its program and is about to block on the result: the
-        # service routes the previous step's outputs then (engine/service.py).
-        self.on_enqueued: Callable[[], None] | None = None
         self._dp = 1
         if mesh is not None:
             from dynamo_tpu.parallel.sharding import cache_shardings, shard_params
@@ -623,29 +617,34 @@ class ModelRunner:
                                    history, mrope_delta=None,
                                    mm_embeds=None, mm_slot_offset=None, mm_counts=None,
                                    mrope_positions=None, la_masks=None, la_groups=None,
-                                   window_tables=None, window_slots=None, *, impl, lp_k=0):
+                                   window_tables=None, window_slots=None, logit_mask=None, *, impl, lp_k=0):
             """Explicit-args chained step: mesh runners (the packed buffer
-            cannot be row-sharded) and any chained dispatch carrying extras
-            the packed buffer has no slots for — multimodal embeds, explicit
-            3-axis mrope coords, or lookahead constraint-mask groups.
+            cannot be row-sharded) and, on one device, any async dispatch
+            carrying extras the packed buffer has no slots for — multimodal
+            embeds, explicit 3-axis mrope coords, a host-built constraint mask
+            (``logit_mask``: no row chains) or lookahead constraint-mask groups.
+            Returns ``(_step's outputs, the new chain buffer)``: on one device
+            the samples at the chain buffer's one width, as the text programs
+            hand them back; a mesh's keep ``[Bp]`` (its programs only ever
+            chain among themselves).
 
             The lookahead mask selection happens strictly AFTER the chain
             gather: each row's group id is looked up at its (possibly
             device-sourced) column-0 token, which is exactly the token the
             host could not know at compose time."""
             tokens, history = _chain_rows(tokens, history, sample_steps, chain_buf, chain_src)
-            logit_mask = None
             if la_masks is not None:
                 rows = jnp.arange(tokens.shape[0])
                 g = la_groups[rows, tokens[:, 0]]
                 logit_mask = la_masks[rows, g]
-            return _step(
+            out = _step(
                 params, k_cache, v_cache, tokens, positions, block_tables,
                 slot_mapping, last_idx, temperature, top_k, top_p, seeds,
                 sample_steps, freq_pen, pres_pen, pos_limit, history, mrope_delta,
                 mm_embeds, mm_slot_offset, mm_counts, mrope_positions, logit_mask,
                 window_tables, window_slots, impl=impl, lp_k=lp_k,
             )
+            return out, (out[0] if self.mesh is not None else _chain_out(out[0], chain_buf.shape[0]))
 
         self._step_chained_explicit_fn = _step_chained_explicit
 
@@ -739,94 +738,14 @@ class ModelRunner:
 
         self._spec_step_chained_fn = _spec_step_chained
 
-        @functools.partial(jax.jit, static_argnames=("num_steps",), donate_argnums=(1, 2))
-        def _multi_step(params, k_cache, v_cache, tokens, positions, block_tables,
-                        temperature, top_k, top_p, seeds, sample_steps,
-                        freq_pen, pres_pen, pos_limit, history, mrope_delta=None,
-                        window_tables=None, *, num_steps):
-            """``num_steps`` fused decode iterations in one dispatch.
-
-            The sampled token of step i is step i+1's input; slot mapping is
-            derived in-graph from positions and block tables (pages must be
-            pre-allocated to cover positions + num_steps). Returns the sampled
-            tokens [num_steps, B] — one host round-trip per burst, not per
-            token.
-            """
-            ps = self.page_size
-            zeros = jnp.zeros_like(tokens)
-            h_width = history.shape[1]
-
-            def body(carry, _):
-                tok, pos, kc, vc, cnt, hist = carry
-                page = jnp.take_along_axis(block_tables, (pos // ps)[:, None], axis=1)[:, 0]
-                slot = page * ps + pos % ps
-                # Burst overshoot (host discards those tokens) must never
-                # touch live pages: past each row's finish line the write
-                # lands in the reserved null page 0. This is what makes
-                # page allocation capped at remaining-tokens safe.
-                slot = jnp.where(pos < pos_limit, slot, 0)
-                mm_kw = {}
-                if self.cfg.mrope_section:
-                    mm_kw["mrope_positions"] = _delta_mrope(pos[:, None], mrope_delta)
-                if window_tables is not None:  # the sliding layers' pool: the same derivation from its own table
-                    wpage = jnp.take_along_axis(window_tables, (pos // ps)[:, None], axis=1)[:, 0]
-                    wslot = jnp.where(pos < pos_limit, wpage * ps + pos % ps, 0)
-                    mm_kw.update(window_tables=window_tables, window_slots=wslot[:, None], window_pages=self.window_pages)
-                logits, kc, vc = self._forward(
-                    params, self.cfg, tok[:, None], pos[:, None], kc, vc,
-                    block_tables, slot[:, None], zeros, attn_impl=self.attn_impl,
-                    mesh=self.mesh,
-                    **mm_kw,
-                )
-                keys = jax.vmap(lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c))(seeds, cnt)
-                with jax.named_scope("sample"):
-                    nxt = sample_tokens(
-                        logits, keys, temperature, top_k, top_p,
-                        history=hist, frequency_penalty=freq_pen, presence_penalty=pres_pen,
-                    )
-                # The burst's own samples count toward later steps' penalties.
-                write = jnp.minimum(cnt, h_width - 1)
-                hist = jax.vmap(lambda hrow, w, t: hrow.at[w].set(t))(hist, write, nxt)
-                return (nxt, pos + 1, kc, vc, cnt + 1, hist), nxt
-
-            (_, _, k_cache, v_cache, _, _), toks = jax.lax.scan(
-                body, (tokens, positions, k_cache, v_cache, sample_steps, history), None, length=num_steps
-            )
-            return toks, k_cache, v_cache
-
-        self._multi_step_fn = _multi_step
-
-        @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "num_steps"), donate_argnums=(1, 2))
-        def _multi_step_packed(params, k_cache, v_cache, packed, *, b, t, n, h, num_steps):
-            (tokens, positions, block_tables, _slot, _last,
-             temperature, top_k, top_p, seeds, sample_steps,
-             freq_pen, pres_pen, pos_limit, history, mrope_delta, _src, *pools) = _unpack(
-                 packed, b, t, n, h, pools=two_pool)
-            return _multi_step(
-                params, k_cache, v_cache, tokens[:, 0], positions[:, 0], block_tables,
-                temperature, top_k, top_p, seeds, sample_steps,
-                freq_pen, pres_pen, pos_limit, history, mrope_delta,
-                pools[0].reshape(b, n) if two_pool else None, num_steps=num_steps,
-            )
-
-        self._multi_step_packed_fn = _multi_step_packed
-
-        # The latest async dispatch's samples, device-resident: i32[_chain_width]
-        # in the batch's row order after a text step of one device, whatever its
-        # rows bucket; a mesh's or an extras step's [Bp] and a verify's [Bp*V]
-        # keep their own shape (``_chain_for_text`` brings those to the width
-        # when a text step chains out of them).
+        # The latest async step's samples, device-resident, in the batch's row
+        # order: i32[_chain_width] on one device, whatever the rows bucket and
+        # whichever program ran; a mesh's programs keep [Bp]. None after a
+        # verify, whose tokens the host hands on (nothing chains out of one).
         self._chain_tokens = None
         self._chain_width = self._bucket_batch(max_batch_size)
         # What a step that chains nothing passes for the buffer.
         self._chain_idle = jax.device_put(np.zeros(self._chain_width, np.int32), self.device)
-
-        @jax.jit
-        def _rebase_chain(buf, src):
-            picked = jnp.where(src >= 0, buf[jnp.clip(src, 0, buf.shape[0] - 1)], 0)
-            return _chain_out(picked, self._chain_width)
-
-        self._rebase_chain_fn = _rebase_chain
 
         @functools.partial(jax.jit, donate_argnums=(0, 1))
         def _write_page(k_cache, v_cache, k, v, pid):
@@ -1238,8 +1157,6 @@ class ModelRunner:
         result. Outside an engine step (warm-up, tests) there is no clock."""
         if self.clock is not None:
             self.clock.mark_in_step(tracing.WAIT)
-        if self.on_enqueued is not None:
-            self.on_enqueued()
         self._count_kv(self._report)  # the device is busy now: host work here costs no step time
 
     def _chunk_rows(self, padded: StepBatch, chain_src: np.ndarray | None = None) -> np.ndarray | None:
@@ -1455,60 +1372,11 @@ class ModelRunner:
         targets, self.k_cache, self.v_cache = out
         return np.asarray(targets)[:b_real]
 
-    @_locked
-    def multi_step(self, batch: StepBatch, num_steps: int) -> np.ndarray:
-        """Fused decode burst; returns sampled tokens i32[B_real, num_steps].
-
-        ``batch`` must be a decode batch (T == 1) whose block tables cover
-        positions + num_steps.
-        """
-        assert batch.tokens.shape[1] == 1, "multi_step is decode-only"
-        self._refuse_recurrent("a fused decode burst")
-        b_real = batch.batch_size
-        padded = self._pad(batch)
-        dispatch_key = (
-            padded.tokens.shape[0], padded.tokens.shape[1],
-            padded.block_tables.shape[1], padded.history.shape[1],
-            num_steps, self.mesh is not None,
-        )
-        with self._dispatch("multi_step", dispatch_key, padded, self.attn_impl,
-                            (ROWS_X_T, padded.tokens.size)):
-            if self.mesh is not None:
-                from dynamo_tpu.parallel.sharding import batch_sharding
-
-                def put(a):
-                    return jax.device_put(a, batch_sharding(self.mesh, a.ndim))
-
-                toks, self.k_cache, self.v_cache = self._enqueue(
-                    self._multi_step_fn,
-                    self.params, self.k_cache, self.v_cache,
-                    put(padded.tokens[:, 0]), put(padded.positions[:, 0]),
-                    put(padded.block_tables), put(padded.temperature),
-                    put(padded.top_k), put(padded.top_p),
-                    put(padded.seeds), put(padded.sample_steps),
-                    put(padded.freq_pen), put(padded.pres_pen),
-                    put(padded.pos_limit), put(padded.history),
-                    put(padded.mrope_delta),
-                    None if padded.window_block_tables is None else put(padded.window_block_tables),
-                    num_steps=num_steps,
-                )
-            else:
-                b, t = padded.tokens.shape
-                toks, self.k_cache, self.v_cache = self._enqueue(
-                    self._multi_step_packed_fn,
-                    self.params, self.k_cache, self.v_cache, jnp.asarray(_pack(padded)),
-                    b=b, t=t, n=padded.block_tables.shape[1], h=padded.history.shape[1],
-                    num_steps=num_steps,
-                )
-            self._mark_wait()
-            return np.asarray(toks).T[:b_real]  # [B, num_steps]
-
     def _chain_src_padded(self, chain_src, b_real: int, bp: int) -> np.ndarray:
         """Pad a per-row chain source vector to the batch bucket (-1 = host).
 
-        ``chain_src=None`` with chaining requested means the legacy
-        whole-batch form: row i chains from flat index i of the previous
-        dispatch's buffer."""
+        ``chain_src=None`` with chaining requested means the whole-batch
+        form: row i chains from row i of the previous step."""
         src = np.full(bp, -1, np.int32)
         if chain_src is None:
             src[:b_real] = np.arange(b_real, dtype=np.int32)
@@ -1519,19 +1387,6 @@ class ModelRunner:
             self._chain_tokens is not None and mx < self._chain_tokens.shape[0]
         ), "chain_src points past the device-resident sample buffer"
         return src
-
-    def _chain_for_text(self, src: np.ndarray) -> tuple[jax.Array, np.ndarray]:
-        """The chain buffer and sources a text step's program takes: the buffer
-        at its one width. The samples of a verify, or of a step that went by
-        the explicit arguments, keep a shape of their own; a text step that
-        chains out of one has them gathered to the width first (one small
-        program more, on the step after such a dispatch only)."""
-        buf = self._chain_tokens
-        if buf.shape[0] == self._chain_width:
-            return buf, src
-        chained = src >= 0
-        return (self._rebase_chain_fn(buf, jnp.asarray(src)),
-                np.where(chained, np.arange(len(src), dtype=np.int32), -1).astype(np.int32))
 
     @_locked
     def step_async(self, batch: StepBatch, lp_k: int = 0, *, chain: bool = False,
@@ -1544,8 +1399,7 @@ class ModelRunner:
         next step can be dispatched with ``chain=True`` — each row's input
         token gathered in-graph per ``chain_src`` — before this step's
         tokens ever reach the host. ``chain_src`` i32[B_real] names, per
-        row, a flat index into the previous dispatch's buffer (plain step:
-        its row index; spec verify: row*V + accepted-column) or -1 to feed
+        row, its row index in the previous step's batch or -1 to feed
         that row from host (prefill chunks, fresh admissions). Rows may
         carry multiple real token columns exactly like :meth:`step` — only
         column 0 is ever chained, which is where mixed decode rows keep
@@ -1577,14 +1431,12 @@ class ModelRunner:
         n = padded.block_tables.shape[1]
         h = padded.history.shape[1]
         src = self._chain_src_padded(chain_src, b_real, b) if chain else None
+        chain_buf = self._chain_tokens if chain else self._chain_idle
         rows = slice(b_real)
         if self.mesh is None and not (
             padded.mm_embeds is not None or padded.mrope_positions is not None
             or padded.logit_mask is not None or padded.la_masks is not None
         ):
-            chain_buf = self._chain_idle
-            if chain:
-                chain_buf, src = self._chain_for_text(src)
             key, layout, rows, fn, pack, statics = self._text_step(padded, b_real, lp_k, src)
             with self._dispatch("step", (b, t, n, h, lp_k, impl, False, False, False) + key,
                                 padded, impl, layout):
@@ -1592,35 +1444,36 @@ class ModelRunner:
                                                jnp.asarray(pack()), chain_buf, **statics, state=self.state)
         else:
             self._refuse_recurrent("a step with image rows or constraint masks")
+            # One device runs the chained program whether or not a row chains
+            # (every source -1 then): its samples come back at the chain
+            # buffer's width, so a text step can chain out of them as they are.
+            chained_program = chain or self.mesh is None
             dispatch_key = (
-                b, t, n, h, lp_k, chain, impl, self.mesh is not None,
+                b, t, n, h, lp_k, chained_program, impl, self.mesh is not None,
                 padded.mm_embeds is not None, padded.logit_mask is not None,
                 padded.la_masks is not None,
             )
             with self._dispatch("step_async", dispatch_key, padded, impl, (ROWS_X_T, b * t)):
                 opt, explicit = self._explicit_inputs(padded)
-                if chain:
-                    out = self._enqueue(
+                extras = (opt(padded.mm_embeds), opt(padded.mm_slot_offset),
+                          opt(padded.mm_counts), opt(padded.mrope_positions))
+                pools = (opt(padded.window_block_tables), opt(padded.window_slot_mapping))
+                if chained_program:
+                    out, chain_buf = self._enqueue(
                         self._step_chained_explicit_fn,
                         self.params, self.k_cache, self.v_cache,
-                        self._chain_tokens, opt(src), *explicit,
-                        opt(padded.mm_embeds), opt(padded.mm_slot_offset),
-                        opt(padded.mm_counts), opt(padded.mrope_positions),
-                        opt(padded.la_masks), opt(padded.la_groups),
-                        opt(padded.window_block_tables), opt(padded.window_slot_mapping),
+                        chain_buf, opt(src if chain else np.full(b, -1, np.int32)), *explicit, *extras,
+                        opt(padded.la_masks), opt(padded.la_groups), *pools, opt(padded.logit_mask),
                         impl=impl, lp_k=lp_k,
                     )
-                else:
+                else:  # a mesh's step that chains nothing
                     out = self._enqueue(
                         self._step_fn,
-                        self.params, self.k_cache, self.v_cache, *explicit,
-                        opt(padded.mm_embeds), opt(padded.mm_slot_offset),
-                        opt(padded.mm_counts), opt(padded.mrope_positions),
-                        opt(padded.logit_mask),
-                        opt(padded.window_block_tables), opt(padded.window_slot_mapping),
+                        self.params, self.k_cache, self.v_cache, *explicit, *extras,
+                        opt(padded.logit_mask), *pools,
                         impl=impl, lp_k=lp_k,
                     )
-            chain_buf = out[0]  # [Bp], the rows in order: a shape of its own
+                    chain_buf = out[0]
         out = self._keep_moe_counts(self._keep_state(out))
         if lp_k:
             toks, self.k_cache, self.v_cache, chosen, top_ids, top_lps = out
@@ -1647,9 +1500,8 @@ class ModelRunner:
         verify can itself be the pipeline's one-step lookahead after a plain
         chained step (a plain step emits exactly one token per row, so the
         verify's positions are host-predictable even before that token
-        lands). The verify's own targets become the new chain buffer, flat
-        i32[Bp*V] row-major — the engine chains the NEXT dispatch from flat
-        index row*V + (accepted columns - 1) once acceptance is known.
+        lands). Nothing chains out of a verify: the engine harvests it before
+        it composes the next dispatch, whose tokens the host then knows.
         """
         assert batch.mm_embeds is None and batch.logit_mask is None, (
             "spec_step_async does not take multimodal/constrained batches"
@@ -1709,7 +1561,7 @@ class ModelRunner:
         else:
             targets, self.k_cache, self.v_cache = out
             aux = None
-        self._chain_tokens = targets.reshape(-1)  # flat [Bp*V] chain buffer
+        self._chain_tokens = None  # the step after a verify takes its tokens from the host
         for buf in (targets, *(aux or ())):
             try:  # start the device->host DMA early; overlaps the next step
                 buf.copy_to_host_async()

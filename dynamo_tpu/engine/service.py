@@ -46,10 +46,6 @@ class JaxEngineService(AsyncEngine[Any, dict]):
         self._wake = asyncio.Event()
         self._closed = False
         self._draining = False
-        # Outputs of the last step, routed once the next step's program is
-        # enqueued (see _engine_loop), and whether the running step enqueued.
-        self._held: list[tuple[Sequence, EngineOutput]] = []
-        self._step_enqueued = False
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -73,8 +69,6 @@ class JaxEngineService(AsyncEngine[Any, dict]):
             except asyncio.CancelledError:
                 pass
             self._loop_task = None
-        if getattr(self.core.runner, "on_enqueued", None) == self._on_enqueued:
-            self.core.runner.on_enqueued = None
         # Cancelling the loop task does NOT stop a core.step() already
         # running in the executor thread — abort_all takes the core's
         # step_lock, so running it in the executor waits that step out
@@ -121,36 +115,13 @@ class JaxEngineService(AsyncEngine[Any, dict]):
 
     # -- engine loop -------------------------------------------------------
 
-    def _on_enqueued(self) -> None:
-        """From the engine's thread: the step's program is on the device."""
-        self._step_enqueued = True
-        if self._held:
-            try:
-                self._loop.call_soon_threadsafe(self._route_held)
-            except RuntimeError:  # the loop closed under a step still running
-                pass
-
-    def _route_held(self) -> None:
-        if self._held:
-            outputs, self._held = self._held, []
-            self._route(outputs)
-
     async def _engine_loop(self) -> None:
-        loop = self._loop = asyncio.get_running_loop()
-        # A step's outputs go to their streams when the NEXT step's program
-        # has been enqueued, not when the step returns. The streams' consumers
-        # (SSE encoding and writes) run on this loop's thread; woken at once
-        # they hold the GIL against the engine thread's sched, build and
-        # dispatch, which is host time the device idles through (on a v5e's
-        # host `dispatch` 1.71 -> 1.06 ms with six rows streaming, 1.06 ->
-        # 0.90 with one; PERF.md section 6, PR 26). Under the next
-        # program they cost the step nothing, and every token is late by the
-        # same host phase, so the gap between tokens is the step's. Only a
-        # runner that says when it has enqueued (ModelRunner's synchronous
-        # dispatches) defers; a step that enqueued nothing, the overlapped
-        # loop, and the last step before the engine idles route at once.
-        if hasattr(self.core.runner, "on_enqueued"):
-            self.core.runner.on_enqueued = self._on_enqueued
+        loop = asyncio.get_running_loop()
+        # A step's outputs go to their streams when the step returns. The
+        # streams' consumers (SSE encoding and writes) run on this loop's
+        # thread and hold the GIL against the engine thread; the pipelined
+        # loop returns the tokens it read after enqueueing its own program,
+        # so they run under that program and cost the step nothing (PR 29).
         # The gap between two steps, in five parts: handoff (the step returned
         # in its thread -> this loop resumed; the core opens it), route,
         # intake, no_work, submit (-> the next step begins; the core ends it).
@@ -192,7 +163,6 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                 admitted = True
 
             if not self.core.has_work:
-                self._route_held()
                 if not admitted:
                     clock.mark(NO_WORK)
                     self._wake.clear()
@@ -206,7 +176,6 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                 if FAULTS.armed:
                     FAULTS.fire("engine.step")
                 clock.mark(SUBMIT)
-                self._step_enqueued = False
                 outputs = await loop.run_in_executor(None, self.core.step)
                 clock.mark(ROUTE)
             except Exception as exc:
@@ -235,14 +204,9 @@ class JaxEngineService(AsyncEngine[Any, dict]):
                         "error": type(exc).__name__, "detail": str(exc)[:500],
                         "where": "engine_loop", "streams": len(self._streams),
                     })
-                self._route_held()
                 self._fail_all_streams()
                 continue
-            self._route_held()  # that step enqueued nothing: its predecessor's outputs are still here
-            if self._step_enqueued and not self.core.pending_offloads:
-                self._held = outputs
-            else:
-                self._route(outputs)
+            self._route(outputs)
             # Tier write-through happens after outputs are routed, so token
             # delivery latency never waits on device->host offload copies.
             if self.core.pending_offloads:

@@ -162,10 +162,6 @@ class WorkerSpec:
                 env_flag(os.environ, "DYN_ASYNC_ONBOARD")
                 or env_flag(os.environ, "DYN_CACHE_AWARE")
             ),
-            overlap_spec=(
-                env_flag(os.environ, "DYN_OVERLAP_SPEC", default=True)
-                and env_flag(os.environ, "DYN_WORKER_OVERLAP_SPEC", default=True)
-            ),
             constraint_lookahead_tokens=int(
                 os.environ.get("DYN_CONSTRAINT_LOOKAHEAD_TOKENS", "32")
             ),
@@ -1146,7 +1142,7 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     )
     parser.add_argument(
         "--decode-steps", type=int, default=ws.decode_steps,
-        help="fused decode steps per device dispatch",
+        help="chained decode sub-dispatches per step of the pipelined loop",
     )
     parser.add_argument(
         "--chunk-prefill-tokens", type=int, default=ws.chunk_prefill_tokens,

@@ -175,7 +175,7 @@ class MockRunner:
 
     def _chain_col0(self, batch: StepBatch, chain: bool, chain_src) -> np.ndarray:
         """Column-0 input token per row, with per-row chain sourcing from the
-        flat host-side sample buffer (mirrors runner._apply_chain)."""
+        host-side sample buffer (mirrors runner._apply_chain)."""
         tok0 = batch.tokens[:, 0].copy()
         if not chain:
             return tok0
@@ -255,7 +255,7 @@ class MockRunner:
     def spec_step_async(self, batch: StepBatch, verify_width: int, lp_k: int = 0, *,
                         chain_src=None):
         """Mock of ModelRunner.spec_step_async: verify as the pipeline's
-        lookahead; targets become the flat chain buffer [B*V]."""
+        lookahead; nothing chains out of it."""
         compute = self._mixed_compute_us(batch)
         self.busy_us += compute
         self.simulated_us += compute + self.d2h_us
@@ -266,25 +266,12 @@ class MockRunner:
         tokens = batch.tokens.copy()
         tokens[:, 0] = self._chain_col0(batch, chain_src is not None, chain_src)
         targets = self._spec_targets(batch, verify_width, tokens)
-        self._chain_host = targets.reshape(-1)
+        self._chain_host = None
         aux = self._spec_lp_aux(targets, lp_k) if lp_k else None
         return MockSpecTokens(self, targets, aux, ready_at)
 
     def reset_chain(self) -> None:
         self._chain_host = None
-
-    def multi_step(self, batch: StepBatch, num_steps: int) -> np.ndarray:
-        self._report = DispatchReport()
-        b = batch.tokens.shape[0]
-        out = np.zeros((b, num_steps), np.int32)
-        tok = batch.tokens[:, 0]
-        pos = batch.positions[:, 0]
-        for i in range(num_steps):
-            self._sleep_us((self.decode_us_base + self.decode_us_per_seq * b) * self._timing_scale())
-            tok = self._tokens_for(pos, tok)
-            out[:, i] = tok
-            pos = pos + 1
-        return out
 
     # Tier hooks: payload-free stubs (pair with NullStorage tiers).
     def read_page(self, page_id: int):

@@ -186,7 +186,7 @@ class EngineMetrics:
             "dynamo_engine_overlap_barrier_total",
             "Overlap barrier steps by the condition that forced them: "
             "'cancel'/'drain' (in-flight state invalidated), 'spec' (verify "
-            "harvest or DYN_OVERLAP_SPEC off), 'prefill' (whole-prompt XOR "
+            "harvest or no async verify), 'prefill' (whole-prompt XOR "
             "mode), 'constraint' (lookahead disabled), 'constraint_miss' "
             "(mask-cache miss or successor fan-out over the lookahead cap), "
             "'runner' (runner cannot chain), 'pages' (lookahead page "

@@ -161,8 +161,9 @@ def test_preemption_then_chunked_reprefill():
 
 
 def test_chunked_decode_steps_pipeline_interleave():
-    """Chunked admission composes with the fused-burst decode path: bursts
-    drain when chunks arrive, then resume; tokens stay exact."""
+    """Chunked admission composes with the pipeline's decode_steps bursts
+    (chained sub-dispatches): a step that carries a chunk dispatches no
+    burst, then bursts resume; tokens stay exact."""
     core = make_core(chunk=4, decode_steps=4)
     p1 = [1, 2, 3, 4, 5]
     core.add_request(greedy_request(p1, max_tokens=12))

@@ -234,8 +234,9 @@ def make_core_multi(decode_steps, num_pages=64, max_batch=8, **cfg_kw):
     return EngineCore(runner, config)
 
 
-def test_multi_step_decode_matches_single_step():
-    # Fused 4-step decode bursts must be token-identical to per-step decode.
+def test_chained_sub_dispatches_match_single_step():
+    # decode_steps=4 in the pipelined loop (the default): four chained
+    # sub-dispatches a step must be token-identical to per-step decode.
     prompts = [[1, 2, 3, 4, 5], [9, 8, 7]]
     core = make_core_multi(decode_steps=4)
     for p in prompts:
@@ -245,7 +246,7 @@ def test_multi_step_decode_matches_single_step():
         assert outputs[i] == greedy_reference(p, 10), f"seq {i}"
 
 
-def test_multi_step_decode_stop_token_discards_overshoot():
+def test_chained_sub_dispatches_stop_token_discards_overshoot():
     prompt = [5, 6, 7]
     ref = greedy_reference(prompt, 8)
     stop_at = ref[2]
@@ -256,7 +257,7 @@ def test_multi_step_decode_stop_token_discards_overshoot():
     assert outputs["finish"][0] == FinishReason.STOP
 
 
-def test_multi_step_decode_odd_max_tokens():
+def test_chained_sub_dispatches_odd_max_tokens():
     # max_tokens not a multiple of the burst size.
     prompt = [2, 4, 6]
     core = make_core_multi(decode_steps=4)
@@ -306,9 +307,10 @@ def test_pipelined_decode_cancellation_inflight():
 
 
 def test_burst_overshoot_cannot_corrupt_live_pages():
-    """Heterogeneous finish lines inside one fused burst: a sequence whose
-    max_tokens ends mid-burst must not let the burst's overshoot KV writes
-    land in live pages (they are masked to the null page). Everyone stays
+    """Heterogeneous finish lines inside one burst of chained sub-dispatches:
+    a sequence whose max_tokens ends mid-burst must not let the burst's
+    overshoot KV writes land in live pages (the shortest row clamps the
+    depth; the finish-line clamp masks the rest to the null page). Everyone stays
     token-exact vs the step-by-step greedy reference, including a follow-up
     request that reuses the short sequence's cached prefix."""
     core = make_core_multi(decode_steps=8)

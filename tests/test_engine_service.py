@@ -80,49 +80,53 @@ async def test_cancellation_ends_stream():
         await svc.close()
 
 
-# -- outputs are routed once the next step's program is enqueued -----------------
+# -- a step's outputs are routed when the step returns ---------------------------
 
 
 def _spy(svc):
-    """Record, in order, every enqueue (the runner's callback) and every routed
-    batch of outputs (how many tokens it carried)."""
+    """Record, in order, every step the loop submits (when it begins, and the
+    tokens it hands back when it returns) and every routed batch of outputs
+    (how many tokens it carried)."""
     log = []
-    on_enqueued, route = svc._on_enqueued, svc._route
+    step, route = svc.core.step, svc._route
 
-    def enq():
-        log.append("enqueued")
-        on_enqueued()
+    def stepped():
+        log.append("step")
+        outputs = step()
+        log.append(("returned", sum(len(o.token_ids) for _, o in outputs)))
+        return outputs
 
     def routed(outputs):
         log.append(("routed", sum(len(o.token_ids) for _, o in outputs)))
         route(outputs)
 
-    svc._on_enqueued, svc._route = enq, routed
+    svc.core.step, svc._route = stepped, routed
     return log
 
 
-async def test_a_steps_outputs_are_routed_after_the_next_enqueue():
-    svc = make_service(overlap=False)  # the synchronous step: what the pipelined loop barriers to
+@pytest.mark.parametrize("overlap", [True, False], ids=["pipelined", "synchronous"])
+async def test_a_steps_outputs_are_routed_before_the_next_step_is_submitted(overlap):
+    svc = make_service(overlap=overlap)
     log = _spy(svc)
+    on_the_runner = set(vars(svc.core.runner))
     try:
         outs = [o async for o in svc.generate(req([1, 2, 3], 6), Context())]
-        assert [t for o in outs for t in o["token_ids"]] and outs[-1]["finish_reason"] == "length"
-        routed = [i for i, e in enumerate(log) if e != "enqueued"]
-        assert sum(log[i][1] for i in routed) == 6
-        # Every batch but the last waited for the enqueue after the step that made it:
-        # between two routed batches lies exactly one enqueue, and none is routed
-        # before the second step is on the device.
-        assert log[:2] == ["enqueued", "enqueued"]
-        for a, b in zip(routed, routed[1:-1]):
-            assert log[a + 1: b] == ["enqueued"]
-        # The last step has no successor: its outputs go when the engine idles.
-        assert log[-1][0] == "routed" and not svc._held and not svc._streams
+        assert sum(len(o["token_ids"]) for o in outs) == 6 and outs[-1]["finish_reason"] == "length"
+        # step, returned n, routed n, and only then the next step: nothing waits for a later enqueue.
+        assert len(log) % 3 == 0 and len(log) >= 3 * 6
+        for begin, returned, routed in zip(log[0::3], log[1::3], log[2::3]):
+            assert begin == "step" and returned[0] == "returned" and routed == ("routed", returned[1])
+        assert sum(e[1] for e in log[2::3]) == 6 and not svc._streams
+        # The pipelined loop hands a step's tokens back one call later; the synchronous step its own.
+        assert log[1] == ("returned", 0 if overlap else 1)
+        # The service knows the runner through the core alone: it has put nothing on it.
+        assert set(vars(svc.core.runner)) == on_the_runner
     finally:
         await svc.close()
 
 
 @pytest.mark.parametrize("overlap", [True, False], ids=["pipelined", "synchronous"])
-async def test_streams_see_every_token_in_order_under_deferred_routing(overlap):
+async def test_streams_see_every_token_in_order(overlap):
     svc = make_service(overlap=overlap)
     try:
         async def run(prompt, n):
@@ -141,38 +145,3 @@ async def test_streams_see_every_token_in_order_under_deferred_routing(overlap):
         assert got == want
     finally:
         await svc.close()
-
-
-async def test_a_runner_without_the_callback_routes_at_once():
-    svc = make_service(overlap=False)
-    log = _spy(svc)
-    try:
-        await svc.start()
-        await asyncio.sleep(0)
-        svc.core.runner.on_enqueued = None  # the loop installed it when it started
-        outs = [o async for o in svc.generate(req([1, 2, 3], 4), Context())]
-        assert sum(len(o["token_ids"]) for o in outs) == 4
-        assert "enqueued" not in log and not svc._held
-        assert [e[1] for e in log] == [1, 1, 1, 1]  # one batch a step, none held back
-    finally:
-        await svc.close()
-
-
-async def test_the_overlapped_loop_holds_nothing_back():
-    svc = make_service()  # the serving loop; step_async never blocks on its own result: no callback
-    log = _spy(svc)
-    try:
-        outs = [o async for o in svc.generate(req([1, 2, 3], 5), Context())]
-        assert sum(len(o["token_ids"]) for o in outs) == 5
-        assert "enqueued" not in log and not svc._held
-    finally:
-        await svc.close()
-
-
-async def test_close_takes_the_callback_off_the_runner():
-    svc = make_service()
-    await svc.start()
-    await asyncio.sleep(0)
-    assert svc.core.runner.on_enqueued == svc._on_enqueued
-    await svc.close()
-    assert svc.core.runner.on_enqueued is None

@@ -13,11 +13,12 @@ released) instead of emitting it.
 
 ISSUE 11 erased the hot barriers, so the parity net now also pins the
 newly chained compositions: mixed prefill+decode steps, penalized rows
-(history written in-graph), ``spec_k>0`` (verify chain-out), and
-budget-clamped final tokens (in-graph pos_limit mask instead of a host
-drain). Also covered: barrier-reason accounting, the offload-batch async
-gather routing, and the launch side: no name of the cascade arms the loop,
-DYN_OVERLAP_SPEC still resolves.
+(history written in-graph), ``spec_k>0`` (a verify chains its base token
+in; the step after it takes its tokens from the host), and budget-clamped
+final tokens (in-graph pos_limit mask instead of a host drain). Also
+covered: barrier-reason accounting, the offload-batch async gather routing,
+and the launch side: no name of the cascade arms the loop or keeps a verify
+out of it.
 """
 
 import numpy as np
@@ -324,9 +325,9 @@ def test_mixed_prefill_decode_interleave_chains(preset):
 
 
 def test_spec_k_chains_with_overlap():
-    """overlap + spec_k compose: the verify's accepted tokens stay device
-    resident and feed the next dispatch — bit-identical to the plain
-    baseline with both speculation and chaining engaged."""
+    """overlap + spec_k compose: a verify rides the pipeline (its base
+    token chained in from a plain step in flight) — bit-identical to the
+    plain baseline with both speculation and chaining engaged."""
     reqs = lambda: [PreprocessedRequest(  # noqa: E731 - periodic prompt drafts well
         token_ids=[5, 7, 5, 7, 5, 7, 9, 11],
         sampling=SamplingOptions(temperature=0.0, logprobs=2),
@@ -348,22 +349,36 @@ def test_spec_k_chains_with_overlap():
     assert core.overlap_step_counts["overlapped"] > 0  # and still pipelined
 
 
-def test_overlap_spec_off_barriers_to_sync_verify():
-    """DYN_OVERLAP_SPEC=0: speculation must not be silently dropped — the
-    engine barriers to the synchronous verify path (reason 'spec') and
-    stays bit-identical."""
-    reqs = lambda: [PreprocessedRequest(  # noqa: E731
-        token_ids=[5, 7, 5, 7, 5, 7, 9, 11],
-        sampling=SamplingOptions(temperature=0.0),
-        stop=StopConditions(max_tokens=12, ignore_eos=True),
-    )]
-    base_tok, _ = run_all(make_core(), reqs())
-    core = make_core(overlap=True, spec_k=3, overlap_spec=False)
-    spec_tok, _ = run_all(core, reqs())
-    assert spec_tok == base_tok
-    assert core.spec_tokens_proposed > 0  # speculation still engaged
-    assert core.overlap_step_counts["overlapped"] == 0  # overlap stood down
-    assert core.overlap_barrier_counts.get("spec", 0) > 0
+@pytest.mark.parametrize("temperature, seed", [(0.0, None), (0.8, 5)], ids=["greedy", "seeded"])
+def test_the_step_after_a_verify_takes_its_tokens_from_the_host(temperature, seed):
+    """A verify is harvested before anything is composed on it, so the
+    accepted tokens are in ``s.tokens``: the dispatch after it chains nothing
+    (no ``_chain_map`` entry, no chained row) and the streams are
+    ``spec_k=0``'s."""
+    reqs = lambda: [PreprocessedRequest(  # noqa: E731 - periodic prompts draft well
+        token_ids=prompt,
+        sampling=SamplingOptions(temperature=temperature, seed=seed),
+        stop=StopConditions(max_tokens=14, ignore_eos=True),
+    ) for prompt in ([5, 7, 5, 7, 5, 7, 9, 11], [3, 1, 3, 1, 3, 1, 3])]
+    base_tok, _ = run_all(make_core(overlap=True), reqs())
+    core = make_core(overlap=True, spec_k=3)
+    spec_tok = {core.add_request(r).seq_id: [] for r in reqs()}
+    after_a_verify = 0
+    for _ in range(400):
+        if not core.has_work:
+            break
+        verify_in_flight = core._inflight is not None and core._inflight.kind == "spec"
+        for seq, out in core.step():
+            spec_tok[seq.seq_id].extend(out.token_ids)
+        if verify_in_flight and core.last_step_info.get("decode_rows"):
+            after_a_verify += 1
+            assert core.last_step_info["chained_rows"] == 0
+            if core._inflight.kind == "spec":  # a verify again: it leaves no entry and no buffer either
+                assert core._chain_map == {} and core.runner._chain_tokens is None
+    assert not core.has_work and spec_tok == base_tok
+    assert after_a_verify > 0 and core.spec_tokens_proposed > 0
+    assert core.overlap_barrier_counts.get("spec", 0) >= after_a_verify  # a verify in flight is harvested first
+    assert core.allocator.stats().active_pages == 0
 
 
 def test_penalized_sampling_chains():
@@ -418,8 +433,9 @@ def test_budget_clamped_final_token_chains(preset):
 def test_multistep_rides_the_chained_pipeline_under_overlap():
     """overlap + decode_steps>1: the burst is served as K chained
     sub-dispatches inside the unified pipeline (no 'multistep' barrier
-    exists anymore) — bit-identically vs the sync fused burst, admission
-    drains included, and with the sub-steps counted as chained rows."""
+    exists anymore) — bit-identically vs the one-token synchronous oracle,
+    admission drains included, and with the sub-steps counted as chained
+    rows."""
     reqs = lambda: [  # noqa: E731
         PreprocessedRequest(
             token_ids=[5, 7, 5, 7, 9, 11],
@@ -455,9 +471,9 @@ def test_multistep_rides_the_chained_pipeline_under_overlap():
 
 
 def test_multistep_chained_burst_deep_parity():
-    """decode_steps sweep: the chained burst path must replay the sync
-    fused burst token-for-token at several depths, including depths that
-    overshoot the rows' budgets (the clamp keeps every sub-step real)."""
+    """decode_steps sweep: the chained burst path must replay the
+    synchronous oracle token-for-token at several depths, including depths
+    that overshoot the rows' budgets (the clamp keeps every sub-step real)."""
     vocab = PRESETS["test-tiny"].vocab_size
     reqs = lambda: [  # noqa: E731
         PreprocessedRequest(
@@ -480,6 +496,47 @@ def test_multistep_chained_burst_deep_parity():
     for k in (2, 8):
         over_tok, _ = run_all(make_core(overlap=True, decode_steps=k), reqs())
         assert over_tok == base_tok, f"decode_steps={k} diverged"
+
+
+def test_the_synchronous_oracle_emits_one_token_a_step_whatever_decode_steps_says():
+    """``decode_steps`` counts the pipeline's chained sub-dispatches and
+    nothing else: with ``overlap=False`` every output carries one token and
+    the streams are ``decode_steps=1``'s, while the pipeline hands back four
+    a call."""
+    reqs = lambda: [  # noqa: E731
+        PreprocessedRequest(
+            token_ids=[5, 7, 5, 7, 9, 11],
+            sampling=SamplingOptions(temperature=0.0),
+            stop=StopConditions(max_tokens=12, ignore_eos=True),
+        ),
+        PreprocessedRequest(
+            token_ids=[3, 3, 3, 3, 2, 1],
+            sampling=SamplingOptions(temperature=0.7, seed=7, frequency_penalty=0.4),
+            stop=StopConditions(max_tokens=9, ignore_eos=True),
+        ),
+    ]
+    base_tok, _ = run_all(make_core(decode_steps=1), reqs())
+
+    def sizes(core):
+        tokens = {core.add_request(r).seq_id: [] for r in reqs()}
+        per_output = []
+        while core.has_work:
+            for seq, out in core.step():
+                tokens[seq.seq_id].extend(out.token_ids)
+                per_output.append(len(out.token_ids))
+        return tokens, per_output
+
+    sync_tok, per_output = sizes(make_core(decode_steps=4))
+    assert sync_tok == base_tok and set(per_output) == {1}
+    assert not hasattr(make_core().runner, "multi_step")  # no program fuses a burst
+    # The pipeline's burst is the knob's one meaning (rows with a penalty take one token a step).
+    greedy = reqs()[:1]
+    core = make_core(overlap=True, decode_steps=4)
+    seq = core.add_request(greedy[0])
+    per_call = []
+    while core.has_work:
+        per_call.append(sum(len(out.token_ids) for s, out in core.step() if s is seq))
+    assert max(per_call) == 4 and sum(per_call) == 12
 
 
 # -- chained constrained (JSON-mode) decode ----------------------------------
@@ -682,7 +739,7 @@ def test_core_flush_offloads_uses_runner_async_gather(monkeypatch):
 
 def test_launch_serves_the_pipelined_loop_whatever_the_environment(monkeypatch):
     """The pipelined loop is the serving loop: no name of the cascade arms or
-    disarms it. ``DYN_OVERLAP_SPEC`` still says whether a verify rides it."""
+    disarms it, or says whether a verify rides it."""
     from dynamo_tpu.launch import WorkerSpec
     from dynamo_tpu.model_card import ModelDeploymentCard
 
@@ -691,16 +748,12 @@ def test_launch_serves_the_pipelined_loop_whatever_the_environment(monkeypatch):
     )
     monkeypatch.delenv("DYN_OVERLAP", raising=False)
     monkeypatch.delenv("DYN_WORKER_OVERLAP", raising=False)
-    monkeypatch.delenv("DYN_OVERLAP_SPEC", raising=False)
-    monkeypatch.delenv("DYN_WORKER_OVERLAP_SPEC", raising=False)
     assert EngineConfig().overlap is True
     assert WorkerSpec._engine_cfg(card, {}).overlap is True
-    assert WorkerSpec._engine_cfg(card, {}).overlap_spec is True  # default on
     monkeypatch.setenv("DYN_OVERLAP", "0")
     monkeypatch.setenv("DYN_WORKER_OVERLAP", "false")
     assert WorkerSpec._engine_cfg(card, {}).overlap is True  # read by nothing
-    monkeypatch.setenv("DYN_OVERLAP_SPEC", "0")
-    assert WorkerSpec._engine_cfg(card, {}).overlap_spec is False
+    assert not hasattr(WorkerSpec._engine_cfg(card, {}), "overlap_spec")  # gone with its two names
 
 
 def test_launch_resolves_constraint_lookahead(monkeypatch):
@@ -725,7 +778,4 @@ def test_worker_settings_overlap_field(monkeypatch):
     assert not hasattr(load_worker_settings(env={"DYN_WORKER_OVERLAP": "1"}), "overlap")
     with pytest.raises(SystemExit):
         parse_args(["--role", "local", "--overlap"])  # the flag is gone with the knob
-    assert load_worker_settings(env={}).overlap_spec is True
-    assert load_worker_settings(
-        env={"DYN_WORKER_OVERLAP_SPEC": "0"}
-    ).overlap_spec is False
+    assert not hasattr(load_worker_settings(env={}), "overlap_spec")

@@ -154,6 +154,56 @@ def test_a_split_step_chains_its_decode_rows_and_hands_on_a_chunk_rows_sample(ro
         np.testing.assert_array_equal(np.asarray(ca)[:, 1:], np.asarray(cb)[:, 1:])
 
 
+@pytest.mark.parametrize("before", ["text", "logit_mask", "lookahead"])
+def test_a_text_step_chains_out_of_every_kind_of_dispatch_before_it(before):
+    """Each kind of async dispatch that can precede a chained text step on one
+    device (a text step; an explicit-argument step with a host-built
+    ``logit_mask``, which chains nothing; one with lookahead mask groups, which
+    chains) leaves its samples in the chain buffer at the one width, and the
+    text step after it samples what the synchronous ``step`` samples when the
+    host feeds the same tokens."""
+    cfg = MODELS["dense-gqa"]
+    a, b = runner_for(cfg), runner_for(cfg)
+    rows = [(5, 1), (21, 1), (12, 1)]  # a rows bucket of 4 under a chain buffer of 8
+    odd = np.zeros((len(rows), cfg.vocab_size), bool)
+    odd[:, 1::2] = True
+    src = np.arange(len(rows), dtype=np.int32)
+
+    def batches(rows, toks=None, **kw):
+        """The batch twice: for the pipelined runner (a chained row's token a placeholder), for the oracle."""
+        mine, host = (step_batch(rows, temperature=0.9, **kw) for _ in range(2))
+        if toks is not None:
+            mine.tokens[:, 0], host.tokens[:, 0] = 0, toks
+        return mine, host
+
+    mine, host = batches(rows)
+    if before == "logit_mask":
+        mine.logit_mask = host.logit_mask = odd
+    a.step_async(mine)
+    toks = b.step(host)
+    if before == "logit_mask":
+        assert (toks % 2 == 1).all()
+    if before == "lookahead":  # chained itself: a row whose gathered token is even samples under group 1, odd tokens only
+        rows = [(start + 1, 1) for start, _ in rows]
+        mine, host = batches(rows, toks, seed=1)
+        mine.la_masks = np.stack([np.ones_like(odd), odd], axis=1)
+        mine.la_groups = np.broadcast_to((np.arange(cfg.vocab_size) % 2 == 0).astype(np.int32), odd.shape).copy()
+        host.logit_mask = np.where((toks % 2 == 0)[:, None], odd, True)
+        a.step_async(mine, chain=True, chain_src=src)
+        toks = b.step(host)
+        assert (toks[np.flatnonzero(host.tokens[:, 0] % 2 == 0)] % 2 == 1).all()
+    assert np.asarray(a._chain_tokens).shape == (a._chain_width,)
+    assert np.asarray(a._chain_tokens)[: len(rows)].tolist() == toks.tolist()
+    mine, host = batches([(start + 1, 1) for start, _ in rows], toks, seed=2)
+    got = a.step_async(mine, chain=True, chain_src=src).result()[0][:, 0]
+    assert a.last_step_layout == (ROWS_X_T, 4)
+    assert got.tolist() == b.step(host).tolist()
+    for ca, cb in ((a.k_cache, b.k_cache), (a.v_cache, b.v_cache)):
+        np.testing.assert_array_equal(np.asarray(ca)[:, 1:], np.asarray(cb)[:, 1:])
+    # One decode program whatever came before: the chain buffer's shape is no part of its key.
+    assert a._step_packed_fn._cache_size() == 1
+
+
 def test_tokens_come_back_in_the_batchs_row_order():
     """Row i of the result is row i of the batch wherever its slot sits: each
     row alone (a batch of one, no slot to confuse) samples the same token."""

@@ -247,12 +247,11 @@ def _null_batch(b, t, n=4):
     return null_batch(b, t, n)
 
 
-#: The runner's five dispatch sites: a call on a new bucket, the attention phase
+#: The runner's four dispatch sites: a call on a new bucket, the attention phase
 #: it reports and the token positions of its padded rectangle.
 SITES = {
     "step": (lambda r: r.step(_null_batch(2, 1)), "decode", 2),
     "spec_step": (lambda r: r.spec_step(_null_batch(2, 4), 3), "verify", 8),
-    "multi_step": (lambda r: r.multi_step(_null_batch(2, 1), 2), "decode", 2),
     "step_async": (lambda r: r.step_async(_null_batch(2, 1)).result(), "decode", 2),
     "spec_step_async": (lambda r: r.spec_step_async(_null_batch(2, 4), 3).result(), "verify", 8),
 }
@@ -262,6 +261,24 @@ def _runner(**kw):
     cfg = PRESETS["test-tiny"]
     return ModelRunner(cfg, llama.init_params(cfg, 0), num_pages=16, page_size=PAGE, max_batch_size=4,
                        prefill_bucket=4, attn_impl="reference", **kw)
+
+
+def test_the_runners_step_programs_and_dispatch_sites_are_the_named_ones():
+    """Every program a ``ModelRunner`` jits is found on it by attribute: the
+    step programs are these six and the rest move pages or embed, so the next
+    one is added on purpose (each is one more place a new mechanism is
+    threaded through or refused in). The methods that dispatch are ``SITES``."""
+    import inspect
+
+    jitted = {name for name, v in vars(_runner()).items() if hasattr(v, "lower") and hasattr(v, "trace")}
+    assert {n for n in jitted if "step" in n} == {
+        "_step_fn", "_step_split_fn", "_step_packed_fn", "_step_chained_explicit_fn",
+        "_spec_step_fn", "_spec_step_chained_fn"}
+    assert {n for n in jitted if "step" not in n} == {
+        "_write_page_fn", "_gather_pages_fn", "_scatter_pages_fn", "_embed_fn"}
+    sites = {name for name, fn in vars(ModelRunner).items()
+             if inspect.isfunction(fn) and "with self._dispatch(" in inspect.getsource(fn)}
+    assert sites == set(SITES)
 
 
 @pytest.mark.parametrize("site", SITES)
