@@ -409,10 +409,11 @@ class ModelRunner:
         # has the flat path: text models on one device (llama.forward), GQA,
         # MHA and MLA attention alike.
         self._can_split = forward_fn is None and mesh is None and not cfg.mrope_section
-        # A model with recurrent layers (KDA): a second kind of per-sequence
-        # state beside the pages, a fixed-size slot a running sequence
-        # (models/kda.py); slot 0 is the null slot, as page 0 is the null page.
-        self.recurrent = forward_fn is None and bool(cfg.layer_group_size)
+        # A model with recurrent layers (KDA layers in place of attention, or a
+        # Mamba-2 mixer beside it): a second kind of per-sequence state beside
+        # the pages, a fixed-size slot a running sequence (models/kda.py,
+        # models/mamba2.py); slot 0 is the null slot, as page 0 is the null page.
+        self.recurrent = forward_fn is None and bool(cfg.recurrent_layers)
         if self.recurrent and mesh is not None:
             raise NotImplementedError(f"{cfg.name}: a model with recurrent layers is served on one device, not a mesh")
         self.state_slots = max_batch_size + 1 if self.recurrent else 0
@@ -460,9 +461,10 @@ class ModelRunner:
                 from dynamo_tpu.models.mla import lay_heads_major
 
                 params = lay_heads_major(params)
-        # The recurrent state buffers (state, conv), donated through a step
-        # and handed back like the caches; () for every other model, whose
-        # step programs take and return nothing for it.
+        # The recurrent state buffers (state, conv; their shapes are the
+        # model's, ``cfg.state_shapes``), donated through a step and handed
+        # back like the caches; () for every other model, whose step programs
+        # take and return nothing for it.
         self.state: tuple = ()
         if self.recurrent:
             from dynamo_tpu.models.kda import init_state
@@ -1007,7 +1009,8 @@ class ModelRunner:
         ("ring") without touching the jitted program."""
         t = int(padded.tokens.shape[1])
         phase = "verify" if (verify and t > 1) else ("decode" if t == 1 else "prefill")
-        if self.cfg.sliding_window or self.cfg.attn_type == "mla":  # other models count nothing (0, 0)
+        # (other models count nothing (0, 0); a model with a mixer counts its one kind of GQA layer)
+        if self.cfg.sliding_window or self.cfg.attn_type == "mla" or self.cfg.ssm_heads:
             self._kv_pending = padded
         if impl == "ring":
             return phase, "ring"
@@ -1045,7 +1048,8 @@ class ModelRunner:
         a full layer (an MLA model's attention sublayer) every row's context;
         a windowed layer at most the window plus the row's new tokens less
         one. Padding rows (a null block table) count nothing. Only a model
-        with a windowed layer, or with latent attention, is counted."""
+        with a windowed layer, with latent attention or with a mixer beside
+        its attention is counted."""
         pos = np.asarray(padded.positions)[np.asarray(padded.block_tables).any(axis=1)]
         if not len(pos):
             return 0, 0

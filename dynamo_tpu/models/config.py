@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import pathlib
 from typing import Any
 
@@ -120,6 +121,15 @@ def _expert_share(config: dict, held_key: str = "n_routed_experts") -> tuple[int
     if held <= 0 or first + held > total:
         raise ValueError(f"experts [{first}, {first + held}) lie outside the {total} published")
     return held, total, first
+
+
+def _refuse_unserved(config: dict, served: dict, model_type: str) -> None:
+    """Refuses by name every key of ``served`` that ``config`` sets otherwise
+    (a falsy key is a falsy key, however it is spelt)."""
+    for key, want in served.items():
+        got = config.get(key, want)
+        if got != want and (got or want):
+            raise ValueError(f"{key} {got!r} is not served for model_type {model_type!r}: only {want!r}")
 
 
 def _group_limit(config: dict, held: int, total: int) -> tuple[int, int]:
@@ -248,6 +258,31 @@ class ModelConfig:
     layer_group_size: int = 0
     kda_conv_size: int = 4  # taps of the causal depthwise convolution on q, k and v
     kda_lower_bound: float = -5.0  # the log-decay lies in (kda_lower_bound, 0)
+    # A Mamba-2 mixer beside the attention of every layer (Falcon-H1's
+    # ``falcon_h1``, models/mamba2.py): both read the layer's normed input and
+    # their outputs are summed, so a layer owns a slab of the page pool *and* a
+    # slot. ``ssm_heads`` heads of ``ssm_head_dim`` channels, each with a float32
+    # ``ssm_state_size x ssm_head_dim`` state; ``ssm_groups`` groups of heads
+    # share a B and a C; a causal depthwise convolution of ``ssm_conv_size``
+    # taps, with a bias, over x, B and C. 0 heads = no mixer.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1
+    ssm_conv_size: int = 4
+    # muP multipliers (Falcon-H1), each where the published code has it; 1.0 = none.
+    # ``ssm_multipliers`` scales the five sections [z | x | B | C | dt] of the
+    # mixer's input projection.
+    embed_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
 
     @property
     def q_dim(self) -> int:
@@ -262,24 +297,51 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def ssm_inner(self) -> int:
+        """Channels of the mixer's x (and of its gate z): heads x head channels."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the mixer's convolution runs over: x, then B and C a group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    @property
     def recurrent_layers(self) -> int:
-        """Layers whose state is a slot, not pages (KDA): all but one a period."""
+        """Layers whose state is a slot: every layer of a model with a mixer
+        (beside its pages); of a KDA hybrid all but one a period (instead of
+        pages)."""
+        if self.ssm_heads:
+            return self.num_layers
         g = self.layer_group_size
         return self.num_layers - self.num_layers // g if g else 0
 
     @property
     def cache_layers(self) -> int:
         """Slabs of the paged cache: one per attention (sub)layer that attends
-        over the context (a recurrent layer holds none)."""
-        return (self.num_layers - self.recurrent_layers) * (2 if self.shortcut_moe else 1)
+        over the context (a KDA layer holds none; a layer with a mixer beside
+        its attention holds one)."""
+        attending = self.num_layers if self.ssm_heads else self.num_layers - self.recurrent_layers
+        return attending * (2 if self.shortcut_moe else 1)
+
+    def state_shapes(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """One recurrent layer's part of a slot, by the kind of its state:
+        (the float32 state, the conv state in the model's dtype). KDA: a
+        ``key x value`` matrix a head and the last ``taps - 1`` inputs of the
+        q, k and v streams. Mamba-2: a ``state x head channels`` matrix a head
+        (state-major, the transposition of the published cache's) and the last
+        ``taps - 1`` inputs of x, B and C."""
+        if self.ssm_heads:
+            return ((self.ssm_heads, self.ssm_state_size, self.ssm_head_dim),
+                    (self.ssm_conv_size - 1, self.ssm_conv_dim))
+        return ((self.num_heads, self.head_dim, self.head_dim), (self.kda_conv_size - 1, 3 * self.q_dim))
 
     def state_bytes_per_slot(self) -> int:
-        """Bytes of recurrent state one sequence holds over all KDA layers: a
-        float32 ``head_dim x head_dim`` matrix a head, and the last
-        ``kda_conv_size - 1`` inputs of the three convolved streams."""
+        """Bytes of recurrent state one sequence holds over all recurrent
+        layers (``state_shapes``: float32, and the conv state at the dtype's width)."""
         itemsize = 2 if self.dtype == "bfloat16" else 4
-        per_layer = self.num_heads * self.head_dim * self.head_dim * 4 + (self.kda_conv_size - 1) * 3 * self.q_dim * itemsize
-        return self.recurrent_layers * per_layer
+        state, conv = self.state_shapes()
+        return self.recurrent_layers * (math.prod(state) * 4 + math.prod(conv) * itemsize)
 
     @property
     def routed_experts(self) -> int:
@@ -363,7 +425,11 @@ class ModelConfig:
             gate = d * self.num_heads  # the latent-attention layers' head-wise output gate (``w_out_gate``)
             return (embed + head + d + n_kda * kda + (self.num_layers - n_kda) * (attn + gate)
                     + self.num_layers * norms + k_dense * dense + (self.num_layers - k_dense) * moe)
-        return (embed + head + d + self.num_layers * (attn + norms)
+        # A mixer: two projections, the filter and its bias, A_log, dt_bias and D a head, the gated norm.
+        inner = self.ssm_inner
+        mixer = (d * (inner + self.ssm_conv_dim + self.ssm_heads) + inner * d
+                 + (self.ssm_conv_size + 1) * self.ssm_conv_dim + 3 * self.ssm_heads + inner) if inner else 0
+        return (embed + head + d + self.num_layers * (attn + mixer + norms)
                 + k_dense * dense + (self.num_layers - k_dense) * moe)
 
     @classmethod
@@ -437,10 +503,7 @@ class ModelConfig:
             "topk_method": "noaux_tc", "moe_router_enable_expert_bias": True, "hidden_act": "silu",
             "q_lora_rank": None, "rope_scaling": None,
         }
-        for key, served in unserved.items():
-            got = config.get(key, served)
-            if got != served and (got or served):  # a falsy key is a falsy key, however it is spelt
-                raise ValueError(f"{key} {got!r} is not served for model_type 'bailing_hybrid': only {served!r}")
+        _refuse_unserved(config, unserved, "bailing_hybrid")
         for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
             limits = list(config.get(key) or [])
             held = [(i, v) for i, v in enumerate(limits[:layers]) if v]
@@ -473,6 +536,66 @@ class ModelConfig:
             attention_bias=False, layer_group_size=group,
             kda_conv_size=int(config.get("short_conv_kernel_size", 4)),
             kda_lower_bound=float(config.get("kda_lower_bound", -5.0)),
+        )
+
+    @classmethod
+    def _from_falcon_h1(cls, config: dict, name: str | None) -> "ModelConfig":
+        """Falcon-H1's config.json (``model_type`` ``falcon_h1``): every layer
+        a Mamba-2 mixer and GQA attention side by side on one normed input,
+        their outputs summed, then a SwiGLU FFN; a muP multiplier on the
+        embedding, on the mixer's input and the five sections of its
+        projection, on the keys, on each block's output, inside the FFN and on
+        the logits. A file that states a pipeline stage
+        (``num_hidden_layers_published`` beside ``num_hidden_layers`` held here,
+        ``pipeline_stages`` equal stages) gives a model of that many layers.
+        Refuses by name what the layer does not compute; ``mamba_chunk_size``
+        tiles the published kernels and changes no mathematics (the program's
+        chunk is the engine's), ``mamba_expand`` is overridden by
+        ``mamba_d_ssm``, ``mamba_use_mlp`` and ``num_logits_to_keep`` decide
+        nothing here: taken without complaint."""
+        unserved = {
+            "mamba_norm_before_gate": False, "mamba_rms_norm": True, "mamba_conv_bias": True, "mamba_proj_bias": False,
+            "projectors_bias": False, "attention_bias": False, "mlp_bias": False,
+            "attn_layer_indices": None, "rope_scaling": None, "hidden_act": "silu", "tie_word_embeddings": False,
+        }
+        _refuse_unserved(config, unserved, "falcon_h1")
+        hidden, layers = config["hidden_size"], int(config["num_hidden_layers"])
+        heads, head_dim, groups = int(config["mamba_n_heads"]), int(config["mamba_d_head"]), int(config["mamba_n_groups"])
+        d_ssm = config.get("mamba_d_ssm")
+        d_ssm = int(config["mamba_expand"] * hidden) if d_ssm is None else int(d_ssm)
+        if heads * head_dim != d_ssm:
+            raise ValueError(f"mamba_n_heads {heads} x mamba_d_head {head_dim} is not mamba_d_ssm {d_ssm}: not served")
+        if groups <= 0 or heads % groups:
+            raise ValueError(f"mamba_n_heads {heads} is not a multiple of mamba_n_groups {groups}: not served")
+        stages = int(config.get("pipeline_stages", 1))
+        published = int(config.get("num_hidden_layers_published", layers * stages))
+        if layers * stages != published or not 0 <= int(config.get("stage_rank", 0)) < stages:
+            raise ValueError(f"num_hidden_layers {layers} x pipeline_stages {stages} (stage_rank "
+                             f"{config.get('stage_rank', 0)}) is not num_hidden_layers_published {published}: "
+                             "a stage holds an equal share of the layers")
+        mlp, ssm = config.get("mlp_multipliers") or (1.0, 1.0), config.get("ssm_multipliers") or (1.0,) * 5
+        if len(mlp) != 2 or len(ssm) != 5:
+            raise ValueError(f"mlp_multipliers {mlp!r} / ssm_multipliers {ssm!r}: expected 2 and 5 entries (z, x, B, C, dt)")
+        attn_heads = config["num_attention_heads"]
+        return cls(
+            name=name or config.get("_name_or_path", "falcon_h1"),
+            vocab_size=config["vocab_size"], hidden_size=hidden, num_layers=layers,
+            num_heads=attn_heads, num_kv_heads=config.get("num_key_value_heads", attn_heads),
+            head_dim=config.get("head_dim") or hidden // attn_heads, intermediate_size=config["intermediate_size"],
+            rope_theta=float(config.get("rope_theta", 10000.0)), rope_scaling=None,
+            rms_eps=config.get("rms_norm_eps", 1e-5), max_position=config.get("max_position_embeddings", 8192),
+            tie_embeddings=False, attention_bias=False,
+            ssm_heads=heads, ssm_head_dim=head_dim, ssm_state_size=int(config["mamba_d_state"]), ssm_groups=groups,
+            ssm_conv_size=int(config.get("mamba_d_conv", 4)),
+            embed_multiplier=float(config.get("embedding_multiplier", 1.0)),
+            lm_head_multiplier=float(config.get("lm_head_multiplier", 1.0)),
+            attn_in_multiplier=float(config.get("attention_in_multiplier", 1.0)),
+            attn_out_multiplier=float(config.get("attention_out_multiplier", 1.0)),
+            key_multiplier=float(config.get("key_multiplier", 1.0)),
+            mlp_gate_multiplier=float(mlp[0]), mlp_down_multiplier=float(mlp[1]),
+            ssm_in_multiplier=float(config.get("ssm_in_multiplier", 1.0)),
+            ssm_out_multiplier=float(config.get("ssm_out_multiplier", 1.0)),
+            ssm_multipliers=tuple(float(m) for m in ssm),
         )
 
     @classmethod
@@ -525,6 +648,16 @@ class ModelConfig:
             return cls._from_longcat(config, name)
         if config.get("model_type") == "bailing_hybrid":
             return cls._from_bailing_hybrid(config, name)
+        if config.get("model_type") == "falcon_h1":
+            return cls._from_falcon_h1(config, name)
+        # A state-space model's config also describes a GQA stack: served by this
+        # branch it would run as that stack alone, silently (what PR 26 found for Mellum2).
+        ssm_keys = sorted(k for k in config if k.startswith(("mamba_", "ssm_")))
+        if ssm_keys:
+            raise ValueError(
+                f"model_type {config.get('model_type')!r} states {ssm_keys[0]} (and {len(ssm_keys) - 1} more mamba_* / "
+                "ssm_* keys): a state-space layer that no branch of from_hf reads is not served "
+                "(served with a mixer: model_type 'falcon_h1')")
         hidden = config["hidden_size"]
         heads = config["num_attention_heads"]
         # DeepSeek replaces the first k MoE layers with dense MLPs
@@ -883,3 +1016,37 @@ TINY_HYBRID_HF: dict[str, Any] = {
 }
 PRESETS["test-tiny-hybrid"] = dataclasses.replace(
     ModelConfig.from_hf(TINY_HYBRID_HF, name="test-tiny-hybrid"), dtype="float32")
+
+
+#: Falcon-H1-34B-Instruct's published ``config.json`` (tiiuae; ``model_type``
+#: ``falcon_h1``), key for key: ``tests/benchmark/test_benchmark_falcon_h1.py``
+#: holds it to the catalog row where the catalog is on the machine.
+FALCON_H1_34B_HF: dict[str, Any] = {
+    "model_type": "falcon_h1", "attention_bias": False, "attention_in_multiplier": 1, "attention_out_multiplier": 0.0375,
+    "attn_layer_indices": None, "embedding_multiplier": 5.656854249492381, "head_dim": 128, "hidden_act": "silu",
+    "hidden_size": 5120, "intermediate_size": 21504, "key_multiplier": 0.011048543456039804,
+    "lm_head_multiplier": 0.0078125, "mamba_chunk_size": 128, "mamba_conv_bias": True, "mamba_d_conv": 4,
+    "mamba_d_head": 128, "mamba_d_ssm": 4096, "mamba_d_state": 256, "mamba_expand": 2, "mamba_n_groups": 2,
+    "mamba_n_heads": 32, "mamba_norm_before_gate": False, "mamba_proj_bias": False, "mamba_rms_norm": True,
+    "mamba_use_mlp": True, "max_position_embeddings": 262144, "mlp_bias": False, "mlp_expansion_factor": 8,
+    "mlp_multipliers": [0.1767766952966369, 0.011160714285714284], "num_attention_heads": 20,
+    "num_hidden_layers": 72, "num_key_value_heads": 4, "num_logits_to_keep": 1, "projectors_bias": False,
+    "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 100000000000, "ssm_in_multiplier": 0.25,
+    "ssm_multipliers": [0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738],
+    "ssm_out_multiplier": 0.08838834764831845, "tie_word_embeddings": False, "vocab_size": 261120,
+}
+#: The same keys at toy widths: three layers, 4 query heads over 2 KV
+#: heads of 16, a mixer of 4 heads of 16 channels in 2 groups with a state of
+#: 8, float32. The multipliers are made-up values near 1, each different, so
+#: that a multiplier in the wrong place shows in the logits (the published
+#: ``key_multiplier`` would make every softmax flat at this size).
+TINY_FALCON_H1_HF: dict[str, Any] = {
+    **FALCON_H1_34B_HF, "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 3,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "mamba_n_heads": 4, "mamba_d_head": 16,
+    "mamba_d_ssm": 64, "mamba_d_state": 8, "mamba_chunk_size": 8, "vocab_size": 256, "max_position_embeddings": 512,
+    "rope_theta": 10000.0, "attention_in_multiplier": 0.75, "attention_out_multiplier": 0.6,
+    "embedding_multiplier": 1.5, "key_multiplier": 0.8, "lm_head_multiplier": 0.5, "mlp_multipliers": [0.7, 1.25],
+    "ssm_in_multiplier": 0.5, "ssm_multipliers": [0.9, 0.8, 1.1, 1.2, 0.6], "ssm_out_multiplier": 0.7,
+}
+PRESETS["test-tiny-falcon-h1"] = dataclasses.replace(
+    ModelConfig.from_hf(TINY_FALCON_H1_HF, name="test-tiny-falcon-h1"), dtype="float32")

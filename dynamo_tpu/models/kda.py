@@ -77,12 +77,15 @@ def init_kda_params(cfg: ModelConfig, key: jax.Array, dt, num_layers: int) -> di
 
 
 def init_state(cfg: ModelConfig, slots: int, dtype=None) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``(state f32[kda layers * slots, heads, key, value], conv [kda layers *
-    slots, taps - 1, 3 * q_dim])``, zeros. ``slots`` counts the null slot."""
+    """The two state buffers of a model with recurrent layers, zeros, flat
+    over ``(recurrent layer, slot)``; ``slots`` counts the null slot. The
+    shapes are the model's (``cfg.state_shapes``). KDA: ``(state f32[layers *
+    slots, heads, key, value], conv [layers * slots, taps - 1, 3 * q_dim])``;
+    a Mamba-2 mixer (``models/mamba2.py``): ``(f32[layers * slots, heads,
+    state, head channels], [layers * slots, taps - 1, conv channels])``."""
     n = cfg.recurrent_layers * slots
-    dt = dtype or jnp.dtype(cfg.dtype)
-    return (jnp.zeros((n, cfg.num_heads, cfg.head_dim, cfg.head_dim), jnp.float32),
-            jnp.zeros((n, cfg.kda_conv_size - 1, 3 * cfg.q_dim), dt))
+    state, conv = cfg.state_shapes()
+    return jnp.zeros((n, *state), jnp.float32), jnp.zeros((n, *conv), dtype or jnp.dtype(cfg.dtype))
 
 
 def recurrent_step(s, q, k, v, g, beta):
@@ -128,15 +131,18 @@ def chunk_step(s0, q, k, v, g, beta):
     return o, s
 
 
-def _conv(x, prev, filt, n_valid):
-    """Causal depthwise convolution then SiLU of rows ``x [R, T, W]`` behind
-    their carried inputs ``prev [R, taps - 1, W]``; ``filt [taps, W]``, the
-    last tap on the current token. Returns ``(y [R, T, W], the last taps - 1
-    inputs up to each row's ``n_valid``-th token)``."""
+def causal_conv(x, prev, filt, n_valid, bias=None):
+    """Causal depthwise convolution (plus ``bias [W]``, where the layer has
+    one) then SiLU of rows ``x [R, T, W]`` behind their carried inputs ``prev
+    [R, taps - 1, W]``; ``filt [taps, W]``, the last tap on the current token.
+    Returns ``(y [R, T, W], the last taps - 1 inputs up to each row's
+    ``n_valid``-th token)``."""
     taps, t = filt.shape[0], x.shape[1]
     full = jnp.concatenate([prev.astype(x.dtype), x], axis=1)  # [R, taps - 1 + T, W]
     y = sum(full[:, j: j + t].astype(jnp.float32) * filt[j].astype(jnp.float32) for j in range(taps))
     carried = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, taps - 1, axis=0))(full, n_valid)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y), carried
 
 
@@ -223,7 +229,7 @@ def kda_attention(
         fresh = shape(positions)[:, 0] == 0
         with jax.named_scope("kda.conv"):
             prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv[ids])
-            y, carried = _conv(xr, prev, filt, ok.sum(axis=1, dtype=jnp.int32))
+            y, carried = causal_conv(xr, prev, filt, ok.sum(axis=1, dtype=jnp.int32))
             conv = conv.at[ids].set(carried.astype(conv.dtype))
             q, k, v = (y[..., i * heads * hd: (i + 1) * heads * hd].reshape(n, width, heads, hd) for i in range(3))
             q, k = _l2norm(q) * hd**-0.5, _l2norm(k)

@@ -80,6 +80,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
                     "wo": w(ks[3], (l, q, d), q),
                 }
             )
+        if attn and cfg.ssm_heads:  # a Mamba-2 mixer beside the attention, in every layer
+            from dynamo_tpu.models.mamba2 import init_mamba_params
+
+            layers.update(init_mamba_params(cfg, jax.random.fold_in(ks[0], 7), dt, l))
         if attn and cfg.attention_bias:
             layers.update(
                 {
@@ -245,11 +249,16 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int, dtype: jnp.d
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
-def _mlp_dense(lp: Params, x: jnp.ndarray, act: str = "silu") -> jnp.ndarray:
+def _mlp_dense(lp: Params, x: jnp.ndarray, act: str = "silu", gate_mult: float = 1.0, down_mult: float = 1.0) -> jnp.ndarray:
+    """``gate_mult`` / ``down_mult``: Falcon-H1's ``mlp_multipliers``, on the
+    gate's pre-activation and on the output (1.0: no multiply in the program)."""
     gp = _qmm(x, lp["w_gate"])
+    if gate_mult != 1.0:
+        gp = gp * jnp.asarray(gate_mult, gp.dtype)
     # Gemma's GeGLU uses the tanh-approximate gelu (HF gelu_pytorch_tanh).
     gate = jax.nn.gelu(gp, approximate=True) if act == "gelu_tanh" else jax.nn.silu(gp)
-    return _qmm(gate * _qmm(x, lp["w_up"]), lp["w_down"])
+    out = _qmm(gate * _qmm(x, lp["w_up"]), lp["w_down"])
+    return out * jnp.asarray(down_mult, out.dtype) if down_mult != 1.0 else out
 
 
 def _routing_kwargs(cfg: ModelConfig) -> dict:
@@ -388,7 +397,7 @@ def forward(
     contiguous_positions: bool = True,  # False: route attention via gappy-safe paths
     split: tuple[int, int, int] | None = None,  # (decode slots, chunk slots, tokens per chunk slot)
     moe_counts: bool = False,  # also return the held-share expert layers' counters
-    recurrent: tuple | None = None,  # (state, conv, slot ids i32[rows]) of a model with KDA layers
+    recurrent: tuple | None = None,  # (state, conv, slot ids i32[rows]) of a model with recurrent layers
     window_tables: jnp.ndarray | None = None,  # a mixed model's sliding layers: their block tables,
     window_slots: jnp.ndarray | None = None,  # their slot mapping (shaped as the full layers')
     window_pages: int | None = None,  # and the pages each of them holds (``init_kv_cache``)
@@ -402,7 +411,8 @@ def forward(
     position). Without them the sliding layers take the full layers' tables,
     which a cache of equal pools (``window_pages`` None) seats.
 
-    ``recurrent`` (a model with recurrent layers, ``cfg.layer_group_size``):
+    ``recurrent`` (a model with recurrent layers, ``cfg.recurrent_layers``: KDA
+    layers in periods, or a Mamba-2 mixer beside every layer's attention):
     the two state buffers of ``models/kda.init_state`` and each row's slot
     (each slot's, on the split token axis; 0 is the null slot). The buffers
     come back as the last two outputs, updated: the caller donates them as it
@@ -451,13 +461,13 @@ def forward(
     MLA kernel, a chunk's queries in tiles, ``models/mla.py``). Text models
     without a mesh only.
     """
-    if cfg.layer_group_size and (mesh is not None or logit_indices is not None
+    if cfg.recurrent_layers and (mesh is not None or logit_indices is not None
                                  or not contiguous_positions or mm_embeds is not None):
         raise NotImplementedError(
-            "a model with recurrent (KDA) layers is served on one device, one token after another: "
-            "no mesh, no speculative verify, no image rows")
+            "a model with recurrent layers (KDA, or a Mamba-2 mixer) is served on one device, one token after "
+            "another: no mesh, no speculative verify, no image rows")
     keep_state = recurrent is not None
-    if cfg.layer_group_size and not keep_state:  # a state of the call's own: a slot a row, all zeros
+    if cfg.recurrent_layers and not keep_state:  # a state of the call's own: a slot a row, all zeros
         from dynamo_tpu.models.kda import init_state
 
         rows = block_tables.shape[0]
@@ -476,6 +486,8 @@ def forward(
     x = params["embed"][tokens]  # [B, T, D]
     if cfg.embed_scale:  # Gemma: embeddings scale by sqrt(hidden)
         x = x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
+    if cfg.embed_multiplier != 1.0:  # Falcon-H1's muP
+        x = x * jnp.asarray(cfg.embed_multiplier, x.dtype)
     if mm_embeds is not None and cfg.image_token_id is not None:
         is_img = tokens == jnp.int32(cfg.image_token_id)  # [B, T]
         if cfg.video_token_id is not None:
@@ -615,7 +627,7 @@ def forward(
             of its experts carries their counters beside the stream."""
             with jax.named_scope("mlp"):
                 if not moe_layer:
-                    return _mlp_dense(lp, h2, cfg.mlp_act), counts
+                    return _mlp_dense(lp, h2, cfg.mlp_act, cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier), counts
                 if cfg.moe_held_share:
                     mlp, counted = _mlp_moe_held(lp, h2, cfg, slot_mapping != 0, mesh)
                     return mlp, [counts[0] + counted]
@@ -623,6 +635,7 @@ def forward(
 
         def layer_step(carry, lp):
             x, k_full, v_full, li, *counts = carry
+            counts, rec = counts[:n_counts], counts[n_counts:]  # then a mixer's two state buffers
             kind = None
             if layer_kinds is not None:
                 lp, kind = lp
@@ -649,10 +662,24 @@ def forward(
                 h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
                 mlp, counts = ffn(lp, h2, counts)
                 return (x + mlp, k_full, v_full, li + 1, *counts), None
+            mixed = None
+            if cfg.ssm_heads:
+                # The mixer reads the same normed input as the attention and joins the
+                # stream with it: one more term of the block, its slot beside the layer's pages.
+                from dynamo_tpu.models.mamba2 import mamba_mixer
+
+                with jax.named_scope("attn.ssm"):
+                    mixed, *rec = mamba_mixer(lp, cfg, h, positions, slot_mapping != 0, *rec,
+                                              recurrent[2] + li * ssm_slots, impl=attn_impl, split=split)
+                    mixed = mixed * jnp.asarray(cfg.ssm_out_multiplier, mixed.dtype)
+                if cfg.attn_in_multiplier != 1.0:
+                    h = h * jnp.asarray(cfg.attn_in_multiplier, h.dtype)
             with jax.named_scope("attn"):  # projections, rope, cache write, attention, output
                 qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
                 if cfg.attention_bias:
                     qp, kp, vp = qp + lp["bq"], kp + lp["bk"], vp + lp["bv"]
+                if cfg.key_multiplier != 1.0:
+                    kp = kp * jnp.asarray(cfg.key_multiplier, kp.dtype)
                 if cfg.qk_norm == "flat":  # OLMoE: norm the flat projection
                     qp = rms_norm(qp, lp["q_norm"], eps=cfg.rms_eps)
                     kp = rms_norm(kp, lp["k_norm"], eps=cfg.rms_eps)
@@ -726,11 +753,14 @@ def forward(
                         attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
                                                sliding_window=window,
                                                contiguous_positions=contiguous_positions)
-                x = x + _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
+                attn_out = _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
+                if cfg.attn_out_multiplier != 1.0:
+                    attn_out = attn_out * jnp.asarray(cfg.attn_out_multiplier, attn_out.dtype)
+                x = x + (attn_out if mixed is None else mixed + attn_out)
             h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
             mlp, counts = ffn(lp, h2, counts)
             x = x + mlp
-            return (x, k_full, v_full, li + 1, *counts), None
+            return (x, k_full, v_full, li + 1, *counts, *rec), None
 
         return layer_step
 
@@ -810,10 +840,17 @@ def forward(
     # A model that holds a share of its experts (or has identity experts)
     # carries its expert layers' HELD_COUNTS beside the stream, summed.
     carry = (x, kf0, vf0, jnp.int32(0))
+    n_counts = 0
     if cfg.moe_held_share:
         from dynamo_tpu.parallel.moe import HELD_COUNTS
 
         carry += (jnp.zeros((len(HELD_COUNTS),), jnp.int32),)
+        n_counts = 1
+    if cfg.ssm_heads:  # the plain body with a mixer: its two state buffers ride behind the counters
+        if mla or ring or layer_kinds is not None:
+            raise NotImplementedError("a Mamba-2 mixer is served beside GQA attention of one kind, by the paged path")
+        ssm_slots = recurrent[0].shape[0] // cfg.num_layers
+        carry += tuple(recurrent[:2])
 
     def scanned(layers, lo: int, hi: int):
         if layer_kinds is None:
@@ -836,6 +873,7 @@ def forward(
             carry,
             scanned(moe_layers, n_dense, cfg.num_layers),
         )
+        counts, state_out = counts[:n_counts], counts[n_counts:] if keep_state else ()
     k_out = k_out.reshape(k_cache.shape)
     v_out = v_out.reshape(v_cache.shape)
     extra = ((counts[0] if counts else None,) if moe_counts else ()) + tuple(state_out)
@@ -857,6 +895,8 @@ def forward(
     else:
         last = jnp.take_along_axis(x, last_token_index[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _qmm(last, head, preferred_element_type=jnp.float32)  # [B, vocab]
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
     return (logits, k_out, v_out, *extra)
 
 
@@ -884,7 +924,7 @@ def encode(
     (`lib/llm/src/http/service/openai.rs:580`, `engines.rs:321`).
     """
     b, t = tokens.shape
-    if cfg.mixed_attention or cfg.shortcut_moe or cfg.moe_held_share or cfg.layer_group_size:
+    if cfg.mixed_attention or cfg.shortcut_moe or cfg.moe_held_share or cfg.recurrent_layers:
         raise NotImplementedError("encode() serves models whose layers are all alike (one window, one RoPE), "
                                   "hold one attention block each and all their experts")
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))

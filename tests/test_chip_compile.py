@@ -511,6 +511,7 @@ def _benchmark_config(name: str, layers: int, vocab: int = 8192):
     hf = conf["hf"]
     layers += hf.get("first_k_dense_replace", 0)  # leading dense layers are a scan of their own
     hf.update({"num_layers" if "num_layers" in hf else "num_hidden_layers": layers, "vocab_size": vocab})
+    hf.pop("num_hidden_layers_published", None)  # (a file that states a pipeline stage: the cut is this helper's)
     for per_layer in ("layer_types", "mlp_layer_types"):
         if per_layer in hf:
             hf[per_layer] = hf[per_layer][:layers]
@@ -731,3 +732,82 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     relaid = [(op["name"], op["shape"]) for op in load_tool().relayouts(text) if op["dtype"] == "s8"]
     assert not relaid, relaid
     assert not _up_projection_moves(text, heads=32)  # the MLA layer's w_uk / w_uv are read where they lie
+
+
+# -- a Mamba-2 mixer beside GQA attention in every layer: pages and a slot a layer (ISSUE 46) ------------
+
+def test_mamba_decode_kernel_compiles(sds):
+    """The decode step of the Mamba-2 recurrence at Falcon-H1-34B's widths: 64
+    rows x 32 heads of 256 x 128 in 2 groups over 9 layers x 65 slots, the
+    state aliased to the kernel's output (updated where it lies: no second
+    2.45 GB buffer)."""
+    from dynamo_tpu.ops.pallas_mamba import mamba_decode_step
+
+    f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
+    compiled = jax.jit(mamba_decode_step.__wrapped__, donate_argnums=(0,)).lower(
+        f32(9 * 65, 32, 256, 128), sds((64,), jnp.int32), sds((64,), jnp.bool_),
+        f32(64, 32, 128), f32(64, 2, 256), f32(64, 2, 256), f32(64, 32), f32(32)).compile()
+    assert "mamba_decode_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 9 * 65 * 32 * 256 * 128 * 4 and mem.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
+def test_parallel_mixer_step_falcon_h1_largest_corners(sds, split):
+    """reason-saturated's largest steps at Falcon-H1-34B's widths, two layers
+    and a small vocabulary: 64 decode rows, and 64 decode slots + one 64-token
+    chunk slot, over 16 pages. The mixer is a term of the plain layer body:
+    its decode rows go through ``mamba_decode_step``, the layer's attention
+    (20 query heads over 4 KV heads) through the paged GQA kernels on the
+    layer's own slab; the state buffers come back as the last two outputs and
+    are updated where they lie (aliased, no copy of the state buffer in the
+    step program); no int8 weight is re-laid inside the scan."""
+    from dynamo_tpu.models import kda, llama
+    from tests.test_step_relayouts import load_tool
+
+    cfg = _benchmark_config("falcon-h1-34b-pp8-int8", layers=2)
+    assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, cfg.ssm_heads, cfg.num_heads, cfg.num_kv_heads) == (2, 2, 2, 32, 20, 4)
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = _served_params(sds, cfg)
+    assert params["layers"]["w_ssm_in"].dtype == jnp.bfloat16 and params["layers"]["wq"]["qw"].dtype == jnp.int8
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
+    state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
+    assert state.shape == (2 * 65, 32, 256, 128) and conv.shape == (2 * 65, 3, 5120) and conv.dtype == jnp.bfloat16
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if split is None:
+        toks, slots = (64, 1), 64
+    else:
+        toks, slots = (split[0] + split[1] * split[2],), split[0] + split[1]
+
+    def step(params, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index, state, conv, slot_ids):
+        return llama.forward(params, cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index,
+                             attn_impl="pallas", split=split, recurrent=(state, conv, slot_ids))
+
+    compiled = jax.jit(step, donate_argnums=(3, 4, 8, 9)).lower(
+        params, i32(*toks), i32(*toks), k_cache, v_cache, i32(slots, 16), i32(*toks), i32(slots), state, conv, i32(slots),
+    ).compile()
+    text = compiled.as_text()
+    assert "mamba_decode_step" in text and "kda_decode_step" not in text
+    assert ("paged_prefill_attention" if split else "paged_decode_attention") in text
+    shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
+    assert shapes[-2:] == [state.shape, conv.shape] and len(shapes) == 5  # logits, the caches, the state buffers
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4 + 2 * k_cache.size * 2 and mem.temp_size_in_bytes < 1 << 30
+    ops = load_tool().relayouts(text)
+    assert not [(op["name"], op["shape"]) for op in ops if op["dtype"] == "s8"]
+    assert not [op for op in ops if op["bytes"] >= state.size * 4 // 65]  # nothing the size of a layer's rows of state moves
+
+
+@pytest.mark.parametrize("config, rows", [("olmoe-1b-7b-int8", 48), ("joyai-llm-flash-ep8-int8", 64)])
+def test_a_model_without_a_mixer_compiles_nothing_of_it(sds, monkeypatch, config, rows):
+    """The mixer sits in the plain layer body under a static predicate of the
+    configuration: the decode step of a model without one holds no operation
+    of it, no multiplier and no state buffer (against the parent commit the
+    two texts are equal but for the source lines the kernels embed: PERF.md,
+    PR 46, by ``tools/step_relayouts.py --dump`` on both trees)."""
+    text = _two_layer_step_text(sds, monkeypatch, config, rows, mixed=False)
+    assert "attn.ssm" not in text and "mamba_decode_step" not in text and "softplus" not in text
+    cfg = _benchmark_config(config, layers=2)
+    assert not cfg.ssm_heads and not cfg.recurrent_layers
+    assert {cfg.embed_multiplier, cfg.lm_head_multiplier, cfg.attn_in_multiplier, cfg.attn_out_multiplier, cfg.key_multiplier,
+            cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier} == {1.0}

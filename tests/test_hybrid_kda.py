@@ -10,7 +10,13 @@ form is the decode step taken 64 times; rows that swap places keep their
 states; a preempted sequence's second run equals its first; the eight shares
 add up to the uncut layer with the groups on; and a model with recurrent
 layers never matches a prefix and is refused by page transfer and the KV
-router."""
+router.
+
+Since ISSUE 46 the slot tests run over **both recurrent kinds** (``KINDS``):
+the KDA hybrid, whose recurrent layers hold a slot *instead of* pages, and a
+model with a Mamba-2 mixer beside the GQA attention of every layer
+(Falcon-H1's ``falcon_h1``, ``models/mamba2.py``), whose every layer holds a
+slot *and* pages; the predicate they share is ``cfg.recurrent_layers``."""
 
 import dataclasses
 import functools
@@ -26,13 +32,15 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
+from benchmark.reference import falcon_h1 as ref_h1  # noqa: E402
 from benchmark.reference import ling_3_flash as ref  # noqa: E402
 from dynamo_tpu.engine.allocator import SlotAllocator  # noqa: E402
 from dynamo_tpu.engine.core import EngineConfig, EngineCore  # noqa: E402
 from dynamo_tpu.engine.runner import ROWS_X_T, SPLIT, ModelRunner, StepBatch  # noqa: E402
 from dynamo_tpu.engine.sequence import SeqStatus  # noqa: E402
-from dynamo_tpu.models import kda, llama  # noqa: E402
-from dynamo_tpu.models.config import LING_3_FLASH_HF, PRESETS, TINY_HYBRID_HF, ModelConfig  # noqa: E402
+from dynamo_tpu.models import kda, llama, mamba2  # noqa: E402
+from dynamo_tpu.models.config import (  # noqa: E402
+    LING_3_FLASH_HF, PRESETS, TINY_FALCON_H1_HF, TINY_HYBRID_HF, ModelConfig)
 from dynamo_tpu.protocols.common import PreprocessedRequest, SamplingOptions, StopConditions  # noqa: E402
 from dynamo_tpu.runtime.engine import Context  # noqa: E402
 from tests.test_mixed_attention import _distance  # noqa: E402  (max |served - reference| logprob over the largest |logit|)
@@ -62,8 +70,46 @@ def _weights(cfg, seed=2**31 + 40, bias=0.05):
     return params
 
 
-def _reference(params, sequence, hf=TINY_HYBRID_HF):
-    return np.asarray(jax.jit(functools.partial(ref.forward, hf=hf))(params, tokens=jnp.asarray(sequence)))
+def _toy_h1(**edit) -> ModelConfig:
+    return dataclasses.replace(ModelConfig.from_hf({**TINY_FALCON_H1_HF, **edit}, name="toy-h1"), dtype="float32")
+
+
+def _weights_h1(cfg, seed=2**31 + 46):
+    """The benchmark's weights (plain float32) with the mixer's constants made
+    live: in half the heads of every layer a small step (dt about 0.01-0.05
+    against A = -1: a decay of 0.95-0.99 a token, so a token is still felt
+    chunks later), a skip weight D and a conv bias that are not 1 and 0."""
+    from benchmark import weights
+
+    params = weights.make_weights(cfg, seed, quant="")
+    layers, keys = params["layers"], jax.random.split(jax.random.PRNGKey(seed % 2**31), 3)
+    shape = layers["ssm_dt_bias"].shape  # [layers, heads]
+    slow = jnp.arange(shape[1])[None, :] < shape[1] // 2
+    layers["ssm_dt_bias"] = jnp.where(slow, jax.random.uniform(keys[0], shape, jnp.float32, -4.6, -3.0), 0.0)
+    layers["ssm_a_log"] = jnp.zeros(shape, jnp.float32)
+    layers["ssm_d"] = jax.random.normal(keys[1], shape, jnp.float32)
+    layers["ssm_conv_bias"] = 0.1 * jax.random.normal(keys[2], layers["ssm_conv_bias"].shape, jnp.float32)
+    return params
+
+
+#: The two recurrent kinds: how a toy of each is made, its plain reference,
+#: and the layer function whose slot handling the tests break.
+KINDS = {
+    "kda": dict(toy=_toy, weights=_weights, hf=TINY_HYBRID_HF, ref=ref, module=kda, layer="kda_attention"),
+    "mamba2": dict(toy=_toy_h1, weights=_weights_h1, hf=TINY_FALCON_H1_HF, ref=ref_h1, module=mamba2, layer="mamba_mixer"),
+}
+both_kinds = pytest.mark.parametrize("kind", sorted(KINDS))
+
+
+def _reference(params, sequence, hf=TINY_HYBRID_HF, kind="kda"):
+    return np.asarray(jax.jit(functools.partial(KINDS[kind]["ref"].forward, hf=hf))(params, tokens=jnp.asarray(sequence)))
+
+
+def _model(kind):
+    k = KINDS[kind]
+    cfg = k["toy"]()
+    params = k["weights"](cfg)
+    return cfg, params, functools.partial(_reference, params, hf=k["hf"], kind=kind)
 
 
 # -- from_hf --------------------------------------------------------------------------
@@ -121,13 +167,14 @@ def test_from_hf_refuses_by_name(edit, says):
 # -- (a) the engine against the plain reference ----------------------------------------
 
 
-@pytest.fixture(scope="module", params=[True, False], ids=["split", "rectangle"])
+@pytest.fixture(scope="module", params=[(k, s) for k in sorted(KINDS) for s in (True, False)],
+                ids=lambda p: f"{p[0]}-{'split' if p[1] else 'rectangle'}")
 def served(request):
-    cfg = _toy()
-    params = _weights(cfg)
+    kind, split = request.param
+    cfg, params, reference = _model(kind)
     prompt = np.random.default_rng(5).integers(1, cfg.vocab_size, size=40).tolist()
-    entries, core = _served_logprobs(cfg, params, prompt, 8, chunk=12, split=request.param)
-    return cfg, params, prompt, entries, core, request.param
+    entries, core = _served_logprobs(cfg, params, prompt, 8, chunk=12, split=split)
+    return cfg, reference, prompt, entries, core, split
 
 
 def test_engine_chunked_prefill_and_decode_agree_with_the_reference(served):
@@ -139,39 +186,44 @@ def test_engine_chunked_prefill_and_decode_agree_with_the_reference(served):
     pass (the recurrence token by token over one sequence). Both sides
     float32 at ``highest`` matmul precision (conftest): what is left is the
     order of accumulation (the chunkwise form against token by token), about
-    3e-6 of the logit range, so the tolerance is 1e-4."""
-    cfg, params, prompt, entries, core, split = served
+    3e-6 of the logit range, so the tolerance is 1e-4. The same for the model
+    with a mixer beside GQA attention in every layer (its chunked form against
+    the reference's scan over tokens, pages and slots in every layer)."""
+    cfg, reference, prompt, entries, core, split = served
     sequence = prompt + [e["id"] for e in entries][:-1]
-    assert len(entries) == 8 and _distance(entries, prompt, _reference(params, sequence)) < TOL
+    assert len(entries) == 8 and _distance(entries, prompt, reference(sequence)) < TOL
     steps = core.flight.snapshot(kind="step")
     assert {"mixed", "decode"} <= {s["step_kind"] for s in steps}
     assert {s["layout"] for s in steps if s["step_kind"] == "mixed"} == ({SPLIT} if split else {ROWS_X_T})
     assert max(s["state_rows"] for s in steps) == 2 and max(s["state_slots_live"] for s in steps) == 2
     assert all(s["state_rows"] == s["decode_rows"] + s["chunk_rows"] for s in steps if s["layout"])
-    assert sum(s["moe_choices"] for s in steps) > 0 and core.runner.recurrent and not core.prefix_matching
+    assert (sum(s["moe_choices"] for s in steps) > 0) == cfg.is_moe and core.runner.recurrent and not core.prefix_matching
+    if cfg.ssm_heads:  # every layer attends: the one kind of GQA layer's key tokens are counted
+        assert all(s["kv_tokens_full"] > 0 for s in steps if s["layout"])
 
 
-def _no_carry(lp, cfg, h, positions, valid, state, conv, slot_ids, **kw):
+def _no_carry(layer, lp, cfg, h, positions, valid, state, conv, slot_ids, **kw):
     """Every chunk starts from zeros: the state is not carried across a chunk edge."""
-    return _KDA(lp, cfg, h, jnp.zeros_like(positions), valid, state, conv, slot_ids, **kw)
+    return layer(lp, cfg, h, jnp.zeros_like(positions), valid, state, conv, slot_ids, **kw)
 
 
-def _no_zeroing(lp, cfg, h, positions, valid, state, conv, slot_ids, **kw):
+def _no_zeroing(layer, lp, cfg, h, positions, valid, state, conv, slot_ids, **kw):
     """No row is ever fresh: a taken slot keeps what the sequence before left in it."""
-    return _KDA(lp, cfg, h, positions + 1, valid, state, conv, slot_ids, **kw)
+    return layer(lp, cfg, h, positions + 1, valid, state, conv, slot_ids, **kw)
 
 
-def _by_row(lp, cfg, h, positions, valid, state, conv, slot_ids, **kw):
+def _by_row(layer, lp, cfg, h, positions, valid, state, conv, slot_ids, **kw):
     """The state of a row is looked up by its place in the step, not by its slot."""
     base = slot_ids - slot_ids % _SLOTS
-    return _KDA(lp, cfg, h, positions, valid, state, conv, base + 1 + jnp.arange(slot_ids.shape[0]) % (_SLOTS - 1), **kw)
+    return layer(lp, cfg, h, positions, valid, state, conv, base + 1 + jnp.arange(slot_ids.shape[0]) % (_SLOTS - 1), **kw)
 
 
-_KDA, _SLOTS = kda.kda_attention, 3  # max_batch_size 2 and the null slot
+_SLOTS = 3  # max_batch_size 2 and the null slot
 
 
+@both_kinds
 @pytest.mark.parametrize("broken", [_no_carry, _no_zeroing, _by_row], ids=lambda f: f.__name__.strip("_"))
-def test_a_program_made_wrong_is_far_from_the_reference(broken, monkeypatch):
+def test_a_program_made_wrong_is_far_from_the_reference(broken, kind, monkeypatch):
     """The same run with the layer broken in one of the three ways the slots
     are easy to get wrong, each more than a hundred times the tolerance off:
     the carry across a chunk edge, the zeroing of a slot that is taken over
@@ -179,13 +231,13 @@ def test_a_program_made_wrong_is_far_from_the_reference(broken, monkeypatch):
     the slot the checked prompt is given), the slot indirection (the checked
     row is the step's second row in the mixed steps and its first once the
     other has finished)."""
-    monkeypatch.setattr(kda, "kda_attention", broken)
-    cfg = _toy()
-    params = _weights(cfg)
+    module, name = KINDS[kind]["module"], KINDS[kind]["layer"]
+    monkeypatch.setattr(module, name, functools.partial(broken, getattr(module, name)))
+    cfg, params, reference = _model(kind)
     prompt = np.random.default_rng(5).integers(1, cfg.vocab_size, size=40).tolist()
     entries = _run_after_another(cfg, params, prompt)
     sequence = prompt + [e["id"] for e in entries][:-1]
-    assert _distance(entries, prompt, _reference(params, sequence)) > 100 * TOL
+    assert _distance(entries, prompt, reference(sequence)) > 100 * TOL
 
 
 def _run_after_another(cfg, params, prompt, n_out=8, chunk=12):
@@ -218,14 +270,15 @@ def _run_after_another(cfg, params, prompt, n_out=8, chunk=12):
     return entries
 
 
-def test_the_run_after_another_is_sound_unbroken():
-    """The control of the test above: the same run, nothing broken, agrees."""
-    cfg = _toy()
-    params = _weights(cfg)
+@both_kinds
+def test_the_run_after_another_is_sound_unbroken(kind):
+    """The control of the test above: the same run, nothing broken, agrees
+    (a row that joins a running batch, in a slot another sequence has left)."""
+    cfg, params, reference = _model(kind)
     prompt = np.random.default_rng(5).integers(1, cfg.vocab_size, size=40).tolist()
     entries = _run_after_another(cfg, params, prompt)
     sequence = prompt + [e["id"] for e in entries][:-1]
-    assert _distance(entries, prompt, _reference(params, sequence)) < TOL
+    assert _distance(entries, prompt, reference(sequence)) < TOL
 
 
 # -- (b) the chunk step is the decode step, 64 times -----------------------------------------
@@ -268,13 +321,13 @@ def _null_batch(b, t, n):
     return null_batch(b, t, n)
 
 
-def test_two_sequences_that_swap_rows_keep_their_states():
+@both_kinds
+def test_two_sequences_that_swap_rows_keep_their_states(kind):
     """Two sequences decode side by side through the runner by hand; from one
     step to the next they swap rows. With their slot ids they read the same
     tokens as when each keeps its row; a step built like the benchmark's null
     batch (no slot ids: every row the null slot) touches neither."""
-    cfg = _toy()
-    params = _weights(cfg)
+    cfg, params, _ = _model(kind)
 
     def run(swap: bool):
         runner = ModelRunner(cfg, params, num_pages=16, page_size=8, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
@@ -303,6 +356,27 @@ def test_two_sequences_that_swap_rows_keep_their_states():
     assert np.abs(np.stack(plain[1]) - np.stack(plain[2])).max() > 1e-2  # two sequences, two states
 
 
+@both_kinds
+def test_slots_and_pages_are_sized_from_the_model(kind):
+    """The runner's buffers take their shapes from the model: a slab of pages
+    for every layer that attends (2 of the hybrid's 6; all 3 of the model with
+    a mixer), a slot's part for every recurrent layer (4 of 6; all 3), the
+    state float32 in the kind's own shape, and a slot's bytes as
+    ``state_bytes_per_slot`` says."""
+    cfg, params, _ = _model(kind)
+    runner = ModelRunner(cfg, params, num_pages=8, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
+    want = {"kda": (6, 4, 2, (4, 16, 16), (3, 3 * 64)), "mamba2": (3, 3, 3, (4, 8, 16), (3, 64 + 2 * 2 * 8))}[kind]
+    state, conv = runner.state
+    assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, *cfg.state_shapes()) == want
+    assert runner.recurrent and runner.state_slots == 3 and runner.k_cache.shape[0] == cfg.cache_layers
+    assert state.shape == (cfg.recurrent_layers * 3, *want[3]) and state.dtype == jnp.float32
+    assert conv.shape == (cfg.recurrent_layers * 3, *want[4]) and conv.dtype == jnp.float32  # the toy's dtype
+    assert cfg.state_bytes_per_slot() == (state.nbytes + conv.nbytes) // 3
+    assert (kda.init_state(cfg, 3)[0].shape, kda.init_state(cfg, 3)[1].shape) == (state.shape, conv.shape)
+    assert not ModelRunner(PRESETS["test-tiny"], llama.init_params(PRESETS["test-tiny"], 0), num_pages=8, page_size=4,
+                           max_batch_size=2, prefill_bucket=4, attn_impl="reference").recurrent
+
+
 def test_slot_allocator_and_the_step_batchs_default():
     slots = SlotAllocator(4)
     assert (slots.total, slots.live) == (3, 0)
@@ -329,13 +403,13 @@ def _request(tokens, n):
                                stop=StopConditions(max_tokens=n, ignore_eos=True))
 
 
-def test_a_preempted_sequences_second_run_equals_its_first():
+@both_kinds
+def test_a_preempted_sequences_second_run_equals_its_first(kind):
     """Two sequences in a pool too small for both to finish: the later one is
     preempted (its slot goes back), waits, and starts again from its tokens in
     whatever slot it is given; what it emits in all equals what it emits alone
     in a pool that holds it."""
-    cfg = _toy()
-    params = _weights(cfg)
+    cfg, params, _ = _model(kind)
 
     def run(num_pages, prompts):
         runner = ModelRunner(cfg, params, num_pages=num_pages, page_size=4, max_batch_size=2, prefill_bucket=4,
@@ -366,15 +440,16 @@ def test_a_preempted_sequences_second_run_equals_its_first():
 # -- prefix matching, page transfer, the KV router ------------------------------------------------
 
 
-def test_a_model_with_recurrent_layers_never_matches_a_prefix(caplog, monkeypatch):
+@both_kinds
+def test_a_model_with_recurrent_layers_never_matches_a_prefix(kind, caplog, monkeypatch):
     """``enable_prefix_caching`` left on: the engine says once that matching
     is off, never calls ``match_prefix``, still commits pages (KV events go
     out), and a second request with the first one's prompt computes it all."""
     import logging
 
-    cfg = _toy()
+    cfg, params, _ = _model(kind)
     events = []
-    runner = ModelRunner(cfg, _weights(cfg), num_pages=64, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
+    runner = ModelRunner(cfg, params, num_pages=64, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
     with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.core"):
         core = EngineCore(runner, EngineConfig(num_pages=64, page_size=4, max_batch_size=2, max_prefill_tokens=16,
                                                chunk_prefill_tokens=16, max_seq_len=64, enable_prefix_caching=True),
@@ -396,15 +471,17 @@ def test_a_model_with_recurrent_layers_never_matches_a_prefix(caplog, monkeypatc
     assert core.allocator.stats().hits == 0
 
 
-def test_page_transfer_and_the_kv_router_refuse_the_model_by_name():
+@both_kinds
+def test_page_transfer_and_the_kv_router_refuse_the_model_by_name(kind):
     import asyncio
 
     from dynamo_tpu.disagg.transfer import KvTransferService
 
-    cfg = _toy()
-    runner = ModelRunner(cfg, _weights(cfg), num_pages=16, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
+    cfg, params, _ = _model(kind)
+    name = cfg.name
+    runner = ModelRunner(cfg, params, num_pages=16, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
     core = EngineCore(runner, EngineConfig(num_pages=16, page_size=4, max_batch_size=2, max_seq_len=32))
-    with pytest.raises(NotImplementedError, match="toy-hybrid: the KV transfer service is not served for a model with recurrent layers"):
+    with pytest.raises(NotImplementedError, match=f"{name}: the KV transfer service is not served for a model with recurrent layers"):
         KvTransferService(core)
     from dynamo_tpu.disagg.prefill_worker import PrefillWorker
 
@@ -413,11 +490,13 @@ def test_page_transfer_and_the_kv_router_refuse_the_model_by_name():
 
     service = _Service()
     service.core = core
-    with pytest.raises(NotImplementedError, match="toy-hybrid: a prefill worker .* is not served for a model with recurrent layers"):
+    with pytest.raises(NotImplementedError, match=f"{name}: a prefill worker .* is not served for a model with recurrent layers"):
         PrefillWorker(None, service)
     with pytest.raises(ValueError, match="spec_k 2 / decode_steps 1 are not served for a model with recurrent layers"):
         EngineCore(runner, EngineConfig(num_pages=16, page_size=4, max_batch_size=2, max_seq_len=32, spec_k=2))
-    with pytest.raises(NotImplementedError, match="toy-hybrid: speculative verify is not served"):
+    with pytest.raises(ValueError, match="spec_k 0 / decode_steps 2 are not served for a model with recurrent layers"):
+        EngineCore(runner, EngineConfig(num_pages=16, page_size=4, max_batch_size=2, max_seq_len=32, decode_steps=2))
+    with pytest.raises(NotImplementedError, match=f"{name}: speculative verify is not served"):
         runner.spec_step(_null_batch(2, 1, 1), 3)
 
     async def kv_routed():
@@ -425,12 +504,12 @@ def test_page_transfer_and_the_kv_router_refuse_the_model_by_name():
         from dynamo_tpu.model_card import ModelDeploymentCard
         from dynamo_tpu.runtime.component import DistributedRuntime
 
-        card = ModelDeploymentCard(name="toy-hybrid", tokenizer="byte", context_length=32, kv_page_size=4, router_mode="kv")
-        spec = launch.WorkerSpec(model_config=cfg, card=card, params=_weights(cfg),
+        card = ModelDeploymentCard(name=name, tokenizer="byte", context_length=32, kv_page_size=4, router_mode="kv")
+        spec = launch.WorkerSpec(model_config=cfg, card=card, params=params,
                                  engine_config=EngineConfig(num_pages=16, page_size=4, max_batch_size=2, max_seq_len=32))
         await launch.serve_worker(DistributedRuntime.detached(), spec)
 
-    with pytest.raises(ValueError, match="toy-hybrid: router_mode 'kv' is not served for a model with recurrent layers"):
+    with pytest.raises(ValueError, match=f"{name}: router_mode 'kv' is not served for a model with recurrent layers"):
         asyncio.run(kv_routed())
 
 

@@ -1,0 +1,148 @@
+"""The Mamba-2 decode kernel (``ops/pallas_mamba.py``) in interpret mode
+against the plain ``jax.numpy`` step (``models/mamba2.recurrent_step``): slots
+read through their ids, a fresh row read as zeros, the null slot, the state
+written back in place and no other slot touched, a block of heads reading its
+own group's B and C; and the chunked form (``models/mamba2.chunk_step``) over
+any split of a sequence into chunks, the last one padded, against the
+recurrence token by token."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.models import kda, mamba2
+from dynamo_tpu.models.config import PRESETS
+from dynamo_tpu.ops import pallas_mamba
+
+
+def _case(seed, rows, heads, groups, n, p, slots):
+    rng = np.random.default_rng(seed)
+    f = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    return dict(
+        state=f(rng.normal(size=(slots, heads, n, p))), x=f(rng.normal(size=(rows, heads, p))),
+        b=f(rng.normal(size=(rows, groups, n))), c=f(rng.normal(size=(rows, groups, n))),
+        dt=f(rng.uniform(0.0, 0.7, size=(rows, heads)) ** 2), a=f(-rng.uniform(0.3, 3.0, size=heads)))
+
+
+def _plain(case, ids, fresh):
+    rows, heads, p = case["x"].shape
+    groups, n = case["b"].shape[1:]
+    hg = heads // groups
+    s_in = jnp.where(fresh[:, None, None, None], 0.0, case["state"][ids]).reshape(rows, groups, hg, n, p)
+    y, s = mamba2.recurrent_step(s_in, case["x"].reshape(rows, groups, hg, p), case["b"], case["c"],
+                                 case["dt"].reshape(rows, groups, hg), case["a"].reshape(groups, hg))
+    return y.reshape(rows, heads, p), s.reshape(rows, heads, n, p)
+
+
+@pytest.mark.parametrize("rows, heads, groups, n, p, per_block", [
+    (3, 4, 2, 8, 128, 8),  # a block a group (2 heads), the toy's state
+    (4, 32, 2, 256, 128, 8),  # the published mixer: two blocks of 8 heads a group of 16
+    (2, 12, 2, 16, 128, 4),  # 6 heads a group, which 4 does not divide: the largest divisor within it (3)
+    (3, 8, 1, 8, 16, 8),  # one group; channels narrower than a lane tile (the interpreter tiles nothing)
+], ids=["toy", "published", "odd-heads", "one-group"])
+def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, groups, n, p, per_block):
+    monkeypatch.setattr(pallas_mamba, "HEADS_PER_BLOCK", per_block)
+    slots = rows + 3
+    c = _case(rows, rows, heads, groups, n, p, slots)
+    ids = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, slots))[:rows], jnp.int32)
+    fresh = jnp.asarray(np.arange(rows) % 2 == 1)
+    before = np.asarray(c["state"])
+    y_want, s_want = _plain(c, ids, fresh)
+    y_got, state = pallas_mamba.mamba_decode_step(c["state"], ids, fresh, c["x"], c["b"], c["c"], c["dt"], c["a"], interpret=True)
+    np.testing.assert_allclose(y_got, y_want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(state)[np.asarray(ids)], s_want, rtol=1e-6, atol=2e-6)
+    others = [i for i in range(slots) if i not in set(np.asarray(ids).tolist())]
+    assert np.array_equal(np.asarray(state)[others], before[others])  # bit for bit: never read, never written
+
+
+def test_padding_rows_share_the_null_slot_and_leave_the_live_slots_alone():
+    """What ``mamba_mixer`` hands over for padding rows: slot 0, ``dt = 0`` (no
+    decay, no write), and ``fresh`` (their position is 0): the null slot reads
+    as zeros and is written as zeros, whatever several rows do to it at once;
+    a live row with ``dt = 0`` leaves its slot as it was, bit for bit."""
+    c = _case(7, 4, 4, 2, 8, 128, 5)
+    ids, fresh = jnp.asarray([0, 3, 0, 2], jnp.int32), jnp.asarray([True, False, True, False])
+    dt = c["dt"].at[jnp.asarray([0, 2, 3])].set(0.0)
+    before = np.asarray(c["state"])
+    y, state = pallas_mamba.mamba_decode_step(c["state"], ids, fresh, c["x"], c["b"], c["c"], dt, c["a"], interpret=True)
+    after = np.asarray(state)
+    assert np.array_equal(after[[1, 2, 4]], before[[1, 2, 4]]) and not after[0].any() and not np.asarray(y)[[0, 2]].any()
+    y_want, s_want = _plain({**c, "dt": dt}, ids, fresh)
+    np.testing.assert_allclose(after[3], s_want[1], atol=2e-6)
+    np.testing.assert_allclose(y, y_want, atol=1e-5)
+
+
+def test_supported_shapes(monkeypatch):
+    monkeypatch.setattr(pallas_mamba, "interpret_mode", lambda: False)
+    assert pallas_mamba.supported(256, 128) and pallas_mamba.supported(8, 128)
+    assert not pallas_mamba.supported(8, 16) and not pallas_mamba.supported(12, 128)
+    assert pallas_mamba._heads_block(16) == 8 and pallas_mamba._heads_block(6) == 6 and pallas_mamba._heads_block(12) == 6
+
+
+@pytest.mark.parametrize("cuts", [(64,), (13, 40, 11), (1, 1, 62), (7,) * 9 + (1,)], ids=["whole", "ragged", "ones-first", "sevens"])
+def test_chunk_steps_over_any_split_are_the_recurrence_token_by_token(cuts):
+    """64 tokens from a carried state, cut into chunks and each chunk padded
+    to 16 more tokens with ``dt = 0`` (what ``mamba_mixer`` makes of padding):
+    outputs and the last state against ``recurrent_step`` 64 times. Heads
+    that forget within a few tokens beside heads that hardly forget."""
+    rng = np.random.default_rng(3)
+    t, g, r, n, p = 64, 2, 3, 8, 16
+    f = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    x, b, c = f(rng.normal(size=(t, g, r, p))), f(rng.normal(size=(t, g, n))), f(rng.normal(size=(t, g, n)))
+    dt = f(rng.uniform(0.001, 1.5, size=(t, g, r)) * np.asarray([1.0, 0.01, 3.0])[None, None, :])
+    a, s0 = f(-rng.uniform(0.5, 16.0, size=(g, r))), f(rng.normal(size=(g, r, n, p)))
+    s, want = s0, []
+    for i in range(t):
+        y, s = mamba2.recurrent_step(s, x[i], b[i], c[i], dt[i], a)
+        want.append(y)
+    got, carried, lo = [], s0, 0
+    for width in cuts:
+        pad = lambda z: jnp.concatenate([z[lo: lo + width], jnp.ones((16, *z.shape[1:]), z.dtype)])  # noqa: E731,B023
+        dt_c = jnp.concatenate([dt[lo: lo + width], jnp.zeros((16, g, r))])
+        y, carried = mamba2.chunk_step(carried, pad(x), pad(b), pad(c), dt_c, a)
+        got.append(y[:width])
+        lo += width
+    assert lo == t
+    want = jnp.stack(want)  # sums that cancel: the error is float32's at the largest output, not at each
+    np.testing.assert_allclose(jnp.concatenate(got), want, atol=2e-5 * float(jnp.abs(want).max()))
+    np.testing.assert_allclose(carried, s, atol=2e-5 * float(jnp.abs(s).max()))
+    # The fastest decay there is (dt A = -48 a token) neither overflows nor divides by zero.
+    y, s_fast = mamba2.chunk_step(s0, x, b, c, jnp.full_like(dt, 3.0), jnp.full_like(a, -16.0))
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(s_fast)).all()
+
+
+def test_the_mixer_takes_the_kernel_where_the_platform_runs_it(monkeypatch):
+    """``mamba_mixer`` with ``impl="pallas"`` under the interpreter against
+    the ``jax.numpy`` step, through slots (a live row, a fresh row, a padding
+    row), at a head width the kernel tiles; then the same rows on the split
+    token axis beside a chunk slot."""
+    monkeypatch.setenv("DYNAMO_PALLAS_INTERPRET", "1")
+    cfg = dataclasses.replace(PRESETS["test-tiny-falcon-h1"], ssm_heads=2, ssm_head_dim=128)
+    lp = jax.tree.map(lambda x: x[0], mamba2.init_mamba_params(cfg, jax.random.PRNGKey(0), jnp.float32, 1))
+    lp["ssm_dt_bias"] = jnp.asarray([-2.0, 0.5])
+    h = jax.random.normal(jax.random.PRNGKey(1), (3, 1, 64), jnp.float32)
+    state, conv = kda.init_state(cfg, 5, dtype=jnp.float32)
+    state = state + jax.random.normal(jax.random.PRNGKey(2), state.shape)
+    conv = conv + jax.random.normal(jax.random.PRNGKey(3), conv.shape)
+    args = dict(positions=jnp.asarray([[9], [0], [0]]), valid=jnp.asarray([[True], [True], [False]]), slot_ids=jnp.asarray([4, 2, 0]))
+    before = np.asarray(state)
+    want = mamba2.mamba_mixer(lp, cfg, h, state=state, conv=conv, impl="reference", **args)
+    got = mamba2.mamba_mixer(lp, cfg, h, state=jnp.array(before), conv=conv, impl="pallas", **args)  # the kernel donates it
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    assert np.array_equal(np.asarray(got[1])[[1, 3]], before[[1, 3]])  # slots no row names
+    # One token axis: the three decode slots, then a chunk slot of 4 tokens (its last a padding token) in slot 1.
+    chunk = jax.random.normal(jax.random.PRNGKey(4), (1, 4, 64), jnp.float32)
+    flat = dict(positions=jnp.asarray([[9, 0, 0, 5, 6, 7, 0]]), valid=jnp.asarray([[True, True, False, True, True, True, False]]),
+                slot_ids=jnp.asarray([4, 2, 0, 1]), split=(3, 1, 4))
+    out, state2, conv2 = mamba2.mamba_mixer(lp, cfg, jnp.concatenate([h.reshape(1, 3, 64), chunk], axis=1),
+                                            state=jnp.array(before), conv=conv, impl="pallas", **flat)
+    np.testing.assert_allclose(out[0, :3], want[0][:, 0], atol=1e-5)
+    alone = mamba2.mamba_mixer(lp, cfg, chunk, state=state, conv=conv, impl="reference", positions=jnp.asarray([[5, 6, 7, 0]]),
+                               valid=jnp.asarray([[True, True, True, False]]), slot_ids=jnp.asarray([1]))
+    np.testing.assert_allclose(out[0, 3:6], alone[0][0, :3], atol=1e-5)
+    np.testing.assert_allclose(state2[1], alone[1][1], atol=1e-5)
+    np.testing.assert_allclose(conv2[1], alone[2][1], atol=1e-6)

@@ -81,7 +81,7 @@ def child(config: str, rows: list[int]) -> int:
     for r in rows:
         for label, split, toks, slots in (("decode", None, (r, 1), r), ("mixed", (r, 1, chunk), (r + chunk,), r + 1)):
             kept = {}
-            if mc.layer_group_size:
+            if mc.recurrent_layers:
                 kept["recurrent"] = (*like(jax.eval_shape(lambda: kda.init_state(mc, eng["max_batch_size"] + 1))), i32(slots))
             if window_pages is not None:
                 kept.update(window_tables=i32(slots, pages_per_row), window_slots=i32(*toks))

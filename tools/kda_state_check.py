@@ -1,7 +1,11 @@
 #!/usr/bin/env python3
-"""One KDA layer alone, with a slow decay: the served layer's chunk steps and
-decode steps through slots against the plain reference's layer, at the
-configuration's published widths.
+"""One recurrent layer alone, with a slow decay: the served layer's chunk steps
+and decode steps through slots against the plain reference's layer, at the
+configuration's published widths. A KDA layer (Ling's cell, the default) or,
+since PR 46, the Mamba-2 mixer of a layer that runs one beside its attention
+(``--workload falcon-h1-34b-pp8-int8.reason-saturated``: ``models/mamba2.mamba_mixer``
+against the reference's ``mixer``, half the heads' step size cut so that they
+decay by 0.97-0.993 a token).
 
     python3 tools/kda_state_check.py [--workload <cell>] --seeds 3 --rows 3 --prompt 192 --decode 8
 
@@ -69,28 +73,48 @@ def slow_decay(lp: dict, cfg, seed: int) -> dict:
             "dt_bias": bias.astype(lp["dt_bias"].dtype), "a_log": jnp.zeros_like(lp["a_log"])}
 
 
+def slow_steps(lp: dict, cfg, seed: int) -> dict:
+    """A mixer's leaves with half the heads forgetting slowly: their ``dt_bias`` in [-6, -3.5] (a step of
+    0.003-0.03 against A = -1: a decay of 0.97-0.997 a token), and a skip weight ``D`` of 1 in every head."""
+    import jax
+    import jax.numpy as jnp
+
+    heads = cfg.ssm_heads
+    bias = jax.random.uniform(jax.random.PRNGKey(seed % 2**31), (heads,), jnp.float32, -6.0, -3.5)
+    bias = jnp.where(jnp.arange(heads) < heads // 2, bias, 0.0)
+    return {**lp, "ssm_dt_bias": bias.astype(lp["ssm_dt_bias"].dtype), "ssm_a_log": jnp.zeros_like(lp["ssm_a_log"]),
+            "ssm_d": jnp.ones_like(lp["ssm_d"])}
+
+
 def check(conf: dict, seed: int, rows: int, prompt: int, decode: int, chunk: int) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from benchmark import serving, weights
-    from dynamo_tpu.models import kda
+    from dynamo_tpu.models import kda, mamba2
 
     ref = importlib.import_module(f"benchmark.reference.{conf['reference']}")
     cfg = serving.model_config(conf)
-    one = dataclasses.replace(cfg, num_layers=cfg.layer_group_size, vocab_size=256)  # one period: its KDA layers' leaves
-    lp = jax.tree.map(lambda x: x[0], weights.make_weights(one, seed, quant=conf["serve"]["quant"])["kda_layers"])
-    lp = slow_decay(lp, cfg, seed)
+    if cfg.ssm_heads:  # a mixer beside the attention of every layer: one layer's leaves
+        one = dataclasses.replace(cfg, num_layers=1, vocab_size=256)
+        lp = slow_steps(jax.tree.map(lambda x: x[0], weights.make_weights(one, seed, quant=conf["serve"]["quant"])["layers"]), cfg, seed)
+        served_layer, ref_layer = mamba2.mamba_mixer, ref.mixer
+    else:
+        one = dataclasses.replace(cfg, num_layers=cfg.layer_group_size, vocab_size=256)  # one period: its KDA layers' leaves
+        lp = jax.tree.map(lambda x: x[0], weights.make_weights(one, seed, quant=conf["serve"]["quant"])["kda_layers"])
+        lp = slow_decay(lp, cfg, seed)
+        served_layer, ref_layer = kda.kda_attention, ref.kda
     dt = jnp.dtype(cfg.dtype)
     total = prompt + decode
     h = jax.random.normal(jax.random.PRNGKey((seed + 1) % 2**31), (rows, total, cfg.hidden_size), jnp.float32).astype(dt)
     z = ref.shape_of(conf["hf"])
     with jax.default_matmul_precision("highest"):
-        want = np.stack([np.asarray(jax.jit(functools.partial(ref.kda, z=z))(h[r].astype(jnp.float32), lp)) for r in range(rows)])
+        want = np.stack([np.asarray(jax.jit(functools.partial(ref_layer, z=z))(h[r].astype(jnp.float32), lp)) for r in range(rows)])
     slots = rows + 2
     slot_of = np.arange(rows, 0, -1, dtype=np.int32) + 1  # row r in slot rows + 1 - r: never its row number
-    layer = jax.jit(functools.partial(kda.kda_attention, cfg=cfg), donate_argnames=("state", "conv"))
+    layer = jax.jit(lambda lp, h, positions, valid, state, conv, slot_ids: served_layer(
+        lp, cfg, h, positions, valid, state, conv, slot_ids), donate_argnames=("state", "conv"))
 
     def served(*, carry=True, start=0, by_row=False):
         state, conv = kda.init_state(one, slots)
@@ -148,7 +172,7 @@ def main() -> int:
     rows = []
     for i in range(args.seeds):
         rows.append(check(conf, args.first_seed + 7919 * i, args.rows, args.prompt // chunk * chunk, args.decode, chunk))
-        print(json.dumps({"kda_state_check": rows[-1]}), flush=True)
+        print(json.dumps({"kda_state_check": rows[-1], "workload": args.workload}), flush=True)
     keys = [k for k in rows[0] if isinstance(rows[0][k], float) and k != "reference_absmax"]
     summary = {k: [min(r[k] for r in rows), max(r[k] for r in rows)] for k in keys}
     ok = (all(summary[k][1] < LIMIT for k in ("chunks", "decodes"))

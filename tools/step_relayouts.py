@@ -275,7 +275,7 @@ def step_texts(mc, *, rows: int, chunk: int, pages_per_row: int, pool_pages: int
     def compiled(split, toks, slots) -> str:
         fn = functools.partial(llama.forward, cfg=mc, attn_impl="pallas", split=split, **counted)
         kept = {}
-        if mc.layer_group_size:  # a model with recurrent layers: its state buffers (rows + the null slot) and slot ids
+        if mc.recurrent_layers:  # a model with recurrent layers: its state buffers (rows + the null slot) and slot ids
             from dynamo_tpu.models import kda
 
             kept["recurrent"] = (*like(jax.eval_shape(lambda: kda.init_state(mc, rows + 1))), i32(slots))
