@@ -16,16 +16,26 @@ heads of 128 x 128); everything else is a few KB.
 Layout. ``S`` lies key-major (keys on sublanes, values on lanes), so ``v``,
 ``u`` and ``o`` are lane rows and the two matrix-vector products are a
 multiply and a sum over sublanes on the VPU (the MXU would reload a 128 x 128
-operand for one row of work). ``q``, ``k``, ``alpha`` and ``beta k`` scale the
-rows of ``S``, so the wrapper hands them over as columns: one
-``[rows, head blocks, key, 4 * block]`` array (``cols``), a lane a (quantity,
-head) pair.
-The grid is ``(rows, heads / block)``; a block of ``HEADS_PER_BLOCK`` heads
-moves 0.5 MiB in and out a step at 128 x 128.
+operand for one row of work). ``q``, ``k`` and ``alpha = exp(g)`` scale the
+rows of ``S``, so the kernel needs them as columns. It takes ``q``, ``k`` and
+``g`` as the layer's projections leave them, ``[rows, heads, key]`` with the
+keys on lanes, and turns a block's ``[heads, key]`` rows into ``[key, heads]``
+columns in VMEM (three small transpositions a grid step; ``exp`` there too);
+``beta``, one number a (row, head), comes through SMEM beside the slot ids
+and scales ``u``. The wrapper builds nothing.
+
+The grid is ``(rows, heads / block)``. A block is the most heads that divide
+the head count and whose state block, in and out and each double-buffered
+(4 x block), fits ``STATE_VMEM`` (``heads_block``): all 32 heads of 128 x 128
+(2 MiB a block, 8 MiB buffered; 64 grid steps for 64 rows), 16 of 32 heads of
+256 x 128. VMEM in all: the four state buffers, the rows' operands and outputs
+(5 x 16 KB, double-buffered), the three transposed tiles and a head's
+temporaries, under ``vmem_limit_bytes = 2 x STATE_VMEM``.
 
 Tests: ``tests/test_pallas_kda.py`` (interpret mode against
 ``models/kda.recurrent_step``), ``tests/test_chip_compile.py`` (compiled for a
-described v5e). ``docs/KERNELS.md`` has the contract.
+described v5e), ``tools/state_kernel_bench.py`` (one call alone on the chip,
+block by block). ``docs/KERNELS.md`` has the contract.
 """
 
 from __future__ import annotations
@@ -39,8 +49,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.pallas_paged import interpret_mode  # noqa: F401  (re-exported: the callers' one switch)
 
-HEADS_PER_BLOCK = 8
-VMEM_LIMIT = 32 << 20
+#: VMEM the state's four buffers may take (the block read and the block written, each double-buffered): the one
+#: budget both slot-state kernels size their grid step from (``ops/pallas_mamba.py`` reads it here). The kernels'
+#: ``vmem_limit_bytes`` is twice it: the rows' operands, the transposed tiles and a head's temporaries are the rest.
+STATE_VMEM = 8 << 20
 
 
 def supported(key: int, value: int) -> bool:
@@ -49,24 +61,31 @@ def supported(key: int, value: int) -> bool:
     return interpret_mode() or (key % 8 == 0 and value % 128 == 0)
 
 
-def _heads_block(heads: int) -> int:
-    hb = min(HEADS_PER_BLOCK, heads)
-    while heads % hb:
+def heads_block(heads: int, head_bytes: int, per_group: int = 1) -> int:
+    """Heads a grid step takes: the most that divide ``heads`` and whose
+    float32 states (``head_bytes`` each), in and out and double-buffered, fit
+    ``STATE_VMEM``. Where heads share operands a group of ``per_group``, a
+    block is a whole number of groups or a divisor of one."""
+    hb = max(1, min(heads, STATE_VMEM // (4 * head_bytes)))
+    while heads % hb or (hb % per_group and per_group % hb):
         hb -= 1
     return hb
 
 
-def _kernel(slots_ref, fresh_ref, v_ref, cols_ref, s_ref, s_out_ref, o_ref, *, hb: int):
+def _kernel(slots_ref, fresh_ref, beta_ref, q_ref, k_ref, g_ref, v_ref, s_ref, s_out_ref, o_ref, *, hb: int):
     del slots_ref  # read by the index maps only
-    keep = jnp.where(fresh_ref[pl.program_id(0)] != 0, 0.0, 1.0)  # a fresh row's slot holds another sequence's state
+    r, j = pl.program_id(0), pl.program_id(1)
+    keep = jnp.where(fresh_ref[r] != 0, 0.0, 1.0)  # a fresh row's slot holds another sequence's state
+    first = (r * pl.num_programs(1) + j) * hb  # the block's first (row, head) among the scalars
+    # A head's row as the column it scales S by: [key, hb].
+    q, k, alpha = q_ref[...].T, k_ref[...].T, jnp.exp(g_ref[...]).T * keep
     for i in range(hb):
-        col = lambda n: cols_ref[:, n * hb + i: n * hb + i + 1]  # noqa: E731  [key, 1]
-        q, k, alpha, kb = col(0), col(1), col(2), col(3)
-        s = s_ref[i] * (alpha * keep)  # decayed (and zeroed where fresh)
-        u = v_ref[pl.ds(i, 1), :] - jnp.sum(s * k, axis=0, keepdims=True)  # [1, value]
-        s = s + kb * u
+        ki = k[:, i: i + 1]
+        s = s_ref[i] * alpha[:, i: i + 1]  # decayed (and zeroed where fresh)
+        u = (v_ref[pl.ds(i, 1), :] - jnp.sum(s * ki, axis=0, keepdims=True)) * beta_ref[first + i]  # [1, value]
+        s = s + ki * u
         s_out_ref[i] = s
-        o_ref[pl.ds(i, 1), :] = jnp.sum(s * q, axis=0, keepdims=True)
+        o_ref[pl.ds(i, 1), :] = jnp.sum(s * q[:, i: i + 1], axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
@@ -85,32 +104,30 @@ def kda_decode_step(
     """One step of the recurrence for ``R`` rows: ``(o f32[R, H, V], state)``."""
     rows, heads, kd = q.shape
     vd = v.shape[-1]
-    hb = _heads_block(heads)
+    hb = heads_block(heads, 4 * kd * vd)
     f32 = jnp.float32
-    # Columns, a block of heads at a time: [R, H / hb, K, 4 * hb], lane n * hb + i holding quantity n of the block's head i.
-    cols = jnp.stack([q, k, jnp.exp(g), k * beta[..., None]], axis=1).astype(f32)  # [R, 4, H, K]
-    cols = cols.reshape(rows, 4, heads // hb, hb, kd).transpose(0, 2, 4, 1, 3).reshape(rows, heads // hb, kd, 4 * hb)
 
-    def at(index):  # index maps see the grid position, then the two prefetched scalars
-        return lambda r, j, slots, fresh: index(r, j, slots)
+    def at(index):  # index maps see the grid position, then the prefetched scalars
+        return lambda r, j, slots, *_: index(r, j, slots)
 
     s_spec = pl.BlockSpec((None, hb, kd, vd), at(lambda r, j, slots: (slots[r], j, 0, 0)))
-    row_spec = pl.BlockSpec((None, hb, vd), at(lambda r, j, slots: (r, j, 0)))
+    k_spec, v_spec = (pl.BlockSpec((None, hb, width), at(lambda r, j, slots: (r, j, 0))) for width in (kd, vd))
     state, o = pl.pallas_call(
         functools.partial(_kernel, hb=hb),
         out_shape=(jax.ShapeDtypeStruct(state.shape, f32), jax.ShapeDtypeStruct((rows, heads, vd), f32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(rows, heads // hb),
-            in_specs=[row_spec, pl.BlockSpec((None, None, kd, 4 * hb), at(lambda r, j, slots: (r, j, 0, 0))), s_spec],
-            out_specs=[s_spec, row_spec],
+            in_specs=[k_spec, k_spec, k_spec, v_spec, s_spec],
+            out_specs=[s_spec, v_spec],
         ),
-        input_output_aliases={4: 0},  # the state, after the two scalars, v and cols
+        input_output_aliases={7: 0},  # the state, after the three scalar operands, q, k, g and v
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"),
-                                             vmem_limit_bytes=VMEM_LIMIT),
-        cost_estimate=pl.CostEstimate(flops=8 * rows * heads * kd * vd, transcendentals=0,
+                                             vmem_limit_bytes=2 * STATE_VMEM),
+        cost_estimate=pl.CostEstimate(flops=8 * rows * heads * kd * vd, transcendentals=rows * heads * kd,
                                       bytes_accessed=2 * 4 * rows * heads * kd * vd),
         interpret=interpret,
         name="kda_decode_step",
-    )(slot_ids.astype(jnp.int32), fresh.astype(jnp.int32), v.astype(f32), cols, state)
+    )(slot_ids.astype(jnp.int32), fresh.astype(jnp.int32), beta.astype(f32).reshape(-1),
+      q.astype(f32), k.astype(f32), g.astype(f32), v.astype(f32), state)
     return o, state

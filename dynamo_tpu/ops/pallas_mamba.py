@@ -14,17 +14,27 @@ head channels`` a row a head (8.39 MB a row at 32 heads of 256 x 128);
 everything else is a few KB.
 
 Layout. ``S`` lies state-major (the ``N`` state entries on sublanes, a head's
-``P`` channels on lanes), so ``dt x``, the decay and ``y`` are lane rows, ``B``
-and ``C`` scale the rows of ``S`` and come as columns, and both products are a
-multiply and a sum over sublanes on the VPU. ``B`` and ``C`` belong to a
-*group* of heads: the wrapper hands them over once a group (``[rows, groups,
-N, 2]``) and a block of heads reads its group's. The grid is ``(rows, heads /
-block)``; a block of ``HEADS_PER_BLOCK`` heads moves 1 MiB in and out a step
-at 256 x 128.
+``P`` channels on lanes), so ``x`` and ``y`` are lane rows and both products
+are a multiply and a sum over sublanes on the VPU. The decay ``exp(dt A)`` and
+``dt`` are one number a (row, head): they come through SMEM beside the slot
+ids, and the kernel scales ``S`` and ``x`` by them itself. ``B`` and ``C``
+scale the rows of ``S`` and belong to a *group* of heads: the kernel takes
+them as the conv leaves them, ``[rows, groups, N]`` with the state entries on
+lanes, and turns a group's row into a ``[N, 1]`` column in VMEM. The wrapper
+builds nothing but the ``[rows, heads]`` decays.
+
+The grid is ``(rows, heads / block)``, the block from ``ops/pallas_kda``'s
+``heads_block`` and its one budget ``STATE_VMEM``: the most heads that divide
+the head count, are a whole number of groups or a divisor of one, and whose
+state block, in and out and each double-buffered (4 x block), fits: a group's
+16 of the 32 heads of 256 x 128 (2 MiB a block, 8 MiB buffered; all 32 would
+take 16 MiB and run no faster). A block that spans groups reads each head's
+group statically; one inside a group finds its group from the grid position.
 
 Tests: ``tests/test_pallas_mamba.py`` (interpret mode against
 ``models/mamba2.recurrent_step``), ``tests/test_chip_compile.py`` (compiled
-for a described v5e). ``docs/KERNELS.md`` has the contract.
+for a described v5e), ``tools/state_kernel_bench.py`` (one call alone on the
+chip, block by block). ``docs/KERNELS.md`` has the contract.
 """
 
 from __future__ import annotations
@@ -36,10 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops import pallas_kda
 from dynamo_tpu.ops.pallas_paged import interpret_mode  # noqa: F401  (re-exported: the callers' one switch)
-
-HEADS_PER_BLOCK = 8
-VMEM_LIMIT = 32 << 20
 
 
 def supported(state: int, channels: int) -> bool:
@@ -48,20 +56,16 @@ def supported(state: int, channels: int) -> bool:
     return interpret_mode() or (state % 8 == 0 and channels % 128 == 0)
 
 
-def _heads_block(heads_per_group: int) -> int:
-    """Heads a grid step takes: a divisor of a group's heads, so that a block reads one group's B and C."""
-    hb = min(HEADS_PER_BLOCK, heads_per_group)
-    while heads_per_group % hb:
-        hb -= 1
-    return hb
-
-
-def _kernel(slots_ref, fresh_ref, u_ref, decay_ref, bc_ref, s_ref, s_out_ref, y_ref, *, hb: int):
+def _kernel(slots_ref, fresh_ref, decay_ref, dt_ref, x_ref, b_ref, c_ref, s_ref, s_out_ref, y_ref, *, hb: int, per_group: int):
     del slots_ref  # read by the index maps only
-    keep = jnp.where(fresh_ref[pl.program_id(0)] != 0, 0.0, 1.0)  # a fresh row's slot holds another sequence's state
-    b, c = bc_ref[:, 0:1], bc_ref[:, 1:2]  # [state, 1]
+    r, j = pl.program_id(0), pl.program_id(1)
+    keep = jnp.where(fresh_ref[r] != 0, 0.0, 1.0)  # a fresh row's slot holds another sequence's state
+    first = (r * pl.num_programs(1) + j) * hb  # the block's first (row, head) among the scalars
     for i in range(hb):
-        s = s_ref[i] * (decay_ref[pl.ds(i, 1), :] * keep) + b * u_ref[pl.ds(i, 1), :]
+        if i % per_group == 0:  # the next group's B and C (a block inside a group: its one group), as columns [state, 1]
+            group = (j * hb + i) // per_group
+            b, c = b_ref[pl.ds(group, 1), :].T, c_ref[pl.ds(group, 1), :].T
+        s = s_ref[i] * (decay_ref[first + i] * keep) + b * (x_ref[pl.ds(i, 1), :] * dt_ref[first + i])
         s_out_ref[i] = s
         y_ref[pl.ds(i, 1), :] = jnp.sum(s * c, axis=0, keepdims=True)
 
@@ -82,34 +86,32 @@ def mamba_decode_step(
     """One step of the recurrence for ``R`` rows: ``(y f32[R, H, P], state)``."""
     rows, heads, p = x.shape
     groups, n = b.shape[1:]
-    hb = _heads_block(heads // groups)
-    per_group = heads // groups // hb  # head blocks a group
+    hb = pallas_kda.heads_block(heads, 4 * n * p, heads // groups)
     f32 = jnp.float32
-    u = (x * dt[..., None]).astype(f32)
-    decay = jnp.broadcast_to(jnp.exp(dt * a)[..., None], x.shape).astype(f32)  # a head's decay on each of its lanes
-    bc = jnp.stack([b, c], axis=-1).astype(f32)  # [R, G, N, 2]
+    dt = dt.astype(f32)
 
-    def at(index):  # index maps see the grid position, then the two prefetched scalars
-        return lambda r, j, slots, fresh: index(r, j, slots)
+    def at(index):  # index maps see the grid position, then the prefetched scalars
+        return lambda r, j, slots, *_: index(r, j, slots)
 
     s_spec = pl.BlockSpec((None, hb, n, p), at(lambda r, j, slots: (slots[r], j, 0, 0)))
     row_spec = pl.BlockSpec((None, hb, p), at(lambda r, j, slots: (r, j, 0)))
+    group_spec = pl.BlockSpec((None, groups, n), at(lambda r, j, slots: (r, 0, 0)))  # every group's: fetched once a row
     state, y = pl.pallas_call(
-        functools.partial(_kernel, hb=hb),
+        functools.partial(_kernel, hb=hb, per_group=heads // groups),
         out_shape=(jax.ShapeDtypeStruct(state.shape, f32), jax.ShapeDtypeStruct((rows, heads, p), f32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=4,
             grid=(rows, heads // hb),
-            in_specs=[row_spec, row_spec,
-                      pl.BlockSpec((None, None, n, 2), at(lambda r, j, slots: (r, j // per_group, 0, 0))), s_spec],
+            in_specs=[row_spec, group_spec, group_spec, s_spec],
             out_specs=[s_spec, row_spec],
         ),
-        input_output_aliases={5: 0},  # the state, after the two scalars, u, the decay and the group's B and C
+        input_output_aliases={7: 0},  # the state, after the four scalar operands, x, B and C
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"),
-                                             vmem_limit_bytes=VMEM_LIMIT),
+                                             vmem_limit_bytes=2 * pallas_kda.STATE_VMEM),
         cost_estimate=pl.CostEstimate(flops=6 * rows * heads * n * p, transcendentals=0,
                                       bytes_accessed=2 * 4 * rows * heads * n * p),
         interpret=interpret,
         name="mamba_decode_step",
-    )(slot_ids.astype(jnp.int32), fresh.astype(jnp.int32), u, decay, bc, state)
+    )(slot_ids.astype(jnp.int32), fresh.astype(jnp.int32), jnp.exp(dt * a).reshape(-1), dt.reshape(-1),
+      x.astype(f32), b.astype(f32), c.astype(f32), state)
     return y, state

@@ -668,19 +668,29 @@ def test_held_layer_routes_a_decode_step_without_sort_scatter_or_loop(sds, monke
 
 # -- a hybrid stack: KDA layers in slots beside the paged latent cache (ISSUE 40) ----------------
 
-def test_kda_decode_kernel_compiles(sds):
+@pytest.mark.parametrize("budget_mib, block", [(None, 32), (4, 16), (2, 8)], ids=["served", "4MiB", "2MiB"])
+def test_kda_decode_kernel_compiles(sds, monkeypatch, budget_mib, block):
     """The decode step of the KDA recurrence at Ling-3.0-flash's widths: 64
     rows x 32 heads of 128 x 128 over 15 layers x 65 slots, the state aliased
-    to the kernel's output (updated where it lies: no second 2 GB buffer)."""
-    from dynamo_tpu.ops.pallas_kda import kda_decode_step
+    to the kernel's output (updated where it lies: no second 2 GB buffer). At
+    the budget the tree ships the block is all 32 heads (2 MiB in, 2 MiB out,
+    double-buffered: the whole budget) and the grid runs over rows only; a
+    smaller budget takes a smaller block. Mosaic holds the kernel to its
+    ``vmem_limit_bytes``, twice the budget. The wrapper lays out nothing: no
+    ``[rows, blocks, key, 4 x block]`` column array reaches the kernel."""
+    from dynamo_tpu.ops import pallas_kda
 
+    if budget_mib:
+        monkeypatch.setattr(pallas_kda, "STATE_VMEM", budget_mib << 20)
+    assert pallas_kda.heads_block(32, 4 * 128 * 128) == block and 4 * block * 4 * 128 * 128 <= pallas_kda.STATE_VMEM
     f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
-    compiled = jax.jit(kda_decode_step.__wrapped__, donate_argnums=(0,)).lower(
+    compiled = jax.jit(lambda *a: pallas_kda.kda_decode_step.__wrapped__(*a), donate_argnums=(0,)).lower(
         f32(15 * 65, 32, 128, 128), sds((64,), jnp.int32), sds((64,), jnp.bool_),
         f32(64, 32, 128), f32(64, 32, 128), f32(64, 32, 128), f32(64, 32, 128), f32(64, 32)).compile()
-    assert "kda_decode_step" in compiled.as_text()
+    text = compiled.as_text()
+    assert "kda_decode_step" in text and "f32[64,1,128,128]" not in text and "f32[64,4,128,32]" not in text
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 15 * 65 * 32 * 128 * 128 * 4 and mem.temp_size_in_bytes < 64 << 20
+    assert mem.alias_size_in_bytes >= 15 * 65 * 32 * 128 * 128 * 4 and mem.temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
@@ -724,6 +734,8 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     ).compile()
     text = compiled.as_text()
     assert "kda_decode_step" in text and "mla_paged_decode_attention" in text and text.count("moe_grouped_matmul_int8") >= 2
+    # The kernel takes q, k, g as the projections leave them: no stack of four, no column array laid out for it.
+    assert "f32[64,4,4,8,128]" not in text and "f32[64,4,128,32]" not in text and "f32[64,1,128,128]" not in text
     assert "ragged-dot" not in text and "ragged_dot" not in text
     shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
     assert shapes[-3:] == [(5,), state.shape, conv.shape]  # HELD_COUNTS, then the state buffers
@@ -736,20 +748,30 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
 
 # -- a Mamba-2 mixer beside GQA attention in every layer: pages and a slot a layer (ISSUE 46) ------------
 
-def test_mamba_decode_kernel_compiles(sds):
+@pytest.mark.parametrize("budget_mib, block", [(None, 16), (16, 32), (4, 8)], ids=["served", "16MiB", "4MiB"])
+def test_mamba_decode_kernel_compiles(sds, monkeypatch, budget_mib, block):
     """The decode step of the Mamba-2 recurrence at Falcon-H1-34B's widths: 64
     rows x 32 heads of 256 x 128 in 2 groups over 9 layers x 65 slots, the
     state aliased to the kernel's output (updated where it lies: no second
-    2.45 GB buffer)."""
-    from dynamo_tpu.ops.pallas_mamba import mamba_decode_step
+    2.45 GB buffer). At the budget the tree ships a block is a group's 16
+    heads (2 MiB in, 2 MiB out, double-buffered: the whole budget); twice the
+    budget takes both groups in one block and the grid over rows only, half
+    takes half a group. The wrapper lays out nothing: no lane-wide decay or
+    ``dt x`` and no ``[rows, groups, N, 2]`` columns reach the kernel, only
+    the ``[rows x heads]`` scalars."""
+    from dynamo_tpu.ops import pallas_kda, pallas_mamba
 
+    if budget_mib:
+        monkeypatch.setattr(pallas_kda, "STATE_VMEM", budget_mib << 20)
+    assert pallas_kda.heads_block(32, 4 * 256 * 128, 16) == block and 4 * block * 4 * 256 * 128 <= pallas_kda.STATE_VMEM
     f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
-    compiled = jax.jit(mamba_decode_step.__wrapped__, donate_argnums=(0,)).lower(
+    compiled = jax.jit(lambda *a: pallas_mamba.mamba_decode_step.__wrapped__(*a), donate_argnums=(0,)).lower(
         f32(9 * 65, 32, 256, 128), sds((64,), jnp.int32), sds((64,), jnp.bool_),
         f32(64, 32, 128), f32(64, 2, 256), f32(64, 2, 256), f32(64, 32), f32(32)).compile()
-    assert "mamba_decode_step" in compiled.as_text()
+    text = compiled.as_text()
+    assert "mamba_decode_step" in text and "f32[64,2,256,2]" not in text and "f32[2048]" in text
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 9 * 65 * 32 * 256 * 128 * 4 and mem.temp_size_in_bytes < 64 << 20
+    assert mem.alias_size_in_bytes >= 9 * 65 * 32 * 256 * 128 * 4 and mem.temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
@@ -788,6 +810,7 @@ def test_parallel_mixer_step_falcon_h1_largest_corners(sds, split):
     ).compile()
     text = compiled.as_text()
     assert "mamba_decode_step" in text and "kda_decode_step" not in text
+    assert "f32[64,2,256,2]" not in text  # B and C reach the kernel as the conv leaves them, not as padded columns
     assert ("paged_prefill_attention" if split else "paged_decode_attention") in text
     shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
     assert shapes[-2:] == [state.shape, conv.shape] and len(shapes) == 5  # logits, the caches, the state buffers

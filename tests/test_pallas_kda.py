@@ -1,7 +1,7 @@
 """The KDA decode kernel (``ops/pallas_kda.py``) in interpret mode against the
 plain ``jax.numpy`` step (``models/kda.recurrent_step``): slots read through
 their ids, a fresh row read as zeros, the state written back in place and no
-other slot touched, at one block of heads and at several."""
+other slot touched, at every block of heads the VMEM budget can choose."""
 
 import jax
 import jax.numpy as jnp
@@ -23,13 +23,24 @@ def _case(seed, rows, heads, key, value, slots):
         beta=f(rng.uniform(size=(rows, heads))))
 
 
-@pytest.mark.parametrize("rows, heads, key, value, per_block", [
-    (3, 4, 16, 128, 8),  # one block of heads, the toy's key width
-    (5, 16, 128, 128, 8),  # two blocks at the published head size
-    (2, 6, 8, 128, 4),  # heads that the block does not divide: the largest divisor within it (3)
-], ids=["one-block", "two-blocks", "odd-heads"])
-def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, key, value, per_block):
-    monkeypatch.setattr(pallas_kda, "HEADS_PER_BLOCK", per_block)
+def _budget(monkeypatch, block, key, value):
+    """``STATE_VMEM`` at which ``block`` heads of ``key x value`` just fit, in and out and double-buffered."""
+    monkeypatch.setattr(pallas_kda, "STATE_VMEM", 4 * block * 4 * key * value)
+
+
+@pytest.mark.parametrize("rows, heads, key, value, fits, block", [
+    (3, 4, 16, 128, 8, 4),  # every head in one block, the toy's key width
+    (5, 16, 128, 128, 8, 8),  # two blocks at the published head size
+    (2, 32, 128, 128, 8, 8),  # the published layer at each block the budget may be set to choose: four blocks a row,
+    (2, 32, 128, 128, 16, 16),  # two,
+    (2, 32, 128, 128, 32, 32),  # and the grid over rows only
+    (2, 6, 8, 128, 4, 3),  # heads that the fitting block does not divide: the largest divisor within it
+    (2, 12, 16, 128, 8, 6),  # a head count that is no power of two, in two blocks
+    (3, 24, 16, 128, 64, 24),  # and in one
+], ids=["one-block", "two-blocks", "published-8", "published-16", "published-32", "odd-heads", "twelve-heads", "twenty-four-heads"])
+def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, key, value, fits, block):
+    _budget(monkeypatch, fits, key, value)
+    assert pallas_kda.heads_block(heads, 4 * key * value) == block
     slots = rows + 3
     c = _case(rows, rows, heads, key, value, slots)
     ids = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, slots))[:rows], jnp.int32)
@@ -37,20 +48,23 @@ def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, key, value, per
     before = np.asarray(c["state"])
     s_in = jnp.where(fresh[:, None, None, None], 0.0, c["state"][ids])
     o_want, s_want = kda.recurrent_step(s_in, c["q"], c["k"], c["v"], c["g"], c["beta"])
-    o_got, state = pallas_kda.kda_decode_step(c["state"], ids, fresh, c["q"], c["k"], c["v"], c["g"], c["beta"], interpret=True)
+    # (not through the jitted wrapper: its cache would answer a second budget with the first one's block)
+    o_got, state = pallas_kda.kda_decode_step.__wrapped__(c["state"], ids, fresh, c["q"], c["k"], c["v"], c["g"], c["beta"], interpret=True)
     np.testing.assert_allclose(o_got, o_want, atol=2e-6)
     np.testing.assert_allclose(np.asarray(state)[np.asarray(ids)], s_want, atol=2e-6)
     others = [i for i in range(slots) if i not in set(np.asarray(ids).tolist())]
     assert np.array_equal(np.asarray(state)[others], before[others])  # bit for bit: never read, never written
 
 
-def test_a_row_that_neither_decays_nor_writes_leaves_its_slot_as_it_was():
+@pytest.mark.parametrize("fits", [1, 2, 4], ids=["a-head-a-step", "two-blocks", "one-block"])
+def test_a_row_that_neither_decays_nor_writes_leaves_its_slot_as_it_was(monkeypatch, fits):
     """What ``kda_attention`` hands over for a padding token: g = 0, beta = 0."""
+    _budget(monkeypatch, fits, 16, 128)
     c = _case(7, 2, 4, 16, 128, 4)
     ids, fresh = jnp.asarray([2, 3], jnp.int32), jnp.zeros(2, bool)
     before = np.asarray(c["state"])
-    _, state = pallas_kda.kda_decode_step(c["state"], ids, fresh, c["q"], c["k"], c["v"], jnp.zeros_like(c["g"]),
-                                          jnp.zeros_like(c["beta"]), interpret=True)
+    _, state = pallas_kda.kda_decode_step.__wrapped__(c["state"], ids, fresh, c["q"], c["k"], c["v"], jnp.zeros_like(c["g"]),
+                                                      jnp.zeros_like(c["beta"]), interpret=True)
     np.testing.assert_array_equal(np.asarray(state), before)
 
 
@@ -58,7 +72,22 @@ def test_supported_shapes(monkeypatch):
     monkeypatch.setattr(pallas_kda, "interpret_mode", lambda: False)
     assert pallas_kda.supported(128, 128) and pallas_kda.supported(16, 128)
     assert not pallas_kda.supported(16, 16) and not pallas_kda.supported(12, 128)
-    assert pallas_kda._heads_block(32) == 8 and pallas_kda._heads_block(6) == 6 and pallas_kda._heads_block(12) == 6
+
+
+
+def test_the_block_is_the_most_heads_the_budget_holds(monkeypatch):
+    """The state's four buffers (a block in and out, each double-buffered)
+    within ``STATE_VMEM`` (half of ``vmem_limit_bytes``): a divisor of
+    the head count and, where heads share operands a group, a whole number of
+    groups or a divisor of one."""
+    head, block = 4 * 128 * 128, pallas_kda.heads_block
+    assert block(32, head) == 32 and 4 * 32 * head <= pallas_kda.STATE_VMEM  # the published layer: the grid over rows only
+    monkeypatch.setattr(pallas_kda, "STATE_VMEM", 4 * 16 * head)  # room for 16 heads
+    assert (block(32, head), block(6, head), block(24, head), block(34, head)) == (16, 6, 12, 2)
+    assert (block(32, head, 8), block(32, head, 16), block(64, head, 32)) == (16, 16, 16)  # two groups, one, half of one
+    assert (block(24, head, 12), block(24, head, 6), block(20, head, 5), block(48, head, 3)) == (12, 12, 10, 12)
+    assert block(12, 4 * head, 3) == 3  # 4 fit, which neither divides a group of 3 nor holds whole ones
+    assert block(7, 32 * head) == 1  # a head that does not fit still goes alone
 
 
 def test_the_layer_takes_the_kernel_where_the_platform_runs_it(monkeypatch):

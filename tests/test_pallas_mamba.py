@@ -1,8 +1,8 @@
 """The Mamba-2 decode kernel (``ops/pallas_mamba.py``) in interpret mode
 against the plain ``jax.numpy`` step (``models/mamba2.recurrent_step``): slots
 read through their ids, a fresh row read as zeros, the null slot, the state
-written back in place and no other slot touched, a block of heads reading its
-own group's B and C; and the chunked form (``models/mamba2.chunk_step``) over
+written back in place and no other slot touched, at every block of heads the
+VMEM budget can choose (inside a group, a whole group, several groups); and the chunked form (``models/mamba2.chunk_step``) over
 any split of a sequence into chunks, the last one padded, against the
 recurrence token by token."""
 
@@ -15,7 +15,7 @@ import pytest
 
 from dynamo_tpu.models import kda, mamba2
 from dynamo_tpu.models.config import PRESETS
-from dynamo_tpu.ops import pallas_mamba
+from dynamo_tpu.ops import pallas_kda, pallas_mamba
 
 
 def _case(seed, rows, heads, groups, n, p, slots):
@@ -37,37 +37,53 @@ def _plain(case, ids, fresh):
     return y.reshape(rows, heads, p), s.reshape(rows, heads, n, p)
 
 
-@pytest.mark.parametrize("rows, heads, groups, n, p, per_block", [
-    (3, 4, 2, 8, 128, 8),  # a block a group (2 heads), the toy's state
-    (4, 32, 2, 256, 128, 8),  # the published mixer: two blocks of 8 heads a group of 16
-    (2, 12, 2, 16, 128, 4),  # 6 heads a group, which 4 does not divide: the largest divisor within it (3)
-    (3, 8, 1, 8, 16, 8),  # one group; channels narrower than a lane tile (the interpreter tiles nothing)
-], ids=["toy", "published", "odd-heads", "one-group"])
-def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, groups, n, p, per_block):
-    monkeypatch.setattr(pallas_mamba, "HEADS_PER_BLOCK", per_block)
+def _budget(monkeypatch, block, n, p):
+    """The budget (``ops/pallas_kda.STATE_VMEM``) at which ``block`` heads of ``n x p`` just fit, in and out and double-buffered."""
+    monkeypatch.setattr(pallas_kda, "STATE_VMEM", 4 * block * 4 * n * p)
+
+
+@pytest.mark.parametrize("rows, heads, groups, n, p, fits, block", [
+    (3, 4, 2, 8, 128, 2, 2),  # a block a group (2 heads), the toy's state
+    (2, 32, 2, 256, 128, 8, 8),  # the published mixer at each block the budget may be set to choose: two blocks a group of 16,
+    (2, 32, 2, 256, 128, 16, 16),  # a block a group,
+    (2, 32, 2, 256, 128, 32, 32),  # and one block over both groups: the grid over rows only
+    (2, 12, 2, 16, 128, 4, 3),  # 6 heads a group, which 4 does not divide: the largest divisor within it
+    (2, 12, 2, 16, 128, 16, 12),  # a head count that is no power of two, both groups in one block
+    (2, 24, 3, 16, 128, 16, 8),  # three groups of 8: 16 would fit but does not divide 24, 12 would cut a group
+    (2, 24, 4, 8, 128, 12, 12),  # four groups of 6, two blocks of two groups each: the group by the grid position
+    (3, 8, 1, 8, 16, 8, 8),  # one group; channels narrower than a lane tile (the interpreter tiles nothing)
+], ids=["toy", "published-8", "published-16", "published-32", "odd-heads", "twelve-heads", "three-groups", "two-groups-a-block",
+        "one-group"])
+def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, groups, n, p, fits, block):
+    _budget(monkeypatch, fits, n, p)
+    assert pallas_kda.heads_block(heads, 4 * n * p, heads // groups) == block
     slots = rows + 3
     c = _case(rows, rows, heads, groups, n, p, slots)
     ids = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, slots))[:rows], jnp.int32)
     fresh = jnp.asarray(np.arange(rows) % 2 == 1)
     before = np.asarray(c["state"])
     y_want, s_want = _plain(c, ids, fresh)
-    y_got, state = pallas_mamba.mamba_decode_step(c["state"], ids, fresh, c["x"], c["b"], c["c"], c["dt"], c["a"], interpret=True)
+    # (not through the jitted wrapper: its cache would answer a second budget with the first one's block)
+    y_got, state = pallas_mamba.mamba_decode_step.__wrapped__(c["state"], ids, fresh, c["x"], c["b"], c["c"], c["dt"], c["a"],
+                                                              interpret=True)
     np.testing.assert_allclose(y_got, y_want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(state)[np.asarray(ids)], s_want, rtol=1e-6, atol=2e-6)
     others = [i for i in range(slots) if i not in set(np.asarray(ids).tolist())]
     assert np.array_equal(np.asarray(state)[others], before[others])  # bit for bit: never read, never written
 
 
-def test_padding_rows_share_the_null_slot_and_leave_the_live_slots_alone():
+@pytest.mark.parametrize("fits", [1, 2, 4], ids=["a-head-a-step", "a-block-a-group", "one-block"])
+def test_padding_rows_share_the_null_slot_and_leave_the_live_slots_alone(monkeypatch, fits):
     """What ``mamba_mixer`` hands over for padding rows: slot 0, ``dt = 0`` (no
     decay, no write), and ``fresh`` (their position is 0): the null slot reads
     as zeros and is written as zeros, whatever several rows do to it at once;
     a live row with ``dt = 0`` leaves its slot as it was, bit for bit."""
+    _budget(monkeypatch, fits, 8, 128)
     c = _case(7, 4, 4, 2, 8, 128, 5)
     ids, fresh = jnp.asarray([0, 3, 0, 2], jnp.int32), jnp.asarray([True, False, True, False])
     dt = c["dt"].at[jnp.asarray([0, 2, 3])].set(0.0)
     before = np.asarray(c["state"])
-    y, state = pallas_mamba.mamba_decode_step(c["state"], ids, fresh, c["x"], c["b"], c["c"], dt, c["a"], interpret=True)
+    y, state = pallas_mamba.mamba_decode_step.__wrapped__(c["state"], ids, fresh, c["x"], c["b"], c["c"], dt, c["a"], interpret=True)
     after = np.asarray(state)
     assert np.array_equal(after[[1, 2, 4]], before[[1, 2, 4]]) and not after[0].any() and not np.asarray(y)[[0, 2]].any()
     y_want, s_want = _plain({**c, "dt": dt}, ids, fresh)
@@ -79,7 +95,6 @@ def test_supported_shapes(monkeypatch):
     monkeypatch.setattr(pallas_mamba, "interpret_mode", lambda: False)
     assert pallas_mamba.supported(256, 128) and pallas_mamba.supported(8, 128)
     assert not pallas_mamba.supported(8, 16) and not pallas_mamba.supported(12, 128)
-    assert pallas_mamba._heads_block(16) == 8 and pallas_mamba._heads_block(6) == 6 and pallas_mamba._heads_block(12) == 6
 
 
 @pytest.mark.parametrize("cuts", [(64,), (13, 40, 11), (1, 1, 62), (7,) * 9 + (1,)], ids=["whole", "ragged", "ones-first", "sevens"])
@@ -146,3 +161,26 @@ def test_the_mixer_takes_the_kernel_where_the_platform_runs_it(monkeypatch):
     np.testing.assert_allclose(out[0, 3:6], alone[0][0, :3], atol=1e-5)
     np.testing.assert_allclose(state2[1], alone[1][1], atol=1e-5)
     np.testing.assert_allclose(conv2[1], alone[2][1], atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["kda", "mamba"])
+def test_the_bench_rehearses_every_candidate_against_the_plain_step(kind):
+    """``tools/state_kernel_bench.py`` at its toy shapes under the interpreter:
+    the served kernel at two budgets, the bare copy / read / write of the
+    same blocks and the XLA step, each scanned over the layers on one donated
+    state: every kernel row equal to the plain step, the padding row's slot
+    and the unnamed slots bit for bit, and no time printed off the chip."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parent.parent / "tools" / "state_kernel_bench.py"
+    spec = importlib.util.spec_from_file_location("state_kernel_bench", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    shape = tool.TOY[kind]
+    rows = tool.bench(kind, tool.candidates_of(kind, shape, [2, 4], "", None, interpret=True), shape,
+                      seed=7, iters=1, timed=False, peak=0.0)
+    assert [r["candidate"] for r in rows] == ["xla", "served@2", "served@4", "copy@4", "read@4", "write@4"]
+    kernels = [r for r in rows if "stream" not in r]
+    assert all(r["kept"] and r["out_err"] < 1e-5 and r["state_err"] < 1e-5 for r in kernels), rows
+    assert [r["block"] for r in kernels[1:]] == [2, 4] and not any("us_call" in r or "error" in r for r in rows), rows

@@ -1,0 +1,325 @@
+#!/usr/bin/env python3
+"""The two slot-state decode kernels alone, on the chip: one call of
+``ops/pallas_kda.kda_decode_step`` and of ``ops/pallas_mamba.mamba_decode_step``
+at the shapes their cells serve, for each block of heads the VMEM budget can
+choose and each operand form on offer, the wrapper's operations inside the
+timed function.
+
+    python3 tools/state_kernel_bench.py [--kinds kda,mamba] [--budgets-mib 2,4,8,16] [--parent DIR] [--extra FILE] [--iters 20]
+
+Shapes: ``kda`` is Ling-3.0-flash's layer (64 rows, 32 heads of 128 x 128, 15
+layers of 65 slots), ``mamba`` Falcon-H1-34B's mixer (64 rows, 32 heads of
+256 x 128 in 2 groups, 9 layers of 65 slots); the rows' slots are a seeded
+permutation, not the row order, one row is a padding row (the null slot, no
+decay, no write) and one is fresh. A candidate is called once a layer inside a
+``lax.scan`` over the layers, as the layer scan calls it, on the layer's own
+slots of one state buffer that the program donates; its operands are the
+layer's slices of seeded arrays in the forms the model's projections leave
+(``q k g [R, H, K]``, ``beta [R, H]``; ``x [R, H, P]``, ``B C [R, G, N]``, ``dt
+[R, H]``), so whatever a wrapper lays out is inside the time.
+
+Candidates: ``served@<MiB>`` is the tree's kernel with ``STATE_VMEM`` set to
+each of ``--budgets-mib`` (the block that budget chooses is in the row;
+``served`` marks the budget the tree ships); ``parent`` the kernels of another
+tree (``--parent DIR``: its two kernel files are loaded beside this tree's);
+``--extra FILE`` names a Python file whose ``CANDIDATES = {"kda": {name: fn},
+"mamba": {name: fn}}`` take the kernels' arguments (a builder's scratch forms);
+``copy@<block>``, ``read@<block>`` and ``write@<block>`` are no recurrence at
+all, only the served block's traffic through the same ``BlockSpec`` pipeline
+(the states copied where they lie, only read, only written): the rate the DMA
+gives a stream of that kind, which no kernel on this pipeline can pass;
+``xla`` is the plain step (``recurrent_step`` on gathered states, scattered
+back), which is also what every candidate is checked against on the chip:
+``out_err`` / ``state_err`` the largest difference over all layers, ``kept``
+whether every slot no live row names, the padding row's among them, is still
+bit for bit what it was.
+
+Per candidate: ``us_call`` by the host's clock over ``--iters`` runs of the
+scan enqueued back to back (a run is 9 or 15 calls and takes the state the
+run before gave) and, from a traced run, ``kernel_us`` (the device events
+whose name starts with the kernel's, a call), ``other_us`` (every other
+device operation inside the scan, a call: the wrapper's operations and the
+scan's own slices) and ``peak_pct``: the state's bytes (one read and one write
+of every row's float32 state: what the cells' roofline metrics count bar a
+percent of small operands; a ``read@`` or ``write@`` row moves half) at the
+HBM peak over ``kernel_us``. Written to
+``chiprun_out/state_kernel_bench.json``. Without a TPU (or ``--rehearse``) it
+runs toy shapes under the interpreter, prints no time and exits 3.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import functools
+import importlib.util
+import json
+import pathlib
+import shutil
+import sys
+import time
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+# kind: rows, heads, groups (0: no groups), state rows (key / N), lanes (value / P), layers, slots a layer
+SHAPES = {"kda": (64, 32, 0, 128, 128, 15, 65), "mamba": (64, 32, 2, 256, 128, 9, 65)}
+TOY = {"kda": (4, 4, 0, 16, 128, 2, 6), "mamba": (4, 4, 2, 8, 128, 2, 6)}
+KERNEL = {"kda": "kda_decode_step", "mamba": "mamba_decode_step"}
+
+
+@contextlib.contextmanager
+def state_vmem(budget: int):
+    """``ops/pallas_kda.STATE_VMEM`` set to ``budget`` for what is traced inside."""
+    from dynamo_tpu.ops import pallas_kda
+
+    was, pallas_kda.STATE_VMEM = pallas_kda.STATE_VMEM, budget
+    try:
+        yield
+    finally:
+        pallas_kda.STATE_VMEM = was
+
+
+def load_file(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def operands(kind: str, shape: tuple, seed: int):
+    """``(state, ids, fresh, live, per-layer operands)``: row 1 fresh, row 2 a
+    padding row on the null slot (not fresh, so that its slot must come back as it was)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    rows, heads, groups, n, p, layers, slots = shape
+    rng = np.random.default_rng(seed)
+    f = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    ids = rng.permutation(np.arange(1, slots))[:rows]
+    ids[2] = 0
+    live = np.ones(rows, bool)
+    live[2] = False
+    fresh = np.zeros(rows, bool)
+    fresh[1] = True
+    state = f(rng.standard_normal((layers * slots, heads, n, p)))
+    mask = live[None, :, None]
+    if kind == "kda":
+        unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+        q, k = (unit(rng.standard_normal((layers, rows, heads, n))) for _ in range(2))
+        v = rng.standard_normal((layers, rows, heads, p))
+        g = -5 * rng.uniform(size=(layers, rows, heads, n)) ** 3 * mask[..., None]
+        beta = rng.uniform(size=(layers, rows, heads)) * mask
+        ops = tuple(f(o) for o in (q, k, v, g, beta))
+    else:
+        x = rng.standard_normal((layers, rows, heads, p))
+        b, c = (rng.standard_normal((layers, rows, groups, n)) for _ in range(2))
+        dt = rng.uniform(0.0, 0.7, size=(layers, rows, heads)) ** 2 * mask
+        ops = (f(x), f(b), f(c), f(dt), f(-rng.uniform(0.3, 3.0, size=heads)))
+    return state, jnp.asarray(ids, jnp.int32), jnp.asarray(fresh), live, ops
+
+
+def xla_step(kind: str):
+    """The plain step through slots, with the kernels' signature."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import kda, mamba2
+
+    def kda_step(state, ids, fresh, q, k, v, g, beta):
+        o, s = kda.recurrent_step(jnp.where(fresh[:, None, None, None], 0.0, state[ids]), q, k, v, g, beta)
+        return o, state.at[ids].set(s)
+
+    def mamba_step(state, ids, fresh, x, b, c, dt, a):
+        (r, h, p), (gr, n) = x.shape, b.shape[1:]
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state[ids]).reshape(r, gr, h // gr, n, p)
+        y, s = mamba2.recurrent_step(s0, x.reshape(r, gr, h // gr, p), b, c, dt.reshape(r, gr, h // gr), a.reshape(gr, h // gr))
+        return y.reshape(r, h, p), state.at[ids].set(s.reshape(r, h, n, p))
+
+    return kda_step if kind == "kda" else mamba_step
+
+
+def stream_step(mode: str, hb: int, interpret: bool):
+    """No recurrence, only the kernels' traffic: the named slots' states in
+    blocks of ``hb`` heads through the same ``BlockSpec`` pipeline, ``copy``
+    (read, written back where they lay), ``read`` (read, a column sum out) or
+    ``write`` (a constant written, nothing read): what the DMA alone takes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(slots_ref, s_ref, *outs):
+        for i in range(hb):
+            if mode != "read":
+                outs[0][i] = jnp.full(outs[0].shape[1:], 0.25, jnp.float32) if mode == "write" else s_ref[i]
+            outs[-1][pl.ds(i, 1), :] = jnp.zeros((1, outs[-1].shape[-1]), jnp.float32) if mode == "write" else jnp.sum(
+                s_ref[i], axis=0, keepdims=True)
+
+    def step(state, ids, fresh, *_):
+        (rows,), (_, heads, n, p) = ids.shape, state.shape
+        s_spec = pl.BlockSpec((None, hb, n, p), lambda r, j, slots: (slots[r], j, 0, 0))
+        y_spec, y_shape = pl.BlockSpec((None, hb, p), lambda r, j, slots: (r, j, 0)), jax.ShapeDtypeStruct((rows, heads, p), jnp.float32)
+        writes = mode != "read"
+        out = pl.pallas_call(
+            kernel, out_shape=(jax.ShapeDtypeStruct(state.shape, jnp.float32), y_shape) if writes else y_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(rows, heads // hb),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY) if mode == "write" else s_spec],
+                out_specs=[s_spec, y_spec] if writes else y_spec),
+            input_output_aliases={1: 0} if writes else {},
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=64 << 20),
+            interpret=interpret, name=f"stream_{mode}")(ids, state)
+        return (out[1], out[0]) if writes else (out, state)
+
+    return step
+
+
+def scanned(kind: str, step, slots: int, ids, fresh):
+    """``(state, operands) -> (state, outputs [L, R, H, P])``: ``step`` once a layer on the layer's slots."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(state, ops):
+        per_layer, shared = (ops, ()) if kind == "kda" else (ops[:-1], ops[-1:])
+
+        def layer(state, xs):
+            l, *o = xs
+            y, state = step(state, ids + l * slots, fresh, *o, *shared)
+            return state, y
+
+        return jax.lax.scan(layer, state, (jnp.arange(per_layer[0].shape[0], dtype=jnp.int32), *per_layer))
+
+    return run
+
+
+def device_us(trace_dir: str, kernel: str, calls: int) -> tuple[float, float]:
+    """``(the kernel's events, every other device operation)`` of the trace, us a call."""
+    from benchmark import trace_reduce as tr
+
+    trace = tr.load_xplane(trace_dir)
+    by_name = tr.exclusive_by_name(tr.line_events(tr.device_planes(trace)[0], tr.OPS_LINE))
+    own = sum(s for name, s in by_name.items() if name.startswith(kernel))
+    return own / calls * 1e6, (sum(by_name.values()) - own) / calls * 1e6
+
+
+def bench(kind: str, candidates: dict, shape: tuple, *, seed: int, iters: int, timed: bool, peak: float) -> list[dict]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rows, heads, _, n, p, layers, slots = shape
+    state0, ids, fresh, live, ops = operands(kind, shape, seed)
+    untouched = np.setdiff1d(np.arange(layers * slots), (np.asarray(ids)[live][None] + np.arange(layers)[:, None] * slots).ravel())
+    want_state, want_out = jax.jit(scanned(kind, xla_step(kind), slots, ids, fresh))(jnp.array(state0), ops)
+    apart = jax.jit(lambda a, b: jnp.abs(a - b).max())  # fused: no third copy of a 2.4 GB state
+    same_at = jax.jit(lambda a, b, at: jnp.array_equal(a[at], b[at]))
+    table = []
+    for name, (step, note) in candidates.items():
+        row = {"kind": kind, "candidate": name, **note}
+        try:
+            run = jax.jit(scanned(kind, step, slots, ids, fresh), donate_argnums=(0,))
+            state, out = run(jnp.array(state0), ops)
+            if "stream" not in note:
+                row["out_err"], row["state_err"] = float(apart(out, want_out)), float(apart(state, want_state))
+                row["kept"] = bool(same_at(state, state0, untouched))
+            if timed:
+                t0 = time.perf_counter()
+                for _ in range(iters):  # enqueued back to back: each run takes the state the last one gave
+                    state, out = run(state, ops)
+                jax.block_until_ready(out)
+                row["us_call"] = round((time.perf_counter() - t0) / iters / layers * 1e6, 1)
+                trace_dir = ROOT / ".bench_work" / "state_kernel_trace"
+                shutil.rmtree(trace_dir, ignore_errors=True)
+                jax.profiler.start_trace(str(trace_dir))
+                for _ in range(3):
+                    state, out = run(state, ops)
+                jax.block_until_ready(out)
+                jax.profiler.stop_trace()
+                own, other = device_us(str(trace_dir), "stream_" if "stream" in note else KERNEL[kind], 3 * layers)
+                row["kernel_us"], row["other_us"] = round(own, 1), round(other, 1)
+                if own:
+                    moved = (1 if note.get("stream") in ("read", "write") else 2) * 4 * rows * heads * n * p
+                    row["peak_pct"] = round(100 * moved / peak / (own * 1e-6), 1)
+                shutil.rmtree(trace_dir, ignore_errors=True)
+            del state, out
+        except Exception as e:  # a form the compiler refuses is a row of the table, not the end of the call
+            row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+        print(json.dumps(row), flush=True)
+        table.append(row)
+    return table
+
+
+def candidates_of(kind: str, shape: tuple, budgets: list[int], parent: str, extra, interpret: bool) -> dict:
+    """name -> ``(step with the kernels' positional arguments, what the row says of it)``."""
+    from dynamo_tpu.ops import pallas_kda, pallas_mamba
+
+    own = {"kda": pallas_kda.kda_decode_step, "mamba": pallas_mamba.mamba_decode_step}[kind].__wrapped__
+    _, heads, groups, n, p, _, _ = shape
+    out = {"xla": (xla_step(kind), {})}
+
+    def at_budget(budget: int):
+        def step(*args):
+            with state_vmem(budget):  # read while the step is traced
+                return own(*args, interpret=interpret)
+        return step
+
+    for mib in budgets:
+        budget = mib << 20 if not interpret else mib * 4 * 4 * n * p  # the toy: "MiB" counts heads
+        with state_vmem(budget):
+            block = pallas_kda.heads_block(heads, 4 * n * p, heads // groups if groups else 1)
+        served = budget == pallas_kda.STATE_VMEM
+        out[f"served@{mib}"] = (at_budget(budget), {"block": block, "served": served})
+        if served or (interpret and mib == budgets[-1]):
+            for mode in ("copy", "read", "write"):
+                out[f"{mode}@{block}"] = (stream_step(mode, block, interpret), {"block": block, "stream": mode})
+    if parent:
+        mod = load_file(pathlib.Path(parent) / "dynamo_tpu" / "ops" / f"pallas_{kind}.py", f"parent_pallas_{kind}")
+        fn = getattr(mod, KERNEL[kind]).__wrapped__
+        out["parent"] = (functools.partial(fn, interpret=interpret), {"block": getattr(mod, "HEADS_PER_BLOCK", None)})
+    if extra is not None:
+        for name, fn in extra.CANDIDATES.get(kind, {}).items():
+            out[name] = (functools.partial(fn, interpret=interpret), {})
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--kinds", default="kda,mamba")
+    ap.add_argument("--budgets-mib", default="2,4,8,16", help="STATE_VMEM values to set, MiB")
+    ap.add_argument("--parent", default="", help="another tree whose two kernel files are timed as they are")
+    ap.add_argument("--extra", default="", help="a Python file with CANDIDATES = {kind: {name: fn}}")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=4700000101)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+    import jax
+
+    on_chip = jax.default_backend() == "tpu" and not args.rehearse
+    peak = 0.0
+    if on_chip:
+        peaks = json.loads((ROOT / "benchmark" / "peaks.json").read_text())
+        peak = peaks[jax.devices()[0].device_kind]["hbm_bytes_per_s"]  # a device that is not in the table is an error
+    extra = load_file(pathlib.Path(args.extra), "state_kernel_bench_extra") if args.extra else None
+    budgets = [int(v) for v in args.budgets_mib.split(",")] if on_chip else [1, 2, 4]
+    table = []
+    for kind in args.kinds.split(","):
+        shape = (SHAPES if on_chip else TOY)[kind]
+        cands = candidates_of(kind, shape, budgets, args.parent, extra, interpret=not on_chip)
+        table += bench(kind, cands, shape, seed=args.seed, iters=args.iters if on_chip else 1, timed=on_chip, peak=peak)
+    sound = [r for r in table if "error" not in r]
+    ok = all(r["kept"] and r["out_err"] < 1e-4 and r["state_err"] < 1e-4 for r in sound if "stream" not in r)
+    verdict = {"state_kernel_bench": jax.devices()[0].device_kind if on_chip else "rehearsal: no time is a device time",
+               "rows": len(table), "refused": len(table) - len(sound), "every_candidate_sound": ok}
+    print(json.dumps(verdict))
+    if on_chip:
+        out = ROOT / "chiprun_out" / "state_kernel_bench.json"
+        out.parent.mkdir(exist_ok=True)
+        out.write_text(json.dumps({**verdict, "table": table}, indent=1))
+    if not ok:
+        return 1
+    return 0 if on_chip else 3
+
+
+if __name__ == "__main__":
+    sys.exit(main())
