@@ -999,14 +999,19 @@ class ModelRunner:
             return "ring"
         return self.attn_impl
 
-    def _attn_dispatch(self, padded: StepBatch, impl: str | None, *, verify: bool = False) -> tuple[str, str]:
+    def _attn_dispatch(self, padded: StepBatch, impl: str | None, *, verify: bool = False,
+                       split: bool = False) -> tuple[str, str]:
         """(phase, path) the attention layer will take for this dispatch.
 
         A host-side mirror of the models/* routing predicates (pure shape
         math — no tracing), so every engine step can record whether its
         attention ran on a Pallas kernel ("pallas"), the XLA gather
         formulation ("fallback"), or the sequence-parallel ring path
-        ("ring") without touching the jitted program."""
+        ("ring") without touching the jitted program. A model with
+        recurrent layers also says "fallback" for a step whose decode rows
+        (every row of a one-token step, the decode slots of a ``split``
+        one) leave the conv kernel or the state kernel for the gathered XLA
+        step; a chunk row's chunked recurrence in XLA is the served path."""
         t = int(padded.tokens.shape[1])
         phase = "verify" if (verify and t > 1) else ("decode" if t == 1 else "prefill")
         # (other models count nothing (0, 0); a model with a mixer counts its one kind of GQA layer)
@@ -1036,6 +1041,12 @@ class ModelRunner:
                 self.cfg.num_heads, self.cfg.head_dim, self.k_cache.shape[-1],
                 t_q, interpret=interp if phase != "prefill" else False,
             )
+        if self.recurrent and (t == 1 or split):
+            from dynamo_tpu.ops import pallas_conv, pallas_kda, pallas_mamba
+
+            state, conv = self.state  # [layers * slots, heads, key | state, value | channels], [.., taps - 1, rows, lanes]
+            ok = ok and pallas_conv.supported(1, *conv.shape[2:]) and (
+                pallas_mamba if self.cfg.ssm_heads else pallas_kda).supported(*state.shape[2:])
         return phase, "pallas" if ok else "fallback"
 
     def _count_kv(self, report: DispatchReport) -> None:
@@ -1074,7 +1085,7 @@ class ModelRunner:
         report = self._report
         if report is None:
             report = self._report = DispatchReport(moe_path=self.moe_path)
-        report.attn_phase, report.attn_path = self._attn_dispatch(padded, impl, verify=verify)
+        report.attn_phase, report.attn_path = self._attn_dispatch(padded, impl, verify=verify, split=layout[0] == SPLIT)
         report.layout, report.step_tokens = layout
         report.router_select = router_select(report.step_tokens, self._router_outputs, self.cfg.num_experts_per_token)
         if padded.state_slots is not None:

@@ -330,11 +330,18 @@ class ModelConfig:
         ``key x value`` matrix a head and the last ``taps - 1`` inputs of the
         q, k and v streams. Mamba-2: a ``state x head channels`` matrix a head
         (state-major, the transposition of the published cache's) and the last
-        ``taps - 1`` inputs of x, B and C."""
+        ``taps - 1`` inputs of x, B and C. The conv state's channels lie in
+        rows of 128 lanes, ``(taps - 1, channels / 128, 128)`` (a width that is
+        no multiple of 128: one row), so that the device's tiles hold a slot's
+        inputs whole and the buffer needs no other layout than the one it is
+        allocated in (``models/kda.slot_conv``)."""
         if self.ssm_heads:
-            return ((self.ssm_heads, self.ssm_state_size, self.ssm_head_dim),
-                    (self.ssm_conv_size - 1, self.ssm_conv_dim))
-        return ((self.num_heads, self.head_dim, self.head_dim), (self.kda_conv_size - 1, 3 * self.q_dim))
+            state, taps, channels = ((self.ssm_heads, self.ssm_state_size, self.ssm_head_dim), self.ssm_conv_size,
+                                     self.ssm_conv_dim)
+        else:
+            state, taps, channels = (self.num_heads, self.head_dim, self.head_dim), self.kda_conv_size, 3 * self.q_dim
+        lanes = 128 if channels % 128 == 0 else channels
+        return state, (taps - 1, channels // lanes, lanes)
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state one sequence holds over all recurrent

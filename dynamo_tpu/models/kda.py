@@ -29,7 +29,12 @@ What a step does with a row's slot:
   sequence is given is zeroed by the sequence's own first chunk, not by a
   pass of its own;
 - a padding token (``valid`` false: it writes the null page) leaves the state
-  as it is (``beta = 0``, ``g = 0``) and does not enter the conv state.
+  as it is (``beta = 0``, ``g = 0``) and does not enter the conv state;
+- the conv state of either kind of row (:func:`slot_conv`) goes on a TPU
+  through the Pallas kernel ``ops/pallas_conv.slot_conv_step`` (the slot's
+  ``taps - 1`` inputs read once, the conv and SiLU done on them and the row's
+  tokens, the last inputs written back in place), elsewhere through
+  :func:`causal_conv` on gathered rows.
 
 The state buffers are flat over ``(KDA layer, slot)`` like the paged cache is
 over ``(layer, page)``: a layer addresses ``layer * slots + slot``. Slot 0 is
@@ -80,9 +85,13 @@ def init_state(cfg: ModelConfig, slots: int, dtype=None) -> tuple[jnp.ndarray, j
     """The two state buffers of a model with recurrent layers, zeros, flat
     over ``(recurrent layer, slot)``; ``slots`` counts the null slot. The
     shapes are the model's (``cfg.state_shapes``). KDA: ``(state f32[layers *
-    slots, heads, key, value], conv [layers * slots, taps - 1, 3 * q_dim])``;
-    a Mamba-2 mixer (``models/mamba2.py``): ``(f32[layers * slots, heads,
-    state, head channels], [layers * slots, taps - 1, conv channels])``."""
+    slots, heads, key, value], conv [layers * slots, taps - 1, 3 * q_dim / 128,
+    128])``; a Mamba-2 mixer (``models/mamba2.py``): ``(f32[layers * slots,
+    heads, state, head channels], [layers * slots, taps - 1, conv channels /
+    128, 128])``. The conv buffer's channels lie in rows of 128 lanes, so that
+    a slot's inputs of one layer are one run of whole tiles in the layout the
+    buffer is allocated in: a step program takes it, updates the rows' slots
+    where they lie and hands it back (:func:`slot_conv`)."""
     n = cfg.recurrent_layers * slots
     state, conv = cfg.state_shapes()
     return jnp.zeros((n, *state), jnp.float32), jnp.zeros((n, *conv), dtype or jnp.dtype(cfg.dtype))
@@ -146,6 +155,39 @@ def causal_conv(x, prev, filt, n_valid, bias=None):
     return jax.nn.silu(y), carried
 
 
+def slot_conv(conv, ids, fresh, x, filt, n_valid, bias=None, *, impl: str | None):
+    """:func:`causal_conv` of rows ``x f32[R, T, W]`` through the slots ``ids``
+    of ``conv [slots, taps - 1, W / lanes, lanes]``; ``fresh`` rows start from
+    zeros, a row's first ``n_valid`` tokens enter its slot. Returns ``(y [R, T,
+    W / lanes, lanes], conv)``: the output in the buffer's rows of lanes, for
+    :func:`conv_heads` to split. Each row's slot moves once each way: through
+    ``ops/pallas_conv.slot_conv_step`` where it tiles the shape, else by one
+    gather and one scatter of the rows' slots."""
+    r, t, w = x.shape
+    tile = conv.shape[1:]
+    lay = lambda z: None if z is None else z.reshape(*z.shape[:-1], *tile[1:])  # noqa: E731
+    if impl == "pallas":
+        from dynamo_tpu.ops import pallas_conv
+
+        if pallas_conv.supported(t, *tile[1:]):
+            return pallas_conv.slot_conv_step(conv, ids, fresh, n_valid, lay(x), lay(filt), lay(bias),
+                                              interpret=pallas_conv.interpret_mode())
+    prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv[ids].reshape(r, -1, w))
+    y, carried = causal_conv(x, prev, filt, n_valid, bias)
+    return lay(y), conv.at[ids].set(carried.astype(conv.dtype).reshape(r, *tile))
+
+
+def conv_heads(y, first: int, heads: int, dim: int):
+    """Channels ``[first, first + heads * dim)`` of :func:`slot_conv`'s output
+    ``y [R, T, rows, lanes]`` as ``[R, T, heads, dim]``: the rows that hold
+    them, sliced where they lie (a head of 128 channels *is* a row), where the
+    channels are whole rows."""
+    r, t, _, lanes = y.shape
+    if first % lanes == 0 and (heads * dim) % lanes == 0:
+        return y[:, :, first // lanes: (first + heads * dim) // lanes].reshape(r, t, heads, dim)
+    return y.reshape(r, t, -1)[..., first: first + heads * dim].reshape(r, t, heads, dim)
+
+
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
@@ -180,7 +222,7 @@ def kda_attention(
     positions: jnp.ndarray,  # i32[B, T]
     valid: jnp.ndarray,  # bool[B, T]: the token is real (it writes a live cache slot)
     state: jnp.ndarray,  # f32[layers * slots, H, K, V]
-    conv: jnp.ndarray,  # [layers * slots, taps - 1, 3 * q_dim]
+    conv: jnp.ndarray,  # [layers * slots, taps - 1, 3 * q_dim / 128, 128]
     slot_ids: jnp.ndarray,  # i32[rows]: this layer's slot of each row (layer * slots + slot)
     *,
     impl: str | None = None,
@@ -228,10 +270,8 @@ def kda_attention(
         xr, ok = shape(x), shape(valid)
         fresh = shape(positions)[:, 0] == 0
         with jax.named_scope("kda.conv"):
-            prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv[ids])
-            y, carried = causal_conv(xr, prev, filt, ok.sum(axis=1, dtype=jnp.int32))
-            conv = conv.at[ids].set(carried.astype(conv.dtype))
-            q, k, v = (y[..., i * heads * hd: (i + 1) * heads * hd].reshape(n, width, heads, hd) for i in range(3))
+            y, conv = slot_conv(conv, ids, fresh, xr, filt, ok.sum(axis=1, dtype=jnp.int32), impl=impl)
+            q, k, v = (conv_heads(y, i * heads * hd, heads, hd) for i in range(3))
             q, k = _l2norm(q) * hd**-0.5, _l2norm(k)
         with jax.named_scope("kda.state"):
             o, state = _rows_update(state, ids, fresh, q, k, v, shape(g).reshape(n, width, heads, hd), shape(beta),

@@ -29,6 +29,9 @@ What a step does with a row's slot, exactly as for KDA:
   ``dt x``, the carried state in with its decay, the chunk's last state out,
   in plain ``jax.numpy``. Decays between two tokens are ``exp`` of a
   *difference* of cumulated log-decays, so nothing overflows;
+- the conv state of either kind of row goes through ``models/kda.slot_conv``:
+  on a TPU the kernel ``ops/pallas_conv.slot_conv_step``, the bias and SiLU in
+  it, elsewhere ``causal_conv`` on gathered rows;
 - a row whose first position is 0 starts from a zero state and a zero conv
   state: the sequence's own first chunk zeroes the slot it was given;
 - a padding token (``valid`` false) leaves the state as it is (``dt = 0``:
@@ -44,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.models.kda import CHUNK_ROWS_AT_ONCE, causal_conv
+from dynamo_tpu.models.kda import CHUNK_ROWS_AT_ONCE, conv_heads, slot_conv
 
 Params = dict
 HI = jax.lax.Precision.HIGHEST
@@ -145,7 +148,7 @@ def mamba_mixer(
     positions: jnp.ndarray,  # i32[B, T]
     valid: jnp.ndarray,  # bool[B, T]: the token is real (it writes a live cache slot)
     state: jnp.ndarray,  # f32[layers * slots, H, N, P]
-    conv: jnp.ndarray,  # [layers * slots, taps - 1, conv_dim]
+    conv: jnp.ndarray,  # [layers * slots, taps - 1, conv_dim / 128, 128]
     slot_ids: jnp.ndarray,  # i32[rows]: this layer's slot of each row (layer * slots + slot)
     *,
     impl: str | None = None,
@@ -180,12 +183,10 @@ def mamba_mixer(
         ok = shape(valid)
         fresh = shape(positions)[:, 0] == 0
         with jax.named_scope("attn.ssm.conv"):
-            prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv[ids])
-            y, carried = causal_conv(shape(xbc), prev, lp["ssm_conv"], ok.sum(axis=1, dtype=jnp.int32),
-                                     bias=lp["ssm_conv_bias"])
-            conv = conv.at[ids].set(carried.astype(conv.dtype))
-            x = y[..., :inner].reshape(r, width, g, hg, p)
-            b, c = (y[..., inner + i * g * n: inner + (i + 1) * g * n].reshape(r, width, g, n) for i in range(2))
+            y, conv = slot_conv(conv, ids, fresh, shape(xbc), lp["ssm_conv"], ok.sum(axis=1, dtype=jnp.int32),
+                                bias=lp["ssm_conv_bias"], impl=impl)
+            x = conv_heads(y, 0, heads, p).reshape(r, width, g, hg, p)
+            b, c = (conv_heads(y, inner + i * g * n, g, n) for i in range(2))
         with jax.named_scope("attn.ssm.state"):
             y, state = _rows_update(state, ids, fresh, x, b, c, shape(dt).reshape(r, width, g, hg), a, impl=impl)
             y = y + lp["ssm_d"].astype(f32).reshape(g, hg)[..., None] * x

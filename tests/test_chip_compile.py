@@ -693,6 +693,48 @@ def test_kda_decode_kernel_compiles(sds, monkeypatch, budget_mib, block):
     assert mem.alias_size_in_bytes >= 15 * 65 * 32 * 128 * 128 * 4 and mem.temp_size_in_bytes < 1 << 20
 
 
+def _conv_buffer_stays(text: str, conv) -> None:
+    """The step program takes the conv buffer, hands it to ``slot_conv_step``
+    where it lies (pinned to HBM) and hands it back: no instruction makes a
+    buffer of its shape (a ``copy`` to another layout or memory, a scatter, a
+    ``dynamic-update-slice``), no fusion slices or gathers from it, and (the
+    callers count them) no loop is left but the layer loops (the parent's gather and scatter by slot id
+    were a ``while`` over the 64 rows each; PERF.md, PR 48)."""
+    import re
+
+    shape = re.escape(f"bf16[{','.join(map(str, conv.shape))}]")
+    made = set(re.findall(rf"= {shape}\S* ([\w-]+)\(", text))
+    assert made <= {"parameter", "get-tuple-element"}, made
+    assert not re.search(rf"^%fused_computation\S* \(.*{shape}", text, re.M)  # no fusion takes it: nothing gathers or slices from it
+    assert not re.search(rf"{shape}\S*, u32\[\]\S*\) copy-start\(", text)  # nor moves it to another memory
+    assert "slot_conv_step" in text and '"output_memory_colors":["0","-1"]' in text
+
+
+@pytest.mark.parametrize("slots, c, bias, rows, tokens", [
+    (15 * 65, 96, False, 64, 1), (9 * 65, 40, True, 64, 1), (15 * 65, 96, False, 1, 64), (9 * 65, 40, True, 2, 64),
+], ids=["ling-decode", "falcon-h1-decode", "ling-chunk", "falcon-h1-chunks"])
+def test_slot_conv_kernel_compiles(sds, slots, c, bias, rows, tokens):
+    """The conv rows' step at both cells' widths (Ling-3.0-flash's 12,288
+    channels in 96 rows of lanes, no bias; Falcon-H1-34B's 5,120 in 40, whose
+    last bfloat16 tile is half full, with one): 64 one-token rows and a
+    64-token chunk, the buffer aliased to the kernel's output, pinned to HBM
+    and taken in the layout it is allocated in (row-major, ``(8, 128)(2, 1)``
+    tiles over the last two axes): nothing but the kernel in the program."""
+    from dynamo_tpu.ops import pallas_conv
+
+    assert pallas_conv.supported(tokens, c, 128) and not pallas_conv.supported(128, 96, 128)
+    args = [sds((slots, 3, c, 128), jnp.bfloat16), sds((rows,), jnp.int32), sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
+            sds((rows, tokens, c, 128), jnp.float32), sds((4, c, 128), jnp.float32)] + ([sds((c, 128), jnp.float32)] if bias else [])
+    compiled = jax.jit(lambda *a: pallas_conv.slot_conv_step.__wrapped__(*a), donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "slot_conv_step" in text and f"bf16[{slots},3,{c},128]{{3,2,1,0:T(8,128)(2,1)}} parameter(0)" in text
+    assert '"output_memory_colors":["0","-1"]' in text and " fusion(" not in text and "= bf16" not in text.replace(
+        f"= bf16[{slots},3,{c},128]{{3,2,1,0:T(8,128)(2,1)}} parameter(0)", "").replace(
+        f"= bf16[{slots},3,{c},128]{{3,2,1,0:T(8,128)(2,1)}} get-tuple-element(", "")  # nothing else makes a bfloat16 array
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= slots * 3 * c * 128 * 2 and mem.temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
 def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     """reason-saturated's largest steps at Ling-3.0-flash's widths, one period
@@ -718,7 +760,7 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     params = _served_params(sds, cfg)
     k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
     state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
-    assert state.shape == (5 * 65, 32, 128, 128) and conv.shape == (5 * 65, 3, 3 * 4096)
+    assert state.shape == (5 * 65, 32, 128, 128) and conv.shape == (5 * 65, 3, 96, 128)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     if split is None:
         toks, slots = (64, 1), 64
@@ -737,6 +779,8 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
     # The kernel takes q, k, g as the projections leave them: no stack of four, no column array laid out for it.
     assert "f32[64,4,4,8,128]" not in text and "f32[64,4,128,32]" not in text and "f32[64,1,128,128]" not in text
     assert "ragged-dot" not in text and "ragged_dot" not in text
+    _conv_buffer_stays(text, conv)
+    assert text.count(" while(") == 2  # the dense layers and the period's KDA layers (one period is no loop): none over rows
     shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
     assert shapes[-3:] == [(5,), state.shape, conv.shape]  # HELD_COUNTS, then the state buffers
     mem = compiled.memory_analysis()
@@ -794,7 +838,7 @@ def test_parallel_mixer_step_falcon_h1_largest_corners(sds, split):
     assert params["layers"]["w_ssm_in"].dtype == jnp.bfloat16 and params["layers"]["wq"]["qw"].dtype == jnp.int8
     k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
     state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
-    assert state.shape == (2 * 65, 32, 256, 128) and conv.shape == (2 * 65, 3, 5120) and conv.dtype == jnp.bfloat16
+    assert state.shape == (2 * 65, 32, 256, 128) and conv.shape == (2 * 65, 3, 40, 128) and conv.dtype == jnp.bfloat16
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     if split is None:
         toks, slots = (64, 1), 64
@@ -812,6 +856,8 @@ def test_parallel_mixer_step_falcon_h1_largest_corners(sds, split):
     assert "mamba_decode_step" in text and "kda_decode_step" not in text
     assert "f32[64,2,256,2]" not in text  # B and C reach the kernel as the conv leaves them, not as padded columns
     assert ("paged_prefill_attention" if split else "paged_decode_attention") in text
+    _conv_buffer_stays(text, conv)
+    assert text.count(" while(") == 1  # the layer scan: no loop over rows
     shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
     assert shapes[-2:] == [state.shape, conv.shape] and len(shapes) == 5  # logits, the caches, the state buffers
     mem = compiled.memory_analysis()

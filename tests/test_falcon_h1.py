@@ -52,7 +52,7 @@ def test_from_hf_reads_the_published_keys():
     assert cfg.ssm_multipliers == (0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738)
     # A layer holds pages and a slot: 2,048 B of K/V a token, a 4,194,304 B state and a 30,720 B conv state.
     assert cfg.kv_bytes_per_token() == 72 * 2048 and cfg.state_bytes_per_slot() == 72 * (4_194_304 + 30_720)
-    assert cfg.state_shapes() == ((32, 256, 128), (3, 5120))
+    assert cfg.state_shapes() == ((32, 256, 128), (3, 40, 128))  # the conv's 5,120 channels in rows of 128 lanes
     layer = (cfg.param_count() - 2 * 261120 * 5120 - 5120) // 72
     assert layer == 31_457_280 + 330_301_440 + 68_351_072 + 2 * 5120 and cfg.param_count() == pytest.approx(33.6e9, rel=2e-3)
     # One of eight equal pipeline stages: the stage keys are read and checked.

@@ -365,7 +365,7 @@ def test_slots_and_pages_are_sized_from_the_model(kind):
     ``state_bytes_per_slot`` says."""
     cfg, params, _ = _model(kind)
     runner = ModelRunner(cfg, params, num_pages=8, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
-    want = {"kda": (6, 4, 2, (4, 16, 16), (3, 3 * 64)), "mamba2": (3, 3, 3, (4, 8, 16), (3, 64 + 2 * 2 * 8))}[kind]
+    want = {"kda": (6, 4, 2, (4, 16, 16), (3, 1, 3 * 64)), "mamba2": (3, 3, 3, (4, 8, 16), (3, 1, 64 + 2 * 2 * 8))}[kind]
     state, conv = runner.state
     assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, *cfg.state_shapes()) == want
     assert runner.recurrent and runner.state_slots == 3 and runner.k_cache.shape[0] == cfg.cache_layers

@@ -145,7 +145,7 @@ def test_the_mixer_takes_the_kernel_where_the_platform_runs_it(monkeypatch):
     args = dict(positions=jnp.asarray([[9], [0], [0]]), valid=jnp.asarray([[True], [True], [False]]), slot_ids=jnp.asarray([4, 2, 0]))
     before = np.asarray(state)
     want = mamba2.mamba_mixer(lp, cfg, h, state=state, conv=conv, impl="reference", **args)
-    got = mamba2.mamba_mixer(lp, cfg, h, state=jnp.array(before), conv=conv, impl="pallas", **args)  # the kernel donates it
+    got = mamba2.mamba_mixer(lp, cfg, h, state=jnp.array(before), conv=jnp.array(conv), impl="pallas", **args)  # the kernels donate them
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-5)
     assert np.array_equal(np.asarray(got[1])[[1, 3]], before[[1, 3]])  # slots no row names
@@ -154,7 +154,7 @@ def test_the_mixer_takes_the_kernel_where_the_platform_runs_it(monkeypatch):
     flat = dict(positions=jnp.asarray([[9, 0, 0, 5, 6, 7, 0]]), valid=jnp.asarray([[True, True, False, True, True, True, False]]),
                 slot_ids=jnp.asarray([4, 2, 0, 1]), split=(3, 1, 4))
     out, state2, conv2 = mamba2.mamba_mixer(lp, cfg, jnp.concatenate([h.reshape(1, 3, 64), chunk], axis=1),
-                                            state=jnp.array(before), conv=conv, impl="pallas", **flat)
+                                            state=jnp.array(before), conv=jnp.array(conv), impl="pallas", **flat)
     np.testing.assert_allclose(out[0, :3], want[0][:, 0], atol=1e-5)
     alone = mamba2.mamba_mixer(lp, cfg, chunk, state=state, conv=conv, impl="reference", positions=jnp.asarray([[5, 6, 7, 0]]),
                                valid=jnp.asarray([[True, True, True, False]]), slot_ids=jnp.asarray([1]))
@@ -163,13 +163,17 @@ def test_the_mixer_takes_the_kernel_where_the_platform_runs_it(monkeypatch):
     np.testing.assert_allclose(conv2[1], alone[2][1], atol=1e-6)
 
 
-@pytest.mark.parametrize("kind", ["kda", "mamba"])
+@pytest.mark.parametrize("kind", ["kda", "mamba", "conv.kda", "conv.mamba"])
 def test_the_bench_rehearses_every_candidate_against_the_plain_step(kind):
     """``tools/state_kernel_bench.py`` at its toy shapes under the interpreter:
     the served kernel at two budgets, the bare copy / read / write of the
     same blocks and the XLA step, each scanned over the layers on one donated
     state: every kernel row equal to the plain step, the padding row's slot
-    and the unnamed slots bit for bit, and no time printed off the chip."""
+    and the unnamed slots bit for bit, and no time printed off the chip. The
+    conv rows (``--kinds conv``, PR 48): the flat buffer's gather and scatter
+    they are checked against, the same on the tiled buffer, the served kernel
+    and the bare copy of its blocks; the carried inputs are copies, so the
+    buffers are equal bit for bit."""
     import importlib.util
     import pathlib
 
@@ -180,6 +184,11 @@ def test_the_bench_rehearses_every_candidate_against_the_plain_step(kind):
     shape = tool.TOY[kind]
     rows = tool.bench(kind, tool.candidates_of(kind, shape, [2, 4], "", None, interpret=True), shape,
                       seed=7, iters=1, timed=False, peak=0.0)
+    if kind.startswith("conv"):
+        assert [r["candidate"] for r in rows] == ["flat", "xla@tiled", "served", "copy@row"]
+        assert all(r["kept"] and r["out_err"] < 1e-5 and r["state_err"] == 0 for r in rows[:3]), rows
+        assert rows[2]["served"] and not any("us_call" in r or "error" in r for r in rows), rows
+        return
     assert [r["candidate"] for r in rows] == ["xla", "served@2", "served@4", "copy@4", "read@4", "write@4"]
     kernels = [r for r in rows if "stream" not in r]
     assert all(r["kept"] and r["out_err"] < 1e-5 and r["state_err"] < 1e-5 for r in kernels), rows

@@ -1,11 +1,12 @@
 #!/usr/bin/env python3
-"""The two slot-state decode kernels alone, on the chip: one call of
+"""The slot kernels alone, on the chip: one call of
 ``ops/pallas_kda.kda_decode_step`` and of ``ops/pallas_mamba.mamba_decode_step``
 at the shapes their cells serve, for each block of heads the VMEM budget can
-choose and each operand form on offer, the wrapper's operations inside the
-timed function.
+choose and each operand form on offer, and of the conv rows' step
+(``models/kda.slot_conv``) in each form on offer, the wrapper's operations
+inside the timed function.
 
-    python3 tools/state_kernel_bench.py [--kinds kda,mamba] [--budgets-mib 2,4,8,16] [--parent DIR] [--extra FILE] [--iters 20]
+    python3 tools/state_kernel_bench.py [--kinds kda,mamba,conv] [--budgets-mib 2,4,8,16] [--parent DIR] [--extra FILE] [--iters 20]
 
 Shapes: ``kda`` is Ling-3.0-flash's layer (64 rows, 32 heads of 128 x 128, 15
 layers of 65 slots), ``mamba`` Falcon-H1-34B's mixer (64 rows, 32 heads of
@@ -34,6 +35,22 @@ back), which is also what every candidate is checked against on the chip:
 whether every slot no live row names, the padding row's among them, is still
 bit for bit what it was.
 
+``conv`` is the conv rows of both cells (``conv.kda``: Ling's ``[975, 3,
+12288]`` bfloat16 buffer, no bias, 15 layers; ``conv.mamba``: Falcon-H1's
+``[585, 3, 5120]`` with a bias, 9 layers): 64 one-token rows on the same
+permuted slots, a fresh row and a padding row, the layer's ``x f32[R, W]`` as
+the projections leave it, its filter and bias as the parameters hold them.
+Candidates: ``flat`` is the form the buffer had before PR 48 (``[slots, taps -
+1, W]``: ``conv[ids]``, ``causal_conv``, ``conv.at[ids].set``), which every
+other candidate is checked against (``state_err`` 0: the carried inputs are
+copies); ``xla@tiled`` the same gather and scatter on the buffer as it is
+allocated now (``[slots, taps - 1, W / 128, 128]``); ``served`` the tree's
+``slot_conv`` (the kernel ``slot_conv_step`` and what the wrapper lays out for
+it); ``copy@row`` a bare copy of the same blocks (a row's slot copied where it
+lies, its ``x`` copied to ``y``) through the same ``BlockSpec`` pipeline: the
+ceiling. ``peak_pct`` counts the rows' conv state and ``x`` read and the state
+and ``y`` written.
+
 Per candidate: ``us_call`` by the host's clock over ``--iters`` runs of the
 scan enqueued back to back (a run is 9 or 15 calls and takes the state the
 run before gave) and, from a traced run, ``kernel_us`` (the device events
@@ -54,6 +71,7 @@ import contextlib
 import functools
 import importlib.util
 import json
+import os
 import pathlib
 import shutil
 import sys
@@ -65,7 +83,10 @@ sys.path.insert(0, str(ROOT))
 # kind: rows, heads, groups (0: no groups), state rows (key / N), lanes (value / P), layers, slots a layer
 SHAPES = {"kda": (64, 32, 0, 128, 128, 15, 65), "mamba": (64, 32, 2, 256, 128, 9, 65)}
 TOY = {"kda": (4, 4, 0, 16, 128, 2, 6), "mamba": (4, 4, 2, 8, 128, 2, 6)}
-KERNEL = {"kda": "kda_decode_step", "mamba": "mamba_decode_step"}
+KERNEL = {"kda": "kda_decode_step", "mamba": "mamba_decode_step", "conv.kda": "slot_conv_step", "conv.mamba": "slot_conv_step"}
+# the conv rows: rows, channels, bias, taps, layers, slots a layer
+SHAPES.update({"conv.kda": (64, 12288, False, 4, 15, 65), "conv.mamba": (64, 5120, True, 4, 9, 65)})
+TOY.update({"conv.kda": (4, 3 * 128, False, 4, 2, 6), "conv.mamba": (4, 5 * 128, True, 4, 2, 6)})
 
 
 @contextlib.contextmanager
@@ -78,6 +99,20 @@ def state_vmem(budget: int):
         yield
     finally:
         pallas_kda.STATE_VMEM = was
+
+
+@contextlib.contextmanager
+def interpreted(on: bool):
+    """``DYNAMO_PALLAS_INTERPRET`` (``ops/pallas_paged.interpret_mode``) set for what is traced inside: the rehearsal's kernels."""
+    was = os.environ.get("DYNAMO_PALLAS_INTERPRET")
+    os.environ["DYNAMO_PALLAS_INTERPRET"] = "1" if on else ""
+    try:
+        yield
+    finally:
+        if was is None:
+            del os.environ["DYNAMO_PALLAS_INTERPRET"]
+        else:
+            os.environ["DYNAMO_PALLAS_INTERPRET"] = was
 
 
 def load_file(path: pathlib.Path, name: str):
@@ -94,7 +129,7 @@ def operands(kind: str, shape: tuple, seed: int):
     import jax.numpy as jnp
     import numpy as np
 
-    rows, heads, groups, n, p, layers, slots = shape
+    rows, layers, slots = shape[0], shape[-2], shape[-1]
     rng = np.random.default_rng(seed)
     f = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
     ids = rng.permutation(np.arange(1, slots))[:rows]
@@ -103,6 +138,13 @@ def operands(kind: str, shape: tuple, seed: int):
     live[2] = False
     fresh = np.zeros(rows, bool)
     fresh[1] = True
+    if kind.startswith("conv"):  # the flat buffer; x as the projections leave it, filter and bias as the parameters hold them
+        _, width, bias, taps = shape[:4]
+        bf = lambda x: jnp.asarray(x, jnp.bfloat16)  # noqa: E731
+        ops = (f(rng.standard_normal((layers, rows, width))), bf(0.5 * rng.standard_normal((layers, taps, width))),
+               bf(0.3 * rng.standard_normal((layers, width))) if bias else None, jnp.asarray(live, jnp.int32))
+        return bf(rng.standard_normal((layers * slots, taps - 1, width))), jnp.asarray(ids, jnp.int32), jnp.asarray(fresh), live, ops
+    _, heads, groups, n, p = shape[:5]
     state = f(rng.standard_normal((layers * slots, heads, n, p)))
     mask = live[None, :, None]
     if kind == "kda":
@@ -136,7 +178,54 @@ def xla_step(kind: str):
         y, s = mamba2.recurrent_step(s0, x.reshape(r, gr, h // gr, p), b, c, dt.reshape(r, gr, h // gr), a.reshape(gr, h // gr))
         return y.reshape(r, h, p), state.at[ids].set(s.reshape(r, h, n, p))
 
-    return kda_step if kind == "kda" else mamba_step
+    def conv_step(conv, ids, fresh, x, filt, bias, n_valid):  # the buffer flat, as it lay before PR 48
+        prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv[ids])
+        y, carried = kda.causal_conv(x[:, None], prev, filt, n_valid, bias)
+        return y[:, 0], conv.at[ids].set(carried.astype(conv.dtype))
+
+    return conv_step if kind.startswith("conv") else kda_step if kind == "kda" else mamba_step
+
+
+def conv_candidates(interpret: bool) -> dict:
+    """The conv rows' forms on the buffer as it is allocated (``tiled``: the
+    bench lays the flat buffer out for them, outside the timed function)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dynamo_tpu.models import kda
+
+    def through(impl):
+        def step(conv, ids, fresh, x, filt, bias, n_valid):
+            y, conv = kda.slot_conv(conv, ids, fresh, x[:, None], filt, n_valid, bias, impl=impl)
+            return y.reshape(x.shape), conv
+        return step
+
+    def served(*args):
+        with interpreted(interpret):  # the model's wrapper reads the switch while the step is traced
+            return through("pallas")(*args)
+
+    def copy_rows(conv, ids, fresh, x, *_):
+        """No conv: a row's slot copied where it lies and its ``x`` to ``y``, in ``slot_conv_step``'s blocks."""
+        tile, rows = conv.shape[1:], x.shape[0]
+        c_spec = pl.BlockSpec((None, *tile), lambda r, slots: (slots[r], 0, 0, 0))
+        x_spec = pl.BlockSpec((None, *tile[1:]), lambda r, slots: (r, 0, 0))
+
+        def kernel(slots_ref, x_ref, c_ref, c_out_ref, y_ref):
+            c_out_ref[...] = c_ref[...]
+            y_ref[...] = x_ref[...]
+
+        conv, y = pl.pallas_call(
+            kernel, out_shape=(jax.ShapeDtypeStruct(conv.shape, conv.dtype), jax.ShapeDtypeStruct((rows, *tile[1:]), jnp.float32)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, grid=(rows,), in_specs=[x_spec, c_spec],
+                                                   out_specs=[c_spec, x_spec]),
+            input_output_aliases={2: 0}, compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+            interpret=interpret, name="stream_copy")(ids, x.reshape(rows, *tile[1:]), conv)
+        return y.reshape(x.shape), conv
+
+    return {"xla@tiled": (through("reference"), {"tiled": True}), "served": (served, {"tiled": True, "served": True}),
+            "copy@row": (copy_rows, {"tiled": True, "stream": "copy"})}
 
 
 def stream_step(mode: str, hb: int, interpret: bool):
@@ -181,7 +270,7 @@ def scanned(kind: str, step, slots: int, ids, fresh):
     import jax.numpy as jnp
 
     def run(state, ops):
-        per_layer, shared = (ops, ()) if kind == "kda" else (ops[:-1], ops[-1:])
+        per_layer, shared = (ops, ()) if kind == "kda" else (ops[:-1], ops[-1:])  # Mamba-2's A, the conv rows' valid tokens
 
         def layer(state, xs):
             l, *o = xs
@@ -208,21 +297,28 @@ def bench(kind: str, candidates: dict, shape: tuple, *, seed: int, iters: int, t
     import jax.numpy as jnp
     import numpy as np
 
-    rows, heads, _, n, p, layers, slots = shape
+    rows, layers, slots = shape[0], shape[-2], shape[-1]
+    if kind.startswith("conv"):  # the rows' conv state and x read, the state and y written
+        moved = rows * shape[1] * (2 * (shape[3] - 1) * 2 + 2 * 4)
+    else:  # one read and one write of every row's float32 state
+        moved = 2 * 4 * rows * shape[1] * shape[3] * shape[4]
     state0, ids, fresh, live, ops = operands(kind, shape, seed)
     untouched = np.setdiff1d(np.arange(layers * slots), (np.asarray(ids)[live][None] + np.arange(layers)[:, None] * slots).ravel())
     want_state, want_out = jax.jit(scanned(kind, xla_step(kind), slots, ids, fresh))(jnp.array(state0), ops)
-    apart = jax.jit(lambda a, b: jnp.abs(a - b).max())  # fused: no third copy of a 2.4 GB state
+    apart = jax.jit(lambda a, b: jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())  # fused: no third copy of a 2.4 GB state
     same_at = jax.jit(lambda a, b, at: jnp.array_equal(a[at], b[at]))
     table = []
     for name, (step, note) in candidates.items():
         row = {"kind": kind, "candidate": name, **note}
         try:
             run = jax.jit(scanned(kind, step, slots, ids, fresh), donate_argnums=(0,))
-            state, out = run(jnp.array(state0), ops)
+            # A conv candidate on the buffer as it is allocated: channels in rows of 128 lanes, laid out outside the timed function.
+            tiled = (lambda z: z.reshape(*z.shape[:2], -1, 128)) if note.get("tiled") else (lambda z: z)
+            state, out = run(tiled(jnp.array(state0)), ops)
             if "stream" not in note:
-                row["out_err"], row["state_err"] = float(apart(out, want_out)), float(apart(state, want_state))
-                row["kept"] = bool(same_at(state, state0, untouched))
+                row["out_err"] = float(apart(out, want_out))
+                row["state_err"] = float(apart(state.reshape(want_state.shape), want_state))
+                row["kept"] = bool(same_at(state.reshape(state0.shape), state0, untouched))
             if timed:
                 t0 = time.perf_counter()
                 for _ in range(iters):  # enqueued back to back: each run takes the state the last one gave
@@ -239,8 +335,8 @@ def bench(kind: str, candidates: dict, shape: tuple, *, seed: int, iters: int, t
                 own, other = device_us(str(trace_dir), "stream_" if "stream" in note else KERNEL[kind], 3 * layers)
                 row["kernel_us"], row["other_us"] = round(own, 1), round(other, 1)
                 if own:
-                    moved = (1 if note.get("stream") in ("read", "write") else 2) * 4 * rows * heads * n * p
-                    row["peak_pct"] = round(100 * moved / peak / (own * 1e-6), 1)
+                    half = note.get("stream") in ("read", "write")
+                    row["peak_pct"] = round(100 * moved / (2 if half else 1) / peak / (own * 1e-6), 1)
                 shutil.rmtree(trace_dir, ignore_errors=True)
             del state, out
         except Exception as e:  # a form the compiler refuses is a row of the table, not the end of the call
@@ -254,6 +350,8 @@ def candidates_of(kind: str, shape: tuple, budgets: list[int], parent: str, extr
     """name -> ``(step with the kernels' positional arguments, what the row says of it)``."""
     from dynamo_tpu.ops import pallas_kda, pallas_mamba
 
+    if kind.startswith("conv"):
+        return {"flat": (xla_step(kind), {}), **conv_candidates(interpret)}
     own = {"kda": pallas_kda.kda_decode_step, "mamba": pallas_mamba.mamba_decode_step}[kind].__wrapped__
     _, heads, groups, n, p, _, _ = shape
     out = {"xla": (xla_step(kind), {})}
@@ -285,7 +383,7 @@ def candidates_of(kind: str, shape: tuple, budgets: list[int], parent: str, extr
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--kinds", default="kda,mamba")
+    ap.add_argument("--kinds", default="kda,mamba", help="kda, mamba, conv (the conv rows of both cells)")
     ap.add_argument("--budgets-mib", default="2,4,8,16", help="STATE_VMEM values to set, MiB")
     ap.add_argument("--parent", default="", help="another tree whose two kernel files are timed as they are")
     ap.add_argument("--extra", default="", help="a Python file with CANDIDATES = {kind: {name: fn}}")
@@ -303,7 +401,7 @@ def main() -> int:
     extra = load_file(pathlib.Path(args.extra), "state_kernel_bench_extra") if args.extra else None
     budgets = [int(v) for v in args.budgets_mib.split(",")] if on_chip else [1, 2, 4]
     table = []
-    for kind in args.kinds.split(","):
+    for kind in args.kinds.replace("conv", "conv.kda,conv.mamba").split(","):
         shape = (SHAPES if on_chip else TOY)[kind]
         cands = candidates_of(kind, shape, budgets, args.parent, extra, interpret=not on_chip)
         table += bench(kind, cands, shape, seed=args.seed, iters=args.iters if on_chip else 1, timed=on_chip, peak=peak)
