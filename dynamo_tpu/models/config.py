@@ -145,6 +145,42 @@ def _group_limit(config: dict, held: int, total: int) -> tuple[int, int]:
     return n_group, int(config.get("topk_group", 0) or 0)
 
 
+def _stage_layers(config: dict) -> int:
+    """Layers held here of a file that states a pipeline stage
+    (``num_hidden_layers_published`` beside ``num_hidden_layers`` held here,
+    ``pipeline_stages`` equal stages, this one ``stage_rank``): a stage holds
+    an equal share of the layers."""
+    layers, stages = int(config["num_hidden_layers"]), int(config.get("pipeline_stages", 1))
+    published = int(config.get("num_hidden_layers_published", layers * stages))
+    if layers * stages != published or not 0 <= int(config.get("stage_rank", 0)) < stages:
+        raise ValueError(f"num_hidden_layers {layers} x pipeline_stages {stages} (stage_rank "
+                         f"{config.get('stage_rank', 0)}) is not num_hidden_layers_published {published}: "
+                         "a stage holds an equal share of the layers")
+    return layers
+
+
+def _heads_per_row(head_dim: int, per_group: int) -> int:
+    """Heads of a Mamba-2 state that lie side by side on the 128 lanes of one
+    buffer row (``ModelConfig.ssm_heads_per_row``): ``128 // head_dim`` for
+    heads narrower than the lanes where a group's heads fill whole rows, else 1."""
+    side = 128 // head_dim if 0 < head_dim < 128 and 128 % head_dim == 0 else 1
+    return side if per_group % side == 0 else 1
+
+
+def _mamba_sizes(config: dict, inner: int, what: str) -> tuple[int, int, int, int]:
+    """(heads, head channels, groups, state) of a config's Mamba-2 keys, held
+    to the ``inner`` channels that ``what`` states. (A state the decode kernel
+    does not tile is refused where the kernel is chosen, on a chip at any
+    size: ``models/mamba2._rows_update``.)"""
+    heads, head_dim, groups = int(config["mamba_n_heads"]), int(config["mamba_d_head"]), int(config["mamba_n_groups"])
+    state = int(config["mamba_d_state"])
+    if heads * head_dim != inner:
+        raise ValueError(f"mamba_n_heads {heads} x mamba_d_head {head_dim} is not {what} {inner}: not served")
+    if groups <= 0 or heads % groups:
+        raise ValueError(f"mamba_n_heads {heads} is not a multiple of mamba_n_groups {groups}: not served")
+    return heads, head_dim, groups, state
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -283,6 +319,18 @@ class ModelConfig:
     ssm_in_multiplier: float = 1.0
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    # A period whose recurrent layers are Mamba-2 mixers that stand alone
+    # (Granite-4.0-H's ``granitemoehybrid``): ``ssm_heads`` *and*
+    # ``layer_group_size`` set. The one layer of a period that attends (GQA
+    # where ``attn_type`` is "gqa") sits at ``group_attn_index`` of the period
+    # (-1: its last layer, as in Ling's), and holds pages and no slot; every
+    # other layer holds a slot and no pages.
+    group_attn_index: int = -1
+    # Granite's multipliers: ``residual_multiplier`` on each block's output
+    # before it joins the stream, ``attn_scale`` the softmax scale where it is
+    # not ``head_dim ** -0.5`` (0.0: the usual scale).
+    residual_multiplier: float = 1.0
+    attn_scale: float = 0.0
 
     @property
     def q_dim(self) -> int:
@@ -308,40 +356,64 @@ class ModelConfig:
 
     @property
     def recurrent_layers(self) -> int:
-        """Layers whose state is a slot: every layer of a model with a mixer
-        (beside its pages); of a KDA hybrid all but one a period (instead of
-        pages)."""
-        if self.ssm_heads:
-            return self.num_layers
+        """Layers whose state is a slot: of a model in periods (KDA layers, or
+        Mamba-2 layers that stand alone) all but one a period (instead of
+        pages); every layer of a model with a mixer beside its attention
+        (beside its pages)."""
         g = self.layer_group_size
-        return self.num_layers - self.num_layers // g if g else 0
+        if g:
+            return self.num_layers - self.num_layers // g
+        return self.num_layers if self.ssm_heads else 0
+
+    @property
+    def period_attn_index(self) -> int:
+        """Where in a period the layer that attends sits, from 0."""
+        return self.group_attn_index % self.layer_group_size
 
     @property
     def cache_layers(self) -> int:
         """Slabs of the paged cache: one per attention (sub)layer that attends
-        over the context (a KDA layer holds none; a layer with a mixer beside
-        its attention holds one)."""
-        attending = self.num_layers if self.ssm_heads else self.num_layers - self.recurrent_layers
+        over the context (a recurrent layer of a period holds none; a layer
+        with a mixer beside its attention holds one)."""
+        g = self.layer_group_size
+        attending = self.num_layers // g if g else self.num_layers
         return attending * (2 if self.shortcut_moe else 1)
+
+    @property
+    def ssm_heads_per_row(self) -> int:
+        """Heads of a Mamba-2 state that lie side by side on the 128 lanes of
+        one buffer row: 1 where a head's channels are whole lane tiles (or
+        tile nothing, a toy); ``128 // ssm_head_dim`` for narrower heads (two
+        heads of 64 channels) where a group's heads fill whole rows, so that
+        the buffer is not padded to the lanes and a row of it serves that many
+        heads of one group at once (``ops/pallas_mamba.py``)."""
+        return _heads_per_row(self.ssm_head_dim, self.ssm_heads // self.ssm_groups)
 
     def state_shapes(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
         """One recurrent layer's part of a slot, by the kind of its state:
         (the float32 state, the conv state in the model's dtype). KDA: a
         ``key x value`` matrix a head and the last ``taps - 1`` inputs of the
         q, k and v streams. Mamba-2: a ``state x head channels`` matrix a head
-        (state-major, the transposition of the published cache's) and the last
+        (state-major, the transposition of the published cache's; heads
+        narrower than the lanes ``ssm_heads_per_row`` side by side, ``[heads /
+        side, state, side x head channels]``) and the last
         ``taps - 1`` inputs of x, B and C. The conv state's channels lie in
-        rows of 128 lanes, ``(taps - 1, channels / 128, 128)`` (a width that is
-        no multiple of 128: one row), so that the device's tiles hold a slot's
-        inputs whole and the buffer needs no other layout than the one it is
+        rows of 128 lanes, ``(taps - 1, channels / 128, 128)``, the rows rounded
+        up to whole float32 sublane tiles of 8 (8,448 channels: 66 rows in 72;
+        the device would lay a 66-row buffer out with the slots on the
+        sublanes, and every step would re-lay it; a width that is no multiple
+        of 128: one row), so that the device's tiles hold a slot's inputs
+        whole and the buffer needs no other layout than the one it is
         allocated in (``models/kda.slot_conv``)."""
         if self.ssm_heads:
-            state, taps, channels = ((self.ssm_heads, self.ssm_state_size, self.ssm_head_dim), self.ssm_conv_size,
-                                     self.ssm_conv_dim)
+            side = self.ssm_heads_per_row
+            state, taps, channels = ((self.ssm_heads // side, self.ssm_state_size, side * self.ssm_head_dim),
+                                     self.ssm_conv_size, self.ssm_conv_dim)
         else:
             state, taps, channels = (self.num_heads, self.head_dim, self.head_dim), self.kda_conv_size, 3 * self.q_dim
-        lanes = 128 if channels % 128 == 0 else channels
-        return state, (taps - 1, channels // lanes, lanes)
+        if channels % 128:
+            return state, (taps - 1, 1, channels)
+        return state, (taps - 1, -(-channels // 128 // 8) * 8, 128)
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state one sequence holds over all recurrent
@@ -425,6 +497,13 @@ class ModelConfig:
         if self.shortcut_moe:  # two attention blocks, two dense FFNs and the experts in one layer
             return embed + head + d + self.num_layers * (2 * (attn + dense + norms) + moe)
         k_dense = self.first_k_dense if self.is_moe else self.num_layers
+        # A mixer: two projections, the filter and its bias, A_log, dt_bias and D a head, the gated norm.
+        inner = self.ssm_inner
+        mixer = (d * (inner + self.ssm_conv_dim + self.ssm_heads) + inner * d
+                 + (self.ssm_conv_size + 1) * self.ssm_conv_dim + 3 * self.ssm_heads + inner) if inner else 0
+        if self.layer_group_size and inner:  # a period of Mamba-2 layers and one that attends
+            return (embed + head + d + self.recurrent_layers * mixer + self.cache_layers * attn
+                    + self.num_layers * norms + k_dense * dense + (self.num_layers - k_dense) * moe)
         if self.layer_group_size:  # KDA: five full projections, two head-wise ones, filters, decay constants, head norm
             q = self.q_dim
             kda = (5 * d * q + 2 * d * self.num_heads + 3 * self.kda_conv_size * q + self.num_heads + q + self.head_dim)
@@ -432,10 +511,6 @@ class ModelConfig:
             gate = d * self.num_heads  # the latent-attention layers' head-wise output gate (``w_out_gate``)
             return (embed + head + d + n_kda * kda + (self.num_layers - n_kda) * (attn + gate)
                     + self.num_layers * norms + k_dense * dense + (self.num_layers - k_dense) * moe)
-        # A mixer: two projections, the filter and its bias, A_log, dt_bias and D a head, the gated norm.
-        inner = self.ssm_inner
-        mixer = (d * (inner + self.ssm_conv_dim + self.ssm_heads) + inner * d
-                 + (self.ssm_conv_size + 1) * self.ssm_conv_dim + 3 * self.ssm_heads + inner) if inner else 0
         return (embed + head + d + self.num_layers * (attn + mixer + norms)
                 + k_dense * dense + (self.num_layers - k_dense) * moe)
 
@@ -566,20 +641,10 @@ class ModelConfig:
             "attn_layer_indices": None, "rope_scaling": None, "hidden_act": "silu", "tie_word_embeddings": False,
         }
         _refuse_unserved(config, unserved, "falcon_h1")
-        hidden, layers = config["hidden_size"], int(config["num_hidden_layers"])
-        heads, head_dim, groups = int(config["mamba_n_heads"]), int(config["mamba_d_head"]), int(config["mamba_n_groups"])
+        hidden, layers = config["hidden_size"], _stage_layers(config)
         d_ssm = config.get("mamba_d_ssm")
         d_ssm = int(config["mamba_expand"] * hidden) if d_ssm is None else int(d_ssm)
-        if heads * head_dim != d_ssm:
-            raise ValueError(f"mamba_n_heads {heads} x mamba_d_head {head_dim} is not mamba_d_ssm {d_ssm}: not served")
-        if groups <= 0 or heads % groups:
-            raise ValueError(f"mamba_n_heads {heads} is not a multiple of mamba_n_groups {groups}: not served")
-        stages = int(config.get("pipeline_stages", 1))
-        published = int(config.get("num_hidden_layers_published", layers * stages))
-        if layers * stages != published or not 0 <= int(config.get("stage_rank", 0)) < stages:
-            raise ValueError(f"num_hidden_layers {layers} x pipeline_stages {stages} (stage_rank "
-                             f"{config.get('stage_rank', 0)}) is not num_hidden_layers_published {published}: "
-                             "a stage holds an equal share of the layers")
+        heads, head_dim, groups, state = _mamba_sizes(config, d_ssm, "mamba_d_ssm")
         mlp, ssm = config.get("mlp_multipliers") or (1.0, 1.0), config.get("ssm_multipliers") or (1.0,) * 5
         if len(mlp) != 2 or len(ssm) != 5:
             raise ValueError(f"mlp_multipliers {mlp!r} / ssm_multipliers {ssm!r}: expected 2 and 5 entries (z, x, B, C, dt)")
@@ -592,7 +657,7 @@ class ModelConfig:
             rope_theta=float(config.get("rope_theta", 10000.0)), rope_scaling=None,
             rms_eps=config.get("rms_norm_eps", 1e-5), max_position=config.get("max_position_embeddings", 8192),
             tie_embeddings=False, attention_bias=False,
-            ssm_heads=heads, ssm_head_dim=head_dim, ssm_state_size=int(config["mamba_d_state"]), ssm_groups=groups,
+            ssm_heads=heads, ssm_head_dim=head_dim, ssm_state_size=state, ssm_groups=groups,
             ssm_conv_size=int(config.get("mamba_d_conv", 4)),
             embed_multiplier=float(config.get("embedding_multiplier", 1.0)),
             lm_head_multiplier=float(config.get("lm_head_multiplier", 1.0)),
@@ -603,6 +668,78 @@ class ModelConfig:
             ssm_in_multiplier=float(config.get("ssm_in_multiplier", 1.0)),
             ssm_out_multiplier=float(config.get("ssm_out_multiplier", 1.0)),
             ssm_multipliers=tuple(float(m) for m in ssm),
+        )
+
+    @classmethod
+    def _from_granite_hybrid(cls, config: dict, name: str | None) -> "ModelConfig":
+        """Granite-4.0-H's config.json (``model_type`` ``granitemoehybrid``): by
+        ``layer_types`` a layer is a Mamba-2 mixer that stands alone or GQA
+        attention without RoPE (``position_embedding_type`` ``nope``), one
+        attention layer a period; every layer's FFN is ``num_local_experts``
+        routed experts (the ``num_experts_per_tok`` largest router logits,
+        softmax over those) beside a shared expert of
+        ``shared_intermediate_size``; ``residual_multiplier`` on each block's
+        output, ``attention_multiplier`` the softmax scale,
+        ``embedding_multiplier`` on the embedding, ``logits_scaling`` a divisor
+        of the logits. A file that states a pipeline stage gives a model of
+        that many layers (``_stage_layers``), whole periods of them;
+        ``layer_types`` may stay whole, the entries of the layers held are
+        read. Refuses by name what the layers do not compute (a ``rope``
+        sibling and the dense siblings, ``num_local_experts`` 0, are paths of
+        their own and can follow); ``mamba_chunk_size`` tiles the published
+        kernels and changes no mathematics, ``rope_theta`` rotates nothing:
+        taken without complaint."""
+        unserved = {
+            "mamba_proj_bias": False, "attention_bias": False, "mamba_conv_bias": True, "position_embedding_type": "nope",
+            "rope_scaling": None, "hidden_act": "silu", "normalization_function": "rmsnorm",
+        }
+        _refuse_unserved(config, unserved, "granitemoehybrid")
+        hidden, layers = config["hidden_size"], _stage_layers(config)
+        experts = int(config.get("num_local_experts", 0) or 0)
+        if experts <= 0:
+            raise ValueError(f"num_local_experts {experts} is not served for model_type 'granitemoehybrid': a layer whose "
+                             "FFN is the shared MLP alone is a path of its own")
+        heads, head_dim, groups, state = _mamba_sizes(config, int(config["mamba_expand"] * hidden), "mamba_expand x hidden_size")
+        if groups != 1:  # GraniteMoeHybridRMSNormGated has no groups; the program's gated norm runs over a group's channels
+            raise ValueError(f"mamba_n_groups {groups} is not served for model_type 'granitemoehybrid': only 1 (the published "
+                             "gated norm runs over all the mixer's channels, the program's over each group's)")
+        kinds = list(config.get("layer_types") or [])
+        unknown = sorted(set(kinds) - {"mamba", "attention"})
+        if unknown or len(kinds) < layers:
+            raise ValueError(f"layer_types holds {unknown or len(kinds)}: expected at least {layers} entries of "
+                             "'mamba' / 'attention'")
+        attending = [i for i, kind in enumerate(kinds) if kind == "attention"]
+        period = attending[1] - attending[0] if len(attending) > 1 else len(kinds)
+        if not attending or period < 2 or any((kind == "attention") != (i % period == attending[0]) for i, kind in enumerate(kinds)):
+            raise ValueError(f"layer_types with 'attention' at {attending[:6]} is not served: periods of Mamba layers "
+                             "with one attention layer at the same place in each")
+        if layers % period:
+            raise ValueError(f"num_hidden_layers {layers} is not whole periods of {period} layers (layer_types): not served")
+        attn_heads = config["num_attention_heads"]
+        attn_head_dim = hidden // attn_heads  # the published layer has no ``head_dim`` key
+        return cls(
+            name=name or config.get("_name_or_path", "granitemoehybrid"),
+            vocab_size=config["vocab_size"], hidden_size=hidden, num_layers=layers,
+            num_heads=attn_heads, num_kv_heads=config.get("num_key_value_heads", attn_heads), head_dim=attn_head_dim,
+            intermediate_size=config["intermediate_size"],
+            # No rotary embedding: the identity table (``ops/rope.py``), as in K-EXAONE's full layers.
+            rope_theta=float(config.get("rope_theta", 10000.0)), rope_scaling={"rope_type": "nope"},
+            rms_eps=config.get("rms_norm_eps", 1e-5), max_position=config.get("max_position_embeddings", 8192),
+            tie_embeddings=bool(config.get("tie_word_embeddings", True)), attention_bias=False,
+            num_experts=experts, num_experts_per_token=int(config["num_experts_per_tok"]),
+            moe_intermediate_size=config["intermediate_size"],
+            shared_expert_size=int(config.get("shared_intermediate_size", 0) or 0),
+            # The k largest logits, softmax over those: the softmax over all, renormalised over the chosen. (The
+            # published gate is ``self.layer(h).float()``: the product in the activations' dtype, then widened, which
+            # is route_tokens' own form.)
+            moe_scoring="softmax", moe_norm_topk=True,
+            layer_group_size=period, group_attn_index=attending[0],
+            ssm_heads=heads, ssm_head_dim=head_dim, ssm_state_size=state, ssm_groups=groups,
+            ssm_conv_size=int(config.get("mamba_d_conv", 4)),
+            embed_multiplier=float(config.get("embedding_multiplier", 1.0)),
+            lm_head_multiplier=1.0 / float(config.get("logits_scaling", 1.0)),
+            residual_multiplier=float(config.get("residual_multiplier", 1.0)),
+            attn_scale=float(config.get("attention_multiplier", attn_head_dim**-0.5)),
         )
 
     @classmethod
@@ -657,6 +794,8 @@ class ModelConfig:
             return cls._from_bailing_hybrid(config, name)
         if config.get("model_type") == "falcon_h1":
             return cls._from_falcon_h1(config, name)
+        if config.get("model_type") == "granitemoehybrid":
+            return cls._from_granite_hybrid(config, name)
         # A state-space model's config also describes a GQA stack: served by this
         # branch it would run as that stack alone, silently (what PR 26 found for Mellum2).
         ssm_keys = sorted(k for k in config if k.startswith(("mamba_", "ssm_")))
@@ -664,7 +803,7 @@ class ModelConfig:
             raise ValueError(
                 f"model_type {config.get('model_type')!r} states {ssm_keys[0]} (and {len(ssm_keys) - 1} more mamba_* / "
                 "ssm_* keys): a state-space layer that no branch of from_hf reads is not served "
-                "(served with a mixer: model_type 'falcon_h1')")
+                "(served with a mixer: model_type 'falcon_h1', 'granitemoehybrid')")
         hidden = config["hidden_size"]
         heads = config["num_attention_heads"]
         # DeepSeek replaces the first k MoE layers with dense MLPs
@@ -1057,3 +1196,37 @@ TINY_FALCON_H1_HF: dict[str, Any] = {
 }
 PRESETS["test-tiny-falcon-h1"] = dataclasses.replace(
     ModelConfig.from_hf(TINY_FALCON_H1_HF, name="test-tiny-falcon-h1"), dtype="float32")
+
+
+#: granite-4.0-h-small's published ``config.json`` (ibm-granite; ``model_type``
+#: ``granitemoehybrid``), the catalog row's keys key for key:
+#: ``tests/benchmark/test_benchmark_granite.py`` holds it to the row where the
+#: catalog is on the machine.
+GRANITE_4_H_SMALL_HF: dict[str, Any] = {
+    "model_type": "granitemoehybrid", "attention_bias": False, "attention_multiplier": 0.0078125,
+    "embedding_multiplier": 12, "hidden_act": "silu", "hidden_size": 4096, "intermediate_size": 768,
+    "layer_types": (["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 4, "logits_scaling": 16,
+    "mamba_chunk_size": 256, "mamba_conv_bias": True, "mamba_d_conv": 4, "mamba_d_head": 64, "mamba_d_state": 128,
+    "mamba_expand": 2, "mamba_n_groups": 1, "mamba_n_heads": 128, "mamba_proj_bias": False,
+    "max_position_embeddings": 131072, "normalization_function": "rmsnorm", "num_attention_heads": 32,
+    "num_experts_per_tok": 10, "num_hidden_layers": 40, "num_key_value_heads": 8, "num_local_experts": 72,
+    "position_embedding_type": "nope", "residual_multiplier": 0.22, "rms_norm_eps": 1e-05, "rope_scaling": None,
+    "rope_theta": 10000, "shared_intermediate_size": 1536, "tie_word_embeddings": True, "vocab_size": 100352,
+}
+#: The same keys at toy widths: two periods of ``[mamba, mamba, attention,
+#: mamba]`` (the layer that attends inside the period), 4 query heads over 2 KV
+#: heads of 16, a mixer of 4 heads of 16 channels in one group with a state of
+#: 8 (``mamba_expand`` 1), 6 experts top-3 beside a shared one, float32. The multipliers are made-up
+#: values, none of them 1 and each different, so that a multiplier in the wrong
+#: place shows in the logits (the published softmax scale is kept: 1/16 where
+#: the usual one would be 1/4).
+TINY_GRANITE_HYBRID_HF: dict[str, Any] = {
+    **GRANITE_4_H_SMALL_HF, "hidden_size": 64, "intermediate_size": 32, "shared_intermediate_size": 48,
+    "num_hidden_layers": 8, "layer_types": ["mamba", "mamba", "attention", "mamba"] * 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "mamba_n_heads": 4, "mamba_d_head": 16, "mamba_d_state": 8,
+    "mamba_expand": 1, "mamba_chunk_size": 8, "num_local_experts": 6, "num_experts_per_tok": 3, "vocab_size": 256,
+    "max_position_embeddings": 512, "attention_multiplier": 0.0625, "embedding_multiplier": 3.0,
+    "logits_scaling": 4.0, "residual_multiplier": 0.6,
+}
+PRESETS["test-tiny-granite-hybrid"] = dataclasses.replace(
+    ModelConfig.from_hf(TINY_GRANITE_HYBRID_HF, name="test-tiny-granite-hybrid"), dtype="float32")

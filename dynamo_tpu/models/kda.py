@@ -162,19 +162,30 @@ def slot_conv(conv, ids, fresh, x, filt, n_valid, bias=None, *, impl: str | None
     W / lanes, lanes], conv)``: the output in the buffer's rows of lanes, for
     :func:`conv_heads` to split. Each row's slot moves once each way: through
     ``ops/pallas_conv.slot_conv_step`` where it tiles the shape, else by one
-    gather and one scatter of the rows' slots."""
+    gather and one scatter of the rows' slots. Where the buffer's rows hold
+    more than ``W`` channels (``ModelConfig.state_shapes`` rounds them up to
+    whole sublane tiles), the inputs are padded with zeros to fill them."""
     r, t, w = x.shape
     tile = conv.shape[1:]
-    lay = lambda z: None if z is None else z.reshape(*z.shape[:-1], *tile[1:])  # noqa: E731
+    held = tile[1] * tile[2]  # the channels a slot's rows hold: ``w``, or ``w`` rounded up to whole tiles (zeros behind it)
+
+    def lay(z):
+        if z is None:
+            return None
+        if held > w:
+            z = jnp.pad(z, [(0, 0)] * (z.ndim - 1) + [(0, held - w)])
+        return z.reshape(*z.shape[:-1], *tile[1:])
+
     if impl == "pallas":
         from dynamo_tpu.ops import pallas_conv
 
         if pallas_conv.supported(t, *tile[1:]):
             return pallas_conv.slot_conv_step(conv, ids, fresh, n_valid, lay(x), lay(filt), lay(bias),
                                               interpret=pallas_conv.interpret_mode())
-    prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv[ids].reshape(r, -1, w))
+    prev = conv[ids].reshape(r, -1, held)
+    prev = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), prev if held == w else prev[..., :w])
     y, carried = causal_conv(x, prev, filt, n_valid, bias)
-    return lay(y), conv.at[ids].set(carried.astype(conv.dtype).reshape(r, *tile))
+    return lay(y), conv.at[ids].set(lay(carried.astype(conv.dtype)))
 
 
 def conv_heads(y, first: int, heads: int, dim: int):

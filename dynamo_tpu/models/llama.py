@@ -160,7 +160,15 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
     }
     if k_dense:
         params["dense_layers"] = layer_stack(k_dense, False, 1, attn=not hybrid)
-    if hybrid:
+    if hybrid and cfg.ssm_heads:
+        # Mamba-2 layers that stand alone and the GQA blocks of the layers that attend, each stack in layer order.
+        from dynamo_tpu.models.mamba2 import init_mamba_params
+
+        n_attn, ks = cfg.cache_layers, [jax.random.fold_in(k, 4) for k in keys]
+        params["ssm_layers"] = init_mamba_params(cfg, jax.random.fold_in(keys[0], 7), dt, cfg.recurrent_layers)
+        params["attn_layers"] = {"wq": w(ks[0], (n_attn, d, q), d), "wk": w(ks[1], (n_attn, d, kv), d),
+                                 "wv": w(ks[2], (n_attn, d, kv), d), "wo": w(ks[3], (n_attn, q, d), q)}
+    elif hybrid:
         # The attention blocks by kind, each stack in layer order: ``layers`` and
         # ``dense_layers`` hold the norms and the FFNs of every layer.
         from dynamo_tpu.models.kda import init_kda_params
@@ -412,7 +420,7 @@ def forward(
     which a cache of equal pools (``window_pages`` None) seats.
 
     ``recurrent`` (a model with recurrent layers, ``cfg.recurrent_layers``: KDA
-    layers in periods, or a Mamba-2 mixer beside every layer's attention):
+    or Mamba-2 layers in periods, or a Mamba-2 mixer beside every layer's attention):
     the two state buffers of ``models/kda.init_state`` and each row's slot
     (each slot's, on the split token axis; 0 is the null slot). The buffers
     come back as the last two outputs, updated: the caller donates them as it
@@ -483,10 +491,13 @@ def forward(
     nl, npages, ps = k_cache.shape[0], k_cache.shape[1], k_cache.shape[2]
     inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, theta=cfg.rope_theta, scaling=cfg.rope_scaling))
     attn_mscale = rope_attention_factor(cfg.rope_scaling) ** 2
+    attn_rescale = cfg.attn_scale * cfg.head_dim**0.5 if cfg.attn_scale else 1.0
+    if cfg.residual_multiplier != 1.0 and not cfg.layer_group_size:
+        raise NotImplementedError("residual_multiplier is served in the period scan only (Granite-4.0-H's layers)")
     x = params["embed"][tokens]  # [B, T, D]
     if cfg.embed_scale:  # Gemma: embeddings scale by sqrt(hidden)
         x = x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
-    if cfg.embed_multiplier != 1.0:  # Falcon-H1's muP
+    if cfg.embed_multiplier != 1.0:  # Falcon-H1's muP, Granite's embedding_multiplier
         x = x * jnp.asarray(cfg.embed_multiplier, x.dtype)
     if mm_embeds is not None and cfg.image_token_id is not None:
         is_img = tokens == jnp.int32(cfg.image_token_id)  # [B, T]
@@ -621,6 +632,98 @@ def forward(
             y = a1 + _mlp_dense(lp["sub1"], h1, cfg.mlp_act) + m
         return (y, k_full, v_full, li + 1, counts + counted), None
 
+    def gqa_attention(lp, h, k_full, v_full, li, kind=None):
+        """One layer's GQA block on its normed input ``h``: the projections with
+        their optional biases, norms and multipliers, RoPE by the layer's
+        kind, the cache write, paged attention (on the split token axis too)
+        and the output projection. ``li`` is the layer's slab of the cache,
+        ``kind`` a mixed model's scalars of the layer. Returns ``(out, k_full,
+        v_full)``. The plain layer body and the period scan both call it."""
+        qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
+        if cfg.attention_bias:
+            qp, kp, vp = qp + lp["bq"], kp + lp["bk"], vp + lp["bv"]
+        if cfg.key_multiplier != 1.0:
+            kp = kp * jnp.asarray(cfg.key_multiplier, kp.dtype)
+        if cfg.qk_norm == "flat":  # OLMoE: norm the flat projection
+            qp = rms_norm(qp, lp["q_norm"], eps=cfg.rms_eps)
+            kp = rms_norm(kp, lp["k_norm"], eps=cfg.rms_eps)
+        else:
+            # Nothing stands between the projection and its heads, so
+            # the heads' layout would reach the dot and re-lay wq and
+            # wk. (A flat norm reads whole rows and is laid out flat
+            # itself; v's heads are flattened again by the cache write.)
+            qp, kp = held_flat(qp), held_flat(kp)
+        q = qp.reshape(b, t, cfg.num_heads, cfg.head_dim)
+        k = kp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        v = vp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_norm == "head":  # Qwen3: per-head norm before rope
+            q = rms_norm(q, lp["q_norm"], eps=cfg.rms_eps)
+            k = rms_norm(k, lp["k_norm"], eps=cfg.rms_eps)
+        if mrope_positions is not None and cfg.mrope_section:
+            # Qwen2-VL 3D rope: ONLY the rotation angles change; cache
+            # slots, masking, and lengths keep the sequential positions.
+            q = apply_mrope(q, mrope_positions, inv_freq, cfg.mrope_section)
+            k = apply_mrope(k, mrope_positions, inv_freq, cfg.mrope_section)
+        elif kind is not None:  # this layer's own RoPE, and its YaRN factor (1 if plain)
+            q = apply_rope(q, positions, rope_tables[kind["rope"]])
+            k = apply_rope(k, positions, rope_tables[kind["rope"]])
+            q = q * kind["mscale"].astype(q.dtype)
+        else:
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
+        if kind is None and attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
+            q = q * jnp.asarray(attn_mscale, q.dtype)
+        if attn_rescale != 1.0:  # a softmax scale of the model's own: the kernels' is head_dim ** -0.5
+            q = q * jnp.asarray(attn_rescale, q.dtype)
+        if kind is None:
+            slots_l = slot_mapping + li * (npages * ps)
+        else:  # the layer's own pool: its kind's slots, from its first page
+            slots_l = jnp.where(kind["windowed"], window_slots, slot_mapping) + kind["base"] * ps
+        k_full, v_full = write_kv(k_full, v_full, k, v, slots_l)
+        if ring:
+            from dynamo_tpu.parallel.ring import ring_attention
+
+            attn = ring_attention(q, k, v, ring_pos, mesh, scale=cfg.head_dim**-0.5)
+        else:
+            if kind is None:
+                tables_l = block_tables + li * npages
+            else:  # and its kind's block table
+                tables_l = jnp.where(kind["windowed"], window_tables, block_tables) + kind["base"]
+            # 0 = full causal; a mixed model hands each layer its own
+            # (a runtime scalar: NO_WINDOW in its full layers).
+            window = cfg.sliding_window if kind is None else kind["window"]
+            if attn_impl == "pallas" and mesh is not None:
+                # Explicit tp/dp layout around the kernel: GSPMD would
+                # otherwise all-gather the cache and replicate the
+                # pallas_call on every device.
+                from dynamo_tpu.ops.attention import paged_attention_sharded
+
+                attn = paged_attention_sharded(
+                    q, k_full, v_full, tables_l, positions,
+                    mesh=mesh, impl=attn_impl, sliding_window=window,
+                    contiguous_positions=contiguous_positions,
+                )
+            elif split is not None:
+                # One query per decode slot, tc per chunk slot; back onto the token axis.
+                def rows(tok: slice, slot: slice, width: int):
+                    n = slot.stop - slot.start
+                    return paged_attention(
+                        q[0, tok].reshape(n, width, cfg.num_heads, cfg.head_dim), k_full, v_full,
+                        tables_l[slot], positions[0, tok].reshape(n, width),
+                        impl=attn_impl, sliding_window=window, chunked=True,
+                    ).reshape(1, n * width, cfg.num_heads, cfg.head_dim)
+
+                attn = jnp.concatenate(
+                    [rows(slice(0, nd), slice(0, nd), 1), rows(slice(nd, t), slice(nd, nd + nc), tc)], axis=1)
+            else:
+                attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
+                                       sliding_window=window,
+                                       contiguous_positions=contiguous_positions)
+        attn_out = _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
+        if cfg.attn_out_multiplier != 1.0:
+            attn_out = attn_out * jnp.asarray(cfg.attn_out_multiplier, attn_out.dtype)
+        return attn_out, k_full, v_full
+
     def make_layer_step(moe_layer: bool):
         def ffn(lp, h2, counts: list):
             """The layer's FFN on the normed stream; a model that holds a share
@@ -675,87 +778,7 @@ def forward(
                 if cfg.attn_in_multiplier != 1.0:
                     h = h * jnp.asarray(cfg.attn_in_multiplier, h.dtype)
             with jax.named_scope("attn"):  # projections, rope, cache write, attention, output
-                qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
-                if cfg.attention_bias:
-                    qp, kp, vp = qp + lp["bq"], kp + lp["bk"], vp + lp["bv"]
-                if cfg.key_multiplier != 1.0:
-                    kp = kp * jnp.asarray(cfg.key_multiplier, kp.dtype)
-                if cfg.qk_norm == "flat":  # OLMoE: norm the flat projection
-                    qp = rms_norm(qp, lp["q_norm"], eps=cfg.rms_eps)
-                    kp = rms_norm(kp, lp["k_norm"], eps=cfg.rms_eps)
-                else:
-                    # Nothing stands between the projection and its heads, so
-                    # the heads' layout would reach the dot and re-lay wq and
-                    # wk. (A flat norm reads whole rows and is laid out flat
-                    # itself; v's heads are flattened again by the cache write.)
-                    qp, kp = held_flat(qp), held_flat(kp)
-                q = qp.reshape(b, t, cfg.num_heads, cfg.head_dim)
-                k = kp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-                v = vp.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-                if cfg.qk_norm == "head":  # Qwen3: per-head norm before rope
-                    q = rms_norm(q, lp["q_norm"], eps=cfg.rms_eps)
-                    k = rms_norm(k, lp["k_norm"], eps=cfg.rms_eps)
-                if mrope_positions is not None and cfg.mrope_section:
-                    # Qwen2-VL 3D rope: ONLY the rotation angles change; cache
-                    # slots, masking, and lengths keep the sequential positions.
-                    q = apply_mrope(q, mrope_positions, inv_freq, cfg.mrope_section)
-                    k = apply_mrope(k, mrope_positions, inv_freq, cfg.mrope_section)
-                elif kind is not None:  # this layer's own RoPE, and its YaRN factor (1 if plain)
-                    q = apply_rope(q, positions, rope_tables[kind["rope"]])
-                    k = apply_rope(k, positions, rope_tables[kind["rope"]])
-                    q = q * kind["mscale"].astype(q.dtype)
-                else:
-                    q = apply_rope(q, positions, inv_freq)
-                    k = apply_rope(k, positions, inv_freq)
-                if kind is None and attn_mscale != 1.0:  # YaRN temperature: logits scale by mscale^2
-                    q = q * jnp.asarray(attn_mscale, q.dtype)
-                if kind is None:
-                    slots_l = slot_mapping + li * (npages * ps)
-                else:  # the layer's own pool: its kind's slots, from its first page
-                    slots_l = jnp.where(kind["windowed"], window_slots, slot_mapping) + kind["base"] * ps
-                k_full, v_full = write_kv(k_full, v_full, k, v, slots_l)
-                if ring:
-                    from dynamo_tpu.parallel.ring import ring_attention
-
-                    attn = ring_attention(q, k, v, ring_pos, mesh, scale=cfg.head_dim**-0.5)
-                else:
-                    if kind is None:
-                        tables_l = block_tables + li * npages
-                    else:  # and its kind's block table
-                        tables_l = jnp.where(kind["windowed"], window_tables, block_tables) + kind["base"]
-                    # 0 = full causal; a mixed model hands each layer its own
-                    # (a runtime scalar: NO_WINDOW in its full layers).
-                    window = cfg.sliding_window if kind is None else kind["window"]
-                    if attn_impl == "pallas" and mesh is not None:
-                        # Explicit tp/dp layout around the kernel: GSPMD would
-                        # otherwise all-gather the cache and replicate the
-                        # pallas_call on every device.
-                        from dynamo_tpu.ops.attention import paged_attention_sharded
-
-                        attn = paged_attention_sharded(
-                            q, k_full, v_full, tables_l, positions,
-                            mesh=mesh, impl=attn_impl, sliding_window=window,
-                            contiguous_positions=contiguous_positions,
-                        )
-                    elif split is not None:
-                        # One query per decode slot, tc per chunk slot; back onto the token axis.
-                        def rows(tok: slice, slot: slice, width: int):
-                            n = slot.stop - slot.start
-                            return paged_attention(
-                                q[0, tok].reshape(n, width, cfg.num_heads, cfg.head_dim), k_full, v_full,
-                                tables_l[slot], positions[0, tok].reshape(n, width),
-                                impl=attn_impl, sliding_window=window, chunked=True,
-                            ).reshape(1, n * width, cfg.num_heads, cfg.head_dim)
-
-                        attn = jnp.concatenate(
-                            [rows(slice(0, nd), slice(0, nd), 1), rows(slice(nd, t), slice(nd, nd + nc), tc)], axis=1)
-                    else:
-                        attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
-                                               sliding_window=window,
-                                               contiguous_positions=contiguous_positions)
-                attn_out = _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
-                if cfg.attn_out_multiplier != 1.0:
-                    attn_out = attn_out * jnp.asarray(cfg.attn_out_multiplier, attn_out.dtype)
+                attn_out, k_full, v_full = gqa_attention(lp, h, k_full, v_full, li, kind)
                 x = x + (attn_out if mixed is None else mixed + attn_out)
             h2 = rms_norm(x, lp["mlp_norm"], eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
             mlp, counts = ffn(lp, h2, counts)
@@ -765,24 +788,38 @@ def forward(
         return layer_step
 
     def hybrid_stack(x, kf, vf, *counts):
-        """Periods of ``cfg.layer_group_size`` layers: KDA layers closed by
-        one latent-attention layer, the first ``n_dense`` layers' FFN dense
-        and every later one routed. Two attention kinds have two parameter
-        trees and two kinds of state, so the stack is a scan over periods
-        around a loop over the period's KDA layers; a layer reads its leaves
-        from the stacks by index (what a scan's own slicing does), so that
-        the three layer bodies (KDA + dense, KDA + routed, MLA + routed) are
-        each compiled once whatever the depth."""
+        """Periods of ``cfg.layer_group_size`` layers: recurrent layers, whose
+        state is a slot, and one layer that attends, whose state is pages.
+        The configuration says which recurrent kind (KDA, its leaves under
+        ``kda_layers``; a Mamba-2 mixer where ``cfg.ssm_heads``, under
+        ``ssm_layers``), which attention kind (latent attention, ``mla_layers``;
+        the GQA block, ``attn_layers``), where in the period the layer that
+        attends sits (``cfg.period_attn_index``: Ling's last of 6, Granite's
+        sixth of 10) and which FFN a layer has (the first ``n_dense`` layers'
+        dense, every later one routed, whole or a held share), and
+        ``cfg.residual_multiplier`` scales each block's output. Two kinds of
+        layer have two parameter trees and two kinds of state, so the stack
+        is a scan over periods around loops over the period's recurrent
+        layers; a layer reads its leaves from the stacks by index (what a
+        scan's own slicing does), so that each layer body is compiled once
+        whatever the depth (twice the recurrent one where the layer that
+        attends stands inside the period)."""
         from dynamo_tpu.models.kda import kda_attention
+        from dynamo_tpu.models.mamba2 import mamba_mixer
         from dynamo_tpu.models.mla import mla_attention
 
-        if ring or layer_kinds is not None or cfg.first_k_dense != n_dense:
-            raise NotImplementedError("a hybrid stack is served by the paged path, its leading dense FFNs as stated")
+        group, n_rec, attends = cfg.layer_group_size, cfg.recurrent_layers, cfg.period_attn_index
+        if ring or layer_kinds is not None or cfg.first_k_dense != n_dense or n_dense > attends:
+            raise NotImplementedError("a hybrid stack is served by the paged path, its leading dense FFNs as stated "
+                                      "and under recurrent layers")
+        if bool(cfg.ssm_heads) == mla:
+            raise NotImplementedError("a period is KDA layers with one latent-attention layer, or Mamba-2 layers with one GQA layer")
         state, conv, slot_ids = recurrent
-        group, n_kda = cfg.layer_group_size, cfg.recurrent_layers
-        slots = state.shape[0] // n_kda
+        slots = state.shape[0] // n_rec
         valid = slot_mapping != 0
         at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)  # noqa: E731
+        res = cfg.residual_multiplier
+        joined = lambda x, out: x + (out if res == 1.0 else out * jnp.asarray(res, out.dtype))  # noqa: E731
 
         def ffn(carry, lp, i_moe):
             x, kf, vf, state, conv, *counts = carry
@@ -795,40 +832,58 @@ def forward(
                     counts = [counts[0] + counted]
                 else:
                     mlp = _mlp_moe(join_expert_stack(lp, expert_stack, i_moe), h2, cfg, mesh)
-            return (x + mlp, kf, vf, state, conv, *counts)
+            return (joined(x, mlp), kf, vf, state, conv, *counts)
 
-        def kda_layer(carry, i_kda, ffn_layers, i_ffn, routed: bool):
+        def recurrent_layer(carry, i_rec, ffn_layers, i_ffn, routed: bool):
             x, kf, vf, state, conv, *counts = carry
             lp = at(ffn_layers, i_ffn)
             h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps)
-            with jax.named_scope("attn.kda"):
-                out, state, conv = kda_attention(
-                    at(params["kda_layers"], i_kda), cfg, h, positions, valid, state, conv,
-                    slot_ids + i_kda * slots, impl=attn_impl, split=split)
-            return ffn((x + out, kf, vf, state, conv, *counts), lp, i_ffn if routed else None)
+            if cfg.ssm_heads:
+                with jax.named_scope("attn.ssm"):
+                    out, state, conv = mamba_mixer(
+                        at(params["ssm_layers"], i_rec), cfg, h, positions, valid, state, conv,
+                        slot_ids + i_rec * slots, impl=attn_impl, split=split)
+            else:
+                with jax.named_scope("attn.kda"):
+                    out, state, conv = kda_attention(
+                        at(params["kda_layers"], i_rec), cfg, h, positions, valid, state, conv,
+                        slot_ids + i_rec * slots, impl=attn_impl, split=split)
+            return ffn((joined(x, out), kf, vf, state, conv, *counts), lp, i_ffn if routed else None)
 
-        def mla_layer(carry, i_mla, i_ffn):
+        def attention_layer(carry, i_attn, i_ffn):
             x, kf, vf, state, conv, *counts = carry
             lp = at(moe_layers, i_ffn)
             h = rms_norm(x, lp["attn_norm"], eps=cfg.rms_eps)
             with jax.named_scope("attn"):
-                out, kf, vf = mla_attention(
-                    at(params["mla_layers"], i_mla), cfg, h, positions, kf, vf,
-                    block_tables + i_mla * npages, slot_mapping + i_mla * (npages * ps), inv_freq_mla,
-                    attn_mscale=attn_mscale, impl=attn_impl, split=split)
-            return ffn((x + out, kf, vf, state, conv, *counts), lp, i_ffn)
+                if mla:
+                    out, kf, vf = mla_attention(
+                        at(params["mla_layers"], i_attn), cfg, h, positions, kf, vf,
+                        block_tables + i_attn * npages, slot_mapping + i_attn * (npages * ps), inv_freq_mla,
+                        attn_mscale=attn_mscale, impl=attn_impl, split=split)
+                else:
+                    out, kf, vf = gqa_attention(at(params["attn_layers"], i_attn), h, kf, vf, i_attn)
+            return ffn((joined(x, out), kf, vf, state, conv, *counts), lp, i_ffn)
 
         carry = (x, kf, vf, state, conv, *counts)
         if n_dense:
             carry, _ = jax.lax.scan(
-                lambda c, i: (kda_layer(c, i, params["dense_layers"], i, False), None), carry, jnp.arange(n_dense))
+                lambda c, i: (recurrent_layer(c, i, params["dense_layers"], i, False), None), carry, jnp.arange(n_dense))
 
         def period(carry, p):
             first = jnp.where(p == 0, n_dense, 0) if n_dense else 0  # the first period's dense layers are done
             carry = jax.lax.fori_loop(
-                first, group - 1,
-                lambda j, c: kda_layer(c, p * (group - 1) + j, moe_layers, p * group + j - n_dense, True), carry)
-            return mla_layer(carry, p, p * group + group - 1 - n_dense), None
+                first, attends,
+                lambda j, c: recurrent_layer(c, p * (group - 1) + j, moe_layers, p * group + j - n_dense, True), carry)
+            # (counted back from the period's end, not ``p * group + attends``: where the layer that attends closes the
+            # period, Ling's, the traced arithmetic is then the parent's, and so is the compiled text of Ling's decode and
+            # chunk programs down to its computations' names: ``tools/step_relayouts.py ling-3.0-flash-ep8-int8 64
+            # --dump`` on both trees, ISSUE 49's acceptance; simplify it when Ling's programs next change anyway)
+            carry = attention_layer(carry, p, p * group + group - (group - attends) - n_dense)
+            if attends < group - 1:  # the period's recurrent layers after the one that attends
+                carry = jax.lax.fori_loop(
+                    attends, group - 1,
+                    lambda j, c: recurrent_layer(c, p * (group - 1) + j, moe_layers, p * group + j + 1 - n_dense, True), carry)
+            return carry, None
 
         carry, _ = jax.lax.scan(period, carry, jnp.arange(cfg.num_layers // group))
         return carry
@@ -846,7 +901,7 @@ def forward(
 
         carry += (jnp.zeros((len(HELD_COUNTS),), jnp.int32),)
         n_counts = 1
-    if cfg.ssm_heads:  # the plain body with a mixer: its two state buffers ride behind the counters
+    if cfg.ssm_heads and not cfg.layer_group_size:  # the plain body with a mixer: its two state buffers ride behind the counters
         if mla or ring or layer_kinds is not None:
             raise NotImplementedError("a Mamba-2 mixer is served beside GQA attention of one kind, by the paged path")
         ssm_slots = recurrent[0].shape[0] // cfg.num_layers

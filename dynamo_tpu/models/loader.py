@@ -560,9 +560,10 @@ def load_params(
 
 #: Architectures ``ModelConfig.from_hf`` reads whose checkpoint weight names
 #: have no map in ``_leaf_specs`` (no published list of them to work from; for
-#: ``falcon_h1`` the names are published and the map is not written: its
-#: mixer's leaves, and a state laid the other way round than the published cache).
-UNMAPPED_MODEL_TYPES = frozenset({"mellum", "exaone_moe", "falcon_h1"})
+#: ``falcon_h1`` and ``granitemoehybrid`` the names are published and the map is
+#: not written: their mixers' leaves, and a state laid the other way round than
+#: the published cache).
+UNMAPPED_MODEL_TYPES = frozenset({"mellum", "exaone_moe", "falcon_h1", "granitemoehybrid"})
 
 
 def load_model(

@@ -1,7 +1,9 @@
-"""The Mamba-2 mixer of a layer that runs it beside its attention (Falcon-H1,
-``modeling_falcon_h1.FalconH1Mixer``): a selective state-space layer whose
-per-sequence state is a fixed-size **slot**, as KDA's is (``models/kda.py``),
-held here *beside* the layer's pages and not instead of them.
+"""The Mamba-2 mixer: of a layer that runs it beside its attention (Falcon-H1,
+``modeling_falcon_h1.FalconH1Mixer``: the slot *beside* the layer's pages) and
+of a layer that is nothing else (Granite-4.0-H,
+``modeling_granitemoehybrid.GraniteMoeHybridMambaLayer``: a slot and no pages,
+in periods with one layer that attends). A selective state-space layer whose
+per-sequence state is a fixed-size **slot**, as KDA's is (``models/kda.py``).
 
 With ``u`` the layer's normed input, ``H`` heads of ``P`` channels, a state of
 ``N`` and ``G`` groups of heads that share a ``B`` and a ``C``:
@@ -16,14 +18,18 @@ With ``u`` the layer's normed input, ``H`` heads of ``P`` channels, a state of
 (the caller multiplies ``out`` by ``ssm_out_multiplier``). A head keeps ``S``
 state-major, ``[N, P]``: the transposition of the published cache's ``[P, N]``,
 so that the decode kernel's two products are a multiply and a sum over
-sublanes, as ``ops/pallas_kda`` has them. ``benchmark/reference/falcon_h1.py``
-writes the layer out equation by equation.
+sublanes, as ``ops/pallas_kda`` has them. Heads narrower than the 128 lanes
+lie ``cfg.ssm_heads_per_row`` of a group side by side in a buffer row, ``[H /
+side, N, side x P]`` (:func:`lay_side_by_side`), so that the buffer is not
+padded to the lanes. ``benchmark/reference/falcon_h1.py`` and
+``granite_hybrid.py`` write the layer out equation by equation.
 
 What a step does with a row's slot, exactly as for KDA:
 
 - a **decode** row takes one step of the recurrence: on a TPU the Pallas
   kernel ``ops/pallas_mamba.mamba_decode_step`` (one read and one write of the
-  slot's state, in place), elsewhere :func:`recurrent_step` on gathered rows;
+  slot's state, in place; a shape it does not tile is refused there by name,
+  never gathered), elsewhere :func:`recurrent_step` on gathered rows;
 - a **chunk** row takes the chunked form (:func:`chunk_step`): within the
   chunk the ``[tokens, tokens]`` decay-masked ``C B^T`` product a group times
   ``dt x``, the carried state in with its decay, the chunk's last state out,
@@ -115,22 +121,44 @@ def chunk_step(s0, x, b, c, dt, a):
     return y, s
 
 
+def lay_side_by_side(s, side: int):
+    """States ``[..., H, N, P]`` as the buffer holds them, ``[..., H / side, N,
+    side x P]``: ``side`` neighbouring heads on the lanes of one row."""
+    if side == 1:
+        return s
+    *lead, h, n, p = s.shape
+    return jnp.moveaxis(s.reshape(*lead, h // side, side, n, p), -3, -2).reshape(*lead, h // side, n, side * p)
+
+
+def lay_by_head(s, side: int):
+    """:func:`lay_side_by_side`'s inverse: buffer rows as ``[..., H, N, P]``."""
+    if side == 1:
+        return s
+    *lead, rows, n, lanes = s.shape
+    return jnp.moveaxis(s.reshape(*lead, rows, n, side, lanes // side), -2, -3).reshape(*lead, rows * side, n, lanes // side)
+
+
 def _rows_update(state, ids, fresh, x, b, c, dt, a, *, impl: str | None):
     """The recurrence over rows ``[R, T]`` (``x [R, T, G, Hg, P]``, ``b c [R,
     T, G, N]``, ``dt [R, T, G, Hg]``, float32) on the slots ``ids`` of ``state
-    f32[slots, H, N, P]``; ``fresh`` rows start from zeros. Returns ``(y like
-    x, state)``."""
+    f32[slots, H / side, N, side x P]``; ``fresh`` rows start from zeros.
+    Returns ``(y like x, state)``."""
     r, t, g, hg, p = x.shape
     n = b.shape[-1]
+    side = g * hg // state.shape[1]
     if t == 1 and impl == "pallas":
         from dynamo_tpu.ops import pallas_mamba
 
-        if pallas_mamba.supported(n, p):
-            y, state = pallas_mamba.mamba_decode_step(
-                state, ids, fresh, x[:, 0].reshape(r, g * hg, p), b[:, 0], c[:, 0], dt[:, 0].reshape(r, g * hg),
-                a.reshape(-1), interpret=pallas_mamba.interpret_mode())
-            return y.reshape(r, 1, g, hg, p), state
-    s0 = jnp.where(fresh[:, None, None, None], 0.0, state[ids]).reshape(r, g, hg, n, p)
+        if not pallas_mamba.supported(*state.shape[2:]):  # on a chip: never through the gather below, at any size
+            raise ValueError(f"mamba_d_head {p} (mamba_d_state {n}, {hg} heads a group) is not a shape the decode kernel "
+                             f"tiles: a slot's state lies [{state.shape[2]}, {state.shape[3]}] a buffer row, and the "
+                             "kernel takes the state in eights and a row of whole lane tiles of 128 (a head's channels, "
+                             "or narrower heads that fill them group by group): not served")
+        y, state = pallas_mamba.mamba_decode_step(
+            state, ids, fresh, x[:, 0].reshape(r, g * hg, p), b[:, 0], c[:, 0], dt[:, 0].reshape(r, g * hg),
+            a.reshape(-1), interpret=pallas_mamba.interpret_mode())
+        return y.reshape(r, 1, g, hg, p), state
+    s0 = lay_by_head(jnp.where(fresh[:, None, None, None], 0.0, state[ids]), side).reshape(r, g, hg, n, p)
     if t == 1:
         y, s = recurrent_step(s0, x[:, 0], b[:, 0], c[:, 0], dt[:, 0], a)
         y = y[:, None]
@@ -138,7 +166,7 @@ def _rows_update(state, ids, fresh, x, b, c, dt, a, *, impl: str | None):
         y, s = jax.lax.map(lambda z: chunk_step(*z, a), (s0, x, b, c, dt))
     else:
         y, s = jax.vmap(lambda *z: chunk_step(*z, a))(s0, x, b, c, dt)
-    return y, state.at[ids].set(s.reshape(r, g * hg, n, p))
+    return y, state.at[ids].set(lay_side_by_side(s.reshape(r, g * hg, n, p), side))
 
 
 def mamba_mixer(
@@ -147,7 +175,7 @@ def mamba_mixer(
     u: jnp.ndarray,  # [B, T, D] the layer's normed input
     positions: jnp.ndarray,  # i32[B, T]
     valid: jnp.ndarray,  # bool[B, T]: the token is real (it writes a live cache slot)
-    state: jnp.ndarray,  # f32[layers * slots, H, N, P]
+    state: jnp.ndarray,  # f32[layers * slots, H / side, N, side x P]
     conv: jnp.ndarray,  # [layers * slots, taps - 1, conv_dim / 128, 128]
     slot_ids: jnp.ndarray,  # i32[rows]: this layer's slot of each row (layer * slots + slot)
     *,
@@ -169,7 +197,9 @@ def mamba_mixer(
     if cfg.ssm_in_multiplier != 1.0:
         u = u * jnp.asarray(cfg.ssm_in_multiplier, u.dtype)
     # float32 out of the projection: the conv, the gate and the step size read it unrounded.
-    proj = jnp.dot(u, lp["w_ssm_in"], preferred_element_type=f32) * jnp.asarray(mup_vector(cfg))
+    proj = jnp.dot(u, lp["w_ssm_in"], preferred_element_type=f32)
+    if any(m != 1.0 for m in cfg.ssm_multipliers):
+        proj = proj * jnp.asarray(mup_vector(cfg))
     z, xbc, dt = proj[..., :inner], proj[..., inner: inner + conv_dim], proj[..., inner + conv_dim:]
     dt = jax.nn.softplus(dt + lp["ssm_dt_bias"].astype(f32))
     dt = jnp.where(valid[..., None], dt, 0.0)  # a padding token neither decays nor writes

@@ -23,6 +23,18 @@ them as the conv leaves them, ``[rows, groups, N]`` with the state entries on
 lanes, and turns a group's row into a ``[N, 1]`` column in VMEM. The wrapper
 builds nothing but the ``[rows, heads]`` decays.
 
+Heads narrower than the lanes (``P`` 64: granite-4.0-h's 128 heads of 64
+channels in one group). A float32 ``[.., N, 64]`` buffer is padded to 128 lanes
+by the device's tiling, twice the bytes, so such a state lies **``side = 128 /
+P`` heads of a group side by side on the lanes**, ``[slots, H / side, N, side x
+P]`` (``ModelConfig.ssm_heads_per_row`` decides it; ``models/mamba2.lay_side_by_side``
+is the map): a buffer row *is* ``side`` heads. ``x`` and ``y`` are ``[R, H /
+side, side x P]`` by a free reshape, the group's ``B`` and ``C`` columns serve
+every head of the row, and the decay and ``dt`` become a lane row of ``side``
+values, built in the kernel from the same SMEM scalars (a select a head). The
+body is otherwise the one above. The kernel reads ``side`` off the buffer's
+shape; with ``side`` 1 nothing of it is in the program.
+
 The grid is ``(rows, heads / block)``, the block from ``ops/pallas_kda``'s
 ``heads_block`` and its one budget ``STATE_VMEM``: the most heads that divide
 the head count, are a whole number of groups or a divisor of one, and whose
@@ -30,6 +42,8 @@ state block, in and out and each double-buffered (4 x block), fits: a group's
 16 of the 32 heads of 256 x 128 (2 MiB a block, 8 MiB buffered; all 32 would
 take 16 MiB and run no faster). A block that spans groups reads each head's
 group statically; one inside a group finds its group from the grid position.
+Of heads side by side a block is rows of the buffer: 32 rows of 2 x 64 of the
+64 (the same 2 MiB).
 
 Tests: ``tests/test_pallas_mamba.py`` (interpret mode against
 ``models/mamba2.recurrent_step``), ``tests/test_chip_compile.py`` (compiled
@@ -50,29 +64,44 @@ from dynamo_tpu.ops import pallas_kda
 from dynamo_tpu.ops.pallas_paged import interpret_mode  # noqa: F401  (re-exported: the callers' one switch)
 
 
-def supported(state: int, channels: int) -> bool:
-    """Shapes the kernel tiles: state entries in whole sublane tiles, a head's
-    channels in whole lane tiles (or interpret mode, which tiles nothing)."""
-    return interpret_mode() or (state % 8 == 0 and channels % 128 == 0)
+def supported(state: int, lanes: int) -> bool:
+    """Shapes the kernel tiles, as the state buffer has them (``state.shape[2:]``):
+    state entries in whole sublane tiles, a buffer row (a head's channels, or
+    narrower heads side by side) in whole lane tiles. Or interpret mode, which
+    tiles nothing."""
+    return interpret_mode() or (state % 8 == 0 and lanes % 128 == 0)
 
 
-def _kernel(slots_ref, fresh_ref, decay_ref, dt_ref, x_ref, b_ref, c_ref, s_ref, s_out_ref, y_ref, *, hb: int, per_group: int):
+def _kernel(slots_ref, fresh_ref, decay_ref, dt_ref, x_ref, b_ref, c_ref, s_ref, s_out_ref, y_ref, *, hb: int, per_group: int,
+            side: int):
     del slots_ref  # read by the index maps only
     r, j = pl.program_id(0), pl.program_id(1)
     keep = jnp.where(fresh_ref[r] != 0, 0.0, 1.0)  # a fresh row's slot holds another sequence's state
-    first = (r * pl.num_programs(1) + j) * hb  # the block's first (row, head) among the scalars
-    for i in range(hb):
-        if i % per_group == 0:  # the next group's B and C (a block inside a group: its one group), as columns [state, 1]
-            group = (j * hb + i) // per_group
+    first = (r * pl.num_programs(1) + j) * hb * side  # the block's first (row, head) among the scalars
+    if side > 1:
+        lanes = s_ref.shape[-1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+
+    def of_heads(ref, head: int):
+        """A head's scalar; of heads side by side, each one's over its own lanes."""
+        value = ref[head]
+        for k in range(1, side):
+            value = jnp.where(lane >= k * (lanes // side), ref[head + k], value)
+        return value
+
+    for i in range(hb):  # a buffer row: a head, or ``side`` heads of one group
+        head = i * side
+        if head % per_group == 0:  # the next group's B and C (a block inside a group: its one group), as columns [state, 1]
+            group = (j * hb * side + head) // per_group
             b, c = b_ref[pl.ds(group, 1), :].T, c_ref[pl.ds(group, 1), :].T
-        s = s_ref[i] * (decay_ref[first + i] * keep) + b * (x_ref[pl.ds(i, 1), :] * dt_ref[first + i])
+        s = s_ref[i] * (of_heads(decay_ref, first + head) * keep) + b * (x_ref[pl.ds(i, 1), :] * of_heads(dt_ref, first + head))
         s_out_ref[i] = s
         y_ref[pl.ds(i, 1), :] = jnp.sum(s * c, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
 def mamba_decode_step(
-    state: jnp.ndarray,  # f32[slots, H, N, P]: every (layer, slot)'s state; updated in place
+    state: jnp.ndarray,  # f32[slots, H / side, N, side x P]: every (layer, slot)'s state; updated in place
     slot_ids: jnp.ndarray,  # i32[R]
     fresh: jnp.ndarray,  # bool[R]: the row starts from zeros
     x: jnp.ndarray,  # f32[R, H, P]
@@ -86,7 +115,10 @@ def mamba_decode_step(
     """One step of the recurrence for ``R`` rows: ``(y f32[R, H, P], state)``."""
     rows, heads, p = x.shape
     groups, n = b.shape[1:]
-    hb = pallas_kda.heads_block(heads, 4 * n * p, heads // groups)
+    side = heads // state.shape[1]  # heads side by side on a buffer row's lanes
+    assert state.shape[1:] == (heads // side, n, side * p) and (heads // groups) % side == 0, (state.shape, x.shape, b.shape)
+    heads, p = heads // side, side * p  # the buffer's rows from here on
+    hb = pallas_kda.heads_block(heads, 4 * n * p, max(heads // groups, 1))
     f32 = jnp.float32
     dt = dt.astype(f32)
 
@@ -97,7 +129,7 @@ def mamba_decode_step(
     row_spec = pl.BlockSpec((None, hb, p), at(lambda r, j, slots: (r, j, 0)))
     group_spec = pl.BlockSpec((None, groups, n), at(lambda r, j, slots: (r, 0, 0)))  # every group's: fetched once a row
     state, y = pl.pallas_call(
-        functools.partial(_kernel, hb=hb, per_group=heads // groups),
+        functools.partial(_kernel, hb=hb, per_group=heads * side // groups, side=side),
         out_shape=(jax.ShapeDtypeStruct(state.shape, f32), jax.ShapeDtypeStruct((rows, heads, p), f32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -113,5 +145,5 @@ def mamba_decode_step(
         interpret=interpret,
         name="mamba_decode_step",
     )(slot_ids.astype(jnp.int32), fresh.astype(jnp.int32), jnp.exp(dt * a).reshape(-1), dt.reshape(-1),
-      x.astype(f32), b.astype(f32), c.astype(f32), state)
-    return y, state
+      x.astype(f32).reshape(rows, heads, p), b.astype(f32), c.astype(f32), state)
+    return y.reshape(x.shape), state

@@ -712,17 +712,20 @@ def _conv_buffer_stays(text: str, conv) -> None:
 
 @pytest.mark.parametrize("slots, c, bias, rows, tokens", [
     (15 * 65, 96, False, 64, 1), (9 * 65, 40, True, 64, 1), (15 * 65, 96, False, 1, 64), (9 * 65, 40, True, 2, 64),
-], ids=["ling-decode", "falcon-h1-decode", "ling-chunk", "falcon-h1-chunks"])
+    (9 * 65, 72, True, 64, 1), (9 * 65, 72, True, 1, 64),
+], ids=["ling-decode", "falcon-h1-decode", "ling-chunk", "falcon-h1-chunks", "granite-decode", "granite-chunk"])
 def test_slot_conv_kernel_compiles(sds, slots, c, bias, rows, tokens):
     """The conv rows' step at both cells' widths (Ling-3.0-flash's 12,288
     channels in 96 rows of lanes, no bias; Falcon-H1-34B's 5,120 in 40, whose
-    last bfloat16 tile is half full, with one): 64 one-token rows and a
+    last bfloat16 tile is half full, with one; granite-4.0-h-small's 8,448 in
+    66, held in 72: a 66-row buffer the device would lay out with the slots on
+    the sublanes): 64 one-token rows and a
     64-token chunk, the buffer aliased to the kernel's output, pinned to HBM
     and taken in the layout it is allocated in (row-major, ``(8, 128)(2, 1)``
     tiles over the last two axes): nothing but the kernel in the program."""
     from dynamo_tpu.ops import pallas_conv
 
-    assert pallas_conv.supported(tokens, c, 128) and not pallas_conv.supported(128, 96, 128)
+    assert pallas_conv.supported(tokens, c, 128) and not pallas_conv.supported(128, 96, 128) and not pallas_conv.supported(1, 66, 128)
     args = [sds((slots, 3, c, 128), jnp.bfloat16), sds((rows,), jnp.int32), sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
             sds((rows, tokens, c, 128), jnp.float32), sds((4, c, 128), jnp.float32)] + ([sds((c, 128), jnp.float32)] if bias else [])
     compiled = jax.jit(lambda *a: pallas_conv.slot_conv_step.__wrapped__(*a), donate_argnums=(0,)).lower(*args).compile()
@@ -792,8 +795,11 @@ def test_hybrid_step_ling_largest_corners(sds, monkeypatch, split):
 
 # -- a Mamba-2 mixer beside GQA attention in every layer: pages and a slot a layer (ISSUE 46) ------------
 
-@pytest.mark.parametrize("budget_mib, block", [(None, 16), (16, 32), (4, 8)], ids=["served", "16MiB", "4MiB"])
-def test_mamba_decode_kernel_compiles(sds, monkeypatch, budget_mib, block):
+@pytest.mark.parametrize("heads, groups, n, p, budget_mib, block", [
+    (32, 2, 256, 128, None, 16), (32, 2, 256, 128, 16, 32), (32, 2, 256, 128, 4, 8),
+    (128, 1, 128, 64, None, 32), (128, 1, 128, 64, 4, 16), (128, 2, 128, 64, None, 32),
+], ids=["served", "16MiB", "4MiB", "narrow-served", "narrow-4MiB", "narrow-two-groups"])
+def test_mamba_decode_kernel_compiles(sds, monkeypatch, heads, groups, n, p, budget_mib, block):
     """The decode step of the Mamba-2 recurrence at Falcon-H1-34B's widths: 64
     rows x 32 heads of 256 x 128 in 2 groups over 9 layers x 65 slots, the
     state aliased to the kernel's output (updated where it lies: no second
@@ -802,20 +808,28 @@ def test_mamba_decode_kernel_compiles(sds, monkeypatch, budget_mib, block):
     budget takes both groups in one block and the grid over rows only, half
     takes half a group. The wrapper lays out nothing: no lane-wide decay or
     ``dt x`` and no ``[rows, groups, N, 2]`` columns reach the kernel, only
-    the ``[rows x heads]`` scalars."""
+    the ``[rows x heads]`` scalars. And at granite-4.0-h-small's: 128 heads
+    of 128 x 64 in one group (and in two), two heads side by side in a buffer
+    row, ``[slots, 64, 128, 128]`` at 4,194,304 B a slot a layer with no lane
+    padding; a block is 32 rows (the same 2 MiB), the decay and ``dt`` still
+    the ``[rows x heads]`` scalars (8,192 of them)."""
     from dynamo_tpu.ops import pallas_kda, pallas_mamba
 
     if budget_mib:
         monkeypatch.setattr(pallas_kda, "STATE_VMEM", budget_mib << 20)
-    assert pallas_kda.heads_block(32, 4 * 256 * 128, 16) == block and 4 * block * 4 * 256 * 128 <= pallas_kda.STATE_VMEM
+    side = 128 // p if p < 128 else 1
+    rows_of, lanes = heads // side, side * p  # the buffer's rows: a head, or two side by side
+    assert pallas_kda.heads_block(rows_of, 4 * n * lanes, rows_of // groups) == block and 4 * block * 4 * n * lanes <= pallas_kda.STATE_VMEM
     f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
     compiled = jax.jit(lambda *a: pallas_mamba.mamba_decode_step.__wrapped__(*a), donate_argnums=(0,)).lower(
-        f32(9 * 65, 32, 256, 128), sds((64,), jnp.int32), sds((64,), jnp.bool_),
-        f32(64, 32, 128), f32(64, 2, 256), f32(64, 2, 256), f32(64, 32), f32(32)).compile()
+        f32(9 * 65, rows_of, n, lanes), sds((64,), jnp.int32), sds((64,), jnp.bool_),
+        f32(64, heads, p), f32(64, groups, n), f32(64, groups, n), f32(64, heads), f32(heads)).compile()
     text = compiled.as_text()
-    assert "mamba_decode_step" in text and "f32[64,2,256,2]" not in text and "f32[2048]" in text
+    assert "mamba_decode_step" in text and f"f32[64,{groups},{n},2]" not in text and f"f32[{64 * heads}]" in text
+    assert f"f32[{9 * 65},{rows_of},{n},{lanes}]{{3,2,1,0:T(8,128)}}" in text  # row-major, whole tiles: nothing padded
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 9 * 65 * 32 * 256 * 128 * 4 and mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes >= 9 * 65 * heads * n * p * 4 and mem.temp_size_in_bytes < 1 << 20
+    assert mem.argument_size_in_bytes < 9 * 65 * heads * n * p * 4 + (8 << 20)  # the buffer at its bytes, not twice them
 
 
 @pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
@@ -880,3 +894,80 @@ def test_a_model_without_a_mixer_compiles_nothing_of_it(sds, monkeypatch, config
     assert not cfg.ssm_heads and not cfg.recurrent_layers
     assert {cfg.embed_multiplier, cfg.lm_head_multiplier, cfg.attn_in_multiplier, cfg.attn_out_multiplier, cfg.key_multiplier,
             cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier} == {1.0}
+
+
+# -- Mamba-2 layers that stand alone, one GQA layer inside the period, experts in every layer, a tied head (ISSUE 49) ------
+
+
+@pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
+def test_period_step_granite_largest_corners(sds, monkeypatch, split):
+    """reason-saturated's largest steps of granite-4.0-h-small's stage as it is
+    served: the whole period of ten layers (Mamba x5, attention, Mamba x4), 72
+    experts a layer, the whole 100,352-id vocabulary under the tied head; 64
+    decode rows, and 64 decode slots + one 64-token chunk slot, over 16 pages.
+    The period scan is two loops over the Mamba layers round the one layer
+    that attends: ``mamba_decode_step`` in each loop's body (all nine Mamba
+    layers), the attention layer through the paged GQA kernels on the one
+    slab, the experts through the grouped int8 kernel in all three bodies.
+    The state buffer is ``65 x 9 x 4,194,304`` B (two heads of 64 side by side:
+    no lane padding), handed in, stepped where it lies and handed back
+    (aliased: no copy, no gather and no scatter of it; in the chunk step the
+    one chunk row's state alone moves); the conv buffer stays where it lies;
+    **no transposed copy of the embedding**: the head contracts the 822 MB
+    array as the gather reads it; no int8 weight is re-laid inside the loops."""
+    import re
+
+    from dynamo_tpu.models import kda, llama
+    from dynamo_tpu.parallel import moe
+    from tests.test_step_relayouts import load_tool
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    cfg = _benchmark_config("granite-4.0-h-small-pp4-int8", layers=10, vocab=100352)
+    assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, cfg.layer_group_size, cfg.period_attn_index) == (10, 9, 1, 10, 5)
+    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_heads_per_row, cfg.num_experts, cfg.tie_embeddings) == (128, 64, 2, 72, True)
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = _served_params(sds, cfg)
+    assert params["ssm_layers"]["w_ssm_in"].dtype == jnp.bfloat16 and params["attn_layers"]["wq"]["qw"].dtype == jnp.int8
+    assert params["embed"].shape == (100352, 4096) and "lm_head" not in params
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
+    state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
+    assert state.shape == (9 * 65, 64, 128, 128) and state.size * 4 == 65 * 9 * 4_194_304
+    assert conv.shape == (9 * 65, 3, 72, 128) and conv.dtype == jnp.bfloat16 and k_cache.shape == (1, 1025, 128, 1024)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if split is None:
+        toks, slots = (64, 1), 64
+    else:
+        toks, slots = (split[0] + split[1] * split[2],), split[0] + split[1]
+
+    def step(params, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index, state, conv, slot_ids):
+        return llama.forward(params, cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index,
+                             attn_impl="pallas", split=split, recurrent=(state, conv, slot_ids))
+
+    compiled = jax.jit(step, donate_argnums=(3, 4, 8, 9)).lower(
+        params, i32(*toks), i32(*toks), k_cache, v_cache, i32(slots, 16), i32(*toks), i32(slots), state, conv, i32(slots),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2  # the Mamba layers before and after the one that attends (one period is no loop): none over rows
+    bodies = [body for body in re.split(r"\n(?=%?[\w.\-]+ \(.*\) -> .* \{\n)", text) if " custom-call(" in body and "mamba_decode_step" in body]
+    assert len(bodies) == 2 and all(body.count('custom_call_target="tpu_custom_call"') >= 1 for body in bodies)  # both loops' bodies
+    assert "kda_decode_step" not in text and ("paged_prefill_attention" if split else "paged_decode_attention") in text
+    assert text.count("moe_grouped_matmul_int8") >= 3 and "ragged-dot" not in text and "ragged_dot" not in text
+    _conv_buffer_stays(text, conv)
+    # The state buffer: a parameter, carried through the loops and the kernel, handed back: nothing else makes one.
+    shape = re.escape("f32[585,64,128,128]")
+    made = set(re.findall(rf"= (?:\()?{shape}\S*(?:, [^)]*\))? ([\w-]+)\(", text))
+    assert made <= {"parameter", "get-tuple-element", "custom-call", "while", "tuple"} | ({"dynamic-update-slice", "fusion"} if split else set()), made
+    assert f"f32[585,64,128,128]{{3,2,1,0:T(8,128)}} parameter(" in text
+    shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
+    assert shapes[-2:] == [state.shape, conv.shape] and len(shapes) == 5  # logits, the caches, the state buffers
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4 + 2 * k_cache.size * 2 and mem.temp_size_in_bytes < 1 << 30
+    # The embedding: one array, read by the gather and contracted by the head where it lies.
+    assert load_tool().embedding_copies(text, 100352, 4096) == [] and "bf16[100352,4096]" in text
+    wrong = text.replace("bf16[100352,4096]{1,0:T(8,128)(2,1)} bitcast(", "bf16[4096,100352]{1,0:T(8,128)(2,1)} transpose(", 1)
+    # (the tool sees one where there is one: the transposition, and the fusion round it that is a bitcast no more)
+    assert [c["opcode"] for c in load_tool().embedding_copies(wrong, 100352, 4096)] == ["transpose", "fusion"]
+    ops = load_tool().relayouts(text)
+    assert not [(op["name"], op["shape"]) for op in ops if op["dtype"] == "s8"]
+    if split is None:
+        assert not [op for op in ops if op["bytes"] >= state.size * 4 // 65 // 9]  # nothing the size of a row's state moves

@@ -94,7 +94,7 @@ def test_from_hf_refuses_by_name(edit, says):
         ModelConfig.from_hf({**FALCON_H1_34B_HF, **edit}, name="t")
 
 
-@pytest.mark.parametrize("model_type", ["llama", "bamba", "granitemoehybrid", None])
+@pytest.mark.parametrize("model_type", ["llama", "bamba", "nemotron_h", None])
 def test_a_state_space_config_no_branch_reads_is_refused_by_name(model_type):
     """The general branch would serve such a config as the GQA stack it also
     describes, silently (what the tree before ISSUE 46 did with this file)."""

@@ -33,6 +33,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 from benchmark.reference import falcon_h1 as ref_h1  # noqa: E402
+from benchmark.reference import granite_hybrid as ref_granite  # noqa: E402
 from benchmark.reference import ling_3_flash as ref  # noqa: E402
 from dynamo_tpu.engine.allocator import SlotAllocator  # noqa: E402
 from dynamo_tpu.engine.core import EngineConfig, EngineCore  # noqa: E402
@@ -40,7 +41,7 @@ from dynamo_tpu.engine.runner import ROWS_X_T, SPLIT, ModelRunner, StepBatch  # 
 from dynamo_tpu.engine.sequence import SeqStatus  # noqa: E402
 from dynamo_tpu.models import kda, llama, mamba2  # noqa: E402
 from dynamo_tpu.models.config import (  # noqa: E402
-    LING_3_FLASH_HF, PRESETS, TINY_FALCON_H1_HF, TINY_HYBRID_HF, ModelConfig)
+    LING_3_FLASH_HF, PRESETS, TINY_FALCON_H1_HF, TINY_GRANITE_HYBRID_HF, TINY_HYBRID_HF, ModelConfig)
 from dynamo_tpu.protocols.common import PreprocessedRequest, SamplingOptions, StopConditions  # noqa: E402
 from dynamo_tpu.runtime.engine import Context  # noqa: E402
 from tests.test_mixed_attention import _distance  # noqa: E402  (max |served - reference| logprob over the largest |logit|)
@@ -82,21 +83,44 @@ def _weights_h1(cfg, seed=2**31 + 46):
     from benchmark import weights
 
     params = weights.make_weights(cfg, seed, quant="")
-    layers, keys = params["layers"], jax.random.split(jax.random.PRNGKey(seed % 2**31), 3)
+    _live_mixer(params["layers"], seed)
+    return params
+
+
+def _live_mixer(layers, seed):
+    """The mixers' constants of a stack of layers made live, in place."""
+    keys = jax.random.split(jax.random.PRNGKey(seed % 2**31), 3)
     shape = layers["ssm_dt_bias"].shape  # [layers, heads]
     slow = jnp.arange(shape[1])[None, :] < shape[1] // 2
     layers["ssm_dt_bias"] = jnp.where(slow, jax.random.uniform(keys[0], shape, jnp.float32, -4.6, -3.0), 0.0)
     layers["ssm_a_log"] = jnp.zeros(shape, jnp.float32)
     layers["ssm_d"] = jax.random.normal(keys[1], shape, jnp.float32)
     layers["ssm_conv_bias"] = 0.1 * jax.random.normal(keys[2], layers["ssm_conv_bias"].shape, jnp.float32)
+
+
+def _toy_granite(**edit) -> ModelConfig:
+    return dataclasses.replace(ModelConfig.from_hf({**TINY_GRANITE_HYBRID_HF, **edit}, name="toy-granite"), dtype="float32")
+
+
+def _weights_granite(cfg, seed=2**31 + 49):
+    """The benchmark's weights (plain float32) with the constants of the
+    mixers that stand alone (``ssm_layers``) made live as ``_weights_h1``'s are."""
+    from benchmark import weights
+
+    params = weights.make_weights(cfg, seed, quant="")
+    _live_mixer(params["ssm_layers"], seed)
     return params
 
 
-#: The two recurrent kinds: how a toy of each is made, its plain reference,
-#: and the layer function whose slot handling the tests break.
+#: The recurrent kinds: how a toy of each is made, its plain reference, and
+#: the layer function whose slot handling the tests break. ``mamba2`` is the
+#: mixer beside every layer's attention, ``mamba2-alone`` the mixer as a layer
+#: of its own in periods with one GQA layer.
 KINDS = {
     "kda": dict(toy=_toy, weights=_weights, hf=TINY_HYBRID_HF, ref=ref, module=kda, layer="kda_attention"),
     "mamba2": dict(toy=_toy_h1, weights=_weights_h1, hf=TINY_FALCON_H1_HF, ref=ref_h1, module=mamba2, layer="mamba_mixer"),
+    "mamba2-alone": dict(toy=_toy_granite, weights=_weights_granite, hf=TINY_GRANITE_HYBRID_HF, ref=ref_granite, module=mamba2,
+                         layer="mamba_mixer"),
 }
 both_kinds = pytest.mark.parametrize("kind", sorted(KINDS))
 
@@ -197,7 +221,8 @@ def test_engine_chunked_prefill_and_decode_agree_with_the_reference(served):
     assert {s["layout"] for s in steps if s["step_kind"] == "mixed"} == ({SPLIT} if split else {ROWS_X_T})
     assert max(s["state_rows"] for s in steps) == 2 and max(s["state_slots_live"] for s in steps) == 2
     assert all(s["state_rows"] == s["decode_rows"] + s["chunk_rows"] for s in steps if s["layout"])
-    assert (sum(s["moe_choices"] for s in steps) > 0) == cfg.is_moe and core.runner.recurrent and not core.prefix_matching
+    # (a model that holds all its experts counts no choices: ``moe_choices`` is the held-share layers')
+    assert (sum(s["moe_choices"] for s in steps) > 0) == cfg.moe_held_share and core.runner.recurrent and not core.prefix_matching
     if cfg.ssm_heads:  # every layer attends: the one kind of GQA layer's key tokens are counted
         assert all(s["kv_tokens_full"] > 0 for s in steps if s["layout"])
 
@@ -360,12 +385,14 @@ def test_two_sequences_that_swap_rows_keep_their_states(kind):
 def test_slots_and_pages_are_sized_from_the_model(kind):
     """The runner's buffers take their shapes from the model: a slab of pages
     for every layer that attends (2 of the hybrid's 6; all 3 of the model with
-    a mixer), a slot's part for every recurrent layer (4 of 6; all 3), the
+    a mixer; 2 of the 8 of the model whose mixers stand alone), a slot's part
+    for every recurrent layer (4 of 6; all 3; 6 of 8), the
     state float32 in the kind's own shape, and a slot's bytes as
     ``state_bytes_per_slot`` says."""
     cfg, params, _ = _model(kind)
     runner = ModelRunner(cfg, params, num_pages=8, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
-    want = {"kda": (6, 4, 2, (4, 16, 16), (3, 1, 3 * 64)), "mamba2": (3, 3, 3, (4, 8, 16), (3, 1, 64 + 2 * 2 * 8))}[kind]
+    want = {"kda": (6, 4, 2, (4, 16, 16), (3, 1, 3 * 64)), "mamba2": (3, 3, 3, (4, 8, 16), (3, 1, 64 + 2 * 2 * 8)),
+            "mamba2-alone": (8, 6, 2, (4, 8, 16), (3, 1, 64 + 2 * 8))}[kind]
     state, conv = runner.state
     assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, *cfg.state_shapes()) == want
     assert runner.recurrent and runner.state_slots == 3 and runner.k_cache.shape[0] == cfg.cache_layers

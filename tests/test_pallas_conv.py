@@ -102,6 +102,38 @@ def test_slot_conv_is_the_conv_on_gathered_rows_by_every_way(monkeypatch, way, t
         np.testing.assert_array_equal(kda.conv_heads(y, first, heads, dim), flat[..., first: first + heads * dim].reshape(4, t, heads, dim))
 
 
+@pytest.mark.parametrize("t, impl", [(1, "pallas"), (1, "reference"), (5, "pallas"), (5, "reference")],
+                         ids=["kernel", "reference", "chunk", "chunk-reference"])
+def test_slot_conv_fills_a_buffer_whose_rows_hold_more_than_the_channels(monkeypatch, t, impl):
+    """A channel count whose rows of lanes are no whole sublane tiles
+    (granite-4.0-h-small's 8,448 channels: 66 rows; here 1,152: 9) is held in
+    rows rounded up to eights (``ModelConfig.state_shapes``): ``slot_conv``
+    pads the rows' inputs, the filter and the bias with zeros, by the kernel
+    and by the gather alike; outputs and the carried inputs of the real
+    channels are the flat buffer's, the channels behind them stay zeros, and
+    ``conv_heads`` reads the streams where they lie."""
+    import dataclasses
+
+    from dynamo_tpu.models.config import PRESETS
+
+    cfg = dataclasses.replace(PRESETS["test-tiny-falcon-h1"], ssm_heads=8, ssm_head_dim=128, ssm_state_size=64, ssm_groups=1)
+    channels, tile = cfg.ssm_conv_dim, cfg.state_shapes()[1]
+    assert channels == 9 * 128 and tile == (3, 16, 128)
+    monkeypatch.setattr(pallas_conv, "interpret_mode", lambda: True)
+    c = _case(13, 4, 6, 4, channels, jnp.float32, True, t)
+    y_want, conv_want = (np.asarray(z) for z in _plain(c))
+    held = jnp.pad(c["conv"], ((0, 0), (0, 0), (0, 7), (0, 0)))  # the buffer as it is allocated: 16 rows, the last 7 zeros
+    assert held.shape == (6, *tile)
+    y, conv = kda.slot_conv(held, c["ids"], c["fresh"], c["x"], c["filt"], c["n_valid"], c["bias"], impl=impl)
+    assert conv.shape == held.shape and y.shape == (4, t, 16, 128)
+    np.testing.assert_allclose(np.asarray(y).reshape(4, t, -1)[..., :channels], y_want, atol=2e-6, rtol=2e-6)
+    np.testing.assert_array_equal(np.asarray(conv).reshape(6, 3, -1)[..., :channels], conv_want)
+    assert not np.asarray(conv)[:, :, 9:].any() and not np.asarray(y)[:, :, 9:].any()  # silu(0 + 0) behind the channels
+    flat = np.asarray(y).reshape(4, t, -1)
+    for first, heads, dim in ((0, 8, 128), (1024, 1, 64), (1088, 1, 64)):  # x, B, C
+        np.testing.assert_array_equal(kda.conv_heads(y, first, heads, dim), flat[..., first: first + heads * dim].reshape(4, t, heads, dim))
+
+
 @pytest.mark.parametrize("refused, decode_path", [(None, "pallas"), ("pallas_conv", "fallback"), ("pallas_kda", "fallback")],
                          ids=["every-kernel", "conv-refused", "state-refused"])
 def test_the_step_record_says_fallback_when_a_decode_rows_conv_or_state_leaves_its_kernel(monkeypatch, refused, decode_path):

@@ -2,7 +2,9 @@
 against the plain ``jax.numpy`` step (``models/mamba2.recurrent_step``): slots
 read through their ids, a fresh row read as zeros, the null slot, the state
 written back in place and no other slot touched, at every block of heads the
-VMEM budget can choose (inside a group, a whole group, several groups); and the chunked form (``models/mamba2.chunk_step``) over
+VMEM budget can choose (inside a group, a whole group, several groups), for
+heads of whole lane tiles and for narrower heads that lie side by side on the
+lanes (two of 64 channels: one group of 128 heads, and two groups); and the chunked form (``models/mamba2.chunk_step``) over
 any split of a sequence into chunks, the last one padded, against the
 recurrence token by token."""
 
@@ -42,48 +44,63 @@ def _budget(monkeypatch, block, n, p):
     monkeypatch.setattr(pallas_kda, "STATE_VMEM", 4 * block * 4 * n * p)
 
 
-@pytest.mark.parametrize("rows, heads, groups, n, p, fits, block", [
-    (3, 4, 2, 8, 128, 2, 2),  # a block a group (2 heads), the toy's state
-    (2, 32, 2, 256, 128, 8, 8),  # the published mixer at each block the budget may be set to choose: two blocks a group of 16,
-    (2, 32, 2, 256, 128, 16, 16),  # a block a group,
-    (2, 32, 2, 256, 128, 32, 32),  # and one block over both groups: the grid over rows only
-    (2, 12, 2, 16, 128, 4, 3),  # 6 heads a group, which 4 does not divide: the largest divisor within it
-    (2, 12, 2, 16, 128, 16, 12),  # a head count that is no power of two, both groups in one block
-    (2, 24, 3, 16, 128, 16, 8),  # three groups of 8: 16 would fit but does not divide 24, 12 would cut a group
-    (2, 24, 4, 8, 128, 12, 12),  # four groups of 6, two blocks of two groups each: the group by the grid position
-    (3, 8, 1, 8, 16, 8, 8),  # one group; channels narrower than a lane tile (the interpreter tiles nothing)
+@pytest.mark.parametrize("rows, heads, groups, n, p, fits, block, side", [
+    (3, 4, 2, 8, 128, 2, 2, 1),  # a block a group (2 heads), the toy's state
+    (2, 32, 2, 256, 128, 8, 8, 1),  # the published mixer at each block the budget may be set to choose: two blocks a group of 16,
+    (2, 32, 2, 256, 128, 16, 16, 1),  # a block a group,
+    (2, 32, 2, 256, 128, 32, 32, 1),  # and one block over both groups: the grid over rows only
+    (2, 12, 2, 16, 128, 4, 3, 1),  # 6 heads a group, which 4 does not divide: the largest divisor within it
+    (2, 12, 2, 16, 128, 16, 12, 1),  # a head count that is no power of two, both groups in one block
+    (2, 24, 3, 16, 128, 16, 8, 1),  # three groups of 8: 16 would fit but does not divide 24, 12 would cut a group
+    (2, 24, 4, 8, 128, 12, 12, 1),  # four groups of 6, two blocks of two groups each: the group by the grid position
+    (3, 8, 1, 8, 16, 8, 8, 1),  # one group; channels narrower than a lane tile (the interpreter tiles nothing)
+    # Heads narrower than the lanes, ``side`` of a group side by side in a buffer row (blocks count buffer rows):
+    (2, 128, 1, 128, 64, 32, 32, 2),  # granite-4.0-h's mixer: 128 heads of 64 in one group, 64 rows of two, two blocks of 32 rows
+    (3, 8, 2, 16, 64, 2, 2, 2),  # two groups of 4 heads of 64: two rows a group, a block a group
+    (3, 8, 2, 16, 64, 1, 1, 2),  # a row a step: the group by the grid position
+    (2, 8, 2, 16, 64, 4, 4, 2),  # both groups in one block: each row's group statically
+    (3, 16, 2, 8, 16, 2, 2, 8),  # 8 heads of 16 side by side: a row a group
 ], ids=["toy", "published-8", "published-16", "published-32", "odd-heads", "twelve-heads", "three-groups", "two-groups-a-block",
-        "one-group"])
-def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, groups, n, p, fits, block):
-    _budget(monkeypatch, fits, n, p)
-    assert pallas_kda.heads_block(heads, 4 * n * p, heads // groups) == block
+        "one-group", "side2-published", "side2-two-groups", "side2-a-row-a-step", "side2-one-block", "side8"])
+def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, groups, n, p, fits, block, side):
+    _budget(monkeypatch, fits, n, side * p)
+    assert pallas_kda.heads_block(heads // side, 4 * n * p * side, max(heads // side // groups, 1)) == block
     slots = rows + 3
     c = _case(rows, rows, heads, groups, n, p, slots)
     ids = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, slots))[:rows], jnp.int32)
     fresh = jnp.asarray(np.arange(rows) % 2 == 1)
     before = np.asarray(c["state"])
     y_want, s_want = _plain(c, ids, fresh)
+    laid = mamba2.lay_side_by_side(c["state"], side)
+    assert laid.shape == (slots, heads // side, n, side * p)
+    np.testing.assert_array_equal(mamba2.lay_by_head(laid, side), c["state"])  # the two maps are each other's inverse
     # (not through the jitted wrapper: its cache would answer a second budget with the first one's block)
-    y_got, state = pallas_mamba.mamba_decode_step.__wrapped__(c["state"], ids, fresh, c["x"], c["b"], c["c"], c["dt"], c["a"],
+    y_got, state = pallas_mamba.mamba_decode_step.__wrapped__(laid, ids, fresh, c["x"], c["b"], c["c"], c["dt"], c["a"],
                                                               interpret=True)
+    state = np.asarray(mamba2.lay_by_head(state, side))
     np.testing.assert_allclose(y_got, y_want, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(state)[np.asarray(ids)], s_want, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(state[np.asarray(ids)], s_want, rtol=1e-6, atol=2e-6)
     others = [i for i in range(slots) if i not in set(np.asarray(ids).tolist())]
-    assert np.array_equal(np.asarray(state)[others], before[others])  # bit for bit: never read, never written
+    assert np.array_equal(state[others], before[others])  # bit for bit: never read, never written
 
 
-@pytest.mark.parametrize("fits", [1, 2, 4], ids=["a-head-a-step", "a-block-a-group", "one-block"])
-def test_padding_rows_share_the_null_slot_and_leave_the_live_slots_alone(monkeypatch, fits):
+@pytest.mark.parametrize("fits, p", [(1, 128), (2, 128), (4, 128), (1, 64), (2, 64)],
+                         ids=["a-head-a-step", "a-block-a-group", "one-block", "side2-a-row-a-step", "side2-one-block"])
+def test_padding_rows_share_the_null_slot_and_leave_the_live_slots_alone(monkeypatch, fits, p):
     """What ``mamba_mixer`` hands over for padding rows: slot 0, ``dt = 0`` (no
     decay, no write), and ``fresh`` (their position is 0): the null slot reads
     as zeros and is written as zeros, whatever several rows do to it at once;
-    a live row with ``dt = 0`` leaves its slot as it was, bit for bit."""
+    a live row with ``dt = 0`` leaves its slot as it was, bit for bit. With
+    heads of 128 channels and with two of 64 side by side."""
+    side = 128 // p
     _budget(monkeypatch, fits, 8, 128)
-    c = _case(7, 4, 4, 2, 8, 128, 5)
+    c = _case(7, 4, 4, 2, 8, p, 5)
     ids, fresh = jnp.asarray([0, 3, 0, 2], jnp.int32), jnp.asarray([True, False, True, False])
     dt = c["dt"].at[jnp.asarray([0, 2, 3])].set(0.0)
     before = np.asarray(c["state"])
-    y, state = pallas_mamba.mamba_decode_step.__wrapped__(c["state"], ids, fresh, c["x"], c["b"], c["c"], dt, c["a"], interpret=True)
+    y, state = pallas_mamba.mamba_decode_step.__wrapped__(mamba2.lay_side_by_side(c["state"], side), ids, fresh, c["x"], c["b"],
+                                                          c["c"], dt, c["a"], interpret=True)
+    state = mamba2.lay_by_head(state, side)
     after = np.asarray(state)
     assert np.array_equal(after[[1, 2, 4]], before[[1, 2, 4]]) and not after[0].any() and not np.asarray(y)[[0, 2]].any()
     y_want, s_want = _plain({**c, "dt": dt}, ids, fresh)
@@ -95,6 +112,12 @@ def test_supported_shapes(monkeypatch):
     monkeypatch.setattr(pallas_mamba, "interpret_mode", lambda: False)
     assert pallas_mamba.supported(256, 128) and pallas_mamba.supported(8, 128)
     assert not pallas_mamba.supported(8, 16) and not pallas_mamba.supported(12, 128)
+    # What the predicate is asked is the buffer's row: heads of 64 channels tile where two lie side by side, not alone.
+    narrow = dataclasses.replace(PRESETS["test-tiny-falcon-h1"], ssm_heads=128, ssm_head_dim=64, ssm_state_size=128, ssm_groups=1)
+    assert narrow.ssm_heads_per_row == 2 and narrow.state_shapes()[0] == (64, 128, 128)
+    assert pallas_mamba.supported(*narrow.state_shapes()[0][1:]) and not pallas_mamba.supported(128, 64)
+    odd = dataclasses.replace(narrow, ssm_heads=3, ssm_groups=3)  # a head a group: no two of a group to lay side by side
+    assert odd.ssm_heads_per_row == 1 and not pallas_mamba.supported(*odd.state_shapes()[0][1:])
 
 
 @pytest.mark.parametrize("cuts", [(64,), (13, 40, 11), (1, 1, 62), (7,) * 9 + (1,)], ids=["whole", "ragged", "ones-first", "sevens"])

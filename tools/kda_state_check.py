@@ -5,7 +5,9 @@ configuration's published widths. A KDA layer (Ling's cell, the default) or,
 since PR 46, the Mamba-2 mixer of a layer that runs one beside its attention
 (``--workload falcon-h1-34b-pp8-int8.reason-saturated``: ``models/mamba2.mamba_mixer``
 against the reference's ``mixer``, half the heads' step size cut so that they
-decay by 0.97-0.993 a token).
+decay by 0.97-0.993 a token) or, since PR 49, of a layer that is nothing else
+(``--workload granite-4.0-h-small-pp4-int8.reason-saturated``: 128 heads of 64
+channels, two side by side in a row of the state buffer).
 
     python3 tools/kda_state_check.py [--workload <cell>] --seeds 3 --rows 3 --prompt 192 --decode 8
 
@@ -96,9 +98,10 @@ def check(conf: dict, seed: int, rows: int, prompt: int, decode: int, chunk: int
 
     ref = importlib.import_module(f"benchmark.reference.{conf['reference']}")
     cfg = serving.model_config(conf)
-    if cfg.ssm_heads:  # a mixer beside the attention of every layer: one layer's leaves
+    if cfg.ssm_heads:  # a mixer, beside every layer's attention or a layer of its own (``ssm_layers``): one layer's leaves
         one = dataclasses.replace(cfg, num_layers=1, vocab_size=256)
-        lp = slow_steps(jax.tree.map(lambda x: x[0], weights.make_weights(one, seed, quant=conf["serve"]["quant"])["layers"]), cfg, seed)
+        tree = weights.make_weights(one, seed, quant=conf["serve"]["quant"])
+        lp = slow_steps(jax.tree.map(lambda x: x[0], tree.get("ssm_layers", tree["layers"])), cfg, seed)
         served_layer, ref_layer = mamba2.mamba_mixer, ref.mixer
     else:
         one = dataclasses.replace(cfg, num_layers=cfg.layer_group_size, vocab_size=256)  # one period: its KDA layers' leaves

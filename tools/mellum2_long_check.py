@@ -2,7 +2,7 @@
 """The outputs check of a windowed configuration at lengths that cross its
 window, and the controls that show the check can see the window and the RoPEs.
 
-    python3 tools/mellum2_long_check.py [--workload <cell>] --seeds 3 --variants long,ragged,fp8kv,int4w
+    python3 tools/mellum2_long_check.py [--workload <cell>] --seeds 3 --variants long,ragged,fp8kv,int4w,allw
 
 ``benchmark/correct.py`` checks prompts of 192 and 128 tokens, which never
 reach a window of 1024, and that file is the benchmark's. This script uses the
@@ -29,6 +29,16 @@ fit beside; here each leaf goes as soon as it is re-coded). The KV pool is cut
 to ``--pool-tokens`` in this process only, to leave room for the reference at
 2,308 tokens. Run by hand on the chip; ``JAX_PLATFORMS=cpu`` rehearses at the
 configuration's toy size with lengths cut by its check scale.
+
+``allw`` (PR 49) is the control for a configuration whose residual stream its
+plain leaves carry (granite-4.0-h-small's: ``int4w`` moves a tenth of it and
+reads inside the sound readings): ``int4w``'s re-coding, and besides it every
+plain bf16 matmul leaf (``PLAIN_MATMUL``: the embedding, which a tied model
+also serves as its head, the router and a Mamba-2 mixer's two projections)
+one precision down as well, to e4m3 with a float32 scale per output channel,
+widened back to bf16 for the program. The cell's ``logprob_rel_limit`` lies
+between the sound readings and this control's. ``--pool-tokens 0`` keeps the
+cell's own pool.
 
 ``--where`` (PR 42) says where a reading comes from: for every compared token
 the served logprobs against the float32 reference, and beside it the reference
@@ -83,6 +93,55 @@ def _to_int4_leaf_by_leaf(params):
         return {k: walk(v) for k, v in t.items()} if isinstance(t, dict) else t
 
     return walk(params)
+
+
+#: Plain bf16 leaves that are an operand of a matmul, with the axis their
+#: output channel's scale is taken over (the embedding's row is a lookup and,
+#: tied, the head's output channel; the others are [.., d_in, d_out]).
+PLAIN_MATMUL = {"embed": -1, "router": -2, "w_ssm_in": -2, "w_ssm_out": -2}
+
+
+def _plain_leaves_down(params):
+    """Every ``PLAIN_MATMUL`` leaf re-coded one precision down: e4m3 (three
+    bits of mantissa) with a float32 scale per output channel, widened back to
+    the leaf's dtype; each leaf goes as soon as its re-coding exists. By
+    ``lax.reduce_precision`` on values scaled to e4m3's largest normal of 240:
+    a convert to a float8 type and back is a pair the TPU compiler removes,
+    and the leaf comes back as it went (PERF.md, PR 49). Says how far each
+    leaf moved (rms of the change over rms of the leaf: 0.026), so that a
+    re-coding that did nothing shows."""
+    import jax
+    import jax.numpy as jnp
+
+    def down(w, axis):
+        x = w.astype(jnp.float32)
+        top = jnp.maximum(jnp.max(jnp.abs(x), axis=axis, keepdims=True), 1e-30)
+        back = jax.lax.reduce_precision(x * (240.0 / top), exponent_bits=4, mantissa_bits=3) * (top / 240.0)
+        return back.astype(w.dtype), jnp.sum(jnp.square(back - x)), jnp.sum(jnp.square(x))
+
+    moved = {}
+
+    def leaf(name, w, axis):
+        if w.size <= 2**27:
+            low, err, norm = jax.jit(lambda y: down(y, axis))(w)
+        else:  # a slice of the leading axis at a time: the float32 transient of the whole leaf would not fit
+            cut = (8, w.shape[0] // 8) + w.shape[1:] if w.ndim == 2 else w.shape
+            low, err, norm = jax.jit(lambda y: jax.lax.map(lambda z: down(z, axis), y.reshape(cut)))(w)
+            low = low.reshape(w.shape)
+        moved[name] = round(float(jnp.sqrt(jnp.sum(err) / jnp.sum(norm))), 5)
+        _free(w)
+        return low
+
+    def walk(t):
+        return {k: leaf(k, v, PLAIN_MATMUL[k]) if k in PLAIN_MATMUL and not isinstance(v, dict) else walk(v)
+                for k, v in t.items()} if isinstance(t, dict) else t
+
+    low = walk(params)
+    bench_run.say(plain_leaves_down={"rms_moved": moved})
+    return low
+
+
+TRANSFORMS = {"int4w": _to_int4_leaf_by_leaf, "allw": lambda params: _plain_leaves_down(_to_int4_leaf_by_leaf(params))}
 
 
 def reference_variants(conf: dict) -> dict:
@@ -162,7 +221,7 @@ async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> li
         os.environ["DYN_KV_CACHE_DTYPE"] = "fp8"
     correct.CHECKED = {"long": LONG, "ragged": RAGGED}.get(variant, [(192, 4), (128, 4)])
     state = await bench_run.bring_up(args, bench, cell, rehearsal, warm=False,
-                                     transform=_to_int4_leaf_by_leaf if variant == "int4w" else None)
+                                     transform=TRANSFORMS.get(variant))
     conf, core = state["conf"], state["core"]
     try:
         serve = serve_together if variant == "ragged" else correct.serve_sample
@@ -173,7 +232,7 @@ async def one(args, bench, cell, rehearsal: bool, seed: int, variant: str) -> li
         os.environ.pop("DYN_KV_CACHE_DTYPE", None)
     runner, params = core.runner, state["params"]
     _free(runner.k_cache, runner.v_cache, getattr(runner, "state", ()))  # room for the reference's whole-sequence pass
-    if variant == "int4w":  # the reference reads the weights as configured: make them again
+    if variant in TRANSFORMS:  # the reference reads the weights as configured: make them again
         _free(runner.params, params)
         params = weights.make_weights(serving.model_config(conf), seed, quant=conf["serve"]["quant"])
     rows = []
@@ -206,7 +265,7 @@ async def amain(args) -> int:
 
     def load_with_small_pool(path, *, rehearsal=False):
         conf = load(path, rehearsal=rehearsal)
-        if not rehearsal:
+        if not rehearsal and args.pool_tokens:
             conf["serve"]["engine"]["pool_tokens"] = args.pool_tokens
         return conf
 
@@ -231,7 +290,7 @@ if __name__ == "__main__":
     ap.add_argument("--seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=2600000003)
     ap.add_argument("--variants", default="long,fp8kv,int4w")
-    ap.add_argument("--pool-tokens", type=int, default=12288)
+    ap.add_argument("--pool-tokens", type=int, default=12288, help="0: the cell's own pool")
     ap.add_argument("--where", action="store_true", help="per compared token: served and bfloat16 reference against float32")
     os.environ.setdefault("DYN_FLIGHT_BUFFER", "65536")
     sys.exit(asyncio.run(amain(ap.parse_args())))

@@ -10,7 +10,12 @@ inside the timed function.
 
 Shapes: ``kda`` is Ling-3.0-flash's layer (64 rows, 32 heads of 128 x 128, 15
 layers of 65 slots), ``mamba`` Falcon-H1-34B's mixer (64 rows, 32 heads of
-256 x 128 in 2 groups, 9 layers of 65 slots); the rows' slots are a seeded
+256 x 128 in 2 groups, 9 layers of 65 slots) and, as ``mamba.narrow`` (``--kinds
+mamba`` runs both), granite-4.0-h-small's (64 rows, 128 heads of 128 x 64 in
+one group, 9 layers of 65 slots: heads narrower than the lanes, which the tree
+serves two side by side in a buffer row, ``[slots, 64, 128, 128]``; the bench
+lays the buffer out for a candidate outside the timed function, by the
+candidate's ``layout``, and reads it back head by head to compare); the rows' slots are a seeded
 permutation, not the row order, one row is a padding row (the null slot, no
 decay, no write) and one is fresh. A candidate is called once a layer inside a
 ``lax.scan`` over the layers, as the layer scan calls it, on the layer's own
@@ -81,9 +86,18 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 # kind: rows, heads, groups (0: no groups), state rows (key / N), lanes (value / P), layers, slots a layer
-SHAPES = {"kda": (64, 32, 0, 128, 128, 15, 65), "mamba": (64, 32, 2, 256, 128, 9, 65)}
-TOY = {"kda": (4, 4, 0, 16, 128, 2, 6), "mamba": (4, 4, 2, 8, 128, 2, 6)}
-KERNEL = {"kda": "kda_decode_step", "mamba": "mamba_decode_step", "conv.kda": "slot_conv_step", "conv.mamba": "slot_conv_step"}
+SHAPES = {"kda": (64, 32, 0, 128, 128, 15, 65), "mamba": (64, 32, 2, 256, 128, 9, 65), "mamba.narrow": (64, 128, 1, 128, 64, 9, 65)}
+TOY = {"kda": (4, 4, 0, 16, 128, 2, 6), "mamba": (4, 4, 2, 8, 128, 2, 6), "mamba.narrow": (4, 8, 1, 8, 64, 2, 6)}
+KERNEL = {"kda": "kda_decode_step", "mamba": "mamba_decode_step", "mamba.narrow": "mamba_decode_step",
+          "conv.kda": "slot_conv_step", "conv.mamba": "slot_conv_step"}
+
+
+def layouts(side: int) -> dict:
+    """name -> ``(lay, read back)`` of a Mamba-2 state buffer ``[slots, H, N, P]``: how a candidate's buffer lies."""
+    from dynamo_tpu.models import mamba2
+
+    return {"by_head": (lambda s: s, lambda s: s),
+            "side_by_side": (lambda s: mamba2.lay_side_by_side(s, side), lambda s: mamba2.lay_by_head(s, side))}
 # the conv rows: rows, channels, bias, taps, layers, slots a layer
 SHAPES.update({"conv.kda": (64, 12288, False, 4, 15, 65), "conv.mamba": (64, 5120, True, 4, 9, 65)})
 TOY.update({"conv.kda": (4, 3 * 128, False, 4, 2, 6), "conv.mamba": (4, 5 * 128, True, 4, 2, 6)})
@@ -314,11 +328,14 @@ def bench(kind: str, candidates: dict, shape: tuple, *, seed: int, iters: int, t
             run = jax.jit(scanned(kind, step, slots, ids, fresh), donate_argnums=(0,))
             # A conv candidate on the buffer as it is allocated: channels in rows of 128 lanes, laid out outside the timed function.
             tiled = (lambda z: z.reshape(*z.shape[:2], -1, 128)) if note.get("tiled") else (lambda z: z)
+            back = lambda z: z.reshape(state0.shape)  # noqa: E731
+            if note.get("layout"):  # a Mamba-2 state that lies otherwise than head by head
+                tiled, back = (jax.jit(f) for f in layouts(max(1, 128 // shape[4]))[note["layout"]])
             state, out = run(tiled(jnp.array(state0)), ops)
             if "stream" not in note:
                 row["out_err"] = float(apart(out, want_out))
-                row["state_err"] = float(apart(state.reshape(want_state.shape), want_state))
-                row["kept"] = bool(same_at(state.reshape(state0.shape), state0, untouched))
+                row["state_err"] = float(apart(back(state), want_state))
+                row["kept"] = bool(same_at(back(state), state0, untouched))
             if timed:
                 t0 = time.perf_counter()
                 for _ in range(iters):  # enqueued back to back: each run takes the state the last one gave
@@ -352,9 +369,13 @@ def candidates_of(kind: str, shape: tuple, budgets: list[int], parent: str, extr
 
     if kind.startswith("conv"):
         return {"flat": (xla_step(kind), {}), **conv_candidates(interpret)}
-    own = {"kda": pallas_kda.kda_decode_step, "mamba": pallas_mamba.mamba_decode_step}[kind].__wrapped__
+    own = {"kda": pallas_kda.kda_decode_step}.get(kind, pallas_mamba.mamba_decode_step).__wrapped__
     _, heads, groups, n, p, _, _ = shape
     out = {"xla": (xla_step(kind), {})}
+    lies = {}
+    if p < 128:  # the tree's buffer rows: heads side by side on the lanes
+        side = 128 // p
+        heads, p, lies = heads // side, side * p, {"layout": "side_by_side"}
 
     def at_budget(budget: int):
         def step(*args):
@@ -367,23 +388,23 @@ def candidates_of(kind: str, shape: tuple, budgets: list[int], parent: str, extr
         with state_vmem(budget):
             block = pallas_kda.heads_block(heads, 4 * n * p, heads // groups if groups else 1)
         served = budget == pallas_kda.STATE_VMEM
-        out[f"served@{mib}"] = (at_budget(budget), {"block": block, "served": served})
+        out[f"served@{mib}"] = (at_budget(budget), {"block": block, "served": served, **lies})
         if served or (interpret and mib == budgets[-1]):
             for mode in ("copy", "read", "write"):
-                out[f"{mode}@{block}"] = (stream_step(mode, block, interpret), {"block": block, "stream": mode})
-    if parent:
+                out[f"{mode}@{block}"] = (stream_step(mode, block, interpret), {"block": block, "stream": mode, **lies})
+    if parent and kind in ("kda", "mamba"):
         mod = load_file(pathlib.Path(parent) / "dynamo_tpu" / "ops" / f"pallas_{kind}.py", f"parent_pallas_{kind}")
         fn = getattr(mod, KERNEL[kind]).__wrapped__
         out["parent"] = (functools.partial(fn, interpret=interpret), {"block": getattr(mod, "HEADS_PER_BLOCK", None)})
     if extra is not None:
         for name, fn in extra.CANDIDATES.get(kind, {}).items():
-            out[name] = (functools.partial(fn, interpret=interpret), {})
+            out[name] = (functools.partial(fn, interpret=interpret), {"layout": fn.layout} if hasattr(fn, "layout") else {})
     return out
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--kinds", default="kda,mamba", help="kda, mamba, conv (the conv rows of both cells)")
+    ap.add_argument("--kinds", default="kda,mamba", help="kda, mamba (both its shapes; mamba.narrow: granite's alone), conv (the conv rows of both cells)")
     ap.add_argument("--budgets-mib", default="2,4,8,16", help="STATE_VMEM values to set, MiB")
     ap.add_argument("--parent", default="", help="another tree whose two kernel files are timed as they are")
     ap.add_argument("--extra", default="", help="a Python file with CANDIDATES = {kind: {name: fn}}")
@@ -401,7 +422,8 @@ def main() -> int:
     extra = load_file(pathlib.Path(args.extra), "state_kernel_bench_extra") if args.extra else None
     budgets = [int(v) for v in args.budgets_mib.split(",")] if on_chip else [1, 2, 4]
     table = []
-    for kind in args.kinds.replace("conv", "conv.kda,conv.mamba").split(","):
+    several = {"conv": ["conv.kda", "conv.mamba"], "mamba": ["mamba", "mamba.narrow"]}  # a word that names more than one row set
+    for kind in [k for word in args.kinds.split(",") for k in several.get(word, [word])]:
         shape = (SHAPES if on_chip else TOY)[kind]
         cands = candidates_of(kind, shape, budgets, args.parent, extra, interpret=not on_chip)
         table += bench(kind, cands, shape, seed=args.seed, iters=args.iters if on_chip else 1, timed=on_chip, peak=peak)

@@ -13,7 +13,10 @@ or more: a ``copy``, a ``transpose``, or a fusion that holds nothing but a
 slice, a copy or a transposition. Each with its shape, the layouts it reads and
 writes, its bytes, how often a step runs it and the ``op_name`` it came from;
 then the bytes a step re-lays in all, and how many of them are ``s8``
-(a quantized weight).
+(a quantized weight). For a model whose head is its embedding
+(``tie_embeddings``) also ``embedding_copies``: every instruction, inside a
+loop or not, that makes an array of the embedding's elements again (the head
+contracts the embedding where it lies: the list is empty).
 
 A projection whose output is split into heads should read its weight where it
 lies (``models/quant.held_flat``). Where it does not, the compiler pushes
@@ -232,6 +235,32 @@ def body_counts(text: str) -> dict:
     return out
 
 
+def embedding_copies(text: str, vocab: int, hidden: int) -> list[dict]:
+    """Every instruction of a compiled program, inside a loop or not, that
+    makes an array of the embedding's elements again: of shape ``[vocab,
+    hidden]`` or ``[hidden, vocab]``, whatever its dtype, and not the
+    parameter itself, a bitcast of it (a fusion that holds nothing but
+    bitcasts is one) or its way through a tuple. A tied head
+    (``tie_embeddings``) contracts the embedding where the gather reads it;
+    an entry here is a transposed or re-laid copy of it (822 MB a step at
+    100,352 x 4,096 bfloat16)."""
+    comps = _computations(text)
+    found = []
+    for comp, instructions in comps.items():
+        for ins in instructions:
+            shape = _SHAPE.match(ins["shape"])
+            if not shape or shape.group(2) not in (f"{vocab},{hidden}", f"{hidden},{vocab}"):
+                continue
+            if ins["opcode"] in ("parameter", "bitcast", "get-tuple-element"):
+                continue
+            if ins["opcode"] == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", ins["rest"]).group(1)
+                if all(i["opcode"] in ("parameter", "bitcast") for i in comps.get(called, [])):
+                    continue
+            found.append({"name": ins["name"], "opcode": ins["opcode"], "shape": ins["shape"], "in": comp})
+    return found
+
+
 def summary(found: list[dict]) -> dict:
     total = sum(f["bytes"] * f["times"] for f in found)
     s8 = sum(f["bytes"] * f["times"] for f in found if f["dtype"] == "s8")
@@ -321,6 +350,8 @@ def main() -> int:
             continue
         out["steps"][label] = {"fusions": len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = .* fusion\(", text, re.M)),
                                **summary(relayouts(text))}
+        if mc.tie_embeddings:  # the head is the embedding: no step program may make a second copy of it
+            out["steps"][label]["embedding_copies"] = embedding_copies(text, mc.vocab_size, mc.hidden_size)
     print(json.dumps(out, indent=1))
     return 0
 
