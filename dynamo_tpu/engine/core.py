@@ -778,6 +778,7 @@ class EngineCore:
                 kv_tokens_full=report.kv_tokens_full,
                 kv_tokens_window=report.kv_tokens_window,
                 step_tokens=report.step_tokens,
+                moe_pad_positions=report.moe_pad_positions,
                 moe_choices=report.moe_counts[0],  # parallel/moe.HELD_COUNTS, in its order: plain keywords,
                 moe_choices_zero=report.moe_counts[1],  # because a ** in the middle takes the whole call
                 moe_choices_held=report.moe_counts[2],  # off the interpreter's fast path (0.015 ms a step

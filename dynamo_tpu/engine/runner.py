@@ -342,6 +342,7 @@ class DispatchReport:
     router_select: str = ""  # "passes" / "sort": how the router takes the step's tokens' top k; "" for a dense model
     layout: str = ""  # ROWS_X_T / SPLIT
     step_tokens: int = 0  # token positions the dispatched program computes
+    moe_pad_positions: int = 0  # those of them that are padding (slot 0): a routed model's expert layers route them nowhere
     kv_tokens_full: int = 0  # key tokens one full / one windowed attention (sub)layer visits
     kv_tokens_window: int = 0
     # parallel/moe.HELD_COUNTS, counted on the device by the programs of a model
@@ -1088,6 +1089,8 @@ class ModelRunner:
         report.attn_phase, report.attn_path = self._attn_dispatch(padded, impl, verify=verify, split=layout[0] == SPLIT)
         report.layout, report.step_tokens = layout
         report.router_select = router_select(report.step_tokens, self._router_outputs, self.cfg.num_experts_per_token)
+        if report.moe_path:
+            report.moe_pad_positions = report.step_tokens - int(np.count_nonzero(padded.slot_mapping))
         if padded.state_slots is not None:
             report.state_rows = int(np.count_nonzero(padded.state_slots))
         timed = timed_dispatch(self.compile_tracker, program, key)
@@ -1183,8 +1186,8 @@ class ModelRunner:
         explicit M-RoPE rows), more chunk rows than ``MAX_CHUNK_SLOTS``, a
         chunk row whose first token is chained (no engine composes one: a
         chunk's tokens are the host's), or a step the split would not make
-        smaller (a lone chunk row: its one decode slot would be padding, and a
-        padding token still routes through experts of its own)."""
+        smaller (a lone chunk row: its one decode slot would be padding, one
+        position more than the rectangle computes)."""
         bp, tp = padded.tokens.shape
         if (not self._can_split or tp == 1 or padded.mm_embeds is not None
                 or padded.logit_mask is not None or padded.mrope_positions is not None):

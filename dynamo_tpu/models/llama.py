@@ -283,8 +283,10 @@ def _routing_kwargs(cfg: ModelConfig) -> dict:
     )
 
 
-def _mlp_moe(lp: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None) -> jnp.ndarray:
-    """Top-k routed MoE (``dynamo_tpu/parallel/moe.py``).
+def _mlp_moe(lp: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None, valid: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Top-k routed MoE (``dynamo_tpu/parallel/moe.py``). ``valid``
+    (``bool[B, T]``, a paged step's ``slot_mapping != 0``) keeps padding
+    positions out of the dropless dispatch: they route to no expert.
 
     Without an ``ep`` mesh axis: dropless ragged-matmul dispatch — exact,
     batch-composition-independent (deterministic greedy). With experts
@@ -313,7 +315,8 @@ def _mlp_moe(lp: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None) -> jnp.nda
         out = _routed_dense(lp, xt, cfg)
     elif ep <= 1 and dispatch not in ("capacity", "dense"):
         out = moe_mlp_dropless(
-            lp, xt, num_experts_per_token=cfg.num_experts_per_token, routing=routing, mesh=mesh
+            lp, xt, num_experts_per_token=cfg.num_experts_per_token, routing=routing, mesh=mesh,
+            valid=None if valid is None else valid.reshape(-1),
         )
     else:
         cf = cfg.moe_capacity_factor
@@ -725,6 +728,10 @@ def forward(
         return attn_out, k_full, v_full
 
     def make_layer_step(moe_layer: bool):
+        # A whole-expert layer's padding mask, made once before the scan: made in the
+        # layer's body it is one more instruction a layer (compiled for a v5e: PERF.md, PR 50).
+        routed_valid = slot_mapping != 0 if moe_layer and not cfg.moe_held_share else None
+
         def ffn(lp, h2, counts: list):
             """The layer's FFN on the normed stream; a model that holds a share
             of its experts carries their counters beside the stream."""
@@ -734,7 +741,7 @@ def forward(
                 if cfg.moe_held_share:
                     mlp, counted = _mlp_moe_held(lp, h2, cfg, slot_mapping != 0, mesh)
                     return mlp, [counts[0] + counted]
-                return _mlp_moe(lp, h2, cfg, mesh), counts
+                return _mlp_moe(lp, h2, cfg, mesh, routed_valid), counts
 
         def layer_step(carry, lp):
             x, k_full, v_full, li, *counts = carry
@@ -831,7 +838,7 @@ def forward(
                     mlp, counted = _mlp_moe_held(join_expert_stack(lp, expert_stack, i_moe), h2, cfg, valid, mesh)
                     counts = [counts[0] + counted]
                 else:
-                    mlp = _mlp_moe(join_expert_stack(lp, expert_stack, i_moe), h2, cfg, mesh)
+                    mlp = _mlp_moe(join_expert_stack(lp, expert_stack, i_moe), h2, cfg, mesh, valid)
             return (joined(x, mlp), kf, vf, state, conv, *counts)
 
         def recurrent_layer(carry, i_rec, ffn_layers, i_ffn, routed: bool):

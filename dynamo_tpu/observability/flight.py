@@ -55,7 +55,7 @@ STEP_KEYS = (
     "admission_rejections", "mixed_steps", "stall_violations",
     "spec_drafted", "spec_accepted", "spec_accept_rate", "wall_ms",
     "dispatch_ms", "attn_phase", "attn_path", "moe_path", "router_select",
-    "kv_tokens_full", "kv_tokens_window", "step_tokens",
+    "kv_tokens_full", "kv_tokens_window", "step_tokens", "moe_pad_positions",
     "moe_choices", "moe_choices_zero", "moe_choices_held", "moe_experts_touched",
     "moe_extra_passes", "state_rows", "state_slots_live",
     "full_pages_live", "window_pages_live", "window_pages_released", "layout",

@@ -310,6 +310,7 @@ def moe_mlp_dropless(
     *,
     num_experts_per_token: int,
     routing: dict | None = None,
+    valid: jnp.ndarray | None = None,  # bool[N]: padding tokens route nowhere (None: every token is valid)
     mesh=None,
 ) -> jnp.ndarray:
     """Dropless routed MoE via grouped matmuls: ``lax.ragged_dot``, or for
@@ -322,6 +323,14 @@ def moe_mlp_dropless(
     output is exact and independent of batch composition — the default
     serving path whenever the expert axis is not sharded (parity with the
     dropless DeepEP-style dispatch the reference gets from SGLang).
+
+    A token that ``valid`` masks (a step's padding) has no copies in the
+    grouped matmuls, by the convention :func:`moe_mlp_held` shares: its
+    copies sort last, the group sizes leave them out (an expert that only
+    padding chose is never visited and its weights are never read), and its
+    rows, which no matmul wrote, come out as zeros. A valid token's copies
+    keep their order among themselves and every row is its own contraction,
+    so its output is bit for bit what it is without the mask.
     """
     n, d = x.shape
     e = lp["router"].shape[-1]
@@ -331,11 +340,13 @@ def moe_mlp_dropless(
     # (what a profile shows instead of ``bitcast_multiply_fusion.N``).
     with jax.named_scope("moe.router"):
         weights, topi = route_tokens(lp, x, k=k, **(routing or {}))
+        if valid is not None:
+            topi = jnp.where(valid[:, None], topi, e)  # one past the last expert: sorted last, in no group
 
         flat_e = topi.reshape(-1)  # [N*k]
         order = jnp.argsort(flat_e, stable=True)
         xk = jnp.repeat(x, k, axis=0)[order]  # [N*k, D] grouped by expert
-        group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+        group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)  # a key past the length is dropped
 
     if experts_path(lp, mesh=mesh) == "fused":
         from dynamo_tpu.ops.pallas_moe import expert_ffn_int8
@@ -356,6 +367,8 @@ def moe_mlp_dropless(
     with jax.named_scope("moe.combine"):
         rows = jnp.zeros_like(down).at[order].set(down)  # unsort
         out = (rows.astype(jnp.float32) * weights.reshape(-1)[:, None]).reshape(n, k, d).sum(axis=1)
+        if valid is not None:  # rows past the groups were never computed: a select, not a product with 0
+            out = jnp.where(valid[:, None], out, 0.0)
         return out.astype(x.dtype)
 
 
@@ -411,6 +424,11 @@ def moe_mlp_held(
     gather and scatter-add. Returns ``(out [N, D], counts i32[5])``, the
     counts as :data:`HELD_COUNTS` names them, over ``valid`` tokens; the last
     is 1 where the pass over every copy's rows ran.
+
+    A token that ``valid`` masks (a step's padding) routes nowhere, by the
+    convention :func:`moe_mlp_dropless` shares: its copies sort last (with
+    those held elsewhere), stand in no expert's size, and the rows past the
+    sized ones, which no matmul wrote, are zeroed in the combine.
     """
     n, d = x.shape
     k = num_experts_per_token

@@ -666,6 +666,25 @@ def test_held_layer_routes_a_decode_step_without_sort_scatter_or_loop(sds, monke
     assert 0 < counts["executed"] <= at_most, counts
 
 
+# -- a padding position routes to no expert in the whole-expert layer (ISSUE 50) ------------------
+
+def test_the_padding_mask_adds_nothing_that_moves_data_to_mellum2s_decode_layer(sds, monkeypatch):
+    """The 8-row decode step of Mellum2 (six rows decode in it in the
+    longctx-decode cell), two layers at the published widths, compiled for the
+    described chip: ``moe_mlp_dropless`` takes ``slot_mapping != 0`` there, and
+    the layer scan's body holds the copies, transpositions, sorts and gathers
+    it held on the parent (commit a9667e6: the counts pinned here) and no
+    instruction more than the parent's 100: the mask is made once before the
+    scan and its two selects ride fusions that were there (the keys' flatten,
+    the residual's add)."""
+    from tests.test_step_relayouts import load_tool
+
+    counts = load_tool().body_counts(_two_layer_step_text(sds, monkeypatch, "mellum2-12b-a2.5b-int8", 8, False))
+    assert all(counts["moving"][op] <= n for op, n in {"copy": 10, "transpose": 18, "sort": 2, "gather": 5}.items()), counts
+    assert len(counts["sorts"]) == 2 and len(counts["moe_scatters"]) == 2, counts
+    assert 0 < counts["executed"] <= 100 and not counts["arms"] and not counts["nested"]["while"], counts
+
+
 # -- a hybrid stack: KDA layers in slots beside the paged latent cache (ISSUE 40) ----------------
 
 @pytest.mark.parametrize("budget_mib, block", [(None, 32), (4, 16), (2, 8)], ids=["served", "4MiB", "2MiB"])

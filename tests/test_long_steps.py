@@ -207,7 +207,7 @@ def test_the_step_record_keeps_its_keys_and_the_tokens_are_those_of_a_run_withou
     from dynamo_tpu.observability.flight import STEP_KEYS
 
     with_long, steps = drive(make_core(clock, at=42, where="wait"))
-    assert len(long_spans()) == 1 and all(tuple(r) == STEP_KEYS for r in steps) and len(STEP_KEYS) == 51  # 44 + `moe_extra_passes` (ISSUE 39) + `state_rows`, `state_slots_live` (ISSUE 40) + the three pool keys (ISSUE 42) + `router_select` (ISSUE 43)
+    assert len(long_spans()) == 1 and all(tuple(r) == STEP_KEYS for r in steps) and len(STEP_KEYS) == 52  # 44 + `moe_extra_passes` (ISSUE 39) + `state_rows`, `state_slots_live` (ISSUE 40) + the three pool keys (ISSUE 42) + `router_select` (ISSUE 43) + `moe_pad_positions` (ISSUE 50)
     assert not {"lost_ms", "expected_ms", "period_ms", "cause"} & set(STEP_KEYS)
     core = make_core(clock)
     core.sentinel.observe_period = lambda *a: 0.0  # no detector

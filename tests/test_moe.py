@@ -357,3 +357,123 @@ def test_a_row_whose_finite_entries_run_out_returns_what_top_k_returns():
     best = np.asarray(scores).reshape(n, 8, 4).max(-1).argmax(-1)  # the kept group, then the row's first entries
     assert all(sorted(row[:4]) == list(range(4 * g, 4 * g + 4)) for row, g in zip(np.asarray(got_i).tolist(), best))
     assert all(row[4:] == [i for i in range(e) if i // 4 != g][:4] for row, g in zip(np.asarray(got_i).tolist(), best))
+
+
+# -- a padding position routes to no expert in the whole-expert layer (ISSUE 50) --
+
+#: family: (routed experts, experts a token, the shared expert's width, the preset whose routing it takes)
+WHOLE = {
+    "softmax-top8-of-64": (64, 8, 0, "olmoe-1b-7b"),  # OLMoE's and Mellum2's router
+    "granite-top10-of-72-shared": (72, 10, 128, "test-tiny-granite-hybrid"),
+}
+_HIDDEN, _WIDTH = 256, 128
+#: ``len(jax.make_jaxpr(moe_mlp_dropless)(...).jaxpr.eqns)`` with no mask, counted on the parent of the PR that
+#: brought ``valid`` (commit a9667e6): a call that hands no mask traces nothing of it.
+PARENT_EQUATIONS = {"ragged_dot": 62, "fused": 49}  # (either family: the router's width changes no equation)
+
+
+def _whole_layer(family: str, seed: int = 3):
+    """``(cfg, lp)``: one whole-expert layer of int8 experts at a narrow
+    width (``tests/test_pallas_moe.py``'s leaves: the kernel takes them under
+    the interpreter, ``ragged_dot`` widens them), with the family's routing."""
+    import dataclasses
+
+    from tests.test_pallas_moe import _leaves
+
+    e, k, shared, preset = WHOLE[family]
+    cfg = dataclasses.replace(PRESETS[preset], num_experts=e, num_experts_per_token=k, hidden_size=_HIDDEN,
+                              shared_expert_size=shared)
+    lp = _leaves(_WIDTH, seed=seed, e=e, d=_HIDDEN)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 3)
+    for key, name, shape in zip(ks, ("w_shared_gate", "w_shared_up", "w_shared_down"),
+                                ((_HIDDEN, shared), (_HIDDEN, shared), (shared, _HIDDEN))):
+        if shared:
+            lp[name] = (jax.random.normal(key, shape, jnp.float32) * shape[0] ** -0.5).astype(jnp.bfloat16)
+    return cfg, lp
+
+
+def _tokens_with_planted_padding(lp, cfg, masked):
+    """Eight tokens; those at ``masked`` are one token (as a step's padding
+    is) that leans on the router's last ``k`` outputs, so that it chooses
+    experts of its own."""
+    k = cfg.num_experts_per_token
+    x = np.random.default_rng(11).standard_normal((8, _HIDDEN)).astype(np.float32)
+    own = np.asarray(lp["router"], np.float32)[:, -k:].sum(axis=1)
+    x[list(masked)] = own * (_HIDDEN ** 0.5 / np.linalg.norm(own))
+    return jnp.asarray(x, jnp.bfloat16)
+
+
+@pytest.fixture
+def visits(monkeypatch):
+    """The group sizes every grouped matmul of a call was handed, on either
+    path (eager calls: the sizes are concrete where they are handed over)."""
+    from dynamo_tpu.ops import pallas_moe
+
+    seen = []
+
+    def spy(fn, position):
+        def call(*args, **kwargs):
+            seen.append(np.asarray(args[position]))
+            return fn(*args, **kwargs)
+        return call
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", spy(jax.lax.ragged_dot, 2))
+    monkeypatch.setattr(pallas_moe, "expert_ffn_int8", spy(pallas_moe.expert_ffn_int8, 4))
+    return seen
+
+
+@pytest.mark.parametrize("case", ["three-of-eight-masked", "none-masked", "all-masked"])
+@pytest.mark.parametrize("path", ["ragged_dot", "fused"])
+@pytest.mark.parametrize("family", list(WHOLE))
+def test_a_masked_token_has_no_copies_in_the_grouped_matmuls(family, path, case, monkeypatch, visits):
+    """``moe_mlp_dropless`` under ``_mlp_moe`` with ``valid``: a valid token's
+    output is bit for bit what the call without a mask gives it, a masked
+    token's routed part is zeros (the shared expert's term alone is left), and
+    the experts that get a visit are those the valid tokens chose, no other."""
+    from dynamo_tpu.parallel import moe
+
+    if path == "fused":
+        monkeypatch.setenv("DYNAMO_PALLAS_INTERPRET", "1")  # interpret mode stands in for the TPU
+    cfg, lp = _whole_layer(family)
+    assert moe.experts_path(lp) == ("fused" if path == "fused" else "widened")
+    masked = {"three-of-eight-masked": (2, 4, 7), "none-masked": (), "all-masked": tuple(range(8))}[case]
+    valid = np.ones(8, bool)
+    valid[list(masked)] = False
+    x = _tokens_with_planted_padding(lp, cfg, (2, 4, 7))
+    _, topi = moe.route_tokens(lp, x, k=cfg.num_experts_per_token, **llama._routing_kwargs(cfg))
+    topi = np.asarray(topi)
+    assert set(topi[2]) == set(topi[4]) == set(topi[7]) and set(topi[2]) - set(topi[[0, 1, 3, 5, 6]].ravel())  # planted
+
+    unmasked = np.asarray(llama._mlp_moe(lp, x[None], cfg)[0], np.float32)
+    visited_unmasked = [set(np.flatnonzero(sizes)) for sizes in visits]
+    del visits[:]
+    got = np.asarray(llama._mlp_moe(lp, x[None], cfg, None, jnp.asarray(valid)[None])[0], np.float32)
+    visited = [set(np.flatnonzero(sizes)) for sizes in visits]
+
+    assert np.isfinite(got).all()
+    assert np.array_equal(got[valid], unmasked[valid])
+    shared = np.asarray(llama._shared_expert(lp, x, cfg), np.float32) if cfg.shared_expert_size else np.zeros_like(got)
+    assert np.array_equal(got[~valid], shared[~valid])
+    assert visited and all(v == set(topi[valid].ravel()) for v in visited)
+    assert all(int(sizes.sum()) == valid.sum() * cfg.num_experts_per_token for sizes in visits)
+    assert all(v == set(topi.ravel()) for v in visited_unmasked)
+    assert (visited == visited_unmasked) == (case == "none-masked")  # no padding: the visits of a call without a mask
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "fused"])
+@pytest.mark.parametrize("family", list(WHOLE))
+def test_a_call_without_a_mask_traces_nothing_of_it(family, path, monkeypatch):
+    from dynamo_tpu.parallel import moe
+
+    if path == "fused":
+        monkeypatch.setenv("DYNAMO_PALLAS_INTERPRET", "1")
+    cfg, lp = _whole_layer(family)
+    x = _tokens_with_planted_padding(lp, cfg, ())
+
+    def equations(valid):
+        fn = lambda lp, x: moe.moe_mlp_dropless(  # noqa: E731
+            lp, x, num_experts_per_token=cfg.num_experts_per_token, routing=llama._routing_kwargs(cfg), valid=valid)
+        return len(jax.make_jaxpr(fn)(lp, x).jaxpr.eqns)
+
+    assert equations(None) == PARENT_EQUATIONS[path]
+    assert equations(jnp.ones(8, bool)) > equations(None)

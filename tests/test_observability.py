@@ -1208,3 +1208,43 @@ def test_step_records_carry_router_select_beside_moe_path(case, monkeypatch):
     assert {(tokens, outputs, k): word for tokens, word in said} == traced
     if case == "grouped_wide":
         assert (16, "passes") in said and min(said)[1] == "sort"
+
+
+@pytest.mark.parametrize("case", ["whole_six_of_eight", "whole_full_bucket", "held_six_of_eight", "dense_six_of_eight"])
+def test_step_records_count_the_positions_the_expert_layers_route_nowhere(case):
+    """``moe_pad_positions``: the padding among a dispatched program's token
+    positions, which a routed model's expert layers (either family) route to
+    no expert. Six rows decoding in the 8-row program say 2, a full bucket 0,
+    a dense model 0 whatever its padding, a step that dispatched nothing 0."""
+    from dynamo_tpu.engine.core import EngineConfig, EngineCore
+    from dynamo_tpu.engine.runner import ModelRunner
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import PRESETS
+    from dynamo_tpu.observability.flight import STEP
+    from dynamo_tpu.protocols.common import PreprocessedRequest, SamplingOptions, StopConditions
+
+    model, rows = {"whole_six_of_eight": ("test-tiny-moe", 6), "whole_full_bucket": ("test-tiny-moe", 8),
+                   "held_six_of_eight": ("test-tiny-scmoe", 6), "dense_six_of_eight": ("test-tiny", 6)}[case]
+    cfg = PRESETS[model]
+    runner = ModelRunner(cfg, llama.init_params(cfg, 0), num_pages=64, page_size=4, max_batch_size=8,
+                         prefill_bucket=16, attn_impl="reference")
+    core = EngineCore(runner, EngineConfig(num_pages=64, page_size=4, max_batch_size=8,
+                                           max_prefill_tokens=64, max_seq_len=64))
+    for i in range(rows):
+        core.add_request(PreprocessedRequest(
+            token_ids=list(range(1 + i, 6 + i)), sampling=SamplingOptions(temperature=0.0),
+            stop=StopConditions(max_tokens=6, ignore_eos=True)))
+    for _ in range(64):
+        if not core.has_work:
+            break
+        core.step()
+    records = core.flight.snapshot(kind=STEP)
+    decodes = [r for r in records if r["step_tokens"] and r["decode_rows"] == rows and not r["chunk_rows"]]
+    assert len(decodes) >= 3 and all(r["step_tokens"] == 8 for r in decodes)
+    want = 0 if model == "test-tiny" else 8 - rows
+    assert [r["moe_pad_positions"] for r in decodes] == [want] * len(decodes)
+    assert all(type(r["moe_pad_positions"]) is int for r in records)
+    for r in records:
+        routed = bool(r["moe_path"])
+        assert r["moe_pad_positions"] == routed * (r["step_tokens"] - r["decode_rows"] - r["chunk_tokens"]), r
+    assert all(r["moe_pad_positions"] == 0 for r in records if not r["step_tokens"])

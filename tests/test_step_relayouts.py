@@ -206,6 +206,7 @@ def test_the_layer_bodys_instructions_are_counted_by_scope(tool):
                                   "other/cond": 1}
     assert counts["sorts"] == ["sort.4"]
     assert counts["moe_scatters"] == ["scatter-add.3"]  # the router's, inside its fusion; not the attention's
+    assert counts["moving"] == {"copy": 0, "transpose": 0, "sort": 1, "gather": 0}
 
 
 @pytest.mark.parametrize("op_name, scope", [
@@ -288,6 +289,19 @@ def test_an_operations_own_time_is_what_nothing_nested_in_it_covers(ops_tool):
     assert (ops["fusion.2"]["parent"], ops["fusion.2"]["depth"], ops["fusion.2"]["calls_per_run"]) == ("while.2", 2, 1.0)
     assert ops["fusion.1"]["calls_per_run"] == 1.5 and ops["head"]["depth"] == 0
     assert ops["fusion.1"]["line"].startswith("%fusion.1 = f32[2]") and ops["fusion.1"]["stats"] == {"device_duration_ps": 1}
+
+
+def test_a_program_can_be_named_by_a_part_of_its_modules_name(ops_tool):
+    """``--program``: the commonest of the step programs whose module's name
+    holds the word (a window with more mixed steps than decode steps still
+    gives the decode step's table); a word no program has is an error that
+    lists what the trace holds."""
+    table = ops_tool.ops_table("anywhere", "packed(2)")
+    assert (table["module"], table["runs"], table["step_programs_in_trace"]) == ("jit__step_packed(2)", 1, 3)
+    assert [op["name"] for op in table["ops"]] == ["fusion.9"]
+    assert ops_tool.ops_table("anywhere", "_step_packed")["module"] == "jit__step_packed(1)"
+    with pytest.raises(ValueError, match=r"_step_split.*jit__step_packed\(1\)"):
+        ops_tool.ops_table("anywhere", "_step_split")
 
 
 def test_scopes_come_from_the_programs_text_by_instruction_name(ops_tool):

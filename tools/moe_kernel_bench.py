@@ -13,7 +13,9 @@ time is not a device number.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
+import os
 import pathlib
 import sys
 import time
@@ -32,8 +34,11 @@ def main() -> int:
     ap.add_argument("--width", type=int, default=1024)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--skip-widened", action="store_true")
+    ap.add_argument("--padding", default="", help="padding tokens among a step's: time the whole layer, routed and masked")
     ap.add_argument("--rehearse", action="store_true", help="off-TPU: interpret mode, control flow only, no times")
     args = ap.parse_args()
+    if args.rehearse:
+        os.environ["DYNAMO_PALLAS_INTERPRET"] = "1"  # the whole layer asks the program's own predicate for its path
 
     import jax
     import jax.numpy as jnp
@@ -109,6 +114,49 @@ def main() -> int:
         out.block_until_ready()
         # A CPU time is never written under a device metric's name.
         return (float("nan") if args.rehearse else (time.perf_counter() - t0) / args.iters * 1e3), out
+
+    def whole_layer(m, padding):
+        """``moe_mlp_dropless`` over the stacked layers on ``m // k`` tokens, the last ``padding`` of them one token."""
+        from dynamo_tpu.parallel import moe
+
+        n = m // k
+        keys = jax.random.split(jax.random.PRNGKey(m + padding), 3)
+        x = jax.random.normal(keys[0], (n, d), jnp.bfloat16)
+        x = x.at[n - padding:].set(jax.random.normal(keys[1], (d,), jnp.bfloat16)) if padding else x
+        layers = {"router": jax.random.normal(keys[2], (nl, d, e), jnp.bfloat16), **stack}
+        path = moe.experts_path(layers)  # "fused" on a TPU: what the cells serve
+        xs, experts = moe.split_expert_stack(layers)  # as ``llama.forward`` scans a stack of layers
+        live = jnp.arange(n) < n - padding
+
+        @functools.partial(jax.jit, static_argnames=("masked", "count"))
+        def run(experts, xs, x, *, masked, count):
+            def layer(carry, lp):
+                li, x = carry
+                lp = moe.join_expert_stack(lp, experts, li)
+                out = moe.moe_mlp_dropless(lp, x, num_experts_per_token=k, valid=live if masked else None)
+                visited = None
+                if count:  # the experts whose group has a row: those the tokens in the matmuls chose
+                    _, topi = moe.route_tokens(lp, x, k=k)
+                    chosen = (topi[:, :, None] == jnp.arange(e)).any(axis=1) & (live[:, None] if masked else True)
+                    visited = chosen.any(axis=0).sum()
+                h = x.astype(jnp.float32) + out.astype(jnp.float32)  # the stream goes on, at a norm of one an entry
+                return (li + 1, (h * jax.lax.rsqrt((h * h).mean(axis=-1, keepdims=True) + 1e-6)).astype(x.dtype)), visited
+
+            (_, x), visited = jax.lax.scan(layer, (jnp.int32(0), x), xs)
+            return (x, visited) if count else x
+
+        for variant in ("routed", "masked"):
+            masked = variant == "masked"
+            ms, _ = timed(functools.partial(run, masked=masked, count=False), experts, xs, x)
+            visited = run(experts, xs, x, masked=masked, count=True)[1]
+            print(json.dumps({"copies": m, "tokens": n, "padding": padding, "variant": variant, "ms_per_layer": ms / nl,
+                              "experts_visited_per_layer": float(visited.mean()), "layers": nl, "path": path}), flush=True)
+
+    if args.padding:
+        for m in (int(c) for c in args.copies.split(",")):
+            for padding in (int(v) for v in args.padding.split(",")):
+                whole_layer(m, padding)
+        return 0
 
     variants = [(0, 0, 0, 0)] + [tuple(int(v) for v in t.split(":")) for t in args.tiles.split(",") if t]
     for m in (int(c) for c in args.copies.split(",")):

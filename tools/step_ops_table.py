@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Every device operation of a cell's commonest step program, by scope: on the chip.
 
-    python3 tools/step_ops_table.py --workload <cell> --seed N [--seconds 51]
+    python3 tools/step_ops_table.py --workload <cell> --seed N [--seconds 51] [--program _step_packed]
     python3 tools/step_ops_table.py --scopes chiprun_out/step_ops-<cell>.json [--hlo <compiled text>]
 
 The benchmark's ``breakdown.device_ops`` keeps the ten operations with most
@@ -9,7 +9,9 @@ time; a layer body of a hundred small operations hides below its tenth entry
 (PERF.md, PR 39). This runs the cell as ``benchmark/run.py --trace 1`` does
 (its ``main``, one process) and, before the trace's files are thrown away,
 reads them once more for the step program the traced seconds hold most often
-(a saturated cell's full decode step): every operation of the device's
+(a saturated cell's full decode step; ``--program`` names another by a part
+of its module's name: ``_step_packed`` is the decode step where a window holds
+more mixed steps, ``_step_split`` the chunk step): every operation of the device's
 ``XLA Ops`` line inside each of its runs, its own time (what no operation
 nested in it covers: a ``while``'s own time is its conditions and the gaps
 between its children) and its whole time, summed by name and divided by the
@@ -41,9 +43,10 @@ sys.path.insert(0, str(ROOT / "benchmark"))
 sys.path.insert(0, str(ROOT / "tools"))
 
 
-def ops_table(trace_dir: str) -> dict:
+def ops_table(trace_dir: str, program: str = "") -> dict:
     """The per-operation table of the commonest step program in the trace
-    under ``trace_dir`` (the profiler's own files)."""
+    under ``trace_dir`` (the profiler's own files), of those whose module's
+    name holds ``program``."""
     import jax
 
     from benchmark import trace_reduce as tr
@@ -57,7 +60,10 @@ def ops_table(trace_dir: str) -> dict:
     by_name: dict[str, list] = {}
     for m in mods:
         by_name.setdefault(m[0], []).append(m)
-    module, runs = max(by_name.items(), key=lambda kv: len(kv[1]))
+    named = {name: ms for name, ms in by_name.items() if program in name}
+    if not named:
+        raise ValueError(f"no step program named *{program}* in the trace: {sorted(by_name)}")
+    module, runs = max(named.items(), key=lambda kv: len(kv[1]))
     events = sorted(((float(e.start_ns), float(e.duration_ns), e) for e in lines[tr.OPS_LINE].events if e.duration_ns > 0),
                     key=lambda t: (t[0], -t[1]))
     rows: dict[str, dict] = {}
@@ -156,6 +162,7 @@ def main() -> int:
     ap.add_argument("--workload")
     ap.add_argument("--seed", type=int, default=3900000101)
     ap.add_argument("--seconds", type=float, default=51.0)
+    ap.add_argument("--program", default="", help="a part of the step program's module name; default: the commonest")
     ap.add_argument("--scopes", default="", help="print the sums by scope of a kept table; no chip")
     ap.add_argument("--hlo", default="", help="with --scopes: a compiled program's text to take op_name from")
     args = ap.parse_args()
@@ -177,7 +184,7 @@ def main() -> int:
 
     def load_and_keep(trace_dir: str) -> dict:
         try:
-            kept["table"] = ops_table(trace_dir)
+            kept["table"] = ops_table(trace_dir, args.program)
             out.parent.mkdir(exist_ok=True)
             out.write_text(json.dumps(kept["table"]))
         except Exception as e:  # the benchmark's own line is worth more than the table

@@ -30,8 +30,9 @@ text, in which an entry's name finds what feeds it and what it feeds.
 (a fusion is one; parameters, constants, tuples and bitcasts run nothing) by the
 scope their ``op_name`` names (``attn``, ``moe.router``, ``moe.combine``,
 ``moe.shared``, ...; ``/while`` and ``/cond`` behind a scope mark what a loop or
-a conditional nested in the body holds), the sorts, and the scatters of a
-``moe.`` scope. A small operation costs a microsecond or more of a step however
+a conditional nested in the body holds), the sorts, the scatters of a
+``moe.`` scope, and how many ``copy``, ``transpose``, ``sort`` and ``gather``
+operations it holds, fused or not (``moving``). A small operation costs a microsecond or more of a step however
 little it computes, 39 times a step in JoyAI-LLM-Flash (PERF.md, PR 39).
 """
 
@@ -60,6 +61,9 @@ ITEMSIZE = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2
 
 #: Opcodes that run nothing on the device.
 FREE = frozenset({"parameter", "constant", "get-tuple-element", "tuple", "bitcast"})
+#: Operations that move data and compute nothing, counted by ``--count`` wherever they stand in the layer's body
+#: (alone or inside a fusion; an asynchronous copy counts as a copy).
+MOVING = ("copy", "transpose", "sort", "gather")
 #: A layer body's scopes: an instruction is counted under the first of these that its ``op_name`` holds.
 SCOPES = ("moe.combine", "moe.dispatch", "moe.shared", "moe.zero", "moe.experts", "moe.router", "attn", "mlp")
 
@@ -191,15 +195,20 @@ def body_counts(text: str) -> dict:
     conditional); ``by_scope``, all of
     them by :func:`scope_of` (``<scope>/while``, ``<scope>/cond`` where
     nested); ``sorts`` and ``moe_scatters``, the names of every ``sort`` and
-    of every ``scatter`` under a ``moe.`` scope, fused or not."""
+    of every ``scatter`` under a ``moe.`` scope, fused or not; ``moving``,
+    how many of each of :data:`MOVING` the body and what it nests hold,
+    fused or not."""
     comps = _computations(text)
     out = {"body": 0, "executed": 0, "nested": {"while": 0, "cond": 0}, "arms": [], "by_scope": {}, "sorts": [],
-           "moe_scatters": []}
+           "moe_scatters": [], "moving": dict.fromkeys(MOVING, 0)}
 
     def inside(comp: str, op_name: str) -> None:
-        """Sorts and scatters of a computation, those of its fusions too."""
+        """Sorts, scatters and the tally of :data:`MOVING` of a computation, those of its fusions too."""
         for ins in comps.get(comp, []):
             name = op_name_of(ins) or op_name
+            moved = "copy" if ins["opcode"] == "copy-start" else ins["opcode"]
+            if moved in out["moving"]:
+                out["moving"][moved] += 1
             if ins["opcode"] == "sort":
                 out["sorts"].append(ins["name"])
             elif ins["opcode"] == "scatter" and "moe." in name:
