@@ -824,7 +824,10 @@ class EngineCore:
             if tracker is not None:
                 events = tracker.events()
                 for ev in events[self._recompile_events_seen:]:
-                    if ev.get("reason") == "new_shape":
+                    # Only what a step paid: a warm-up drives the runner
+                    # outside any step, and its first calls (``in_step``
+                    # false) are no recompiles of the serving path.
+                    if ev.get("reason") == "new_shape" and ev.get("in_step", True):
                         self.recompile_count += 1
                         self._charge_loss("recompile", float(ev.get("wall_ms", 0.0)))
                 self._recompile_events_seen = len(events)

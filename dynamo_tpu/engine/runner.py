@@ -1093,12 +1093,15 @@ class ModelRunner:
             report.moe_pad_positions = report.step_tokens - int(np.count_nonzero(padded.slot_mapping))
         if padded.state_slots is not None:
             report.state_rows = int(np.count_nonzero(padded.state_slots))
-        timed = timed_dispatch(self.compile_tracker, program, key)
+        # A dispatch outside an engine step (a warm-up drives the runner
+        # directly) is no part of the next step's dispatch time, and its
+        # first call is no recompile of the serving path: the tracker's event
+        # says which it was.
+        in_step = self.clock is not None and self.clock.in_step
+        timed = timed_dispatch(self.compile_tracker, program, key, in_step=in_step)
         with timed:
             yield
-        # A dispatch outside an engine step (a warm-up drives the runner
-        # directly) is no part of the next step's dispatch time.
-        if self.clock is None or self.clock.in_step:
+        if self.clock is None or in_step:
             report.seconds += timed.seconds
 
     @staticmethod
@@ -1613,6 +1616,22 @@ class ModelRunner:
 
     def cache_memory_bytes(self) -> int:
         return int(self.k_cache.nbytes + self.v_cache.nbytes + sum(buf.nbytes for buf in self.state))
+
+    def memory_bytes_by_kind(self) -> dict[str, int]:
+        """What this runner allocated beside the weights: ``kv_pool_bytes``
+        (both caches; of a model with a pool per layer kind both pools, and
+        ``window_pool_bytes`` the sliding layers' share of them) and
+        ``state_bytes`` (the recurrent layers' buffers), each only where the
+        model has it."""
+        kv = int(self.k_cache.nbytes + self.v_cache.nbytes)
+        out = {"kv_pool_bytes": kv}
+        if self.two_pool:
+            from dynamo_tpu.models.config import SLIDING
+
+            out["window_pool_bytes"] = kv * self.cfg.cache_layers_of(SLIDING) * self.window_pages // self.k_cache.shape[1]
+        if self.state:
+            out["state_bytes"] = int(sum(buf.nbytes for buf in self.state))
+        return out
 
 
 class InFlightPages:

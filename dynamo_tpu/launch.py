@@ -18,9 +18,11 @@ import argparse
 import asyncio
 import logging
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Any
 
+from dynamo_tpu import tracing
 from dynamo_tpu.config import env_flag
 from dynamo_tpu.engine.core import EngineConfig, EngineCore
 from dynamo_tpu.engine.runner import ModelRunner
@@ -220,10 +222,31 @@ def make_worker_spec(model: str, **engine_kw: Any) -> WorkerSpec:
     )
 
 
-async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage=None) -> JaxEngineService:
-    from dynamo_tpu.tracing import maybe_trace_from_env
+#: The root span of a worker's coming-up, and the ``request_id`` of it and of
+#: its three children: ``GET /debug/traces/worker_bring_up`` is the timeline.
+BRING_UP_SPAN = "worker_bring_up"
 
-    maybe_trace_from_env()  # DYN_TRACE_DIR=dir captures worker bring-up + first steps
+
+def _bring_up_span(spec: WorkerSpec) -> tracing.Span:
+    """``worker`` is filled in when the instance has its lease id."""
+    return tracing.Span(BRING_UP_SPAN, request_id=BRING_UP_SPAN, worker="", model=spec.card.name)
+
+
+def _tree_bytes(params: Any) -> int:
+    import jax
+
+    return int(sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree_util.tree_leaves(params)))
+
+
+async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage=None,
+                               bring_up: tracing.Span | None = None) -> JaxEngineService:
+    """``bring_up`` is the caller's open ``worker_bring_up`` span
+    (``serve_worker``'s); without one the build is its own bring-up."""
+    if bring_up is None:
+        with _bring_up_span(spec) as root:
+            return await build_engine_service(spec, on_kv_event=on_kv_event, g4_storage=g4_storage, bring_up=root)
+    trace = bring_up.context
+    tracing.maybe_trace_from_env()  # DYN_TRACE_DIR=dir captures worker bring-up + first steps
     if spec.mock:
         from dynamo_tpu.mocker import build_mock_core
 
@@ -249,6 +272,9 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
                     num_experts=spec.model_config.num_experts,
                 )
             mesh = make_mesh(plan)
+        t_params = time.perf_counter()
+        source = ("given" if spec.params is not None else "init" if spec.model_dir is None
+                  else "gguf" if spec.model_dir.endswith(".gguf") else "checkpoint")
         if spec.params is not None:
             params = spec.params
         elif spec.model_dir is not None and spec.model_dir.endswith(".gguf"):
@@ -298,7 +324,10 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
                 params = init_sharded(lambda: llama.init_params(spec.model_config, 0), mesh)
             elif params is None:
                 params = llama.init_params(spec.model_config, 0)
-        return ModelRunner(
+        t_runner = time.perf_counter()
+        tracing.record_span("worker_params", (t_runner - t_params) * 1e3, trace=trace, start_mono=t_params,
+                            request_id=BRING_UP_SPAN, source=source, bytes=_tree_bytes(params))
+        runner = ModelRunner(
             spec.model_config,
             params,
             num_pages=spec.engine_config.num_pages,
@@ -316,6 +345,12 @@ async def build_engine_service(spec: WorkerSpec, *, on_kv_event=None, g4_storage
             window_chunk=(max(spec.engine_config.chunk_prefill_tokens, spec.engine_config.max_prefill_tokens)
                           if spec.engine_config.swa_free_pages and spec.engine_config.chunk_prefill_tokens > 0 else None),
         )
+        tracing.record_span("runner_init", (time.perf_counter() - t_runner) * 1e3, trace=trace, start_mono=t_runner,
+                            request_id=BRING_UP_SPAN, **runner.memory_bytes_by_kind())
+        # The runner's first calls are part of this worker's coming-up,
+        # whoever makes them: their spans go under the same trace.
+        runner.compile_tracker.trace = trace
+        return runner
 
     runner = await asyncio.get_running_loop().run_in_executor(None, _build)
     import jax
@@ -370,14 +405,20 @@ async def serve_worker(
     fronts its engine with the disagg operator (remote prefill via the
     prefill queue; see dynamo_tpu.disagg).
     """
+    with _bring_up_span(spec) as root:
+        return await _serve_worker(runtime, spec, root, lease=lease, disagg=disagg)
+
+
+async def _serve_worker(runtime: DistributedRuntime, spec: WorkerSpec, root: tracing.Span, *, lease, disagg):
     from dynamo_tpu.router.events import KV_EVENTS_ENDPOINT, KvEventBroadcaster
     from dynamo_tpu.router.metrics import WorkerMetricsPublisher
 
     broadcaster = KvEventBroadcaster()
     broadcaster.bind_loop(asyncio.get_running_loop())
     service = await build_engine_service(
-        spec, on_kv_event=broadcaster.publish, g4_storage=_g4_storage_for(spec, runtime)
+        spec, on_kv_event=broadcaster.publish, g4_storage=_g4_storage_for(spec, runtime), bring_up=root
     )
+    t_register = time.perf_counter()
     service.spec = spec  # run_local reads vision_config/params off it (VLM)
     if getattr(service.core, "state_slots", None) is not None and spec.card.router_mode == "kv":
         await service.close()
@@ -440,6 +481,9 @@ async def serve_worker(
     )
     card_lease = lease or await runtime.primary_lease()
     await runtime.put_leased(spec.card.instance_key(instance.lease_id), spec.card.to_bytes(), card_lease)
+    root.fields["worker"] = f"{instance.lease_id:x}"
+    tracing.record_span("worker_register", (time.perf_counter() - t_register) * 1e3, trace=root.context,
+                        start_mono=t_register, request_id=BRING_UP_SPAN, worker=root.fields["worker"])
     logger.info("worker serving %s as instance %x", spec.card.name, instance.lease_id)
     return service
 

@@ -14,7 +14,8 @@ recorder keeps the last N steps verbatim, the way an aircraft FDR does:
   "barrier"; "" only from an engine stepped synchronously throughout).
 - ``compile`` records — emitted by the :class:`~dynamo_tpu.observability.
   compile.CompileTracker` when a runner dispatch hits a never-seen shape
-  bucket (the XLA recompile a generic tool cannot see).
+  bucket (the XLA recompile a generic tool cannot see), with what the first
+  call spent tracing, lowering and in the backend or its cache.
 - ``crash`` records — appended by ``EngineCore.step()`` when a step raises,
   capturing the failing step's context before the exception propagates.
 - ``anomaly`` records — rising edges from the

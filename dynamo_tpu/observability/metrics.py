@@ -148,8 +148,9 @@ class EngineMetrics:
         self._recompiles = Gauge(
             "dynamo_engine_recompiles_total",
             "First executions of a padded shape bucket per jitted program "
-            "(reason: new_shape = compiled on the serving path, warm_cache = "
-            "first-seen but fast, e.g. persistent-cache hit)",
+            "(reason: new_shape = the first call took DYN_COMPILE_THRESHOLD_MS "
+            "or more, warm_cache = less; whether the persistent cache had the "
+            "program is the compile event's `cache`)",
             ["worker", "program", "reason"], registry=self.registry,
         )
         # Attention dispatch path per engine step, synced from the core's

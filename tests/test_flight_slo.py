@@ -120,31 +120,6 @@ def test_compile_tracker_one_event_per_bucket():
     assert tracker.total == 2
     assert len(tracker.events()) == 2
     assert [k for k, _ in sink_events] == ["compile", "compile"]
-    # Dispatch time accumulates over every call, not just first executions.
-    assert tracker.dispatch_seconds_total == pytest.approx(0.2 + 5 * 0.3 + 0.001)
-
-
-def test_compile_storm_warns_once(caplog):
-    sink_kinds = []
-    tracker = CompileTracker(
-        threshold_ms=50.0, storm_window=100, storm_threshold=3, warmup_dispatches=0
-    )
-    tracker.bind_sink(lambda kind, **f: sink_kinds.append(kind))
-    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.observability.compile"):
-        for i in range(6):  # six slow compiles on six fresh buckets
-            tracker.observe("step", (i,), 0.2)
-    assert tracker.storm_warned
-    assert sink_kinds.count("compile_storm") == 1
-    assert sum("recompile storm" in r.message for r in caplog.records) == 1
-
-
-def test_compile_storm_respects_warmup():
-    tracker = CompileTracker(
-        threshold_ms=50.0, storm_window=100, storm_threshold=3, warmup_dispatches=32
-    )
-    for i in range(10):  # the lattice legitimately filling during warm-up
-        tracker.observe("step", (i,), 0.2)
-    assert not tracker.storm_warned
 
 
 def test_timed_dispatch_noop_and_exception_paths():
