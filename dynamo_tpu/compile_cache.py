@@ -20,15 +20,21 @@ def enable_compile_cache() -> str:
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself — only make sure
     the directory exists and set no other path in code. Unset:
-    ``<checkout>/.jax_cache`` next to the package (git-ignored).
+    ``<checkout>/.jax_cache`` next to the package (git-ignored). The
+    runners' executable store (``dynamo_tpu/executable_store.py``) lies in
+    ``executables/`` under either: JAX's LRU looks only at its own ``*-cache``
+    files at the top level.
     """
+    from dynamo_tpu import executable_store
+
     path = os.environ.get(CACHE_DIR_ENV)
     if path:
         os.makedirs(path, exist_ok=True)
-        return path
-    import jax
+    else:
+        import jax
 
-    path = str(pathlib.Path(__file__).resolve().parent.parent / ".jax_cache")
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+        path = str(pathlib.Path(__file__).resolve().parent.parent / ".jax_cache")
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    executable_store.set_cache_dir(path)
     return path

@@ -23,10 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu import tracing
+from dynamo_tpu import executable_store, tracing
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
-from dynamo_tpu.observability.compile import CompileTracker, timed_dispatch
+from dynamo_tpu.observability.compile import CompileTracker, note_store, persistent_cache_hits, timed_dispatch
 from dynamo_tpu.ops.sampling import sample_tokens
 from dynamo_tpu.parallel.moe import HELD_COUNTS, router_select
 
@@ -135,6 +135,18 @@ def _unpack(packed: jnp.ndarray, b: int, t: int, n: int, h: int, slots: bool = F
 MAX_CHUNK_SLOTS = 2
 
 ROWS_X_T, SPLIT = "rows_x_t", "split"  # a dispatch's layout, as the STEP record names it
+
+#: The step programs' static keywords, by the jitted function's name: what a
+#: compiled program is specialised on besides its arguments' forms
+#: (``executable_store.StepPrograms`` keeps one a value of them).
+STATIC_KEYWORDS = {
+    "_step": ("impl", "lp_k"),
+    "_step_split": ("nd", "nc", "tc", "n", "h", "lp_k"),
+    "_step_packed": ("b", "t", "n", "h", "lp_k"),
+    "_step_chained_explicit": ("impl", "lp_k"),
+    "_spec_step": ("impl", "lp_k"),
+    "_spec_step_chained": ("impl", "lp_k"),
+}
 
 
 def _pack_split(padded: "StepBatch", chunk: np.ndarray, nc: int,
@@ -372,6 +384,9 @@ class ModelRunner:
         embed_pooling: str = "mean",  # /v1/embeddings pooling ("mean" | "last")
         window_chunk: int | None = None,  # most tokens a row brings to one step, where the caller bounds it
     ) -> None:
+        # Everything this runner is built from but the weights, as the executable
+        # store keys it (before any other local: every argument, by construction).
+        arguments = {k: v for k, v in locals().items() if k not in ("self", "params")}
         from dynamo_tpu.ops.attention import default_impl
 
         self.cfg = cfg
@@ -397,6 +412,9 @@ class ModelRunner:
         # this is how a production recompile becomes visible (metrics plane
         # syncs counts(); the engine's flight recorder is its event sink).
         self.compile_tracker = CompileTracker()
+        # The dispatch site's name and key while its block is open (_dispatch):
+        # what _enqueue keeps a compiled program under.
+        self._dispatching: tuple[str, tuple] = ("", ())
         #: The owning engine's phase clock (``EngineCore`` sets it): the
         #: blocking programs mark dispatch -> wait where the enqueue returns.
         self.clock: tracing.StepClock | None = None
@@ -508,7 +526,7 @@ class ModelRunner:
                 return next_tokens, k_cache, v_cache, chosen, top_ids, top_lps
             return next_tokens, k_cache, v_cache
 
-        @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
+        @functools.partial(jax.jit, static_argnames=STATIC_KEYWORDS["_step"], donate_argnums=(1, 2))
         def _step(params, k_cache, v_cache, tokens, positions, block_tables, slot_mapping,
                   last_idx, temperature, top_k, top_p, seeds, sample_steps,
                   freq_pen, pres_pen, pos_limit, history, mrope_delta=None,
@@ -557,7 +575,7 @@ class ModelRunner:
         # ``_apply_chain`` hands the host's tokens through.
         recurrent, two_pool = self.recurrent, self.two_pool
 
-        @functools.partial(jax.jit, static_argnames=("nd", "nc", "tc", "n", "h", "lp_k"), donate_argnums=(1, 2),
+        @functools.partial(jax.jit, static_argnames=STATIC_KEYWORDS["_step_split"], donate_argnums=(1, 2),
                            donate_argnames=("state",))
         def _step_split(params, k_cache, v_cache, packed, chain_buf, *, nd, nc, tc, n, h, lp_k=0, state=()):
             """A chunk step on one token axis (``_pack_split``): ``nd`` decode
@@ -591,7 +609,7 @@ class ModelRunner:
 
         self._step_split_fn = _step_split
 
-        @functools.partial(jax.jit, static_argnames=("b", "t", "n", "h", "lp_k"), donate_argnums=(1, 2),
+        @functools.partial(jax.jit, static_argnames=STATIC_KEYWORDS["_step_packed"], donate_argnums=(1, 2),
                            donate_argnames=("state",))
         def _step_packed(params, k_cache, v_cache, packed, chain_buf, *, b, t, n, h, lp_k=0, state=()):
             """The rows x T rectangle from one packed buffer. Each row's
@@ -612,7 +630,7 @@ class ModelRunner:
 
         self._step_packed_fn = _step_packed
 
-        @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
+        @functools.partial(jax.jit, static_argnames=STATIC_KEYWORDS["_step_chained_explicit"], donate_argnums=(1, 2))
         def _step_chained_explicit(params, k_cache, v_cache, chain_buf, chain_src,
                                    tokens, positions, block_tables, slot_mapping,
                                    last_idx, temperature, top_k, top_p, seeds,
@@ -651,7 +669,7 @@ class ModelRunner:
 
         self._step_chained_explicit_fn = _step_chained_explicit
 
-        @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
+        @functools.partial(jax.jit, static_argnames=STATIC_KEYWORDS["_spec_step"], donate_argnums=(1, 2))
         def _spec_step(params, k_cache, v_cache, tokens, positions, block_tables, slot_mapping,
                        verify_indices, temperature, top_k, top_p, seeds, sample_steps,
                        freq_pen, pres_pen, history, mrope_delta=None,
@@ -719,7 +737,7 @@ class ModelRunner:
 
         self._spec_step_fn = _spec_step
 
-        @functools.partial(jax.jit, static_argnames=("impl", "lp_k"), donate_argnums=(1, 2))
+        @functools.partial(jax.jit, static_argnames=STATIC_KEYWORDS["_spec_step_chained"], donate_argnums=(1, 2))
         def _spec_step_chained(params, k_cache, v_cache, chain_buf, chain_src,
                                tokens, positions, block_tables, slot_mapping,
                                verify_indices, temperature, top_k, top_p, seeds,
@@ -780,6 +798,23 @@ class ModelRunner:
             return llama.encode(params, self.cfg, tokens, mask, pooling=embed_pooling)
 
         self._embed_fn = _embed
+        #: The step programs kept compiled and the store they are loaded from
+        #: and written to (``dynamo_tpu/executable_store.py``); None where the
+        #: process has no store: _enqueue then calls the jitted function.
+        self._programs = self._step_programs(arguments)
+
+    def _step_programs(self, arguments: dict) -> executable_store.StepPrograms | None:
+        store = executable_store.open_store()
+        if store is None:
+            return None
+        try:  # the resolved implementation too: the default is the platform's and the environment's
+            key = executable_store.built_from({**arguments, "attn_impl_resolved": self.attn_impl})
+        except executable_store.Unkeyable as e:
+            logger.info("%s: no executable store for this runner (%s)", self.cfg.name, e)
+            return None
+        devices = list(self.mesh.devices.flat) if self.mesh is not None else list(self.k_cache.devices())
+        return executable_store.StepPrograms(store, key, devices, note=note_store, cache_hits=persistent_cache_hits,
+                                             on_refusal=self.compile_tracker.refused)
 
     def _on_device(self):
         """Context placing uncommitted arrays on this runner's device."""
@@ -1099,20 +1134,25 @@ class ModelRunner:
         # says which it was.
         in_step = self.clock is not None and self.clock.in_step
         timed = timed_dispatch(self.compile_tracker, program, key, in_step=in_step)
+        self._dispatching = (program, key)
         with timed:
             yield
         if self.clock is None or in_step:
             report.seconds += timed.seconds
 
-    @staticmethod
-    def _enqueue(fn, *args, **kwargs):
-        """Calls a jitted step program from a frame of its own. Called from
-        ``step``'s own frame, the first call of every chunk program took 0.45 to
-        0.55 s longer on the v5e's host (a tenth of a cell's warm set-up over
-        its 25 to 28 chunk programs), with the frame in between it does not:
-        measured both ways on four machines, why is not established (PERF.md,
-        PR 28)."""
-        return fn(*args, **kwargs)
+    def _enqueue(self, fn, *args, **kwargs):
+        """Calls a step program from a frame of its own, inside the dispatch
+        site's ``_dispatch`` block. With an executable store the program is the
+        one kept compiled for the site's key (``StepPrograms.call``: loaded from
+        the store at the key's first sight, or lowered, compiled and written
+        there); without, the jitted function itself. Called from ``step``'s own
+        frame, the first call of every chunk program took 0.45 to 0.55 s longer
+        on the v5e's host (a tenth of a cell's warm set-up over its 25 to 28
+        chunk programs), with the frame in between it does not: measured both
+        ways on four machines, why is not established (PERF.md, PR 28)."""
+        if self._programs is None:
+            return fn(*args, **kwargs)
+        return self._programs.call(fn, *self._dispatching, STATIC_KEYWORDS[fn.__name__], args, kwargs)
 
     def take_dispatch(self) -> DispatchReport | None:
         """The report of what was dispatched since the last take, which this

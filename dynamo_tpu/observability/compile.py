@@ -42,6 +42,18 @@ process, adds what JAX reports on that thread to it:
 - ``cache``: ``hit`` (hits and no miss), ``miss``, ``off`` (a backend event
   and no request to the cache), ``none`` (no backend event: JAX had the
   executable in the process).
+- ``store`` and ``store_read_ms``: what the runner's executable store
+  (``dynamo_tpu/executable_store.py``) did for the call, which the runner says
+  through :func:`note_store`. ``hit``: the compiled program was loaded from the
+  store and nothing was traced, lowered or asked of JAX's cache: ``trace_ms``
+  and ``lower_ms`` are 0, the load (read, decompress, deserialise, load onto
+  the device: ``store_read_ms``) is the call's ``backend_ms``, and the call
+  counts one ``cache_hits`` and one of ``modules``, with ``cache`` ``hit``:
+  it found its program in a persistent cache, by another door. ``miss``: the
+  store was asked and had no entry it could load (``store_read_ms`` is what
+  asking cost, inside ``rest_ms``), and the other fields say what a first call
+  says without a store. ``off``: no store (no cache directory, a process of
+  several hosts, a runner built from a function no key can see into).
 - ``t0_ns`` (``perf_counter_ns`` at entry: the clock of a STEP record) and
   ``in_step`` (the runner's word: the call was made inside an engine step, and
   not by a warm-up that drives the runner directly).
@@ -70,6 +82,8 @@ REASON_NEW_SHAPE = "new_shape"
 REASON_WARM_CACHE = "warm_cache"
 
 COMPILE_KIND = "compile"
+#: The flight record of a kept program that refused a later dispatch's arguments.
+REFUSED_KIND = "program_refused"
 #: The span a first call leaves in ``tracing.SPANS``, and its ``request_id``.
 FIRST_CALL_SPAN = "runner_first_call"
 
@@ -101,7 +115,7 @@ class FirstCall:
     :func:`close_first_call`."""
 
     __slots__ = ("t0_ns", "parts_s", "modules", "cache_requests", "cache_hits",
-                 "cache_read_s", "cache_saved_s", "_intervals")
+                 "cache_read_s", "cache_saved_s", "store", "store_read_s", "_intervals")
 
     def __init__(self) -> None:
         self.parts_s = [0.0, 0.0, 0.0]
@@ -110,6 +124,8 @@ class FirstCall:
         self.cache_hits = 0
         self.cache_read_s = 0.0
         self.cache_saved_s = 0.0
+        self.store = "off"
+        self.store_read_s = 0.0
         # Reported intervals that no later one holds yet, oldest first.
         self._intervals: list[tuple[float, float]] = []
         self.t0_ns = time.perf_counter_ns()
@@ -134,6 +150,17 @@ class FirstCall:
         if part == BACKEND:
             self.modules += 1
 
+    def add_store(self, store: str, read_s: float) -> None:
+        """The executable store's word on this call. A hit is the backend's
+        part of a call that asked JAX for nothing: one program, found."""
+        self.store = store
+        self.store_read_s += read_s
+        if store == "hit":
+            self.parts_s[BACKEND] += read_s
+            self.modules += 1
+            self.cache_requests += 1
+            self.cache_hits += 1
+
     @property
     def cache(self) -> str:
         if not self.modules:
@@ -153,6 +180,7 @@ class FirstCall:
             "cache_hits": self.cache_hits, "cache_misses": self.cache_requests - self.cache_hits,
             "cache_read_ms": round(self.cache_read_s * 1e3, 3),
             "cache_saved_ms": round(self.cache_saved_s * 1e3, 3),
+            "store": self.store, "store_read_ms": round(self.store_read_s * 1e3, 3),
             "modules": self.modules, "t0_ns": self.t0_ns,
         }
 
@@ -162,6 +190,8 @@ class _Open(threading.local):
     the calling thread, and two replicas' engine threads share nothing."""
 
     call: FirstCall | None = None
+    #: Programs JAX's persistent cache has handed this thread, open call or not.
+    cache_hits: int = 0
 
 
 _OPEN = _Open()
@@ -170,6 +200,8 @@ _installed = False
 
 
 def _on_event(event: str, **_kw: Any) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _OPEN.cache_hits += 1
     call = _OPEN.call
     if call is None:
         return
@@ -177,6 +209,13 @@ def _on_event(event: str, **_kw: Any) -> None:
         call.cache_hits += 1
     elif event == _CACHE_REQUEST_EVENT:
         call.cache_requests += 1
+
+
+def persistent_cache_hits() -> int:
+    """How many programs JAX's persistent cache has handed this thread so far
+    (the executable store asks before and after a compile: an executable that
+    came out of the cache is not on every platform written out whole again)."""
+    return _OPEN.cache_hits
 
 
 def _on_duration(event: str, seconds: float, **_kw: Any) -> None:
@@ -218,6 +257,14 @@ def close_first_call() -> None:
     _OPEN.call = None
 
 
+def note_store(store: str, read_s: float) -> None:
+    """The runner's executable store, on the first call open on this thread
+    (none open: a key the tracker had seen, which says nothing)."""
+    call = _OPEN.call
+    if call is not None:
+        call.add_store(store, read_s)
+
+
 # -- the tracker -----------------------------------------------------------------
 
 
@@ -242,6 +289,8 @@ class CompileTracker:
         self._events: list[dict] = []
         self._sink: Callable[..., Any] | None = None
         self._dispatches = 0
+        #: Dispatches a kept compiled program refused (:meth:`refused`).
+        self.refusals = 0
 
     def bind_sink(self, sink: Callable[..., Any] | None) -> "CompileTracker":
         """``sink(kind, **fields)`` receives compile events — wired to the
@@ -288,6 +337,17 @@ class CompileTracker:
         tracing.record_span(FIRST_CALL_SPAN, event["wall_ms"], trace=self.trace, start_mono=start_mono,
                             request_id=FIRST_CALL_SPAN, **event)
         return event
+
+    def refused(self, program: str, key: tuple, error: Exception) -> None:
+        """A compiled program kept for ``key`` refused a later dispatch's
+        arguments, and the jitted function took the call: the ``dispatch_key``
+        does not hold everything its program specialises on. One flight record
+        a refusal, with the key and the refusal's first line."""
+        with self._lock:
+            self.refusals += 1
+        message = str(error).splitlines()[0] if str(error) else type(error).__name__
+        logger.warning("step program %s %s refused its arguments: %s", program, key, message)
+        self._emit(REFUSED_KIND, program=program, bucket=list(key), error=message[:240])
 
     def _emit(self, kind: str, **fields: Any) -> None:
         sink = self._sink

@@ -33,6 +33,13 @@ jax.config.update("jax_default_matmul_precision", "highest")
 from dynamo_tpu.compile_cache import enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
+# ...and no executable store under it: tests patch functions that a step program
+# closes over (a spy, a forced path, an interpreter switch), which no key of the
+# store can see, and count traces. tests/test_executable_store.py gives its
+# runners stores of their own.
+from dynamo_tpu import executable_store  # noqa: E402
+
+executable_store.set_cache_dir(None)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
@@ -56,6 +63,29 @@ FAST_MODULES = {
     "test_stall_free", "test_tokens", "test_tool_calls",
     "test_tracing_objects",
 }
+
+
+@pytest.fixture(autouse=True)
+def _no_executable_store_left_on():
+    """A test that calls ``enable_compile_cache()`` (or takes a store of its
+    own) leaves the worker's later tests without one again."""
+    yield
+    executable_store.set_cache_dir(None)
+
+
+@pytest.fixture
+def fresh_compiles():
+    """JAX's own persistent cache off: every compile in the test is the
+    backend's, whatever earlier runs of the suite left in the suite's cache
+    (the executable store's tests: XLA's CPU backend does not serialise whole
+    an executable it loaded from there)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 def pytest_collection_modifyitems(config, items):

@@ -170,6 +170,61 @@ def test_cache_says_what_jax_said(cache, events):
     assert (fields["cache_read_ms"], fields["cache_saved_ms"]) == (250.0 * hits, 7500.0 * hits)
 
 
+# -- the executable store's word (ISSUE 52) -------------------------------------------
+
+
+def _stored_call(tmp_path, tracker, program, x):
+    """One first call through a runner's table of kept programs, as ``_enqueue``
+    makes it inside ``_dispatch``'s block; a new table each time: a new process."""
+    from dynamo_tpu import executable_store as es
+
+    programs = es.StepPrograms(es.ExecutableStore(str(tmp_path), "build"), "runner", jax.devices()[:1],
+                               note=oc.note_store, cache_hits=oc.persistent_cache_hits)
+    with timed_dispatch(tracker, "step", (8, 8), in_step=False):
+        return programs.call(program, "step", (8, 8), (), (x,), {}).block_until_ready()
+
+
+def test_a_store_miss_says_what_a_first_call_says_and_a_store_hit_traces_nothing(spans, tmp_path, fresh_compiles):
+    program, x = fresh_program(6.0), jnp.ones((8, 8))
+    cold, warm = CompileTracker(threshold_ms=0.0), CompileTracker(threshold_ms=0.0)
+    want = _stored_call(tmp_path, cold, program, x)
+    (miss,) = cold.events()
+    assert miss["store"] == "miss" and 0 < miss["store_read_ms"] < miss["rest_ms"]
+    assert miss["trace_ms"] > 0 and miss["lower_ms"] > 0 and miss["backend_ms"] > 0 and miss["modules"] == 1
+    assert (miss["cache"], miss["cache_hits"], miss["cache_misses"]) == ("off", 0, 0)  # JAX's cache is off here
+    assert sum(miss[p] for p in PARTS) == pytest.approx(miss["wall_ms"], abs=0.01)
+    assert (_stored_call(tmp_path, warm, program, x) == want).all()
+    (hit,) = warm.events()
+    assert hit["store"] == "hit" and (hit["trace_ms"], hit["lower_ms"]) == (0.0, 0.0)
+    assert hit["store_read_ms"] > 0 and hit["backend_ms"] == hit["store_read_ms"]  # the load is the backend's part
+    assert (hit["cache"], hit["cache_hits"], hit["cache_misses"], hit["modules"]) == ("hit", 1, 0, 1)
+    assert (hit["cache_read_ms"], hit["cache_saved_ms"]) == (0.0, 0.0)  # JAX's own cache was not asked
+    assert sum(hit[p] for p in PARTS) == pytest.approx(hit["wall_ms"], abs=0.01) and hit["rest_ms"] >= 0
+    assert [s["store"] for s in first_calls(spans)] == ["miss", "hit"]  # the spans carry the event's fields
+    assert first_calls(spans)[1]["store_read_ms"] == hit["store_read_ms"]
+
+
+def test_a_first_call_without_a_store_says_off(spans):
+    tracker = CompileTracker(threshold_ms=0.0)
+    with timed_dispatch(tracker, "step", (8, 8)):
+        fresh_program(7.0)(jnp.ones((8, 8)))
+    (event,) = tracker.events()
+    assert (event["store"], event["store_read_ms"]) == ("off", 0.0)
+    oc.note_store("hit", 1.0)  # no call open on this thread: heard by no one
+    assert oc._OPEN.call is None
+
+
+def test_the_threads_count_of_persistent_cache_hits_runs_with_no_call_open():
+    CompileTracker()  # the listeners are in
+    before = oc.persistent_cache_hits()
+    monitoring.record_event(oc._CACHE_HIT_EVENT)
+    heard = []
+    thread = threading.Thread(target=lambda: heard.append(oc.persistent_cache_hits()))
+    thread.start()
+    thread.join(timeout=60)
+    assert oc.persistent_cache_hits() == before + 1 and heard == [0]  # another thread's count is its own
+
+
 def test_a_raise_inside_the_block_leaves_the_key_unseen_and_the_collector_closed(spans):
     tracker = CompileTracker(threshold_ms=0.0)
     with pytest.raises(ValueError):

@@ -17,6 +17,13 @@ entries' bytes to the cell's lattice (rows buckets x context buckets, a decode
 and a chunk program each) beside the cache's limit: a lattice larger than an
 LRU is evicted in the order it is read, so a run after a run of the same tree
 hits nothing (PERF.md, PR 40).
+
+Beside it, the runners' executable store (``dynamo_tpu/executable_store.py``,
+``executables/`` under the cache's directory, which no LRU looks at): each
+build's digest, entries and bytes before and after, and of each child run the
+store's hits and misses and what a load took. The children go through the store
+as a runner's first call does: an entry found is loaded and nothing is lowered
+or compiled; one not found is compiled as above and written.
 """
 
 from __future__ import annotations
@@ -48,6 +55,7 @@ def child(config: str, rows: list[int]) -> int:
     import jax.numpy as jnp
 
     from benchmark import serving, weights
+    from dynamo_tpu import executable_store
     from dynamo_tpu.compile_cache import enable_compile_cache
     from dynamo_tpu.models import kda, llama
     from dynamo_tpu.models.mla import lay_heads_major
@@ -63,6 +71,7 @@ def child(config: str, rows: list[int]) -> int:
             events["misses"] += 1
 
     jax.monitoring.register_event_listener(on_event)
+    store = executable_store.open_store()
     conf = serving.load_config(ROOT / "benchmark" / "configs" / f"{config}.json", rehearsal=jax.default_backend() != "tpu")
     mc, eng = serving.model_config(conf), conf["serve"]["engine"]
     page_size, chunk = eng["page_size"], eng["chunk_prefill_tokens"]
@@ -87,17 +96,27 @@ def child(config: str, rows: list[int]) -> int:
                 kept.update(window_tables=i32(slots, pages_per_row), window_slots=i32(*toks))
                 counted = {**counted, "window_pages": window_pages}
             fn = functools.partial(llama.forward, cfg=mc, attn_impl="pallas", split=split, **counted)
+            arguments = dict(params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=kc, v_cache=vc,
+                             block_tables=i32(slots, pages_per_row), slot_mapping=i32(*toks),
+                             last_token_index=i32(slots), **kept)
             before, t0 = listing(path), time.time()
-            jax.jit(fn, donate_argnames=("k_cache", "v_cache", *(k for k in kept if k == "recurrent"))).lower(
-                params=params, tokens=i32(*toks), positions=i32(*toks), k_cache=kc, v_cache=vc,
-                block_tables=i32(slots, pages_per_row), slot_mapping=i32(*toks), last_token_index=i32(slots), **kept,
-            ).compile()
+            name = executable_store.program_key(f"probe:{config}", "forward", label, (r,), (), (), arguments)
+            stored = store.load(name, jax.devices()[:1]) if store is not None else None
+            load_s, hits = time.time() - t0, events["hits"]
+            if stored is None:
+                compiled = jax.jit(fn, donate_argnames=("k_cache", "v_cache", *(k for k in kept if k == "recurrent"))).lower(
+                    **arguments).compile()
+                # (as a runner: a program out of JAX's cache is written only where it serialises whole again)
+                if store is not None and (events["hits"] == hits or jax.default_backend() in executable_store.RESERIALISES):
+                    store.save(name, compiled)
             after = listing(path)
             programs.append({"rows": r, "program": label, "compile_s": round(time.time() - t0, 2),
+                             "store": "off" if store is None else "hit" if stored is not None else "miss",
+                             "store_load_s": round(load_s, 3),
                              "written": {k: v for k, v in after.items() if k not in before},
                              "gone": len([k for k in before if k not in after])})
     print(json.dumps({"backend": jax.default_backend(), "cache_max_size": jax.config.jax_compilation_cache_max_size,
-                      "programs": programs, **events}), flush=True)
+                      "programs": programs, **events, "store": None if store is None else store.counters()}), flush=True)
     return 0
 
 
@@ -112,17 +131,20 @@ def main() -> int:
         return child(args.config, rows)
 
     from benchmark import serving, traffic
+    from dynamo_tpu import executable_store
     from dynamo_tpu.compile_cache import CACHE_DIR_ENV
 
     path = os.environ.get(CACHE_DIR_ENV) or str(ROOT / ".jax_cache")
     os.makedirs(path, exist_ok=True)
+    store_dir = os.path.join(path, executable_store.DIRECTORY)
     had = listing(path)
     by_module: dict[str, list[int]] = {}
     for name, size in had.items():
         by_module.setdefault(name.split("-")[0], []).append(size)
     print(json.dumps({"cache_dir": path, "env": {k: v for k, v in os.environ.items() if k.startswith("JAX_")},
                       "entries": len(had), "bytes": sum(had.values()),
-                      "by_module": {k: [len(v), sum(v)] for k, v in by_module.items()}}), flush=True)
+                      "by_module": {k: [len(v), sum(v)] for k, v in by_module.items()},
+                      "executable_store": executable_store.describe(store_dir)}), flush=True)
     runs = []
     for _ in range(2):  # the parent never touches JAX: a chip belongs to one process at a time
         done = subprocess.run([sys.executable, __file__, args.config, "--rows", args.rows, "--child"],
@@ -146,7 +168,11 @@ def main() -> int:
     print(json.dumps({"config": args.config, "first_run_hits": runs[0]["hits"], "second_run_hits": runs[1]["hits"],
                       "of": len(runs[1]["programs"]), "entry_bytes": wrote, "lattice_programs": lattice,
                       "lattice_bytes_about": None if lattice is None or not all(wrote) else int(sum(wrote) / len(wrote) * lattice),
-                      "entries_after": len(after), "bytes_after": sum(after.values())}), flush=True)
+                      "entries_after": len(after), "bytes_after": sum(after.values()),
+                      "store_hits": [r["store"] and r["store"]["hits"] for r in runs],
+                      "store_misses": [r["store"] and r["store"]["misses"] for r in runs],
+                      "store_entry_bytes": runs[0]["store"] and runs[0]["store"]["bytes_written"],
+                      "executable_store": executable_store.describe(store_dir)}), flush=True)
     return 0
 
 

@@ -191,14 +191,14 @@ def main() -> int:
             print(json.dumps({"step_ops_error": repr(e)}), flush=True)
         return load(trace_dir)
 
-    def enqueue_and_remember(fn, *a, **kw):
+    def enqueue_and_remember(runner, fn, *a, **kw):
         # (``state``: a recurrent model's buffers ride as a keyword, arrays like the positional ones)
         key = (getattr(fn, "__name__", ""), tuple(sorted((k, v) for k, v in kw.items() if k != "state")))
         if key not in seen:  # the shapes of a program's first call: what lowers it again
             shapes = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), tree)  # noqa: E731
             seen[key] = [fn, shapes(a), {k: shapes(v) if k == "state" else v for k, v in kw.items()}, 0]
         seen[key][3] += 1
-        return enqueue(fn, *a, **kw)
+        return enqueue(runner, fn, *a, **kw)
 
     offer = bench_run.offer
 
@@ -212,7 +212,7 @@ def main() -> int:
 
     bench_run.offer = offer_and_count
     trace_reduce.load_xplane = load_and_keep
-    ModelRunner._enqueue = staticmethod(enqueue_and_remember)
+    ModelRunner._enqueue = enqueue_and_remember
     sys.argv = [str(ROOT / "benchmark" / "run.py"), "--workload", args.workload, "--seed", str(args.seed),
                 "--seconds", str(args.seconds), "--trace", "1"]
     code = bench_run.main()
