@@ -1050,8 +1050,8 @@ class ModelRunner:
         step; a chunk row's chunked recurrence in XLA is the served path."""
         t = int(padded.tokens.shape[1])
         phase = "verify" if (verify and t > 1) else ("decode" if t == 1 else "prefill")
-        # (other models count nothing (0, 0); a model with a mixer counts its one kind of GQA layer)
-        if self.cfg.sliding_window or self.cfg.attn_type == "mla" or self.cfg.ssm_heads:
+        # (other models count nothing (0, 0); a model with recurrent layers counts its one kind of layer that attends)
+        if self.cfg.sliding_window or self.cfg.attn_type == "mla" or self.cfg.recurrent_layers:
             self._kv_pending = padded
         if impl == "ring":
             return phase, "ring"
@@ -1095,8 +1095,9 @@ class ModelRunner:
         a full layer (an MLA model's attention sublayer) every row's context;
         a windowed layer at most the window plus the row's new tokens less
         one. Padding rows (a null block table) count nothing. Only a model
-        with a windowed layer, with latent attention or with a mixer beside
-        its attention is counted."""
+        with a windowed layer, with latent attention or with recurrent layers
+        (a mixer beside its attention, or periods round one layer that attends)
+        is counted."""
         pos = np.asarray(padded.positions)[np.asarray(padded.block_tables).any(axis=1)]
         if not len(pos):
             return 0, 0

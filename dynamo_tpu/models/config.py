@@ -159,6 +159,20 @@ def _stage_layers(config: dict) -> int:
     return layers
 
 
+def _period_layout(attending: list[int], listed: int, layers: int, key: str) -> tuple[int, int]:
+    """(layers a period, the place in it of the one layer that attends) of a
+    config whose ``key`` puts the layers that attend at ``attending`` of the
+    ``listed`` layers it describes, ``layers`` of them held here: periods all
+    alike, whole ones held."""
+    period = attending[1] - attending[0] if len(attending) > 1 else listed
+    if not attending or period < 2 or attending != list(range(attending[0], listed, period)) or attending[0] >= period:
+        raise ValueError(f"{key} with 'attention' at {attending[:6]} is not served: periods of recurrent layers "
+                         "with one attention layer at the same place in each")
+    if layers % period:
+        raise ValueError(f"num_hidden_layers {layers} is not whole periods of {period} layers ({key}): not served")
+    return period, attending[0]
+
+
 def _heads_per_row(head_dim: int, per_group: int) -> int:
     """Heads of a Mamba-2 state that lie side by side on the 128 lanes of one
     buffer row (``ModelConfig.ssm_heads_per_row``): ``128 // head_dim`` for
@@ -323,14 +337,29 @@ class ModelConfig:
     # (Granite-4.0-H's ``granitemoehybrid``): ``ssm_heads`` *and*
     # ``layer_group_size`` set. The one layer of a period that attends (GQA
     # where ``attn_type`` is "gqa") sits at ``group_attn_index`` of the period
-    # (-1: its last layer, as in Ling's), and holds pages and no slot; every
-    # other layer holds a slot and no pages.
+    # (-1: its last layer, as in Ling's; 0: it opens the period, as in
+    # Solar-Open2's KDA periods round a GQA layer), and holds pages and no
+    # slot; every other layer holds a slot and no pages.
     group_attn_index: int = -1
     # Granite's multipliers: ``residual_multiplier`` on each block's output
     # before it joins the stream, ``attn_scale`` the softmax scale where it is
     # not ``head_dim ** -0.5`` (0.0: the usual scale).
     residual_multiplier: float = 1.0
     attn_scale: float = 0.0
+    # The KDA layer's form where it is not Ling's (Solar-Open2's ``solar_open2``:
+    # Kimi Linear's own, arXiv 2510.26692). ``kda_decay``: "bounded", ``g =
+    # kda_lower_bound * sigmoid(exp(a_log) (a + dt_bias))``, or "softplus", ``g =
+    # -exp(a_log) * softplus(a + dt_bias)``. ``kda_beta_scale``: the write strength
+    # is that times a sigmoid (2.0: ``I - beta k k^T`` may have a negative
+    # eigenvalue). ``kda_low_rank`` r > 0: the decay input ``a`` and the output
+    # gate come through pairs ``[hidden, r] [r, q_dim]`` and the gate is a value a
+    # channel; 0: a full-rank ``w_decay`` and a gate a head.
+    kda_decay: str = "bounded"
+    kda_beta_scale: float = 1.0
+    kda_low_rank: int = 0
+    # A sigmoid gate a channel on the GQA block's attention output, before its
+    # output projection, from the layer's normed input (``w_out_gate [hidden, q_dim]``).
+    attn_out_gate: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -488,7 +517,7 @@ class ModelConfig:
                     + self.kv_lora_rank * self.num_heads * (self.qk_nope_head_dim + self.v_head_dim)
                     + self.num_heads * self.v_head_dim * d)
         else:
-            attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+            attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d + d * self.q_dim * self.attn_out_gate
         dense = 3 * d * self.intermediate_size
         moe = (self.num_experts * 3 * d * self.moe_intermediate_size + d * self.router_outputs
                + 3 * d * self.shared_expert_size + (d if self.shared_expert_gated else 0))
@@ -504,11 +533,14 @@ class ModelConfig:
         if self.layer_group_size and inner:  # a period of Mamba-2 layers and one that attends
             return (embed + head + d + self.recurrent_layers * mixer + self.cache_layers * attn
                     + self.num_layers * norms + k_dense * dense + (self.num_layers - k_dense) * moe)
-        if self.layer_group_size:  # KDA: five full projections, two head-wise ones, filters, decay constants, head norm
-            q = self.q_dim
-            kda = (5 * d * q + 2 * d * self.num_heads + 3 * self.kda_conv_size * q + self.num_heads + q + self.head_dim)
+        if self.layer_group_size:
+            # KDA: four full projections, the decay input and the output gate (full rank and a value a head, or a
+            # low-rank pair each), the write strength, filters, decay constants, head norm.
+            q, r = self.q_dim, self.kda_low_rank
+            forms = 2 * (d * r + r * q) if r else d * q + d * self.num_heads
+            kda = (4 * d * q + forms + d * self.num_heads + 3 * self.kda_conv_size * q + self.num_heads + q + self.head_dim)
             n_kda = self.recurrent_layers
-            gate = d * self.num_heads  # the latent-attention layers' head-wise output gate (``w_out_gate``)
+            gate = d * self.num_heads if self.attn_type == "mla" else 0  # the latent-attention layers' head-wise output gate
             return (embed + head + d + n_kda * kda + (self.num_layers - n_kda) * (attn + gate)
                     + self.num_layers * norms + k_dense * dense + (self.num_layers - k_dense) * moe)
         return (embed + head + d + self.num_layers * (attn + mixer + norms)
@@ -708,13 +740,8 @@ class ModelConfig:
         if unknown or len(kinds) < layers:
             raise ValueError(f"layer_types holds {unknown or len(kinds)}: expected at least {layers} entries of "
                              "'mamba' / 'attention'")
-        attending = [i for i, kind in enumerate(kinds) if kind == "attention"]
-        period = attending[1] - attending[0] if len(attending) > 1 else len(kinds)
-        if not attending or period < 2 or any((kind == "attention") != (i % period == attending[0]) for i, kind in enumerate(kinds)):
-            raise ValueError(f"layer_types with 'attention' at {attending[:6]} is not served: periods of Mamba layers "
-                             "with one attention layer at the same place in each")
-        if layers % period:
-            raise ValueError(f"num_hidden_layers {layers} is not whole periods of {period} layers (layer_types): not served")
+        period, attends = _period_layout([i for i, kind in enumerate(kinds) if kind == "attention"], len(kinds), layers,
+                                         "layer_types")
         attn_heads = config["num_attention_heads"]
         attn_head_dim = hidden // attn_heads  # the published layer has no ``head_dim`` key
         return cls(
@@ -733,13 +760,75 @@ class ModelConfig:
             # published gate is ``self.layer(h).float()``: the product in the activations' dtype, then widened, which
             # is route_tokens' own form.)
             moe_scoring="softmax", moe_norm_topk=True,
-            layer_group_size=period, group_attn_index=attending[0],
+            layer_group_size=period, group_attn_index=attends,
             ssm_heads=heads, ssm_head_dim=head_dim, ssm_state_size=state, ssm_groups=groups,
             ssm_conv_size=int(config.get("mamba_d_conv", 4)),
             embed_multiplier=float(config.get("embedding_multiplier", 1.0)),
             lm_head_multiplier=1.0 / float(config.get("logits_scaling", 1.0)),
             residual_multiplier=float(config.get("residual_multiplier", 1.0)),
             attn_scale=float(config.get("attention_multiplier", attn_head_dim**-0.5)),
+        )
+
+    @classmethod
+    def _from_solar_open2(cls, config: dict, name: str | None) -> "ModelConfig":
+        """Solar-Open2's config.json (``model_type`` ``solar_open2``): the layers
+        ``gqa_layers`` name (every ``gqa_interval + 1``-th, from 0: the layer that
+        attends *opens* its period) are GQA attention without RoPE (``use_rope``
+        false) with a sigmoid gate a channel on their output (``use_gqa_gate``),
+        every other one a KDA layer in Kimi Linear's own form
+        (``linear_attn_config``; ``kda_use_full_proj`` false: the decay input and
+        the output gate through low-rank pairs of rank ``head_dim``, the gate a
+        value a channel; the decay ``-exp(a_log) softplus(.)``, the file has no
+        ``kda_safe_gate`` / ``kda_lower_bound``; ``kda_allow_neg_eigval``: a write
+        strength in (0, 2)); every layer's FFN ``n_routed_experts`` sigmoid-routed
+        experts (DeepSeek-V3's keys and their convention: a selection bias,
+        weights renormalised over the chosen) beside ``n_shared_experts`` of
+        ``moe_intermediate_size``. A file that states a share
+        (``n_routed_experts_published`` beside ``n_routed_experts`` held here, of
+        rank ``expert_share_rank``) gives a model that holds that share;
+        ``gqa_layers`` may stay whole, the entries of the layers held are read.
+        Refuses by name what the layers do not compute; ``rope_theta`` and
+        ``partial_rotary_factor`` rotate nothing and ``intermediate_size`` is
+        no layer's width (no dense layer): taken without complaint."""
+        unserved = {"kda_use_full_proj": False, "use_rope": False, "first_k_dense_replace": 0, "scoring_func": "sigmoid",
+                    "topk_method": "noaux_tc", "hidden_act": "silu", "rope_scaling": None, "attention_bias": False}
+        _refuse_unserved(config, unserved, "solar_open2")
+        hidden, layers, heads = config["hidden_size"], int(config["num_hidden_layers"]), config["num_attention_heads"]
+        head_dim = config.get("head_dim") or hidden // heads
+        linear = config["linear_attn_config"]
+        if linear.get("num_kv_heads") is not None:
+            raise ValueError(f"linear_attn_config.num_kv_heads {linear['num_kv_heads']!r} is not served for model_type "
+                             "'solar_open2': only None (every linear-attention head its own key and value)")
+        if (linear["num_heads"], linear["head_dim"]) != (heads, head_dim):
+            raise ValueError(f"linear_attn_config num_heads {linear['num_heads']} x head_dim {linear['head_dim']} against "
+                             f"num_attention_heads {heads} x head_dim {head_dim} is not served: one head count for both layer kinds")
+        attending = [int(i) for i in config["gqa_layers"]]
+        listed = max(attending[-1] + 1, layers) if attending else layers
+        period, attends = _period_layout(attending, listed, layers, "gqa_layers")
+        if attends != 0 or period != int(config.get("gqa_interval", period - 1)) + 1:
+            raise ValueError(f"gqa_layers {attending[:6]} with gqa_interval {config.get('gqa_interval')!r} is not served: "
+                             "only range(0, num_hidden_layers, gqa_interval + 1)")
+        held, total, first = _expert_share(config)
+        n_group, topk_group = _group_limit(config, held, total)
+        return cls(
+            name=name or config.get("_name_or_path", "solar_open2"),
+            vocab_size=config["vocab_size"], hidden_size=hidden, num_layers=layers,
+            num_heads=heads, num_kv_heads=config.get("num_key_value_heads", heads), head_dim=head_dim,
+            intermediate_size=config["intermediate_size"],
+            # No rotary embedding: the identity table (``ops/rope.py``), as in Granite's attention layers.
+            rope_theta=float(config.get("rope_theta", 10000.0)), rope_scaling={"rope_type": "nope"},
+            rms_eps=config.get("rms_norm_eps", 1e-5), max_position=config.get("max_position_embeddings", 8192),
+            tie_embeddings=bool(config.get("tie_word_embeddings", False)), attention_bias=False,
+            num_experts=held, num_experts_per_token=int(config["num_experts_per_tok"]),
+            moe_intermediate_size=config["moe_intermediate_size"],
+            moe_experts_total=total if total != held else 0, moe_expert_first=first,
+            shared_expert_size=int(config.get("n_shared_experts", 0) or 0) * int(config["moe_intermediate_size"]),
+            moe_scoring="sigmoid", moe_norm_topk=bool(config.get("norm_topk_prob", True)), moe_router_bias=True,
+            moe_routed_scaling=float(config.get("routed_scaling_factor", 1.0) or 1.0),
+            moe_n_group=n_group, moe_topk_group=topk_group,
+            layer_group_size=period, group_attn_index=0, attn_out_gate=bool(config.get("use_gqa_gate", False)),
+            kda_conv_size=int(linear.get("short_conv_kernel_size", 4)), kda_decay="softplus",
+            kda_beta_scale=2.0 if config.get("kda_allow_neg_eigval") else 1.0, kda_low_rank=int(linear["head_dim"]),
         )
 
     @classmethod
@@ -796,6 +885,8 @@ class ModelConfig:
             return cls._from_falcon_h1(config, name)
         if config.get("model_type") == "granitemoehybrid":
             return cls._from_granite_hybrid(config, name)
+        if config.get("model_type") == "solar_open2":
+            return cls._from_solar_open2(config, name)
         # A state-space model's config also describes a GQA stack: served by this
         # branch it would run as that stack alone, silently (what PR 26 found for Mellum2).
         ssm_keys = sorted(k for k in config if k.startswith(("mamba_", "ssm_")))
@@ -804,6 +895,10 @@ class ModelConfig:
                 f"model_type {config.get('model_type')!r} states {ssm_keys[0]} (and {len(ssm_keys) - 1} more mamba_* / "
                 "ssm_* keys): a state-space layer that no branch of from_hf reads is not served "
                 "(served with a mixer: model_type 'falcon_h1', 'granitemoehybrid')")
+        if "linear_attn_config" in config:  # likewise: linear-attention layers beside a GQA stack's keys
+            raise ValueError(
+                f"model_type {config.get('model_type')!r} states linear_attn_config: linear-attention layers that no branch "
+                "of from_hf reads are not served (served with KDA layers: model_type 'bailing_hybrid', 'solar_open2')")
         hidden = config["hidden_size"]
         heads = config["num_attention_heads"]
         # DeepSeek replaces the first k MoE layers with dense MLPs
@@ -1230,3 +1325,31 @@ TINY_GRANITE_HYBRID_HF: dict[str, Any] = {
 }
 PRESETS["test-tiny-granite-hybrid"] = dataclasses.replace(
     ModelConfig.from_hf(TINY_GRANITE_HYBRID_HF, name="test-tiny-granite-hybrid"), dtype="float32")
+
+
+#: Solar-Open2-250B's published ``config.json`` (upstage; ``model_type``
+#: ``solar_open2``), the catalog row's keys key for key:
+#: ``tests/benchmark/test_benchmark_solar_open2.py`` holds it to the row where
+#: the catalog is on the machine.
+SOLAR_OPEN2_250B_HF: dict[str, Any] = {
+    "model_type": "solar_open2", "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128, "num_heads": 64, "num_kv_heads": None},
+    "hidden_size": 4096, "num_hidden_layers": 48, "num_attention_heads": 64, "head_dim": 128, "num_key_value_heads": 8,
+    "vocab_size": 196608, "intermediate_size": 10240, "moe_intermediate_size": 1280, "rms_norm_eps": 1e-05,
+    "rope_theta": 10000, "tie_word_embeddings": False, "max_position_embeddings": 1048576, "first_k_dense_replace": 0,
+    "use_rope": False, "gqa_interval": 3, "gqa_layers": list(range(0, 48, 4)), "use_gqa_gate": True,
+    "kda_use_full_proj": False, "kda_allow_neg_eigval": True, "n_routed_experts": 320, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1, "num_experts_per_tok": 8,
+}
+#: The same keys at toy widths: two periods of ``[GQA, KDA, KDA, KDA]``, 4 heads
+#: of 16 over 2 KV heads, low-rank pairs of rank 16, 5 of 10 experts held (the
+#: second of 2 shares: no power of two), top-2 beside a shared expert, float32.
+TINY_SOLAR_OPEN2_HF: dict[str, Any] = {
+    **SOLAR_OPEN2_250B_HF, "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_hidden_layers": 8, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4, "num_kv_heads": None},
+    "n_routed_experts": 5, "n_routed_experts_published": 10, "expert_share_rank": 1, "expert_share_chips": 2,
+    "num_experts_per_tok": 2, "vocab_size": 256, "max_position_embeddings": 512,
+}
+PRESETS["test-tiny-solar-open2"] = dataclasses.replace(
+    ModelConfig.from_hf(TINY_SOLAR_OPEN2_HF, name="test-tiny-solar-open2"), dtype="float32")

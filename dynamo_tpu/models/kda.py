@@ -10,8 +10,14 @@ with a decay ``alpha_t = exp(g_t)`` per key channel and a write strength
 ``beta_t`` per head. ``q``, ``k`` and ``v`` come from a causal depthwise
 convolution of ``cfg.kda_conv_size`` taps over the projections followed by
 SiLU, so a sequence also carries the last ``taps - 1`` inputs of the three
-streams (its **conv state**). ``benchmark/reference/ling_3_flash.py`` writes
-the layer out equation by equation.
+streams (its **conv state**). The configuration says which form the gates
+take: Ling-3.0's (a full-rank decay input, the log-decay bounded ``g =
+kda_lower_bound * sigmoid(.)``, ``beta`` in (0, 1), an output gate a head) or
+Kimi Linear's own, Solar-Open2's (``cfg.kda_low_rank``: the decay input and
+the output gate through low-rank pairs, the gate a value a channel;
+``cfg.kda_decay`` "softplus": ``g = -exp(a_log) softplus(.)``;
+``cfg.kda_beta_scale`` 2: ``beta`` in (0, 2)). ``benchmark/reference/ling_3_flash.py``
+and ``solar_open2.py`` write the two out equation by equation.
 
 What a step does with a row's slot:
 
@@ -51,9 +57,16 @@ from dynamo_tpu.models.quant import held_flat, quant_matmul as _qmm
 
 Params = dict
 
-#: Chunk rows beyond which the chunkwise form runs row by row (``lax.map``): its
-#: ``[tokens, tokens, key]`` decay differences are 67 MB a row at 64 tokens x 32 heads.
-CHUNK_ROWS_AT_ONCE = 4
+#: Chunk rows the chunkwise form takes at once (``vmap``; more run row by row, ``lax.map``) at up to this many
+#: heads: its ``[tokens, tokens, heads, key]`` decay differences are 67 MB a row at 64 tokens x 32 heads of 128.
+CHUNK_ROWS_AT_ONCE, CHUNK_ROWS_HEADS = 4, 32
+
+
+def chunk_rows_at_once(heads: int) -> int:
+    """Chunk rows the chunkwise form takes at once at ``heads`` heads: as many
+    as keep a step's decay differences where :data:`CHUNK_ROWS_AT_ONCE` rows of
+    :data:`CHUNK_ROWS_HEADS` heads have them (4 at 32 heads, 2 at 64, 1 from 128)."""
+    return max(1, CHUNK_ROWS_AT_ONCE * CHUNK_ROWS_HEADS // heads)
 
 
 def init_kda_params(cfg: ModelConfig, key: jax.Array, dt, num_layers: int) -> dict[str, jnp.ndarray]:
@@ -61,18 +74,22 @@ def init_kda_params(cfg: ModelConfig, key: jax.Array, dt, num_layers: int) -> di
     large projections take the stack's names for them (``wq wk wv wo``: they
     are the layer's q, k, v and output projections, and are served int8)."""
     d, h, hd, q = cfg.hidden_size, cfg.num_heads, cfg.head_dim, cfg.q_dim
-    l, taps = num_layers, cfg.kda_conv_size
+    l, taps, r = num_layers, cfg.kda_conv_size, cfg.kda_low_rank
     keys = jax.random.split(key, 10)
 
     def w(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
 
+    if r:  # the decay input and the output gate, a value a channel each, through pairs of rank r
+        forms = {"w_decay_a": w(keys[4], (l, d, r), d), "w_decay_b": w(jax.random.fold_in(keys[4], 1), (l, r, q), r),
+                 "w_out_gate_a": w(keys[6], (l, d, r), d), "w_out_gate_b": w(jax.random.fold_in(keys[6], 1), (l, r, q), r)}
+    else:  # a_t: one log-decay input a head and key channel, full rank; the gate a value a head
+        forms = {"w_decay": w(keys[4], (l, d, q), d), "w_out_gate": w(keys[6], (l, d, h), d)}
     return {
         "wq": w(keys[0], (l, d, q), d), "wk": w(keys[1], (l, d, q), d), "wv": w(keys[2], (l, d, q), d),
         "wo": w(keys[3], (l, q, d), q),
-        "w_decay": w(keys[4], (l, d, q), d),  # a_t: one log-decay input a head and key channel
+        **forms,
         "w_beta": w(keys[5], (l, d, h), d),
-        "w_out_gate": w(keys[6], (l, d, h), d),
         "conv_q": w(keys[7], (l, taps, q), taps), "conv_k": w(keys[8], (l, taps, q), taps),
         "conv_v": w(keys[9], (l, taps, q), taps),
         "a_log": jnp.zeros((l, h), dt),  # exp(a_log) scales a head's decay input
@@ -219,7 +236,7 @@ def _rows_update(state, ids, fresh, q, k, v, g, beta, *, impl: str | None):
     if t == 1:
         o, s = recurrent_step(s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
         o = o[:, None]
-    elif r > CHUNK_ROWS_AT_ONCE:
+    elif r > chunk_rows_at_once(q.shape[2]):
         o, s = jax.lax.map(lambda a: chunk_step(*a), (s0, q, k, v, g, beta))
     else:
         o, s = jax.vmap(chunk_step)(s0, q, k, v, g, beta)
@@ -263,12 +280,26 @@ def kda_attention(
         filt = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)  # [taps, 3Q]
     with jax.named_scope("kda.gates"):
         f32 = lambda name: jnp.dot(h, lp[name], preferred_element_type=jnp.float32)  # noqa: E731
+
+        def through_pair(name: str):
+            """``h`` through the low-rank pair ``name_a`` ``name_b``, float32 out; the rank-wide middle in ``h``'s dtype."""
+            return jnp.dot(f32(f"{name}_a").astype(h.dtype), lp[f"{name}_b"], preferred_element_type=jnp.float32)
+
         # (held flat, as the q, k, v projections are: the heads' layout must not reach the dot and re-lay its weight)
-        a = held_flat(f32("w_decay")) + lp["dt_bias"].astype(jnp.float32)
+        a = held_flat(through_pair("w_decay") if cfg.kda_low_rank else f32("w_decay")) + lp["dt_bias"].astype(jnp.float32)
         rate = jnp.repeat(jnp.exp(lp["a_log"].astype(jnp.float32)), hd)  # a head's rate on each of its channels
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * a)  # in (lower bound, 0)
+        if cfg.kda_decay == "softplus":
+            g = -rate * jax.nn.softplus(a)  # <= 0, unbounded below
+        elif cfg.kda_decay == "bounded":
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * a)  # in (lower bound, 0)
+        else:
+            raise NotImplementedError(f"kda_decay {cfg.kda_decay!r}: 'bounded' or 'softplus'")
         beta = jax.nn.sigmoid(f32("w_beta"))  # [B, T, H]
-        out_gate = jax.nn.sigmoid(f32("w_out_gate"))
+        if cfg.kda_beta_scale != 1.0:
+            beta = beta * cfg.kda_beta_scale
+        # The output gate: a value a channel through its pair [B, T, H, hd], or a value a head [B, T, H].
+        out_gate = (jax.nn.sigmoid(held_flat(through_pair("w_out_gate"))).reshape(b, t, heads, hd) if cfg.kda_low_rank
+                    else jax.nn.sigmoid(f32("w_out_gate")))
         # A padding token neither decays nor writes.
         g = jnp.where(valid[..., None], g, 0.0)
         beta = jnp.where(valid[..., None], beta, 0.0)
@@ -298,5 +329,5 @@ def kda_attention(
         o = jnp.concatenate([o_d, o_c], axis=1)
     with jax.named_scope("kda.out"):
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps) * lp["o_norm"].astype(jnp.float32)
-        o = (o * out_gate[..., None]).astype(h.dtype)
+        o = (o * (out_gate if cfg.kda_low_rank else out_gate[..., None])).astype(h.dtype)
         return _qmm(o.reshape(b, t, heads * hd), lp["wo"]), state, conv

@@ -80,6 +80,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
                     "wo": w(ks[3], (l, q, d), q),
                 }
             )
+            if cfg.attn_out_gate:
+                layers["w_out_gate"] = w(ks[8], (l, d, q), d)
         if attn and cfg.ssm_heads:  # a Mamba-2 mixer beside the attention, in every layer
             from dynamo_tpu.models.mamba2 import init_mamba_params
 
@@ -160,24 +162,28 @@ def init_params(cfg: ModelConfig, rng: jax.Array | int = 0) -> Params:
     }
     if k_dense:
         params["dense_layers"] = layer_stack(k_dense, False, 1, attn=not hybrid)
-    if hybrid and cfg.ssm_heads:
-        # Mamba-2 layers that stand alone and the GQA blocks of the layers that attend, each stack in layer order.
-        from dynamo_tpu.models.mamba2 import init_mamba_params
-
-        n_attn, ks = cfg.cache_layers, [jax.random.fold_in(k, 4) for k in keys]
-        params["ssm_layers"] = init_mamba_params(cfg, jax.random.fold_in(keys[0], 7), dt, cfg.recurrent_layers)
-        params["attn_layers"] = {"wq": w(ks[0], (n_attn, d, q), d), "wk": w(ks[1], (n_attn, d, kv), d),
-                                 "wv": w(ks[2], (n_attn, d, kv), d), "wo": w(ks[3], (n_attn, q, d), q)}
-    elif hybrid:
-        # The attention blocks by kind, each stack in layer order: ``layers`` and
-        # ``dense_layers`` hold the norms and the FFNs of every layer.
+    if hybrid:
+        # The blocks of a period by kind, each stack in layer order (``layers`` and ``dense_layers`` hold the
+        # norms and the FFNs of every layer): the recurrent layers' (Mamba-2 layers that stand alone, or KDA) and
+        # those of the layers that attend (latent attention with its head-wise output gate, or the GQA block).
         from dynamo_tpu.models.kda import init_kda_params
+        from dynamo_tpu.models.mamba2 import init_mamba_params
         from dynamo_tpu.models.mla import init_mla_params
 
-        n_mla = cfg.num_layers - cfg.recurrent_layers
-        params["kda_layers"] = init_kda_params(cfg, jax.random.fold_in(keys[0], 2), dt, cfg.recurrent_layers)
-        params["mla_layers"] = {**init_mla_params(cfg, jax.random.fold_in(keys[0], 3), dt, n_mla),
-                                "w_out_gate": w(jax.random.fold_in(keys[1], 3), (n_mla, d, cfg.num_heads), d)}
+        n_attn = cfg.cache_layers
+        if cfg.ssm_heads:
+            params["ssm_layers"] = init_mamba_params(cfg, jax.random.fold_in(keys[0], 7), dt, cfg.recurrent_layers)
+        else:
+            params["kda_layers"] = init_kda_params(cfg, jax.random.fold_in(keys[0], 2), dt, cfg.recurrent_layers)
+        if cfg.attn_type == "mla":
+            params["mla_layers"] = {**init_mla_params(cfg, jax.random.fold_in(keys[0], 3), dt, n_attn),
+                                    "w_out_gate": w(jax.random.fold_in(keys[1], 3), (n_attn, d, cfg.num_heads), d)}
+        else:
+            ks = [jax.random.fold_in(k, 4) for k in keys]
+            params["attn_layers"] = {"wq": w(ks[0], (n_attn, d, q), d), "wk": w(ks[1], (n_attn, d, kv), d),
+                                     "wv": w(ks[2], (n_attn, d, kv), d), "wo": w(ks[3], (n_attn, q, d), q)}
+            if cfg.attn_out_gate:
+                params["attn_layers"]["w_out_gate"] = w(ks[8], (n_attn, d, q), d)
     if not cfg.tie_embeddings:
         params["lm_head"] = w(keys[9], (d, cfg.vocab_size), d)
     return params
@@ -638,9 +644,10 @@ def forward(
     def gqa_attention(lp, h, k_full, v_full, li, kind=None):
         """One layer's GQA block on its normed input ``h``: the projections with
         their optional biases, norms and multipliers, RoPE by the layer's
-        kind, the cache write, paged attention (on the split token axis too)
-        and the output projection. ``li`` is the layer's slab of the cache,
-        ``kind`` a mixed model's scalars of the layer. Returns ``(out, k_full,
+        kind, the cache write, paged attention (on the split token axis too),
+        the output gate where the model has one and the output projection.
+        ``li`` is the layer's slab of the cache, ``kind`` a mixed model's scalars
+        of the layer. Returns ``(out, k_full,
         v_full)``. The plain layer body and the period scan both call it."""
         qp, kp, vp = _qmm(h, lp["wq"]), _qmm(h, lp["wk"]), _qmm(h, lp["wv"])
         if cfg.attention_bias:
@@ -722,7 +729,12 @@ def forward(
                 attn = paged_attention(q, k_full, v_full, tables_l, positions, impl=attn_impl,
                                        sliding_window=window,
                                        contiguous_positions=contiguous_positions)
-        attn_out = _qmm(attn.reshape(b, t, cfg.q_dim), lp["wo"])
+        attn = attn.reshape(b, t, cfg.q_dim)
+        if cfg.attn_out_gate:  # a sigmoid gate a channel on the heads' outputs, from the layer's normed input
+            with jax.named_scope("attn.gate"):
+                gate = jax.nn.sigmoid(jnp.dot(h, lp["w_out_gate"], preferred_element_type=jnp.float32))
+                attn = (attn * gate).astype(attn.dtype)
+        attn_out = _qmm(attn, lp["wo"])
         if cfg.attn_out_multiplier != 1.0:
             attn_out = attn_out * jnp.asarray(cfg.attn_out_multiplier, attn_out.dtype)
         return attn_out, k_full, v_full
@@ -802,8 +814,8 @@ def forward(
         ``ssm_layers``), which attention kind (latent attention, ``mla_layers``;
         the GQA block, ``attn_layers``), where in the period the layer that
         attends sits (``cfg.period_attn_index``: Ling's last of 6, Granite's
-        sixth of 10) and which FFN a layer has (the first ``n_dense`` layers'
-        dense, every later one routed, whole or a held share), and
+        sixth of 10, Solar-Open2's first of 4) and which FFN a layer has (the
+        first ``n_dense`` layers' dense, every later one routed, whole or a held share), and
         ``cfg.residual_multiplier`` scales each block's output. Two kinds of
         layer have two parameter trees and two kinds of state, so the stack
         is a scan over periods around loops over the period's recurrent
@@ -817,10 +829,10 @@ def forward(
 
         group, n_rec, attends = cfg.layer_group_size, cfg.recurrent_layers, cfg.period_attn_index
         if ring or layer_kinds is not None or cfg.first_k_dense != n_dense or n_dense > attends:
-            raise NotImplementedError("a hybrid stack is served by the paged path, its leading dense FFNs as stated "
-                                      "and under recurrent layers")
-        if bool(cfg.ssm_heads) == mla:
-            raise NotImplementedError("a period is KDA layers with one latent-attention layer, or Mamba-2 layers with one GQA layer")
+            raise NotImplementedError(
+                "a hybrid stack is served by the paged path (no ring attention, no window layers), its leading dense "
+                "FFNs as stated and under recurrent layers; not served: two layers that attend in a period, a "
+                "recurrent kind per layer, head counts per attention kind")
         state, conv, slot_ids = recurrent
         slots = state.shape[0] // n_rec
         valid = slot_mapping != 0
@@ -878,9 +890,10 @@ def forward(
 
         def period(carry, p):
             first = jnp.where(p == 0, n_dense, 0) if n_dense else 0  # the first period's dense layers are done
-            carry = jax.lax.fori_loop(
-                first, attends,
-                lambda j, c: recurrent_layer(c, p * (group - 1) + j, moe_layers, p * group + j - n_dense, True), carry)
+            if attends:  # the period's recurrent layers before the one that attends
+                carry = jax.lax.fori_loop(
+                    first, attends,
+                    lambda j, c: recurrent_layer(c, p * (group - 1) + j, moe_layers, p * group + j - n_dense, True), carry)
             # (counted back from the period's end, not ``p * group + attends``: where the layer that attends closes the
             # period, Ling's, the traced arithmetic is then the parent's, and so is the compiled text of Ling's decode and
             # chunk programs down to its computations' names: ``tools/step_relayouts.py ling-3.0-flash-ep8-int8 64

@@ -562,8 +562,9 @@ def load_params(
 #: have no map in ``_leaf_specs`` (no published list of them to work from; for
 #: ``falcon_h1`` and ``granitemoehybrid`` the names are published and the map is
 #: not written: their mixers' leaves, and a state laid the other way round than
-#: the published cache).
-UNMAPPED_MODEL_TYPES = frozenset({"mellum", "exaone_moe", "falcon_h1", "granitemoehybrid"})
+#: the published cache; ``solar_open2``'s KDA and gate leaves have no published
+#: list either).
+UNMAPPED_MODEL_TYPES = frozenset({"mellum", "exaone_moe", "falcon_h1", "granitemoehybrid", "solar_open2"})
 
 
 def load_model(

@@ -712,39 +712,66 @@ def test_kda_decode_kernel_compiles(sds, monkeypatch, budget_mib, block):
     assert mem.alias_size_in_bytes >= 15 * 65 * 32 * 128 * 128 * 4 and mem.temp_size_in_bytes < 1 << 20
 
 
-def _conv_buffer_stays(text: str, conv) -> None:
+def test_kda_decode_kernel_compiles_at_sixty_four_heads(sds):
+    """The same kernel at Solar-Open2-250B's widths (ISSUE 53): 64 rows x 64
+    heads of 128 x 128 over 6 layers x 65 slots, a slot-layer of 4.19 MB. The
+    budget holds 32 heads a block, so the grid is 64 rows x 2 blocks; the 4,096
+    write strengths ride SMEM beside the slot ids; the 1.64 GB state is
+    aliased to the kernel's output."""
+    from dynamo_tpu.ops import pallas_kda
+
+    assert pallas_kda.heads_block(64, 4 * 128 * 128) == 32
+    f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
+    compiled = jax.jit(lambda *a: pallas_kda.kda_decode_step.__wrapped__(*a), donate_argnums=(0,)).lower(
+        f32(6 * 65, 64, 128, 128), sds((64,), jnp.int32), sds((64,), jnp.bool_),
+        f32(64, 64, 128), f32(64, 64, 128), f32(64, 64, 128), f32(64, 64, 128), f32(64, 64)).compile()
+    text = compiled.as_text()
+    assert "kda_decode_step" in text and "f32[64,1,128,128]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * 65 * 64 * 128 * 128 * 4 and mem.temp_size_in_bytes < 1 << 20
+
+
+def _conv_buffer_stays(text: str, conv, chunk_row_in_xla: bool = False) -> None:
     """The step program takes the conv buffer, hands it to ``slot_conv_step``
     where it lies (pinned to HBM) and hands it back: no instruction makes a
     buffer of its shape (a ``copy`` to another layout or memory, a scatter, a
     ``dynamic-update-slice``), no fusion slices or gathers from it, and (the
     callers count them) no loop is left but the layer loops (the parent's gather and scatter by slot id
-    were a ``while`` over the 64 rows each; PERF.md, PR 48)."""
+    were a ``while`` over the 64 rows each; PERF.md, PR 48). ``chunk_row_in_xla``: a
+    chunk too wide for the kernel (``pallas_conv.supported``) reads its one
+    row's slot by a slice and writes it back where it lies
+    (``dynamic-update-slice`` in the buffer's own layout): still no copy."""
     import re
 
     shape = re.escape(f"bf16[{','.join(map(str, conv.shape))}]")
     made = set(re.findall(rf"= {shape}\S* ([\w-]+)\(", text))
-    assert made <= {"parameter", "get-tuple-element"}, made
-    assert not re.search(rf"^%fused_computation\S* \(.*{shape}", text, re.M)  # no fusion takes it: nothing gathers or slices from it
+    assert made <= {"parameter", "get-tuple-element"} | ({"dynamic-update-slice", "fusion"} if chunk_row_in_xla else set()), made
+    if not chunk_row_in_xla:
+        assert not re.search(rf"^%fused_computation\S* \(.*{shape}", text, re.M)  # no fusion takes it: nothing gathers or slices from it
     assert not re.search(rf"{shape}\S*, u32\[\]\S*\) copy-start\(", text)  # nor moves it to another memory
     assert "slot_conv_step" in text and '"output_memory_colors":["0","-1"]' in text
 
 
 @pytest.mark.parametrize("slots, c, bias, rows, tokens", [
     (15 * 65, 96, False, 64, 1), (9 * 65, 40, True, 64, 1), (15 * 65, 96, False, 1, 64), (9 * 65, 40, True, 2, 64),
-    (9 * 65, 72, True, 64, 1), (9 * 65, 72, True, 1, 64),
-], ids=["ling-decode", "falcon-h1-decode", "ling-chunk", "falcon-h1-chunks", "granite-decode", "granite-chunk"])
+    (9 * 65, 72, True, 64, 1), (9 * 65, 72, True, 1, 64), (6 * 65, 192, False, 64, 1), (6 * 65, 192, False, 1, 32),
+], ids=["ling-decode", "falcon-h1-decode", "ling-chunk", "falcon-h1-chunks", "granite-decode", "granite-chunk",
+        "solar-open2-decode", "solar-open2-half-chunk"])
 def test_slot_conv_kernel_compiles(sds, slots, c, bias, rows, tokens):
     """The conv rows' step at both cells' widths (Ling-3.0-flash's 12,288
     channels in 96 rows of lanes, no bias; Falcon-H1-34B's 5,120 in 40, whose
     last bfloat16 tile is half full, with one; granite-4.0-h-small's 8,448 in
     66, held in 72: a 66-row buffer the device would lay out with the slots on
-    the sublanes): 64 one-token rows and a
+    the sublanes; Solar-Open2-250B's 24,576 in 192, whose 64-token chunk is 25
+    MiB of blocks against ``ROWS_VMEM``'s 16 and keeps the XLA path: 32 tokens
+    are the most the kernel takes at that width): 64 one-token rows and a
     64-token chunk, the buffer aliased to the kernel's output, pinned to HBM
     and taken in the layout it is allocated in (row-major, ``(8, 128)(2, 1)``
     tiles over the last two axes): nothing but the kernel in the program."""
     from dynamo_tpu.ops import pallas_conv
 
     assert pallas_conv.supported(tokens, c, 128) and not pallas_conv.supported(128, 96, 128) and not pallas_conv.supported(1, 66, 128)
+    assert not pallas_conv.supported(64, 192, 128)
     args = [sds((slots, 3, c, 128), jnp.bfloat16), sds((rows,), jnp.int32), sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
             sds((rows, tokens, c, 128), jnp.float32), sds((4, c, 128), jnp.float32)] + ([sds((c, 128), jnp.float32)] if bias else [])
     compiled = jax.jit(lambda *a: pallas_conv.slot_conv_step.__wrapped__(*a), donate_argnums=(0,)).lower(*args).compile()
@@ -990,3 +1017,68 @@ def test_period_step_granite_largest_corners(sds, monkeypatch, split):
     assert not [(op["name"], op["shape"]) for op in ops if op["dtype"] == "s8"]
     if split is None:
         assert not [op for op in ops if op["bytes"] >= state.size * 4 // 65 // 9]  # nothing the size of a row's state moves
+
+
+# -- KDA layers (64 heads, low-rank gates) round a gated GQA layer that opens the period, a held share of 40 (ISSUE 53) ------
+
+
+@pytest.mark.parametrize("split", [None, (64, 1, 64)], ids=["decode", "mixed-chunk"])
+def test_period_step_solar_open2_largest_corners(sds, monkeypatch, split):
+    """reason-saturated's largest steps at Solar-Open2-250B's widths, one period
+    ([GQA, KDA, KDA, KDA], this chip's 40 of 320 experts, a small vocabulary):
+    64 decode rows, and 64 decode slots + one 64-token chunk slot, over 16
+    pages. The layer that attends opens the period, so the period scan is the
+    GQA layer (the paged GQA kernels at 64 / 8 heads, its gate's 67 MB bf16
+    weight read where it lies) and *one* loop over the KDA layers behind it:
+    ``kda_decode_step`` at 64 heads and ``slot_conv_step`` at 192 rows of lanes
+    in its body, the held experts through the grouped int8 kernel in both
+    bodies by a layer index, the 320-output router without a sort. The state
+    buffers come back as the last two outputs, updated where they lie; no int8
+    weight is re-laid inside the loops and nothing the size of a gate or of a
+    low-rank pair is copied."""
+    from dynamo_tpu.models import kda, llama
+    from dynamo_tpu.parallel import moe
+    from tests.test_step_relayouts import load_tool
+
+    monkeypatch.setattr(moe, "_kernel_platform", lambda: True)  # the described chip, not this CPU
+    cfg = _benchmark_config("solar-open2-250b-ep8-int8", layers=4)
+    assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, cfg.layer_group_size, cfg.period_attn_index) == (4, 3, 1, 4, 0)
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.num_experts, cfg.routed_experts, cfg.kda_low_rank, cfg.attn_out_gate) == (
+        64, 8, 40, 320, 128, True)
+    assert moe.router_select(64, 320, 8) == "passes" and moe.router_select(1, 320, 8) == "sort" and moe.held_rows_cap(512, 40, 320) == 128
+    like = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = _served_params(sds, cfg)
+    assert params["attn_layers"]["w_out_gate"].dtype == jnp.bfloat16 and params["attn_layers"]["wq"]["qw"].dtype == jnp.int8
+    assert params["kda_layers"]["w_decay_b"].shape == (3, 128, 8192) and params["kda_layers"]["wq"]["qw"].dtype == jnp.int8
+    k_cache, v_cache = like(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 1025, 128)))
+    state, conv = like(jax.eval_shape(lambda: kda.init_state(cfg, 65)))
+    assert state.shape == (3 * 65, 64, 128, 128) and conv.shape == (3 * 65, 3, 192, 128) and k_cache.shape == (1, 1025, 128, 1024)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if split is None:
+        toks, slots = (64, 1), 64
+    else:
+        toks, slots = (split[0] + split[1] * split[2],), split[0] + split[1]
+
+    def step(params, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index, state, conv, slot_ids):
+        return llama.forward(params, cfg, tokens, positions, k_cache, v_cache, block_tables, slot_mapping, last_token_index,
+                             attn_impl="pallas", split=split, moe_counts=True, recurrent=(state, conv, slot_ids))
+
+    compiled = jax.jit(step, donate_argnums=(3, 4, 8, 9)).lower(
+        params, i32(*toks), i32(*toks), k_cache, v_cache, i32(slots, 16), i32(*toks), i32(slots), state, conv, i32(slots),
+    ).compile()
+    text = compiled.as_text()
+    assert "kda_decode_step" in text and ("paged_prefill_attention" if split else "paged_decode_attention") in text
+    assert "mla_paged_decode_attention" not in text and "mamba_decode_step" not in text
+    assert text.count("moe_grouped_matmul_int8") >= 2 and "ragged-dot" not in text and "ragged_dot" not in text
+    # (the decode rows' conv through the kernel in both steps; the chunk slot's 64 tokens x 24,576 channels are
+    # over the kernel's VMEM budget and take the XLA path, one slot sliced out and written back in place)
+    _conv_buffer_stays(text, conv, chunk_row_in_xla=split is not None)
+    assert text.count(" while(") == 1  # the period's KDA layers behind the one that attends (one period is no loop): none over rows
+    assert " sort(" not in text  # a full step's router takes its 8 of 320 by passes of max
+    shapes = [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
+    assert shapes[-3:] == [(5,), state.shape, conv.shape]  # HELD_COUNTS, then the state buffers
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4 and mem.temp_size_in_bytes < 1 << 30  # no second copy of the state
+    ops = load_tool().relayouts(text)
+    assert not [(op["name"], op["shape"]) for op in ops if op["dtype"] == "s8"]
+    assert not [(op["name"], op["shape"]) for op in ops if op["dtype"] == "bf16" and op["bytes"] >= 128 * 8192 * 2]  # no gate, no pair

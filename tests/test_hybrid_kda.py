@@ -115,7 +115,8 @@ def _weights_granite(cfg, seed=2**31 + 49):
 #: The recurrent kinds: how a toy of each is made, its plain reference, and
 #: the layer function whose slot handling the tests break. ``mamba2`` is the
 #: mixer beside every layer's attention, ``mamba2-alone`` the mixer as a layer
-#: of its own in periods with one GQA layer.
+#: of its own in periods with one GQA layer. (``tests/test_solar_open2.py`` adds a fourth, ``kda-gqa``, and runs
+#: the cases below for it from its own file, so that this one, the longest of the suite, stays one worker's share.)
 KINDS = {
     "kda": dict(toy=_toy, weights=_weights, hf=TINY_HYBRID_HF, ref=ref, module=kda, layer="kda_attention"),
     "mamba2": dict(toy=_toy_h1, weights=_weights_h1, hf=TINY_FALCON_H1_HF, ref=ref_h1, module=mamba2, layer="mamba_mixer"),
@@ -392,7 +393,7 @@ def test_slots_and_pages_are_sized_from_the_model(kind):
     cfg, params, _ = _model(kind)
     runner = ModelRunner(cfg, params, num_pages=8, page_size=4, max_batch_size=2, prefill_bucket=4, attn_impl="reference")
     want = {"kda": (6, 4, 2, (4, 16, 16), (3, 1, 3 * 64)), "mamba2": (3, 3, 3, (4, 8, 16), (3, 1, 64 + 2 * 2 * 8)),
-            "mamba2-alone": (8, 6, 2, (4, 8, 16), (3, 1, 64 + 2 * 8))}[kind]
+            "mamba2-alone": (8, 6, 2, (4, 8, 16), (3, 1, 64 + 2 * 8)), "kda-gqa": (8, 6, 2, (4, 16, 16), (3, 1, 3 * 64))}[kind]
     state, conv = runner.state
     assert (cfg.num_layers, cfg.recurrent_layers, cfg.cache_layers, *cfg.state_shapes()) == want
     assert runner.recurrent and runner.state_slots == 3 and runner.k_cache.shape[0] == cfg.cache_layers

@@ -46,6 +46,8 @@ def _plain(c):
     (6, 9, 4, 5 * 128, jnp.bfloat16, True, 1),  # Mamba-2's x, B, C with a bias: a row count no tile of 16 divides
     (5, 7, 4, 12288, jnp.bfloat16, False, 1),  # Ling-3.0-flash's width
     (5, 7, 4, 5120, jnp.bfloat16, True, 1),  # Falcon-H1-34B's
+    (3, 5, 4, 24576, jnp.bfloat16, False, 1),  # Solar-Open2-250B's: three streams of 64 heads, 192 rows of lanes
+    (2, 4, 4, 24576, jnp.bfloat16, False, 64),  # and a served chunk's 64 tokens at that width
     (3, 5, 4, 384, jnp.float32, True, 1),  # the toys' dtype
     (4, 6, 2, 256, jnp.bfloat16, False, 1),  # one carried input
     (4, 6, 5, 256, jnp.float32, True, 1),  # four
@@ -54,7 +56,7 @@ def _plain(c):
     (6, 9, 4, 5 * 128, jnp.bfloat16, True, 2),  # fewer tokens than carried inputs
     (5, 7, 4, 256, jnp.float32, True, 3),  # as many
     (5, 7, 3, 256, jnp.bfloat16, True, 64),  # a served chunk's 64 tokens
-], ids=["kda", "mamba-bias", "ling-width", "falcon-h1-width", "float32", "two-taps", "five-taps", "one-row",
+], ids=["kda", "mamba-bias", "ling-width", "falcon-h1-width", "solar-open2-width", "chunk-solar-open2-width", "float32", "two-taps", "five-taps", "one-row",
         "chunk-kda", "chunk-two-tokens", "chunk-three-tokens", "chunk-64"])
 def test_kernel_matches_the_conv_on_gathered_rows(rows, slots, taps, channels, dtype, bias, tokens):
     c = _case(rows * 7 + taps, rows, slots, taps, channels, dtype, bias, tokens)

@@ -12,7 +12,7 @@ from dynamo_tpu.models import kda
 from dynamo_tpu.ops import pallas_kda
 
 
-def _case(seed, rows, heads, key, value, slots):
+def _case(seed, rows, heads, key, value, slots, beta_scale=1.0):
     rng = np.random.default_rng(seed)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     f = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
@@ -20,7 +20,7 @@ def _case(seed, rows, heads, key, value, slots):
         state=f(rng.normal(size=(slots, heads, key, value))),
         q=f(unit(rng.normal(size=(rows, heads, key)))), k=f(unit(rng.normal(size=(rows, heads, key)))),
         v=f(rng.normal(size=(rows, heads, value))), g=f(-5 * rng.uniform(size=(rows, heads, key)) ** 3),
-        beta=f(rng.uniform(size=(rows, heads))))
+        beta=f(beta_scale * rng.uniform(size=(rows, heads))))
 
 
 def _budget(monkeypatch, block, key, value):
@@ -37,12 +37,15 @@ def _budget(monkeypatch, block, key, value):
     (2, 6, 8, 128, 4, 3),  # heads that the fitting block does not divide: the largest divisor within it
     (2, 12, 16, 128, 8, 6),  # a head count that is no power of two, in two blocks
     (3, 24, 16, 128, 64, 24),  # and in one
-], ids=["one-block", "two-blocks", "published-8", "published-16", "published-32", "odd-heads", "twelve-heads", "twenty-four-heads"])
+    (2, 64, 128, 128, 32, 32),  # Solar-Open2's 64 heads at the budget as served (8 MiB: 32 heads a block, two blocks a row)
+    (2, 64, 128, 128, 16, 16),  # and at half of it: four
+], ids=["one-block", "two-blocks", "published-8", "published-16", "published-32", "odd-heads", "twelve-heads", "twenty-four-heads",
+        "sixty-four-heads", "sixty-four-heads-16"])
 def test_kernel_matches_the_plain_step(monkeypatch, rows, heads, key, value, fits, block):
     _budget(monkeypatch, fits, key, value)
     assert pallas_kda.heads_block(heads, 4 * key * value) == block
     slots = rows + 3
-    c = _case(rows, rows, heads, key, value, slots)
+    c = _case(rows, rows, heads, key, value, slots, beta_scale=2.0 if heads == 64 else 1.0)  # a write strength in (0, 2)
     ids = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, slots))[:rows], jnp.int32)
     fresh = jnp.asarray(np.arange(rows) % 2 == 1)
     before = np.asarray(c["state"])

@@ -7,7 +7,10 @@ since PR 46, the Mamba-2 mixer of a layer that runs one beside its attention
 against the reference's ``mixer``, half the heads' step size cut so that they
 decay by 0.97-0.993 a token) or, since PR 49, of a layer that is nothing else
 (``--workload granite-4.0-h-small-pp4-int8.reason-saturated``: 128 heads of 64
-channels, two side by side in a row of the state buffer).
+channels, two side by side in a row of the state buffer) or, since PR 53, a KDA
+layer in Kimi Linear's own form at 64 heads (``--workload
+solar-open2-250b-ep8-int8.reason-saturated``: a softplus decay through a
+low-rank pair, a write strength up to 2, here drawn towards both its ends).
 
     python3 tools/kda_state_check.py [--workload <cell>] --seeds 3 --rows 3 --prompt 192 --decode 8
 
@@ -63,7 +66,9 @@ sys.path.insert(0, str(ROOT))
 
 
 def slow_decay(lp: dict, cfg, seed: int) -> dict:
-    """Half the heads forget slowly: their decay input cut to a tenth, their bias in [-8.5, -4.6]."""
+    """Half the heads forget slowly: their decay input cut to a tenth (the full-rank projection's columns, or the
+    second matrix's of a low-rank pair), their bias in [-8.5, -4.6]. Where the write strength reaches past 1
+    (``kda_beta_scale``), its input is made three times as large, so that it comes near both of its ends."""
     import jax
     import jax.numpy as jnp
 
@@ -71,8 +76,10 @@ def slow_decay(lp: dict, cfg, seed: int) -> dict:
     scale = jnp.repeat(jnp.where(jnp.arange(heads) < slow, 0.1, 1.0), hd)
     bias = jax.random.uniform(jax.random.PRNGKey(seed % 2**31), (heads, hd), jnp.float32, -8.5, -4.6)
     bias = jnp.where(jnp.arange(heads)[:, None] < slow, bias, 0.0).reshape(-1)
-    return {**lp, "w_decay": (lp["w_decay"].astype(jnp.float32) * scale).astype(lp["w_decay"].dtype),
-            "dt_bias": bias.astype(lp["dt_bias"].dtype), "a_log": jnp.zeros_like(lp["a_log"])}
+    times = lambda name, by: (lp[name].astype(jnp.float32) * by).astype(lp[name].dtype)  # noqa: E731
+    decay = "w_decay_b" if cfg.kda_low_rank else "w_decay"
+    out = {**lp, decay: times(decay, scale), "dt_bias": bias.astype(lp["dt_bias"].dtype), "a_log": jnp.zeros_like(lp["a_log"])}
+    return {**out, "w_beta": times("w_beta", 3.0)} if cfg.kda_beta_scale != 1.0 else out
 
 
 def slow_steps(lp: dict, cfg, seed: int) -> dict:
